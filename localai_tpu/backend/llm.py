@@ -9,6 +9,7 @@ from __future__ import annotations
 import json
 import os
 import resource
+import sys
 import threading
 import time
 
@@ -57,6 +58,7 @@ class LLMServicer(BackendServicer):
         self.cfg = None
         self.model_name = ""
         self._state = pb.StatusResponse.UNINITIALIZED
+        self._load_seconds: dict = {}
         self._load_lock = threading.Lock()
         if preloaded is not None:
             self.engine, self.cfg, self.tok, self.model_name = preloaded
@@ -78,7 +80,17 @@ class LLMServicer(BackendServicer):
                 self._state = pb.StatusResponse.READY
                 return pb.Result(success=True, message="ok")
             except Exception as e:  # surface load errors to the control plane
+                import traceback
+
+                traceback.print_exc()
                 self._state = pb.StatusResponse.ERROR
+                # a half-loaded engine must not answer "already loaded" to
+                # the next LoadModel, nor keep its loop thread on the chip.
+                # lockdep: allow(lock-blocking) — stopping the engine this
+                # load started is the tail of the same load; the load lock
+                # is the outermost lock and covers it like the load itself
+                self.shutdown()
+                self.engine = self.embedder = self.scorer = None
                 return pb.Result(success=False, message=f"{type(e).__name__}: {e}")
 
     def _load(self, request):
@@ -173,8 +185,11 @@ class LLMServicer(BackendServicer):
                 est.weights_bytes / 2**30, est.kv_cache_bytes / 2**30,
                 est.working_bytes / 2**30, shards)
 
+        t0 = time.monotonic()
         params = load_params(model_dir, cfg, dtype=request.dtype or None,
                              mesh=mesh)
+        jax.block_until_ready(params)   # so the phase below is not charged
+        t_weights = time.monotonic() - t0
         tok = load_tokenizer(model_dir)
         # single-shot prefill up to the chunk size; longer prompts prefill in
         # chunk-sized pieces interleaved with running decodes
@@ -239,17 +254,34 @@ class LLMServicer(BackendServicer):
         self.cfg, self.tok = cfg, tok
         self.model_name = request.model
         self.engine.start()
+        t1 = time.monotonic()
         if os.environ.get("LOCALAI_NO_PREWARM") != "1":
             self._prewarm()
+        # set-up time, for the device report and the log: where a cold start
+        # goes (compiles land in prewarm; a warm compile cache shrinks it)
+        self._load_seconds = {
+            "weights": round(t_weights, 1),
+            "engine": round(t1 - t0 - t_weights, 1),
+            "prewarm": round(time.monotonic() - t1, 1),
+        }
+        print(f"[load] {request.model}: {self._load_seconds}",
+              file=sys.stderr, flush=True)
 
     def _prewarm(self):
         """Compile the serving hot path before LoadModel returns READY (the
         llama.cpp server warms its graph the same way): K=1 admission, the
         fused decode block, and the fast-sampling tail. Without this the
-        FIRST user request pays tens of seconds of XLA compiles on TPU."""
+        FIRST user request pays tens of seconds of XLA compiles on TPU.
+
+        A failure here fails LoadModel: these are the programs every request
+        runs, so a compile the device refuses (a Pallas kernel Mosaic will
+        not lower, an OOM) is a model that cannot serve, not a warning."""
         from localai_tpu.engine import GenRequest
         from localai_tpu.ops.sampling import SamplingParams
 
+        if faults.fire("prewarm_raise") is not None:
+            raise RuntimeError(
+                "injected prewarm failure (LOCALAI_FAULT=prewarm_raise)")
         try:
             # pre-compile every decode-loop variant, sort-free sampling
             # tier, and remaining scan-ladder width directly (all-inactive
@@ -274,14 +306,18 @@ class LLMServicer(BackendServicer):
                 _, q = self.engine.submit(GenRequest(
                     prompt_ids=[1], max_tokens=n, ignore_eos=True,
                     params=sp))
-                while not q.get(timeout=600).finished:
-                    pass
-        except Exception:
-            import logging
-
-            logging.getLogger("localai_tpu").warning(
-                "prewarm failed; first request will pay compiles",
-                exc_info=True)
+                while True:
+                    o = q.get(timeout=600)
+                    if o.finished:
+                        break
+                if o.finish_reason != "length":
+                    # the engine loop caught a step failure, failed the
+                    # request and restarted (engine._loop) — from here that
+                    # looks like a short stream, so check what it ended on
+                    raise RuntimeError(
+                        f"prewarm request ended {o.finish_reason!r} after "
+                        f"{o.generated_tokens}/{n} tokens: "
+                        f"{self.engine.last_error or 'no engine error'}")
         finally:
             # the synthetic warm requests must not pollute the serving SLO
             # percentiles (warmup() snapshots the dispatch counters the same
@@ -650,7 +686,25 @@ class LLMServicer(BackendServicer):
         return pb.StatusResponse(
             state=self._state,
             memory=pb.MemoryUsageData(total=rss, breakdown={"rss_peak": rss}),
+            device_json=json.dumps(self._device_report()),
         )
+
+    def _device_report(self) -> dict:
+        """The device this process holds and what runs on it — {} until a
+        model is loaded (an idle backend has not touched the chip yet)."""
+        if self.engine is None and self.embedder is None:
+            return {}
+        from localai_tpu.system.device import device_report
+
+        rep = device_report()
+        rep["model"] = self.model_name
+        if self.engine is not None:
+            from localai_tpu.parallel.mesh import mesh_shape
+
+            rep["mesh"] = mesh_shape(self.engine.mesh)
+            rep["tiers"] = self.engine.kernel_tiers()
+            rep["load_seconds"] = self._load_seconds
+        return rep
 
     def GetMetrics(self, request, context):
         m = dict(self.engine.metrics) if self.engine else {}

@@ -481,8 +481,10 @@ def cli_util(args) -> int:
     if args.hbm_gb:
         hbm = int(args.hbm_gb * 2**30)
     else:
-        # table lookup only — a pre-flight CLI must never init a PJRT
-        # client (it would contend for the chip with a running server)
+        # table lookup on the operator's forced capability only — a
+        # pre-flight CLI never inits a device client (it would contend for
+        # the chip with a running server); without --hbm-gb or a forced
+        # capability, `fits` is unknown
         from localai_tpu.system.capabilities import detect_capability
         from localai_tpu.system.memory import hbm_table_bytes
 

@@ -46,6 +46,22 @@ def free_port() -> int:
     return port
 
 
+def _terminate(proc: subprocess.Popen, grace: float = 10.0):
+    """SIGTERM, then SIGKILL after `grace` (the forced-shutdown escape hatch,
+    process.go:29-43) — and wait either way: a chip belongs to one process at
+    a time, so a backend is only gone once it has been reaped. A backend
+    SIGTERMed mid-LoadModel does not exit on its own (the load runs on a
+    worker thread the interpreter joins at exit)."""
+    if proc.poll() is not None:
+        return
+    proc.terminate()
+    try:
+        proc.wait(timeout=grace)
+    except subprocess.TimeoutExpired:
+        proc.kill()
+        proc.wait()
+
+
 class SpawnCrashed(RuntimeError):
     """The backend child exited before ever answering health — either it
     crashed at startup or lost the free_port TOCTOU race (another process
@@ -63,6 +79,9 @@ class BackendHandle:
     busy: int = 0                 # in-flight requests
     last_used: float = field(default_factory=time.monotonic)
     busy_since: float = 0.0
+    device: dict = field(default_factory=dict)   # the backend's device report
+                                  # (Status.device_json) as of its load —
+                                  # the control plane's only device facts
     poisoned: str = ""            # terminal reason stamped by the reaper —
                                   # in-flight requests that now fail their
                                   # RPC surface THIS instead of a raw
@@ -130,9 +149,9 @@ class ModelManager:
     def _spawn_once(self, cfg: ModelConfig) -> BackendHandle:
         port = free_port()
         env = dict(os.environ)
-        # child must import localai_tpu regardless of the parent's cwd, and
-        # existing PYTHONPATH entries (e.g. a site hook registering the TPU
-        # PJRT plugin) must survive — prepend, never replace
+        # child must import localai_tpu regardless of the parent's cwd;
+        # existing PYTHONPATH entries are the operator's — prepend, never
+        # replace
         pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))))
         parts = [pkg_root] + [
@@ -195,7 +214,9 @@ class ModelManager:
             time.sleep(0.25)
         if not ready:
             client.close()
-            proc.terminate()
+            # the child may already hold the chip: it must be gone before a
+            # respawn can take it
+            _terminate(proc)
             raise RuntimeError(
                 f"backend for {cfg.name} never became healthy "
                 f"within {budget:.0f}s")
@@ -265,6 +286,12 @@ class ModelManager:
         )
         if not r.success:
             raise RuntimeError(f"LoadModel({m.name}) failed: {r.message}")
+        try:
+            handle.device = json.loads(
+                handle.client.status().device_json or "{}")
+        except grpc.RpcError:
+            # external (gallery-installed) backends may not implement Status
+            handle.device = {}
 
     # ------------------------------------------------------------ public api
 
@@ -343,6 +370,11 @@ class ModelManager:
         with self._lock:
             return sorted(self._models)
 
+    def devices(self) -> dict[str, dict]:
+        """model → the device report its backend gave at load."""
+        with self._lock:
+            return {n: h.device for n, h in sorted(self._models.items())}
+
     # reap reasons that are routine lifecycle, not failures — they go in the
     # flight-recorder ring but do not trigger a post-mortem dump
     _GRACEFUL_REAPS = ("stopped by request", "drained for shutdown",
@@ -362,12 +394,7 @@ class ModelManager:
                 del self._models[h.name]
         h.poison(reason)
         h.client.close()
-        if h.alive():
-            h.proc.terminate()
-            try:
-                h.proc.wait(timeout=10)
-            except subprocess.TimeoutExpired:
-                h.proc.kill()  # forced-shutdown escape hatch (process.go:29-43)
+        _terminate(h.proc)
 
     def stop_model(self, name: str) -> bool:
         h = self.get(name)

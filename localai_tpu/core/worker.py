@@ -25,6 +25,9 @@ def run_worker(args) -> int:
 
     import os
 
+    from localai_tpu.system.device import configure_compile_cache
+
+    configure_compile_cache()
     init_distributed(args.coordinator, args.num_processes, args.process_id)
     import jax
 

@@ -507,11 +507,25 @@ def _decode_dq(q, kc, vc, lengths, sliding_window=None, table=None,
                       sliding_window=sliding_window)
 
 
-def _pallas_paged_scatter(cfg: LlamaConfig | None, kv_quant: bool) -> bool:
-    """Whether the paged decode write should use the Pallas scatter-append
-    kernel (ops/pallas/paged_scatter.py) instead of the XLA scatter. Same
-    tier selection as _attn_impls' decode branch: Pallas on TPU (probe-gated)
-    or under LOCALAI_FORCE_PALLAS; XLA on CPU and under LOCALAI_NO_PALLAS.
+def _pallas_attention(mesh) -> bool:
+    """Whether attention runs on the Pallas kernels: on TPU without a mesh
+    (a mesh sends attention to XLA so GSPMD shards the einsums).
+    LOCALAI_FORCE_PALLAS=1 forces Pallas (interpreter off-TPU — tests);
+    LOCALAI_NO_PALLAS=1 is the one deliberate way to XLA on a TPU. Nothing
+    else chooses: a kernel Mosaic refuses fails the compile that uses it
+    (LoadModel's warmup) with its own message."""
+    import os
+
+    if os.environ.get("LOCALAI_FORCE_PALLAS") == "1":
+        return True
+    return (mesh is None and os.environ.get("LOCALAI_NO_PALLAS") != "1"
+            and jax.default_backend() == "tpu")
+
+
+def _pallas_paged_scatter(cfg: LlamaConfig | None) -> bool:
+    """Whether the paged decode write uses the Pallas scatter-append kernel
+    (ops/pallas/paged_scatter.py) instead of the XLA scatter: on TPU or
+    under LOCALAI_FORCE_PALLAS; XLA on CPU and under LOCALAI_NO_PALLAS.
 
     Under a mesh the pool shards its KV-head axis on 'model' and the kernel
     runs per-shard via shard_map (paged_scatter_append_sharded) — usable iff
@@ -530,58 +544,54 @@ def _pallas_paged_scatter(cfg: LlamaConfig | None, kv_quant: bool) -> bool:
             return False
     if os.environ.get("LOCALAI_FORCE_PALLAS") == "1":
         return True
-    if (os.environ.get("LOCALAI_NO_PALLAS") == "1"
-            or jax.default_backend() != "tpu"):
-        return False
-    from localai_tpu.ops.pallas import pallas_works
-
-    if cfg is not None:
-        return pallas_works(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                            cfg.sliding_window, cfg.jdtype, kv_quant=kv_quant)
-    return pallas_works(kv_quant=kv_quant)
+    return (os.environ.get("LOCALAI_NO_PALLAS") != "1"
+            and jax.default_backend() == "tpu")
 
 
-def _attn_impls(cfg: LlamaConfig | None = None, kv_quant: bool = False):
+def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
+                 ragged: bool = False, tiered: bool = False) -> dict[str, str]:
+    """Which implementation serves each hot-path op for an engine of this
+    shape — the same predicates the forwards consult at trace time, named
+    for the backend's device report. 'pallas-interpret' means the Pallas
+    kernels run in the interpreter (forced off-TPU): correct, never fast."""
+    from localai_tpu.ops.pallas.flash_attention import _interpret
+    from localai_tpu.parallel.mesh import activate_mesh, seq_axis_size
+
+    pallas = "pallas-interpret" if _interpret() else "pallas"
+    attn = pallas if _pallas_attention(mesh) else "xla"
+    with activate_mesh(mesh):
+        paged_kernel = pallas if paged and _pallas_paged_scatter(cfg) \
+            else "xla"
+    prefill = "xla-ring" if attn == "xla" and seq_axis_size(mesh) > 1 \
+        else attn
+    tiers = {
+        # first-chunk prompt attention (prefill); the KV lifecycle tier
+        # masks it per slot on XLA
+        "prefill_attention": "xla" if tiered else prefill,
+        # later chunks of a long prompt and spec verify windows (extend)
+        # attend against the cache on XLA in every configuration
+        "chunk_attention": "xla",
+        # the tiered (ring-mapped) cache read has no kernel yet
+        "decode_attention": "xla" if tiered else attn,
+        "decode_kv_write": paged_kernel,
+        "prefill_kv_write": "xla",
+    }
+    if ragged:
+        # ragged ticks: one flat-stream attention + row-DMA write kernel
+        # pair, selected like the paged decode write
+        tiers["ragged_attention"] = "xla" if tiered else paged_kernel
+        tiers["ragged_kv_write"] = paged_kernel
+    return tiers
+
+
+def _attn_impls():
     """Select attention kernels at trace time: Pallas (fused, online-softmax)
     on single-chip TPU; XLA reference under a mesh (GSPMD shards the einsums)
-    or on CPU. LOCALAI_FORCE_PALLAS=1 forces Pallas (interpreter on CPU —
-    used by tests); LOCALAI_NO_PALLAS=1 forces the XLA path."""
-    import os
+    or on CPU — see _pallas_attention."""
+    from localai_tpu.parallel.mesh import current_mesh, seq_axis_size
 
-    from localai_tpu.parallel.mesh import current_mesh
-
-    force = os.environ.get("LOCALAI_FORCE_PALLAS") == "1"
-    block = os.environ.get("LOCALAI_NO_PALLAS") == "1"
     mesh = current_mesh()
-    if mesh is not None and not force:
-        from localai_tpu.parallel.mesh import seq_axis_size
-
-        if seq_axis_size(mesh) > 1:
-            # sequence parallelism: prefill rides the ppermute ring over the
-            # 'seq' axis (parallel/ring_attention.py); decode (S=1) stays on
-            # the XLA path with GSPMD sharding
-            from localai_tpu.parallel.ring_attention import ring_prefill
-
-            return (lambda q, k, v, lengths, sliding_window=None:
-                    ring_prefill(q, k, v, lengths, mesh=mesh,
-                                 sliding_window=sliding_window),
-                    _decode_dq)
-        return mha_prefill, _decode_dq
-    use = force or (not block and jax.default_backend() == "tpu"
-                    and current_mesh() is None)
-    if use and not force:
-        # compile-probe this model's head geometry once: if Mosaic rejects
-        # the kernels on this chip, serve on the XLA path instead of dying
-        # inside the jitted step
-        from localai_tpu.ops.pallas import pallas_works
-
-        if cfg is not None:
-            use = pallas_works(cfg.num_heads, cfg.num_kv_heads, cfg.head_dim,
-                               cfg.sliding_window, cfg.jdtype,
-                               kv_quant=kv_quant)
-        else:
-            use = pallas_works(kv_quant=kv_quant)
-    if use:
+    if _pallas_attention(mesh):
         from localai_tpu.ops.pallas import (
             flash_prefill, ragged_decode, ragged_decode_q8,
         )
@@ -608,6 +618,16 @@ def _attn_impls(cfg: LlamaConfig | None = None, kv_quant: bool = False):
         return (lambda q, k, v, lengths, sliding_window=None:
                 flash_prefill(q, k, v, lengths, sliding_window=sliding_window),
                 attn_decode)
+    if seq_axis_size(mesh) > 1:
+        # sequence parallelism: prefill rides the ppermute ring over the
+        # 'seq' axis (parallel/ring_attention.py); decode (S=1) stays on
+        # the XLA path with GSPMD sharding
+        from localai_tpu.parallel.ring_attention import ring_prefill
+
+        return (lambda q, k, v, lengths, sliding_window=None:
+                ring_prefill(q, k, v, lengths, mesh=mesh,
+                             sliding_window=sliding_window),
+                _decode_dq)
     return mha_prefill, _decode_dq
 
 
@@ -624,7 +644,7 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     Returns (last_token_logits [B, V] f32, k_cache, v_cache).
     """
     b, s = tokens.shape
-    attn_prefill, _ = _attn_impls(cfg)
+    attn_prefill, _ = _attn_impls()
     if kvt is not None:
         # KV lifecycle tier: first-chunk self-attention under the per-slot
         # sink+window retention mask (engine/kvtier.py). quantize_cold slots
@@ -696,7 +716,7 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     b = tokens.shape[0]
     kv_quant = isinstance(k_cache, QuantKV)
     T = k_cache.shape[3] if table is None else table.shape[1] * 128
-    _, attn_decode = _attn_impls(cfg, kv_quant=kv_quant)
+    _, attn_decode = _attn_impls()
     positions = lengths[:, None]  # [B,1]
     if active is None:
         wpos, redirect = positions, None
@@ -714,7 +734,7 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     # (O(slots) traffic, provably in place) instead of an XLA scatter
     # through gathered physical indices — the scatter XLA de-optimizes into
     # a full-pool copy inside the fused decode block (VERDICT Weak #2)
-    kernel_write = table is not None and _pallas_paged_scatter(cfg, kv_quant)
+    kernel_write = table is not None and _pallas_paged_scatter(cfg)
     # under a mesh the pool shards its KV-head axis: the kernel runs
     # per-shard via shard_map (pallas_call has no GSPMD partitioning rule —
     # without this the partitioner would all-gather the whole pool)
@@ -839,7 +859,7 @@ def ragged_forward(params, cfg: LlamaConfig, tokens, cos, sin,
     t = tokens.shape[0]
     kv_quant = isinstance(k_cache, QuantKV)
     blk = (k_cache.q if kv_quant else k_cache).shape[3]        # pool BS
-    use_kernel = _pallas_paged_scatter(cfg, kv_quant)
+    use_kernel = _pallas_paged_scatter(cfg)
     mesh = None
     if use_kernel:
         from localai_tpu.parallel.mesh import current_mesh
@@ -1217,7 +1237,7 @@ def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):
     positions = jnp.arange(s)[None, :].repeat(b, 0)
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
-    attn_prefill, _ = _attn_impls(cfg)
+    attn_prefill, _ = _attn_impls()
     sax = _seq_ax()
     x = params["embed"].astype(cfg.jdtype)[tokens]
     x = _shard_act(x, P("data", sax, None))

@@ -2,8 +2,6 @@ from localai_tpu.ops.pallas.flash_attention import (  # noqa: F401
     flash_prefill,
     ragged_decode,
     ragged_decode_q8,
-    pallas_available,
-    pallas_works,
 )
 from localai_tpu.ops.pallas.paged_scatter import (  # noqa: F401
     paged_scatter_append,

@@ -23,8 +23,8 @@ Heads therefore live in the grid, never in a trailing block dim; every block
 is [..., seq_block, head_dim] over head-major [B, H, S, D] layouts.
 
 On CPU (tests) both run in interpreter mode; the math is identical. Real-TPU
-lowering is validated by tests/test_tpu_real.py (TPU-gated) and by the
-pallas_works() probe the model uses before selecting this path.
+lowering is validated by tests/test_tpu_real.py (TPU-gated), and at serving
+time by LoadModel's warmup compiles: a kernel Mosaic refuses fails the load.
 """
 from __future__ import annotations
 
@@ -35,20 +35,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from localai_tpu.testing import faults
+
 # large-but-finite so exp(NEG_INF - NEG_INF) stays 0/1 instead of NaN when a
 # row's first blocks are fully masked (sliding window, ragged tails)
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 
-# jax < 0.5 spells it TPUCompilerParams; 0.5+ renamed it CompilerParams
-CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams")
-
-
-def pallas_available() -> bool:
-    return jax.default_backend() == "tpu"
-
 
 def _interpret() -> bool:
+    """Interpreter mode off-TPU (tests; the backend's device report names it
+    'pallas-interpret'). Every pallas_call in this package asks here at
+    trace time, which makes it the chaos harness's one hook for "a kernel
+    the device refuses" (LOCALAI_FAULT=kernel_raise)."""
+    if faults.fire("kernel_raise") is not None:
+        raise RuntimeError(
+            "injected kernel lowering failure (LOCALAI_FAULT=kernel_raise)")
     return jax.default_backend() != "tpu"
 
 
@@ -147,7 +148,7 @@ def flash_prefill(q, k, v, lengths, sliding_window=None,
                                    lambda b, h, qb, lens: (b, h, qb, 0)),
         ),
         out_shape=jax.ShapeDtypeStruct(qt.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(lengths.astype(jnp.int32), qt, kt, vt)
@@ -266,7 +267,7 @@ def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
                 ],
             ),
             out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
         )(lengths.astype(jnp.int32), table.astype(jnp.int32), qg,
@@ -306,7 +307,7 @@ def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
@@ -431,7 +432,7 @@ def ragged_decode_q8(q, k_q, k_s, v_q, v_s, lengths, sliding_window=None,
                 ],
             ),
             out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=_interpret(),
         )(lengths.astype(jnp.int32), table.astype(jnp.int32), qg,
@@ -476,110 +477,9 @@ def ragged_decode_q8(q, k_q, k_s, v_q, v_s, lengths, sliding_window=None,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(lengths.astype(jnp.int32), qg, k_q, k_s.astype(jnp.float32),
       v_q, v_s.astype(jnp.float32))
     return out.reshape(B, 1, H, D)
-
-
-# --------------------------------------------------------------- probe
-
-_PROBE_CACHE: dict[tuple, bool] = {}
-
-
-def pallas_works(num_heads: int = 4, num_kv_heads: int = 2,
-                 head_dim: int = 128, sliding_window: int | None = None,
-                 dtype=jnp.bfloat16, kv_quant: bool = False) -> bool:
-    """Compile-probe the kernels once per (shape, dtype) on this backend.
-
-    Round-3 failure mode: the kernels lowered fine in interpreter mode but
-    Mosaic rejected them on the real chip — killing the serving engine from
-    inside the jitted step. Mosaic's tiling legality is SHAPE-dependent, so
-    the probe uses the caller's head geometry (the model passes its config),
-    letting the attention selector fall back to the XLA path instead of dying.
-    """
-    key = (num_heads, num_kv_heads, head_dim, sliding_window,
-           jnp.dtype(dtype).name, kv_quant)
-    if key in _PROBE_CACHE:
-        return _PROBE_CACHE[key]
-    if jax.default_backend() != "tpu":
-        _PROBE_CACHE[key] = True        # interpreter mode: always lowers
-        return True
-
-    def _probe():
-        # load-time tier probe: the block_until_ready fences ARE the point
-        # (prove each kernel lowers+runs on this chip before serving
-        # starts); never on a request path
-        B, S, T = 1, 256, 512
-        q = jnp.zeros((B, S, num_heads, head_dim), dtype)
-        kv = jnp.zeros((B, S, num_kv_heads, head_dim), dtype)
-        lengths = jnp.array([S], jnp.int32)
-        # lint: allow(sync-block-until-ready)
-        flash_prefill(q, kv, kv, lengths,
-                      sliding_window=sliding_window).block_until_ready()
-        qd = jnp.zeros((B, 1, num_heads, head_dim), dtype)
-        # paged pool shapes for the scatter-append probe (ops/pallas/
-        # paged_scatter.py) — the decode hot path's write kernel must lower
-        # on this chip too, or the whole paged tier falls back to XLA
-        from localai_tpu.ops.pallas.paged_scatter import (
-            paged_scatter_append, paged_scatter_append_q8,
-        )
-
-        table = jnp.zeros((B, 2), jnp.int32)
-        pos = jnp.zeros((B,), jnp.int32)
-        knew = jnp.zeros((B, num_kv_heads, head_dim), dtype)
-        if kv_quant:
-            cq = jnp.zeros((B, num_kv_heads, T, head_dim), jnp.int8)
-            cs = jnp.zeros((B, num_kv_heads, T // 128, 128), jnp.float32)
-            # lint: allow(sync-block-until-ready)
-            ragged_decode_q8(
-                qd, cq, cs, cq, cs, lengths,
-                sliding_window=sliding_window).block_until_ready()
-            pq = jnp.zeros((2, num_kv_heads, 128, head_dim), jnp.int8)
-            ps = jnp.zeros((2, num_kv_heads, 1, 128), jnp.float32)
-            # lint: allow(sync-block-until-ready)
-            jax.block_until_ready(paged_scatter_append_q8(
-                pq, ps, pq, ps, knew, knew, pos, table))
-        else:
-            cache = jnp.zeros((B, num_kv_heads, T, head_dim), dtype)
-            # lint: allow(sync-block-until-ready)
-            ragged_decode(qd, cache, cache, lengths,
-                          sliding_window=sliding_window).block_until_ready()
-            pool = jnp.zeros((2, num_kv_heads, 128, head_dim), dtype)
-            # lint: allow(sync-block-until-ready)
-            jax.block_until_ready(paged_scatter_append(
-                pool, pool, knew, knew, pos, table))
-
-    # _attn_impls consults this probe at TRACE time (inside jit). JAX's trace
-    # stack is thread-local, so a worker thread compiles + runs the probe
-    # eagerly even mid-trace — jnp.zeros above must produce real arrays, not
-    # tracers (round-4 bench silently fell back to XLA attention exactly
-    # here), and pallas_call cannot run under ensure_compile_time_eval.
-    import threading
-
-    box: dict = {}
-
-    def _runner():
-        try:
-            _probe()
-            box["ok"] = True
-        except Exception as e:          # pragma: no cover - TPU-only branch
-            box["ok"] = False
-            box["err"] = e
-
-    t = threading.Thread(target=_runner, daemon=True)
-    t.start()
-    t.join()
-    ok = box.get("ok", False)
-    if not ok:
-        import logging
-
-        logging.getLogger("localai_tpu").warning(
-            "Pallas attention failed to lower on %s for heads=%d kv=%d d=%d "
-            "— falling back to XLA attention: %s",
-            jax.devices()[0].device_kind, num_heads, num_kv_heads, head_dim,
-            box.get("err"))
-    _PROBE_CACHE[key] = ok
-    return ok

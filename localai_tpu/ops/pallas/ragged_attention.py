@@ -41,8 +41,8 @@ Tiers match the rest of ops/pallas:
 - `*_sharded`: shard_map wrappers over the pool's KV-head axis
   (models/llama.paged_pool_spec), same scheme as paged_scatter.py;
 - `ragged_scatter_append[_q8]`: flat-stream KV writes — the paged_scatter
-  row-DMA kernel driven by host-precomputed (physical block, row) targets,
-  one DMA per token, O(tokens) traffic.
+  kernel driven by per-row (physical block, row) targets, one grid step and
+  one native tile per token, O(tokens) traffic.
 
 Loop-carried metadata (ISSUE 16): every metadata input — block_seq,
 qstart/qlen/kvlen, tables — is an ordinary traced array, never a static
@@ -56,7 +56,7 @@ per tick and break the zero-recompile invariant the compile-count tripwire
 enforces.
 
 On CPU everything runs in interpreter mode (LOCALAI_FORCE_PALLAS=1 in
-tests); real-TPU lowering rides the same `pallas_works` probe gate.
+tests); real-TPU lowering is covered by tests/test_tpu_real.py.
 """
 from __future__ import annotations
 
@@ -64,23 +64,18 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map as _shard_map
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from localai_tpu.ops.pallas.flash_attention import (
     NEG_INF,
-    CompilerParams as _CompilerParams,
     _interpret,
 )
 from localai_tpu.ops.pallas.paged_scatter import (
-    _append_kernel,
-    _append_q8_kernel,
+    scatter_rows,
+    scatter_rows_q8,
 )
-
-try:                                  # jax >= 0.5 top-level export
-    from jax import shard_map as _shard_map
-except ImportError:                   # 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
 
 QBLK = 8   # q rows per grid block; every sequence's rows start on a boundary
 
@@ -220,7 +215,7 @@ def ragged_paged_attention(q, k_pool, v_pool, block_seq, qstart, qlen,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*_meta_i32(block_seq, qstart, qlen, kvlen, tables), qg,
@@ -316,7 +311,7 @@ def ragged_paged_attention_q8(q, k_q, k_s, v_q, v_s, block_seq, qstart,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(qg.shape, q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
     )(*_meta_i32(block_seq, qstart, qlen, kvlen, tables), qg,
@@ -439,7 +434,7 @@ def ragged_paged_attention_sharded(mesh, q, k_pool, v_pool, block_seq,
     """TP wrapper: per-shard ragged kernel over the pool's KV-head axis
     (paged_pool_spec). q's head axis is kv-head-major, so an even KV-head
     split keeps whole GQA groups on one shard (the cfg.num_kv_heads % tp
-    gate in models/llama). Metadata replicates; check_rep=False because the
+    gate in models/llama). Metadata replicates; check_vma=False because the
     kernel body is opaque to the replication checker."""
     from jax.sharding import PartitionSpec as P
 
@@ -451,7 +446,7 @@ def ragged_paged_attention_sharded(mesh, q, k_pool, v_pool, block_seq,
             sliding_window=sliding_window),
         mesh=mesh,
         in_specs=(qs_, pool, pool, rep, rep, rep, rep, rep),
-        out_specs=qs_, check_rep=False,
+        out_specs=qs_, check_vma=False,
     )(q, k_pool, v_pool, block_seq, qstart, qlen, kvlen, tables)
 
 
@@ -469,73 +464,28 @@ def ragged_paged_attention_q8_sharded(mesh, q, k_q, k_s, v_q, v_s,
             sliding_window=sliding_window),
         mesh=mesh,
         in_specs=(qs_, pool, pool, pool, pool, rep, rep, rep, rep, rep),
-        out_specs=qs_, check_rep=False,
+        out_specs=qs_, check_vma=False,
     )(q, k_q, k_s, v_q, v_s, block_seq, qstart, qlen, kvlen, tables)
 
 
 # ------------------------------------------------- flat-stream KV writes
-# The scatter-append kernels from paged_scatter.py, driven by
-# host-precomputed (physical block, in-block row) targets — the host knows
-# every write position at pack time (decode rows write at the slot's
-# current length, prefill rows at their absolute prompt position), so no
-# table math runs on device. Padding rows target the trash block (physical
-# 0) at caller-chosen rows.
+# The scatter-append kernels from paged_scatter.py, driven by per-row
+# (physical block, in-block row) targets derived from the per-sequence
+# metadata (models/llama.ragged_forward). One grid step per row, each
+# rewriting the row's whole native tile — 16 (bf16) or 32 (int8) rows for
+# one: fine for a decode row, wasteful for a long prefill chunk (not
+# measured). Padding rows target the trash block (physical 0).
 
 def ragged_scatter_append(k_pool, v_pool, k_new, v_new, pb, off):
-    """DMA each flat row into its pool slot, in place. k_new/v_new:
+    """Write each flat row into its pool slot, in place. k_new/v_new:
     [T, KVH, D]; pb/off: [T] i32. Returns the aliased (k_pool, v_pool)."""
-    t, kvh, d = k_new.shape
-    kn = k_new.reshape(t, kvh, 1, d).astype(k_pool.dtype)
-    vn = v_new.reshape(t, kvh, 1, d).astype(v_pool.dtype)
-    return pl.pallas_call(
-        _append_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(t,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
-            out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 2,
-            scratch_shapes=[pltpu.SemaphoreType.DMA((2,))],
-        ),
-        out_shape=[jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),
-                   jax.ShapeDtypeStruct(v_pool.shape, v_pool.dtype)],
-        input_output_aliases={4: 0, 5: 1},
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
-    )(pb.astype(jnp.int32), off.astype(jnp.int32), kn, vn, k_pool, v_pool)
+    return scatter_rows(k_pool, v_pool, k_new, v_new, pb, off)
 
 
 def ragged_scatter_append_q8(kq, ks, vq, vs, k_new, v_new, pb, off):
-    """int8 twin: quantize the flat rows (plain XLA) and DMA int8 bodies +
-    scale elements into the [NB, KVH, BS, D] / [NB, KVH, 1, BS] pools."""
-    from localai_tpu.ops.kvcache import quantize_tokens
-
-    t, kvh, d = k_new.shape
-    kq_n, ks_n = quantize_tokens(k_new)          # [T, KVH, D], [T, KVH]
-    vq_n, vs_n = quantize_tokens(v_new)
-    kq_n = kq_n.reshape(t, kvh, 1, d)
-    vq_n = vq_n.reshape(t, kvh, 1, d)
-    ks_n = ks_n.reshape(t, kvh, 1, 1).astype(ks.dtype)
-    vs_n = vs_n.reshape(t, kvh, 1, 1).astype(vs.dtype)
-    return pl.pallas_call(
-        _append_q8_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(t,),
-            in_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 8,
-            out_specs=[pl.BlockSpec(memory_space=pltpu.ANY)] * 4,
-            scratch_shapes=[pltpu.SemaphoreType.DMA((4,))],
-        ),
-        out_shape=[jax.ShapeDtypeStruct(kq.shape, kq.dtype),
-                   jax.ShapeDtypeStruct(ks.shape, ks.dtype),
-                   jax.ShapeDtypeStruct(vq.shape, vq.dtype),
-                   jax.ShapeDtypeStruct(vs.shape, vs.dtype)],
-        input_output_aliases={6: 0, 7: 1, 8: 2, 9: 3},
-        compiler_params=_CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        interpret=_interpret(),
-    )(pb.astype(jnp.int32), off.astype(jnp.int32), kq_n, ks_n, vq_n, vs_n,
-      kq, ks, vq, vs)
+    """int8 twin: quantize the flat rows and write int8 bodies + scale
+    elements into the [NB, KVH, BS, D] / [NB, KVH, 1, BS] pools."""
+    return scatter_rows_q8(kq, ks, vq, vs, k_new, v_new, pb, off)
 
 
 def ragged_scatter_append_sharded(mesh, k_pool, v_pool, k_new, v_new,
@@ -548,7 +498,7 @@ def ragged_scatter_append_sharded(mesh, k_pool, v_pool, k_new, v_new,
         lambda kp, vp, kn, vn, p, o: ragged_scatter_append(
             kp, vp, kn, vn, p, o),
         mesh=mesh, in_specs=(pool, pool, new, new, rep, rep),
-        out_specs=(pool, pool), check_rep=False,
+        out_specs=(pool, pool), check_vma=False,
     )(k_pool, v_pool, k_new, v_new, pb, off)
 
 
@@ -563,7 +513,7 @@ def ragged_scatter_append_q8_sharded(mesh, kq, ks, vq, vs, k_new, v_new,
         lambda a, b, c, d, kn, vn, p, o: ragged_scatter_append_q8(
             a, b, c, d, kn, vn, p, o),
         mesh=mesh, in_specs=(pool,) * 4 + (new, new, rep, rep),
-        out_specs=(pool,) * 4, check_rep=False,
+        out_specs=(pool,) * 4, check_vma=False,
     )(kq, ks, vq, vs, k_new, v_new, pb, off)
 
 

@@ -31,11 +31,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:                                  # jax >= 0.5 top-level export
-    _shard_map = jax.shard_map
-except AttributeError:                # 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from localai_tpu.models.llama import (
     LlamaConfig, _attn_impls, _lm_head, _mlp, _qkv, param_specs, rms_norm,
 )
@@ -107,7 +102,7 @@ def pipeline_hidden(params, cfg: LlamaConfig, tokens, *, mesh: Mesh,
     cos, sin = rope_table(cfg.rope, T)
     if lengths is None:
         lengths = jnp.full((B,), T, jnp.int32)
-    attn, _ = _attn_impls(cfg)
+    attn, _ = _attn_impls()
     emb = params["embed"].astype(cfg.jdtype)[tokens]          # [B, T, D]
     D = emb.shape[-1]
     positions = jnp.arange(T)[None, :]
@@ -139,12 +134,10 @@ def pipeline_hidden(params, cfg: LlamaConfig, tokens, *, mesh: Mesh,
             return (recv, out), None
 
         # the carry is stage-varying (and data-varying): mark the zeros init
-        # accordingly or jax 0.9's vma check rejects the scan (0.4.x has no
-        # varying-axes tracking — pcast is absent and unnecessary there)
+        # accordingly or the vma check rejects the scan
         init = (jnp.zeros((mb, T, D), emb_local.dtype),
                 jnp.zeros((n_micro, mb, T, D), emb_local.dtype))
-        if hasattr(jax.lax, "pcast"):
-            init = jax.lax.pcast(init, ("data", "pipe"), to="varying")
+        init = jax.lax.pcast(init, ("data", "pipe"), to="varying")
         (_, out), _ = jax.lax.scan(tick, init, jnp.arange(n_micro + S - 1))
         # broadcast the last stage's collected outputs to every pipe rank
         out = jax.lax.psum(
@@ -152,7 +145,7 @@ def pipeline_hidden(params, cfg: LlamaConfig, tokens, *, mesh: Mesh,
         return out.reshape(-1, T, D)
 
     dax = "data" if "data" in mesh.axis_names else None
-    x = _shard_map(
+    x = jax.shard_map(
         body, mesh=mesh,
         in_specs=(lspec, P(dax, None, None), P(dax)),
         out_specs=P(dax, None, None),

@@ -18,10 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-try:                                  # jax >= 0.5 top-level export
-    from jax import shard_map
-except ImportError:                   # 0.4.x spelling
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 

@@ -1564,6 +1564,9 @@ class API:
                 "state": int(st.state),
                 "memory_total": st.memory.total,
                 "busy": h.busy,
+                # the device as the backend sees it NOW (per-device bytes
+                # in use included); /system keeps the load-time report
+                "device": json.loads(st.device_json or "{}"),
                 # per-backend engine metrics (reference GetMetrics +
                 # get_token_metrics.go role): tok/s, ttft, cache hits...
                 "metrics": metrics,
@@ -1907,7 +1910,9 @@ class API:
     async def _system(self, request):
         from localai_tpu.system import system_info
 
-        info = await asyncio.to_thread(system_info)
+        # device facts come from the loaded backends' own reports: this
+        # process must never import JAX (it would take the chip from them)
+        info = system_info(self.manager.devices())
         info["loaded_models"] = self.manager.loaded()
         return web.json_response(info)
 

@@ -1,22 +1,59 @@
-"""Hardware capability detection (reference: /root/reference/pkg/system/
+"""Hardware capability keys (reference: /root/reference/pkg/system/
 capabilities.go:28-99 — GPU vendor → capability string used to pick concrete
 backends, force-file override :49-64; sysinfo pkg/xsysinfo).
 
-TPU build: capability keys are `tpu-v4|tpu-v5e|tpu-v5p|tpu-v6e|cpu`, detected
-from the attached JAX device (lazily — detection must not initialize a TPU
-client at import time)."""
+TPU build: capability keys are `tpu-v4|tpu-v5e|tpu-v5p|tpu-v6e|cpu`. This
+module never touches JAX: a chip belongs to one process at a time, and that
+process is a backend. The control plane (HTTP server, CLI pre-flight, backend
+gallery) learns the device from the force file/env or from what a loaded
+backend reports (`Status.device_json`, system/device.py) — a control plane
+that called `jax.devices()` would take the chip from every backend it spawns.
+"""
 from __future__ import annotations
 
-import functools
+import dataclasses
 import os
 
 
 CAPABILITY_FORCE_FILE = "/run/localai/capability"
 
 
-@functools.lru_cache(maxsize=1)
-def detect_capability() -> str:
-    # force-file override wins (capabilities.go:49-64)
+@dataclasses.dataclass(frozen=True)
+class Chip:
+    capability: str
+    bf16_flops: float       # peak dense bf16 FLOP/s per chip
+    int8_ops: float         # peak int8 OP/s per chip
+    hbm_bytes_per_s: float  # peak HBM bandwidth per chip
+
+
+# The one table of accelerators this repo knows, keyed by JAX's
+# `device.device_kind`. Peaks are the published per-chip figures (Google
+# Cloud TPU documentation, system-architecture pages "TPU v4" / "TPU v5e" /
+# "TPU v5p" / "TPU v6e"). There is no default and no CPU row: a device that
+# is not here gets no MFU or roofline figure at all. The v5e key was read
+# off the chip (chip_smoke.py, PR 21); the other three keys follow JAX's
+# naming for those generations and have not been seen by this repo.
+CHIPS: dict[str, Chip] = {
+    "TPU v4": Chip("tpu-v4", 275e12, 275e12, 1228e9),
+    "TPU v5 lite": Chip("tpu-v5e", 197e12, 393e12, 819e9),
+    "TPU v5": Chip("tpu-v5p", 459e12, 918e12, 2765e9),
+    "TPU v6 lite": Chip("tpu-v6e", 918e12, 1836e12, 1640e9),
+}
+
+
+def capability_of(platform: str, device_kind: str) -> str:
+    """Capability key for a device as JAX names it (a backend's report)."""
+    if platform == "cpu":
+        return "cpu"
+    chip = CHIPS.get(device_kind)
+    return chip.capability if chip else platform
+
+
+def detect_capability(device: dict | None = None) -> str:
+    """Capability key: the operator's override, else the device a backend
+    reported ({"platform", "device_kind"}), else "unknown" — the control
+    plane does not probe for itself."""
+    # force-file override wins, then the env (capabilities.go:49-64)
     if os.path.exists(CAPABILITY_FORCE_FILE):
         with open(CAPABILITY_FORCE_FILE) as f:
             forced = f.read().strip()
@@ -24,34 +61,26 @@ def detect_capability() -> str:
             return forced
     if os.environ.get("LOCALAI_FORCE_CAPABILITY"):
         return os.environ["LOCALAI_FORCE_CAPABILITY"]
+    if device and device.get("platform"):
+        return capability_of(device["platform"],
+                             device.get("device_kind", ""))
+    return "unknown"
+
+
+def system_info(backends: dict[str, dict] | None = None) -> dict:
+    """CPU/memory/accelerator summary (xsysinfo role). `backends` maps each
+    loaded model to its backend's device report; the accelerator fields come
+    from there (empty until a model is loaded)."""
+    from localai_tpu.system.memory import hbm_table_bytes
+
+    backends = backends or {}
+    first = next(iter(backends.values()), None)
+    info: dict = {"capability": detect_capability(first)}
+    hbm = hbm_table_bytes(info["capability"])
+    if hbm:
+        info["hbm_bytes"] = hbm
+    info["cpu_count"] = os.cpu_count()
     try:
-        import jax
-
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", "").lower()
-        if d.platform == "cpu":
-            return "cpu"
-        for tag in ("v6e", "v5p", "v5e", "v5", "v4"):
-            if tag in kind:
-                return f"tpu-{'v5e' if tag == 'v5' else tag}"
-        return "tpu"
-    except Exception:
-        return "cpu"
-
-
-def system_info() -> dict:
-    """CPU/memory/accelerator summary (xsysinfo role)."""
-    info: dict = {"capability": detect_capability()}
-    try:
-        from localai_tpu.system.memory import hbm_table_bytes
-
-        hbm = hbm_table_bytes(info["capability"])
-        if hbm:
-            info["hbm_bytes"] = hbm
-    except Exception:
-        pass
-    try:
-        info["cpu_count"] = os.cpu_count()
         with open("/proc/meminfo") as f:
             for line in f:
                 if line.startswith("MemTotal"):
@@ -59,14 +88,5 @@ def system_info() -> dict:
                     break
     except OSError:
         pass
-    try:
-        import jax
-
-        info["devices"] = [
-            {"id": d.id, "platform": d.platform,
-             "kind": getattr(d, "device_kind", "")}
-            for d in jax.devices()
-        ]
-    except Exception:
-        info["devices"] = []
+    info["backends"] = backends
     return info

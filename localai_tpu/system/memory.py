@@ -69,21 +69,19 @@ def hbm_table_bytes(capability: str) -> int | None:
 
 def detect_hbm_bytes() -> int | None:
     """Attached accelerator memory: memory_stats()['bytes_limit'] when the
-    runtime exposes it, else the generation table, else None (CPU)."""
-    try:
-        import jax
+    runtime exposes it, else the generation table, else None (CPU).
+    Initializes the device client — backend-process only."""
+    import jax
 
-        dev = jax.devices()[0]
-        if dev.platform == "cpu":
-            return None
-        stats = getattr(dev, "memory_stats", lambda: None)()
-        if stats and stats.get("bytes_limit"):
-            return int(stats["bytes_limit"])
-    except Exception:
+    from localai_tpu.system.capabilities import capability_of
+
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
         return None
-    from localai_tpu.system.capabilities import detect_capability
-
-    return hbm_table_bytes(detect_capability())
+    stats = dev.memory_stats() or {}
+    if stats.get("bytes_limit"):
+        return int(stats["bytes_limit"])
+    return hbm_table_bytes(capability_of(dev.platform, dev.device_kind))
 
 
 def estimate(cfg, *, slots: int, context: int, dtype: str = "bfloat16",

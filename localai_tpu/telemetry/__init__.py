@@ -46,7 +46,6 @@ from localai_tpu.telemetry.trace import (  # noqa: F401
 from localai_tpu.telemetry.profiler import (  # noqa: F401
     StepProfiler,
     engine_profiler,
-    peak_flops,
     profile_enabled,
     set_profile_enabled,
 )
@@ -69,7 +68,6 @@ from localai_tpu.telemetry.sched import (  # noqa: F401
     TickLedger,
     current_tick,
     maybe_ledger,
-    peak_bandwidth,
     reason_category,
     roofline_entry,
     sched_enabled,

@@ -49,23 +49,6 @@ def set_profile_enabled(value: bool | None) -> None:
     _FORCED = value
 
 
-def peak_flops(device_kind: str) -> float:
-    """bf16 peak for the accelerator kind (v5e 197 TF/s, v6e 918; CPU gets a
-    nominal 100 GF/s so MFU stays meaningful in smoke runs)."""
-    kind = (device_kind or "").lower()
-    if "v6" in kind:
-        return 918e12
-    if "v5p" in kind:
-        return 459e12
-    if "v5" in kind:
-        return 197e12
-    if "v4" in kind:
-        return 275e12
-    if "cpu" in kind:
-        return 100e9
-    return 197e12
-
-
 class _Stage:
     __slots__ = ("count", "total_s", "min_s", "max_s", "tokens", "hist")
 
@@ -265,15 +248,13 @@ def engine_profiler(cfg=None, mesh=None) -> StepProfiler | None:
             n_params = param_count(cfg)
         except Exception:
             n_params = 0
-    kind = ""
-    try:
-        import jax
+    import jax
 
-        d = jax.devices()[0]
-        kind = getattr(d, "device_kind", d.platform)
-    except Exception:
-        pass
-    from localai_tpu.telemetry.sched import peak_bandwidth
+    from localai_tpu.system.capabilities import CHIPS
 
-    return StepProfiler(fence=True, n_params=n_params, peak=peak_flops(kind),
-                        mesh=shape, peak_bw=peak_bandwidth(kind))
+    # no peak for a device the chip table does not know: the report then
+    # carries timings and counts, and no MFU
+    chip = CHIPS.get(jax.devices()[0].device_kind)
+    return StepProfiler(fence=True, n_params=n_params,
+                        peak=chip.bf16_flops if chip else 0.0, mesh=shape,
+                        peak_bw=chip.hbm_bytes_per_s if chip else 0.0)

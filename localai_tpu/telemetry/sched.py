@@ -24,10 +24,12 @@ Three pieces, all riding the LOCALAI_METRICS default-ON gate:
 
 - roofline helpers: fold XLA's `lower().compile().cost_analysis()` FLOPs +
   bytes into compute- vs bandwidth-bound attribution per compiled program
-  variant. `peak_bandwidth` mirrors profiler.peak_flops; the ridge point
-  (peak_flops / peak_bw) splits the two regimes, and the per-variant `mfu`
-  is the roofline model's ceiling for that program — what the dispatch
-  could reach if it ran exactly at the limiting resource's peak.
+  variant. Peaks come from the one chip table (system/capabilities.CHIPS,
+  keyed by `device_kind`); the ridge point (peak_flops / peak_bw) splits
+  the two regimes, and the per-variant `mfu` is the roofline model's
+  ceiling for that program — what the dispatch could reach if it ran
+  exactly at the limiting resource's peak. A device the table does not
+  know gets the cost counts only.
 """
 from __future__ import annotations
 
@@ -188,47 +190,38 @@ def current_tick() -> int | None:
 
 
 # ------------------------------------------------------------------ rooflines
-def peak_bandwidth(device_kind: str) -> float:
-    """HBM peak bytes/s for the accelerator kind (v5e 819 GB/s, v6e 1640;
-    CPU gets a nominal 50 GB/s so roofline attribution stays meaningful in
-    smoke runs). Mirrors profiler.peak_flops."""
-    kind = (device_kind or "").lower()
-    if "v6" in kind:
-        return 1640e9
-    if "v5p" in kind:
-        return 2765e9
-    if "v5" in kind:
-        return 819e9
-    if "v4" in kind:
-        return 1228e9
-    if "cpu" in kind:
-        return 50e9
-    return 819e9
-
-
-def roofline_entry(flops: float, bytes_: float, peak_flops: float,
-                   peak_bw: float) -> dict:
+def roofline_entry(flops: float, bytes_: float,
+                   peak_flops: float | None = None,
+                   peak_bw: float | None = None) -> dict:
     """Fold one program's XLA cost analysis into roofline attribution.
 
     `mfu` here is the roofline-model CEILING for the program: the fraction
     of peak FLOP/s it could sustain if it ran exactly at the limiting
     resource's peak (1.0 when compute-bound, intensity/ridge when
-    bandwidth-bound). Measured MFU can only be lower."""
-    t_c = flops / peak_flops if peak_flops > 0 else 0.0
-    t_m = bytes_ / peak_bw if peak_bw > 0 else 0.0
-    t = max(t_c, t_m)
-    return {
+    bandwidth-bound). Measured MFU can only be lower.
+
+    Without peaks (a device not in system/capabilities.CHIPS — a CPU
+    included) the entry carries the counts only: no time, bound or mfu is
+    invented for a device nobody published peaks for."""
+    entry = {
         "cost_flops": flops,
         "cost_bytes": bytes_,
         "intensity_flops_per_byte": (flops / bytes_) if bytes_ > 0 else 0.0,
-        "ridge_flops_per_byte": (peak_flops / peak_bw) if peak_bw > 0
-        else 0.0,
+    }
+    if not peak_flops or not peak_bw:
+        return entry
+    t_c = flops / peak_flops
+    t_m = bytes_ / peak_bw
+    t = max(t_c, t_m)
+    entry.update({
+        "ridge_flops_per_byte": peak_flops / peak_bw,
         "bound": "compute" if t_c >= t_m else "bandwidth",
         "t_compute_us": t_c * 1e6,
         "t_memory_us": t_m * 1e6,
         "t_roofline_us": t * 1e6,
         "mfu": (t_c / t) if t > 0 else 0.0,
-    }
+    })
+    return entry
 
 
 # ----------------------------------------------------------------- the ledger
@@ -368,7 +361,8 @@ class TickLedger:
             for name, e in self.rooflines.items():
                 out[f"{prefix}roofline__{name}__flops"] = e["cost_flops"]
                 out[f"{prefix}roofline__{name}__bytes"] = e["cost_bytes"]
-                out[f"{prefix}roofline__{name}__mfu"] = e["mfu"]
+                if "mfu" in e:
+                    out[f"{prefix}roofline__{name}__mfu"] = e["mfu"]
         return out
 
     def snapshot(self, last: int = 64) -> dict:

@@ -7,6 +7,11 @@ list of fault specs, each `kind[:arg[:limit[:target]]]`:
     `spawn_crash`   backend process exits immediately at startup (the
                     free_port TOCTOU / dead-child shape; arg = exit code)
     `slow_start`    backend sleeps `arg` seconds before serving health
+    `prewarm_raise` the llm backend's LoadModel prewarm raises — the load
+                    must fail, not report READY
+    `kernel_raise`  a Pallas kernel wrapper raises at trace time (a kernel
+                    the device refuses to lower) — the load must fail with
+                    that message, not switch tier
     `unavailable`   Predict/PredictStream aborts with gRPC UNAVAILABLE
     `deadline`      Predict/PredictStream aborts with DEADLINE_EXCEEDED
     `stall_stream`  PredictStream sleeps `arg` seconds after its first chunk

@@ -163,7 +163,7 @@ def test_manager_spawns_external_backend(tmp_path):
         "--backend store \"$@\"\n")
     store_dir = tmp_path / "store-data"
     store_dir.mkdir()
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     app = AppConfig(models_path=str(tmp_path), backends_path=str(bp))
     mgr = ModelManager(app)
     cfg = ModelConfig.from_dict({

@@ -37,7 +37,7 @@ def test_cli_version(capsys):
 
 
 def test_cli_tts_writes_wav(tmp_path, monkeypatch):
-    monkeypatch.setenv("LOCALAI_JAX_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     out = tmp_path / "speech.wav"
     rc = main(["tts", "hello from the cli", "--output-file", str(out),
                "--models-path", str(tmp_path)])
@@ -50,7 +50,7 @@ def test_cli_tts_writes_wav(tmp_path, monkeypatch):
 def test_cli_soundgeneration_writes_wav(tmp_path, monkeypatch):
     """`soundgeneration` wraps the existing SoundGeneration RPC (reference
     core/cli/soundgeneration.go; VERDICT Missing #7)."""
-    monkeypatch.setenv("LOCALAI_JAX_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     out = tmp_path / "rain.wav"
     rc = main(["soundgeneration", "rain on a tin roof", "--duration", "1.0",
                "--output-file", str(out), "--models-path", str(tmp_path)])
@@ -62,7 +62,7 @@ def test_cli_soundgeneration_writes_wav(tmp_path, monkeypatch):
 
 def test_cli_transcript_formats(tmp_path, monkeypatch, whisper_models_dir,
                                 capsys):
-    monkeypatch.setenv("LOCALAI_JAX_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     wav = tmp_path / "in.wav"
     rc = main(["tts", "testing one two three", "--output-file", str(wav),
                "--models-path", str(tmp_path)])

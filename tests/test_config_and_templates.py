@@ -235,7 +235,7 @@ def test_watchdog_reaps_idle(tmp_path, tmp_path_factory):
     from localai_tpu.core.manager import ModelManager
 
     import os
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     ckpt = tiny_checkpoint(tmp_path_factory)
     cfg = ModelConfig(name="tiny", context_size=64, parallel=1, dtype="float32")
     cfg.parameters.model = ckpt

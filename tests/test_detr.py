@@ -127,7 +127,7 @@ def detect_stack(tmp_path_factory):
         "name": "det", "backend": "detect",
         "parameters": {"model": ckpt},
     }))
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]

@@ -129,11 +129,18 @@ def test_capability_detection_forced(monkeypatch):
     from localai_tpu.system import capabilities
 
     monkeypatch.setenv("LOCALAI_FORCE_CAPABILITY", "tpu-v5e")
-    capabilities.detect_capability.cache_clear()
     assert capabilities.detect_capability() == "tpu-v5e"
     monkeypatch.delenv("LOCALAI_FORCE_CAPABILITY")
-    capabilities.detect_capability.cache_clear()
-    assert capabilities.detect_capability() == "cpu"  # tests force CPU
+    # the control plane does not probe: without an override the capability
+    # is whatever a backend reported, else unknown
+    assert capabilities.detect_capability() == "unknown"
+    assert capabilities.detect_capability(
+        {"platform": "cpu", "device_kind": "cpu"}) == "cpu"
+    assert capabilities.detect_capability(
+        {"platform": "tpu", "device_kind": "TPU v5 lite"}) == "tpu-v5e"
+    # a TPU the chip table does not know keeps its platform, gets no peaks
+    assert capabilities.detect_capability(
+        {"platform": "tpu", "device_kind": "TPU v9"}) == "tpu"
 
 
 def test_gallery_path_traversal_rejected(gallery_fixture, tmp_path):

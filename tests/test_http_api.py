@@ -81,7 +81,7 @@ def stack(tmp_path_factory):
         },
     }))
 
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     port = _free_port()
     app_cfg = AppConfig(address=f"127.0.0.1:{port}",
                         models_path=str(models), parallel_requests=2)

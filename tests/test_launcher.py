@@ -15,7 +15,7 @@ def test_launcher_lifecycle(tmp_path, monkeypatch):
         port = s.getsockname()[1]
     models = tmp_path / "models"
     models.mkdir()
-    monkeypatch.setenv("LOCALAI_JAX_PLATFORM", "cpu")
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     l = Launcher(address=f"127.0.0.1:{port}", models_path=str(models))
     assert not l.running
     assert l.start()

@@ -113,7 +113,7 @@ def mcp_stack(tmp_path_factory):
             "command": f"{sys.executable} {SERVER} {call_log}"}]},
         "agent": {"max_iterations": 2},
     }))
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     with socket.socket() as s:
         s.bind(("127.0.0.1", 0))
         port = s.getsockname()[1]

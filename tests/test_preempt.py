@@ -514,7 +514,7 @@ def pstack(tmp_path_factory, preempt_faultenv):
         _write_kv_model(models, name, ckpt, kv_host_bytes=1 << 26)
     for name in ("ntiny", "rtiny"):
         _write_kv_model(models, name, ckpt)
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     app_cfg = AppConfig(
         address=f"127.0.0.1:{_free_port()}", models_path=str(models),
         parallel_requests=2, retry_budget=1, spawn_retries=1,

@@ -1,9 +1,8 @@
 """Fused multi-step ragged ticks (ISSUE 16).
 
 Tier-1 (cheap units): the decode-token-aware dispatch budget has teeth in
-BOTH directions, `_ragged_loop_fn` rides the compile-count guard's attr
-list, and bench.py's probe-keepalive reuse path works on CPU (fake child —
-the protocol, not the chip, is under test).
+BOTH directions, and `_ragged_loop_fn` rides the compile-count guard's attr
+list.
 
 Slow (engine-driving, per PR 8/10 precedent): exact token parity fused vs
 single-step ragged across greedy + sampled + grammar tenants with
@@ -112,76 +111,6 @@ def test_ragged_loop_fn_rides_compile_count_guard():
     from localai_tpu.testing.tripwires import DECODE_FN_ATTRS
 
     assert "_ragged_loop_fn" in DECODE_FN_ATTRS
-
-
-# ------------------------------------------------- probe keepalive (CPU)
-
-_FAKE_PROBE_CHILD = r"""
-import sys
-for p in ("plugin_handshake", "client_init", "first_device_put",
-          "first_compile"):
-    print(f"PROBE_PHASE {p} 0.0s", flush=True)
-print("PROBE_OK cpu cpu 0s", flush=True)
-for line in sys.stdin:
-    cmd = line.strip()
-    if cmd == "PING":
-        print("PROBE_ALIVE cpu cpu", flush=True)
-    elif cmd == "QUIT":
-        break
-"""
-
-
-def test_probe_keepalive_reuses_live_client(monkeypatch):
-    """--probe-keepalive: the first probe cold-starts one child; the next
-    probe PINGs it instead of re-running the ladder (the pre-initialized
-    device-client reuse path). Fake child — protocol-level unit test."""
-    import bench
-
-    monkeypatch.setattr(bench, "_KEEPALIVE_CHILD", _FAKE_PROBE_CHILD)
-    monkeypatch.setattr(bench, "_KEEPALIVE", None)
-    args = bench.build_parser().parse_args(
-        ["--mode", "engine", "--probe-keepalive"])
-    use_cpu, err, kind = bench.probe_accelerator(args)
-    assert (use_cpu, err, kind) == (True, "", "cpu")
-    a = args.probe_report["attempts"][0]
-    assert a["ok"] and a["keepalive"] and a["phases_s"]["first_compile"] == 0
-    ka = bench._KEEPALIVE
-    assert ka is not None and ka.alive()
-    try:
-        args2 = bench.build_parser().parse_args(
-            ["--mode", "ragged", "--probe-keepalive"])
-        use_cpu2, err2, kind2 = bench.probe_accelerator(args2)
-        assert (use_cpu2, err2, kind2) == (True, "", "cpu")
-        assert args2.probe_report["keepalive_reused"] is True
-        # reuse = NO new cold attempt, same live child
-        assert args2.probe_report["attempts"] == []
-        assert bench._KEEPALIVE is ka and ka.alive()
-    finally:
-        ka.close()
-        bench._KEEPALIVE = None
-    assert not ka.alive()
-
-
-def test_probe_keepalive_dead_child_cold_probes(monkeypatch):
-    """A died keepalive child doesn't poison later probes: ping fails and
-    the next call cold-starts a fresh child."""
-    import bench
-
-    monkeypatch.setattr(bench, "_KEEPALIVE_CHILD", _FAKE_PROBE_CHILD)
-    monkeypatch.setattr(bench, "_KEEPALIVE", None)
-    args = bench.build_parser().parse_args(
-        ["--mode", "engine", "--probe-keepalive"])
-    bench.probe_accelerator(args)
-    bench._KEEPALIVE.proc.kill()
-    bench._KEEPALIVE.proc.wait()
-    args2 = bench.build_parser().parse_args(
-        ["--mode", "engine", "--probe-keepalive"])
-    use_cpu, err, kind = bench.probe_accelerator(args2)
-    assert (use_cpu, err, kind) == (True, "", "cpu")
-    assert "keepalive_reused" not in args2.probe_report
-    assert args2.probe_report["attempts"][0]["ok"]
-    bench._KEEPALIVE.close()
-    bench._KEEPALIVE = None
 
 
 # --------------------------------------------- engine parity (slow tier)

@@ -129,7 +129,7 @@ def stack(tmp_path_factory, faultenv):
         _write_model(models, name, ckpt)
     _write_model(models, "staller2", ckpt, parallel=1)
 
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     app_cfg = AppConfig(
         address=f"127.0.0.1:{_free_port()}", models_path=str(models),
         parallel_requests=2, queue_depth=0, retry_budget=1,
@@ -522,7 +522,7 @@ def wd_stack(tmp_path_factory, faultenv):
     ckpt = tiny_checkpoint(tmp_path_factory)
     models = tmp_path_factory.mktemp("models-wd")
     _write_model(models, "wtiny", ckpt)
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     app_cfg = AppConfig(
         address=f"127.0.0.1:{_free_port()}", models_path=str(models),
         parallel_requests=2, watchdog_busy_timeout=1.5,
@@ -559,7 +559,7 @@ def drain_stack(tmp_path_factory, faultenv):
     ckpt = tiny_checkpoint(tmp_path_factory)
     models = tmp_path_factory.mktemp("models-drain")
     _write_model(models, "dtiny", ckpt)
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     app_cfg = AppConfig(
         address=f"127.0.0.1:{_free_port()}", models_path=str(models),
         parallel_requests=2, drain_timeout=15.0, spawn_timeout=60.0)

@@ -255,7 +255,7 @@ def traced_stack(tmp_path_factory):
     port = s.getsockname()[1]
     s.close()
 
-    os.environ["LOCALAI_JAX_PLATFORM"] = "cpu"
+    os.environ["JAX_PLATFORMS"] = "cpu"
     old_trace = os.environ.get("LOCALAI_TRACE")
     old_prof = os.environ.get("LOCALAI_PROFILE")
     os.environ["LOCALAI_TRACE"] = "1"    # backend subprocess inherits

@@ -471,6 +471,9 @@ def test_manager_plumbs_tensor_parallel_to_mesh_model():
             self.kw = kw
             return types.SimpleNamespace(success=True)
 
+        def status(self):
+            return types.SimpleNamespace(device_json="")
+
     mgr = ModelManager.__new__(ModelManager)
     mgr.app = AppConfig(tensor_parallel=4)
     h = types.SimpleNamespace(client=FakeClient(),

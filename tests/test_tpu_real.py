@@ -1,9 +1,13 @@
-"""Real-TPU lowering tests (the round-3 gap: kernels that pass in interpreter
-mode but die in Mosaic lowering on hardware).
+"""Real-TPU lowering tests: kernels that pass in interpreter mode can still
+die in Mosaic lowering on hardware, and only a chip run can tell.
 
-Skipped on the CPU harness; run with `LOCALAI_TPU_TESTS=1 python -m pytest
-tests/test_tpu_real.py` on a machine with a TPU attached. The driver's bench
-exercises the same compile path, but these give targeted failures.
+One lowering-and-parity test per `pallas_call` in `localai_tpu/ops/pallas/`,
+each against its XLA twin, at the Llama-8B head geometry (32/8/128) and one
+D=64 geometry; then the engine programs that compose them (dense, paged,
+int8-paged, ragged) for a few ticks.
+
+Skipped on the CPU harness; run on a machine with a TPU attached:
+`LOCALAI_TPU_TESTS=1 python -m pytest tests/test_tpu_real.py`.
 """
 import jax
 import jax.numpy as jnp
@@ -15,30 +19,50 @@ pytestmark = pytest.mark.skipif(
     reason="requires a real TPU (LOCALAI_TPU_TESTS=1)",
 )
 
+GEOMS = [(32, 8, 128), (8, 4, 64)]          # (H, KVH, D)
+BS = 128                                     # ops.paged.BLOCK
+
 
 def _bf16(key, shape):
     return jax.random.normal(jax.random.PRNGKey(key), shape, jnp.bfloat16)
 
 
-@pytest.mark.parametrize("H,KVH,D", [(8, 4, 64), (8, 8, 128), (32, 8, 128)])
-def test_flash_prefill_lowers_and_matches(H, KVH, D):
+def _close(out, ref, tol=3e-2):
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+def _quant_pool(key, nb, kvh, d):
+    """int8 pool [NB, KVH, BS, D] + scales [NB, KVH, 1, BS] (ops/paged.py)."""
+    from localai_tpu.ops.kvcache import quantize_tokens
+
+    dense = jax.random.normal(jax.random.PRNGKey(key), (nb, kvh, BS, d))
+    q, s = quantize_tokens(dense)            # [NB,KVH,BS,D] i8, [NB,KVH,BS]
+    return q, s.reshape(nb, kvh, 1, BS)
+
+
+# ------------------------------------------------- flash_attention.py
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS + [(8, 8, 128)])
+@pytest.mark.parametrize("S", [256, 512])   # 512 = the largest prefill chunk
+def test_flash_prefill(H, KVH, D, S):
     from localai_tpu.ops.attention import mha_prefill
     from localai_tpu.ops.pallas import flash_prefill
 
-    B, S = 2, 256
-    q, k, v = _bf16(0, (B, S, H, D)), _bf16(1, (B, S, KVH, D)), _bf16(2, (B, S, KVH, D))
+    B = 2
+    q, k, v = _bf16(0, (B, S, H, D)), _bf16(1, (B, S, KVH, D)), \
+        _bf16(2, (B, S, KVH, D))
     lengths = jnp.array([S, 100], jnp.int32)
     out = flash_prefill(q, k, v, lengths)
     ref = mha_prefill(q, k, v, lengths)
     for b in range(B):
         n = int(lengths[b])
-        np.testing.assert_allclose(np.asarray(out[b, :n], np.float32),
-                                   np.asarray(ref[b, :n], np.float32),
-                                   rtol=3e-2, atol=3e-2)
+        _close(out[b, :n], ref[b, :n])
 
 
-@pytest.mark.parametrize("H,KVH,D", [(8, 4, 64), (8, 8, 128), (32, 8, 128)])
-def test_ragged_decode_lowers_and_matches(H, KVH, D):
+@pytest.mark.parametrize("H,KVH,D", GEOMS + [(8, 8, 128)])
+def test_ragged_decode_dense(H, KVH, D):
     from localai_tpu.ops.attention import mha_decode
     from localai_tpu.ops.pallas import ragged_decode
 
@@ -46,30 +70,261 @@ def test_ragged_decode_lowers_and_matches(H, KVH, D):
     q = _bf16(3, (B, 1, H, D))
     kc, vc = _bf16(4, (B, KVH, T, D)), _bf16(5, (B, KVH, T, D))
     lengths = jnp.array([1, 100, 777, T], jnp.int32)
-    out = ragged_decode(q, kc, vc, lengths)
-    ref = mha_decode(q, kc, vc, lengths)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=3e-2, atol=3e-2)
+    _close(ragged_decode(q, kc, vc, lengths), mha_decode(q, kc, vc, lengths))
 
 
-def test_pallas_probe_reports_ok():
-    from localai_tpu.ops.pallas import pallas_works
+def _table(B, maxb):
+    """Distinct physical blocks per slot, shuffled so the table matters."""
+    perm = np.random.default_rng(0).permutation(B * maxb) + 1   # 0 = trash
+    return jnp.asarray(perm.reshape(B, maxb), jnp.int32)
 
-    assert pallas_works()
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+def test_ragged_decode_paged(H, KVH, D):
+    from localai_tpu.ops.attention import mha_decode
+    from localai_tpu.ops.paged import paged_view
+    from localai_tpu.ops.pallas import ragged_decode
+
+    B, maxb = 4, 4
+    table = _table(B, maxb)
+    nb = B * maxb + 1
+    q = _bf16(6, (B, 1, H, D))
+    kp, vp = _bf16(7, (nb, KVH, BS, D)), _bf16(8, (nb, KVH, BS, D))
+    lengths = jnp.array([1, 100, 300, maxb * BS], jnp.int32)
+    out = ragged_decode(q, kp, vp, lengths, table=table)
+    ref = mha_decode(q, paged_view(kp, table), paged_view(vp, table), lengths)
+    _close(out, ref)
 
 
-def test_model_decode_step_compiles_on_tpu():
-    """The engine's hot path — decode_step through the Pallas selector — must
-    compile and run on the chip (this is exactly where BENCH_r03 died)."""
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+def test_ragged_decode_q8_dense(H, KVH, D):
+    from localai_tpu.ops.attention import mha_decode
+    from localai_tpu.ops.kvcache import QuantKV, dequant, quantize_tokens
+    from localai_tpu.ops.pallas import ragged_decode_q8
+
+    B, T = 4, 1024
+    q = _bf16(9, (B, 1, H, D))
+    kq, ks = quantize_tokens(
+        jax.random.normal(jax.random.PRNGKey(10), (B, KVH, T, D)))
+    vq, vs = quantize_tokens(
+        jax.random.normal(jax.random.PRNGKey(11), (B, KVH, T, D)))
+    kc = QuantKV(kq, ks.reshape(B, KVH, T // 128, 128))
+    vc = QuantKV(vq, vs.reshape(B, KVH, T // 128, 128))
+    lengths = jnp.array([1, 100, 777, T], jnp.int32)
+    out = ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths)
+    _close(out, mha_decode(q, dequant(kc), dequant(vc), lengths))
+
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+def test_ragged_decode_q8_paged(H, KVH, D):
+    from localai_tpu.ops.attention import mha_decode
+    from localai_tpu.ops.kvcache import QuantKV, dequant
+    from localai_tpu.ops.paged import paged_view
+    from localai_tpu.ops.pallas import ragged_decode_q8
+
+    B, maxb = 4, 4
+    table = _table(B, maxb)
+    nb = B * maxb + 1
+    q = _bf16(12, (B, 1, H, D))
+    kq, ks = _quant_pool(13, nb, KVH, D)
+    vq, vs = _quant_pool(14, nb, KVH, D)
+    lengths = jnp.array([1, 100, 300, maxb * BS], jnp.int32)
+    out = ragged_decode_q8(q, kq, ks, vq, vs, lengths, table=table)
+    kv = paged_view(QuantKV(kq, ks), table)
+    vv = paged_view(QuantKV(vq, vs), table)
+    _close(out, mha_decode(q, dequant(kv), dequant(vv), lengths))
+
+
+# --------------------------------------------------- paged_scatter.py
+
+def _scatter_case(B=16, maxb=4):
+    table = _table(B, maxb)
+    # every in-block row offset class: 0, mid-tile, tile edges, last row
+    positions = jnp.asarray(
+        [0, 1, 7, 8, 15, 16, 31, 32, 33, 127, 128, 129, 255, 300, 383, 511],
+        jnp.int32)[:B]
+    active = jnp.asarray([True] * (B - 2) + [False, False])
+    return table, positions, active
+
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_paged_scatter_append(H, KVH, D, dtype):
+    from localai_tpu.ops.pallas import paged_scatter_append
+    from localai_tpu.ops.pallas.paged_scatter import _targets
+
+    B, maxb = 16, 4
+    table, positions, active = _scatter_case(B, maxb)
+    nb = B * maxb + 1
+    kp = _bf16(15, (nb, KVH, BS, D)).astype(dtype)
+    vp = _bf16(16, (nb, KVH, BS, D)).astype(dtype)
+    kn, vn = _bf16(17, (B, KVH, D)), _bf16(18, (B, KVH, D))
+    pb, off = _targets(positions, table, active)
+    want_k = np.array(kp, np.float32)
+    want_v = np.array(vp, np.float32)
+    for b in range(B):
+        want_k[int(pb[b]), :, int(off[b])] = np.asarray(
+            kn[b].astype(dtype), np.float32)
+        want_v[int(pb[b]), :, int(off[b])] = np.asarray(
+            vn[b].astype(dtype), np.float32)
+    ko, vo = jax.jit(paged_scatter_append)(kp, vp, kn, vn, positions, table,
+                                           active)
+    np.testing.assert_array_equal(np.asarray(ko, np.float32), want_k)
+    np.testing.assert_array_equal(np.asarray(vo, np.float32), want_v)
+
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+def test_paged_scatter_append_q8(H, KVH, D):
+    from localai_tpu.ops.kvcache import quantize_tokens
+    from localai_tpu.ops.pallas import paged_scatter_append_q8
+    from localai_tpu.ops.pallas.paged_scatter import _targets
+
+    B, maxb = 16, 4
+    table, positions, active = _scatter_case(B, maxb)
+    nb = B * maxb + 1
+    kq, ks = _quant_pool(19, nb, KVH, D)
+    vq, vs = _quant_pool(20, nb, KVH, D)
+    kn, vn = _bf16(21, (B, KVH, D)), _bf16(22, (B, KVH, D))
+    pb, off = _targets(positions, table, active)
+    want = [np.array(x) for x in (kq, ks, vq, vs)]
+    knq, kns = quantize_tokens(kn)
+    vnq, vns = quantize_tokens(vn)
+    for b in range(B):
+        p, o = int(pb[b]), int(off[b])
+        want[0][p, :, o] = np.asarray(knq[b])
+        want[1][p, :, 0, o] = np.asarray(kns[b])
+        want[2][p, :, o] = np.asarray(vnq[b])
+        want[3][p, :, 0, o] = np.asarray(vns[b])
+    got = jax.jit(paged_scatter_append_q8)(kq, ks, vq, vs, kn, vn, positions,
+                                           table, active)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), w)
+
+
+# ------------------------------------------------ ragged_attention.py
+
+def _ragged_case(H, KVH, D, maxb=4):
+    """Three sequences in one 48-row flat stream: a decode row (kv 300), a
+    decode row (kv 1) and a 20-token prefill chunk ending at kv 150."""
+    from localai_tpu.ops.pallas import QBLK
+
+    nseq, t = 4, 48
+    tables = _table(nseq, maxb)
+    block_seq = jnp.asarray([0, 1, 2, 2, 2, -1], jnp.int32)
+    assert block_seq.shape[0] == t // QBLK
+    qstart = jnp.asarray([0, 8, 16, 0], jnp.int32)
+    qlen = jnp.asarray([1, 1, 20, 0], jnp.int32)
+    kvlen = jnp.asarray([300, 1, 150, 0], jnp.int32)
+    q = _bf16(23, (t, H, D))
+    live = np.zeros((t,), bool)
+    live[0] = live[8] = True
+    live[16:36] = True
+    return q, tables, block_seq, qstart, qlen, kvlen, live
+
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS + [(8, 8, 128)])
+def test_ragged_paged_attention(H, KVH, D):
+    from localai_tpu.ops.pallas import (
+        ragged_attention_xla, ragged_paged_attention,
+    )
+
+    q, tables, bseq, qs, ql, kl, live = _ragged_case(H, KVH, D)
+    nb = tables.size + 1
+    kp, vp = _bf16(24, (nb, KVH, BS, D)), _bf16(25, (nb, KVH, BS, D))
+    out = ragged_paged_attention(q, kp, vp, bseq, qs, ql, kl, tables)
+    ref = ragged_attention_xla(q, kp, vp, bseq, qs, ql, kl, tables)
+    _close(out[live], ref[live])
+
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS + [(8, 8, 128)])
+def test_ragged_paged_attention_q8(H, KVH, D):
+    from localai_tpu.ops.pallas import (
+        ragged_attention_xla_q8, ragged_paged_attention_q8,
+    )
+
+    q, tables, bseq, qs, ql, kl, live = _ragged_case(H, KVH, D)
+    nb = tables.size + 1
+    kq, ks = _quant_pool(26, nb, KVH, D)
+    vq, vs = _quant_pool(27, nb, KVH, D)
+    out = ragged_paged_attention_q8(q, kq, ks, vq, vs, bseq, qs, ql, kl,
+                                    tables)
+    ref = ragged_attention_xla_q8(q, kq, ks, vq, vs, bseq, qs, ql, kl,
+                                  tables)
+    _close(out[live], ref[live])
+
+
+def _flat_targets(t, nb):
+    """(block, row) targets for t flat rows, shaped like a real pack: a
+    20-row run in one block that crosses native-tile boundaries (consecutive
+    grid steps revisiting one tile — what a prefill chunk does), then rows
+    scattered one per 32-row tile in pool order (a live tile or block is
+    never revisited once left — the kernel's contract, which position-ordered
+    sequences keep), then 4 padding rows aimed at the trash block."""
+    rng = np.random.default_rng(1)
+    tiles = np.sort(rng.permutation((nb - 2) * (BS // 32))[:t]) \
+        + 2 * (BS // 32)
+    pb, off = tiles // (BS // 32), tiles % (BS // 32) * 32 \
+        + rng.integers(0, 32, t)
+    pb[:20], off[:20] = 1, np.arange(27, 47)
+    pb[-4:] = 0
+    return jnp.asarray(pb, jnp.int32), jnp.asarray(off, jnp.int32)
+
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+def test_ragged_scatter_append(H, KVH, D):
+    from localai_tpu.ops.pallas import (
+        ragged_scatter_append, ragged_scatter_xla,
+    )
+
+    t, nb = 48, 17
+    pb, off = _flat_targets(t, nb)
+    kp, vp = _bf16(28, (nb, KVH, BS, D)), _bf16(29, (nb, KVH, BS, D))
+    kn, vn = _bf16(30, (t, KVH, D)), _bf16(31, (t, KVH, D))
+    wk, wv = ragged_scatter_xla(kp, vp, kn, vn, pb, off)
+    gk, gv = jax.jit(ragged_scatter_append)(kp, vp, kn, vn, pb, off)
+    # block 0 is trash: padding rows may land there in any order
+    np.testing.assert_array_equal(np.asarray(gk[1:], np.float32),
+                                  np.asarray(wk[1:], np.float32))
+    np.testing.assert_array_equal(np.asarray(gv[1:], np.float32),
+                                  np.asarray(wv[1:], np.float32))
+
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+def test_ragged_scatter_append_q8(H, KVH, D):
+    from localai_tpu.ops.pallas import (
+        ragged_scatter_append_q8, ragged_scatter_xla_q8,
+    )
+
+    t, nb = 48, 17
+    pb, off = _flat_targets(t, nb)
+    kq, ks = _quant_pool(32, nb, KVH, D)
+    vq, vs = _quant_pool(33, nb, KVH, D)
+    kn, vn = _bf16(34, (t, KVH, D)), _bf16(35, (t, KVH, D))
+    want = ragged_scatter_xla_q8(kq, ks, vq, vs, kn, vn, pb, off)
+    got = jax.jit(ragged_scatter_append_q8)(kq, ks, vq, vs, kn, vn, pb, off)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g[1:]), np.asarray(w[1:]))
+
+
+# ------------------------------------------------------------- engine
+
+def _tiny_cfg(H, KVH, D):
+    from localai_tpu.models.llama import LlamaConfig
+
+    return LlamaConfig(vocab_size=256, hidden_size=H * D // 4,
+                       intermediate_size=512, num_layers=2, num_heads=H,
+                       num_kv_heads=KVH, head_dim=D, max_position=512)
+
+
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+def test_model_prefill_and_decode_step(H, KVH, D):
+    """decode_step through the Pallas selector compiles and runs."""
     from localai_tpu.models.llama import (
-        LlamaConfig, decode_step, init_kv_cache, init_params, prefill,
+        decode_step, init_kv_cache, init_params, prefill,
     )
     from localai_tpu.ops.rope import rope_table
 
-    cfg = LlamaConfig(vocab_size=256, hidden_size=256, intermediate_size=512,
-                      num_layers=2, num_heads=4, num_kv_heads=2, head_dim=64,
-                      max_position=256)
+    cfg = _tiny_cfg(H, KVH, D)
     params = init_params(cfg, jax.random.PRNGKey(0))
     cos, sin = rope_table(cfg.rope, 256)
     kc, vc = init_kv_cache(cfg, 2, 256)
@@ -77,60 +332,68 @@ def test_model_decode_step_compiles_on_tpu():
     logits, kc, vc = prefill(params, cfg, tokens, jnp.array([4], jnp.int32),
                              cos, sin, kc, vc, jnp.array([0], jnp.int32))
     assert np.isfinite(np.asarray(logits)).all()
-    step_tokens = jnp.array([5, 0], jnp.int32)
-    step_lengths = jnp.array([4, 0], jnp.int32)
-    dlogits, _, _ = decode_step(params, cfg, step_tokens, step_lengths,
+    dlogits, _, _ = decode_step(params, cfg, jnp.array([5, 0], jnp.int32),
+                                jnp.array([4, 0], jnp.int32),
                                 cos, sin, kc, vc)
     assert np.isfinite(np.asarray(dlogits[0])).all()
 
 
-@pytest.mark.parametrize("H,KVH,D", [(8, 4, 64), (32, 8, 128)])
-def test_ragged_decode_q8_lowers_and_matches(H, KVH, D):
-    from localai_tpu.ops.attention import mha_decode
-    from localai_tpu.ops.kvcache import QuantKV, dequant, quantize_tokens
-    from localai_tpu.ops.pallas import ragged_decode_q8
-
-    B, T = 4, 1024
-    q = _bf16(6, (B, 1, H, D))
-    kd = jax.random.normal(jax.random.PRNGKey(7), (B, KVH, T, D))
-    vd = jax.random.normal(jax.random.PRNGKey(8), (B, KVH, T, D))
-    kq, ks = quantize_tokens(kd)
-    vq, vs = quantize_tokens(vd)
-    kc = QuantKV(kq, ks.reshape(B, KVH, T // 128, 128))
-    vc = QuantKV(vq, vs.reshape(B, KVH, T // 128, 128))
-    lengths = jnp.array([1, 100, 777, T], jnp.int32)
-    out = ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths)
-    ref = mha_decode(q, dequant(kc), dequant(vc), lengths)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=3e-2, atol=3e-2)
+ENGINES = {
+    "dense": dict(),
+    "dense-int8kv": dict(cache_type="int8"),
+    "paged": dict(kv_pages=24),
+    "paged-int8kv": dict(kv_pages=24, cache_type="int8"),
+    "ragged": dict(kv_pages=24, ragged_token_budget=64),
+    "ragged-int8kv": dict(kv_pages=24, ragged_token_budget=64,
+                          cache_type="int8"),
+}
 
 
-def test_decode_block_runs_on_tpu():
-    """The fused multi-step decode program (EngineConfig.decode_block) must
-    compile and run on the chip — it is the serving hot loop."""
+@pytest.mark.parametrize("H,KVH,D", GEOMS)
+@pytest.mark.parametrize("kind", sorted(ENGINES))
+def test_engine_runs_on_tpu(H, KVH, D, kind):
+    """The serving programs (admission, chunked prefill, the fused decode
+    while-loop with donated caches, ragged ticks) compile and run on the
+    chip for a few ticks, on the Pallas tier, with exact token counts."""
     from localai_tpu.engine import Engine, EngineConfig
     from localai_tpu.engine.engine import GenRequest, SamplingParams
-    from localai_tpu.models.llama import LlamaConfig, init_params
+    from localai_tpu.models.llama import init_params
 
-    cfg = LlamaConfig(vocab_size=256, hidden_size=256, intermediate_size=512,
-                      num_layers=2, num_heads=4, num_kv_heads=2, head_dim=64,
-                      max_position=256)
+    cfg = _tiny_cfg(H, KVH, D)
     params = init_params(cfg, jax.random.PRNGKey(0))
     eng = Engine(cfg, params, None, EngineConfig(
-        max_slots=2, max_context=128, prefill_buckets=(16,),
-        prefill_chunk=16, decode_block=8))
+        max_slots=4, max_context=384, prefill_buckets=(16, 64),
+        prefill_chunk=64, decode_block=8, **ENGINES[kind]))
+    tiers = eng.kernel_tiers()
+    assert tiers["prefill_attention"] == tiers["decode_attention"] == "pallas"
+    if "kv_pages" in ENGINES[kind]:
+        assert tiers["decode_kv_write"] == "pallas"
+    if kind.startswith("ragged"):
+        assert tiers["ragged_attention"] == "pallas"
+    eng.warmup()
     eng.start()
     try:
-        _, q = eng.submit(GenRequest(
-            prompt_ids=[1, 2, 3], max_tokens=24, ignore_eos=True,
-            params=SamplingParams(temperature=0.0, seed=1)))
-        n = 0
-        while True:
-            o = q.get(timeout=120)
-            n += 1
-            if o.finished:
-                break
-        assert n == 24
+        # a short prompt, one past the 64-token chunk, and a second wave
+        # that admits while the first decodes
+        prompts = [[1, 2, 3], list(range(1, 151)), [7] * 20, [9] * 70]
+        want = [24, 12, 40, 8]
+        qs = [eng.submit(GenRequest(
+            prompt_ids=p, max_tokens=n, ignore_eos=True,
+            params=SamplingParams(temperature=0.0 if i % 2 else 0.8,
+                                  seed=i + 1)))[1]
+            for i, (p, n) in enumerate(zip(prompts, want))]
+        for q, n in zip(qs, want):
+            ids = []
+            while True:
+                o = q.get(timeout=300)
+                if o.token_id >= 0:
+                    ids.append(o.token_id)
+                if o.finished:
+                    break
+            assert o.finish_reason == "length", (kind, eng.last_error)
+            assert len(ids) == n
+            assert all(0 <= t < cfg.vocab_size for t in ids)
+        if kind.startswith("ragged"):
+            assert eng.metrics["ragged_dispatches"] > 0
     finally:
         eng.stop()

@@ -28,6 +28,9 @@ def timeit(fn, *args, n=50, warmup=5):
 
 
 def main():
+    from localai_tpu.system.device import configure_compile_cache
+
+    configure_compile_cache()
     from localai_tpu.ops.pallas import ragged_decode_q8
     from localai_tpu.ops.attention import mha_decode
     from localai_tpu.ops.kvcache import QuantKV, dequant

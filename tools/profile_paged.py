@@ -34,6 +34,9 @@ def timeit(fn, *args, n=20, warmup=3):
 
 
 def main():
+    from localai_tpu.system.device import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--slots", default="16,32")
     ap.add_argument("--ctx", type=int, default=1024)

@@ -36,6 +36,9 @@ def timeit(fn, *args, n=50, warmup=5):
 
 
 def main():
+    from localai_tpu.system.device import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--cpu", action="store_true")
     ap.add_argument("--vocab", type=int, default=128256)

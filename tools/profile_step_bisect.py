@@ -1,8 +1,8 @@
 """Bisect the decode step on the real chip: where do the non-floor ms go?
 
-decode_step at B=32/ctx1024 int8 measures ~50 ms against a ~12 ms weight
-stream floor (TPU_VALIDATION.md). This times each constituent in isolation
-and a cumulative knockout chain:
+The weight-stream floor of decode_step at int8 8B is ~10 ms (8 GB at a v5e's
+819 GB/s); the step itself is not measured on current code. This times each
+constituent in isolation and a cumulative knockout chain:
 
   - full decode_step
   - layer stack with attention + cache-write knocked out (pure matmul chain)
@@ -38,6 +38,9 @@ def timeit(fn, *args, n=20, warmup=3):
 
 
 def main():
+    from localai_tpu.system.device import configure_compile_cache
+
+    configure_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--slots", default="16,32")
     ap.add_argument("--ctx", type=int, default=1024)
@@ -97,7 +100,7 @@ def main():
 
         # attention+rope, no cache write (reads the existing cache)
         positions = lengths[:, None]
-        _, attn_decode = M._attn_impls(cfg, kv_quant=True)
+        _, attn_decode = M._attn_impls()
 
         def no_write(p, t, l):
             x = p["embed"].astype(cfg.jdtype)[t][:, None, :]

@@ -46,6 +46,10 @@ EDITS = [
     # terminal "preempted" chunk the full spill-drain token
     ("Reply", "resume_json", 10,
      descriptor_pb2.FieldDescriptorProto.TYPE_STRING),
+    # the device a backend holds, as JAX reports it to that process
+    # (system/device.py) — the control plane never imports JAX (ISSUE 21)
+    ("StatusResponse", "device_json", 3,
+     descriptor_pb2.FieldDescriptorProto.TYPE_STRING),
 ]
 
 # (method name, input message, output message, server_streaming) — added to

@@ -25,7 +25,7 @@ os.environ["LOCALAI_TRACE"] = "1"
 os.environ["LOCALAI_PROFILE"] = "1"
 os.environ["LOCALAI_ALLOW_SYNTHETIC"] = "1"
 os.environ["LOCALAI_NO_PREWARM"] = "1"
-os.environ.setdefault("LOCALAI_JAX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 
 def main() -> int:
