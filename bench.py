@@ -333,7 +333,7 @@ def bench_serve(args, size: str, on_cpu: bool):
         except Exception:
             pass
         if getattr(args, "trace", False):
-            try:   # pull spans + stage profile before the backend dies
+            try:   # pull the spans before the backend dies
                 args.trace_payload = handle.client.trace()
             except Exception as e:
                 note(f"trace fetch failed: {e}")
@@ -468,7 +468,6 @@ def bench_engine(args, size: str, on_cpu: bool, kv_pages: int | None = None,
 
         args.trace_payload = {
             "spans": telemetry.chrome_events(),
-            "profile": eng._prof.report() if eng._prof is not None else {},
             "pid": os.getpid(),
         }
     import shutil
@@ -1523,10 +1522,8 @@ def build_parser() -> argparse.ArgumentParser:
                         "device. --mode tp runs both legs and defaults N "
                         "to the largest axis the geometry divides into")
     p.add_argument("--trace", action="store_true",
-                   help="telemetry run: record spans + fenced stage timings "
-                        "(LOCALAI_TRACE/LOCALAI_PROFILE), write a "
-                        "Chrome-trace dump and add a per-stage breakdown "
-                        "to the result JSON")
+                   help="telemetry run: record spans (LOCALAI_TRACE) and "
+                        "write a Chrome-trace dump")
     p.add_argument("--trace-out", default="bench_trace.json",
                    help="Chrome-trace output path for --trace")
     return p
@@ -1553,20 +1550,6 @@ def emit_result(result: dict, args) -> int:
         result.setdefault(k, v)
     payload = getattr(args, "trace_payload", None)
     if payload is not None:
-        profile = payload.get("profile") or {}
-        stages = profile.get("stages") or {}
-        if stages:
-            result["stages"] = {
-                name: dict(
-                    share=round(st["share"], 4),
-                    total_ms=round(st["total_ms"], 2),
-                    p50_ms=round(st["p50_ms"], 3),
-                    count=st["count"],
-                    tok_s=round(st["tok_s"], 1),
-                    **({"mfu": round(st["mfu"], 4)}
-                       if st.get("mfu") else {}))
-                for name, st in stages.items()}
-            result["stage_coverage"] = round(profile.get("coverage", 0.0), 4)
         try:
             from localai_tpu import telemetry
 
@@ -1592,7 +1575,6 @@ def main(argv=None):
         # env, not in-process flags: serve mode's backend subprocess must
         # inherit them (manager spawn copies os.environ)
         os.environ["LOCALAI_TRACE"] = "1"
-        os.environ["LOCALAI_PROFILE"] = "1"
 
     # the chip or an error, for this process and every backend it spawns:
     # with JAX_PLATFORMS unset JAX itself falls back to the CPU when the TPU

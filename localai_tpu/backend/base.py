@@ -30,8 +30,8 @@ class BackendServicer:
 
     def GetTrace(self, request, context):
         """Telemetry export (every role): this process's recorded spans as
-        Chrome-trace events in Reply.message JSON. Roles with a device-step
-        profiler (llm) override to add the stage breakdown."""
+        Chrome-trace events in Reply.message JSON. The llm role overrides it
+        to add its SLO, scheduler and flight-recorder snapshots."""
         import json
         import os
 

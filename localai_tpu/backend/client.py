@@ -20,6 +20,9 @@ from localai_tpu.core import resilience
 # gRPC metadata key carrying the HTTP request id into the backend process
 # (server/http.py middleware → here → backend/llm.py → GenRequest.trace_id)
 REQUEST_ID_KEY = "x-localai-request-id"
+# gRPC metadata key that turns a GetTrace call into a device trace of the
+# backend process for that many seconds (GET /debug/xprof)
+XPROF_SECONDS_KEY = "x-localai-xprof-seconds"
 
 
 def _trace_md():
@@ -169,17 +172,31 @@ class BackendClient:
     def rerank(self, timeout: float = 600.0, **kw) -> "pb.RerankResult":
         return self._calls["Rerank"](pb.RerankRequest(**kw), timeout=timeout)
 
+    # the two halves of /backend/monitor: each has a span on this side
+    # (rpc.*) and one in the backend's handler (grpc.*); what the first
+    # holds beyond the second is the wait for a handler thread
+
     def status(self, timeout: float = 10.0) -> "pb.StatusResponse":
-        return self._calls["Status"](pb.HealthMessage(), timeout=timeout)
+        with telemetry.span("rpc.Status", cat="rpc", addr=self.addr):
+            return self._calls["Status"](pb.HealthMessage(), timeout=timeout)
 
     def metrics(self, timeout: float = 10.0) -> dict:
-        r = self._calls["GetMetrics"](pb.MetricsRequest(), timeout=timeout)
-        return dict(r.metrics)
+        with telemetry.span("rpc.GetMetrics", cat="rpc", addr=self.addr):
+            r = self._calls["GetMetrics"](pb.MetricsRequest(),
+                                          timeout=timeout)
+            return dict(r.metrics)
 
-    def trace(self, timeout: float = 30.0) -> dict:
-        """Backend telemetry snapshot: {"spans": [chrome events],
-        "profile": {stage breakdown}, "pid": N} (GetTrace RPC)."""
-        r = self._calls["GetTrace"](pb.MetricsRequest(), timeout=timeout)
+    def trace(self, timeout: float = 30.0,
+              xprof_seconds: float | None = None) -> dict:
+        """Backend telemetry snapshot: {"spans": [chrome events], "slo",
+        "sched", "flightrec", "pid": N} (GetTrace RPC). With
+        `xprof_seconds` the backend instead profiles itself for that long
+        and answers {"xprof": {"dir", "xplane", ...} or {"error"}}; give a
+        `timeout` that covers the seconds and the profiler's stop."""
+        md = (None if xprof_seconds is None
+              else ((XPROF_SECONDS_KEY, repr(float(xprof_seconds))),))
+        r = self._calls["GetTrace"](pb.MetricsRequest(), timeout=timeout,
+                                    metadata=md)
         return json.loads(r.message.decode() or "{}")
 
     def tts(self, timeout: float = 600.0, **kw) -> "pb.Result":

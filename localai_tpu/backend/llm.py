@@ -18,7 +18,7 @@ import grpc
 from localai_tpu import telemetry
 from localai_tpu.backend import pb
 from localai_tpu.backend.base import BackendServicer
-from localai_tpu.backend.client import REQUEST_ID_KEY
+from localai_tpu.backend.client import REQUEST_ID_KEY, XPROF_SECONDS_KEY
 from localai_tpu.ops.sampling import SamplingParams
 from localai_tpu.testing import faults
 
@@ -34,16 +34,21 @@ def _inject_faults(context):
                       "injected DEADLINE_EXCEEDED (LOCALAI_FAULT)")
 
 
-def _request_id(context) -> str:
-    """The HTTP layer's request id, if the client attached one (metadata
-    propagation — backend/client.py _trace_md)."""
+def _metadata(context, key: str) -> str:
+    """One value of the call's gRPC metadata ("" when it is not there)."""
     try:
         for k, v in context.invocation_metadata():
-            if k == REQUEST_ID_KEY:
+            if k == key:
                 return v
     except Exception:
         pass
     return ""
+
+
+def _request_id(context) -> str:
+    """The HTTP layer's request id, if the client attached one (metadata
+    propagation — backend/client.py _trace_md)."""
+    return _metadata(context, REQUEST_ID_KEY)
 
 
 class LLMServicer(BackendServicer):
@@ -60,6 +65,8 @@ class LLMServicer(BackendServicer):
         self._state = pb.StatusResponse.UNINITIALIZED
         self._load_seconds: dict = {}
         self._load_lock = threading.Lock()
+        # counts this process's XLA compiles from before the first load
+        telemetry.compile_counter()
         if preloaded is not None:
             self.engine, self.cfg, self.tok, self.model_name = preloaded
             self._state = pb.StatusResponse.READY
@@ -682,12 +689,14 @@ class LLMServicer(BackendServicer):
         return resp
 
     def Status(self, request, context):
-        rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
-        return pb.StatusResponse(
-            state=self._state,
-            memory=pb.MemoryUsageData(total=rss, breakdown={"rss_peak": rss}),
-            device_json=json.dumps(self._device_report()),
-        )
+        with telemetry.span("grpc.Status", cat="grpc"):
+            rss = resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+            return pb.StatusResponse(
+                state=self._state,
+                memory=pb.MemoryUsageData(total=rss,
+                                          breakdown={"rss_peak": rss}),
+                device_json=json.dumps(self._device_report()),
+            )
 
     def _device_report(self) -> dict:
         """The device this process holds and what runs on it — {} until a
@@ -707,11 +716,16 @@ class LLMServicer(BackendServicer):
         return rep
 
     def GetMetrics(self, request, context):
+        with telemetry.span("grpc.GetMetrics", cat="grpc"):
+            return self._get_metrics()
+
+    def _get_metrics(self):
+        """Host-side counters only: nothing here touches the device."""
         m = dict(self.engine.metrics) if self.engine else {}
-        if self.engine is not None and self.engine._prof is not None:
-            # flattened stage profile (prof_<stage>_{count,total_ms,p50_ms,
-            # tok_s}) rides the existing str→double metrics surface
-            m.update(self.engine._prof.flat())
+        # XLA compiles of this process (xla_compiles_total,
+        # xla_compile_ms_total, xla_compiles__<jit name>): there from the
+        # first scrape, at whatever the load has compiled so far
+        m.update(telemetry.compile_counter().flat())
         slo = telemetry.maybe_slo()
         if slo is not None:
             # SLO histograms (hist_<metric>__<path>__{bN,count,sum} +
@@ -726,12 +740,17 @@ class LLMServicer(BackendServicer):
         return pb.MetricsResponse(metrics={k: float(v) for k, v in m.items()})
 
     def GetTrace(self, request, context):
+        seconds = _metadata(context, XPROF_SECONDS_KEY)
+        if seconds:
+            # GET /debug/xprof (backend/client.py trace(xprof_seconds=)): a
+            # device trace of this process, taken on this handler thread;
+            # the reply carries the directory or the error and nothing else
+            return pb.Reply(message=json.dumps(
+                {"xprof": telemetry.device_trace(float(seconds)),
+                 "model": self.model_name}).encode())
         slo = telemetry.maybe_slo()
         payload = {
             "spans": telemetry.chrome_events(),
-            "profile": (self.engine._prof.report()
-                        if self.engine is not None
-                        and self.engine._prof is not None else {}),
             # SLO percentile snapshot + flight-recorder dump (ISSUE 11):
             # the /debug/slo and /debug/flightrec lanes across the process
             # boundary, reusing the JSON-in-Reply transport

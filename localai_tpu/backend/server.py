@@ -83,8 +83,16 @@ def _make_base():
     return BackendServicer()
 
 
+# A stream holds a handler thread for its whole life. The HTTP process sends
+# at most min(32, cpu count + 4) streams at once (one pump thread each, on its
+# loop's default executor), so 40 handlers leave Status, GetMetrics and
+# GetTrace a thread whatever the load: with 16, /backend/monitor waited
+# seconds behind 16 open streams for a handler that then ran for 0.3 ms.
+HANDLER_THREADS = 40
+
+
 def serve(addr: str = "127.0.0.1:50051", backend: str = "llm",
-          max_workers: int = 16, servicer=None):
+          max_workers: int = HANDLER_THREADS, servicer=None):
     """Start a backend server; returns (grpc.Server, servicer, bound_port).
     `servicer` overrides role construction (multi-host worker preloads one)."""
     if servicer is None:

@@ -86,9 +86,6 @@ def _add_run(sub):
     p.add_argument("--trace", action="store_true",
                    help="record request/engine spans (LOCALAI_TRACE=1); "
                         "export via /debug/trace or `util trace`")
-    p.add_argument("--profile", action="store_true",
-                   help="fenced device-step stage timing (LOCALAI_PROFILE=1;"
-                        " measurement mode — serializes the decode pipeline)")
     p.add_argument("--log-level", default="info")
     return p
 
@@ -266,8 +263,8 @@ def _add_util(sub):
                                       "flightrec", "sched"],
                    help="hf-info: checkpoint geometry + params; "
                             "fits: HBM fit estimate; "
-                            "trace: pull a Chrome-trace + stage profile "
-                            "from a running server's /debug endpoints; "
+                            "trace: pull a Chrome-trace + the engine "
+                            "thread's phase times from a running server; "
                             "flightrec: dump the server's flight recorder "
                             "(recent request timelines + SLO percentiles); "
                             "sched: scheduler X-ray (reason-code counters, "
@@ -290,8 +287,9 @@ def _add_util(sub):
 
 def cli_util_trace(args) -> int:
     """`local-ai util trace <addr>` — fetch /debug/trace into a Chrome-trace
-    file (open at chrome://tracing) and print the /debug/profile stage
-    breakdown. The server must run with --trace (and --profile for stages)."""
+    file (open at chrome://tracing) and print where each model's engine
+    thread spent its time (the always-on phase counters of
+    /backend/monitor). Spans need a server run with --trace."""
     import json as _json
     import urllib.request
 
@@ -311,25 +309,17 @@ def cli_util_trace(args) -> int:
         _json.dump(trace, fh)
     n = len(trace.get("traceEvents", []))
     print(f"{out}: {n} events")
-    profile = fetch("/debug/profile")
-    for model, prof in (profile.get("models") or {}).items():
-        stages = (prof or {}).get("stages") or {}
-        if not stages:
+    for model, st in (fetch("/backend/monitor") or {}).items():
+        phases = {k: v for k, v in (st.get("metrics") or {}).items()
+                  if k.startswith(("engine_host_ms__", "engine_wait_ms__"))}
+        total = sum(phases.values())
+        if not total:
             continue
-        print(f"\n{model}: coverage {prof.get('coverage', 0):.0%} of "
-              f"{prof.get('wall_ms', 0):.0f} ms busy window")
-        width = max(len(s) for s in stages)
-        for name, st in sorted(stages.items(),
-                               key=lambda kv: -kv[1]["total_ms"]):
-            mfu = f" mfu {st['mfu']:.1%}" if st.get("mfu") else ""
-            print(f"  {name:<{width}}  {st['share']:>5.1%}  "
-                  f"{st['total_ms']:>9.1f} ms  x{st['count']:<6d} "
-                  f"p50 {st['p50_ms']:.2f} ms  "
-                  f"{st['tok_s']:.0f} tok/s{mfu}")
-    if not any((p or {}).get("stages")
-               for p in (profile.get("models") or {}).values()):
-        print("no stage profile (run the server with --profile / "
-              "LOCALAI_PROFILE=1)")
+        print(f"\n{model}: engine thread, {total / 1e3:.1f} s")
+        for key, ms in sorted(phases.items(), key=lambda kv: -kv[1]):
+            kind, _, phase = key[len("engine_"):].partition("_ms__")
+            print(f"  {phase:<9} {kind:<5} {ms / total:>6.1%}  "
+                  f"{ms:>11.1f} ms")
     return 0
 
 

@@ -309,7 +309,9 @@ class _Slot:
                                      # max_tokens however dispatches overlap
     # SLO phase timeline (ISSUE 11) — maintained only when the registry is
     # enabled (engine._slo is not None); all zeros/None otherwise
-    prefill_done_t: float | None = None  # last prompt chunk committed
+    join_t: float | None = None      # enqueue of the first decode dispatch
+                                     # that carried this slot (admit_to_join
+                                     # ends and join_to_first starts here)
     last_token_t: float | None = None    # host arrival of the latest token
                                          # batch (TPOT reference point)
     obs_tokens: int = 0              # generated count at last_token_t — the
@@ -578,6 +580,15 @@ class Engine:
             "decode_dispatches": 0,
             "decode_steps_dispatched": 0,
             "admit_dispatches": 0,
+            # per-dispatch counters, credited TOGETHER where a dispatch is
+            # consumed (_credit_consumed): dispatches whose results reached
+            # the host and the steps the device reports it ran in them.
+            # requests_admitted is credited at slot assignment. Their
+            # ratios say how many steps a dispatch ran and how many
+            # requests it let in.
+            "decode_dispatches_consumed": 0,
+            "decode_steps_consumed": 0,
+            "requests_admitted": 0,
             # cumulative ms the engine thread spent BLOCKED waiting for a
             # dispatch's results to land on the host (the async-fetch wait,
             # not the detok/stream fan-out) — per token this is the number
@@ -637,16 +648,18 @@ class Engine:
                 kv_host_blocks=0, kv_host_bytes=0, kv_host_bytes_peak=0,
                 kv_host_hits=0, kv_host_spills=0, kv_host_evictions=0)
 
-        # telemetry (localai_tpu/telemetry): both gates resolve to None/False
-        # here so the per-dispatch cost of a disabled build is one attribute
-        # load + branch (see _obs) — the hot path stays fence-free
+        # telemetry (localai_tpu/telemetry): the ring tracer resolves to
+        # None here when LOCALAI_TRACE is off; the phase clock is always on
+        # (engine_host_ms__* / engine_wait_ms__* in self.metrics, a
+        # TraceAnnotation per phase) and costs a clock read per phase switch,
+        # a handful per tick — nothing per token or per step
         from localai_tpu import telemetry
 
-        self._prof = telemetry.engine_profiler(cfg, mesh=self.mesh)
         self._tracer = telemetry.maybe_tracer()
+        self._phases = telemetry.PhaseClock(self.metrics, self._tracer)
         # serving SLO layer (ISSUE 11): streaming histograms + the flight
-        # recorder, same one-attribute-load-and-branch contract as _obs when
-        # disabled (LOCALAI_METRICS=0 → both None)
+        # recorder, one attribute load and a branch when disabled
+        # (LOCALAI_METRICS=0 → both None)
         self._slo = telemetry.maybe_slo()
         self._flightrec = (telemetry.flightrec()
                            if self._slo is not None else None)
@@ -1398,27 +1411,6 @@ class Engine:
             return jax.transfer_guard(self._xfer_guard)
         return contextlib.nullcontext()
 
-    def _obs(self, stage: str, t0: float, tokens: int = 0, fence=None,
-             **args):
-        """Record one device-dispatch observation (telemetry subsystem).
-
-        With LOCALAI_PROFILE the profiler fences (`block_until_ready`) before
-        reading the clock, so the sample is the stage's real host+device cost
-        — opt-in because the fence defeats the decode pipeline. With
-        LOCALAI_TRACE a span lands in the ring buffer (un-fenced samples
-        measure enqueue time only and say so via the `fenced` arg). Disabled
-        (the default) this is two attribute loads and a branch."""
-        prof, tr = self._prof, self._tracer
-        if prof is None and tr is None:
-            return
-        dur = None
-        if prof is not None:
-            dur = prof.record(stage, t0, tokens=tokens, fence=fence)
-        if tr is not None:
-            tr.add_complete("engine." + stage, t0, dur_s=dur, cat="engine",
-                            args=dict(args, tokens=tokens,
-                                      fenced=prof is not None))
-
     def _sched_pack(self, variant: str, fn, fargs, fkw, **comp):
         """Tick-ledger dispatch record (ISSUE 13): the pack composition of
         one dispatch under its compiled-program variant name, plus a one-
@@ -1455,7 +1447,6 @@ class Engine:
     def _dev_admit_many(self, ids, lens, slots, rows, counts_rows,
                         inject=None):
         self.metrics["admit_dispatches"] += 1
-        t0 = time.perf_counter()
         self._bcast("admit_many", ids=ids, lens=lens, slots=slots,
                     rows={k: np.asarray(v) for k, v in rows.items()},
                     counts_rows=counts_rows, inject=self._inj_msg(inject))
@@ -1469,8 +1460,6 @@ class Engine:
                 {k: jnp.asarray(v) for k, v in rows.items()},
                 None if counts_rows is None else jnp.asarray(counts_rows),
                 self._tab(), self._inj(inject), self._kvt())
-        self._obs("admit", t0, tokens=int(np.sum(lens)),
-                  fence=self._lengths, requests=len(slots))
 
     @staticmethod
     def _inj(inject):
@@ -1497,7 +1486,6 @@ class Engine:
         return (msg["extra"], msg["mask"])
 
     def _dev_extend_mid(self, buf, pos, idx, inject=None):
-        t0 = time.perf_counter()
         self._bcast("extend_mid", buf=buf, pos=pos, idx=idx,
                     inject=self._inj_msg(inject))
         with activate_mesh(self.mesh):
@@ -1505,12 +1493,9 @@ class Engine:
                 self.params, self._cos, self._sin, self._kc, self._vc,
                 jnp.asarray(buf), jnp.int32(pos), jnp.int32(idx), self._tab(),
                 self._inj(inject), self._kvt())
-        self._obs("prefill", t0, tokens=int(buf.shape[1]), fence=self._kc,
-                  slot=int(idx), final=False)
 
     def _dev_extend_final(self, buf, pos, nvalid, idx, row, counts_row,
                           inject=None):
-        t0 = time.perf_counter()
         self._bcast("extend_final", buf=buf, pos=pos, nvalid=nvalid, idx=idx,
                     row={k: np.asarray(v) for k, v in row.items()},
                     counts_row=counts_row, inject=self._inj_msg(inject))
@@ -1524,13 +1509,10 @@ class Engine:
                 {k: jnp.asarray(v) for k, v in row.items()},
                 None if counts_row is None else jnp.asarray(counts_row),
                 self._tab(), self._inj(inject), self._kvt())
-        self._obs("prefill", t0, tokens=int(nvalid), fence=self._lengths,
-                  slot=int(idx), final=True)
 
     def _dev_decode(self, active, mask_host=None, fast_width=None):
         self.metrics["decode_dispatches"] += 1
         self.metrics["decode_steps_dispatched"] += 1
-        t0 = time.perf_counter()
         self._bcast("decode", active=active,
                     mask=None if mask_host is None else mask_host,
                     fast_width=fast_width)
@@ -1557,16 +1539,12 @@ class Engine:
                              rows_used=B, pad_rows=B - n_act, packed=n_act)
             (tokens, logprobs, self._kc, self._vc, self._sampler,
              self._last_logits, self._lengths) = fn(*fargs, **fkw)
-        self._obs("decode", t0, tokens=n_act, fence=tokens,
-                  fast_width=fast_width or 0,
-                  grammar=mask_host is not None)
         return _AsyncFetch((tokens, logprobs))
 
     def _dev_decode_block(self, active, steps: int, fast_width=None,
                           mask_host=None):
         self.metrics["decode_dispatches"] += 1
         self.metrics["decode_steps_dispatched"] += steps
-        t0 = time.perf_counter()
         self._bcast("decode_block", active=active, steps=steps,
                     fast_width=fast_width,
                     mask=None if mask_host is None else mask_host)
@@ -1592,9 +1570,6 @@ class Engine:
                              packed=steps * n_act)
             (tokens, logprobs, self._kc, self._vc, self._sampler,
              self._last_logits, self._lengths) = fn(*fargs, **fkw)
-        self._obs("decode_block", t0, tokens=steps * int(np.sum(active)),
-                  fence=tokens, steps=steps, fast_width=fast_width or 0,
-                  grammar=mask_host is not None)
         return _AsyncFetch((tokens, logprobs))
 
     def _dev_decode_loop(self, active, remaining, check_eos, fast_width=None,
@@ -1612,7 +1587,6 @@ class Engine:
         back with the async fetch — the dispatch-step metric is credited at
         consume time, when the early-exit count is known."""
         self.metrics["decode_dispatches"] += 1
-        t0 = time.perf_counter()
         self._bcast("decode_loop", active=active, remaining=remaining,
                     check_eos=check_eos, fast_width=fast_width,
                     gstate=gstate)
@@ -1637,13 +1611,6 @@ class Engine:
             (toks, lps, n_out, steps, self._kc, self._vc, self._sampler,
              self._last_logits, self._lengths) = self._decode_loop_fn(
                 *fargs, **fkw)
-        # tokens here is the RESERVED upper bound (actual count rides the
-        # fetch); the consume-side "sample" stage records the exact number
-        self._obs("decode_loop", t0,
-                  tokens=int(np.minimum(np.maximum(remaining, 0),
-                                        self.ec.decode_loop).sum()),
-                  fence=toks, fast_width=fast_width or 0,
-                  grammar=gstate is not None)
         return _AsyncFetch((toks, lps, n_out, steps))
 
     def _dev_ragged(self, pack):
@@ -1669,7 +1636,6 @@ class Engine:
         self.metrics["budget_utilization"] = (
             self.metrics["ragged_tokens_packed"]
             / max(self.metrics["ragged_dispatches"] * self._ragged_rows, 1))
-        t0 = time.perf_counter()
         self._bcast("ragged", **dict(
             pack, inject=self._inj_msg(pack.get("inject"))))
         with activate_mesh(self.mesh), self._decode_guard():
@@ -1702,8 +1668,6 @@ class Engine:
                 packed=int(pack["packed"]))
             (tokens, logprobs, self._kc, self._vc, self._sampler,
              self._last_logits, self._lengths) = self._ragged_fn(*fargs)
-        self._obs("ragged", t0, tokens=int(pack["packed"]), fence=tokens,
-                  grammar=pack.get("mask") is not None)
         return _AsyncFetch((tokens, logprobs))
 
     def _dev_ragged_loop(self, pack, remaining, check_eos, prefill_pending,
@@ -1730,7 +1694,6 @@ class Engine:
         self.metrics["budget_utilization"] = (
             self.metrics["ragged_tokens_packed"]
             / max(self.metrics["ragged_dispatches"] * self._ragged_rows, 1))
-        t0 = time.perf_counter()
         self._bcast("ragged_loop", remaining=remaining, check_eos=check_eos,
                     prefill_pending=bool(prefill_pending), gstate=gstate,
                     **pack)
@@ -1770,8 +1733,6 @@ class Engine:
             (toks, lps, n_out, steps, code, self._kc, self._vc,
              self._sampler, self._last_logits,
              self._lengths) = self._ragged_loop_fn(*fargs, **fkw)
-        self._obs("ragged_loop", t0, tokens=int(pack["packed"]), fence=toks,
-                  grammar=gstate is not None)
         return _AsyncFetch((toks, lps, n_out, steps, code))
 
     def _dev_rloop_decode(self, active, remaining, check_eos,
@@ -1782,7 +1743,6 @@ class Engine:
         slot returns control to the host so the freed slot admits
         immediately instead of waiting out the remaining steps."""
         self.metrics["decode_dispatches"] += 1
-        t0 = time.perf_counter()
         self._bcast("rloop_decode", active=active, remaining=remaining,
                     check_eos=check_eos, fast_width=fast_width,
                     gstate=gstate)
@@ -1809,11 +1769,6 @@ class Engine:
             (toks, lps, n_out, steps, code, self._kc, self._vc,
              self._sampler, self._last_logits,
              self._lengths) = self._ragged_loop_fn(*fargs, **fkw)
-        self._obs("rloop_decode", t0,
-                  tokens=int(np.minimum(np.maximum(remaining, 0),
-                                        self.ec.ragged_loop_steps).sum()),
-                  fence=toks, fast_width=fast_width or 0,
-                  grammar=gstate is not None)
         return _AsyncFetch((toks, lps, n_out, steps, code))
 
     def _dev_spec_ragged(self, pack):
@@ -1838,7 +1793,6 @@ class Engine:
         self.metrics["budget_utilization"] = (
             self.metrics["ragged_tokens_packed"]
             / max(self.metrics["ragged_dispatches"] * self._ragged_rows, 1))
-        t0 = time.perf_counter()
         self._bcast("spec_ragged", **dict(
             pack, inject=self._inj_msg(pack.get("inject"))))
         with activate_mesh(self.mesh), self._decode_guard():
@@ -1882,21 +1836,17 @@ class Engine:
              self._kc, self._vc, self._kcd, self._vcd, self._sampler,
              self._last_logits, self._lengths,
              n_extra) = self._spec_ragged_fn(*fargs, **fkw)
-        self._obs("spec_ragged", t0, tokens=int(pack["packed"]),
-                  fence=tokens_out, grammar=pack.get("gstate") is not None)
         return _AsyncFetch((tokens_out, n_out, logprobs_out, n_extra))
 
     def _dev_demote(self, pb: int, ci: int):
         """Copy hot physical block `pb` into cold-pool index `ci` (int8,
         sub-channel scales). Enqueued AFTER any in-flight decode dispatch on
         the same stream, so the copy reads the block's final hot content."""
-        t0 = time.perf_counter()
         self._bcast("demote", pb=pb, ci=ci)
         with activate_mesh(self.mesh):
             self._ck, self._cv = self._demote_fn(
                 self._kc, self._vc, self._ck, self._cv,
                 jnp.int32(pb), jnp.int32(ci))
-        self._obs("demote", t0, tokens=128, block=int(pb))
 
     # ------------------------------------------------- host KV tier (ISSUE 17)
 
@@ -1918,14 +1868,12 @@ class Engine:
         # copy can no longer free the chain head under its in-flight tail
         if h is None or not self._kvhost.begin_spill(h, group=gkey):
             return
-        t0 = time.perf_counter()
         with activate_mesh(self.mesh):
             arrs = self._spill_fn(self._kc, self._vc, jnp.int32(pb))
         self._host_pending.append((h, gkey, _AsyncFetch(arrs)))
         self.metrics["kv_host_spills"] += 1
         if self._sched is not None:
             self._sched.reason("kv_host_spill", block=int(pb))
-        self._obs("host_spill", t0, tokens=128, block=int(pb))
 
     def _host_drain(self):
         """Land every in-flight spill in the HostKVPool. The copies were
@@ -1966,13 +1914,11 @@ class Engine:
         admission path — the decode transfer guard wraps decode dispatches
         only, and the uploads overlap the uncovered suffix's prefill
         chunks (they are enqueued first on the same stream)."""
-        t0 = time.perf_counter()
         with activate_mesh(self.mesh):
             self._kc, self._vc = self._readmit_fn(
                 self._kc, self._vc,
                 jnp.asarray(blk.kq), jnp.asarray(blk.ks),
                 jnp.asarray(blk.vq), jnp.asarray(blk.vs), jnp.int32(pb))
-        self._obs("host_readmit", t0, tokens=128, block=int(pb))
 
     def _host_extend(self, slot: int, req: GenRequest, shared, shtok: int):
         """Extend a device prefix-cache match with host-tier blocks.
@@ -2047,7 +1993,6 @@ class Engine:
         """Sampler-row install for a ragged final prefill chunk (the dense
         path installs inside _extend_final; the ragged program defers it
         here so its own signature stays row-structure-free)."""
-        t0 = time.perf_counter()
         self._bcast("install", idx=idx,
                     row={k: np.asarray(v) for k, v in row.items()},
                     counts_row=counts_row)
@@ -2056,10 +2001,8 @@ class Engine:
                 self._sampler, jnp.int32(idx),
                 {k: jnp.asarray(v) for k, v in row.items()},
                 None if counts_row is None else jnp.asarray(counts_row))
-        self._obs("install", t0, slot=int(idx))
 
     def _dev_shift(self, idx):
-        t0 = time.perf_counter()
         self._bcast("shift", idx=idx)
         with activate_mesh(self.mesh):
             if self._paged:
@@ -2081,7 +2024,6 @@ class Engine:
             else:
                 self._kc, self._vc, self._lengths = self._shift_fn(
                     self._kc, self._vc, self._lengths, jnp.int32(idx))
-        self._obs("shift", t0, fence=self._lengths, slot=int(idx))
 
     def _dev_draft_ingest(self, buf, pos, idx):
         self._bcast("draft_ingest", buf=buf, pos=pos, idx=idx)
@@ -2115,7 +2057,6 @@ class Engine:
         self.metrics["decode_dispatches"] += 1
         # one spec dispatch fuses gamma draft steps + the verify pass
         self.metrics["decode_steps_dispatched"] += self.ec.gamma + 1
-        t0 = time.perf_counter()
         self._bcast("spec", active=active)
         with activate_mesh(self.mesh):
             fargs = (self.params, self._draft[1], self._cos, self._sin,
@@ -2135,9 +2076,6 @@ class Engine:
             (tokens_out, n_out, logprobs_out, self._next_tokens,
              self._kc, self._vc, self._kcd, self._vcd, self._sampler,
              self._lengths, n_extra) = self._spec_fn(*fargs)
-        self._obs("spec_decode", t0,
-                  tokens=(self.ec.gamma + 1) * int(np.sum(active)),
-                  fence=tokens_out)
         return _AsyncFetch((tokens_out, n_out, logprobs_out, n_extra))
 
     def follow(self, channel) -> None:
@@ -2603,18 +2541,10 @@ class Engine:
             prefilled=not chunked, row=row, counts_row=counts_row,
             prefill_pos=lcp, disk_prefix=disk_prefix, fast_w=fast_w,
         )
-        slo = self._slo
-        if slo is not None:
-            if req.queued_t:
-                slo.observe("queue_wait", "all",
-                            slot_obj.start_time - req.queued_t)
-            if not chunked:
-                # single-shot prefill: committed within this admission (the
-                # dispatch itself is async — host-side prefill time is the
-                # admission work, real chunked time lands in _prefill_drain)
-                slot_obj.prefill_done_t = time.monotonic()
-                slo.observe("prefill", "all",
-                            slot_obj.prefill_done_t - slot_obj.start_time)
+        self.metrics["requests_admitted"] += 1
+        if self._slo is not None and req.queued_t:
+            self._slo.observe("queue_wait", "all",
+                              slot_obj.start_time - req.queued_t)
         if self._tracer is not None:
             # one span per request, admission → release; request_id ties it
             # to the HTTP/gRPC spans of the same request, trace_parent nests
@@ -2740,11 +2670,6 @@ class Engine:
                 if final:
                     slot.prefilled = True
                     self._prefillq.remove(idx)
-                    if self._slo is not None:
-                        slot.prefill_done_t = time.monotonic()
-                        self._slo.observe(
-                            "prefill", "all",
-                            slot.prefill_done_t - slot.start_time)
                     if self._draft is not None:
                         tok, lp = self._dev_spec_admit_tail(idx)
                         self._emit(idx, slot, tok, lp, time.monotonic(),
@@ -2952,6 +2877,7 @@ class Engine:
         for i, _ in live:
             res[i] = int(min(G, remaining[i]))
             self._slots[i].inflight += res[i]
+        self._mark_join(live)
         self._inflight_steps = G
         if self._sched is not None:
             # the fast path is recorded too, so the dispatch-category codes
@@ -3010,11 +2936,46 @@ class Engine:
         for i, _ in entries:
             res[i] = steps
             self._slots[i].inflight += steps
+        self._mark_join(entries)
         if steps > 1:
             fetch = self._dev_decode_block(active, steps, fast, gmask)
         else:
             fetch = self._dev_decode(active, gmask, fast)
         return ("block", fetch, entries, gmask, res)
+
+    def _await(self, fetch):
+        """Block for a dispatch's results: phase `device` for the wait (the
+        cumulative host_sync_wait_ms is the same milliseconds), `emit` from
+        there on — detok, stop scan and stream fan-out follow every fetch."""
+        m, ph = self.metrics, self._phases
+        waited = m["engine_wait_ms__device"]
+        ph.switch("device")
+        out = fetch.wait()
+        ph.switch("emit")
+        m["host_sync_wait_ms"] += m["engine_wait_ms__device"] - waited
+        return out
+
+    def _credit_consumed(self, steps: int):
+        """One dispatch's results are on the host: credit it and the steps
+        the device ran in it, together."""
+        self.metrics["decode_dispatches_consumed"] += 1
+        self.metrics["decode_steps_consumed"] += steps
+
+    def _mark_join(self, entries):
+        """Stamp the slots this decode dispatch is the first to carry, just
+        before its enqueue: admit_to_join ends and join_to_first starts here.
+        One clock read, and only in a dispatch that some slot joins."""
+        now = None
+        for i, _ in entries:
+            s = self._slots[i]
+            if s.join_t is None:
+                if now is None:
+                    now = time.monotonic()
+                s.join_t = now
+
+    def _admit_phase(self):
+        with self._phases.within("admit"):
+            self._prefill_tick()
 
     def _release_reservations(self, entries, res):
         """Return a consumed dispatch's per-slot token reservations (see
@@ -3024,20 +2985,6 @@ class Engine:
             s = self._slots[i]
             if s is not None and s.request_id == rid:
                 s.inflight = max(0, s.inflight - res.get(i, 0))
-
-    def _dispatch_gauges(self):
-        """Refresh the profiler's dispatch-fusing gauges (prof_* GetMetrics
-        keys → scoreboard/Prometheus). Profiling-mode only — the disabled
-        hot path stays a None-check."""
-        if self._prof is None:
-            return
-        m = self.metrics
-        d = max(m["decode_dispatches"], 1)
-        self._prof.set_gauges(
-            decode_dispatches_count=m["decode_dispatches"],
-            steps_per_dispatch=m["decode_steps_dispatched"] / d,
-            host_sync_wait_ms_per_token=(
-                m["host_sync_wait_ms"] / max(m["tokens_generated"], 1)))
 
     # device exit codes of the fused ragged loop (models/llama.py
     # RLOOP_EXIT_*) → telemetry.sched pack reason codes. host_arbitration is
@@ -3068,9 +3015,7 @@ class Engine:
         terminate a slot mid-buffer, and the rest of its tokens are dropped
         by the request-id check exactly as on the block path."""
         tag, fetch, entries, res = pend
-        t0 = time.perf_counter()
-        out = fetch.wait()
-        self.metrics["host_sync_wait_ms"] += (time.perf_counter() - t0) * 1e3
+        out = self._await(fetch)
         if tag == "rloop":
             # fused ragged loop (pack-free variant): the fetch carries the
             # device's exit code — map it onto the pack reason taxonomy and
@@ -3081,6 +3026,7 @@ class Engine:
             tokens, logprobs, n_out, steps = out
         steps = int(steps)
         self.metrics["decode_steps_dispatched"] += steps
+        self._credit_consumed(steps)
         self._release_reservations(entries, res)
         now = time.monotonic()
         if self._slo is not None:
@@ -3088,7 +3034,6 @@ class Engine:
                 s = self._slots[i]
                 if s is not None and s.request_id == rid:
                     s.dispatches += 1
-        emitted = 0
         for g in range(steps):
             for i, rid in entries:
                 if g >= int(n_out[i]):
@@ -3099,9 +3044,6 @@ class Engine:
                 self._emit(i, slot, int(tokens[g, i]),
                            float(logprobs[g, i]), now,
                            path="rloop" if tag == "rloop" else "loop")
-                emitted += 1
-        self._obs("sample", t0, tokens=emitted, steps=steps, rollbacks=0)
-        self._dispatch_gauges()
 
     def _consume(self, pend):
         """Block on a dispatched step's results and run the host-side token
@@ -3114,14 +3056,13 @@ class Engine:
             self._consume_loop(pend)
             return
         _, fetch, entries, gmask, res = pend
-        t0 = time.perf_counter()
-        tokens, logprobs = fetch.wait()
-        self.metrics["host_sync_wait_ms"] += (time.perf_counter() - t0) * 1e3
+        tokens, logprobs = self._await(fetch)
         self._release_reservations(entries, res)
         now = time.monotonic()
         if tokens.ndim == 1:
             tokens, logprobs = tokens[None], logprobs[None]
         steps = tokens.shape[0]
+        self._credit_consumed(steps)
         if self._slo is not None:
             for i, rid in entries:
                 s = self._slots[i]
@@ -3153,13 +3094,6 @@ class Engine:
             slot = self._slots[i]
             if slot is not None:
                 self._repair(i, slot)
-        # "sample" = the host side of sampling: async-fetch completion (the
-        # copy started at dispatch — on the pipelined path it has usually
-        # already landed) plus token commit (grammar advance, detok, stop
-        # scan, stream fan-out)
-        self._obs("sample", t0, tokens=steps * len(entries),
-                  steps=steps, rollbacks=len(rolled))
-        self._dispatch_gauges()
 
     def _repair(self, idx: int, slot: _Slot):
         """Roll a grammar slot back to its last PDA-accepted token after a
@@ -3196,14 +3130,13 @@ class Engine:
         if active.any():
             entries = [(int(i), self._slots[i].request_id)
                        for i in np.where(active)[0]]
+            self._mark_join(entries)
             pend = self._dev_spec_decode(active)
-            self._prefill_tick()   # admission overlaps the device step
-            t0 = time.perf_counter()
-            tokens_out, n_out, logprobs_out, n_extra = pend.wait()
-            self.metrics["host_sync_wait_ms"] += (
-                time.perf_counter() - t0) * 1e3
+            self._admit_phase()    # admission overlaps the device step
+            tokens_out, n_out, logprobs_out, n_extra = self._await(pend)
             now = time.monotonic()
             G = self.ec.gamma
+            self._credit_consumed(G + 1)
             for i, rid in entries:
                 slot = self._slots[i]
                 if slot is None or slot.request_id != rid:
@@ -3219,7 +3152,7 @@ class Engine:
                     self._emit(i, slot, int(tokens_out[i, j]),
                                float(logprobs_out[i, j]), now, path="spec")
         else:
-            self._prefill_tick()
+            self._admit_phase()
         return (any(s is not None for s in self._slots)
                 or not self._queue.empty() or self._deferred is not None)
 
@@ -3230,7 +3163,7 @@ class Engine:
         prefill chunks (multimodal inject rows included). This is the path
         a mixed tenant soup rides: spec, grammar, mm and plain traffic all
         share the one program (engine/spec.py build_spec_ragged)."""
-        self._prefill_tick()   # ragged admissions are host-only bookkeeping,
+        self._admit_phase()    # ragged admissions are host-only bookkeeping,
         # so new arrivals can pack into THIS tick's stream
         active = self._active_mask()
         if active.any() or self._ragged_chunkable():
@@ -3332,6 +3265,7 @@ class Engine:
                             if self._grammar_slots > 0 else None),
                     inject=(None if inj_extra is None
                             else (inj_extra, inj_mask)))
+        self._mark_join(entries)
         fetch = self._dev_spec_ragged(pack)
         # chunk bookkeeping overlaps the device step; the draft ingests each
         # chunk's token ids through its own (tiny) prefill program
@@ -3346,9 +3280,6 @@ class Engine:
                 s.prefilled = True
                 self._prefillq.remove(idx)
                 if self._slo is not None:
-                    s.prefill_done_t = time.monotonic()
-                    self._slo.observe("prefill", "all",
-                                      s.prefill_done_t - s.start_time)
                     s.dispatches += 1
                     s.path = "ragged"
                 tok, lp = self._dev_spec_admit_tail(idx)
@@ -3356,11 +3287,9 @@ class Engine:
             elif self._slo is not None:
                 s.dispatches += 1
                 s.path = "ragged"
-        t0 = time.perf_counter()
-        tokens_out, n_out, logprobs_out, n_extra = fetch.wait()
-        self.metrics["host_sync_wait_ms"] += (time.perf_counter() - t0) * 1e3
+        tokens_out, n_out, logprobs_out, n_extra = self._await(fetch)
         now = time.monotonic()
-        emitted = 0
+        self._credit_consumed(G + 1)
         for i, rid in entries:
             slot = self._slots[i]
             if slot is None or slot.request_id != rid:
@@ -3375,9 +3304,6 @@ class Engine:
                     break  # finished mid-window (EOS/length/stop)
                 self._emit(i, slot, int(tokens_out[i, j]),
                            float(logprobs_out[i, j]), now, path="spec")
-                emitted += 1
-        self._obs("sample", t0, tokens=emitted, steps=G + 1, rollbacks=0)
-        self._dispatch_gauges()
 
     # ------------------------------------------------------ ragged scheduling
 
@@ -3410,10 +3336,11 @@ class Engine:
         if self._pending is not None:
             self._consume(self._pending)
             self._pending = None
-        self._prefill_tick()   # ragged admissions land chunked (host-only)
+        self._admit_phase()    # ragged admissions land chunked (host-only)
         chunkable = self._ragged_chunkable()
         if not chunkable:
             return False       # only mm prompts queued: dense tick serves
+        self._phases.switch("dispatch")
         self._ragged_tick(chunkable)
         return True
 
@@ -3534,6 +3461,7 @@ class Engine:
                               for i, _ in entries))
         use_loop = (self._ragged_loop_fn is not None and bool(entries)
                     and inj_extra is None and not arbitration)
+        self._mark_join(entries)
         if use_loop:
             remaining = np.zeros((B,), np.int32)
             check_eos = np.zeros((B,), bool)
@@ -3573,21 +3501,16 @@ class Engine:
                 self._dev_install(idx, s.row, s.counts_row)
                 s.prefilled = True
                 self._prefillq.remove(idx)
-                if self._slo is not None:
-                    s.prefill_done_t = time.monotonic()
-                    self._slo.observe("prefill", "all",
-                                      s.prefill_done_t - s.start_time)
-        t0 = time.perf_counter()
         steps = 1
         if use_loop:
-            tokens_out, logprobs, n_out, steps, code = fetch.wait()
+            tokens_out, logprobs, n_out, steps, code = self._await(fetch)
             steps = int(steps)
             self.metrics["decode_steps_dispatched"] += steps
             self._rloop_exit(int(code))
             self._release_reservations(entries, res)
         else:
-            tokens_out, logprobs = fetch.wait()
-        self.metrics["host_sync_wait_ms"] += (time.perf_counter() - t0) * 1e3
+            tokens_out, logprobs = self._await(fetch)
+        self._credit_consumed(steps)
         now = time.monotonic()
         if self._slo is not None:
             # dispatch attribution: every slot packed into this ragged tick
@@ -3601,7 +3524,6 @@ class Engine:
                 if s is not None:
                     s.dispatches += 1
                     s.path = "ragged"
-        emitted = 0
         if use_loop:
             # drain the [steps, B] device token ring in device order — the
             # host re-derives every finish decision in _emit exactly as on
@@ -3615,7 +3537,6 @@ class Engine:
                         continue
                     self._emit(i, s, int(tokens_out[g, i]),
                                float(logprobs[g, i]), now, path="ragged")
-                    emitted += 1
         else:
             for i, rid in entries:
                 s = self._slots[i]
@@ -3623,9 +3544,6 @@ class Engine:
                     continue
                 self._emit(i, s, int(tokens_out[i]), float(logprobs[i]),
                            now, path="ragged")
-                emitted += 1
-        self._obs("sample", t0, tokens=emitted, steps=steps, rollbacks=0)
-        self._dispatch_gauges()
 
     def _kv_tick(self):
         """Advance the hot→cold→evicted lifecycle for windowed slots.
@@ -3723,10 +3641,20 @@ class Engine:
             # every live slot, manifest the queue, keep serving — the
             # caller owns what happens to the process next
             self._spill_drain()
+        self._tick_n += 1
+        # the engine thread's phases (telemetry.PhaseClock): a tick opens in
+        # `dispatch`, _step_inner marks the rest, and everything between two
+        # ticks — the idle wait of _loop, a caller driving step() — is `idle`
+        self._phases.switch("dispatch", tick=self._tick_n)
+        try:
+            return self._step_tick()
+        finally:
+            self._phases.switch("idle")
+
+    def _step_tick(self) -> bool:
         sched = self._sched
         if sched is None and self._flightrec is None:
             return self._step_inner()
-        self._tick_n += 1
         self._set_tick(self._tick_n)
         if sched is None:
             # flight recorder without the ledger: keep the coarse summary
@@ -3760,13 +3688,15 @@ class Engine:
             # covering verify windows + prefill chunks (mm rows included)
             return (self._step_spec_ragged() if self._ragged
                     else self._step_spec())
-        if self._tiered:
-            self._kv_tick()
-        if self._host_pending:
-            # land last tick's spills (their D2H copies have arrived by
-            # now) so the pool's occupancy metrics stay current even on
-            # admission-free ticks
-            self._host_drain()
+        if self._tiered or self._host_pending:
+            with self._phases.within("kv"):
+                if self._tiered:
+                    self._kv_tick()
+                if self._host_pending:
+                    # land last tick's spills (their D2H copies have arrived
+                    # by now) so the pool's occupancy metrics stay current
+                    # even on admission-free ticks
+                    self._host_drain()
         if self._ragged_now() and self._step_ragged():
             # mixed tick: decode + prefill ran as one ragged dispatch,
             # consumed synchronously (no pending survives a ragged tick)
@@ -3777,8 +3707,9 @@ class Engine:
         if sync and self._pending is not None:
             self._consume(self._pending)
             self._pending = None
+            self._phases.switch("dispatch")
         cur = self._dispatch()
-        self._prefill_tick()
+        self._admit_phase()
         if cur is None:
             if self._pending is not None:
                 self._consume(self._pending)
@@ -3885,9 +3816,20 @@ class Engine:
         if slo is not None:
             slot.path = path
             if slot.last_token_t is None:
-                # TTFT from ARRIVAL (queued_t), matching ttft_ms_last above
+                # TTFT from ARRIVAL (queued_t), matching ttft_ms_last above.
+                # Its stages share their boundary timestamps, so queue_wait
+                # + admit_to_join + join_to_first is this ttft exactly. A
+                # slot no decode dispatch carried yet got its first token
+                # at admission (spec mode): it joined when it was admitted.
+                if slot.join_t is None:
+                    slot.join_t = slot.start_time
                 slo.observe("ttft", path,
                             now - (slot.req.queued_t or slot.start_time))
+                slo.observe("admit_to_join", "all",
+                            slot.join_t - slot.start_time)
+                slo.observe("join_to_first", "all", now - slot.join_t)
+                if self._tracer is not None:
+                    self._stage_spans(slot, now)
                 slot.last_token_t = now
                 slot.obs_tokens = slot.generated
             elif now > slot.last_token_t:
@@ -3954,6 +3896,17 @@ class Engine:
             self._release_slot(idx, slot)
         return True
 
+    def _stage_spans(self, slot: _Slot, now: float):
+        """Ring spans of a request's TTFT stages, under its request id
+        (time.monotonic and perf_counter are one clock on Linux)."""
+        args = {"request_id": slot.req.trace_id or f"rid-{slot.request_id}"}
+        edges = (("queue_wait", slot.req.queued_t or slot.start_time),
+                 ("admit_to_join", slot.start_time),
+                 ("join_to_first", slot.join_t), ("", now))
+        for (name, t0), (_, t1) in zip(edges, edges[1:]):
+            self._tracer.add_complete("engine.stage." + name, t0, t1 - t0,
+                                      cat="engine", args=args)
+
     def _timeline(self, slot: _Slot, reason: str, now: float) -> dict:
         """The request's phase timeline (ms, arrival-relative) — the final
         StepOutput's `timings` payload and the flight-recorder record."""
@@ -3967,8 +3920,12 @@ class Engine:
             "dispatches": slot.dispatches,
             "kv_policy": slot.req.kv_policy or self.ec.kv_policy or "full",
             "queue_wait_ms": (slot.start_time - qt) * 1e3,
-            "prefill_ms": ((slot.prefill_done_t - slot.start_time) * 1e3
-                           if slot.prefill_done_t is not None else None),
+            "admit_to_join_ms": ((slot.join_t - slot.start_time) * 1e3
+                                 if slot.join_t is not None else None),
+            "join_to_first_ms": (
+                (slot.first_token_time - slot.join_t) * 1e3
+                if slot.join_t is not None
+                and slot.first_token_time is not None else None),
             "ttft_ms": ((slot.first_token_time - qt) * 1e3
                         if slot.first_token_time is not None else None),
             "e2e_ms": (now - qt) * 1e3,
@@ -4569,7 +4526,7 @@ class Engine:
         call this off the measured path (bench: after the windows; server:
         first /debug/sched or GetTrace). Results are cached on the engine
         and mirrored into the tick ledger for GetMetrics `sched_roofline_*`
-        keys and the profiler's cost-backed per-stage MFU."""
+        keys."""
         if self._rooflines is not None and not force:
             return self._rooflines
         from localai_tpu import telemetry
@@ -4600,22 +4557,6 @@ class Engine:
         self._rooflines = out
         if self._sched is not None:
             self._sched.rooflines = out
-        if self._prof is not None and out:
-            # fold per-variant costs onto the profiler's stage names (the
-            # first matching variant stands for the stage — stages share
-            # one program modulo static knobs)
-            stage_of = (("spec_ragged", "spec_ragged"),
-                        ("decode_block", "decode_block"),
-                        ("loop", "decode_loop"), ("ragged", "ragged"),
-                        ("decode", "decode"), ("spec", "spec_decode"))
-            costs: dict[str, dict] = {}
-            for name, e in out.items():
-                for prefix, stage in stage_of:
-                    if name.startswith(prefix) and stage not in costs:
-                        costs[stage] = {"flops": e["cost_flops"],
-                                        "bytes": e["cost_bytes"]}
-                        break
-            self._prof.set_costs(costs)
         return out
 
     def sched_snapshot(self, ticks: int = 64,

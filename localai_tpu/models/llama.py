@@ -255,6 +255,15 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+# jax.named_scope below names the model's parts in the XLA ops' metadata
+# (`tf_op` in a device trace), so a trace viewer and tools/trace_gaps.py group
+# `fusion.256` and its kin by part. Trace-time only: the compilation cache's
+# key leaves metadata out, so a scope around XLA ops changes no key. A Pallas
+# kernel is the exception: its serialized body carries the scope it was traced
+# under (and the source lines of its call stack), and the body is in the key.
+# So no scope encloses a kernel call; the kernels go by their own names
+# (ragged_decode_q8, flash_prefill, paged_scatter_append).
+@jax.named_scope("cache_update")
 def _cache_write(kc, vc, k, v, rows, positions, table=None, unique=True,
                  redirect=None, kvt=None):
     """Scatter window K/V [B, S, KVH, D] into head-major caches [B', KVH, T, D]
@@ -325,6 +334,7 @@ def _cache_write(kc, vc, k, v, rows, positions, table=None, unique=True,
 
 # ---------------------------------------------------------------- forward
 
+@jax.named_scope("attention")
 def _qkv(x, lp, cfg: LlamaConfig, spec=None):
     """QKV projections. `spec` (optional) is the head-parallel output
     constraint (P(batch_ax, seq_ax, 'model')) threaded into qmatmul so TP
@@ -344,6 +354,7 @@ def _qkv(x, lp, cfg: LlamaConfig, spec=None):
     return q, k, v
 
 
+@jax.named_scope("lm_head")
 def _lm_head(x32, params):
     """Vocabulary projection in f32 (tied embeddings or separate, possibly
     int8-quantized, lm_head)."""
@@ -375,11 +386,13 @@ def _mlp(x, lp, cfg=None, spec_prefix=None):
     if spec_prefix is not None:
         up_spec = P(*spec_prefix, "model")
         down_spec = P(*spec_prefix, None)
-    return qmatmul(jax.nn.silu(qmatmul(x, lp["w_gate"], up_spec))
-                   * qmatmul(x, lp["w_up"], up_spec),
-                   lp["w_down"], down_spec)
+    with jax.named_scope("mlp"):
+        return qmatmul(jax.nn.silu(qmatmul(x, lp["w_gate"], up_spec))
+                       * qmatmul(x, lp["w_up"], up_spec),
+                       lp["w_down"], down_spec)
 
 
+@jax.named_scope("experts")
 def _moe_mlp(x, lp, k: int):
     """Mixtral top-k routed experts (reference: the MoE GGUFs llama.cpp
     serves within ggml — SURVEY §2.4 expert-parallel row; HF semantics:
@@ -395,19 +408,22 @@ def _moe_mlp(x, lp, k: int):
     def dq(p):
         return dequantize(p, x.dtype) if is_quantized(p) else p
 
-    gate = lp["moe_gate"].astype(jnp.float32)
-    logits = x.astype(jnp.float32) @ gate                      # [B, S, E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_w, top_i = jax.lax.top_k(probs, k)
-    top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-    E = gate.shape[-1]
-    combine = jnp.einsum("bske,bsk->bse",
-                         jax.nn.one_hot(top_i, E, dtype=jnp.float32), top_w)
-    w1, w2, w3 = dq(lp["moe_w1"]), dq(lp["moe_w2"]), dq(lp["moe_w3"])
-    h1 = jnp.einsum("bsh,ehi->bsei", x, w1)
-    h3 = jnp.einsum("bsh,ehi->bsei", x, w3)
-    y = jnp.einsum("bsei,eih->bseh", jax.nn.silu(h1) * h3, w2)
-    return jnp.einsum("bseh,bse->bsh", y, combine.astype(x.dtype))
+    with jax.named_scope("router"):
+        gate = lp["moe_gate"].astype(jnp.float32)
+        logits = x.astype(jnp.float32) @ gate                  # [B, S, E]
+        probs = jax.nn.softmax(logits, axis=-1)
+        top_w, top_i = jax.lax.top_k(probs, k)
+        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+        E = gate.shape[-1]
+        combine = jnp.einsum(
+            "bske,bsk->bse",
+            jax.nn.one_hot(top_i, E, dtype=jnp.float32), top_w)
+    with jax.named_scope("expert_einsums"):
+        w1, w2, w3 = dq(lp["moe_w1"]), dq(lp["moe_w2"]), dq(lp["moe_w3"])
+        h1 = jnp.einsum("bsh,ehi->bsei", x, w1)
+        h3 = jnp.einsum("bsh,ehi->bsei", x, w3)
+        y = jnp.einsum("bsei,eih->bseh", jax.nn.silu(h1) * h3, w2)
+        return jnp.einsum("bseh,bse->bsh", y, combine.astype(x.dtype))
 
 
 # Activation sharding hints: hard constraints when a mesh is active (raises on
@@ -675,8 +691,9 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
         k = apply_rope(k, cos, sin, positions)
         q = _shard_act(q, P("data", sax, "model", None))
         attn = attn_prefill(q, k, v, lengths, sliding_window=cfg.sliding_window)
-        x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
-                        spec=P("data", sax, None))
+        with jax.named_scope("attention"):
+            x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
+                            spec=P("data", sax, None))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, lp, cfg, spec_prefix=("data", sax))
         x = _shard_act(x, P("data", sax, None))
@@ -793,8 +810,9 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
         attn = attn_decode(q, kc, vc, lengths + 1,
                            sliding_window=cfg.sliding_window, table=table,
                            kvt=kvt, ck=ck, cv=cv)
-        x = x + qmatmul(attn.reshape(b, 1, -1), lp["wo"],
-                        spec=P("data", None, None))
+        with jax.named_scope("attention"):
+            x = x + qmatmul(attn.reshape(b, 1, -1), lp["wo"],
+                            spec=P("data", None, None))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, lp, cfg, spec_prefix=("data", None))
         return x, (kc, vc)
@@ -963,8 +981,9 @@ def ragged_forward(params, cfg: LlamaConfig, tokens, cos, sin,
         # counts it), so prefill chunks attend to themselves paged
         kc, vc = write(kc, vc, k[0], v[0])
         attn = attend(q[0], kc, vc)
-        x = x + qmatmul(attn.reshape(1, t, -1), lp["wo"],
-                        spec=P(None, None, None))
+        with jax.named_scope("attention"):
+            x = x + qmatmul(attn.reshape(1, t, -1), lp["wo"],
+                            spec=P(None, None, None))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, lp, cfg, spec_prefix=(None, None))
         return x, (kc, vc)
@@ -1322,28 +1341,30 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
             unique=(table is None or full_window or redirect is not None)
             and red_ok,
             redirect=redirect, kvt=kvt)
-        if kvt is not None:
-            kr, vr, kv_pos, kv_ok = _tiered_kv(
-                kc, vc, table[rows], kvt["sb"][rows], kvt["rw"][rows],
-                start + s,
-                ctab=kvt["cold_tab"][rows] if cold else None, ck=ck, cv=cv)
-            attn = mha_extend_tiered(
-                q, kr, vr, positions, kv_pos, kv_ok,
-                kvt["sinks"][rows], kvt["window"][rows],
-                drop_window=not cold)
-        else:
-            if table is not None:
-                from localai_tpu.ops.paged import paged_view
-
-                kr = paged_view(kc, table[rows])
-                vr = paged_view(vc, table[rows])
+        with jax.named_scope("attention"):
+            if kvt is not None:
+                kr, vr, kv_pos, kv_ok = _tiered_kv(
+                    kc, vc, table[rows], kvt["sb"][rows], kvt["rw"][rows],
+                    start + s,
+                    ctab=kvt["cold_tab"][rows] if cold else None,
+                    ck=ck, cv=cv)
+                attn = mha_extend_tiered(
+                    q, kr, vr, positions, kv_pos, kv_ok,
+                    kvt["sinks"][rows], kvt["window"][rows],
+                    drop_window=not cold)
             else:
-                kr = kc if slot_map is None else kc[rows]
-                vr = vc if slot_map is None else vc[rows]
-            attn = mha_extend(q, dequant(kr), dequant(vr), positions,
-                              sliding_window=cfg.sliding_window)
-        x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
-                        spec=P("data", None, None))
+                if table is not None:
+                    from localai_tpu.ops.paged import paged_view
+
+                    kr = paged_view(kc, table[rows])
+                    vr = paged_view(vc, table[rows])
+                else:
+                    kr = kc if slot_map is None else kc[rows]
+                    vr = vc if slot_map is None else vc[rows]
+                attn = mha_extend(q, dequant(kr), dequant(vr), positions,
+                                  sliding_window=cfg.sliding_window)
+            x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
+                            spec=P("data", None, None))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, lp, cfg, spec_prefix=("data", None))
         return x, (kc, vc)

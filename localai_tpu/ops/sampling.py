@@ -215,6 +215,7 @@ def sampling_probs(logits, state: SamplerState, mask_bits=None):
     ].set(p_sorted)
 
 
+@jax.named_scope("sampling")   # names the ops in a device trace; no key moves
 def sample(logits, state: SamplerState, mask_bits=None, topk_width=None):
     """One sampling step. logits: [B, V] (any float dtype).
 

@@ -14,7 +14,9 @@ event loop.
 from __future__ import annotations
 
 import asyncio
+import concurrent.futures
 import contextlib
+import contextvars
 import json
 import os
 import threading
@@ -41,23 +43,16 @@ try:
                          ["path", "status"])
     _API_LATENCY = Histogram("localai_api_latency_seconds", "API latency",
                              ["path"])
-    # engine-stage series (telemetry subsystem): refreshed from each loaded
-    # backend's GetMetrics prof_* keys at scrape time (LOCALAI_PROFILE
-    # runs). These are cumulative, so they are COUNTERS (ISSUE 11 satellite:
-    # they were Gauges despite the _total suffix); prometheus_client strips
-    # and re-appends the suffix, so the exposed series names are unchanged.
-    # Scrape-side .set() semantics are recovered by inc-ing the delta
-    # against the last scraped value (_counter_sync).
-    _STAGE_SECONDS = Counter(
-        "localai_engine_stage_seconds_total",
-        "Cumulative fenced time per engine stage", ["model", "stage"])
-    _STAGE_DISPATCHES = Counter(
-        "localai_engine_stage_dispatches_total",
-        "Cumulative dispatch count per engine stage", ["model", "stage"])
-    # tokens/s is a last-value rate — legitimately a Gauge
-    _STAGE_TOK_S = Gauge(
-        "localai_engine_stage_tokens_per_second",
-        "Tokens/s through each engine stage", ["model", "stage"])
+    # the engine thread's phases (telemetry.PhaseClock) and the backend's
+    # XLA compiles, refreshed from GetMetrics engine_host_ms__* /
+    # engine_wait_ms__* / xla_compiles_total at scrape time
+    _ENGINE_PHASE = Counter(
+        "localai_engine_phase_seconds_total",
+        "Engine-thread time by phase (kind: host work or wait)",
+        ["model", "kind", "phase"])
+    _XLA_COMPILES = Counter(
+        "localai_xla_compiles_total",
+        "XLA backend compiles of the model's backend process", ["model"])
     # load shedding (ISSUE 4): every 429/503 the admission layer or the
     # drain path produces is counted here so shedding is observable
     _SHED = Counter("localai_shed_total",
@@ -221,6 +216,10 @@ class _AdmissionGate:
         self.depth = max(0, int(depth))
         self.sem = asyncio.Semaphore(self.limit)
         self.waiting = 0
+        # the wait at this gate, one observation per request (0 included):
+        # the first stage of a request's TTFT, merged into the model's
+        # metrics as hist_gate_wait__all__* (/backend/monitor, /metrics)
+        self.wait_hist = telemetry.Hist()
 
 
 class API:
@@ -272,10 +271,11 @@ class API:
         r.add_post("/tts", self._speech)
         r.add_post("/vad", self._vad)
         r.add_post("/sound-generation", self._sound_generation)
-        # telemetry debug surface (ISSUE 2): merged Chrome trace + per-model
-        # stage profile across the HTTP process and every backend subprocess
+        # telemetry debug surface: the ring spans of this process and every
+        # backend merged into one Chrome trace, and a device trace of the
+        # backend that holds the chip, taken on request
         r.add_get("/debug/trace", self._debug_trace)
-        r.add_get("/debug/profile", self._debug_profile)
+        r.add_get("/debug/xprof", self._debug_xprof)
         # SLO observability (ISSUE 11): percentile snapshot per model+path
         # and the crash flight recorder (recent request timelines, engine
         # ticks, tripwire/breaker/supervision events)
@@ -318,6 +318,12 @@ class API:
         # flag the middleware turns into 503s, and the live-request count
         # graceful shutdown waits on
         self._gates: dict[str, _AdmissionGate] = {}
+        # /backend/monitor and the /metrics scrape get two threads of their
+        # own: every open stream's pump holds a thread of the loop's default
+        # executor (cpu count + 4) for the stream's life, and under load a
+        # scrape sent there waited seconds for one
+        self._scrape_pool = concurrent.futures.ThreadPoolExecutor(
+            max_workers=2, thread_name_prefix="scrape")
         self._draining = False
         self._inflight = 0
         # SIGTERM → web.run_app GracefulExit → runner.cleanup → here:
@@ -504,6 +510,7 @@ class API:
                 f"({gate.limit} in flight, {gate.waiting} queued)",
                 model=cfg.name, reason="queue_full", retry_after=1.0)
         gate.waiting += 1
+        t0 = time.monotonic()
         try:
             rem = resilience.deadline_remaining()
             try:
@@ -515,6 +522,12 @@ class API:
                     model=cfg.name, reason="queue_timeout", retry_after=1.0)
         finally:
             gate.waiting -= 1
+        waited = time.monotonic() - t0
+        gate.wait_hist.observe(waited)
+        tr = telemetry.maybe_tracer()
+        if tr is not None:
+            tr.add_complete("http.gate_wait", t0, waited, cat="http",
+                            args={"model": cfg.name})
         try:
             yield
         finally:
@@ -852,14 +865,25 @@ class API:
     async def _metrics(self, request):
         if not _HAVE_PROM:
             raise web.HTTPNotImplemented()
-        await asyncio.to_thread(self._refresh_stage_gauges)
+        await self._scrape(self._refresh_scraped_series)
         return web.Response(body=generate_latest(),
                             content_type=CONTENT_TYPE_LATEST.split(";")[0])
 
-    def _refresh_stage_gauges(self):
-        """Pull each loaded backend's prof_* + hist_* metrics into the
-        Prometheus series (best-effort — a wedged backend must not fail the
-        scrape, and profile-less runs simply publish nothing)."""
+    def _scrape(self, fn):
+        """Run a scrape's blocking half on the scrape pool (contextvars
+        copied, as asyncio.to_thread does)."""
+        return asyncio.get_running_loop().run_in_executor(
+            self._scrape_pool, contextvars.copy_context().run, fn)
+
+    def _gate_metrics(self, name: str) -> dict:
+        """The HTTP process's own per-model histogram under the flat keys
+        the backend's use ({} until the model has a gate)."""
+        gate = self._gates.get(name)
+        return gate.wait_hist.flat("gate_wait") if gate is not None else {}
+
+    def _refresh_scraped_series(self):
+        """Pull each loaded backend's GetMetrics into the Prometheus series
+        (best-effort — a wedged backend must not fail the scrape)."""
         for (model, event), n in list(self.manager.events.items()):
             _counter_sync(_SUPERVISION, (model, event), float(n))
         for name in self.manager.loaded():
@@ -870,6 +894,7 @@ class API:
                 m = h.client.metrics(timeout=2.0)
             except Exception:
                 continue
+            m.update(self._gate_metrics(name))
             # SLO histograms: rebuilt whole from the flat keys; the custom
             # collector exposes them as true histogram series
             hists = telemetry.parse_flat(m)
@@ -904,16 +929,15 @@ class API:
                     _counter_sync(_KV_HOST_EVENTS, (name, key[8:]),
                                   float(v))
                     continue
-                if not key.startswith("prof_"):
+                if key == "xla_compiles_total":
+                    _counter_sync(_XLA_COMPILES, (name,), float(v))
                     continue
-                stage, _, kind = key[5:].rpartition("_")
-                if kind == "count":
-                    _counter_sync(_STAGE_DISPATCHES, (name, stage), float(v))
-                elif kind == "s" and stage.endswith("_tok"):
-                    _STAGE_TOK_S.labels(name, stage[:-4]).set(v)
-                elif kind == "ms" and stage.endswith("_total"):
-                    _counter_sync(_STAGE_SECONDS, (name, stage[:-6]),
-                                  v / 1e3)
+                for kind in ("host", "wait"):
+                    prefix = f"engine_{kind}_ms__"
+                    if key.startswith(prefix):
+                        _counter_sync(_ENGINE_PHASE,
+                                      (name, kind, key[len(prefix):]),
+                                      v / 1e3)
 
     async def _backend_traces(self, model: str = "") -> list[dict]:
         """GetTrace payloads from the loaded backends ({} on any failure)."""
@@ -950,19 +974,42 @@ class API:
         events.sort(key=lambda e: e.get("ts", 0))
         return web.json_response(telemetry.chrome_trace(events, names))
 
-    async def _debug_profile(self, request):
-        """GET /debug/profile[?model=x] → per-model device-step stage
-        breakdown (histograms, tokens/s, MFU) from the engine profiler.
-        Stages populate only under LOCALAI_PROFILE=1."""
-        profiles = {}
-        for payload in await self._backend_traces(
-                request.query.get("model", "")):
-            profiles[payload["model"]] = payload.get("profile") or {}
-        return web.json_response({
-            "tracing_enabled": telemetry.trace_enabled(),
-            "profiling_enabled": telemetry.profile_enabled(),
-            "models": profiles,
-        })
+    async def _debug_xprof(self, request):
+        """GET /debug/xprof?model=<m>&seconds=<s> → the backend that holds
+        the chip profiles itself (jax.profiler, Python tracer off, host
+        tracer 1) for `s` <= 10 seconds and answers {"dir", "xplane", ...}:
+        the device's ops and the engine's `engine.<phase>` annotations on
+        one clock, for `python -m tools.trace_gaps <dir>`. One at a time; a
+        refusal or a profiler failure is a 4xx/5xx JSON reply and nothing
+        else — serving goes on."""
+        name = request.query.get("model", "")
+        try:
+            seconds = float(request.query.get("seconds", "3"))
+        except ValueError:
+            seconds = -1.0
+        if not 0 < seconds <= telemetry.XPROF_MAX_S:
+            return web.json_response(
+                {"error": f"seconds must be in (0, "
+                          f"{telemetry.XPROF_MAX_S:g}]"}, status=400)
+        loaded = self.manager.loaded()
+        if not name and len(loaded) == 1:
+            name = loaded[0]
+        h = self.manager.get(name) if name in loaded else None
+        if h is None:
+            return web.json_response(
+                {"error": f"model {name!r} is not loaded"}, status=404)
+        try:
+            # stopping the profiler takes several times the traced seconds
+            payload = await asyncio.to_thread(
+                lambda: h.client.trace(timeout=seconds * 10 + 120,
+                                       xprof_seconds=seconds))
+            out = payload.get("xprof") or {"error": "backend sent no trace"}
+        except Exception as e:
+            out = {"error": f"{type(e).__name__}: {e}"}
+        out["model"] = name
+        busy = "already running" in out.get("error", "")
+        return web.json_response(
+            out, status=409 if busy else 502 if "error" in out else 200)
 
     async def _debug_slo(self, request):
         """GET /debug/slo[?model=x] → per-model p50/p95/p99 snapshot of the
@@ -1554,12 +1601,14 @@ class API:
             h = self.manager.get(name)
             if h is None:
                 continue
-            st = await asyncio.to_thread(lambda hh=h: hh.client.status())
+            st = await self._scrape(h.client.status)
             try:
-                metrics = await asyncio.to_thread(
-                    lambda hh=h: hh.client.metrics())
+                metrics = await self._scrape(h.client.metrics)
             except Exception:
                 metrics = {}
+            # this process's share of the model's histograms (the wait at
+            # the admission gate) under the same flat keys
+            metrics.update(self._gate_metrics(name))
             out[name] = {
                 "state": int(st.state),
                 "memory_total": st.memory.total,
@@ -1629,6 +1678,7 @@ class API:
         # already did
         if not self._draining:
             await self._drain(getattr(self.cfg, "drain_timeout", 30.0))
+        self._scrape_pool.shutdown(wait=False)
 
     async def _realtime(self, request):
         from localai_tpu.server.realtime import realtime_handler
@@ -2021,12 +2071,10 @@ def run_server(args) -> int:
 
     env_file = getattr(args, "env_file", None)
     load_env_files([env_file] if env_file else None)
-    # --trace/--profile go through the environment so the ModelManager's
-    # backend subprocesses (which inherit os.environ) pick them up too
+    # --trace goes through the environment so the ModelManager's backend
+    # subprocesses (which inherit os.environ) pick it up too
     if getattr(args, "trace", False):
         os.environ["LOCALAI_TRACE"] = "1"
-    if getattr(args, "profile", False):
-        os.environ["LOCALAI_PROFILE"] = "1"
     app_cfg = AppConfig.from_env(
         address=getattr(args, "address", None),
         models_path=getattr(args, "models_path", None),

@@ -1,39 +1,44 @@
-"""Telemetry subsystem: end-to-end request tracing + device-step profiling.
+"""Telemetry: what the serving path says about itself, and to whom.
 
-Three pieces (see ISSUE 2 / ROADMAP open item #1 — the 33 ms decode step has
-never been decomposed):
+One instrumentation layer, three kinds of sink; nothing here touches the
+device or adds a sync, and the always-on parts cost a few clock reads per
+engine tick or per request, never per token or per step.
 
-- `trace`: a lock-free ring-buffer span tracer with request-id propagation
-  HTTP middleware → gRPC metadata → engine, exported as Chrome-trace JSON
-  (`/debug/trace`, `local-ai util trace`, `bench.py --trace`).
-- `profiler`: opt-in `block_until_ready`-fenced per-stage timing of the
-  engine's device dispatches (admit / prefill / decode block / sample /
-  shift), accumulated into histograms with tokens/s + MFU estimates
-  (`/debug/profile`, GetMetrics `prof_*` keys, Prometheus series).
-- exporters live with their surfaces: the HTTP server merges spans across
-  processes via the backend GetTrace RPC.
+- `trace`: the lock-free ring-buffer span tracer (`LOCALAI_TRACE=1`, off by
+  default; request-id propagation HTTP middleware → gRPC metadata → engine;
+  Chrome-trace JSON at `/debug/trace`, `local-ai util trace`), and
+  `PhaseClock`, the engine thread's time tiled into phases (`dispatch`,
+  `admit`, `emit`, `kv`; waits `device`, `idle`): always-on cumulative
+  `engine_host_ms__*` / `engine_wait_ms__*` in GetMetrics, a
+  `jax.profiler.TraceAnnotation("engine.<phase>", tick=n)` on the profiler's
+  own clock in any device trace of the process (`GET /debug/xprof`, reduced
+  by `tools/trace_gaps.py`), and the ring span when the ring is on.
+- `metrics`: the serving SLO layer (`LOCALAI_METRICS`, on by default) —
+  per-request histograms over `BUCKETS_S`: `ttft` and its stages
+  (`gate_wait` in the HTTP process; `queue_wait`, `admit_to_join`,
+  `join_to_first` in the engine, summing to `ttft` exactly), `tpot`, `e2e`,
+  labeled by decode path; exported as GetMetrics `hist_*` keys, true
+  Prometheus histogram series and `/debug/slo`. Plus the crash/tripwire
+  flight recorder (`/debug/flightrec`, auto post-mortem dumps) and
+  `CompileCounter`, the `jax.monitoring` listener behind
+  `xla_compiles_total`, `xla_compile_ms_total` and `xla_compiles__<jit>`.
+- `sched`: the scheduler X-ray — a per-tick pack ledger with a registered
+  reason-code taxonomy for every admission/fallback/demotion decision, plus
+  XLA cost-analysis rooflines per compiled decode variant (`/debug/sched`,
+  GetMetrics `sched_*` keys, `local-ai util sched`; `LOCALAI_SCHED=0`
+  disables it alone).
 
-- `metrics` (ISSUE 11): the serving SLO layer — per-request phase-timeline
-  histograms (TTFT/TPOT/queue wait/prefill/e2e, labeled by decode path)
-  exported via GetMetrics `hist_*` keys, true Prometheus histogram series,
-  and `/debug/slo`; plus the crash/tripwire flight recorder
-  (`/debug/flightrec`, auto post-mortem dumps).
-
-- `sched` (ISSUE 13): the scheduler X-ray — a per-tick pack ledger with a
-  registered reason-code taxonomy for every admission/fallback/demotion
-  decision, plus XLA cost-analysis rooflines per compiled decode variant
-  (`/debug/sched`, GetMetrics `sched_*` keys, `local-ai util sched`).
-
-Enable with `LOCALAI_TRACE=1` (spans) and `LOCALAI_PROFILE=1` (fenced stage
-timing). Both default off; the serving hot path is untouched when disabled.
-SLO metrics default ON (`LOCALAI_METRICS=0` disables); the tick ledger
-rides the same gate (`LOCALAI_SCHED=0` disables it alone).
+The engine's own counters (`engine.metrics`: dispatches and steps consumed,
+requests admitted, tokens by path, ...) ride the same GetMetrics map.
 """
 from localai_tpu.telemetry.trace import (  # noqa: F401
+    XPROF_MAX_S,
+    PhaseClock,
     Tracer,
     chrome_events,
     chrome_trace,
     current_request_id,
+    device_trace,
     maybe_tracer,
     new_request_id,
     reset_request_id,
@@ -43,17 +48,13 @@ from localai_tpu.telemetry.trace import (  # noqa: F401
     trace_enabled,
     tracer,
 )
-from localai_tpu.telemetry.profiler import (  # noqa: F401
-    StepProfiler,
-    engine_profiler,
-    profile_enabled,
-    set_profile_enabled,
-)
 from localai_tpu.telemetry.metrics import (  # noqa: F401
     BUCKETS_S,
+    CompileCounter,
     FlightRecorder,
     Hist,
     SLORegistry,
+    compile_counter,
     flightrec,
     maybe_slo,
     metrics_enabled,

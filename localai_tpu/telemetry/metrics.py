@@ -3,15 +3,17 @@ flight recorder.
 
 Two pieces, both process-wide singletons the way `trace.py`'s tracer is:
 
-- `SLORegistry`: lock-cheap streaming histograms over the profiler's
-  log-spaced buckets (`BUCKETS_S`), keyed (metric, path). The engine feeds
-  TTFT / inter-token latency (TPOT) / queue wait / prefill time / e2e per
-  request, labeled by the decode path that served it (loop / dense / ragged
-  / spec). Observations are plain int increments under the GIL — no lock on
-  the hot path; snapshot readers (GetMetrics scrape, /debug/slo) tolerate a
+- `SLORegistry`: lock-cheap streaming histograms over log-spaced buckets
+  (`BUCKETS_S`), keyed (metric, path). The engine feeds TTFT, its three
+  stages (queue wait / admit→join / join→first token), inter-token latency
+  (TPOT) and e2e per request, labeled by the decode path that served it
+  (loop / dense / ragged / spec); the HTTP process keeps the wait at its
+  admission gate (`gate_wait`) in one `Hist` per model.
+  Observations are plain int increments under the GIL — no lock on the hot
+  path; snapshot readers (GetMetrics scrape, /debug/slo) tolerate a
   half-landed observation the same way the span ring does. Percentiles come
-  from the bucket upper bounds (coarse but free, same trade as
-  profiler._Stage.p50_s). The whole registry flattens onto the GetMetrics
+  from the bucket upper bounds (coarse but free). The whole registry
+  flattens onto the GetMetrics
   str→double surface (`hist_<metric>__<path>__{bN,count,sum}`) so the HTTP
   layer can rebuild TRUE Prometheus histogram series (_bucket/_sum/_count)
   and percentile snapshots across the process boundary without a proto
@@ -25,9 +27,13 @@ Two pieces, both process-wide singletons the way `trace.py`'s tracer is:
   trips, a breaker opens, a backend is reaped, or the engine loop dies —
   the black-box readout for "what was in flight when it crashed".
 
-Enable gate: `LOCALAI_METRICS` (default ON — unlike trace/profile this layer
-is the serving SLO surface; set 0 to disable). Disabled cost in the engine
-is one attribute load + branch, mirroring `_obs`.
+- `CompileCounter`: one `jax.monitoring` duration listener per process that
+  counts XLA backend compiles and their seconds by jitted function; a hit
+  in the persistent compilation cache is not a compile.
+
+Enable gate: `LOCALAI_METRICS` (default ON — unlike the ring tracer this
+layer is the serving SLO surface; set 0 to disable). Disabled cost in the
+engine is one attribute load + branch.
 """
 from __future__ import annotations
 
@@ -39,12 +45,21 @@ import tempfile
 import threading
 import time
 
-from localai_tpu.telemetry.profiler import BUCKETS_S
 from localai_tpu.testing.lockdep import lockdep_lock
 
-# SLO metric names the engine records (seconds); the fixed set keeps the
-# flat()/parse round-trip unambiguous and the exposition surfaces stable
-METRICS = ("ttft", "tpot", "queue_wait", "prefill", "e2e")
+# histogram bucket upper bounds, in seconds (log-spaced 50 µs … 5 s + inf)
+BUCKETS_S: tuple[float, ...] = (
+    50e-6, 100e-6, 200e-6, 500e-6, 1e-3, 2e-3, 5e-3, 10e-3, 20e-3, 50e-3,
+    100e-3, 200e-3, 500e-3, 1.0, 2.0, 5.0, math.inf,
+)
+
+# SLO metric names (seconds); the fixed set keeps the flat()/parse
+# round-trip unambiguous and the exposition surfaces stable. A request's
+# TTFT splits, in order, into gate_wait (HTTP process) and then queue_wait +
+# admit_to_join + join_to_first (engine; these three sum to ttft exactly:
+# they share their boundary timestamps).
+METRICS = ("ttft", "tpot", "gate_wait", "queue_wait", "admit_to_join",
+           "join_to_first", "e2e")
 
 _FORCED: bool | None = None
 
@@ -108,6 +123,18 @@ class Hist:
         self.count += other.count
         self.sum += other.sum
 
+    def flat(self, metric: str, path: str = "all") -> dict[str, float]:
+        """This histogram under the GetMetrics key scheme
+        `hist_<metric>__<path>__{b<i>,count,sum}` (double underscores so
+        `parse_flat` splits unambiguously); zero buckets are skipped to
+        keep the map small, count and sum are always there."""
+        base = f"hist_{metric}__{path}__"
+        out = {base + f"b{i}": float(n)
+               for i, n in enumerate(self.counts) if n}
+        out[base + "count"] = float(self.count)
+        out[base + "sum"] = self.sum
+        return out
+
 
 class SLORegistry:
     """Histograms keyed (metric, path). The creation path takes a lock once
@@ -139,19 +166,12 @@ class SLORegistry:
         return out
 
     def flat(self) -> dict[str, float]:
-        """Flatten onto the GetMetrics str→double surface. Key scheme
-        `hist_<metric>__<path>__{b<i>,count,sum}` (double underscores so
-        `parse_flat` splits unambiguously); zero buckets are skipped to keep
-        the map small. Plus derived headline keys the satellite requires:
-        ttft_ms_p50 / ttft_ms_p95 from the merged TTFT histogram."""
+        """Flatten onto the GetMetrics str→double surface (Hist.flat's key
+        scheme), plus the derived headline keys ttft_ms_p50 / ttft_ms_p95
+        from the merged TTFT histogram."""
         out: dict[str, float] = {}
         for (metric, path), h in list(self._hists.items()):
-            base = f"hist_{metric}__{path}__"
-            for i, n in enumerate(h.counts):
-                if n:
-                    out[base + f"b{i}"] = float(n)
-            out[base + "count"] = float(h.count)
-            out[base + "sum"] = h.sum
+            out.update(h.flat(metric, path))
         ttft = self.merged("ttft")
         if ttft.count:
             out["ttft_ms_p50"] = ttft.percentile(0.50) * 1e3
@@ -240,6 +260,78 @@ def maybe_slo() -> SLORegistry | None:
             if _SLO is None:
                 _SLO = SLORegistry()
     return _SLO
+
+
+# ----------------------------------------------------------- compile counter
+
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class CompileCounter:
+    """XLA backend compiles of this process and their seconds, by jitted
+    function. JAX reports the backend-compile duration around
+    `compile_or_get_cached`, so a hit in the persistent cache fires it too;
+    the cache's own retrieval event comes first on the same thread and
+    marks that one as not a compile. An in-memory jit-cache hit fires
+    nothing. Unlike the count of new cache files this sees compiles under
+    the persistent cache's 1 s threshold."""
+
+    MAX_NAMES = 64   # jitted functions are a few dozen; the rest pool
+
+    def __init__(self):
+        self.counts: dict[str, float] = {
+            "xla_compiles_total": 0, "xla_compile_ms_total": 0.0}
+        self._cached = threading.local()
+        # compiles come from the load thread and the engine thread; the
+        # listener runs once a compile, never on the request path
+        self._lock = lockdep_lock("telemetry.compiles")
+
+    def on_duration(self, event: str, duration: float, **kw) -> None:
+        if event == _CACHE_RETRIEVAL:
+            self._cached.hit = True
+            return
+        if event != _BACKEND_COMPILE:
+            return
+        if getattr(self._cached, "hit", False):
+            self._cached.hit = False
+            return
+        name = str(kw.get("fun_name") or "unnamed")
+        if name.startswith("jit(") and name.endswith(")"):
+            name = name[4:-1]            # JAX says jit(_loop): the function's
+        key = f"xla_compiles__{name}"
+        with self._lock:
+            c = self.counts
+            c["xla_compiles_total"] += 1
+            c["xla_compile_ms_total"] += duration * 1e3
+            if key not in c and len(c) >= self.MAX_NAMES + 2:
+                key = "xla_compiles__other"
+            c[key] = c.get(key, 0) + 1
+
+    def flat(self) -> dict[str, float]:
+        with self._lock:
+            return dict(self.counts)
+
+
+_COMPILES: CompileCounter | None = None
+_COMPILES_LOCK = lockdep_lock("telemetry.compiles_init")
+
+
+def compile_counter() -> CompileCounter:
+    """The process-wide compile counter; the first call registers its
+    listener with jax.monitoring (a listener cannot be taken back, so there
+    is exactly one). Backend processes only: it imports jax."""
+    global _COMPILES
+    if _COMPILES is None:
+        with _COMPILES_LOCK:
+            if _COMPILES is None:
+                import jax.monitoring
+
+                c = CompileCounter()
+                jax.monitoring.register_event_duration_secs_listener(
+                    c.on_duration)
+                _COMPILES = c
+    return _COMPILES
 
 
 # ----------------------------------------------------------- flight recorder

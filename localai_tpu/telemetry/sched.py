@@ -20,7 +20,7 @@ Three pieces, all riding the LOCALAI_METRICS default-ON gate:
   feeds the flight recorder's tick ring, so a post-mortem shows the last N
   *scheduling decisions*, not just dispatch counts. Disabled
   (LOCALAI_SCHED=0 or LOCALAI_METRICS=0) the engine keeps one attribute
-  load + branch per tick (the `_obs` contract).
+  load + branch per tick.
 
 - roofline helpers: fold XLA's `lower().compile().cost_analysis()` FLOPs +
   bytes into compute- vs bandwidth-bound attribution per compiled program

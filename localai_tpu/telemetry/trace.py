@@ -199,6 +199,130 @@ def span(name: str, cat: str = "", **args):
         yield s
 
 
+class PhaseClock:
+    """The engine thread's wall time, tiled into a fixed set of phases.
+
+    The thread is in exactly one phase at any moment; `switch` closes the
+    running phase and opens the next at ONE clock read, so the phases' sum
+    is the wall time between any two switches by construction. Each closed
+    phase goes to three sinks:
+
+    (a) `metrics["engine_host_ms__<phase>"]` (host work) or
+        `metrics["engine_wait_ms__<phase>"]` (waits), cumulative ms — the
+        keys exist at 0.0 from construction, so a scrape always finds them;
+    (b) a `jax.profiler.TraceAnnotation("engine.<phase>", tick=n)`, so any
+        device trace of this process holds the phases on the trace's own
+        clock (host tracer level 1); the first annotation of each tick also
+        carries `unix_us`, which places ring spans of any process on that
+        clock. No trace running: about half a microsecond;
+    (c) a ring span `engine.<phase>` when LOCALAI_TRACE is on — waits of the
+        idle loop and phases under RING_MIN_S stay out of the ring, so an
+        idle engine does not wash the request spans out of it.
+
+    Cost with everything off: one clock read and one annotation per switch,
+    a handful of switches per engine tick; nothing per token or per step."""
+
+    HOST = ("dispatch", "admit", "emit", "kv")
+    WAIT = ("device", "idle")
+    RING_MIN_S = 100e-6
+
+    def __init__(self, metrics: dict, tracer: Tracer | None = None):
+        self._m = metrics
+        self._keys = {p: f"engine_host_ms__{p}" for p in self.HOST}
+        self._keys.update({p: f"engine_wait_ms__{p}" for p in self.WAIT})
+        for k in self._keys.values():
+            metrics[k] = 0.0
+        self._tracer = tracer
+        from jax.profiler import TraceAnnotation   # engine processes only
+
+        self._annotation = TraceAnnotation
+        self.phase = "idle"
+        self.tick = 0
+        self._stamped = -1       # the last tick whose annotation has unix_us
+        self._open = None        # the running phase's annotation
+        self._t0 = time.perf_counter()
+
+    def switch(self, phase: str, tick: int | None = None) -> str:
+        """Close the running phase, open `phase`; returns the one closed."""
+        now = time.perf_counter()
+        prev, t0 = self.phase, self._t0
+        self._m[self._keys[prev]] += (now - t0) * 1e3
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+        tr = self._tracer
+        if tr is not None and prev != "idle" and now - t0 >= self.RING_MIN_S:
+            tr.add_complete("engine." + prev, t0, now - t0, cat="engine",
+                            args={"tick": self.tick})
+        if tick is not None:
+            self.tick = tick
+        self.phase, self._t0 = phase, now
+        if self.tick != self._stamped:
+            self._stamped = self.tick
+            a = self._annotation("engine." + phase, tick=self.tick,
+                                 unix_us=int(now * 1e6) + _EPOCH_US)
+        else:
+            a = self._annotation("engine." + phase, tick=self.tick)
+        a.__enter__()
+        self._open = a
+        return prev
+
+    @contextlib.contextmanager
+    def within(self, phase: str):
+        """`phase` for the block, then back to the phase it interrupted."""
+        prev = self.switch(phase)
+        try:
+            yield
+        finally:
+            self.switch(prev)
+
+    def total_ms(self) -> float:
+        """Closed phases' sum; the running phase is not in it yet."""
+        return sum(self._m[k] for k in self._keys.values())
+
+
+XPROF_MAX_S = 10.0
+_XPROF_LOCK = lockdep_lock("telemetry.xprof")
+
+
+def device_trace(seconds: float) -> dict:
+    """Run the JAX profiler in THIS process for `seconds` (the process that
+    holds the chip: only it can trace it) and return {"dir", "seconds",
+    "xplane"}; Python tracer off, host tracer at 1, the lowest that keeps
+    TraceAnnotation — so the trace holds the device's ops and the engine's
+    `engine.<phase>` annotations on one clock (`tools/trace_gaps.py` reduces
+    it). One at a time, at most XPROF_MAX_S; anything that goes wrong is
+    {"error": ...} — it runs on a handler thread of its own and never
+    raises into the serving path."""
+    if not 0 < seconds <= XPROF_MAX_S:
+        return {"error": f"seconds must be in (0, {XPROF_MAX_S:g}], "
+                         f"got {seconds:g}"}
+    if not _XPROF_LOCK.acquire(blocking=False):
+        return {"error": "a device trace is already running"}
+    try:
+        import glob
+        import tempfile
+
+        import jax
+
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        out = tempfile.mkdtemp(prefix="localai_xprof_")
+        jax.profiler.start_trace(out, profiler_options=opts)
+        try:
+            time.sleep(seconds)
+        finally:
+            jax.profiler.stop_trace()
+        return {"dir": out, "seconds": seconds, "pid": os.getpid(),
+                "xplane": sorted(glob.glob(os.path.join(
+                    out, "plugins", "profile", "*", "*.xplane.pb")))}
+    except Exception as e:
+        return {"error": f"{type(e).__name__}: {e}"}
+    finally:
+        _XPROF_LOCK.release()
+
+
 def chrome_events() -> list[dict]:
     """This process's recorded spans (empty when tracing never started)."""
     return _TRACER.events() if _TRACER is not None else []

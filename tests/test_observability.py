@@ -23,7 +23,7 @@ from fixtures import tiny_checkpoint
 from localai_tpu.telemetry.metrics import (
     FlightRecorder, Hist, SLORegistry, parse_flat, snapshot_from_hists,
 )
-from localai_tpu.telemetry.profiler import BUCKETS_S
+from localai_tpu.telemetry.metrics import BUCKETS_S
 
 
 # ------------------------------------------------------------------ units

@@ -193,23 +193,6 @@ def test_unknown_device_kind_gives_no_peak():
     assert "sched_roofline__decode__mfu" not in flat
 
 
-def test_profiler_cost_backed_mfu_only():
-    from localai_tpu.telemetry.profiler import StepProfiler
-
-    p = StepProfiler(fence=False, n_params=1000, peak=1e9, peak_bw=1e9)
-    p.record("decode", time.perf_counter() - 0.01, tokens=10)
-    r0 = p.report()["stages"]["decode"]
-    # cost-backed only (ISSUE 16): None until set_costs, and the analytic
-    # legacy key no longer exists anywhere in the report or flat surface
-    assert r0["mfu"] is None and "mfu_analytic_legacy" not in r0
-    p.set_costs({"decode": {"flops": 1e6, "bytes": 2e6}})
-    st = p.report()["stages"]["decode"]
-    assert st["mfu"] is not None and st["cost_flops"] == 1e6
-    flat = p.flat()
-    assert "prof_decode_mfu" in flat
-    assert not any("mfu_analytic_legacy" in k for k in flat)
-
-
 # --------------------------------------------------------------- benchdiff
 
 

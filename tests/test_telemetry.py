@@ -1,6 +1,8 @@
-"""Telemetry subsystem tests (ISSUE 2): tracer/profiler units, engine-stage
-instrumentation, trace integrity under concurrent multi-slot serving through
-the full HTTP→gRPC→engine stack, and the disabled-path overhead guard.
+"""Telemetry subsystem tests (ISSUE 2, ISSUE 24): tracer units, the engine
+thread's phases and each request's TTFT stages, the per-dispatch and compile
+counters, trace integrity under concurrent multi-slot serving through the
+full HTTP→gRPC→engine stack, the device-trace endpoint, and the
+disabled-path overhead guard.
 """
 import json
 import os
@@ -75,36 +77,6 @@ def test_tracer_concurrent_writers():
     assert len(set(ids)) == len(ids)
 
 
-def test_profiler_histogram_and_flat():
-    from localai_tpu.telemetry import StepProfiler
-
-    p = StepProfiler(fence=False, n_params=1_000_000, peak=1e12)
-    for _ in range(10):
-        p.record("decode_block", time.perf_counter() - 0.004, tokens=64)
-    p.record("admit", time.perf_counter() - 0.001, tokens=8)
-    r = p.report()
-    st = r["stages"]["decode_block"]
-    assert st["count"] == 10 and st["tokens"] == 640
-    assert 0 < st["p50_ms"] <= 20
-    assert sum(st["hist"]) == 10
-    # mfu is COST-BACKED (ISSUE 13): None until set_costs supplies the
-    # compiled variant's FLOPs; the 2·N·tokens analytic estimate is gone
-    # (removed in ISSUE 16 after its one-release grace period)
-    assert st["mfu"] is None
-    assert "mfu_analytic_legacy" not in st
-    p.set_costs({"decode_block": {"flops": 2e6, "bytes": 1e6}})
-    st = p.report()["stages"]["decode_block"]
-    assert st["mfu"] is not None and st["mfu"] > 0
-    assert st["cost_flops"] == 2e6 and st["cost_bytes"] == 1e6
-    assert abs(sum(s["share"] for s in r["stages"].values()) - 1.0) < 1e-6
-    assert r["coverage"] > 0
-    flat = p.flat()
-    assert flat["prof_decode_block_count"] == 10.0
-    assert flat["prof_admit_total_ms"] > 0
-    assert flat["prof_decode_block_mfu"] > 0
-    assert not any(k.endswith("mfu_analytic_legacy") for k in flat)
-
-
 # ------------------------------------------------- engine instrumentation
 
 
@@ -146,21 +118,25 @@ def test_engine_stage_spans_and_profile(ckpt):
     from localai_tpu import telemetry
 
     telemetry.set_trace_enabled(True)
-    telemetry.set_profile_enabled(True)
     tracer = telemetry.tracer()
     tracer.clear()
     try:
         eng, tok = _engine(ckpt)
-        assert eng._prof is not None and eng._tracer is not None
+        assert eng._tracer is not None
         finished = _run(eng, tok, n_req=4)
         assert finished == 4
         names = {e["name"] for e in tracer.events()}
-        # the device-step stages the ISSUE names: admit, prefill-or-decode
-        # fused dispatches, and the sample (sync+commit) stage
-        assert "engine.admit" in names
-        assert "engine.sample" in names
-        assert names & {"engine.decode_loop", "engine.decode_block",
-                        "engine.decode"}
+        # the engine thread's phases: admission, the build + enqueue of a
+        # decode, the wait for its results, and the emit after the fetch
+        assert {"engine.admit", "engine.dispatch", "engine.device",
+                "engine.emit"} <= names
+        assert "engine.idle" not in names      # idle waits stay out
+        # each request's TTFT stages, under its request id
+        for stage in ("queue_wait", "admit_to_join", "join_to_first"):
+            got = [e for e in tracer.events()
+                   if e["name"] == "engine.stage." + stage]
+            assert len(got) == 4
+            assert len({e["args"]["request_id"] for e in got}) == 4
         # one engine.request span per request, all closed, with ttft args
         reqs = [e for e in tracer.events() if e["name"] == "engine.request"]
         assert len(reqs) == 4
@@ -168,33 +144,26 @@ def test_engine_stage_spans_and_profile(ckpt):
             assert r["args"]["generated"] > 0
             assert r["args"]["ttft_ms"] is not None
             assert r["args"]["request_id"].startswith("rid-")
-        prof = eng._prof.report()
-        assert prof["stages"]["admit"]["count"] >= 1
-        decode_stages = [s for s in prof["stages"]
-                         if s in ("decode", "decode_block", "decode_loop")]
-        assert decode_stages
-        # fenced stage totals cover most of the busy window (the >=90%
-        # wall-coverage acceptance, measured on the in-process engine)
-        assert prof["coverage"] > 0.5
-        assert prof["fenced"] is True
     finally:
         telemetry.set_trace_enabled(None)
-        telemetry.set_profile_enabled(None)
         tracer.clear()
 
 
 def test_tracing_disabled_is_inert_and_cheap(ckpt):
-    """The overhead guard: with telemetry off the engine must hold no tracer
-    or profiler, record nothing, and its step loop must stay within noise of
-    itself — the instrumentation left on the hot path is one perf_counter
-    read and a None-check per device dispatch."""
+    """The overhead guard: with the ring off the engine must hold no tracer,
+    construct none, record nothing, and its step loop must stay within noise
+    of itself — what is left on the hot path is the phase clock: a clock
+    read and an annotation per phase switch, a handful per tick."""
     from localai_tpu import telemetry
+    from localai_tpu.telemetry import trace as trace_mod
 
     telemetry.set_trace_enabled(False)
-    telemetry.set_profile_enabled(False)
+    made = trace_mod._TRACER
     try:
         eng, tok = _engine(ckpt)
-        assert eng._prof is None and eng._tracer is None
+        assert eng._tracer is None and eng._phases._tracer is None
+        assert not hasattr(eng, "_prof")
+        assert trace_mod._TRACER is made      # nothing constructed
         before = len(telemetry.chrome_events())
         _run(eng, tok, n_req=2, max_tokens=16)
         assert len(telemetry.chrome_events()) == before   # nothing recorded
@@ -208,7 +177,7 @@ def test_tracing_disabled_is_inert_and_cheap(ckpt):
         disabled = min(timed() for _ in range(3))
         # enable spans (no fences) on the SAME engine: the recording path
         # itself must be cheap relative to a device dispatch
-        eng._tracer = telemetry.tracer()
+        eng._tracer = eng._phases._tracer = telemetry.tracer()
         eng._tracer.clear()
         enabled = min(timed() for _ in range(3))
         eng._tracer.clear()
@@ -217,7 +186,6 @@ def test_tracing_disabled_is_inert_and_cheap(ckpt):
             f"{disabled:.3f}s disabled")
     finally:
         telemetry.set_trace_enabled(None)
-        telemetry.set_profile_enabled(None)
 
 
 # ------------------------------------- full-stack concurrent trace integrity
@@ -225,7 +193,7 @@ def test_tracing_disabled_is_inert_and_cheap(ckpt):
 
 @pytest.fixture(scope="module")
 def traced_stack(tmp_path_factory):
-    """HTTP server + real backend subprocess with LOCALAI_TRACE/PROFILE on:
+    """HTTP server + real backend subprocess with LOCALAI_TRACE on:
     the end-to-end path the /debug endpoints and request-id propagation
     need. Mirrors test_http_api's stack fixture."""
     import asyncio
@@ -257,9 +225,7 @@ def traced_stack(tmp_path_factory):
 
     os.environ["JAX_PLATFORMS"] = "cpu"
     old_trace = os.environ.get("LOCALAI_TRACE")
-    old_prof = os.environ.get("LOCALAI_PROFILE")
     os.environ["LOCALAI_TRACE"] = "1"    # backend subprocess inherits
-    os.environ["LOCALAI_PROFILE"] = "1"
     app_cfg = AppConfig(address=f"127.0.0.1:{port}", models_path=str(models),
                         parallel_requests=4)
     configs = ModelConfigLoader(str(models))
@@ -288,12 +254,10 @@ def traced_stack(tmp_path_factory):
     yield base, manager
     manager.stop_all()
     loop.call_soon_threadsafe(loop.stop)
-    for key, old in (("LOCALAI_TRACE", old_trace),
-                     ("LOCALAI_PROFILE", old_prof)):
-        if old is None:
-            os.environ.pop(key, None)
-        else:
-            os.environ[key] = old
+    if old_trace is None:
+        os.environ.pop("LOCALAI_TRACE", None)
+    else:
+        os.environ["LOCALAI_TRACE"] = old_trace
 
 
 def _warm(base):
@@ -369,35 +333,93 @@ def test_concurrent_trace_integrity_http_grpc_engine(traced_stack):
     assert engine_reqs
     assert all(e["args"].get("parent_id") in grpc_ids for e in engine_reqs)
 
-    # device stages made it across the process boundary
+    # the engine's phases and the requests' stages made it across the
+    # process boundary, and so did the HTTP process's wait at the gate
     names = {e["name"] for e in spans}
-    assert "engine.admit" in names and "engine.sample" in names
+    assert {"engine.admit", "engine.emit",
+            "engine.stage.join_to_first"} <= names
+    assert "http.gate_wait" in names
 
 
-def test_debug_profile_and_prometheus_stage_series(traced_stack):
+def test_gate_wait_and_counters_in_backend_monitor_and_metrics(traced_stack):
+    """The HTTP process's wait at the admission gate is one histogram per
+    model, merged into the model's metrics under the flat keys the backend's
+    use; the always-on engine counters ride the same map; no prof_* key."""
     base, _ = traced_stack
     _warm(base)
-    prof = requests.get(base + "/debug/profile", timeout=60).json()
-    assert prof["profiling_enabled"] is True
-    stages = prof["models"]["tiny"]["stages"]
-    assert "admit" in stages and "sample" in stages
-    assert any(s in stages for s in ("decode", "decode_block",
-                                     "decode_loop"))
-    assert stages["admit"]["count"] >= 1
-    assert prof["models"]["tiny"]["coverage"] > 0
+    mon = requests.get(base + "/backend/monitor", timeout=60).json()
+    m = mon["tiny"]["metrics"]
+    assert m["hist_gate_wait__all__count"] >= 1      # a wait of 0 counts
+    assert m["hist_gate_wait__all__sum"] >= 0
+    # one observation per request that passed the gate, as for the engine's
+    # stages (every request here got its first token)
+    for stage in ("queue_wait", "admit_to_join", "join_to_first"):
+        assert m[f"hist_{stage}__all__count"] == \
+            m["hist_gate_wait__all__count"], stage
+    for k in ("decode_dispatches_consumed", "decode_steps_consumed",
+              "requests_admitted", "xla_compiles_total",
+              "xla_compile_ms_total", "engine_host_ms__dispatch",
+              "engine_host_ms__admit", "engine_host_ms__emit",
+              "engine_host_ms__kv", "engine_wait_ms__device",
+              "engine_wait_ms__idle"):
+        assert k in m, k
+    assert m["requests_admitted"] >= 1 and m["xla_compiles_total"] >= 1
+    assert any(k.startswith("xla_compiles__") for k in m)
+    assert not any(k.startswith("prof_") for k in m)
 
-    # stage breakdown sums to ~100% of the busy window's stage time
-    assert abs(sum(s["share"] for s in stages.values()) - 1.0) < 1e-6
+    prom = requests.get(base + "/metrics", timeout=60).text
+    assert 'localai_request_gate_wait_seconds_count{model="tiny"' in prom
+    assert 'localai_request_join_to_first_seconds_bucket' in prom
+    assert 'localai_engine_phase_seconds_total{kind="wait",model="tiny",' \
+           'phase="device"}' in prom
+    assert 'localai_xla_compiles_total{model="tiny"}' in prom
+    assert "localai_engine_stage_" not in prom
 
-    # Prometheus series appear after a scrape
-    m = requests.get(base + "/metrics", timeout=60).text
-    assert "localai_engine_stage_seconds_total" in m
-    assert 'stage="admit"' in m
+    # /backend/monitor's two RPCs leave a ring span on each side: what the
+    # caller's holds beyond the handler's is the wait for a handler thread
+    trace = requests.get(base + "/debug/trace", timeout=60).json()
+    names = {e["name"] for e in trace["traceEvents"]}
+    assert {"rpc.Status", "rpc.GetMetrics", "grpc.Status",
+            "grpc.GetMetrics"} <= names
+
+
+def test_debug_xprof_takes_a_trace_and_refuses_long_and_concurrent(
+        traced_stack):
+    """GET /debug/xprof: the backend profiles itself and names the directory;
+    over 10 s is refused, a second call while one runs is refused, and the
+    server goes on serving. /debug/profile is gone."""
+    base, _ = traced_stack
+    _warm(base)
+    assert requests.get(base + "/debug/profile", timeout=60).status_code == 404
+    r = requests.get(base + "/debug/xprof?model=tiny&seconds=11", timeout=60)
+    assert r.status_code == 400 and "seconds" in r.json()["error"]
+    r = requests.get(base + "/debug/xprof?model=nope&seconds=1", timeout=60)
+    assert r.status_code == 404
+
+    replies = []
+
+    def take():
+        replies.append(requests.get(
+            base + "/debug/xprof?model=tiny&seconds=1", timeout=300))
+
+    threads = [threading.Thread(target=take) for _ in range(2)]
+    threads[0].start()
+    time.sleep(0.3)                  # the first is inside its traced second
+    threads[1].start()
+    _warm(base)                      # serving goes on meanwhile
+    [t.join() for t in threads]
+    codes = sorted(r.status_code for r in replies)
+    assert codes == [200, 409], [r.text for r in replies]
+    ok = next(r.json() for r in replies if r.status_code == 200)
+    assert ok["model"] == "tiny" and ok["seconds"] == 1.0
+    assert ok["xplane"] and all(os.path.exists(f) for f in ok["xplane"])
+    refused = next(r.json() for r in replies if r.status_code == 409)
+    assert "already running" in refused["error"]
 
 
 def test_util_trace_cli(traced_stack, tmp_path, capsys):
     """`local-ai util trace <addr>` writes a Chrome-trace file and prints
-    the stage table."""
+    the engine thread's phase table."""
     from localai_tpu.cli import main as cli_main
 
     base, _ = traced_stack
@@ -409,4 +431,4 @@ def test_util_trace_cli(traced_stack, tmp_path, capsys):
     assert any(e.get("ph") == "X" for e in dump["traceEvents"])
     printed = capsys.readouterr().out
     assert "events" in printed
-    assert "admit" in printed   # the stage table rendered
+    assert "admit" in printed and "engine thread" in printed

@@ -494,45 +494,3 @@ def test_bench_parser_has_tp_mode():
     p = bench.build_parser()
     args = p.parse_args(["--mode", "tp", "--tensor-parallel", "2", "--cpu"])
     assert args.mode == "tp" and args.tensor_parallel == 2
-
-
-def test_profiler_records_mesh_and_per_chip_mfu(mesh4):
-    """Telemetry acceptance: profiler artifacts carry the mesh shape and the
-    MFU denominator scales with the chip count, so a TP profile is never
-    silently read as a single-chip one."""
-    from localai_tpu.telemetry import StepProfiler
-
-    shape = mesh_shape(mesh4)
-    assert shape == {"data": 1, "model": 4}
-    prof = StepProfiler(fence=False, n_params=1000, peak=1e9, mesh=shape)
-    single = StepProfiler(fence=False, n_params=1000, peak=1e9)
-    import time
-
-    t0 = time.perf_counter() - 0.01
-    prof.record("decode", t0, tokens=100)
-    single.record("decode", t0, tokens=100)
-    # same compiled cost on both: the per-chip normalization lives entirely
-    # in the cost-backed MFU denominator (the analytic estimate is gone
-    # since ISSUE 16)
-    prof.set_costs({"decode": {"flops": 1e6, "bytes": 1e6}})
-    single.set_costs({"decode": {"flops": 1e6, "bytes": 1e6}})
-    rep, srep = prof.report(), single.report()
-    assert rep["mesh"] == {"data": 1, "model": 4} and rep["chips"] == 4
-    assert srep["mesh"] is None and srep["chips"] == 1
-    # same tokens, same wall time: per-chip-normalized MFU is 4x smaller
-    ratio = (srep["stages"]["decode"]["mfu"]
-             / rep["stages"]["decode"]["mfu"])
-    assert abs(ratio - 4.0) < 0.5
-
-
-def test_engine_profiler_inherits_engine_mesh(mesh4, monkeypatch):
-    from localai_tpu import telemetry
-
-    telemetry.set_profile_enabled(True)
-    try:
-        prof = telemetry.engine_profiler(CFG, mesh=mesh4)
-        assert prof is not None
-        assert prof.mesh == {"data": 1, "model": 4}
-        assert prof.chips == 4
-    finally:
-        telemetry.set_profile_enabled(None)

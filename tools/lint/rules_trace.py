@@ -114,8 +114,7 @@ class SyncBlockUntilReady:
                 yield Violation(
                     ctx.path, node.lineno, self.name,
                     "block_until_ready fences the dispatch pipeline; hot "
-                    "paths must stay async — fence only in opt-in profiling "
-                    "(telemetry/profiler) or startup probes")
+                    "paths must stay async — fence only in startup probes")
 
 
 class TracedBranch:

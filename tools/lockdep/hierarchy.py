@@ -90,7 +90,11 @@ RANKS: dict[str, int] = {
     "telemetry.flightrec_init": 93,
     "telemetry.flightrec": 94,
     "telemetry.sched": 95,
-    "telemetry.profiler": 96,
+    "telemetry.compiles_init": 96,
+    "telemetry.compiles": 96,
+    # held for the seconds of a device trace by the one GetTrace handler
+    # thread that takes it (try-acquire only: nobody ever waits on it)
+    "telemetry.xprof": 97,
     "faults.table": 98,
     "lockdep.graph": 99,
 }

@@ -1,10 +1,11 @@
 """CI telemetry smoke: serve a tiny model through the real process boundary
-with tracing+profiling on, then write the merged Chrome-trace artifact.
+with the ring tracer on, then write the merged Chrome-trace artifact.
 
 This is the scoreboard-path exerciser the tier-1 CI job uploads: a
 ModelManager-spawned gRPC backend (the same surface /v1/chat/completions
 rides), a few concurrent PredictStream requests, then GetTrace → one
-Chrome-trace JSON whose spans cover rpc → grpc → engine stages.
+Chrome-trace JSON whose spans cover rpc → grpc → the engine's phases and
+each request's TTFT stages; GetMetrics must carry the always-on counters.
 
 Usage: python tools/trace_smoke.py [--out trace_smoke.json]
 Exit code is non-zero when the trace is missing the expected layers, so the
@@ -22,7 +23,6 @@ import threading
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 os.environ["LOCALAI_TRACE"] = "1"
-os.environ["LOCALAI_PROFILE"] = "1"
 os.environ["LOCALAI_ALLOW_SYNTHETIC"] = "1"
 os.environ["LOCALAI_NO_PREWARM"] = "1"
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
@@ -78,11 +78,13 @@ def main() -> int:
     got = {e["name"] for e in events}
     rids = {e["args"].get("request_id") for e in events
             if e["name"] == "engine.request"}
-    stages = (payload.get("profile") or {}).get("stages") or {}
+    phases = {k: v for k, v in metrics.items()
+              if k.startswith(("engine_host_ms__", "engine_wait_ms__"))}
     print(f"wrote {args.out}: {len(events)} events, layers={sorted(got)[:8]}")
-    print(f"stage breakdown: " + ", ".join(
-        f"{k}={v['total_ms']:.1f}ms" for k, v in stages.items()))
-    want = {"engine.admit", "engine.sample", "grpc.PredictStream"}
+    print("engine thread: " + ", ".join(
+        f"{k}={v:.1f}ms" for k, v in sorted(phases.items())))
+    want = {"engine.admit", "engine.emit", "engine.stage.join_to_first",
+            "grpc.PredictStream"}
     missing = want - got
     if missing:
         print(f"FAIL: trace missing layers {missing}", file=sys.stderr)
@@ -91,8 +93,17 @@ def main() -> int:
         print(f"FAIL: request ids did not round-trip ({rids})",
               file=sys.stderr)
         return 1
-    if not stages:
-        print("FAIL: no stage profile recorded", file=sys.stderr)
+    always_on = ("engine_host_ms__dispatch", "engine_wait_ms__device",
+                 "decode_dispatches_consumed", "decode_steps_consumed",
+                 "requests_admitted", "xla_compiles_total")
+    if any(k not in metrics for k in always_on):
+        print(f"FAIL: GetMetrics lacks "
+              f"{[k for k in always_on if k not in metrics]}",
+              file=sys.stderr)
+        return 1
+    if metrics["requests_admitted"] < args.requests:
+        print(f"FAIL: requests_admitted {metrics['requests_admitted']}",
+              file=sys.stderr)
         return 1
 
     # SLO layer (ISSUE 11): the same scrape must carry the flat histogram
