@@ -83,11 +83,13 @@ def _make_base():
     return BackendServicer()
 
 
-# A stream holds a handler thread for its whole life. The HTTP process sends
-# at most min(32, cpu count + 4) streams at once (one pump thread each, on its
-# loop's default executor), so 40 handlers leave Status, GetMetrics and
-# GetTrace a thread whatever the load: with 16, /backend/monitor waited
-# seconds behind 16 open streams for a handler that then ran for 0.3 ms.
+# A request holds a handler thread until it is answered, a stream for its
+# whole life. The HTTP process sends a model's backend at most `parallel`
+# requests at once (its admission gate; one pump thread a stream), so 40
+# handlers serve a `parallel` of up to 36 and leave Status, GetMetrics,
+# GetTrace and Health a thread each: with 16, /backend/monitor waited seconds
+# behind 16 open streams for a handler that then ran for 0.3 ms. A `parallel`
+# above 36 is capped here, not at the gate.
 HANDLER_THREADS = 40
 
 

@@ -53,6 +53,10 @@ try:
     _XLA_COMPILES = Counter(
         "localai_xla_compiles_total",
         "XLA backend compiles of the model's backend process", ["model"])
+    # streams open against the model's backend now (the gate's count)
+    _STREAMS_OPEN = Gauge("localai_streams_open",
+                          "Streams open against the model's backend",
+                          ["model"])
     # load shedding (ISSUE 4): every 429/503 the admission layer or the
     # drain path produces is counted here so shedding is observable
     _SHED = Counter("localai_shed_total",
@@ -209,17 +213,40 @@ def _fetch_image(url: str) -> str:
 
 class _AdmissionGate:
     """Per-model admission state: `limit` concurrent requests against the
-    backend plus at most `depth` waiters; the rest shed with 429."""
+    backend plus at most `depth` waiters; the rest shed with 429. Nothing
+    else bounds the streams open against the backend: every permit has a
+    pump thread of its own (started on first use, asleep in a gRPC read
+    while its stream is open), so a stream never waits for a thread that
+    another stream holds."""
 
-    def __init__(self, limit: int, depth: int):
+    def __init__(self, name: str, limit: int, depth: int):
         self.limit = max(1, int(limit))
         self.depth = max(0, int(depth))
         self.sem = asyncio.Semaphore(self.limit)
         self.waiting = 0
+        self.pumps = concurrent.futures.ThreadPoolExecutor(
+            max_workers=self.limit, thread_name_prefix=f"pump-{name}")
         # the wait at this gate, one observation per request (0 included):
         # the first stage of a request's TTFT, merged into the model's
         # metrics as hist_gate_wait__all__* (/backend/monitor, /metrics)
         self.wait_hist = telemetry.Hist()
+        # permit -> the stream's pump thread running, one observation per
+        # stream (hist_stream_start__all__*), and the streams open now;
+        # both written on the loop's thread only
+        self.start_hist = telemetry.Hist()
+        self.streams_open = 0
+
+    def metrics(self) -> dict:
+        """This process's share of the model's metrics, under the flat keys
+        the backend's GetMetrics uses."""
+        return {**self.wait_hist.flat("gate_wait"),
+                **self.start_hist.flat("stream_start"),
+                "streams_open": float(self.streams_open)}
+
+
+# when the request running in this context got its gate permit
+_PERMIT_AT: contextvars.ContextVar[float] = contextvars.ContextVar(
+    "localai_permit_at")
 
 
 class API:
@@ -319,9 +346,9 @@ class API:
         # graceful shutdown waits on
         self._gates: dict[str, _AdmissionGate] = {}
         # /backend/monitor and the /metrics scrape get two threads of their
-        # own: every open stream's pump holds a thread of the loop's default
-        # executor (cpu count + 4) for the stream's life, and under load a
-        # scrape sent there waited seconds for one
+        # own: a unary request holds a thread of the loop's default executor
+        # (cpu count + 4) until the backend answers, and a scrape must not
+        # wait behind those
         self._scrape_pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=2, thread_name_prefix="scrape")
         self._draining = False
@@ -493,7 +520,7 @@ class API:
         g = self._gates.get(cfg.name)
         if g is None:
             g = self._gates[cfg.name] = _AdmissionGate(
-                cfg.parallel or self.cfg.parallel_requests,
+                cfg.name, cfg.parallel or self.cfg.parallel_requests,
                 getattr(self.cfg, "queue_depth", 8))
         return g
 
@@ -522,15 +549,18 @@ class API:
                     model=cfg.name, reason="queue_timeout", retry_after=1.0)
         finally:
             gate.waiting -= 1
-        waited = time.monotonic() - t0
+        now = time.monotonic()
+        waited = now - t0
         gate.wait_hist.observe(waited)
         tr = telemetry.maybe_tracer()
         if tr is not None:
             tr.add_complete("http.gate_wait", t0, waited, cat="http",
                             args={"model": cfg.name})
+        permit = _PERMIT_AT.set(now)
         try:
             yield
         finally:
+            _PERMIT_AT.reset(permit)
             gate.sem.release()
 
     async def _unary(self, cfg: ModelConfig, method: str,
@@ -634,6 +664,10 @@ class API:
         resumes = attempt = 0
         unconfirmed = ""             # resume mode awaiting its first chunk
         cur = opts
+        gate = self._gate(cfg)
+        # the stream's start is timed from its permit, once: a retry or a
+        # resume opens another RPC of the same stream
+        since: float | None = _PERMIT_AT.get(time.monotonic())
         while True:
             if attempt:
                 await asyncio.sleep(resilience.backoff(attempt))
@@ -642,7 +676,8 @@ class API:
             streamed = bool(emitted or sent_chars)
             preempted = False
             err: Exception | None = None
-            pump = self._pump_stream(handle, cur)
+            pump = self._pump_stream(gate, handle, cur, since)
+            since = None
             try:
                 async for reply in pump:
                     if reply.resume_json:
@@ -780,8 +815,10 @@ class API:
             return ropts, "replay", list(emitted[keep:]), keep
         return None
 
-    async def _pump_stream(self, handle, opts: dict):
-        """Bridge the blocking gRPC stream into an async queue."""
+    async def _pump_stream(self, gate: _AdmissionGate, handle, opts: dict,
+                           since: float | None = None):
+        """Bridge the blocking gRPC stream into an async queue, on one of
+        the gate's pump threads. `since`: when the stream got its permit."""
         loop = asyncio.get_running_loop()
         # Bounded queue + BLOCKING put from the pump thread: backpressure
         # propagates to the gRPC stream instead of dropping chunks (or the
@@ -813,6 +850,9 @@ class API:
 
         def pump():
             try:
+                if since is not None:
+                    loop.call_soon_threadsafe(gate.start_hist.observe,
+                                              time.monotonic() - since)
                 for reply in call:
                     if not _put(("chunk", reply)):
                         return
@@ -821,8 +861,9 @@ class API:
                 if not stopped.is_set():
                     _put(("error", e))
 
-        loop.run_in_executor(None, pump)
+        gate.streams_open += 1
         try:
+            loop.run_in_executor(gate.pumps, pump)
             while True:
                 kind, item = await q.get()
                 if kind == "chunk":
@@ -832,6 +873,7 @@ class API:
                 else:
                     raise item
         finally:
+            gate.streams_open -= 1
             stopped.set()
             # cancelling the RPC unblocks a pump waiting on the next reply
             # (client gone mid-generation) and tells the backend to stop
@@ -876,10 +918,10 @@ class API:
             self._scrape_pool, contextvars.copy_context().run, fn)
 
     def _gate_metrics(self, name: str) -> dict:
-        """The HTTP process's own per-model histogram under the flat keys
-        the backend's use ({} until the model has a gate)."""
+        """The HTTP process's own per-model metrics under the flat keys the
+        backend's use ({} until the model has a gate)."""
         gate = self._gates.get(name)
-        return gate.wait_hist.flat("gate_wait") if gate is not None else {}
+        return gate.metrics() if gate is not None else {}
 
     def _refresh_scraped_series(self):
         """Pull each loaded backend's GetMetrics into the Prometheus series
@@ -931,6 +973,9 @@ class API:
                     continue
                 if key == "xla_compiles_total":
                     _counter_sync(_XLA_COMPILES, (name,), float(v))
+                    continue
+                if key == "streams_open":
+                    _STREAMS_OPEN.labels(name).set(v)
                     continue
                 for kind in ("host", "wait"):
                     prefix = f"engine_{kind}_ms__"
@@ -1679,6 +1724,8 @@ class API:
         if not self._draining:
             await self._drain(getattr(self.cfg, "drain_timeout", 30.0))
         self._scrape_pool.shutdown(wait=False)
+        for gate in self._gates.values():
+            gate.pumps.shutdown(wait=False)
 
     async def _realtime(self, request):
         from localai_tpu.server.realtime import realtime_handler

@@ -15,8 +15,9 @@ engine tick or per request, never per token or per step.
   by `tools/trace_gaps.py`), and the ring span when the ring is on.
 - `metrics`: the serving SLO layer (`LOCALAI_METRICS`, on by default) —
   per-request histograms over `BUCKETS_S`: `ttft` and its stages
-  (`gate_wait` in the HTTP process; `queue_wait`, `admit_to_join`,
-  `join_to_first` in the engine, summing to `ttft` exactly), `tpot`, `e2e`,
+  (`gate_wait`, `stream_start` in the HTTP process; `queue_wait`,
+  `admit_to_join`, `join_to_first` in the engine, summing to `ttft`
+  exactly), `tpot`, `e2e`,
   labeled by decode path; exported as GetMetrics `hist_*` keys, true
   Prometheus histogram series and `/debug/slo`. Plus the crash/tripwire
   flight recorder (`/debug/flightrec`, auto post-mortem dumps) and

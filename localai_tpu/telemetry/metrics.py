@@ -8,7 +8,8 @@ Two pieces, both process-wide singletons the way `trace.py`'s tracer is:
   stages (queue wait / admit→join / join→first token), inter-token latency
   (TPOT) and e2e per request, labeled by the decode path that served it
   (loop / dense / ragged / spec); the HTTP process keeps the wait at its
-  admission gate (`gate_wait`) in one `Hist` per model.
+  admission gate (`gate_wait`) and from the permit to the stream's pump
+  thread running (`stream_start`) in one `Hist` each per model.
   Observations are plain int increments under the GIL — no lock on the hot
   path; snapshot readers (GetMetrics scrape, /debug/slo) tolerate a
   half-landed observation the same way the span ring does. Percentiles come
@@ -55,11 +56,12 @@ BUCKETS_S: tuple[float, ...] = (
 
 # SLO metric names (seconds); the fixed set keeps the flat()/parse
 # round-trip unambiguous and the exposition surfaces stable. A request's
-# TTFT splits, in order, into gate_wait (HTTP process) and then queue_wait +
-# admit_to_join + join_to_first (engine; these three sum to ttft exactly:
-# they share their boundary timestamps).
-METRICS = ("ttft", "tpot", "gate_wait", "queue_wait", "admit_to_join",
-           "join_to_first", "e2e")
+# TTFT splits, in order, into gate_wait and stream_start (HTTP process; the
+# second is streams only) and then queue_wait + admit_to_join +
+# join_to_first (engine; these three sum to ttft exactly: they share their
+# boundary timestamps).
+METRICS = ("ttft", "tpot", "gate_wait", "stream_start", "queue_wait",
+           "admit_to_join", "join_to_first", "e2e")
 
 _FORCED: bool | None = None
 

@@ -1,0 +1,261 @@
+"""How many streams are open against a model's backend at once is decided by
+the model's admission gate (limit = `parallel`) and by nothing else: each
+permit has a pump thread of the gate's own, and the loop's default executor
+is left to the short blocking calls.
+
+No engine, no gRPC: a fake handle whose `predict_stream` keeps the iterator
+contract of `backend/client.py` (an iterator of `pb.Reply` with `cancel()`),
+driven through `API._admit` and `API._stream_rpc`. Every test runs under a
+time limit of its own (`_run`), and every wait of a fake is bounded, so a tree
+that cannot open the streams fails here in seconds and leaves no thread
+behind.
+"""
+import asyncio
+import concurrent.futures
+import threading
+import time
+from collections import Counter
+
+import grpc
+import pytest
+
+from localai_tpu.backend import pb
+from localai_tpu.config import AppConfig, ModelConfig
+from localai_tpu.server.http import API
+
+LIMIT_S = 10.0      # a test's own time limit
+WAIT_S = 6.0        # the longest a fake waits: under the limit, so the
+                    # threads of a failing run end before the test does
+
+
+class FakeCall:
+    """One PredictStream call: `gate()` once before the first reply (what
+    the test makes the open stream wait for), then `chunks` replies, the
+    last one finished. After `cancel()` the next read raises, as gRPC's
+    does."""
+
+    def __init__(self, gate, chunks: int):
+        self._gate, self._left, self._first = gate, chunks, True
+        self.cancelled = threading.Event()
+        self.thread = ""
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        self.thread = threading.current_thread().name
+        if self._first:
+            self._first = False
+            self._gate(self)
+        if self.cancelled.is_set():
+            raise grpc.RpcError("cancelled")
+        if not self._left:
+            raise StopIteration
+        self._left -= 1
+        return pb.Reply(message=b"t ", token_ids=[7], tokens=1,
+                        finish_reason="" if self._left else "length")
+
+    def cancel(self):
+        self.cancelled.set()
+
+
+class FakeHandle:
+    def __init__(self, gate, chunks: int):
+        self.client = self
+        self.calls: list[FakeCall] = []
+        self._gate, self._chunks = gate, chunks
+
+    def predict_stream(self, **opts):
+        self.calls.append(FakeCall(self._gate, self._chunks))
+        return self.calls[-1]
+
+    def mark_busy(self):
+        pass
+
+    def mark_idle(self):
+        pass
+
+
+class FakeManager:
+    def __init__(self, handle):
+        self.handle = handle
+        self.events = Counter()
+
+    def load(self, cfg):
+        return self.handle
+
+    def stop_all(self):
+        pass
+
+
+def _api(parallel: int, gate=lambda call: None, chunks: int = 2):
+    handle = FakeHandle(gate, chunks)
+    api = API(AppConfig(queue_depth=64), None, FakeManager(handle))
+    return api, ModelConfig(name="m", backend="llm", parallel=parallel), handle
+
+
+async def _stream(api, cfg) -> int:
+    n = 0
+    async with api._admit(cfg):
+        async for reply in api._stream_rpc(cfg, {}):
+            n += len(reply.token_ids)
+    return n
+
+
+def _run(main, default_threads: int = 2):
+    """Run `main()` under the test's time limit, on a loop whose default
+    executor has `default_threads` threads: what a one-core machine gives
+    the short blocking calls, and what the streams used to share."""
+    async def limited():
+        pool = concurrent.futures.ThreadPoolExecutor(
+            default_threads, thread_name_prefix="default")
+        asyncio.get_running_loop().set_default_executor(pool)
+        try:
+            return await asyncio.wait_for(main(), LIMIT_S)
+        finally:
+            pool.shutdown(wait=False, cancel_futures=True)
+
+    return asyncio.run(limited())
+
+
+def _pump_threads() -> list[threading.Thread]:
+    return [t for t in threading.enumerate() if t.name.startswith("pump-")]
+
+
+async def _close(api):
+    api._draining = True        # nothing to drain: no backend, no request
+    await api._on_shutdown(api.app)
+
+
+@pytest.mark.parametrize("parallel", [4, 40])
+def test_parallel_streams_are_open_at_once(parallel):
+    """Every stream waits, on its pump thread, until `parallel` of them are
+    open: with streams on a pool smaller than `parallel` this never ends."""
+    barrier = threading.Barrier(parallel, timeout=WAIT_S)
+    api, cfg, handle = _api(parallel, gate=lambda call: barrier.wait())
+
+    async def main():
+        try:
+            return await asyncio.gather(
+                *(_stream(api, cfg) for _ in range(parallel)))
+        finally:
+            await _close(api)
+
+    assert _run(main) == [2] * parallel
+    assert not barrier.broken
+    threads = {c.thread for c in handle.calls}
+    assert len(threads) == parallel
+    assert all(t.startswith("pump-m") for t in threads)
+
+
+def test_a_unary_call_returns_while_parallel_streams_are_open():
+    """The default executor is left to the short blocking calls: with every
+    permit's stream open, `asyncio.to_thread` answers at once."""
+    parallel = 8
+    release = threading.Event()
+    opened = threading.Semaphore(0)
+
+    def hold(call):
+        opened.release()
+        release.wait(WAIT_S)
+
+    api, cfg, _ = _api(parallel, gate=hold)
+
+    async def main():
+        streams = [asyncio.create_task(_stream(api, cfg))
+                   for _ in range(parallel)]
+        try:
+            for _ in range(parallel):
+                assert await asyncio.to_thread(opened.acquire, True, WAIT_S)
+            assert api._gate(cfg).streams_open == parallel
+            t0 = time.monotonic()
+            assert await asyncio.wait_for(
+                asyncio.to_thread(lambda: 7), 2.0) == 7
+            return time.monotonic() - t0
+        finally:
+            release.set()
+            assert await asyncio.gather(*streams) == [2] * parallel
+            await _close(api)
+
+    assert _run(main) < 1.0
+
+
+def test_a_stream_the_client_closes_frees_its_thread_and_cancels_its_call():
+    """`parallel: 1` is one pump thread: a stream closed after its first
+    chunk (what a client's disconnect does) must cancel its RPC and give
+    the thread back, or the next stream never starts."""
+    def until_cancelled(call):
+        if len(handle.calls) == 1:
+            return
+        # the second stream got the one thread: the first let go of it
+        assert handle.calls[0].cancelled.is_set()
+
+    api, cfg, handle = _api(1, gate=until_cancelled, chunks=1000)
+
+    async def main():
+        try:
+            async with api._admit(cfg):
+                stream = api._stream_rpc(cfg, {})
+                reply = await stream.__anext__()
+                assert list(reply.token_ids) == [7]
+                assert api._gate(cfg).streams_open == 1
+                await stream.aclose()
+            assert handle.calls[0].cancelled.is_set()
+            assert api._gate(cfg).streams_open == 0
+            handle._chunks = 3
+            assert await _stream(api, cfg) == 3
+            assert handle.calls[1].thread == handle.calls[0].thread
+        finally:
+            await _close(api)
+
+    _run(main)
+
+
+def test_shutdown_leaves_no_pump_thread():
+    api, cfg, _ = _api(6)
+
+    async def main():
+        await asyncio.gather(*(_stream(api, cfg) for _ in range(6)))
+        assert _pump_threads()
+        await _close(api)
+
+    _run(main)
+    for t in _pump_threads():
+        t.join(WAIT_S)
+    assert not [t.name for t in _pump_threads() if t.is_alive()]
+
+
+def test_stream_start_is_observed_once_a_stream_and_streams_open_returns():
+    """`hist_stream_start` (permit -> pump thread running) has one
+    observation a stream, 0 included, a retried stream's too; `streams_open`
+    is the gate's count of open streams and ends at 0. Both ride the model's
+    metrics under the flat keys the backend's use."""
+    api, cfg, handle = _api(4)
+    real = handle.predict_stream
+    failed = []
+
+    def flaky(**opts):
+        call = real(**opts)
+        if not failed:          # the first RPC dies before any chunk
+            failed.append(call)
+            call._gate = lambda c: c.cancel()
+        return call
+
+    handle.predict_stream = flaky
+    api.manager.classify_failure = lambda h, e: (True, e)
+
+    async def main():
+        try:
+            assert api._gate_metrics("m") == {}     # no gate yet
+            assert await asyncio.gather(
+                *(_stream(api, cfg) for _ in range(9))) == [2] * 9
+            return api._gate_metrics("m")
+        finally:
+            await _close(api)
+
+    m = _run(main)
+    assert len(handle.calls) == 10 and api.manager.events[("m", "stream_retry")] == 1
+    assert m["hist_stream_start__all__count"] == 9
+    assert 0 <= m["hist_stream_start__all__sum"] < 9 * LIMIT_S
+    assert m["hist_gate_wait__all__count"] == 9
+    assert m["streams_open"] == 0

@@ -351,6 +351,9 @@ def test_gate_wait_and_counters_in_backend_monitor_and_metrics(traced_stack):
     m = mon["tiny"]["metrics"]
     assert m["hist_gate_wait__all__count"] >= 1      # a wait of 0 counts
     assert m["hist_gate_wait__all__sum"] >= 0
+    # the gate's stream counters ride the same map (no stream has run here)
+    assert m["hist_stream_start__all__count"] >= 0
+    assert m["streams_open"] == 0
     # one observation per request that passed the gate, as for the engine's
     # stages (every request here got its first token)
     for stage in ("queue_wait", "admit_to_join", "join_to_first"):
@@ -369,6 +372,8 @@ def test_gate_wait_and_counters_in_backend_monitor_and_metrics(traced_stack):
 
     prom = requests.get(base + "/metrics", timeout=60).text
     assert 'localai_request_gate_wait_seconds_count{model="tiny"' in prom
+    assert 'localai_request_stream_start_seconds_count{model="tiny"' in prom
+    assert 'localai_streams_open{model="tiny"} 0.0' in prom
     assert 'localai_request_join_to_first_seconds_bucket' in prom
     assert 'localai_engine_phase_seconds_total{kind="wait",model="tiny",' \
            'phase="device"}' in prom
