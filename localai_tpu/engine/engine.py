@@ -45,6 +45,7 @@ from localai_tpu.models.llama import (
     extend,
     init_kv_cache,
     prefill,
+    rope_tables,
 )
 from localai_tpu.ops.rope import rope_table
 from localai_tpu.ops.sampling import (
@@ -411,6 +412,24 @@ class Engine:
         # block-granular (cache_shift_paged); speculative decoding pages
         # the TARGET cache (the small draft keeps a dense one).
         self._paged = self.ec.kv_pages > 0
+        # window and full attention layers in one model: two kinds of cache
+        # (models/llama.py PeriodKV). What knows one cache per layer stack
+        # refuses such a model here, at load, by name.
+        self._mixed = cfg.layer_types is not None
+        if self._mixed and self._paged:
+            raise ValueError(
+                "a model with window and full attention layers "
+                "(layer_types) cannot be served with paged KV (kv_pages > "
+                "0), nor with what rests on it: ragged batching "
+                "(ragged_token_budget), kv_policy windows, the host KV tier "
+                "(kv_host_bytes) and its resume: the block pool holds one "
+                "kind of cache. Serve it with kv_pages: 0")
+        if self._mixed and self._draft is not None:
+            raise ValueError(
+                "a model with window and full attention layers "
+                "(layer_types) cannot be served with speculative decoding "
+                "(a draft model): the verify window writes ahead into a "
+                "ring it may have to take back")
         if self._paged:
             if self.ec.kv_pages < 2:
                 raise ValueError("kv_pages must be >= 2 (block 0 is trash)")
@@ -523,6 +542,12 @@ class Engine:
         if self.ec.prefill_chunk < 8:
             raise ValueError("prefill_chunk must be >= 8")
         self._chunk = min(self.ec.prefill_chunk, self.ec.max_context)
+        if self._mixed and self._chunk < self.ec.decode_block:
+            raise ValueError(
+                "a model with window layers needs prefill_chunk >= "
+                "decode_block: a grammar rollback (_repair) takes back up to "
+                "a block of tokens, which their rings (window + "
+                "prefill_chunk) must not have overwritten the window with")
         small = tuple(b for b in self.ec.prefill_buckets if b <= self._chunk)
         dropped = tuple(b for b in self.ec.prefill_buckets if b > self._chunk)
         if dropped:
@@ -612,6 +637,21 @@ class Engine:
         if self._draft is not None:
             self.metrics["draft_proposed"] = 0
             self.metrics["draft_accepted"] = 0
+        if self._mixed:
+            # two kinds of cache: how many layers and bytes (K and V) each
+            # kind holds (gauges), and the context tokens ONE layer of each
+            # kind attended over in the decode dispatches consumed so far
+            # (_credit_consumed): their ratio is what the window saves
+            kinds = cfg.period
+            for kind in set(kinds):
+                self.metrics[f"layers__{kind}"] = (
+                    cfg.layer_types.count(kind))
+                self.metrics[f"kv_bytes__{kind}"] = sum(
+                    leaf.nbytes
+                    for cache in (self._kc, self._vc)
+                    for slot, k in zip(cache.slots, kinds) if k == kind
+                    for leaf in jax.tree_util.tree_leaves(slot))
+                self.metrics[f"decode_ctx_tokens__{kind}"] = 0
         if self._ragged:
             # token-budget utilization = ragged_tokens_packed /
             # (ragged_dispatches * ragged rows) — how full the flat stream
@@ -753,8 +793,7 @@ class Engine:
                               # every live slot in one tick)
 
         with activate_mesh(self.mesh):
-            cos, sin = rope_table(cfg.rope, T)
-            self._cos, self._sin = cos, sin
+            self._cos, self._sin = rope_tables(cfg, T)
             if self._paged:
                 from localai_tpu.ops.paged import init_paged
 
@@ -771,7 +810,8 @@ class Engine:
                         cache_type="int8")
             else:
                 self._kc, self._vc = init_kv_cache(
-                    cfg, B, T, dtype, cache_type=self.ec.cache_type)
+                    cfg, B, T, dtype, cache_type=self.ec.cache_type,
+                    prefill_chunk=min(self.ec.prefill_chunk, T))
             if self.mesh is not None and jax.process_count() == 1:
                 # pre-place the KV state under its serving sharding (slots
                 # on 'data', KV heads on 'model'; paged pool: block axis
@@ -1071,15 +1111,28 @@ class Engine:
 
                 self._spec_ragged_fn = jax.jit(
                     _specr, donate_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
+        def named(fn, name: str):
+            # jax.jit names a functools.partial's program `jit__unknown`. A
+            # model with window and full layers spends whole seconds in
+            # single decode steps between prefill chunks, and a device trace
+            # of those has to find them (benchmark/programs/decode.json
+            # looks for jit__decode*): there the partial takes the name of
+            # what it wraps. A model with one kind of layer keeps the
+            # programs, names and compile-cache keys it had.
+            if cfg.period:
+                fn.__name__ = name
+            return fn
+
         self._decode_fn = jax.jit(_decode, donate_argnums=(3, 4, 5, 6, 7),
                                   static_argnames=())
         self._decode_nomask_fn = jax.jit(
-            partial(_decode, mask_bits=None), donate_argnums=(3, 4, 5, 6, 7))
+            named(partial(_decode, mask_bits=None), "_decode"),
+            donate_argnums=(3, 4, 5, 6, 7))
         # fast_width static → one compiled variant per width (the base
         # width plus the 8x escalation tier: one wide-top_k tenant no
         # longer de-optimizes the whole batch to the full-sort path)
         self._decode_fast_fn = jax.jit(
-            partial(_decode, mask_bits=None),
+            named(partial(_decode, mask_bits=None), "_decode"),
             donate_argnums=(3, 4, 5, 6, 7),
             static_argnames=("fast_width",))
 
@@ -1110,7 +1163,7 @@ class Engine:
             return toks, lps, kc, vc, sampler, last_logits, lengths
 
         self._decode_block_fn = jax.jit(
-            partial(_decode_block, mask_bits=None),
+            named(partial(_decode_block, mask_bits=None), "_decode_block"),
             donate_argnums=(3, 4, 5, 6, 7),
             static_argnames=("steps", "fast_width"))
         self._decode_block_mask_fn = jax.jit(
@@ -2212,6 +2265,11 @@ class Engine:
             if len(pos) > 1 and (np.diff(pos) <= 0).any():
                 raise ValueError("mm_positions must be strictly increasing")
             req.mm_embeds, req.mm_positions = emb, pos
+        if req.context_shift and self._mixed:
+            raise ValueError(
+                "context_shift is not supported for a model with window and "
+                "full attention layers (cache_shift moves one full-length "
+                "cache, not a ring)")
         if req.context_shift and self._draft is not None:
             raise ValueError(
                 "context_shift is not supported with a draft model "
@@ -2857,7 +2915,7 @@ class Engine:
         whose whole budget is already in flight sits this dispatch out (the
         device would run it zero steps anyway)."""
         G = (self.ec.ragged_loop_steps if self._ragged_loop_fn is not None
-             else self.ec.decode_loop)
+             else self._loop_steps)
         B = self.ec.max_slots
         remaining = np.zeros((B,), np.int32)
         check_eos = np.zeros((B,), bool)
@@ -2868,7 +2926,7 @@ class Engine:
             if rem <= 0:
                 active[i] = False
                 continue
-            remaining[i] = rem
+            remaining[i] = min(rem, G) if self._mixed else rem
             check_eos[i] = self.tok is not None and not s.req.ignore_eos
             live.append((i, rid))
         if not live:
@@ -2955,11 +3013,32 @@ class Engine:
         m["host_sync_wait_ms"] += m["engine_wait_ms__device"] - waited
         return out
 
-    def _credit_consumed(self, steps: int):
+    def _credit_consumed(self, steps: int, entries=(), n_out=None):
         """One dispatch's results are on the host: credit it and the steps
-        the device ran in it, together."""
+        the device ran in it, together. For a model with window and full
+        layers also the context its live rows (`entries`, each with
+        `n_out[i]` steps, or all `steps`) attended over, in one layer of each
+        kind: a row that stood at n tokens attends n + 1, n + 2, ... in a
+        full layer and min(that, window) in a window layer. Call it BEFORE
+        the dispatch's tokens are emitted (`generated` is then what it was
+        at dispatch)."""
         self.metrics["decode_dispatches_consumed"] += 1
         self.metrics["decode_steps_consumed"] += steps
+        if not self._mixed:
+            return
+        window = self.cfg.sliding_window
+        full = win = 0
+        for i, rid in entries:
+            slot = self._slots[i]
+            if slot is None or slot.request_id != rid:
+                continue
+            n = steps if n_out is None else int(n_out[i])
+            lo = slot.prompt_len + slot.generated
+            k = min(n, max(window - lo, 0))     # steps not yet a window long
+            full += n * lo + n * (n + 1) // 2
+            win += k * lo + k * (k + 1) // 2 + (n - k) * window
+        self.metrics["decode_ctx_tokens__full"] += full
+        self.metrics["decode_ctx_tokens__window"] += win
 
     def _mark_join(self, entries):
         """Stamp the slots this decode dispatch is the first to carry, just
@@ -3026,7 +3105,7 @@ class Engine:
             tokens, logprobs, n_out, steps = out
         steps = int(steps)
         self.metrics["decode_steps_dispatched"] += steps
-        self._credit_consumed(steps)
+        self._credit_consumed(steps, entries, n_out)
         self._release_reservations(entries, res)
         now = time.monotonic()
         if self._slo is not None:
@@ -3062,7 +3141,7 @@ class Engine:
         if tokens.ndim == 1:
             tokens, logprobs = tokens[None], logprobs[None]
         steps = tokens.shape[0]
-        self._credit_consumed(steps)
+        self._credit_consumed(steps, entries)
         if self._slo is not None:
             for i, rid in entries:
                 s = self._slots[i]
@@ -4163,6 +4242,9 @@ class Engine:
         if self.ec.prompt_cache and self._draft is None:
             for s in self._free:
                 lcp = common(self._slot_kv_tokens[s])
+                if self._mixed and not self._ring_holds(
+                        lcp, len(self._slot_kv_tokens[s])):
+                    lcp = 0
                 if lcp > best_lcp:
                     best_slot, best_lcp = s, lcp
         if best_slot is not None and best_lcp >= self.ec.prompt_cache_min:
@@ -4176,6 +4258,20 @@ class Engine:
         self._free.remove(cold)
         return cold, 0
 
+    def _ring_holds(self, lcp: int, cached: int) -> bool:
+        """Whether a slot whose last tenant left `cached` tokens can lend a
+        new one their first `lcp`: the window layers' rings (models/llama.py
+        ring_len) must still hold the window the next query looks back over,
+        positions lcp - window + 1 .. lcp - 1. Position p is gone once
+        p + ring has been written, and the device may have written past what
+        the host counts: the dispatches in flight when a request ends (up to
+        two of decode_loop or decode_block steps). Else the prompt is
+        prefilled from 0, never over a stale ring."""
+        ring = self._kc.slots[self.cfg.period.index("window")].shape[3]
+        written = cached + 2 * max(self.ec.decode_loop, self.ec.decode_block,
+                                   1)
+        return max(lcp - self.cfg.sliding_window + 1, 0) + ring >= written
+
     # --------------------------------------------- disk prompt cache
     # (reference PromptCachePath/PromptCacheAll/PromptCacheRO — llama.cpp
     # persists a prompt's KV to a file and restores it across restarts)
@@ -4184,7 +4280,9 @@ class Engine:
         """Restore a saved KV prefix into `slot` if the file's tokens prefix
         this prompt. Returns the reusable length (0 = cold)."""
         if (not self._cache_addressable or self._draft is not None
-                or self._paged):
+                or self._paged or self._mixed):
+            # (mixed: the file holds one [L, ...] cache; the prompt is
+            # prefilled instead)
             return 0
         try:
             with np.load(req.prompt_cache_path, allow_pickle=False) as z:
@@ -4230,7 +4328,8 @@ class Engine:
         cache file (skipped for RO requests, meshes, shifted slots)."""
         if (not slot.req.prompt_cache_path or slot.req.prompt_cache_ro
                 or not self._cache_addressable or self._draft is not None
-                or self._paged or slot.shifted or not slot.prefilled
+                or self._paged or self._mixed or slot.shifted
+                or not slot.prefilled
                 or slot.req.mm_embeds is not None):
             # (mm: no reuse path can load it, and the repeated image-token
             # ids could positionally match a text prompt — see _release_slot)
@@ -4896,3 +4995,19 @@ class Engine:
 
     def generate_text(self, req: GenRequest) -> str:
         return "".join(o.text for o in self.generate(req))
+
+    @property
+    def _loop_steps(self) -> int:
+        """Steps one fused loop may run: the budget `_dispatch_loop` gives
+        each row inside the decode_loop program, no other program. A loop's
+        tokens reach their streams when it ends, and a row that finishes
+        inside it keeps its slot until then. A model with window and full
+        layers takes ~36 ms a step on a v5e (PERF.md section 5): 64 steps
+        held every stream 2.3 s and handed the clients 64 x rows tokens at
+        once, so its loops are a decode_block long. A one-kind model keeps
+        decode_loop: shortening its loops is for a PR that re-measures the
+        cells it is held to. (Down here so that no line above moves: a
+        kernel's compile-cache key holds its callers' line numbers.)"""
+        if self._mixed and 1 < self.ec.decode_block < self.ec.decode_loop:
+            return self.ec.decode_block
+        return self.ec.decode_loop

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from localai_tpu.models.llama import LlamaConfig, param_specs
+from localai_tpu.models.llama import FULL, WINDOW, LlamaConfig, param_specs
 
 # HF architectures the Llama-family decoder covers (SURVEY §2.2 row 1 scope).
 LLAMA_FAMILY = {
@@ -30,7 +30,35 @@ LLAMA_FAMILY = {
     "MixtralForCausalLM": {"moe": True},
     "Qwen2ForCausalLM": {"qkv_bias": True},
     "TinyLlamaForCausalLM": {},
+    # window and full attention layers in one model, each kind its own RoPE,
+    # sparse experts of moe_intermediate_size (Mellum2)
+    "MellumForCausalLM": {"moe": True},
 }
+# config.json files that name no architecture
+_ARCH_OF_MODEL_TYPE = {"mellum": "MellumForCausalLM"}
+_LAYER_KINDS = {"full_attention": FULL, "sliding_attention": WINDOW}
+
+
+def _rope_fields(rs: dict | None, theta: float, max_position: int) -> dict:
+    """LlamaConfig's rope_* fields from one HF rope_scaling/rope_parameters
+    dict (either may carry its own rope_theta)."""
+    rs = rs if isinstance(rs, dict) else {}
+    kw: dict[str, Any] = {"rope_base": rs.get("rope_theta", theta)}
+    rope_type = rs.get("rope_type", rs.get("type"))
+    if rope_type in (None, "default"):
+        return kw
+    kw["rope_scaling"] = rope_type
+    kw["rope_scale_factor"] = rs.get("factor", 1.0)
+    kw["rope_original_max_position"] = rs.get(
+        "original_max_position_embeddings", max_position)
+    if rope_type == "llama3":
+        kw["rope_low_freq_factor"] = rs.get("low_freq_factor", 1.0)
+        kw["rope_high_freq_factor"] = rs.get("high_freq_factor", 4.0)
+    if rope_type == "yarn":
+        kw["rope_beta_fast"] = rs.get("beta_fast", 32.0)
+        kw["rope_beta_slow"] = rs.get("beta_slow", 1.0)
+        kw["rope_attn_factor"] = rs.get("attention_factor")
+    return kw
 
 
 def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
@@ -39,7 +67,9 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
     with open(os.path.join(model_dir, "config.json")) as f:
         hf: dict[str, Any] = json.load(f)
 
-    arch = (hf.get("architectures") or ["LlamaForCausalLM"])[0]
+    arch = (hf.get("architectures")
+            or [_ARCH_OF_MODEL_TYPE.get(hf.get("model_type"),
+                                        "LlamaForCausalLM")])[0]
     if hf.get("model_type") == "llava" or arch.startswith("Llava"):
         # vision-language checkpoint: the language side is a plain
         # Llama-family config nested under text_config (the vision side
@@ -57,6 +87,7 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
     num_heads = hf["num_attention_heads"]
     head_dim = hf.get("head_dim") or hf["hidden_size"] // num_heads
 
+    max_position = hf.get("max_position_embeddings", 8192)
     kw: dict[str, Any] = dict(
         vocab_size=hf["vocab_size"],
         hidden_size=hf["hidden_size"],
@@ -65,36 +96,66 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
         num_heads=num_heads,
         num_kv_heads=hf.get("num_key_value_heads", num_heads),
         head_dim=head_dim,
-        max_position=hf.get("max_position_embeddings", 8192),
+        max_position=max_position,
         rms_eps=hf.get("rms_norm_eps", 1e-5),
-        rope_base=hf.get("rope_theta", 10000.0),
         tie_embeddings=hf.get("tie_word_embeddings", False),
         sliding_window=hf.get("sliding_window"),
         qkv_bias=hf.get("attention_bias", extra.get("qkv_bias", False)),
     )
-    if extra.get("moe") or hf.get("num_local_experts"):
-        kw["num_experts"] = hf.get("num_local_experts", 8)
+    experts = hf.get("num_experts", hf.get("num_local_experts"))
+    mlp_kinds = set(hf.get("mlp_layer_types") or ())
+    if mlp_kinds - {"sparse", "dense"} or len(mlp_kinds) > 1:
+        raise ValueError(
+            f"mlp_layer_types {sorted(mlp_kinds)}: the layer stack is one "
+            "scan over one kind of MLP, so every entry must be 'sparse' or "
+            "every entry 'dense'")
+    if mlp_kinds == {"dense"}:
+        experts = None
+    if (extra.get("moe") or experts) and mlp_kinds != {"dense"}:
+        if hf.get("norm_topk_prob") is False:
+            raise ValueError(
+                "norm_topk_prob: false is not supported: the expert layer "
+                "renormalises the top-k router weights (models/llama.py "
+                "_moe_mlp), and would silently compute another model")
+        kw["num_experts"] = experts or 8
         kw["experts_per_tok"] = hf.get("num_experts_per_tok", 2)
+        kw["moe_intermediate_size"] = hf.get("moe_intermediate_size")
     if dtype is not None:
         # int8 = weight quantization; activations/KV stay bf16
         kw["dtype"] = ("bfloat16" if dtype in ("int8", "q8", "int4", "q4")
                        else dtype)
 
+    kinds = hf.get("layer_types")
+    if kinds:
+        unknown = set(kinds) - set(_LAYER_KINDS)
+        if unknown or len(kinds) != kw["num_layers"]:
+            raise ValueError(
+                f"layer_types: {kw['num_layers']} entries of "
+                f"{sorted(_LAYER_KINDS)} expected, got {len(kinds)} with "
+                f"{sorted(unknown)}")
+        if hf.get("use_sliding_window") is False:
+            kinds = ["full_attention"] * len(kinds)
+    # one kind of layer is the one-kind path: all full has no window, all
+    # windowed is Mistral's (a full-length cache under the window mask)
+    one_kind = next(iter(set(kinds))) if kinds and len(set(kinds)) == 1 \
+        else None
+    if one_kind == "full_attention":
+        kw["sliding_window"] = None
+
+    theta = hf.get("rope_theta", 10000.0)
     rs = hf.get("rope_scaling") or hf.get("rope_parameters") or None
-    if rs and isinstance(rs, dict) and rs.get("rope_type", rs.get("type")) not in (None, "default"):
-        rope_type = rs.get("rope_type", rs.get("type"))
-        kw["rope_scaling"] = rope_type
-        kw["rope_scale_factor"] = rs.get("factor", 1.0)
-        kw["rope_original_max_position"] = rs.get(
-            "original_max_position_embeddings", kw["max_position"]
-        )
-        if rope_type == "llama3":
-            kw["rope_low_freq_factor"] = rs.get("low_freq_factor", 1.0)
-            kw["rope_high_freq_factor"] = rs.get("high_freq_factor", 4.0)
-        if rope_type == "yarn":
-            kw["rope_beta_fast"] = rs.get("beta_fast", 32.0)
-            kw["rope_beta_slow"] = rs.get("beta_slow", 1.0)
-            kw["rope_attn_factor"] = rs.get("attention_factor")
+    if isinstance(rs, dict) and any(k in rs for k in _LAYER_KINDS):
+        # rope_parameters keyed by layer type: one RoPE per kind
+        kw.update(_rope_fields(rs.get(one_kind or "full_attention"), theta,
+                               max_position))
+        if kinds and not one_kind:
+            kw["window_rope"] = LlamaConfig(
+                head_dim=head_dim, **_rope_fields(
+                    rs.get("sliding_attention"), theta, max_position)).rope
+    else:
+        kw.update(_rope_fields(rs, theta, max_position))
+    if kinds and not one_kind:
+        kw["layer_types"] = tuple(_LAYER_KINDS[k] for k in kinds)
     return LlamaConfig(**kw)
 
 
@@ -374,7 +435,8 @@ def _synthetic_params(cfg: LlamaConfig, *, dtype, mesh=None, qbits=None,
 
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, L, inter = (cfg.num_heads, cfg.num_kv_heads, cfg.num_layers,
-                         cfg.intermediate_size)
+                         cfg.expert_width if cfg.num_experts
+                         else cfg.intermediate_size)
     # the RBG generator, not threefry: an 8B model draws 8 G elements here,
     # and on a v5e threefry took 215 s for them and (drawing int32, as
     # randint does whatever dtype is asked for) peaked the load at 14.5 GB

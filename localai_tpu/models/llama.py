@@ -18,6 +18,7 @@ Design (TPU-first, not a torch translation):
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from functools import partial
 
@@ -34,6 +35,9 @@ from localai_tpu.ops.kvcache import (
 )
 from localai_tpu.ops.quant import qmatmul
 from localai_tpu.parallel.mesh import constrain
+
+
+FULL, WINDOW = "full", "window"     # LlamaConfig.layer_types entries
 
 
 @dataclasses.dataclass(frozen=True)
@@ -61,7 +65,53 @@ class LlamaConfig:
     sliding_window: int | None = None   # Mistral
     num_experts: int = 0                # Mixtral MoE (0 = dense MLP)
     experts_per_tok: int = 2
+    # expert width where it is not intermediate_size (moe_intermediate_size)
+    moe_intermediate_size: int | None = None
+    # window and full attention layers in one model (Mellum2): one of
+    # FULL / WINDOW per layer. None = every layer alike (sliding_window, if
+    # set, then applies to all of them over a full-length cache, as Mistral).
+    # WINDOW layers attend over sliding_window tokens, hold a ring cache
+    # (init_kv_cache) and rotate with window_rope; FULL layers with `rope`.
+    layer_types: tuple[str, ...] | None = None
+    window_rope: RopeConfig | None = None
     dtype: str = "bfloat16"
+
+    def __post_init__(self):
+        if self.layer_types is None:
+            return
+        kinds = tuple(self.layer_types)
+        object.__setattr__(self, "layer_types", kinds)
+        if len(kinds) != self.num_layers or set(kinds) - {FULL, WINDOW}:
+            raise ValueError(
+                f"layer_types needs {self.num_layers} entries of "
+                f"{FULL!r}/{WINDOW!r}, got {kinds}")
+        if len(set(kinds)) == 1:
+            raise ValueError(
+                "layer_types with one kind of layer: leave it None (and set "
+                "sliding_window for an all-window model)")
+        if not self.sliding_window or self.sliding_window < 1:
+            raise ValueError("window layers need a sliding_window")
+
+    @property
+    def expert_width(self) -> int:
+        return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def period(self) -> tuple[str, ...] | None:
+        """The shortest run of layer kinds that repeats to give layer_types
+        (the body of the layer scan); None for a one-kind model."""
+        kinds = self.layer_types
+        if kinds is None:
+            return None
+        n = len(kinds)
+        for p in range(1, n + 1):
+            if n % p == 0 and kinds == kinds[:p] * (n // p):
+                return kinds[:p]
+
+    def rope_of(self, kind: str | None) -> RopeConfig:
+        if kind == WINDOW and self.window_rope is not None:
+            return self.window_rope
+        return self.rope
 
     @property
     def rope(self) -> RopeConfig:
@@ -90,6 +140,8 @@ def init_params(cfg: LlamaConfig, key, dtype=None):
     dtype = dtype or cfg.jdtype
     h, hd = cfg.hidden_size, cfg.head_dim
     nh, nkv, L, I = cfg.num_heads, cfg.num_kv_heads, cfg.num_layers, cfg.intermediate_size
+    if cfg.num_experts:
+        I = cfg.expert_width
     ks = jax.random.split(key, 10)
 
     def norm(k, shape, fan_in):
@@ -235,8 +287,32 @@ def paged_pool_spec():
     return P(None, None, "model", None, None)
 
 
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class PeriodKV:
+    """K (or V) of a model with window and full layers: one cache per place
+    in the period of layer kinds, `slots[j]` of [L/period, B, KVH, T_j, D]
+    (dense or QuantKV) — T_j the served context for a FULL layer, the ring
+    for a WINDOW one. The layer scan takes one row of each per iteration and
+    gives it back, as it does with the one [L, ...] cache of a one-kind
+    model, so every layer's update stays in place."""
+    slots: tuple
+
+
+def ring_len(cfg: LlamaConfig, max_len: int, prefill_chunk: int,
+             cache_type: str = "") -> int:
+    """Tokens a WINDOW layer's ring holds per slot: the window plus one
+    prefill chunk (extend writes a chunk before its queries read the window
+    behind them), rounded up to the int8 scale tile, and never more than a
+    full-length cache would be."""
+    quant = is_quant_kind(cache_type)
+    ring = cfg.sliding_window + prefill_chunk
+    full = padded_len(max_len) if quant else max_len
+    return min(padded_len(ring) if quant else ring, full)
+
+
 def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
-                  cache_type: str = ""):
+                  cache_type: str = "", prefill_chunk: int | None = None):
     """Head-major cache [L, B, KVH, T, D] — trailing (T, D) dims are the
     Mosaic-legal tiling for the Pallas decode kernel, and the decode hot path
     reads it with zero transposes.
@@ -245,14 +321,32 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
     /root/reference/backend/backend.proto:257-258) stores int8 + per-token
     scales (ops/kvcache.py) at half the HBM; the token axis is then padded to
     the 128 scale tile (extra rows are never read — lengths mask them).
+
+    A model with layer_types gets a PeriodKV pair instead: FULL layers at
+    max_len, WINDOW layers at ring_len (`prefill_chunk` is then required: the
+    longest window `extend` will be given).
     """
-    if is_quant_kind(cache_type):
-        shape = (cfg.num_layers, batch, cfg.num_kv_heads,
-                 padded_len(max_len), cfg.head_dim)
-        return init_quant(shape), init_quant(shape)
+    quant = is_quant_kind(cache_type)
     dtype = dtype or cfg.jdtype
-    shape = (cfg.num_layers, batch, cfg.num_kv_heads, max_len, cfg.head_dim)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+    def one(layers, t):
+        shape = (layers, batch, cfg.num_kv_heads,
+                 padded_len(t) if quant else t, cfg.head_dim)
+        if quant:
+            return init_quant(shape), init_quant(shape)
+        return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
+
+    if cfg.layer_types is None:
+        return one(cfg.num_layers, max_len)
+    if prefill_chunk is None:
+        raise ValueError("a model with window layers sizes their ring from "
+                         "prefill_chunk")
+    ring = ring_len(cfg, max_len, prefill_chunk, cache_type)
+    period = cfg.period
+    pairs = [one(cfg.num_layers // len(period),
+                 ring if kind == WINDOW else max_len) for kind in period]
+    return (PeriodKV(tuple(k for k, _ in pairs)),
+            PeriodKV(tuple(v for _, v in pairs)))
 
 
 # jax.named_scope below names the model's parts in the XLA ops' metadata
@@ -265,7 +359,7 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
 # (ragged_decode_q8, flash_prefill, paged_scatter_append).
 @jax.named_scope("cache_update")
 def _cache_write(kc, vc, k, v, rows, positions, table=None, unique=True,
-                 redirect=None, kvt=None):
+                 redirect=None, kvt=None, ring_keep=None):
     """Scatter window K/V [B, S, KVH, D] into head-major caches [B', KVH, T, D]
     at (rows[b], :, positions[b, s]). With a paged `table` [B, MAXB] the cache
     is a block pool [NB, KVH, BS, D] and (slot, position) resolves to
@@ -298,9 +392,18 @@ def _cache_write(kc, vc, k, v, rows, positions, table=None, unique=True,
     Full-policy slots carry the identity sentinel — same program, no
     recompile across policy mixes. Uniqueness survives the mapping: the
     ring's wrap period (rw*BLOCK tokens) exceeds any single write window
-    by construction (kvtier.ring_blocks margins)."""
+    by construction (kvtier.ring_blocks margins).
+
+    ring_keep [B, S] bool (dense only): the cache is a WINDOW layer's ring of
+    R = T rows, position p lives in row p mod R, and an entry that is not
+    kept (an inactive decode row, a prompt's padding, what a prompt longer
+    than the ring has before its tail) is aimed at row R: out of bounds,
+    which a scatter drops. A ring has no spare row to take such writes."""
     kvh = kc.shape[1]
     if table is None:
+        if ring_keep is not None:
+            ring = kc.shape[2]
+            positions = jnp.where(ring_keep, positions % ring, ring)
         idx = (rows[:, None, None], jnp.arange(kvh)[None, :, None],
                positions[:, None, :])
     else:
@@ -490,7 +593,7 @@ def _tiered_kv(kc, vc, table_rows, sb, rw, length, ctab=None, ck=None,
 
 
 def _decode_dq(q, kc, vc, lengths, sliding_window=None, table=None,
-               kvt=None, ck=None, cv=None):
+               kvt=None, ck=None, cv=None, ring=False):
     """XLA decode attention over a (possibly quantized) cache: dequant is
     fused into the consuming dots by XLA; quantized caches still halve HBM
     capacity on this path. A paged cache is materialized per layer via
@@ -515,6 +618,14 @@ def _decode_dq(q, kc, vc, lengths, sliding_window=None, table=None,
             mask = ok & ((pos >= (lengths - kvt["window"])[:, None])
                          | (pos < kvt["sinks"][:, None]))
         return mha_decode_masked(q, k, v, mask)
+    if ring:
+        # a WINDOW layer's ring: row p mod R holds position p, so the rows
+        # in the window are those at most window - 1 behind the newest
+        from localai_tpu.ops.attention import mha_decode_masked
+
+        mask = (_ring_back(lengths - 1, kc.shape[2])
+                < jnp.minimum(lengths, sliding_window)[:, None])
+        return mha_decode_masked(q, dequant(kc), dequant(vc), mask)
     if table is not None:
         from localai_tpu.ops.paged import paged_view
 
@@ -613,7 +724,7 @@ def _attn_impls():
         )
 
         def attn_decode(q, kc, vc, lengths, sliding_window=None, table=None,
-                        kvt=None, ck=None, cv=None):
+                        kvt=None, ck=None, cv=None, ring=False):
             if kvt is not None:
                 # KV lifecycle tier: the ring-position/tier-map read rides
                 # the XLA reference path for now — the Pallas decode kernel
@@ -627,9 +738,10 @@ def _attn_impls():
             if isinstance(kc, QuantKV):
                 return ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths,
                                         sliding_window=sliding_window,
-                                        table=table)
+                                        table=table, ring=ring)
             return ragged_decode(q, kc, vc, lengths,
-                                 sliding_window=sliding_window, table=table)
+                                 sliding_window=sliding_window, table=table,
+                                 ring=ring)
 
         return (lambda q, k, v, lengths, sliding_window=None:
                 flash_prefill(q, k, v, lengths, sliding_window=sliding_window),
@@ -645,6 +757,95 @@ def _attn_impls():
                              sliding_window=sliding_window),
                 _decode_dq)
     return mha_prefill, _decode_dq
+
+
+def rope_tables(cfg: LlamaConfig, max_len: int):
+    """The (cos, sin) the forwards take: one pair of tables for a one-kind
+    model; for a model with layer_types a pair of dicts keyed by layer kind
+    (window and full layers rotate differently)."""
+    if cfg.layer_types is None:
+        return rope_table(cfg.rope, max_len)
+    tabs = {kind: rope_table(cfg.rope_of(kind), max_len)
+            for kind in (FULL, WINDOW)}
+    return ({kind: t[0] for kind, t in tabs.items()},
+            {kind: t[1] for kind, t in tabs.items()})
+
+
+def _layer_rope(cos, sin, kind):
+    return (cos, sin) if kind is None else (cos[kind], sin[kind])
+
+
+def _layer_window(cfg: LlamaConfig, kind):
+    """The attention window of a layer of `kind` (None: a one-kind model)."""
+    return None if kind == FULL else cfg.sliding_window
+
+
+def _attn_scope(kind):
+    """Device time by layer kind: a mixed model's attention (projections,
+    kernel and all: it has no older compile-cache key to keep) is traced
+    under attention/<kind>; a one-kind model's as it always was."""
+    return (contextlib.nullcontext() if kind is None
+            else jax.named_scope(f"attention/{kind}"))
+
+
+def _ring_back(newest, ring: int):
+    """[B, R]: how many positions behind `newest` [B] the entry in each row
+    of a ring of R rows is (row p mod R holds position p)."""
+    return jnp.mod(newest[:, None] - jnp.arange(ring)[None, :], ring)
+
+
+def _scan_layers(cfg: LlamaConfig, body, x, layers, k_cache=None,
+                 v_cache=None, extra=()):
+    """Run `body(x, lp, kc, vc, kind, *extra) -> (x, (kc, vc))` over the
+    layer stack and return (x, (k_cache, v_cache)).
+
+    One kind of layer: lax.scan over [L, ...] with kind None, the program a
+    one-kind model always had. With layer_types the scan's body is one
+    PERIOD of kinds, unrolled, over a PeriodKV's per-place caches: compile
+    time grows with the period, not the depth, and each layer still updates
+    its own cache row in place."""
+    period = cfg.period
+    if period is None:
+        def layer(x, xs):
+            lp, kc, vc, *ex = xs
+            return body(x, lp, kc, vc, None, *ex)
+
+        return jax.lax.scan(layer, x, (layers, k_cache, v_cache, *extra))
+    if extra:
+        raise NotImplementedError("layer_types with per-layer extras")
+    p = len(period)
+    cached = k_cache is not None
+    ks = k_cache.slots if cached else (None,) * p
+    vs = v_cache.slots if cached else (None,) * p
+
+    def step(x, xs):
+        i, kcs, vcs = xs
+        ko, vo = [], []
+        for j, kind in enumerate(period):
+            # one layer's weights, sliced out of the [L, ...] stack where
+            # they are used, as scan slices its xs (a [period, ...] slice of
+            # a folded stack is copied whole every iteration: 1.6 GB of
+            # experts a period at Mellum2's widths)
+            lpj = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(
+                    a, i * p + j, keepdims=False), layers)
+            x, (kc, vc) = body(x, lpj, kcs[j], vcs[j], kind)
+            ko.append(kc)
+            vo.append(vc)
+        return x, (tuple(ko), tuple(vo))
+
+    x, (ko, vo) = jax.lax.scan(
+        step, x, (jnp.arange(cfg.num_layers // p), ks, vs))
+    if not cached:
+        return x, (None, None)
+    return x, (PeriodKV(ko), PeriodKV(vo))
+
+
+def _no_mixed(cfg: LlamaConfig, what: str):
+    if cfg.layer_types is not None:
+        raise NotImplementedError(
+            f"{what} does not take a model with window and full layers "
+            "(layer_types): it knows one cache per layer stack")
 
 
 def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
@@ -683,29 +884,39 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
         x = jnp.where(is_embed[..., None], extra.astype(x.dtype), x)
     x = _shard_act(x, P("data", sax, None))
 
-    def layer(x, xs):
-        lp, kc, vc = xs
+    if table is not None or kvt is not None:
+        _no_mixed(cfg, "a paged or tiered prefill")
+
+    def layer(x, lp, kc, vc, kind):
+        lcos, lsin = _layer_rope(cos, sin, kind)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(h, lp, cfg, spec=P("data", sax, "model"))
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        q = _shard_act(q, P("data", sax, "model", None))
-        attn = attn_prefill(q, k, v, lengths, sliding_window=cfg.sliding_window)
-        with jax.named_scope("attention"):
-            x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
-                            spec=P("data", sax, None))
+        with _attn_scope(kind):
+            q, k, v = _qkv(h, lp, cfg, spec=P("data", sax, "model"))
+            q = apply_rope(q, lcos, lsin, positions)
+            k = apply_rope(k, lcos, lsin, positions)
+            q = _shard_act(q, P("data", sax, "model", None))
+            attn = attn_prefill(q, k, v, lengths,
+                                sliding_window=_layer_window(cfg, kind))
+            with jax.named_scope("attention"):
+                x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
+                                spec=P("data", sax, None))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, lp, cfg, spec_prefix=("data", sax))
         x = _shard_act(x, P("data", sax, None))
+        keep = None
+        if kind == WINDOW:
+            # a ring takes the prompt's own tokens only, and of a prompt
+            # longer than the ring its tail
+            keep = ((positions < lengths[:, None])
+                    & (positions >= lengths[:, None] - kc.shape[2]))
         # unique=False: batched admission pads groups by repeating a real
         # request's plan (engine _flush_admits), so slot_map can repeat
         kc, vc = _cache_write(kc, vc, k, v, slot_map, positions, table,
-                              unique=False, kvt=kvt)
+                              unique=False, kvt=kvt, ring_keep=keep)
         return x, (kc, vc)
 
-    x, (k_cache, v_cache) = jax.lax.scan(
-        layer, x, (params["layers"], k_cache, v_cache)
-    )
+    x, (k_cache, v_cache) = _scan_layers(
+        cfg, layer, x, params["layers"], k_cache, v_cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
@@ -731,21 +942,17 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     Returns (logits [B, V] f32, k_cache, v_cache).
     """
     b = tokens.shape[0]
+    if table is not None or kvt is not None:
+        _no_mixed(cfg, "a paged or tiered decode step")
     kv_quant = isinstance(k_cache, QuantKV)
-    T = k_cache.shape[3] if table is None else table.shape[1] * 128
     _, attn_decode = _attn_impls()
     positions = lengths[:, None]  # [B,1]
-    if active is None:
-        wpos, redirect = positions, None
-    elif table is None:
-        # dense: each row owns its slot row, so T-1 (never readable — the
-        # engine terminates at max_context-2) is a safe per-row target
-        wpos, redirect = jnp.where(active[:, None], positions, T - 1), None
-    else:
+    redirect = None
+    if active is not None and table is not None:
         # paged: inactive rows write to the trash block at distinct per-row
         # offsets (_cache_write redirect) — never through their own table,
         # whose last virtual block can be a RETAINED warm-prefix block
-        wpos, redirect = positions, ~active
+        redirect = ~active
     unique = table is None or b <= 128
     # paged Pallas tier: the per-step write is a scatter-append DMA kernel
     # (O(slots) traffic, provably in place) instead of an XLA scatter
@@ -770,16 +977,15 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     if kvt is not None:
         sb, rw = kvt["sb"], kvt["rw"]
 
-    def layer(x, xs):
-        if cold:
-            lp, kc, vc, ck, cv = xs
-        else:
-            (lp, kc, vc), ck, cv = xs, None, None
+    def layer(x, lp, kc, vc, kind, ck=None, cv=None):
+        ring = kind == WINDOW
+        lcos, lsin = _layer_rope(cos, sin, kind)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(h, lp, cfg, spec=P("data", None, "model"))
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        q = _shard_act(q, P("data", None, "model", None))
+        with _attn_scope(kind):
+            q, k, v = _qkv(h, lp, cfg, spec=P("data", None, "model"))
+            q = apply_rope(q, lcos, lsin, positions)
+            k = apply_rope(k, lcos, lsin, positions)
+            q = _shard_act(q, P("data", None, "model", None))
         if kernel_write:
             from localai_tpu.ops.pallas import (
                 paged_scatter_append, paged_scatter_append_q8,
@@ -805,22 +1011,34 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
                                               lengths, table, active,
                                               sb=sb, rw=rw)
         else:
+            wpos, keep = positions, None
+            if ring:
+                # an inactive row's write is dropped (_cache_write)
+                keep = (jnp.ones((b, 1), bool) if active is None
+                        else active[:, None])
+            elif active is not None and table is None:
+                # dense: each row owns its slot row, so T-1 (never readable
+                # — the engine terminates at max_context-2) is a safe
+                # per-row target
+                wpos = jnp.where(active[:, None], positions,
+                                 kc.shape[2] - 1)
             kc, vc = _cache_write(kc, vc, k, v, jnp.arange(b), wpos, table,
-                                  unique=unique, redirect=redirect, kvt=kvt)
-        attn = attn_decode(q, kc, vc, lengths + 1,
-                           sliding_window=cfg.sliding_window, table=table,
-                           kvt=kvt, ck=ck, cv=cv)
-        with jax.named_scope("attention"):
-            x = x + qmatmul(attn.reshape(b, 1, -1), lp["wo"],
-                            spec=P("data", None, None))
+                                  unique=unique, redirect=redirect, kvt=kvt,
+                                  ring_keep=keep)
+        with _attn_scope(kind):
+            attn = attn_decode(q, kc, vc, lengths + 1,
+                               sliding_window=_layer_window(cfg, kind),
+                               table=table, kvt=kvt, ck=ck, cv=cv, ring=ring)
+            with jax.named_scope("attention"):
+                x = x + qmatmul(attn.reshape(b, 1, -1), lp["wo"],
+                                spec=P("data", None, None))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, lp, cfg, spec_prefix=("data", None))
         return x, (kc, vc)
 
-    xs = (params["layers"], k_cache, v_cache)
-    if cold:
-        xs = xs + (kvt["cold_k"], kvt["cold_v"])
-    x, (k_cache, v_cache) = jax.lax.scan(layer, x, xs)
+    x, (k_cache, v_cache) = _scan_layers(
+        cfg, layer, x, params["layers"], k_cache, v_cache,
+        extra=(kvt["cold_k"], kvt["cold_v"]) if cold else ())
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = _lm_head(x[:, 0].astype(jnp.float32), params)
     return logits, k_cache, v_cache
@@ -874,6 +1092,7 @@ def ragged_forward(params, cfg: LlamaConfig, tokens, cos, sin,
         ragged_scatter_xla, ragged_scatter_xla_q8,
     )
 
+    _no_mixed(cfg, "ragged_forward")
     t = tokens.shape[0]
     kv_quant = isinstance(k_cache, QuantKV)
     blk = (k_cache.q if kv_quant else k_cache).shape[3]        # pool BS
@@ -1252,7 +1471,7 @@ def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):
     """Full-sequence causal forward → final-norm hidden states [B, S, H].
     `lengths` masks padded positions out of attention (defaults to full)."""
     b, s = tokens.shape
-    cos, sin = rope_table(cfg.rope, s)
+    cos, sin = rope_tables(cfg, s)
     positions = jnp.arange(s)[None, :].repeat(b, 0)
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
@@ -1261,21 +1480,23 @@ def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):
     x = params["embed"].astype(cfg.jdtype)[tokens]
     x = _shard_act(x, P("data", sax, None))
 
-    def layer(x, lp):
+    def layer(x, lp, _kc, _vc, kind):
+        lcos, lsin = _layer_rope(cos, sin, kind)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q, k, v = _qkv(h, lp, cfg, spec=P("data", sax, "model"))
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        q = apply_rope(q, lcos, lsin, positions)
+        k = apply_rope(k, lcos, lsin, positions)
         q = _shard_act(q, P("data", sax, "model", None))
-        attn = attn_prefill(q, k, v, lengths, sliding_window=cfg.sliding_window)
+        attn = attn_prefill(q, k, v, lengths,
+                            sliding_window=_layer_window(cfg, kind))
         x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
                         spec=P("data", sax, None))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, lp, cfg, spec_prefix=("data", sax))
         x = _shard_act(x, P("data", sax, None))
-        return x, None
+        return x, (None, None)
 
-    x, _ = jax.lax.scan(layer, x, params["layers"])
+    x, _ = _scan_layers(cfg, layer, x, params["layers"])
     return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
@@ -1299,6 +1520,8 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
     from localai_tpu.ops.attention import mha_extend, mha_extend_tiered
 
     b, s = tokens.shape
+    if table is not None or kvt is not None or redirect is not None:
+        _no_mixed(cfg, "a paged, tiered or redirected extend")
     rows = jnp.arange(b) if slot_map is None else slot_map
     positions = start[:, None] + jnp.arange(s)[None, :]
     x = params["embed"].astype(cfg.jdtype)[tokens]
@@ -1315,15 +1538,14 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
     # them until real tokens overwrite those rows.
     cold = kvt is not None and "cold_tab" in kvt
 
-    def layer(x, xs):
-        if cold:
-            lp, kc, vc, ck, cv = xs
-        else:
-            (lp, kc, vc), ck, cv = xs, None, None
+    def layer(x, lp, kc, vc, kind, ck=None, cv=None):
+        ring = kind == WINDOW
+        lcos, lsin = _layer_rope(cos, sin, kind)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(h, lp, cfg, spec=P("data", None, "model"))
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        with _attn_scope(kind):
+            q, k, v = _qkv(h, lp, cfg, spec=P("data", None, "model"))
+            q = apply_rope(q, lcos, lsin, positions)
+            k = apply_rope(k, lcos, lsin, positions)
         # paged uniqueness: a window whose positions all sit inside the
         # slot's allocation (mid prefill chunks — callers pass
         # full_window=True) never collides; a FINAL chunk's padded tail
@@ -1335,13 +1557,27 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
         # init — engine._build_jit).
         from localai_tpu.ops.paged import BLOCK as _PB
 
+        keep = None
+        if ring:
+            # the chunk's writes wrap; every query must still find the
+            # window - 1 tokens before it, which the chunk's own newest
+            # writes overwrite unless the ring holds window + chunk
+            size = kc.shape[2]
+            if size < lcos.shape[0] and size < cfg.sliding_window + s:
+                raise ValueError(
+                    f"a ring of {size} tokens cannot take a window of "
+                    f"{cfg.sliding_window} behind a chunk of {s}")
+            # a final chunk's padding is not written: nothing in a ring is
+            # out of the way
+            keep = (jnp.ones((b, s), bool) if last_pos is None
+                    else jnp.arange(s)[None, :] <= last_pos[:, None])
         red_ok = redirect is None or b * s <= _PB
         kc, vc = _cache_write(
             kc, vc, k, v, rows, positions, table,
             unique=(table is None or full_window or redirect is not None)
             and red_ok,
-            redirect=redirect, kvt=kvt)
-        with jax.named_scope("attention"):
+            redirect=redirect, kvt=kvt, ring_keep=keep)
+        with _attn_scope(kind), jax.named_scope("attention"):
             if kvt is not None:
                 kr, vr, kv_pos, kv_ok = _tiered_kv(
                     kc, vc, table[rows], kvt["sb"][rows], kvt["rw"][rows],
@@ -1352,6 +1588,15 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
                     q, kr, vr, positions, kv_pos, kv_ok,
                     kvt["sinks"][rows], kvt["window"][rows],
                     drop_window=not cold)
+            elif ring:
+                kr = kc if slot_map is None else kc[rows]
+                vr = vc if slot_map is None else vc[rows]
+                newest = start + s - 1
+                kv_pos = newest[:, None] - _ring_back(newest, kr.shape[2])
+                attn = mha_extend_tiered(
+                    q, dequant(kr), dequant(vr), positions, kv_pos,
+                    kv_pos >= 0, jnp.zeros((b,), jnp.int32),
+                    jnp.full((b,), cfg.sliding_window, jnp.int32))
             else:
                 if table is not None:
                     from localai_tpu.ops.paged import paged_view
@@ -1362,17 +1607,16 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
                     kr = kc if slot_map is None else kc[rows]
                     vr = vc if slot_map is None else vc[rows]
                 attn = mha_extend(q, dequant(kr), dequant(vr), positions,
-                                  sliding_window=cfg.sliding_window)
+                                  sliding_window=_layer_window(cfg, kind))
             x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
                             spec=P("data", None, None))
         h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
         x = x + _mlp(h, lp, cfg, spec_prefix=("data", None))
         return x, (kc, vc)
 
-    xs = (params["layers"], k_cache, v_cache)
-    if cold:
-        xs = xs + (kvt["cold_k"], kvt["cold_v"])
-    x, (k_cache, v_cache) = jax.lax.scan(layer, x, xs)
+    x, (k_cache, v_cache) = _scan_layers(
+        cfg, layer, x, params["layers"], k_cache, v_cache,
+        extra=(kvt["cold_k"], kvt["cold_v"]) if cold else ())
     if not with_logits:
         return None, k_cache, v_cache
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
@@ -1397,6 +1641,7 @@ def cache_shift(cfg: LlamaConfig, k_cache, v_cache, lengths, slot, *,
     """
     from localai_tpu.ops.rope import rope_freqs
 
+    _no_mixed(cfg, "cache_shift")
     inv_freq, _ = rope_freqs(cfg.rope)
     ang = discard * inv_freq                     # [D/2]
     c, s = jnp.cos(ang), jnp.sin(ang)
@@ -1451,6 +1696,7 @@ def cache_shift_paged(cfg: LlamaConfig, k_pool, row_table, *,
     from localai_tpu.ops.paged import BLOCK
     from localai_tpu.ops.rope import rope_freqs
 
+    _no_mixed(cfg, "cache_shift_paged")
     inv_freq, _ = rope_freqs(cfg.rope)
     ang = (discard_blocks * BLOCK) * inv_freq
     c, s = jnp.cos(ang), jnp.sin(ang)

@@ -157,10 +157,22 @@ def flash_prefill(q, k, v, lengths, sliding_window=None,
 
 # --------------------------------------------------------------- decode
 
+def _ring_in_window(k_pos, length, t_total: int, sliding_window: int):
+    """The cache is a ring of t_total rows (row p mod t_total holds position
+    p; `length` counts every token so far, the newest included, and may pass
+    t_total): the rows whose entry is among the newest min(length,
+    sliding_window). Rows are then not in position order, which a decode
+    row's softmax does not mind: K is stored rotated."""
+    newest = jax.lax.rem(jnp.maximum(length, 1) - 1, t_total)
+    back = newest - k_pos
+    back = jnp.where(back < 0, back + t_total, back)
+    return back < jnp.minimum(length, sliding_window)
+
+
 def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
                    m_ref, l_ref, acc_ref, *,
                    block_k: int, num_kb: int, t_total: int, scale: float,
-                   sliding_window: int | None):
+                   sliding_window: int | None, ring: bool = False):
     b = pl.program_id(0)
     kb = pl.program_id(2)
     length = lengths_ref[b]
@@ -173,7 +185,7 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
 
     start = kb * block_k
     live = start < length
-    if sliding_window is not None:
+    if sliding_window is not None and not ring:
         live &= (start + block_k) > (length - sliding_window)
 
     @pl.when(live)
@@ -192,7 +204,9 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
         k_pos = start + jax.lax.broadcasted_iota(
             jnp.int32, s.shape, 1)
         mask = k_pos < jnp.minimum(length, t_total)
-        if sliding_window is not None:
+        if ring:
+            mask &= _ring_in_window(k_pos, length, t_total, sliding_window)
+        elif sliding_window is not None:
             mask &= k_pos >= length - sliding_window
         s = jnp.where(mask, s, NEG_INF)
         # m/l live lane-replicated in [G, 128] scratch
@@ -211,17 +225,27 @@ def _decode_kernel(lengths_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0, 0] = out.astype(o_ref.dtype)
 
 
+def _check_ring(ring: bool, sliding_window, table):
+    if ring and (table is not None or not sliding_window):
+        raise ValueError("ring=True reads a contiguous cache under a "
+                         "sliding_window")
+
+
 def _decode_kernel_paged(lengths_ref, table_ref, *refs, **kw):
     # table is consumed by the index maps only; the body math is identical
     _decode_kernel(lengths_ref, *refs, **kw)
 
 
-@functools.partial(jax.jit, static_argnames=("sliding_window", "block_k"))
+@functools.partial(jax.jit,
+                   static_argnames=("sliding_window", "block_k", "ring"))
 def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
-                  block_k: int = 256, table=None):
+                  block_k: int = 256, table=None, ring: bool = False):
     """Decode-step GQA attention. q: [B, 1, H, D]; caches [B, KVH, T, D];
     lengths: [B] valid entries incl. the newly-written token.
     Returns [B, 1, H, D].
+
+    ring=True (contiguous caches, with a sliding_window): the cache is a
+    ring of T rows and lengths may pass T — see _ring_in_window.
 
     Paged mode (`table` [B, MAXB] i32, ops/paged.py): caches are a block
     pool [NB, KVH, BS, D]; virtual KV block kb of slot b streams from
@@ -233,6 +257,7 @@ def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
     group = H // KVH
     scale = D ** -0.5
     qg = q.reshape(B, KVH, group, D)
+    _check_ring(ring, sliding_window, table)
 
     if table is not None:
         BS = k_cache.shape[2]            # pool [NB, KVH, BS, D]
@@ -286,7 +311,7 @@ def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
 
     kernel = functools.partial(_decode_kernel, block_k=block_k,
                                num_kb=num_kb, t_total=T, scale=scale,
-                               sliding_window=sliding_window)
+                               sliding_window=sliding_window, ring=ring)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -319,7 +344,8 @@ def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
 def _decode_q8_kernel(lengths_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
                       o_ref, m_ref, l_ref, acc_ref, *,
                       num_kb: int, t_total: int, scale: float,
-                      sliding_window: int | None, paged: bool = False):
+                      sliding_window: int | None, paged: bool = False,
+                      ring: bool = False):
     """ragged_decode against an int8 cache: K/V stream from HBM as int8 (half
     the decode bandwidth — the resource decode is bound by); scales are one
     aligned [1, 128] row per 128-token block, applied to score columns (K) and
@@ -339,7 +365,7 @@ def _decode_q8_kernel(lengths_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
 
     start = kb * block_k
     live = start < length
-    if sliding_window is not None:
+    if sliding_window is not None and not ring:
         live &= (start + block_k) > (length - sliding_window)
 
     @pl.when(live)
@@ -357,7 +383,9 @@ def _decode_q8_kernel(lengths_ref, q_ref, kq_ref, ks_ref, vq_ref, vs_ref,
         s = s * k_s                                            # dequant K
         k_pos = start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         mask = k_pos < jnp.minimum(length, t_total)
-        if sliding_window is not None:
+        if ring:
+            mask &= _ring_in_window(k_pos, length, t_total, sliding_window)
+        elif sliding_window is not None:
             mask &= k_pos >= length - sliding_window
         s = jnp.where(mask, s, NEG_INF)
         m_prev = m_ref[...]
@@ -379,21 +407,23 @@ def _decode_q8_kernel_paged(lengths_ref, table_ref, *refs, **kw):
     _decode_q8_kernel(lengths_ref, *refs, paged=True, **kw)
 
 
-@functools.partial(jax.jit, static_argnames=("sliding_window",))
+@functools.partial(jax.jit, static_argnames=("sliding_window", "ring"))
 def ragged_decode_q8(q, k_q, k_s, v_q, v_s, lengths, sliding_window=None,
-                     table=None):
+                     table=None, ring: bool = False):
     """Decode-step GQA attention over an int8 KV cache (ops/kvcache.py
     layout). q: [B, 1, H, D]; k_q/v_q: [B, KVH, T, D] int8;
     k_s/v_s: [B, KVH, T//128, 128] f32 (token t's scale at [t//128, t%128]);
     lengths: [B]. T must be a multiple of 128. Returns [B, 1, H, D].
 
     Paged mode (`table` [B, MAXB] i32): k_q/v_q are a block pool
-    [NB, KVH, 128, D] with scales [NB, KVH, 1, 128] (ops/paged.py)."""
+    [NB, KVH, 128, D] with scales [NB, KVH, 1, 128] (ops/paged.py).
+    ring=True: as in ragged_decode."""
     B, _, H, D = q.shape
     KVH = k_q.shape[1]
     group = H // KVH
     scale = D ** -0.5
     qg = q.reshape(B, KVH, group, D)
+    _check_ring(ring, sliding_window, table)
 
     if table is not None:
         BS = k_q.shape[2]
@@ -450,7 +480,8 @@ def ragged_decode_q8(q, k_q, k_s, v_q, v_s, lengths, sliding_window=None,
         return (b, h, jnp.minimum(kb, last), 0)
 
     kernel = functools.partial(_decode_q8_kernel, num_kb=num_kb, t_total=T,
-                               scale=scale, sliding_window=sliding_window)
+                               scale=scale, sliding_window=sliding_window,
+                               ring=ring)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(

@@ -94,6 +94,10 @@ def pipeline_hidden(params, cfg: LlamaConfig, tokens, *, mesh: Mesh,
     L = cfg.num_layers
     if L % S != 0:
         raise ValueError(f"num_layers {L} not divisible by {S} stages")
+    if cfg.layer_types is not None:
+        raise NotImplementedError(
+            "pipeline stages run one kind of layer with one RoPE: a model "
+            "with window and full layers (layer_types) is not taken")
     B, T = tokens.shape
     dsize = mesh.shape.get("data", 1)
     if B % (dsize * n_micro) != 0:

@@ -127,3 +127,38 @@ def test_yarn_matches_hf():
     theirs, hf_mscale = _hf_freqs("yarn", 128, 10000.0, 4096, scaling)
     np.testing.assert_allclose(np.asarray(ours), theirs, rtol=1e-5)
     assert mscale == pytest.approx(hf_mscale, rel=1e-6)
+
+
+def test_two_rope_configs_from_rope_parameters_keyed_by_layer_type(tmp_path):
+    """One config.json whose rope_parameters is keyed by layer type (window
+    layers plain, full layers YaRN: Mellum2's published values) gives two
+    RopeConfigs, and each matches HF's table for its own section."""
+    import json
+
+    from localai_tpu.engine.loader import load_config
+    from localai_tpu.models.llama import FULL, WINDOW
+
+    full = {"rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 8192, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.2772588722239782}
+    window = {"rope_type": "default", "rope_theta": 500000}
+    (tmp_path / "config.json").write_text(json.dumps({
+        "model_type": "mellum", "vocab_size": 64, "hidden_size": 512,
+        "intermediate_size": 64, "num_hidden_layers": 4,
+        "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 128,
+        "max_position_embeddings": 131072, "sliding_window": 1024,
+        "layer_types": ["sliding_attention"] * 3 + ["full_attention"],
+        "mlp_layer_types": ["sparse"] * 4, "num_experts": 8,
+        "num_experts_per_tok": 2, "moe_intermediate_size": 32,
+        "rope_parameters": {"full_attention": full,
+                            "sliding_attention": window}}))
+    cfg = load_config(str(tmp_path))
+    assert cfg.rope_of(FULL) != cfg.rope_of(WINDOW)
+    ours, mscale = rope_freqs(cfg.rope_of(FULL))
+    theirs, hf_mscale = _hf_freqs("yarn", 128, 500000.0, 131072, full)
+    np.testing.assert_allclose(np.asarray(ours), theirs, rtol=1e-5)
+    assert mscale == pytest.approx(hf_mscale) == 1.2772588722239782
+    ours, mscale = rope_freqs(cfg.rope_of(WINDOW))
+    theirs, _ = _hf_freqs("default", 128, 500000.0, 131072)
+    np.testing.assert_allclose(np.asarray(ours), theirs, rtol=1e-6)
+    assert mscale == 1.0

@@ -115,6 +115,30 @@ def test_ragged_decode_q8_dense(H, KVH, D):
     _close(out, mha_decode(q, dequant(kc), dequant(vc), lengths))
 
 
+@pytest.mark.parametrize("H,KVH,D", GEOMS + [(32, 4, 128)])
+def test_ragged_decode_ring(H, KVH, D):
+    """ring=True (a window layer's cache: row p mod T holds position p) in
+    both dtypes, at Mellum2's ring (1024 + 512) and head geometry, against
+    the XLA mask."""
+    from localai_tpu.models.llama import _decode_dq
+    from localai_tpu.ops.kvcache import QuantKV, quantize_tokens
+    from localai_tpu.ops.pallas import ragged_decode, ragged_decode_q8
+
+    B, T, W = 4, 1536, 1024
+    q = _bf16(31, (B, 1, H, D))
+    k, v = _bf16(32, (B, KVH, T, D)), _bf16(33, (B, KVH, T, D))
+    lengths = jnp.array([1, 1200, 1537, 7000], jnp.int32)
+    _close(ragged_decode(q, k, v, lengths, sliding_window=W, ring=True),
+           _decode_dq(q, k, v, lengths, sliding_window=W, ring=True))
+    kq, ks = quantize_tokens(k)
+    vq, vs = quantize_tokens(v)
+    kc = QuantKV(kq, ks.reshape(B, KVH, T // 128, 128))
+    vc = QuantKV(vq, vs.reshape(B, KVH, T // 128, 128))
+    _close(ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths,
+                            sliding_window=W, ring=True),
+           _decode_dq(q, kc, vc, lengths, sliding_window=W, ring=True))
+
+
 @pytest.mark.parametrize("H,KVH,D", GEOMS)
 def test_ragged_decode_q8_paged(H, KVH, D):
     from localai_tpu.ops.attention import mha_decode

@@ -51,6 +51,7 @@ SCOPES = ("expert_einsums", "router", "experts", "attention", "mlp",
 KERNELS = {"ragged_decode": "attention", "ragged_paged_attention": "attention",
            "flash_prefill": "attention", "paged_scatter_append": "cache_update",
            "ragged_scatter_append": "cache_update"}
+LAYER_KINDS = ("window", "full")
 TOP = 10
 
 
@@ -176,8 +177,14 @@ def scope_of(name: str, stats: dict) -> str:
         parts = text.split("/")
         for scope in SCOPES:          # inner scopes are listed first
             if scope in parts:
-                return ("experts/" + scope
-                        if scope in ("router", "expert_einsums") else scope)
+                if scope in ("router", "expert_einsums"):
+                    return "experts/" + scope
+                # a model with window and full layers traces its attention
+                # under attention/<kind> (models/llama.py _attn_scope)
+                kind = parts[parts.index(scope) + 1:][:1]
+                if scope == "attention" and kind and kind[0] in LAYER_KINDS:
+                    return "attention/" + kind[0]
+                return scope
         for part in parts:
             if part.startswith("jit("):
                 for kernel, scope in KERNELS.items():
