@@ -293,9 +293,11 @@ class PeriodKV:
     """K (or V) of a model with window and full layers: one cache per place
     in the period of layer kinds, `slots[j]` of [L/period, B, KVH, T_j, D]
     (dense or QuantKV) — T_j the served context for a FULL layer, the ring
-    for a WINDOW one. The layer scan takes one row of each per iteration and
-    gives it back, as it does with the one [L, ...] cache of a one-kind
-    model, so every layer's update stays in place."""
+    for a WINDOW one. The layer scan of decode_step, prefill and extend
+    CARRIES the slots, as it carries the one [L, ...] cache of a one-kind
+    model, and place j of period i writes and reads slots[j][i] where it
+    lies (_scan_layers_carry: no layer's cache is sliced out, copied or put
+    back)."""
     slots: tuple
 
 
@@ -359,11 +361,15 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
 # (ragged_decode_q8, flash_prefill, paged_scatter_append).
 @jax.named_scope("cache_update")
 def _cache_write(kc, vc, k, v, rows, positions, table=None, unique=True,
-                 redirect=None, kvt=None, ring_keep=None):
+                 redirect=None, kvt=None, ring_keep=None, layer=None):
     """Scatter window K/V [B, S, KVH, D] into head-major caches [B', KVH, T, D]
     at (rows[b], :, positions[b, s]). With a paged `table` [B, MAXB] the cache
     is a block pool [NB, KVH, BS, D] and (slot, position) resolves to
     (table[slot, pos // BS], :, pos % BS) — ops/paged.py layout.
+
+    layer (i32 scalar, dense only): the caches are the [L, B', KVH, T, D]
+    stack and the write lands at (layer, rows[b], :, positions[b, s]) — one
+    scatter into the stack, which stays where it is.
 
     redirect [B] bool (paged only): rows flagged True write to the TRASH
     block (physical 0, ops/paged.py) at offset (row*S + s) % BLOCK instead
@@ -399,13 +405,15 @@ def _cache_write(kc, vc, k, v, rows, positions, table=None, unique=True,
     kept (an inactive decode row, a prompt's padding, what a prompt longer
     than the ring has before its tail) is aimed at row R: out of bounds,
     which a scatter drops. A ring has no spare row to take such writes."""
-    kvh = kc.shape[1]
+    kvh = kc.shape[-3]
     if table is None:
         if ring_keep is not None:
-            ring = kc.shape[2]
+            ring = kc.shape[-2]
             positions = jnp.where(ring_keep, positions % ring, ring)
         idx = (rows[:, None, None], jnp.arange(kvh)[None, :, None],
                positions[:, None, :])
+        if layer is not None:
+            idx = (layer, *idx)
     else:
         from localai_tpu.ops.paged import BLOCK
 
@@ -593,11 +601,12 @@ def _tiered_kv(kc, vc, table_rows, sb, rw, length, ctab=None, ck=None,
 
 
 def _decode_dq(q, kc, vc, lengths, sliding_window=None, table=None,
-               kvt=None, ck=None, cv=None, ring=False):
+               kvt=None, ck=None, cv=None, ring=False, layer=None):
     """XLA decode attention over a (possibly quantized) cache: dequant is
     fused into the consuming dots by XLA; quantized caches still halve HBM
     capacity on this path. A paged cache is materialized per layer via
     gather (reference tier — the Pallas kernels stream through the table).
+    `layer`: kc/vc are [L, ...] stacks and layer `layer` of them is read.
 
     kvt (KV lifecycle tier, engine/kvtier.py): per-slot residency arrays —
     the gather covers only the RESIDENT ring view (O(sinks+window) rows for
@@ -605,6 +614,8 @@ def _decode_dq(q, kc, vc, lengths, sliding_window=None, table=None,
     the mask derives from true ring positions; with quantize_cold (ck/cv —
     this layer's cold pools) the exited-window blocks attend from the int8
     cold tier instead of being dropped."""
+    if layer is not None:
+        kc, vc = kc[layer], vc[layer]
     if kvt is not None:
         from localai_tpu.ops.attention import mha_decode_masked
 
@@ -724,7 +735,7 @@ def _attn_impls():
         )
 
         def attn_decode(q, kc, vc, lengths, sliding_window=None, table=None,
-                        kvt=None, ck=None, cv=None, ring=False):
+                        kvt=None, ck=None, cv=None, ring=False, layer=None):
             if kvt is not None:
                 # KV lifecycle tier: the ring-position/tier-map read rides
                 # the XLA reference path for now — the Pallas decode kernel
@@ -738,10 +749,10 @@ def _attn_impls():
             if isinstance(kc, QuantKV):
                 return ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths,
                                         sliding_window=sliding_window,
-                                        table=table, ring=ring)
+                                        table=table, ring=ring, layer=layer)
             return ragged_decode(q, kc, vc, lengths,
                                  sliding_window=sliding_window, table=table,
-                                 ring=ring)
+                                 ring=ring, layer=layer)
 
         return (lambda q, k, v, lengths, sliding_window=None:
                 flash_prefill(q, k, v, lengths, sliding_window=sliding_window),
@@ -794,16 +805,72 @@ def _ring_back(newest, ring: int):
     return jnp.mod(newest[:, None] - jnp.arange(ring)[None, :], ring)
 
 
+def _layer_params(layers, i):
+    """One layer's weights, sliced out of the [L, ...] stack where they are
+    used, as scan slices its xs (a [period, ...] slice of a folded stack is
+    copied whole every iteration: 1.6 GB of experts a period at Mellum2's
+    widths)."""
+    return jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), layers)
+
+
+def _scan_layers_carry(cfg: LlamaConfig, body, x, layers, k_cache, v_cache):
+    """_scan_layers with the stacked caches as the scan's CARRY: run
+    `body(x, lp, kc, vc, kind, li=i) -> (x, (kc, vc))` over the layers, where
+    kc / vc are the whole [L', B, KVH, T, D] stacks (a one-kind model's
+    cache; one slot of a PeriodKV) and `i` is the layer's index into them.
+    `body` writes and reads layer i where it lies — one scatter into the
+    stack, a kernel whose index maps take i, a gather of the rows a chunk
+    attends over — so no cache is an xs or a ys and nothing is sliced out,
+    put back or copied (on a v5e the xs/ys form cost a decode step 3.6 ms of
+    20 on Mixtral-8x7B at 6 layers and 12.5 of 37 on Mellum2; PERF.md)."""
+    period = cfg.period
+    if period is None:
+        def layer(carry, xs):
+            x, kc, vc = carry
+            lp, i = xs
+            x, (kc, vc) = body(x, lp, kc, vc, None, li=i)
+            return (x, kc, vc), None
+
+        (x, k_cache, v_cache), _ = jax.lax.scan(
+            layer, (x, k_cache, v_cache),
+            (layers, jnp.arange(cfg.num_layers)))
+        return x, (k_cache, v_cache)
+    p = len(period)
+
+    def step(carry, i):
+        x, ks, vs = carry
+        ks, vs = list(ks), list(vs)
+        for j, kind in enumerate(period):
+            x, (ks[j], vs[j]) = body(x, _layer_params(layers, i * p + j),
+                                     ks[j], vs[j], kind, li=i)
+        return (x, tuple(ks), tuple(vs)), None
+
+    (x, ks, vs), _ = jax.lax.scan(
+        step, (x, k_cache.slots, v_cache.slots),
+        jnp.arange(cfg.num_layers // p))
+    return x, (PeriodKV(ks), PeriodKV(vs))
+
+
 def _scan_layers(cfg: LlamaConfig, body, x, layers, k_cache=None,
-                 v_cache=None, extra=()):
+                 v_cache=None, extra=(), carry=False):
     """Run `body(x, lp, kc, vc, kind, *extra) -> (x, (kc, vc))` over the
-    layer stack and return (x, (k_cache, v_cache)).
+    layer stack and return (x, (k_cache, v_cache)). carry=True (a dense
+    cache, no extras): _scan_layers_carry instead.
 
     One kind of layer: lax.scan over [L, ...] with kind None, the program a
     one-kind model always had. With layer_types the scan's body is one
     PERIOD of kinds, unrolled, over a PeriodKV's per-place caches: compile
-    time grows with the period, not the depth, and each layer still updates
-    its own cache row in place."""
+    time grows with the period, not the depth.
+
+    The caches are the scan's xs and ys, so XLA slices each layer's cache
+    out of the stack for `body` and writes it back (and copies the stack
+    where a loop carries it). That is what a paged or tiered cache still
+    pays, whose per-layer pools a kernel aliases, and what a forward with no
+    cache (hidden_states) has nothing to pay for; every forward over a dense
+    cache takes _scan_layers_carry."""
+    if carry:
+        return _scan_layers_carry(cfg, body, x, layers, k_cache, v_cache)
     period = cfg.period
     if period is None:
         def layer(x, xs):
@@ -822,14 +889,8 @@ def _scan_layers(cfg: LlamaConfig, body, x, layers, k_cache=None,
         i, kcs, vcs = xs
         ko, vo = [], []
         for j, kind in enumerate(period):
-            # one layer's weights, sliced out of the [L, ...] stack where
-            # they are used, as scan slices its xs (a [period, ...] slice of
-            # a folded stack is copied whole every iteration: 1.6 GB of
-            # experts a period at Mellum2's widths)
-            lpj = jax.tree_util.tree_map(
-                lambda a: jax.lax.dynamic_index_in_dim(
-                    a, i * p + j, keepdims=False), layers)
-            x, (kc, vc) = body(x, lpj, kcs[j], vcs[j], kind)
+            x, (kc, vc) = body(x, _layer_params(layers, i * p + j), kcs[j],
+                               vcs[j], kind)
             ko.append(kc)
             vo.append(vc)
         return x, (tuple(ko), tuple(vo))
@@ -887,7 +948,9 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     if table is not None or kvt is not None:
         _no_mixed(cfg, "a paged or tiered prefill")
 
-    def layer(x, lp, kc, vc, kind):
+    stacked = table is None and kvt is None   # as in decode_step
+
+    def layer(x, lp, kc, vc, kind, li=None):
         lcos, lsin = _layer_rope(cos, sin, kind)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         with _attn_scope(kind):
@@ -908,15 +971,15 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
             # a ring takes the prompt's own tokens only, and of a prompt
             # longer than the ring its tail
             keep = ((positions < lengths[:, None])
-                    & (positions >= lengths[:, None] - kc.shape[2]))
+                    & (positions >= lengths[:, None] - kc.shape[-2]))
         # unique=False: batched admission pads groups by repeating a real
         # request's plan (engine _flush_admits), so slot_map can repeat
         kc, vc = _cache_write(kc, vc, k, v, slot_map, positions, table,
-                              unique=False, kvt=kvt, ring_keep=keep)
+                              unique=False, kvt=kvt, ring_keep=keep, layer=li)
         return x, (kc, vc)
 
     x, (k_cache, v_cache) = _scan_layers(
-        cfg, layer, x, params["layers"], k_cache, v_cache)
+        cfg, layer, x, params["layers"], k_cache, v_cache, carry=stacked)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
@@ -977,7 +1040,13 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     if kvt is not None:
         sb, rw = kvt["sb"], kvt["rw"]
 
-    def layer(x, lp, kc, vc, kind, ck=None, cv=None):
+    # a dense cache rides the layer scan as its carry and every touch of it
+    # names the layer (_scan_layers_carry; prefill and extend do the same);
+    # paged and tiered pools keep the xs/ys form their kernels' aliasing was
+    # written for
+    stacked = table is None and kvt is None
+
+    def layer(x, lp, kc, vc, kind, ck=None, cv=None, li=None):
         ring = kind == WINDOW
         lcos, lsin = _layer_rope(cos, sin, kind)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
@@ -1021,14 +1090,15 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
                 # — the engine terminates at max_context-2) is a safe
                 # per-row target
                 wpos = jnp.where(active[:, None], positions,
-                                 kc.shape[2] - 1)
+                                 kc.shape[-2] - 1)
             kc, vc = _cache_write(kc, vc, k, v, jnp.arange(b), wpos, table,
                                   unique=unique, redirect=redirect, kvt=kvt,
-                                  ring_keep=keep)
+                                  ring_keep=keep, layer=li)
         with _attn_scope(kind):
             attn = attn_decode(q, kc, vc, lengths + 1,
                                sliding_window=_layer_window(cfg, kind),
-                               table=table, kvt=kvt, ck=ck, cv=cv, ring=ring)
+                               table=table, kvt=kvt, ck=ck, cv=cv, ring=ring,
+                               layer=li)
             with jax.named_scope("attention"):
                 x = x + qmatmul(attn.reshape(b, 1, -1), lp["wo"],
                                 spec=P("data", None, None))
@@ -1038,7 +1108,7 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
 
     x, (k_cache, v_cache) = _scan_layers(
         cfg, layer, x, params["layers"], k_cache, v_cache,
-        extra=(kvt["cold_k"], kvt["cold_v"]) if cold else ())
+        extra=(kvt["cold_k"], kvt["cold_v"]) if cold else (), carry=stacked)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = _lm_head(x[:, 0].astype(jnp.float32), params)
     return logits, k_cache, v_cache
@@ -1537,8 +1607,14 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
     # at positions > every real query, so the kv_pos <= q_pos mask hides
     # them until real tokens overwrite those rows.
     cold = kvt is not None and "cold_tab" in kvt
+    stacked = table is None and kvt is None   # as in decode_step
 
-    def layer(x, lp, kc, vc, kind, ck=None, cv=None):
+    def rows_of(cache, li):
+        """The rows this window attends over, of layer li of a dense stack
+        (a paged or tiered cache is read through its table instead)."""
+        return cache[li] if slot_map is None else cache[li, rows]
+
+    def layer(x, lp, kc, vc, kind, ck=None, cv=None, li=None):
         ring = kind == WINDOW
         lcos, lsin = _layer_rope(cos, sin, kind)
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
@@ -1562,7 +1638,7 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
             # the chunk's writes wrap; every query must still find the
             # window - 1 tokens before it, which the chunk's own newest
             # writes overwrite unless the ring holds window + chunk
-            size = kc.shape[2]
+            size = kc.shape[-2]
             if size < lcos.shape[0] and size < cfg.sliding_window + s:
                 raise ValueError(
                     f"a ring of {size} tokens cannot take a window of "
@@ -1576,7 +1652,7 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
             kc, vc, k, v, rows, positions, table,
             unique=(table is None or full_window or redirect is not None)
             and red_ok,
-            redirect=redirect, kvt=kvt, ring_keep=keep)
+            redirect=redirect, kvt=kvt, ring_keep=keep, layer=li)
         with _attn_scope(kind), jax.named_scope("attention"):
             if kvt is not None:
                 kr, vr, kv_pos, kv_ok = _tiered_kv(
@@ -1589,8 +1665,7 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
                     kvt["sinks"][rows], kvt["window"][rows],
                     drop_window=not cold)
             elif ring:
-                kr = kc if slot_map is None else kc[rows]
-                vr = vc if slot_map is None else vc[rows]
+                kr, vr = rows_of(kc, li), rows_of(vc, li)
                 newest = start + s - 1
                 kv_pos = newest[:, None] - _ring_back(newest, kr.shape[2])
                 attn = mha_extend_tiered(
@@ -1604,8 +1679,7 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
                     kr = paged_view(kc, table[rows])
                     vr = paged_view(vc, table[rows])
                 else:
-                    kr = kc if slot_map is None else kc[rows]
-                    vr = vc if slot_map is None else vc[rows]
+                    kr, vr = rows_of(kc, li), rows_of(vc, li)
                 attn = mha_extend(q, dequant(kr), dequant(vr), positions,
                                   sliding_window=_layer_window(cfg, kind))
             x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
@@ -1616,7 +1690,7 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
 
     x, (k_cache, v_cache) = _scan_layers(
         cfg, layer, x, params["layers"], k_cache, v_cache,
-        extra=(kvt["cold_k"], kvt["cold_v"]) if cold else ())
+        extra=(kvt["cold_k"], kvt["cold_v"]) if cold else (), carry=stacked)
     if not with_logits:
         return None, k_cache, v_cache
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)

@@ -15,7 +15,9 @@ Two kernels:
   valid one — Mosaic skips the DMA when consecutive grid steps map to the
   same block, so each slot streams only ceil(length/BLOCK) KV blocks from
   HBM. That is the "ragged" part: long-context decode is O(valid tokens) in
-  both compute AND memory traffic, not O(max context).
+  both compute AND memory traffic, not O(max context). With `layer` the
+  caches are the whole [L, B, KVH, T, D] stack and the index maps address
+  layer `layer` of it: nothing slices a layer out for the kernel.
 
 Mosaic tiling rule (the round-3 lesson): the LAST TWO dims of every block
 shape must be (divisible by 8, divisible by 128) or equal to the array dims.
@@ -231,6 +233,24 @@ def _check_ring(ring: bool, sliding_window, table):
                          "sliding_window")
 
 
+def _check_layer(layer, cache, table):
+    stacked = cache.ndim == 5
+    if stacked != (layer is not None) or (stacked and table is not None):
+        raise ValueError("`layer` goes with a contiguous [L, B, KVH, T, D] "
+                         "cache stack, and only with one")
+
+
+def _layer_operand(layer):
+    """The layer index as the [1] i32 scalar-prefetch operand."""
+    return jnp.asarray(layer, jnp.int32).reshape(1)
+
+
+def _decode_kernel_stacked(lengths_ref, layer_ref, *refs, **kw):
+    # the layer index is consumed by the index maps only (their K/V blocks
+    # squeeze the layer axis away); the body math is identical
+    _decode_kernel(lengths_ref, *refs, **kw)
+
+
 def _decode_kernel_paged(lengths_ref, table_ref, *refs, **kw):
     # table is consumed by the index maps only; the body math is identical
     _decode_kernel(lengths_ref, *refs, **kw)
@@ -239,10 +259,15 @@ def _decode_kernel_paged(lengths_ref, table_ref, *refs, **kw):
 @functools.partial(jax.jit,
                    static_argnames=("sliding_window", "block_k", "ring"))
 def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
-                  block_k: int = 256, table=None, ring: bool = False):
+                  block_k: int = 256, table=None, ring: bool = False,
+                  layer=None):
     """Decode-step GQA attention. q: [B, 1, H, D]; caches [B, KVH, T, D];
     lengths: [B] valid entries incl. the newly-written token.
     Returns [B, 1, H, D].
+
+    `layer` (i32 scalar, contiguous caches): the caches are a layer stack
+    [L, B, KVH, T, D] and the kernel reads layer `layer` of it in place (the
+    decode step's layer scan carries the stack — models/llama.py).
 
     ring=True (contiguous caches, with a sliding_window): the cache is a
     ring of T rows and lengths may pass T — see _ring_in_window.
@@ -253,11 +278,12 @@ def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
     repeats the physical index past the valid length and Mosaic skips the
     duplicate DMA."""
     B, _, H, D = q.shape
-    KVH = k_cache.shape[1]   # axis 1 in both layouts ([B,KVH,T,D] / pool)
+    KVH = k_cache.shape[-3]  # [(L,) B, KVH, T, D] / pool [NB, KVH, BS, D]
     group = H // KVH
     scale = D ** -0.5
     qg = q.reshape(B, KVH, group, D)
     _check_ring(ring, sliding_window, table)
+    _check_layer(layer, k_cache, table)
 
     if table is not None:
         BS = k_cache.shape[2]            # pool [NB, KVH, BS, D]
@@ -299,32 +325,34 @@ def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
           k_cache, v_cache)
         return out.reshape(B, 1, H, D)
 
-    T = k_cache.shape[2]
+    if layer is None:
+        k_cache, v_cache, layer = k_cache[None], v_cache[None], 0
+    T = k_cache.shape[3]
     block_k = min(block_k, T)
     num_kb = pl.cdiv(T, block_k)
 
-    def kv_map(b, h, kb, lens):
+    def kv_map(b, h, kb, lens, lyr):
         # clamp beyond-length blocks to the last valid one: Mosaic skips the
         # DMA when the block index repeats, making traffic O(length)
         last = jnp.maximum(pl.cdiv(lens[b], block_k) - 1, 0)
-        return (b, h, jnp.minimum(kb, last), 0)
+        return (lyr[0], b, h, jnp.minimum(kb, last), 0)
 
-    kernel = functools.partial(_decode_kernel, block_k=block_k,
+    kernel = functools.partial(_decode_kernel_stacked, block_k=block_k,
                                num_kb=num_kb, t_total=T, scale=scale,
                                sliding_window=sliding_window, ring=ring)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B, KVH, num_kb),
             in_specs=[
                 pl.BlockSpec((1, 1, group, D),
-                             lambda b, h, kb, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
-                pl.BlockSpec((1, 1, block_k, D), kv_map),
+                             lambda b, h, kb, lens, lyr: (b, h, 0, 0)),
+                pl.BlockSpec((None, 1, 1, block_k, D), kv_map),
+                pl.BlockSpec((None, 1, 1, block_k, D), kv_map),
             ],
             out_specs=pl.BlockSpec((1, 1, group, D),
-                                   lambda b, h, kb, lens: (b, h, 0, 0)),
+                                   lambda b, h, kb, lens, lyr: (b, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((group, 128), jnp.float32),   # m (lane-replicated)
                 pltpu.VMEM((group, 128), jnp.float32),   # l
@@ -335,7 +363,7 @@ def ragged_decode(q, k_cache, v_cache, lengths, sliding_window=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-    )(lengths.astype(jnp.int32), qg, k_cache, v_cache)
+    )(lengths.astype(jnp.int32), _layer_operand(layer), qg, k_cache, v_cache)
     return out.reshape(B, 1, H, D)
 
 
@@ -407,9 +435,13 @@ def _decode_q8_kernel_paged(lengths_ref, table_ref, *refs, **kw):
     _decode_q8_kernel(lengths_ref, *refs, paged=True, **kw)
 
 
+def _decode_q8_kernel_stacked(lengths_ref, layer_ref, *refs, **kw):
+    _decode_q8_kernel(lengths_ref, *refs, **kw)
+
+
 @functools.partial(jax.jit, static_argnames=("sliding_window", "ring"))
 def ragged_decode_q8(q, k_q, k_s, v_q, v_s, lengths, sliding_window=None,
-                     table=None, ring: bool = False):
+                     table=None, ring: bool = False, layer=None):
     """Decode-step GQA attention over an int8 KV cache (ops/kvcache.py
     layout). q: [B, 1, H, D]; k_q/v_q: [B, KVH, T, D] int8;
     k_s/v_s: [B, KVH, T//128, 128] f32 (token t's scale at [t//128, t%128]);
@@ -417,13 +449,15 @@ def ragged_decode_q8(q, k_q, k_s, v_q, v_s, lengths, sliding_window=None,
 
     Paged mode (`table` [B, MAXB] i32): k_q/v_q are a block pool
     [NB, KVH, 128, D] with scales [NB, KVH, 1, 128] (ops/paged.py).
-    ring=True: as in ragged_decode."""
+    ring=True and `layer` (all four arrays then [L, B, ...] stacks): as in
+    ragged_decode."""
     B, _, H, D = q.shape
-    KVH = k_q.shape[1]
+    KVH = k_q.shape[-3]
     group = H // KVH
     scale = D ** -0.5
     qg = q.reshape(B, KVH, group, D)
     _check_ring(ring, sliding_window, table)
+    _check_layer(layer, k_q, table)
 
     if table is not None:
         BS = k_q.shape[2]
@@ -469,38 +503,42 @@ def ragged_decode_q8(q, k_q, k_s, v_q, v_s, lengths, sliding_window=None,
           k_q, k_s.astype(jnp.float32), v_q, v_s.astype(jnp.float32))
         return out.reshape(B, 1, H, D)
 
-    T = k_q.shape[2]
+    if layer is None:
+        k_q, k_s, v_q, v_s, layer = k_q[None], k_s[None], v_q[None], \
+            v_s[None], 0
+    T = k_q.shape[3]
     if T % 128:
         raise ValueError("int8 KV cache length must be a multiple of 128")
     num_kb = T // 128
-    n_tiles = k_s.shape[2]
+    n_tiles = k_s.shape[3]
 
-    def kv_map(b, h, kb, lens):
+    def kv_map(b, h, kb, lens, lyr):
         last = jnp.maximum(pl.cdiv(lens[b], 128) - 1, 0)
-        return (b, h, jnp.minimum(kb, last), 0)
+        return (lyr[0], b, h, jnp.minimum(kb, last), 0)
 
-    kernel = functools.partial(_decode_q8_kernel, num_kb=num_kb, t_total=T,
-                               scale=scale, sliding_window=sliding_window,
-                               ring=ring)
+    def scale_map(b, h, kb, lens, lyr):
+        return (lyr[0], b, h, 0, 0)
+
+    kernel = functools.partial(_decode_q8_kernel_stacked, num_kb=num_kb,
+                               t_total=T, scale=scale,
+                               sliding_window=sliding_window, ring=ring)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
+            num_scalar_prefetch=2,
             grid=(B, KVH, num_kb),
             in_specs=[
                 pl.BlockSpec((1, 1, group, D),
-                             lambda b, h, kb, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, 128, D), kv_map),
+                             lambda b, h, kb, lens, lyr: (b, h, 0, 0)),
+                pl.BlockSpec((None, 1, 1, 128, D), kv_map),
                 # scales ride whole per (slot, head): one small DMA, reused
                 # across every KV block of the row
-                pl.BlockSpec((1, 1, n_tiles, 128),
-                             lambda b, h, kb, lens: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, 128, D), kv_map),
-                pl.BlockSpec((1, 1, n_tiles, 128),
-                             lambda b, h, kb, lens: (b, h, 0, 0)),
+                pl.BlockSpec((None, 1, 1, n_tiles, 128), scale_map),
+                pl.BlockSpec((None, 1, 1, 128, D), kv_map),
+                pl.BlockSpec((None, 1, 1, n_tiles, 128), scale_map),
             ],
             out_specs=pl.BlockSpec((1, 1, group, D),
-                                   lambda b, h, kb, lens: (b, h, 0, 0)),
+                                   lambda b, h, kb, lens, lyr: (b, h, 0, 0)),
             scratch_shapes=[
                 pltpu.VMEM((group, 128), jnp.float32),   # m (lane-replicated)
                 pltpu.VMEM((group, 128), jnp.float32),   # l
@@ -511,6 +549,6 @@ def ragged_decode_q8(q, k_q, k_s, v_q, v_s, lengths, sliding_window=None,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=_interpret(),
-    )(lengths.astype(jnp.int32), qg, k_q, k_s.astype(jnp.float32),
-      v_q, v_s.astype(jnp.float32))
+    )(lengths.astype(jnp.int32), _layer_operand(layer), qg,
+      k_q, k_s.astype(jnp.float32), v_q, v_s.astype(jnp.float32))
     return out.reshape(B, 1, H, D)

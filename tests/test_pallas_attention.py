@@ -41,17 +41,73 @@ def test_flash_prefill_sliding_window():
                                rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("layer", [None, 0, 2])
 @pytest.mark.parametrize("H,KVH", [(4, 4), (8, 2)])
-def test_ragged_decode_matches_reference(H, KVH):
+def test_ragged_decode_matches_reference(H, KVH, layer):
+    """layer: the caches are a [3, ...] stack and the kernel reads that
+    layer of it; the reference is given stack[layer]."""
     B, T, D = 3, 64, 16
+    lead = () if layer is None else (3,)
     q = _rand(6, (B, 1, H, D))
-    kc = _rand(7, (B, KVH, T, D))
-    vc = _rand(8, (B, KVH, T, D))
+    kc = _rand(7, (*lead, B, KVH, T, D))
+    vc = _rand(8, (*lead, B, KVH, T, D))
     lengths = jnp.array([5, 64, 23], jnp.int32)
-    ref = mha_decode(q, kc, vc, lengths)
-    out = ragged_decode(q, kc, vc, lengths, block_k=16)
+    ref = (mha_decode(q, kc, vc, lengths) if layer is None
+           else mha_decode(q, kc[layer], vc[layer], lengths))
+    out = ragged_decode(q, kc, vc, lengths, block_k=16,
+                        layer=None if layer is None else jnp.int32(layer))
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("ring", [False, True], ids=["dense", "ring"])
+@pytest.mark.parametrize("quant", [False, True], ids=["f32", "q8"])
+def test_ragged_decode_reads_its_layer_of_a_stack(quant, ring, layer):
+    """ragged_decode / ragged_decode_q8 over a [L, B, KVH, T, D] stack with a
+    traced layer index, full caches and rings (rows before the first wrap,
+    on it and several wraps in), against the XLA twin on stack[layer] and
+    on the stack with the same index."""
+    from localai_tpu.models.llama import _decode_dq
+    from localai_tpu.ops.kvcache import QuantKV, quantize_tokens
+    from localai_tpu.ops.pallas import ragged_decode_q8
+
+    L, B, H, KVH, D, T, window = 3, 4, 4, 2, 16, 256, 100
+    q = _rand(30, (B, 1, H, D))
+    k = _rand(31, (L, B, KVH, T, D))
+    v = _rand(32, (L, B, KVH, T, D))
+    lengths = jnp.array([1, 60, 256, 1000 if ring else 201], jnp.int32)
+    kw = dict(sliding_window=window, ring=True) if ring else {}
+    if quant:
+        def q8(x):
+            xq, s = quantize_tokens(x)
+            return QuantKV(xq, s.reshape(L, B, KVH, T // 128, 128))
+
+        k, v = q8(k), q8(v)
+        run = jax.jit(lambda i: ragged_decode_q8(
+            q, k.q, k.s, v.q, v.s, lengths, layer=i, **kw))
+    else:
+        run = jax.jit(lambda i: ragged_decode(q, k, v, lengths, layer=i,
+                                              **kw))
+    got = np.asarray(run(jnp.int32(layer)))
+    want = np.asarray(_decode_dq(q, k[layer], v[layer], lengths, **kw))
+    tol = 2e-2 if quant else 2e-5
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    twin = np.asarray(_decode_dq(q, k, v, lengths, layer=layer, **kw))
+    np.testing.assert_array_equal(twin, want)
+    # and not another layer's
+    other = np.asarray(_decode_dq(q, k[1], v[1], lengths, **kw))
+    assert np.abs(got - other).max() > 0.05
+
+
+def test_ragged_decode_layer_goes_with_a_stack():
+    q = _rand(33, (2, 1, 4, 16))
+    kc = _rand(34, (2, 2, 32, 16))
+    lengths = jnp.array([5, 9], jnp.int32)
+    with pytest.raises(ValueError, match="cache stack"):
+        ragged_decode(q, kc, kc, lengths, layer=0)
+    with pytest.raises(ValueError, match="cache stack"):
+        ragged_decode(q, kc[None], kc[None], lengths)
 
 
 def test_ragged_decode_sliding_window():

@@ -61,16 +61,33 @@ def test_flash_prefill(H, KVH, D, S):
         _close(out[b, :n], ref[b, :n])
 
 
+# layer: the caches are a [3, ...] stack and the kernel reads that layer of it
+# through its index maps (what a decode step's layer scan hands it); the
+# reference is given stack[layer]
+LAYERS = [None, 0, 2]
+
+
+def _lead(layer):
+    return () if layer is None else (3,)
+
+
+def _of(layer, *stacks):
+    return stacks if layer is None else tuple(a[layer] for a in stacks)
+
+
+@pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("H,KVH,D", GEOMS + [(8, 8, 128)])
-def test_ragged_decode_dense(H, KVH, D):
+def test_ragged_decode_dense(H, KVH, D, layer):
     from localai_tpu.ops.attention import mha_decode
     from localai_tpu.ops.pallas import ragged_decode
 
     B, T = 4, 1024
     q = _bf16(3, (B, 1, H, D))
-    kc, vc = _bf16(4, (B, KVH, T, D)), _bf16(5, (B, KVH, T, D))
+    kc = _bf16(4, (*_lead(layer), B, KVH, T, D))
+    vc = _bf16(5, (*_lead(layer), B, KVH, T, D))
     lengths = jnp.array([1, 100, 777, T], jnp.int32)
-    _close(ragged_decode(q, kc, vc, lengths), mha_decode(q, kc, vc, lengths))
+    _close(ragged_decode(q, kc, vc, lengths, layer=layer),
+           mha_decode(q, *_of(layer, kc, vc), lengths))
 
 
 def _table(B, maxb):
@@ -96,47 +113,56 @@ def test_ragged_decode_paged(H, KVH, D):
     _close(out, ref)
 
 
+def _quant(key, shape):
+    """A QuantKV of logical shape [..., T, D] from random values."""
+    from localai_tpu.ops.kvcache import QuantKV, quantize_tokens
+
+    xq, s = quantize_tokens(jax.random.normal(jax.random.PRNGKey(key), shape))
+    *lead, t, _ = shape
+    return QuantKV(xq, s.reshape(*lead, t // 128, 128))
+
+
+@pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("H,KVH,D", GEOMS)
-def test_ragged_decode_q8_dense(H, KVH, D):
+def test_ragged_decode_q8_dense(H, KVH, D, layer):
     from localai_tpu.ops.attention import mha_decode
-    from localai_tpu.ops.kvcache import QuantKV, dequant, quantize_tokens
+    from localai_tpu.ops.kvcache import dequant
     from localai_tpu.ops.pallas import ragged_decode_q8
 
     B, T = 4, 1024
     q = _bf16(9, (B, 1, H, D))
-    kq, ks = quantize_tokens(
-        jax.random.normal(jax.random.PRNGKey(10), (B, KVH, T, D)))
-    vq, vs = quantize_tokens(
-        jax.random.normal(jax.random.PRNGKey(11), (B, KVH, T, D)))
-    kc = QuantKV(kq, ks.reshape(B, KVH, T // 128, 128))
-    vc = QuantKV(vq, vs.reshape(B, KVH, T // 128, 128))
+    kc = _quant(10, (*_lead(layer), B, KVH, T, D))
+    vc = _quant(11, (*_lead(layer), B, KVH, T, D))
     lengths = jnp.array([1, 100, 777, T], jnp.int32)
-    out = ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths)
-    _close(out, mha_decode(q, dequant(kc), dequant(vc), lengths))
+    out = ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths, layer=layer)
+    k1, v1 = _of(layer, kc, vc)
+    _close(out, mha_decode(q, dequant(k1), dequant(v1), lengths))
 
 
+@pytest.mark.parametrize("layer", LAYERS)
 @pytest.mark.parametrize("H,KVH,D", GEOMS + [(32, 4, 128)])
-def test_ragged_decode_ring(H, KVH, D):
+def test_ragged_decode_ring(H, KVH, D, layer):
     """ring=True (a window layer's cache: row p mod T holds position p) in
     both dtypes, at Mellum2's ring (1024 + 512) and head geometry, against
     the XLA mask."""
     from localai_tpu.models.llama import _decode_dq
-    from localai_tpu.ops.kvcache import QuantKV, quantize_tokens
     from localai_tpu.ops.pallas import ragged_decode, ragged_decode_q8
 
     B, T, W = 4, 1536, 1024
     q = _bf16(31, (B, 1, H, D))
-    k, v = _bf16(32, (B, KVH, T, D)), _bf16(33, (B, KVH, T, D))
+    k = _bf16(32, (*_lead(layer), B, KVH, T, D))
+    v = _bf16(33, (*_lead(layer), B, KVH, T, D))
     lengths = jnp.array([1, 1200, 1537, 7000], jnp.int32)
-    _close(ragged_decode(q, k, v, lengths, sliding_window=W, ring=True),
-           _decode_dq(q, k, v, lengths, sliding_window=W, ring=True))
-    kq, ks = quantize_tokens(k)
-    vq, vs = quantize_tokens(v)
-    kc = QuantKV(kq, ks.reshape(B, KVH, T // 128, 128))
-    vc = QuantKV(vq, vs.reshape(B, KVH, T // 128, 128))
+    _close(ragged_decode(q, k, v, lengths, sliding_window=W, ring=True,
+                         layer=layer),
+           _decode_dq(q, *_of(layer, k, v), lengths, sliding_window=W,
+                      ring=True))
+    kc = _quant(34, (*_lead(layer), B, KVH, T, D))
+    vc = _quant(35, (*_lead(layer), B, KVH, T, D))
     _close(ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths,
-                            sliding_window=W, ring=True),
-           _decode_dq(q, kc, vc, lengths, sliding_window=W, ring=True))
+                            sliding_window=W, ring=True, layer=layer),
+           _decode_dq(q, *_of(layer, kc, vc), lengths, sliding_window=W,
+                      ring=True))
 
 
 @pytest.mark.parametrize("H,KVH,D", GEOMS)
