@@ -20,24 +20,24 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from localai_tpu.models import kv
+from localai_tpu.models.kv import (  # noqa: F401 — the names tests and the
+    FULL, WINDOW, PeriodKV, _decode_dq,  # engine import from here
+)
 from localai_tpu.ops.norms import rms_norm
 from localai_tpu.ops.rope import RopeConfig, rope_table, apply_rope
-from localai_tpu.ops.attention import mha_prefill, mha_decode
 from localai_tpu.ops.kvcache import (
-    QuantKV, cache_scatter, dequant, init_quant, is_quant_kind, padded_len,
-    requantize,
+    QuantKV, dequant, init_quant, is_quant_kind, padded_len, requantize,
 )
 from localai_tpu.ops.quant import qmatmul
-from localai_tpu.parallel.mesh import constrain
-
-
-FULL, WINDOW = "full", "window"     # LlamaConfig.layer_types entries
+from localai_tpu.parallel.mesh import (
+    activate_mesh, constrain, current_mesh, seq_axis_size,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -246,8 +246,6 @@ def replicated_specs(cfg: LlamaConfig, qbits: int | None = None):
     quantized {q, s} leaves when qbits is given). The right placement for a
     draft model whose dims don't divide the TP axis: drafts are small by
     design, so every chip holds a full copy."""
-    import jax
-
     return jax.tree_util.tree_map(lambda _: P(), param_specs(cfg, qbits))
 
 
@@ -285,20 +283,6 @@ def paged_pool_spec():
     the same head-parallelism the dense cache uses. Holds for both the q and
     s leaves of a QuantKV pool (same leading dims)."""
     return P(None, None, "model", None, None)
-
-
-@jax.tree_util.register_dataclass
-@dataclasses.dataclass
-class PeriodKV:
-    """K (or V) of a model with window and full layers: one cache per place
-    in the period of layer kinds, `slots[j]` of [L/period, B, KVH, T_j, D]
-    (dense or QuantKV) — T_j the served context for a FULL layer, the ring
-    for a WINDOW one. The layer scan of decode_step, prefill and extend
-    CARRIES the slots, as it carries the one [L, ...] cache of a one-kind
-    model, and place j of period i writes and reads slots[j][i] where it
-    lies (_scan_layers_carry: no layer's cache is sliced out, copied or put
-    back)."""
-    slots: tuple
 
 
 def ring_len(cfg: LlamaConfig, max_len: int, prefill_chunk: int,
@@ -351,6 +335,8 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
             PeriodKV(tuple(v for _, v in pairs)))
 
 
+# ---------------------------------------------------------------- forward
+
 # jax.named_scope below names the model's parts in the XLA ops' metadata
 # (`tf_op` in a device trace), so a trace viewer and tools/trace_gaps.py group
 # `fusion.256` and its kin by part. Trace-time only: the compilation cache's
@@ -358,93 +344,8 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
 # kernel is the exception: its serialized body carries the scope it was traced
 # under (and the source lines of its call stack), and the body is in the key.
 # So no scope encloses a kernel call; the kernels go by their own names
-# (ragged_decode_q8, flash_prefill, paged_scatter_append).
-@jax.named_scope("cache_update")
-def _cache_write(kc, vc, k, v, rows, positions, table=None, unique=True,
-                 redirect=None, kvt=None, ring_keep=None, layer=None):
-    """Scatter window K/V [B, S, KVH, D] into head-major caches [B', KVH, T, D]
-    at (rows[b], :, positions[b, s]). With a paged `table` [B, MAXB] the cache
-    is a block pool [NB, KVH, BS, D] and (slot, position) resolves to
-    (table[slot, pos // BS], :, pos % BS) — ops/paged.py layout.
-
-    layer (i32 scalar, dense only): the caches are the [L, B', KVH, T, D]
-    stack and the write lands at (layer, rows[b], :, positions[b, s]) — one
-    scatter into the stack, which stays where it is.
-
-    redirect [B] bool (paged only): rows flagged True write to the TRASH
-    block (physical 0, ops/paged.py) at offset (row*S + s) % BLOCK instead
-    of through their table — the inactive-slot redirect for decode (S=1)
-    and the spec-verify window (S=gamma+1). Routing by PHYSICAL block keeps
-    the garbage out of every real block (a slot's own table can map its
-    last virtual block to a RETAINED warm-prefix block); the per-(row, s)
-    offsets keep the scatter collision-free only while B*S <= BLOCK —
-    callers must drop the uniqueness assertion beyond that.
-
-    unique=True asserts the scatter rows never collide: decode rows target
-    distinct slots (one row per slot; redirected rows get distinct trash
-    offsets), so the assertion holds and keeps XLA on the in-place scatter
-    path — without it the table-gathered indices are unprovably unique and
-    the layer scan re-materializes the whole pool every decode step
-    (O(pool) per token). Callers pass unique=False when collisions are
-    REAL: batched admission pads groups by repeating a plan
-    (engine._flush_admits), and a final prefill chunk's padded tail
-    positions resolve to shared trash offsets — don't lie to the compiler
-    on those paths (both are per-request, not per-token).
-
-    kvt (paged only, KV lifecycle tier — engine/kvtier.py): per-slot
-    residency arrays {"sb": [B], "rw": [B], ...}; raw block indices are
-    ring-mapped (ops/paged.ring_block_map) before the table lookup, so a
-    windowed slot's writes reuse its O(window) ring columns in place.
-    Full-policy slots carry the identity sentinel — same program, no
-    recompile across policy mixes. Uniqueness survives the mapping: the
-    ring's wrap period (rw*BLOCK tokens) exceeds any single write window
-    by construction (kvtier.ring_blocks margins).
-
-    ring_keep [B, S] bool (dense only): the cache is a WINDOW layer's ring of
-    R = T rows, position p lives in row p mod R, and an entry that is not
-    kept (an inactive decode row, a prompt's padding, what a prompt longer
-    than the ring has before its tail) is aimed at row R: out of bounds,
-    which a scatter drops. A ring has no spare row to take such writes."""
-    kvh = kc.shape[-3]
-    if table is None:
-        if ring_keep is not None:
-            ring = kc.shape[-2]
-            positions = jnp.where(ring_keep, positions % ring, ring)
-        idx = (rows[:, None, None], jnp.arange(kvh)[None, :, None],
-               positions[:, None, :])
-        if layer is not None:
-            idx = (layer, *idx)
-    else:
-        from localai_tpu.ops.paged import BLOCK
-
-        raw = positions // BLOCK
-        if kvt is not None:
-            from localai_tpu.ops.paged import ring_block_map
-
-            raw = ring_block_map(raw, kvt["sb"][rows][:, None],
-                                 kvt["rw"][rows][:, None])
-        pb = table[rows[:, None], raw]                     # [B, S] physical
-        off = positions % BLOCK
-        if redirect is not None:
-            # distinct per-(row, window-pos) trash offsets: collision-free
-            # (and so assertable-unique) as long as B*S <= BLOCK
-            s = positions.shape[1]
-            tr_off = (rows[:, None] * s
-                      + jnp.arange(s)[None, :]) % BLOCK
-            pb = jnp.where(redirect[:, None], 0, pb)
-            off = jnp.where(redirect[:, None], tr_off, off)
-        idx = (pb[:, None, :], jnp.arange(kvh)[None, :, None],
-               off[:, None, :])
-    if isinstance(kc, QuantKV):
-        return (cache_scatter(kc, idx, k.transpose(0, 2, 1, 3), unique),
-                cache_scatter(vc, idx, v.transpose(0, 2, 1, 3), unique))
-    kc = kc.at[idx].set(k.transpose(0, 2, 1, 3), unique_indices=unique)
-    vc = vc.at[idx].set(v.transpose(0, 2, 1, 3), unique_indices=unique)
-    return kc, vc
-
-
-# ---------------------------------------------------------------- forward
-
+# (ragged_decode_q8, flash_prefill, paged_scatter_append): the views of
+# models/kv.py put "cache_update" around their XLA scatters alone.
 @jax.named_scope("attention")
 def _qkv(x, lp, cfg: LlamaConfig, spec=None):
     """QKV projections. `spec` (optional) is the head-parallel output
@@ -545,145 +446,7 @@ _shard_act = constrain
 def _seq_ax():
     """'seq' when the ambient mesh carries the ring-attention axis, else None
     (specs naming absent axes would raise)."""
-    from localai_tpu.parallel.mesh import current_mesh, seq_axis_size
-
     return "seq" if seq_axis_size(current_mesh()) > 1 else None
-
-
-def _tiered_kv(kc, vc, table_rows, sb, rw, length, ctab=None, ck=None,
-               cv=None):
-    """Materialize the RESIDENT (ring-mapped) cache view for the KV
-    lifecycle tier (engine/kvtier.py): the per-slot table gather
-    [B, MAXB*BS] plus explicit true positions and row validity, optionally
-    concatenated with the dequantized int8 cold tier.
-
-    table_rows [B, MAXB]; sb/rw/length [B] (already row-indexed by the
-    caller). ctab [B, MAXB_FULL] (quantize_cold): cold block per raw
-    virtual block, 0 = not demoted; ck/cv are the cold QuantKV pools for
-    this layer. Demoted blocks drop out of the hot view (their ring column
-    may already hold a newer generation's rows) and are read from the cold
-    pool at their true positions instead. Returns
-    (k [B, KVH, T, D], v, pos [B, T], ok [B, T]) — `ok` covers residency +
-    freshness (+ demotion state); retention masking (window/sinks) is the
-    attention caller's layer."""
-    from localai_tpu.ops.paged import (
-        BLOCK, paged_view, resident_block_positions, resident_row_positions,
-    )
-
-    maxb = table_rows.shape[1]
-    kr, vr = paged_view(kc, table_rows), paged_view(vc, table_rows)
-    pos, ok = resident_row_positions(maxb, sb, rw, length)
-    k, v = dequant(kr), dequant(vr)
-    if ctab is not None:
-        b = pos.shape[0]
-        mb_full = ctab.shape[1]
-        raw, _ = resident_block_positions(maxb, sb, rw, length)
-        demoted = ctab != 0                                # [B, MAXB_FULL]
-        hot_dem = jnp.take_along_axis(
-            demoted, jnp.clip(raw, 0, mb_full - 1), axis=1)
-        hot_dem = hot_dem & (raw >= 0) & (raw < mb_full)   # [B, MAXB]
-        keep = jnp.broadcast_to(~hot_dem[:, :, None],
-                                (b, maxb, BLOCK)).reshape(b, maxb * BLOCK)
-        ok = ok & keep
-        ckr = paged_view(ck, ctab)
-        cvr = paged_view(cv, ctab)
-        posc = jnp.arange(mb_full * BLOCK, dtype=jnp.int32)[None, :]
-        okc = jnp.broadcast_to(demoted[:, :, None],
-                               (b, mb_full, BLOCK)).reshape(b,
-                                                            mb_full * BLOCK)
-        okc = okc & (posc < length[:, None])
-        k = jnp.concatenate([k, dequant(ckr).astype(k.dtype)], axis=2)
-        v = jnp.concatenate([v, dequant(cvr).astype(v.dtype)], axis=2)
-        pos = jnp.concatenate(
-            [pos, jnp.broadcast_to(posc, (b, mb_full * BLOCK))], axis=1)
-        ok = jnp.concatenate([ok, okc], axis=1)
-    return k, v, pos, ok
-
-
-def _decode_dq(q, kc, vc, lengths, sliding_window=None, table=None,
-               kvt=None, ck=None, cv=None, ring=False, layer=None):
-    """XLA decode attention over a (possibly quantized) cache: dequant is
-    fused into the consuming dots by XLA; quantized caches still halve HBM
-    capacity on this path. A paged cache is materialized per layer via
-    gather (reference tier — the Pallas kernels stream through the table).
-    `layer`: kc/vc are [L, ...] stacks and layer `layer` of them is read.
-
-    kvt (KV lifecycle tier, engine/kvtier.py): per-slot residency arrays —
-    the gather covers only the RESIDENT ring view (O(sinks+window) rows for
-    windowed slots, identity for full-policy slots in the same program) and
-    the mask derives from true ring positions; with quantize_cold (ck/cv —
-    this layer's cold pools) the exited-window blocks attend from the int8
-    cold tier instead of being dropped."""
-    if layer is not None:
-        kc, vc = kc[layer], vc[layer]
-    if kvt is not None:
-        from localai_tpu.ops.attention import mha_decode_masked
-
-        cold = "cold_tab" in kvt
-        k, v, pos, ok = _tiered_kv(
-            kc, vc, table, kvt["sb"], kvt["rw"], lengths,
-            ctab=kvt["cold_tab"] if cold else None, ck=ck, cv=cv)
-        if cold:
-            mask = ok  # demotion state decides hot vs cold; nothing evicted
-        else:
-            mask = ok & ((pos >= (lengths - kvt["window"])[:, None])
-                         | (pos < kvt["sinks"][:, None]))
-        return mha_decode_masked(q, k, v, mask)
-    if ring:
-        # a WINDOW layer's ring: row p mod R holds position p, so the rows
-        # in the window are those at most window - 1 behind the newest
-        from localai_tpu.ops.attention import mha_decode_masked
-
-        mask = (_ring_back(lengths - 1, kc.shape[2])
-                < jnp.minimum(lengths, sliding_window)[:, None])
-        return mha_decode_masked(q, dequant(kc), dequant(vc), mask)
-    if table is not None:
-        from localai_tpu.ops.paged import paged_view
-
-        kc, vc = paged_view(kc, table), paged_view(vc, table)
-    return mha_decode(q, dequant(kc), dequant(vc), lengths,
-                      sliding_window=sliding_window)
-
-
-def _pallas_attention(mesh) -> bool:
-    """Whether attention runs on the Pallas kernels: on TPU without a mesh
-    (a mesh sends attention to XLA so GSPMD shards the einsums).
-    LOCALAI_FORCE_PALLAS=1 forces Pallas (interpreter off-TPU — tests);
-    LOCALAI_NO_PALLAS=1 is the one deliberate way to XLA on a TPU. Nothing
-    else chooses: a kernel Mosaic refuses fails the compile that uses it
-    (LoadModel's warmup) with its own message."""
-    import os
-
-    if os.environ.get("LOCALAI_FORCE_PALLAS") == "1":
-        return True
-    return (mesh is None and os.environ.get("LOCALAI_NO_PALLAS") != "1"
-            and jax.default_backend() == "tpu")
-
-
-def _pallas_paged_scatter(cfg: LlamaConfig | None) -> bool:
-    """Whether the paged decode write uses the Pallas scatter-append kernel
-    (ops/pallas/paged_scatter.py) instead of the XLA scatter: on TPU or
-    under LOCALAI_FORCE_PALLAS; XLA on CPU and under LOCALAI_NO_PALLAS.
-
-    Under a mesh the pool shards its KV-head axis on 'model' and the kernel
-    runs per-shard via shard_map (paged_scatter_append_sharded) — usable iff
-    the KV-head count divides the TP axis; otherwise the XLA scatter tier
-    handles the (unevenly shardable) pool."""
-    import os
-
-    from localai_tpu.parallel.mesh import current_mesh
-
-    mesh = current_mesh()
-    if mesh is not None:
-        if cfg is None:
-            return False
-        tp = dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
-        if cfg.num_kv_heads % int(tp):
-            return False
-    if os.environ.get("LOCALAI_FORCE_PALLAS") == "1":
-        return True
-    return (os.environ.get("LOCALAI_NO_PALLAS") != "1"
-            and jax.default_backend() == "tpu")
 
 
 def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
@@ -693,13 +456,12 @@ def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
     for the backend's device report. 'pallas-interpret' means the Pallas
     kernels run in the interpreter (forced off-TPU): correct, never fast."""
     from localai_tpu.ops.pallas.flash_attention import _interpret
-    from localai_tpu.parallel.mesh import activate_mesh, seq_axis_size
 
     pallas = "pallas-interpret" if _interpret() else "pallas"
-    attn = pallas if _pallas_attention(mesh) else "xla"
+    attn = pallas if kv._pallas_attention(mesh) else "xla"
     with activate_mesh(mesh):
-        paged_kernel = pallas if paged and _pallas_paged_scatter(cfg) \
-            else "xla"
+        paged_kernel = pallas if paged and kv._pallas_paged_scatter(
+            cfg.num_kv_heads) else "xla"
     prefill = "xla-ring" if attn == "xla" and seq_axis_size(mesh) > 1 \
         else attn
     tiers = {
@@ -722,54 +484,6 @@ def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
     return tiers
 
 
-def _attn_impls():
-    """Select attention kernels at trace time: Pallas (fused, online-softmax)
-    on single-chip TPU; XLA reference under a mesh (GSPMD shards the einsums)
-    or on CPU — see _pallas_attention."""
-    from localai_tpu.parallel.mesh import current_mesh, seq_axis_size
-
-    mesh = current_mesh()
-    if _pallas_attention(mesh):
-        from localai_tpu.ops.pallas import (
-            flash_prefill, ragged_decode, ragged_decode_q8,
-        )
-
-        def attn_decode(q, kc, vc, lengths, sliding_window=None, table=None,
-                        kvt=None, ck=None, cv=None, ring=False, layer=None):
-            if kvt is not None:
-                # KV lifecycle tier: the ring-position/tier-map read rides
-                # the XLA reference path for now — the Pallas decode kernel
-                # has no per-slot ring-geometry scalar prefetch yet (the
-                # WRITE side is kernel-native: paged_scatter's targets are
-                # ring-mapped before the DMA kernel). TODO(kvtier): teach
-                # _decode_kernel the ring map + per-block dtype tier.
-                return _decode_dq(q, kc, vc, lengths,
-                                  sliding_window=sliding_window, table=table,
-                                  kvt=kvt, ck=ck, cv=cv)
-            if isinstance(kc, QuantKV):
-                return ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths,
-                                        sliding_window=sliding_window,
-                                        table=table, ring=ring, layer=layer)
-            return ragged_decode(q, kc, vc, lengths,
-                                 sliding_window=sliding_window, table=table,
-                                 ring=ring, layer=layer)
-
-        return (lambda q, k, v, lengths, sliding_window=None:
-                flash_prefill(q, k, v, lengths, sliding_window=sliding_window),
-                attn_decode)
-    if seq_axis_size(mesh) > 1:
-        # sequence parallelism: prefill rides the ppermute ring over the
-        # 'seq' axis (parallel/ring_attention.py); decode (S=1) stays on
-        # the XLA path with GSPMD sharding
-        from localai_tpu.parallel.ring_attention import ring_prefill
-
-        return (lambda q, k, v, lengths, sliding_window=None:
-                ring_prefill(q, k, v, lengths, mesh=mesh,
-                             sliding_window=sliding_window),
-                _decode_dq)
-    return mha_prefill, _decode_dq
-
-
 def rope_tables(cfg: LlamaConfig, max_len: int):
     """The (cos, sin) the forwards take: one pair of tables for a one-kind
     model; for a model with layer_types a pair of dicts keyed by layer kind
@@ -782,27 +496,12 @@ def rope_tables(cfg: LlamaConfig, max_len: int):
             {kind: t[1] for kind, t in tabs.items()})
 
 
-def _layer_rope(cos, sin, kind):
-    return (cos, sin) if kind is None else (cos[kind], sin[kind])
-
-
-def _layer_window(cfg: LlamaConfig, kind):
-    """The attention window of a layer of `kind` (None: a one-kind model)."""
-    return None if kind == FULL else cfg.sliding_window
-
-
 def _attn_scope(kind):
     """Device time by layer kind: a mixed model's attention (projections,
     kernel and all: it has no older compile-cache key to keep) is traced
     under attention/<kind>; a one-kind model's as it always was."""
     return (contextlib.nullcontext() if kind is None
             else jax.named_scope(f"attention/{kind}"))
-
-
-def _ring_back(newest, ring: int):
-    """[B, R]: how many positions behind `newest` [B] the entry in each row
-    of a ring of R rows is (row p mod R holds position p)."""
-    return jnp.mod(newest[:, None] - jnp.arange(ring)[None, :], ring)
 
 
 def _layer_params(layers, i):
@@ -814,99 +513,102 @@ def _layer_params(layers, i):
         lambda a: jax.lax.dynamic_index_in_dim(a, i, keepdims=False), layers)
 
 
-def _scan_layers_carry(cfg: LlamaConfig, body, x, layers, k_cache, v_cache):
-    """_scan_layers with the stacked caches as the scan's CARRY: run
-    `body(x, lp, kc, vc, kind, li=i) -> (x, (kc, vc))` over the layers, where
-    kc / vc are the whole [L', B, KVH, T, D] stacks (a one-kind model's
-    cache; one slot of a PeriodKV) and `i` is the layer's index into them.
-    `body` writes and reads layer i where it lies — one scatter into the
-    stack, a kernel whose index maps take i, a gather of the rows a chunk
-    attends over — so no cache is an xs or a ys and nothing is sliced out,
-    put back or copied (on a v5e the xs/ys form cost a decode step 3.6 ms of
-    20 on Mixtral-8x7B at 6 layers and 12.5 of 37 on Mellum2; PERF.md)."""
-    period = cfg.period
-    if period is None:
-        def layer(carry, xs):
-            x, kc, vc = carry
-            lp, i = xs
-            x, (kc, vc) = body(x, lp, kc, vc, None, li=i)
-            return (x, kc, vc), None
+def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
+    """The transformer block, once: attn_norm → QKV → RoPE by layer kind →
+    `attend` → wo → mlp_norm → MLP over the residual x [B, S, H]. Every
+    forward (and a pipeline stage) is this block over its own
+    `attend(q, k, v) -> (attn [B, S, H, D], out)`: self-attention over a
+    prompt, or a write into the cache's view and a read back; `out` goes
+    back to the scan (the view written; K and V to write after the block).
+    `attend` opens `_attn_scope(kind)` around what counts as attention, and
+    around no cache-writing kernel.
 
-        (x, k_cache, v_cache), _ = jax.lax.scan(
-            layer, (x, k_cache, v_cache),
+    spec: (batch axis, sequence axis) of the activations, e.g. ('data',
+    None): under a mesh the projections' outputs are constrained head-/ffn-
+    parallel on 'model' and the residual back to replicated, the hints that
+    keep TP weights resident-sharded through the scan. None under shard_map
+    (parallel/pipeline.py), where constraints are illegal."""
+    b, s, _ = x.shape
+    sharded = lambda *tail: spec and P(*spec, *tail)  # noqa: E731
+    lcos, lsin = (cos, sin) if kind is None else (cos[kind], sin[kind])
+    h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
+    with _attn_scope(kind):
+        q, k, v = _qkv(h, lp, cfg, spec=sharded("model"))
+        q = apply_rope(q, lcos, lsin, positions)
+        k = apply_rope(k, lcos, lsin, positions)
+        if spec is not None:
+            q = _shard_act(q, sharded("model", None))
+    attn, out = attend(q, k, v)
+    with _attn_scope(kind), jax.named_scope("attention"):
+        x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"], spec=sharded(None))
+    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
+    x = x + _mlp(h, lp, cfg, spec_prefix=spec)
+    if spec is not None:
+        x = _shard_act(x, sharded(None))
+    return x, out
+
+
+def _scan_layers(cfg: LlamaConfig, block, x, layers, cache):
+    """Run `block(x, lp, view, kind) -> (x, view)` over the layer stack and
+    return (x, (k_cache, v_cache)) as the engine holds them. `cache` is the
+    forward's kv.view; how it rides the scan is its `carried`, read here
+    and nowhere else. Carried (a dense stack, a ring): the stacks are the
+    scan's CARRY and the block gets the view `at` the layer's index — no
+    cache is an xs or a ys, nothing is sliced out, put back or copied. Not
+    carried (a block pool; no cache): k, v and a tier's read-only cold
+    pools are the scan's xs and ys, so XLA slices each layer's pool out of
+    the stack and writes it back (and copies the stack where a loop carries
+    it) — what a paged or tiered cache still pays (kv.PagedKV).
+
+    One kind of layer: lax.scan over [L, ...] with kind None, the program a
+    one-kind model always had. With layer_types `cache` is one view per
+    place in the PERIOD of kinds and the scan's body is one period,
+    unrolled: compile time grows with the period, not the depth."""
+    period = cfg.period
+    if period is None and cache.carried:
+        def layer(carry, xs):
+            x, k, v = carry
+            lp, i = xs
+            x, view = block(x, lp, cache.at(k, v, i), None)
+            return (x, view.k, view.v), None
+
+        (x, k, v), _ = jax.lax.scan(
+            layer, (x, cache.k, cache.v),
             (layers, jnp.arange(cfg.num_layers)))
-        return x, (k_cache, v_cache)
+        return x, (k, v)
+    if period is None:
+        def layer(x, xs):
+            lp, k, v, *cold = xs
+            x, view = block(x, lp, cache.at(k, v, cold=cold), None)
+            return x, (view.k, view.v)
+
+        return jax.lax.scan(layer, x,
+                            (layers, cache.k, cache.v, *cache.cold))
     p = len(period)
 
     def step(carry, i):
         x, ks, vs = carry
         ks, vs = list(ks), list(vs)
         for j, kind in enumerate(period):
-            x, (ks[j], vs[j]) = body(x, _layer_params(layers, i * p + j),
-                                     ks[j], vs[j], kind, li=i)
+            x, view = block(x, _layer_params(layers, i * p + j),
+                            cache[j].at(ks[j], vs[j], i), kind)
+            ks[j], vs[j] = view.k, view.v
         return (x, tuple(ks), tuple(vs)), None
 
     (x, ks, vs), _ = jax.lax.scan(
-        step, (x, k_cache.slots, v_cache.slots),
+        step, (x, tuple(c.k for c in cache), tuple(c.v for c in cache)),
         jnp.arange(cfg.num_layers // p))
     return x, (PeriodKV(ks), PeriodKV(vs))
 
 
-def _scan_layers(cfg: LlamaConfig, body, x, layers, k_cache=None,
-                 v_cache=None, extra=(), carry=False):
-    """Run `body(x, lp, kc, vc, kind, *extra) -> (x, (kc, vc))` over the
-    layer stack and return (x, (k_cache, v_cache)). carry=True (a dense
-    cache, no extras): _scan_layers_carry instead.
-
-    One kind of layer: lax.scan over [L, ...] with kind None, the program a
-    one-kind model always had. With layer_types the scan's body is one
-    PERIOD of kinds, unrolled, over a PeriodKV's per-place caches: compile
-    time grows with the period, not the depth.
-
-    The caches are the scan's xs and ys, so XLA slices each layer's cache
-    out of the stack for `body` and writes it back (and copies the stack
-    where a loop carries it). That is what a paged or tiered cache still
-    pays, whose per-layer pools a kernel aliases, and what a forward with no
-    cache (hidden_states) has nothing to pay for; every forward over a dense
-    cache takes _scan_layers_carry."""
-    if carry:
-        return _scan_layers_carry(cfg, body, x, layers, k_cache, v_cache)
-    period = cfg.period
-    if period is None:
-        def layer(x, xs):
-            lp, kc, vc, *ex = xs
-            return body(x, lp, kc, vc, None, *ex)
-
-        return jax.lax.scan(layer, x, (layers, k_cache, v_cache, *extra))
-    if extra:
-        raise NotImplementedError("layer_types with per-layer extras")
-    p = len(period)
-    cached = k_cache is not None
-    ks = k_cache.slots if cached else (None,) * p
-    vs = v_cache.slots if cached else (None,) * p
-
-    def step(x, xs):
-        i, kcs, vcs = xs
-        ko, vo = [], []
-        for j, kind in enumerate(period):
-            x, (kc, vc) = body(x, _layer_params(layers, i * p + j), kcs[j],
-                               vcs[j], kind)
-            ko.append(kc)
-            vo.append(vc)
-        return x, (tuple(ko), tuple(vo))
-
-    x, (ko, vo) = jax.lax.scan(
-        step, x, (jnp.arange(cfg.num_layers // p), ks, vs))
-    if not cached:
-        return x, (None, None)
-    return x, (PeriodKV(ko), PeriodKV(vo))
-
-
-def _no_mixed(cfg: LlamaConfig, what: str):
-    if cfg.layer_types is not None:
-        raise NotImplementedError(
-            f"{what} does not take a model with window and full layers "
-            "(layer_types): it knows one cache per layer stack")
+def _embed(params, cfg: LlamaConfig, tokens, inject=None):
+    """Token embeddings; where inject's is_embed is set, its `extra` rows
+    (the multimodal path, models/llava.py, splices image features here)."""
+    x = params["embed"].astype(cfg.jdtype)[tokens]
+    if inject is not None:
+        extra, is_embed = inject
+        x = jnp.where(is_embed[..., None], extra.astype(x.dtype), x)
+    return x
 
 
 def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
@@ -922,70 +624,31 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     Returns (last_token_logits [B, V] f32, k_cache, v_cache).
     """
     b, s = tokens.shape
-    attn_prefill, _ = _attn_impls()
-    if kvt is not None:
-        # KV lifecycle tier: first-chunk self-attention under the per-slot
-        # sink+window retention mask (engine/kvtier.py). quantize_cold slots
-        # keep full causal coverage (exited content is demoted, not
-        # dropped), so the window term is lifted to a sentinel there.
-        from localai_tpu.ops.attention import mha_prefill_tiered
-
-        _sinks = kvt["sinks"][slot_map]
-        _window = kvt["window"][slot_map]
-        if "cold_tab" in kvt:
-            _window = jnp.full_like(_window, jnp.int32(1 << 30))
-
-        def attn_prefill(q, k, v, lengths, sliding_window=None):  # noqa: F811
-            return mha_prefill_tiered(q, k, v, lengths, _sinks, _window)
+    cache = kv.view(cfg, k_cache, v_cache, table, kvt)
+    self_attention = kv.prompt_attention(cache, slot_map)
     positions = jnp.arange(s)[None, :].repeat(b, 0)
     sax = _seq_ax()
-    x = params["embed"].astype(cfg.jdtype)[tokens]
-    if inject is not None:
-        extra, is_embed = inject
-        x = jnp.where(is_embed[..., None], extra.astype(x.dtype), x)
-    x = _shard_act(x, P("data", sax, None))
+    x = _shard_act(_embed(params, cfg, tokens, inject), P("data", sax, None))
 
-    if table is not None or kvt is not None:
-        _no_mixed(cfg, "a paged or tiered prefill")
+    def layer(x, lp, view, kind):
+        def attend(q, k, v):
+            with _attn_scope(kind):
+                return self_attention(q, k, v, lengths,
+                                      sliding_window=view.window), (k, v)
 
-    stacked = table is None and kvt is None   # as in decode_step
-
-    def layer(x, lp, kc, vc, kind, li=None):
-        lcos, lsin = _layer_rope(cos, sin, kind)
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        with _attn_scope(kind):
-            q, k, v = _qkv(h, lp, cfg, spec=P("data", sax, "model"))
-            q = apply_rope(q, lcos, lsin, positions)
-            k = apply_rope(k, lcos, lsin, positions)
-            q = _shard_act(q, P("data", sax, "model", None))
-            attn = attn_prefill(q, k, v, lengths,
-                                sliding_window=_layer_window(cfg, kind))
-            with jax.named_scope("attention"):
-                x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
-                                spec=P("data", sax, None))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(h, lp, cfg, spec_prefix=("data", sax))
-        x = _shard_act(x, P("data", sax, None))
-        keep = None
-        if kind == WINDOW:
-            # a ring takes the prompt's own tokens only, and of a prompt
-            # longer than the ring its tail
-            keep = ((positions < lengths[:, None])
-                    & (positions >= lengths[:, None] - kc.shape[-2]))
+        x, (k, v) = _block(cfg, x, lp, kind, cos, sin, positions, attend,
+                           ("data", sax))
         # unique=False: batched admission pads groups by repeating a real
         # request's plan (engine _flush_admits), so slot_map can repeat
-        kc, vc = _cache_write(kc, vc, k, v, slot_map, positions, table,
-                              unique=False, kvt=kvt, ring_keep=keep, layer=li)
-        return x, (kc, vc)
+        return x, view.write(k, v, slot_map, positions, end=lengths,
+                             unique=False)
 
-    x, (k_cache, v_cache) = _scan_layers(
-        cfg, layer, x, params["layers"], k_cache, v_cache, carry=stacked)
+    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params["layers"],
+                                         cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     last = jnp.take_along_axis(
-        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1
-    )[:, 0]
-    logits = _lm_head(last.astype(jnp.float32), params)
-    return logits, k_cache, v_cache
+        x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
+    return _lm_head(last.astype(jnp.float32), params), k_cache, v_cache
 
 
 def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
@@ -1000,115 +663,27 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     max_context-1) so a decode step can run concurrently with a chunked
     prefill into an inactive slot without corrupting it.
     `table` [B, MAXB] i32 (optional): block-paged cache (ops/paged.py) — the
-    redirect row then resolves through the table's last virtual block, which
-    is the trash block for any slot not allocated to full context.
+    redirect then goes to the trash block (kv.PagedKV).
     Returns (logits [B, V] f32, k_cache, v_cache).
     """
-    b = tokens.shape[0]
-    if table is not None or kvt is not None:
-        _no_mixed(cfg, "a paged or tiered decode step")
-    kv_quant = isinstance(k_cache, QuantKV)
-    _, attn_decode = _attn_impls()
     positions = lengths[:, None]  # [B,1]
-    redirect = None
-    if active is not None and table is not None:
-        # paged: inactive rows write to the trash block at distinct per-row
-        # offsets (_cache_write redirect) — never through their own table,
-        # whose last virtual block can be a RETAINED warm-prefix block
-        redirect = ~active
-    unique = table is None or b <= 128
-    # paged Pallas tier: the per-step write is a scatter-append DMA kernel
-    # (O(slots) traffic, provably in place) instead of an XLA scatter
-    # through gathered physical indices — the scatter XLA de-optimizes into
-    # a full-pool copy inside the fused decode block (VERDICT Weak #2)
-    kernel_write = table is not None and _pallas_paged_scatter(cfg)
-    # under a mesh the pool shards its KV-head axis: the kernel runs
-    # per-shard via shard_map (pallas_call has no GSPMD partitioning rule —
-    # without this the partitioner would all-gather the whole pool)
-    write_mesh = None
-    if kernel_write:
-        from localai_tpu.parallel.mesh import current_mesh
-
-        write_mesh = current_mesh()
-    x = params["embed"].astype(cfg.jdtype)[tokens][:, None, :]  # [B,1,H]
+    cache = kv.view(cfg, k_cache, v_cache, table, kvt, active=active)
+    x = _embed(params, cfg, tokens)[:, None, :]  # [B,1,H]
     x = _shard_act(x, P("data", None, None))
-    # KV lifecycle tier: the cold pools (per-layer, like kc/vc) ride the scan
-    # as extra READ-ONLY xs — the demote copy is a separate host-driven jit
-    # (engine._demote_fn), so ys stays (kc, vc)
-    cold = kvt is not None and "cold_tab" in kvt
-    sb = rw = None
-    if kvt is not None:
-        sb, rw = kvt["sb"], kvt["rw"]
 
-    # a dense cache rides the layer scan as its carry and every touch of it
-    # names the layer (_scan_layers_carry; prefill and extend do the same);
-    # paged and tiered pools keep the xs/ys form their kernels' aliasing was
-    # written for
-    stacked = table is None and kvt is None
+    def layer(x, lp, view, kind):
+        def attend(q, k, v):
+            # the new token lands in the cache FIRST; attention then reads
+            # it back with the rest (lengths + 1 counts it)
+            wrote = view.append(k, v, lengths, positions)
+            with _attn_scope(kind):
+                return wrote.decode(q, lengths + 1), wrote
 
-    def layer(x, lp, kc, vc, kind, ck=None, cv=None, li=None):
-        ring = kind == WINDOW
-        lcos, lsin = _layer_rope(cos, sin, kind)
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        with _attn_scope(kind):
-            q, k, v = _qkv(h, lp, cfg, spec=P("data", None, "model"))
-            q = apply_rope(q, lcos, lsin, positions)
-            k = apply_rope(k, lcos, lsin, positions)
-            q = _shard_act(q, P("data", None, "model", None))
-        if kernel_write:
-            from localai_tpu.ops.pallas import (
-                paged_scatter_append, paged_scatter_append_q8,
-                paged_scatter_append_q8_sharded, paged_scatter_append_sharded,
-            )
+        return _block(cfg, x, lp, kind, cos, sin, positions, attend,
+                      ("data", None))
 
-            if kv_quant:
-                if write_mesh is not None:
-                    kq, ks, vq, vs = paged_scatter_append_q8_sharded(
-                        write_mesh, kc.q, kc.s, vc.q, vc.s, k[:, 0], v[:, 0],
-                        lengths, table, active, sb=sb, rw=rw)
-                else:
-                    kq, ks, vq, vs = paged_scatter_append_q8(
-                        kc.q, kc.s, vc.q, vc.s, k[:, 0], v[:, 0], lengths,
-                        table, active, sb=sb, rw=rw)
-                kc, vc = QuantKV(kq, ks), QuantKV(vq, vs)
-            elif write_mesh is not None:
-                kc, vc = paged_scatter_append_sharded(
-                    write_mesh, kc, vc, k[:, 0], v[:, 0], lengths, table,
-                    active, sb=sb, rw=rw)
-            else:
-                kc, vc = paged_scatter_append(kc, vc, k[:, 0], v[:, 0],
-                                              lengths, table, active,
-                                              sb=sb, rw=rw)
-        else:
-            wpos, keep = positions, None
-            if ring:
-                # an inactive row's write is dropped (_cache_write)
-                keep = (jnp.ones((b, 1), bool) if active is None
-                        else active[:, None])
-            elif active is not None and table is None:
-                # dense: each row owns its slot row, so T-1 (never readable
-                # — the engine terminates at max_context-2) is a safe
-                # per-row target
-                wpos = jnp.where(active[:, None], positions,
-                                 kc.shape[-2] - 1)
-            kc, vc = _cache_write(kc, vc, k, v, jnp.arange(b), wpos, table,
-                                  unique=unique, redirect=redirect, kvt=kvt,
-                                  ring_keep=keep, layer=li)
-        with _attn_scope(kind):
-            attn = attn_decode(q, kc, vc, lengths + 1,
-                               sliding_window=_layer_window(cfg, kind),
-                               table=table, kvt=kvt, ck=ck, cv=cv, ring=ring,
-                               layer=li)
-            with jax.named_scope("attention"):
-                x = x + qmatmul(attn.reshape(b, 1, -1), lp["wo"],
-                                spec=P("data", None, None))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(h, lp, cfg, spec_prefix=("data", None))
-        return x, (kc, vc)
-
-    x, (k_cache, v_cache) = _scan_layers(
-        cfg, layer, x, params["layers"], k_cache, v_cache,
-        extra=(kvt["cold_k"], kvt["cold_v"]) if cold else (), carry=stacked)
+    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params["layers"],
+                                         cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = _lm_head(x[:, 0].astype(jnp.float32), params)
     return logits, k_cache, v_cache
@@ -1153,138 +728,87 @@ def ragged_forward(params, cfg: LlamaConfig, tokens, cos, sin,
     selection matches the decode path: Pallas ragged kernels on TPU (or
     LOCALAI_FORCE_PALLAS), sharded per KV-head shard under a TP mesh, XLA
     gather/scatter twins otherwise."""
-    from localai_tpu.ops.pallas import (
-        QBLK, ragged_attention_xla, ragged_attention_xla_q8,
-        ragged_paged_attention, ragged_paged_attention_q8,
-        ragged_paged_attention_q8_sharded, ragged_paged_attention_sharded,
-        ragged_scatter_append, ragged_scatter_append_q8,
-        ragged_scatter_append_q8_sharded, ragged_scatter_append_sharded,
-        ragged_scatter_xla, ragged_scatter_xla_q8,
-    )
+    from localai_tpu.ops.pallas import QBLK
 
-    _no_mixed(cfg, "ragged_forward")
+    cache = kv.view(cfg, k_cache, v_cache, tables, kvt, pool=True)
     t = tokens.shape[0]
-    kv_quant = isinstance(k_cache, QuantKV)
-    blk = (k_cache.q if kv_quant else k_cache).shape[3]        # pool BS
-    use_kernel = _pallas_paged_scatter(cfg)
-    mesh = None
-    if use_kernel:
-        from localai_tpu.parallel.mesh import current_mesh
-
-        mesh = current_mesh()
-    block_seq = block_seq.astype(jnp.int32)
-    qstart, qlen = qstart.astype(jnp.int32), qlen.astype(jnp.int32)
-    kvlen = kvlen.astype(jnp.int32)
+    block_seq, qstart, qlen, kvlen = (
+        a.astype(jnp.int32) for a in (block_seq, qstart, qlen, kvlen))
 
     # per-row derivations (device-side, from per-seq metadata): sequence id,
-    # liveness, absolute position, and the (physical block, in-block row)
-    # scatter target. Dead rows target trash (block 0) at per-row offsets —
-    # collisions there only overwrite other dead rows.
+    # liveness and absolute position; the view resolves each row's scatter
+    # target from them (kv.PagedKV.stream)
     rows = jnp.arange(t, dtype=jnp.int32)
     sid = block_seq[rows // QBLK]
     s = jnp.maximum(sid, 0)
     live = (sid >= 0) & (rows >= qstart[s]) & (rows < qstart[s] + qlen[s])
     pos = kvlen[s] - qlen[s] + (rows - qstart[s])
     pos = jnp.where(live, jnp.clip(pos, 0, cos.shape[0] - 1), 0)
-    raw = pos // blk
-    if kvt is not None:
-        # KV lifecycle tier: fold raw blocks into the per-sequence ring
-        # before the table lookup (kvt ships [NSEQ] geometry, like tables)
-        from localai_tpu.ops.paged import ring_block_map
+    cache = cache.stream(block_seq, qstart, qlen, kvlen, rows, pos, live, s)
+    x = _embed(params, cfg, tokens, inject)[None]              # [1, T, H]
 
-        raw = ring_block_map(raw, kvt["sb"][s], kvt["rw"][s])
-    pb = jnp.where(live, tables[s, raw], 0)
-    off = jnp.where(live, pos % blk, rows % blk)
+    def layer(x, lp, view, kind):
+        def attend(q, k, v):
+            # current chunk lands in the pool FIRST (decode_step convention:
+            # attention then reads it back through the table — kvlen already
+            # counts it), so prefill chunks attend to themselves paged
+            wrote = view.write_stream(k[0], v[0])
+            return wrote.attend_stream(q[0]), wrote
 
-    def write(kc, vc, kn, vn):
-        if use_kernel and kv_quant:
-            if mesh is not None:
-                kq, ks, vq, vs = ragged_scatter_append_q8_sharded(
-                    mesh, kc.q, kc.s, vc.q, vc.s, kn, vn, pb, off)
-            else:
-                kq, ks, vq, vs = ragged_scatter_append_q8(
-                    kc.q, kc.s, vc.q, vc.s, kn, vn, pb, off)
-            return QuantKV(kq, ks), QuantKV(vq, vs)
-        if use_kernel:
-            if mesh is not None:
-                return ragged_scatter_append_sharded(mesh, kc, vc, kn, vn,
-                                                     pb, off)
-            return ragged_scatter_append(kc, vc, kn, vn, pb, off)
-        if kv_quant:
-            kq, ks, vq, vs = ragged_scatter_xla_q8(
-                kc.q, kc.s, vc.q, vc.s, kn, vn, pb, off)
-            return QuantKV(kq, ks), QuantKV(vq, vs)
-        return ragged_scatter_xla(kc, vc, kn, vn, pb, off)
+        return _block(cfg, x, lp, kind, cos, sin, pos[None], attend,
+                      (None, None))
 
-    def attend(qf, kc, vc):
-        sw = cfg.sliding_window
-        if kvt is not None:
-            # tiered reads ride the XLA twins (ring positions + retention
-            # masking); the ragged kernel's table streaming has no ring
-            # inverse yet. TODO(kvtier): _kv_map + _row_mask ring support.
-            if kv_quant:
-                return ragged_attention_xla_q8(
-                    qf, kc.q, kc.s, vc.q, vc.s, block_seq, qstart, qlen,
-                    kvlen, tables, sliding_window=sw, kvt=kvt)
-            return ragged_attention_xla(qf, kc, vc, block_seq, qstart,
-                                        qlen, kvlen, tables,
-                                        sliding_window=sw, kvt=kvt)
-        if use_kernel and kv_quant:
-            if mesh is not None:
-                return ragged_paged_attention_q8_sharded(
-                    mesh, qf, kc.q, kc.s, vc.q, vc.s, block_seq, qstart,
-                    qlen, kvlen, tables, sliding_window=sw)
-            return ragged_paged_attention_q8(
-                qf, kc.q, kc.s, vc.q, vc.s, block_seq, qstart, qlen, kvlen,
-                tables, sliding_window=sw)
-        if use_kernel:
-            if mesh is not None:
-                return ragged_paged_attention_sharded(
-                    mesh, qf, kc, vc, block_seq, qstart, qlen, kvlen,
-                    tables, sliding_window=sw)
-            return ragged_paged_attention(qf, kc, vc, block_seq, qstart,
-                                          qlen, kvlen, tables,
-                                          sliding_window=sw)
-        if kv_quant:
-            return ragged_attention_xla_q8(
-                qf, kc.q, kc.s, vc.q, vc.s, block_seq, qstart, qlen, kvlen,
-                tables, sliding_window=sw)
-        return ragged_attention_xla(qf, kc, vc, block_seq, qstart, qlen,
-                                    kvlen, tables, sliding_window=sw)
-
-    emb = params["embed"].astype(cfg.jdtype)[tokens]           # [T, H]
-    if inject is not None:
-        extra, is_embed = inject
-        emb = jnp.where(is_embed[:, None], extra.astype(cfg.jdtype), emb)
-    x = emb[None]                                              # [1, T, H]
-
-    def layer(x, xs):
-        lp, kc, vc = xs
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(h, lp, cfg, spec=P(None, None, "model"))
-        q = apply_rope(q, cos, sin, pos[None])
-        k = apply_rope(k, cos, sin, pos[None])
-        q = _shard_act(q, P(None, None, "model", None))
-        # current chunk lands in the pool FIRST (decode_step convention:
-        # attention then reads it back through the table — kvlen already
-        # counts it), so prefill chunks attend to themselves paged
-        kc, vc = write(kc, vc, k[0], v[0])
-        attn = attend(q[0], kc, vc)
-        with jax.named_scope("attention"):
-            x = x + qmatmul(attn.reshape(1, t, -1), lp["wo"],
-                            spec=P(None, None, None))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(h, lp, cfg, spec_prefix=(None, None))
-        return x, (kc, vc)
-
-    x, (k_cache, v_cache) = jax.lax.scan(
-        layer, x, (params["layers"], k_cache, v_cache)
-    )
+    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params["layers"],
+                                         cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     # [NSEQ, H] for 1-D logit_rows, [NSEQ, R, H] for the 2-D spec windows
     last = x[0][logit_rows.astype(jnp.int32)]
     logits = _lm_head(last.astype(jnp.float32), params)
     return logits, k_cache, v_cache
+
+
+def _loop_body(step_fn, limit: int, consts, stop, gmasks, gtrans):
+    """The iteration build_decode_loop and build_ragged_loop share: one
+    sample→decode `step_fn` over the slots still live, per-slot stops from
+    stop = (remaining, check_eos, eos_ids) and the static `limit`; consts =
+    (params, cos, sin, fast_width, table, kvt)."""
+    params, cos, sin, fast_width, table, kvt = consts
+    remaining, check_eos, eos_ids = stop
+    grammar = gmasks is not None
+
+    def body(carry):
+        (i, done, n_out, toks, lps, gstate, kc, vc, sampler,
+         last_logits, lengths) = carry
+        live = ~done
+        prev_key = sampler.key
+        mask = gmasks[gstate] if grammar else None
+        tokens, lp, kc, vc, sampler, logits, lengths = step_fn(
+            params, cos, sin, kc, vc, sampler, last_logits, lengths,
+            live, mask, fast_width, table, kvt)
+        # freeze finished slots: their key stream and last_logits hold
+        # at the finishing token (step_fn already gates lengths and
+        # token_counts on the active mask)
+        sampler = dataclasses.replace(
+            sampler, key=jnp.where(live[:, None], sampler.key, prev_key))
+        last_logits = jnp.where(live[:, None], logits, last_logits)
+        toks = toks.at[i].set(tokens)
+        lps = lps.at[i].set(lp)
+        n_out = n_out + live.astype(jnp.int32)
+        is_eos = check_eos & jnp.any(
+            tokens[:, None] == eos_ids[None, :], axis=1)
+        if grammar:
+            # advance the automaton on the emitted token; only a live
+            # slot's state moves. gtrans rows self-loop on EOS in
+            # accepting states and send masked-off tokens to the
+            # identity row 0 — neither is ever taken: sampling already
+            # excluded them.
+            gstate = jnp.where(live, gtrans[gstate, tokens], gstate)
+        done = done | (live & (is_eos | (n_out >= remaining)
+                               | (lengths >= limit)))
+        return (i + 1, done, n_out, toks, lps, gstate, kc, vc, sampler,
+                last_logits, lengths)
+
+    return body
 
 
 def build_decode_loop(step_fn, *, max_steps: int, limit: int):
@@ -1338,7 +862,6 @@ def build_decode_loop(step_fn, *, max_steps: int, limit: int):
                     fast_width=None, kvt=None, gstate=None, gmasks=None,
                     gtrans=None):
         B = lengths.shape[0]
-        grammar = gmasks is not None
         if gstate is None:
             gstate = jnp.zeros((B,), jnp.int32)
         init = (
@@ -1355,40 +878,9 @@ def build_decode_loop(step_fn, *, max_steps: int, limit: int):
             i, done = carry[0], carry[1]
             return (i < max_steps) & jnp.any(~done)
 
-        def body(carry):
-            (i, done, n_out, toks, lps, gstate, kc, vc, sampler,
-             last_logits, lengths) = carry
-            live = ~done
-            prev_key = sampler.key
-            mask = gmasks[gstate] if grammar else None
-            tokens, lp, kc, vc, sampler, logits, lengths = step_fn(
-                params, cos, sin, kc, vc, sampler, last_logits, lengths,
-                live, mask, fast_width, table, kvt)
-            # freeze finished slots: their key stream and last_logits hold
-            # at the finishing token (step_fn already gates lengths and
-            # token_counts on the active mask)
-            sampler = dataclasses.replace(
-                sampler,
-                key=jnp.where(live[:, None], sampler.key, prev_key))
-            last_logits = jnp.where(live[:, None], logits, last_logits)
-            toks = toks.at[i].set(tokens)
-            lps = lps.at[i].set(lp)
-            n_out = n_out + live.astype(jnp.int32)
-            is_eos = check_eos & jnp.any(
-                tokens[:, None] == eos_ids[None, :], axis=1)
-            if grammar:
-                # advance the automaton on the emitted token; only a live
-                # slot's state moves. gtrans rows self-loop on EOS in
-                # accepting states and send masked-off tokens to the
-                # identity row 0 — neither is ever taken: sampling already
-                # excluded them.
-                gstate = jnp.where(live, gtrans[gstate, tokens], gstate)
-            done = done | (live & (is_eos
-                                   | (n_out >= remaining)
-                                   | (lengths >= limit)))
-            return (i + 1, done, n_out, toks, lps, gstate, kc, vc, sampler,
-                    last_logits, lengths)
-
+        body = _loop_body(step_fn, limit,
+                          (params, cos, sin, fast_width, table, kvt),
+                          (remaining, check_eos, eos_ids), gmasks, gtrans)
         (steps, _, n_out, toks, lps, _, kc, vc, sampler, last_logits,
          lengths) = jax.lax.while_loop(cond, body, init)
         return (toks, lps, n_out, steps, kc, vc, sampler, last_logits,
@@ -1463,12 +955,6 @@ def build_ragged_loop(ragged_step, decode_step, *, max_steps: int,
         toks = jnp.zeros((max_steps, B), jnp.int32)
         lps = jnp.zeros((max_steps, B), jnp.float32)
 
-        def stops(tokens, n_out, lengths, live):
-            is_eos = check_eos & jnp.any(
-                tokens[:, None] == eos_ids[None, :], axis=1)
-            return live & (is_eos | (n_out >= remaining)
-                           | (lengths >= limit))
-
         i0 = jnp.int32(0)
         if has_pack:
             # iteration 0, unrolled: the exact single-step mixed ragged
@@ -1488,7 +974,10 @@ def build_ragged_loop(ragged_step, decode_step, *, max_steps: int,
             n_out = n_out + is_decode.astype(jnp.int32)
             if grammar:
                 gstate = jnp.where(is_decode, gtrans[gstate, tokens], gstate)
-            done = done | stops(tokens, n_out, lengths, is_decode)
+            is_eos = check_eos & jnp.any(
+                tokens[:, None] == eos_ids[None, :], axis=1)
+            done = done | (is_decode & (is_eos | (n_out >= remaining)
+                                        | (lengths >= limit)))
             i0 = jnp.int32(1)
 
         init = (i0, done, n_out, toks, lps, gstate, kc, vc, sampler,
@@ -1502,28 +991,9 @@ def build_ragged_loop(ragged_step, decode_step, *, max_steps: int,
             return ((i < max_steps) & jnp.any(~done)
                     & ~jnp.any(is_decode & done) & ~prefill_pending)
 
-        def body(carry):
-            (i, done, n_out, toks, lps, gstate, kc, vc, sampler,
-             last_logits, lengths) = carry
-            live = ~done
-            prev_key = sampler.key
-            mask = gmasks[gstate] if grammar else None
-            tokens, lp, kc, vc, sampler, logits, lengths = decode_step(
-                params, cos, sin, kc, vc, sampler, last_logits, lengths,
-                live, mask, fast_width, table, kvt)
-            sampler = dataclasses.replace(
-                sampler,
-                key=jnp.where(live[:, None], sampler.key, prev_key))
-            last_logits = jnp.where(live[:, None], logits, last_logits)
-            toks = toks.at[i].set(tokens)
-            lps = lps.at[i].set(lp)
-            n_out = n_out + live.astype(jnp.int32)
-            if grammar:
-                gstate = jnp.where(live, gtrans[gstate, tokens], gstate)
-            done = done | stops(tokens, n_out, lengths, live)
-            return (i + 1, done, n_out, toks, lps, gstate, kc, vc, sampler,
-                    last_logits, lengths)
-
+        body = _loop_body(decode_step, limit,
+                          (params, cos, sin, fast_width, table, kvt),
+                          (remaining, check_eos, eos_ids), gmasks, gtrans)
         (steps, done, n_out, toks, lps, _, kc, vc, sampler, last_logits,
          lengths) = jax.lax.while_loop(cond, body, init)
         exit_code = jnp.where(
@@ -1545,28 +1015,21 @@ def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):
     positions = jnp.arange(s)[None, :].repeat(b, 0)
     if lengths is None:
         lengths = jnp.full((b,), s, jnp.int32)
-    attn_prefill, _ = _attn_impls()
+    cache = kv.view(cfg, None, None)
+    self_attention = kv.prompt_attention()
     sax = _seq_ax()
-    x = params["embed"].astype(cfg.jdtype)[tokens]
-    x = _shard_act(x, P("data", sax, None))
+    x = _shard_act(_embed(params, cfg, tokens), P("data", sax, None))
 
-    def layer(x, lp, _kc, _vc, kind):
-        lcos, lsin = _layer_rope(cos, sin, kind)
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(h, lp, cfg, spec=P("data", sax, "model"))
-        q = apply_rope(q, lcos, lsin, positions)
-        k = apply_rope(k, lcos, lsin, positions)
-        q = _shard_act(q, P("data", sax, "model", None))
-        attn = attn_prefill(q, k, v, lengths,
-                            sliding_window=_layer_window(cfg, kind))
-        x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
-                        spec=P("data", sax, None))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(h, lp, cfg, spec_prefix=("data", sax))
-        x = _shard_act(x, P("data", sax, None))
-        return x, (None, None)
+    def layer(x, lp, view, kind):
+        def attend(q, k, v):
+            with _attn_scope(kind):
+                return self_attention(q, k, v, lengths,
+                                      sliding_window=view.window), view
 
-    x, _ = _scan_layers(cfg, layer, x, params["layers"])
+        return _block(cfg, x, lp, kind, cos, sin, positions, attend,
+                      ("data", sax))
+
+    x, _ = _scan_layers(cfg, layer, x, params["layers"], cache)
     return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
@@ -1585,120 +1048,35 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
     projection (non-final prefill chunks need only the KV writes) and
     returns (None, k_cache, v_cache). last_pos [B] (optional): project only
     the hidden state at that window position → logits [B, V], avoiding the
-    [B, S, V] buffer when a single row is wanted (final prefill chunk).
+    [B, S, V] buffer when a single row is wanted (final prefill chunk; what
+    follows it is padding). full_window: every position sits inside the
+    slot's allocation (mid chunks); redirect [B]: kv.PagedKV.
     """
-    from localai_tpu.ops.attention import mha_extend, mha_extend_tiered
-
     b, s = tokens.shape
-    if table is not None or kvt is not None or redirect is not None:
-        _no_mixed(cfg, "a paged, tiered or redirected extend")
+    cache = kv.view(cfg, k_cache, v_cache, table, kvt, redirect=redirect)
     rows = jnp.arange(b) if slot_map is None else slot_map
     positions = start[:, None] + jnp.arange(s)[None, :]
-    x = params["embed"].astype(cfg.jdtype)[tokens]
-    if inject is not None:
-        # multimodal chunk: image-feature rows replace token embeddings
-        # (see prefill's inject)
-        extra, is_embed = inject
-        x = jnp.where(is_embed[..., None], extra.astype(x.dtype), x)
-    # KV lifecycle tier (engine/kvtier.py): chunk windows write through the
-    # ring map and attend against the resident view at true positions.
-    # Padded final-chunk tails land in ring margin columns (never the live
-    # window — kvtier.ring_blocks reserves a full prefill chunk of margin)
-    # at positions > every real query, so the kv_pos <= q_pos mask hides
-    # them until real tokens overwrite those rows.
-    cold = kvt is not None and "cold_tab" in kvt
-    stacked = table is None and kvt is None   # as in decode_step
+    x = _embed(params, cfg, tokens, inject)
 
-    def rows_of(cache, li):
-        """The rows this window attends over, of layer li of a dense stack
-        (a paged or tiered cache is read through its table instead)."""
-        return cache[li] if slot_map is None else cache[li, rows]
+    def layer(x, lp, view, kind):
+        def attend(q, k, v):
+            wrote = view.write(k, v, rows, positions, last=last_pos,
+                               full_window=full_window)
+            with _attn_scope(kind), jax.named_scope("attention"):
+                return wrote.attend_window(q, positions, start, rows,
+                                           slot_map is not None), wrote
 
-    def layer(x, lp, kc, vc, kind, ck=None, cv=None, li=None):
-        ring = kind == WINDOW
-        lcos, lsin = _layer_rope(cos, sin, kind)
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        with _attn_scope(kind):
-            q, k, v = _qkv(h, lp, cfg, spec=P("data", None, "model"))
-            q = apply_rope(q, lcos, lsin, positions)
-            k = apply_rope(k, lcos, lsin, positions)
-        # paged uniqueness: a window whose positions all sit inside the
-        # slot's allocation (mid prefill chunks — callers pass
-        # full_window=True) never collides; a FINAL chunk's padded tail
-        # resolves to shared TRASH offsets with different values — a
-        # genuine collision, so the assertion would be a lie there. A
-        # redirect (paged spec verify: inactive rows' windows route to the
-        # trash block) gets distinct per-(row, pos) offsets, so it stays
-        # unique while B*S fits one block (beyond that the engine warns at
-        # init — engine._build_jit).
-        from localai_tpu.ops.paged import BLOCK as _PB
+        return _block(cfg, x, lp, kind, cos, sin, positions, attend,
+                      ("data", None))
 
-        keep = None
-        if ring:
-            # the chunk's writes wrap; every query must still find the
-            # window - 1 tokens before it, which the chunk's own newest
-            # writes overwrite unless the ring holds window + chunk
-            size = kc.shape[-2]
-            if size < lcos.shape[0] and size < cfg.sliding_window + s:
-                raise ValueError(
-                    f"a ring of {size} tokens cannot take a window of "
-                    f"{cfg.sliding_window} behind a chunk of {s}")
-            # a final chunk's padding is not written: nothing in a ring is
-            # out of the way
-            keep = (jnp.ones((b, s), bool) if last_pos is None
-                    else jnp.arange(s)[None, :] <= last_pos[:, None])
-        red_ok = redirect is None or b * s <= _PB
-        kc, vc = _cache_write(
-            kc, vc, k, v, rows, positions, table,
-            unique=(table is None or full_window or redirect is not None)
-            and red_ok,
-            redirect=redirect, kvt=kvt, ring_keep=keep, layer=li)
-        with _attn_scope(kind), jax.named_scope("attention"):
-            if kvt is not None:
-                kr, vr, kv_pos, kv_ok = _tiered_kv(
-                    kc, vc, table[rows], kvt["sb"][rows], kvt["rw"][rows],
-                    start + s,
-                    ctab=kvt["cold_tab"][rows] if cold else None,
-                    ck=ck, cv=cv)
-                attn = mha_extend_tiered(
-                    q, kr, vr, positions, kv_pos, kv_ok,
-                    kvt["sinks"][rows], kvt["window"][rows],
-                    drop_window=not cold)
-            elif ring:
-                kr, vr = rows_of(kc, li), rows_of(vc, li)
-                newest = start + s - 1
-                kv_pos = newest[:, None] - _ring_back(newest, kr.shape[2])
-                attn = mha_extend_tiered(
-                    q, dequant(kr), dequant(vr), positions, kv_pos,
-                    kv_pos >= 0, jnp.zeros((b,), jnp.int32),
-                    jnp.full((b,), cfg.sliding_window, jnp.int32))
-            else:
-                if table is not None:
-                    from localai_tpu.ops.paged import paged_view
-
-                    kr = paged_view(kc, table[rows])
-                    vr = paged_view(vc, table[rows])
-                else:
-                    kr, vr = rows_of(kc, li), rows_of(vc, li)
-                attn = mha_extend(q, dequant(kr), dequant(vr), positions,
-                                  sliding_window=_layer_window(cfg, kind))
-            x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"],
-                            spec=P("data", None, None))
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(h, lp, cfg, spec_prefix=("data", None))
-        return x, (kc, vc)
-
-    x, (k_cache, v_cache) = _scan_layers(
-        cfg, layer, x, params["layers"], k_cache, v_cache,
-        extra=(kvt["cold_k"], kvt["cold_v"]) if cold else (), carry=stacked)
+    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params["layers"],
+                                         cache)
     if not with_logits:
         return None, k_cache, v_cache
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     if last_pos is not None:
         x = jnp.take_along_axis(x, last_pos[:, None, None], axis=1)[:, 0]
-        return _lm_head(x.astype(jnp.float32), params), k_cache, v_cache
-    logits = _lm_head(x.astype(jnp.float32), params)
-    return logits, k_cache, v_cache
+    return _lm_head(x.astype(jnp.float32), params), k_cache, v_cache
 
 
 def cache_shift(cfg: LlamaConfig, k_cache, v_cache, lengths, slot, *,
@@ -1715,7 +1093,7 @@ def cache_shift(cfg: LlamaConfig, k_cache, v_cache, lengths, slot, *,
     """
     from localai_tpu.ops.rope import rope_freqs
 
-    _no_mixed(cfg, "cache_shift")
+    kv.no_mixed(cfg, "cache_shift")
     inv_freq, _ = rope_freqs(cfg.rope)
     ang = discard * inv_freq                     # [D/2]
     c, s = jnp.cos(ang), jnp.sin(ang)
@@ -1770,7 +1148,7 @@ def cache_shift_paged(cfg: LlamaConfig, k_pool, row_table, *,
     from localai_tpu.ops.paged import BLOCK
     from localai_tpu.ops.rope import rope_freqs
 
-    _no_mixed(cfg, "cache_shift_paged")
+    kv.no_mixed(cfg, "cache_shift_paged")
     inv_freq, _ = rope_freqs(cfg.rope)
     ang = (discard_blocks * BLOCK) * inv_freq
     c, s = jnp.cos(ang), jnp.sin(ang)
@@ -1809,11 +1187,8 @@ def encode_pooled(params, cfg: LlamaConfig, tokens, lengths, normalize=True):
     b, s = tokens.shape
     x = hidden_states(params, cfg, tokens, lengths).astype(jnp.float32)
     mask = (jnp.arange(s)[None, :] < lengths[:, None]).astype(jnp.float32)
-    pooled = (x * mask[..., None]).sum(1) / jnp.maximum(
-        mask.sum(1)[:, None], 1.0
-    )
+    pooled = (x * mask[..., None]).sum(1) / jnp.maximum(mask.sum(1)[:, None], 1.0)
     if normalize:
         pooled = pooled / jnp.maximum(
-            jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9
-        )
+            jnp.linalg.norm(pooled, axis=-1, keepdims=True), 1e-9)
     return pooled

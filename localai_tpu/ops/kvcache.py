@@ -120,7 +120,7 @@ def cache_scatter(cache: QuantKV, idx, values, unique: bool = True) -> QuantKV:
     idx: advanced-index tuple addressing [..., T] positions of the cache's
     lead+token axes (the same tuple the dense path hands to `.at[idx].set`);
     values: matching [..., D] dense rows. `unique` asserts non-colliding
-    rows (see models/llama.py _cache_write for when that holds) — the
+    rows (see models/kv.py DenseKV.write for when that holds) — the
     assertion keeps XLA on the in-place scatter path inside the layer scan.
     """
     q, scale = quantize_tokens(values)

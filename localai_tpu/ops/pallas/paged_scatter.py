@@ -1,6 +1,6 @@
 """Pallas scatter-append — the paged-KV decode write path.
 
-The XLA formulation of the per-step cache write (`models/llama._cache_write`
+The XLA formulation of the per-step cache write (`models/kv.PagedKV._scatter`
 with a `table`) scatters through GATHERED physical indices
 (`pool.at[table[b, pos // BS], :, pos % BS].set(row)`). Inside the fused
 multi-step decode block the scatter rides the layer scan's donated carry, and
@@ -60,7 +60,7 @@ def _targets(positions, table, active, sb=None, rw=None):
     Computed at trace time from the scalar-prefetched table — the kernel
     never sees an index it could fail to prove unique. Inactive rows route
     to the trash block at row `b % BS` (distinct while B <= BS, the same
-    bound the XLA redirect asserts — models/llama._cache_write).
+    bound the XLA redirect asserts — models/kv.PagedKV.write).
 
     sb/rw ([B] i32, optional): KV-lifecycle ring geometry
     (ops/paged.ring_block_map) — windowed slots' raw block indices fold into

@@ -31,10 +31,11 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from localai_tpu.models.kv import prompt_attention
 from localai_tpu.models.llama import (
-    LlamaConfig, _attn_impls, _lm_head, _mlp, _qkv, param_specs, rms_norm,
+    LlamaConfig, _block, _lm_head, param_specs, rms_norm,
 )
-from localai_tpu.ops.rope import apply_rope, rope_table
+from localai_tpu.ops.rope import rope_table
 
 
 def pipeline_specs(cfg: LlamaConfig):
@@ -53,25 +54,16 @@ def pipeline_specs(cfg: LlamaConfig):
 
 def _stage_layers(layers_local, x, cfg: LlamaConfig, cos, sin, positions,
                   lengths, attn):
-    """Run this stage's L/S layers over one microbatch [mb, T, D].
-
-    Same math as models/llama.py hidden_states' layer body, minus the
-    activation-sharding hints (with_sharding_constraint is illegal inside
-    shard_map — the manual axes already fix the layout)."""
-    b, s, _ = x.shape
-
+    """Run this stage's L/S layers over one microbatch [mb, T, D]: the one
+    block of models/llama.py with no activation-sharding hints
+    (with_sharding_constraint is illegal inside shard_map — the manual axes
+    already fix the layout) and no cache."""
     def layer(x, lp):
-        h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-        q, k, v = _qkv(h, lp, cfg)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        a = attn(q, k, v, lengths, sliding_window=cfg.sliding_window)
-        from localai_tpu.ops.quant import qmatmul
-
-        x = x + qmatmul(a.reshape(b, s, -1), lp["wo"])
-        h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-        x = x + _mlp(h, lp, cfg)
-        return x, None
+        return _block(
+            cfg, x, lp, None, cos, sin, positions,
+            lambda q, k, v: (attn(q, k, v, lengths,
+                                  sliding_window=cfg.sliding_window), None),
+            None)
 
     x, _ = jax.lax.scan(layer, x, layers_local)
     return x
@@ -106,7 +98,7 @@ def pipeline_hidden(params, cfg: LlamaConfig, tokens, *, mesh: Mesh,
     cos, sin = rope_table(cfg.rope, T)
     if lengths is None:
         lengths = jnp.full((B,), T, jnp.int32)
-    attn, _ = _attn_impls()
+    attn = prompt_attention()
     emb = params["embed"].astype(cfg.jdtype)[tokens]          # [B, T, D]
     D = emb.shape[-1]
     positions = jnp.arange(T)[None, :]

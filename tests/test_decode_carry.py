@@ -1,7 +1,7 @@
 """A decode step over a dense cache leaves the cache where it is: the layer
 scan carries the stacked cache, the new token is one scatter into the stack
 at (layer, row, head, position), and the decode kernel reads its layer out
-of the stack through its index maps (models/llama._scan_layers_carry).
+of the stack through its index maps (models/kv.DenseKV, llama._scan_layers).
 
 - a jaxpr proof, in the manner of tests/test_paged_fast_path.py: outside a
   kernel nothing slices, gathers or puts back a layer's cache or more, and

@@ -4,7 +4,7 @@ import numpy as np
 import pytest
 
 from localai_tpu.models.llama import (
-    LlamaConfig, init_params, init_kv_cache, prefill, decode_step,
+    LlamaConfig, init_params, init_kv_cache, prefill, decode_step, extend,
     forward_train, param_specs,
 )
 from localai_tpu.ops.rope import rope_table
@@ -58,6 +58,30 @@ def test_prefill_decode_matches_forward(tiny_params):
     np.testing.assert_allclose(
         np.asarray(dlogits[0]), np.asarray(full2[0, S]), rtol=2e-4, atol=2e-4
     )
+
+
+def test_extend_matches_decode_chain(tiny_params):
+    """extend() over a window == sequential decode_step calls."""
+    cfg, T = TINY, 64
+    cos, sin = rope_table(cfg.rope, T)
+    prompt = jnp.array([[3, 14, 15, 9, 2]], jnp.int32)
+    n = prompt.shape[1]
+
+    kc, vc = init_kv_cache(cfg, 1, T)
+    _, kc, vc = prefill(tiny_params, cfg, prompt, jnp.array([n]), cos, sin,
+                        kc, vc, jnp.array([0]))
+    window = jnp.array([[7, 21, 4]], jnp.int32)
+    elogits, _, _ = extend(tiny_params, cfg, window, jnp.array([n]),
+                           cos, sin, kc, vc)
+
+    # sequential reference
+    seq_logits = []
+    for i in range(3):
+        dl, kc, vc = decode_step(tiny_params, cfg, window[:, i],
+                                 jnp.array([n + i]), cos, sin, kc, vc)
+        seq_logits.append(np.asarray(dl[0]))
+    np.testing.assert_allclose(np.asarray(elogits[0]), np.stack(seq_logits),
+                               rtol=2e-4, atol=2e-4)
 
 
 def test_param_specs_tree_matches_params(tiny_params):
