@@ -164,9 +164,15 @@ class NoKV:
     def decode(self, q, lengths):
         """q [B, 1, H, D] over `lengths` [B] entries, the token just written
         among them: the Pallas kernel streams the cache where it lies (by
-        layer index, through the table); the XLA twin dequantizes in its dots."""
+        layer index, through the table); the XLA twin dequantizes in its dots.
+        The kernel is told which rows decode: a slot that finished, or that a
+        chunked prefill is filling beside this step, keeps its length, and at
+        length 0 the kernel neither fetches nor multiplies its context (zeros
+        out: a row the host masks anyway)."""
         if _pallas_attention(current_mesh()):
             fn = _kernel("ragged_decode", self.quant, sharded=False)
+            if self.active is not None:
+                lengths = jnp.where(self.active, lengths, 0)
             return fn(q, *self._pools(), lengths, sliding_window=self.window,
                       table=self.table, ring=self.ring, layer=self.layer)
         return self.decode_xla(q, lengths)

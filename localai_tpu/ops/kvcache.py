@@ -13,7 +13,13 @@ Layout is chosen for Mosaic, not for numpy: the scales of cache
 [t // 128, t % 128]) so the trailing two dims of any Pallas block over them
 are (rows, 128) — tile-legal — and a 128-token KV block's scales are exactly
 one aligned scale row. `T` must therefore be a multiple of 128; callers round
-up (extra rows are inert — every read is masked by `lengths`).
+up (extra rows are inert — every read is masked by `lengths`). The dense
+decode kernel moves several such blocks of every KV head of a row in one
+grid step (512 tokens of T = 1536, 1024 of 8192: 1 MB of K + V on Mixtral
+where one head's 128 tokens were 32 KB) and applies as many scale rows; a
+row's whole scale strip rides beside its first block. At one 16 KB block a
+step the kernel was bound by DMA latency and its count of grid steps, at a
+twentieth of the chip's bandwidth (PERF.md §6, PR 30).
 
 The XLA (non-Pallas) attention paths read the cache through `dequant`, which
 XLA fuses into the consuming dot where it can; HBM *capacity* is halved

@@ -94,7 +94,10 @@ def test_extend_parity_int8_vs_dense():
         np.abs(outs[""]) + 1.0)
 
 
-def test_ragged_decode_q8_matches_xla_on_same_values():
+@pytest.mark.parametrize("block_k", [None, 128, 256])
+def test_ragged_decode_q8_matches_xla_on_same_values(block_k):
+    """block_k: the tokens a grid step moves (None: from the shapes, 256
+    here), as many scale rows of [T // 128, 128] a step."""
     from localai_tpu.ops.attention import mha_decode
     from localai_tpu.ops.pallas import ragged_decode_q8
 
@@ -108,7 +111,8 @@ def test_ragged_decode_q8_matches_xla_on_same_values():
     kc = QuantKV(kq, ks.reshape(B, KVH, T // 128, 128))
     vc = QuantKV(vq, vs.reshape(B, KVH, T // 128, 128))
     lengths = jnp.array([200, 77], jnp.int32)
-    out = ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths)
+    out = ragged_decode_q8(q, kc.q, kc.s, vc.q, vc.s, lengths,
+                           block_k=block_k)
     ref = mha_decode(q.astype(jnp.float32),
                      dequant(kc, jnp.float32), dequant(vc, jnp.float32),
                      lengths)

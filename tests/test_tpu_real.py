@@ -165,6 +165,50 @@ def test_ragged_decode_ring(H, KVH, D, layer):
                       ring=True))
 
 
+# the cells' own shapes (PR 30): 32 rows, every KV head of a row a grid step,
+# block_k from the shapes (512 of Mixtral's 1536 and of a ring, 1024 of
+# Mellum2's 8192), a third of the rows not decoding (length 0)
+CELL_SHAPES = {
+    "mixtral": (8, 4, 1536, None, 1536),
+    "mellum2-full": (4, 8, 8192, None, 7680),
+    "mellum2-ring": (4, 8, 1536, 1024, 6000),
+}
+
+
+@pytest.mark.parametrize("quant", [True, False], ids=["q8", "bf16"])
+@pytest.mark.parametrize("shape", list(CELL_SHAPES))
+def test_ragged_decode_at_the_cells_shapes(shape, quant):
+    from localai_tpu.models.llama import _decode_dq
+    from localai_tpu.ops.pallas import ragged_decode, ragged_decode_q8
+
+    KVH, G, T, W, longest = CELL_SHAPES[shape]
+    B, D, L, layer = 32, 128, 2, 1
+    rng = np.random.default_rng(30)
+    lengths = rng.integers(1, longest + 1, B)
+    lengths[:8] = [1, 127, 128, 129, 511, 512, 513, longest]
+    active = np.ones(B, bool)
+    active[rng.permutation(B)[:B // 3]] = False
+    active[[0, 7]] = True
+    kw = dict(sliding_window=W, ring=True) if W else {}
+    q = _bf16(40, (B, 1, KVH * G, D))
+    if quant:
+        k, v = _quant(41, (L, B, KVH, T, D)), _quant(42, (L, B, KVH, T, D))
+    else:
+        k, v = _bf16(41, (L, B, KVH, T, D)), _bf16(42, (L, B, KVH, T, D))
+    want = _decode_dq(q, k[layer], v[layer], jnp.asarray(lengths, jnp.int32),
+                      **kw)
+    lens = jnp.asarray(np.where(active, lengths, 0), jnp.int32)
+    if quant:
+        got = ragged_decode_q8(q, k.q, k.s, v.q, v.s, lens,
+                               layer=jnp.int32(layer), **kw)
+    else:
+        got = ragged_decode(q, k, v, lens, layer=jnp.int32(layer), **kw)
+    got = np.asarray(got, np.float32)
+    assert np.isfinite(got).all()
+    assert not got[~active].any()
+    _close(got[active], np.asarray(want, np.float32)[active])
+
+
 @pytest.mark.parametrize("H,KVH,D", GEOMS)
 def test_ragged_decode_q8_paged(H, KVH, D):
     from localai_tpu.ops.attention import mha_decode
