@@ -39,6 +39,9 @@ import jax.numpy as jnp
 import numpy as np
 
 from localai_tpu.models.llama import (
+    FULL,
+    LINEAR,
+    WINDOW,
     LlamaConfig,
     cache_shift,
     decode_step,
@@ -416,9 +419,15 @@ class Engine:
         # (models/llama.py PeriodKV). What knows one cache per layer stack
         # refuses such a model here, at load, by name.
         self._mixed = cfg.layer_types is not None
+        # linear-attention layers: a recurrent state beside the KV cache
+        # (models/kv.py StateKV). It is not kept per position, so nothing
+        # that lends, saves, shifts or takes back a prefix can serve it.
+        self._linear = self._mixed and LINEAR in cfg.layer_types
+        mixed_name = ("a model with linear-attention layers" if self._linear
+                      else "a model with window and full attention layers")
         if self._mixed and self._paged:
             raise ValueError(
-                "a model with window and full attention layers "
+                f"{mixed_name} "
                 "(layer_types) cannot be served with paged KV (kv_pages > "
                 "0), nor with what rests on it: ragged batching "
                 "(ragged_token_budget), kv_policy windows, the host KV tier "
@@ -426,10 +435,10 @@ class Engine:
                 "kind of cache. Serve it with kv_pages: 0")
         if self._mixed and self._draft is not None:
             raise ValueError(
-                "a model with window and full attention layers "
+                f"{mixed_name} "
                 "(layer_types) cannot be served with speculative decoding "
                 "(a draft model): the verify window writes ahead into a "
-                "ring it may have to take back")
+                "ring, or a recurrent state, it may have to take back")
         if self._paged:
             if self.ec.kv_pages < 2:
                 raise ValueError("kv_pages must be >= 2 (block 0 is trash)")
@@ -542,7 +551,8 @@ class Engine:
         if self.ec.prefill_chunk < 8:
             raise ValueError("prefill_chunk must be >= 8")
         self._chunk = min(self.ec.prefill_chunk, self.ec.max_context)
-        if self._mixed and self._chunk < self.ec.decode_block:
+        if (self._mixed and WINDOW in cfg.layer_types
+                and self._chunk < self.ec.decode_block):
             raise ValueError(
                 "a model with window layers needs prefill_chunk >= "
                 "decode_block: a grammar rollback (_repair) takes back up to "
@@ -651,7 +661,21 @@ class Engine:
                     for cache in (self._kc, self._vc)
                     for slot, k in zip(cache.slots, kinds) if k == kind
                     for leaf in jax.tree_util.tree_leaves(slot))
-                self.metrics[f"decode_ctx_tokens__{kind}"] = 0
+                if not self._linear:
+                    self.metrics[f"decode_ctx_tokens__{kind}"] = 0
+            if self._linear:
+                # what the decode steps consumed so far moved of each kind
+                # of cache (_credit_consumed): K and V bytes the softmax
+                # layers attended over, state bytes the linear layers read
+                # and wrote. Bytes a slot a layer, from the arrays held:
+                self._cache_bytes = {
+                    kind: self.metrics[f"kv_bytes__{kind}"]
+                    / (cfg.layer_types.count(kind) * B)
+                    / (self._kc.slots[kinds.index(kind)].shape[-2]
+                       if kind == FULL else 1)
+                    for kind in (FULL, LINEAR)}
+                self.metrics["decode_cache_bytes__full"] = 0
+                self.metrics["decode_cache_bytes__linear"] = 0
         if self._ragged:
             # token-budget utilization = ragged_tokens_packed /
             # (ragged_dispatches * ragged rows) — how full the flat stream
@@ -2268,8 +2292,15 @@ class Engine:
         if req.context_shift and self._mixed:
             raise ValueError(
                 "context_shift is not supported for a model with window and "
-                "full attention layers (cache_shift moves one full-length "
-                "cache, not a ring)")
+                "full attention layers, or linear-attention ones "
+                "(cache_shift moves one full-length cache, not a ring or a "
+                "recurrent state)")
+        if req.prompt_cache_path and self._linear:
+            raise ValueError(
+                "prompt_cache_path is not supported for a model with "
+                "linear-attention layers: the disk prompt cache saves K and "
+                "V per position, and a recurrent state at a prefix's end is "
+                "not held")
         if req.context_shift and self._draft is not None:
             raise ValueError(
                 "context_shift is not supported with a draft model "
@@ -2985,6 +3016,11 @@ class Engine:
             # sum of reason-code counts
             self._sched.reason(loop_block)
         steps = self._block_steps()
+        if self._linear and self._grammar_slots > 0:
+            # a block samples under its first step's masks and takes back
+            # what the grammar then rejects (_repair): a recurrent state
+            # cannot be taken back, so such a model steps once a dispatch
+            steps = 1
         # snapshot the dispatch-time masks: _consume compares each slot's
         # refreshed mask against what the device sampled under, to catch the
         # allowed-set GROWING mid-block (see _consume)
@@ -3026,6 +3062,8 @@ class Engine:
         self.metrics["decode_steps_consumed"] += steps
         if not self._mixed:
             return
+        if self._linear:
+            return self._credit_cache_bytes(steps, entries, n_out)
         window = self.cfg.sliding_window
         full = win = 0
         for i, rid in entries:
@@ -3039,6 +3077,27 @@ class Engine:
             win += k * lo + k * (k + 1) // 2 + (n - k) * window
         self.metrics["decode_ctx_tokens__full"] += full
         self.metrics["decode_ctx_tokens__window"] += win
+
+    def _credit_cache_bytes(self, steps: int, entries, n_out):
+        """_credit_consumed for a model with linear-attention layers, in
+        bytes: a live row that stood at n tokens attends over n + 1, n + 2,
+        ... tokens of K and V in each softmax layer, and reads and writes
+        its whole state (and convolution tail) once a step in each linear
+        layer, however long it is."""
+        full = lin = 0
+        for i, rid in entries:
+            slot = self._slots[i]
+            if slot is None or slot.request_id != rid:
+                continue
+            n = steps if n_out is None else int(n_out[i])
+            lo = slot.prompt_len + slot.generated
+            full += n * lo + n * (n + 1) // 2
+            lin += n
+        m, per, count = (self.metrics, self._cache_bytes,
+                         self.cfg.layer_types.count)
+        m["decode_cache_bytes__full"] += int(full * per[FULL] * count(FULL))
+        m["decode_cache_bytes__linear"] += int(
+            2 * lin * per[LINEAR] * count(LINEAR))
 
     def _mark_join(self, entries):
         """Stamp the slots this decode dispatch is the first to carry, just
@@ -4242,8 +4301,10 @@ class Engine:
         if self.ec.prompt_cache and self._draft is None:
             for s in self._free:
                 lcp = common(self._slot_kv_tokens[s])
-                if self._mixed and not self._ring_holds(
-                        lcp, len(self._slot_kv_tokens[s])):
+                if self._linear or (self._mixed and not self._ring_holds(
+                        lcp, len(self._slot_kv_tokens[s]))):
+                    # a linear layer's state at the prefix's end is not
+                    # held (snapshots: PERF.md section 7.2): recomputed
                     lcp = 0
                 if lcp > best_lcp:
                     best_slot, best_lcp = s, lcp

@@ -21,7 +21,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import NamedSharding
 
-from localai_tpu.models.llama import FULL, WINDOW, LlamaConfig, param_specs
+from localai_tpu.models.llama import (
+    FULL, LINEAR, WINDOW, LlamaConfig, param_specs,
+)
 
 # HF architectures the Llama-family decoder covers (SURVEY §2.2 row 1 scope).
 LLAMA_FAMILY = {
@@ -33,9 +35,14 @@ LLAMA_FAMILY = {
     # window and full attention layers in one model, each kind its own RoPE,
     # sparse experts of moe_intermediate_size (Mellum2)
     "MellumForCausalLM": {"moe": True},
+    # gated delta-rule linear-attention layers beside NoPE gated GQA layers
+    # (gqa_layers), a shared expert, routed experts of which this process
+    # may hold a share (Solar-Open2)
+    "SolarOpen2ForCausalLM": {"moe": True},
 }
 # config.json files that name no architecture
-_ARCH_OF_MODEL_TYPE = {"mellum": "MellumForCausalLM"}
+_ARCH_OF_MODEL_TYPE = {"mellum": "MellumForCausalLM",
+                       "solar_open2": "SolarOpen2ForCausalLM"}
 _LAYER_KINDS = {"full_attention": FULL, "sliding_attention": WINDOW}
 
 
@@ -59,6 +66,73 @@ def _rope_fields(rs: dict | None, theta: float, max_position: int) -> dict:
         kw["rope_beta_slow"] = rs.get("beta_slow", 1.0)
         kw["rope_attn_factor"] = rs.get("attention_factor")
     return kw
+
+
+def _expert_layer_fields(hf: dict, held: int) -> dict:
+    """What an expert layer has beside its routed experts: shared experts
+    (n of them are one SwiGLU n times as wide), a scale on the routed sum,
+    and the SHARE this process holds (`localai_expert_share`: the published
+    router width and the first expert held; `n_routed_experts` then counts
+    the experts held). What the layer cannot honour is refused by name."""
+    if hf.get("first_k_dense_replace"):
+        raise ValueError(
+            "first_k_dense_replace > 0 is not supported: the layer stack is "
+            "one scan over one kind of MLP, and leading dense layers would "
+            "silently run as expert layers")
+    if hf.get("scoring_func", "softmax") != "softmax":
+        raise ValueError(
+            f"scoring_func {hf['scoring_func']!r} is not supported: the "
+            "router is a float32 softmax over all experts")
+    kw: dict[str, Any] = {
+        "shared_expert_width": (hf.get("n_shared_experts") or 0)
+        * (hf.get("moe_intermediate_size") or hf["intermediate_size"]),
+        "routed_scale": float(hf.get("routed_scaling_factor", 1.0)),
+    }
+    share = hf.get("localai_expert_share")
+    if share:
+        kw["router_experts"] = int(share["router_experts"])
+        kw["first_expert"] = int(share.get("first_expert", 0))
+        if kw["router_experts"] < held:
+            raise ValueError(
+                f"localai_expert_share: a router of {kw['router_experts']} "
+                f"cannot have {held} experts held (n_routed_experts)")
+    return kw
+
+
+def _linear_fields(hf: dict, n_layers: int) -> dict:
+    """Linear-attention layers beside softmax ones: `gqa_layers` lists the
+    softmax (FULL) layers, every other layer is LINEAR."""
+    la = hf["linear_attn_config"]
+    full = sorted(set(hf.get("gqa_layers") or ()))
+    if not full or full[-1] >= n_layers or len(full) == n_layers:
+        raise ValueError(
+            f"gqa_layers {full} must name some, not all, of the "
+            f"{n_layers} layers")
+    kinds = tuple(FULL if i in full else LINEAR for i in range(n_layers))
+    period = next(p for p in range(1, n_layers + 1) if n_layers % p == 0
+                  and kinds == kinds[:p] * (n_layers // p))
+    if period == n_layers and len(full) > 1:
+        raise ValueError(
+            f"gqa_layers {full} is not periodic over {n_layers} layers: the "
+            "layer stack is a scan over one period of layer kinds")
+    if la.get("num_kv_heads") not in (None, la["num_heads"]):
+        raise ValueError(
+            "linear_attn_config.num_kv_heads other than num_heads (grouped "
+            "keys and values in a linear layer) is not supported")
+    if hf.get("kda_use_full_proj"):
+        raise ValueError(
+            "kda_use_full_proj: true is not supported: the decay gate is the "
+            "low-rank pair W_f2 W_f1")
+    return {
+        "layer_types": kinds,
+        "linear_heads": la["num_heads"], "linear_head_dim": la["head_dim"],
+        "linear_conv": la.get("short_conv_kernel_size", 4),
+        # no key gives the gates' rank: the family's convention, head_dim
+        "linear_gate_rank": la["head_dim"],
+        "linear_neg_eigval": bool(hf.get("kda_allow_neg_eigval", False)),
+        "use_rope": bool(hf.get("use_rope", True)),
+        "attn_gate": bool(hf.get("use_gqa_gate", False)),
+    }
 
 
 def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
@@ -102,7 +176,8 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
         sliding_window=hf.get("sliding_window"),
         qkv_bias=hf.get("attention_bias", extra.get("qkv_bias", False)),
     )
-    experts = hf.get("num_experts", hf.get("num_local_experts"))
+    experts = hf.get("num_experts", hf.get("num_local_experts",
+                                           hf.get("n_routed_experts")))
     mlp_kinds = set(hf.get("mlp_layer_types") or ())
     if mlp_kinds - {"sparse", "dense"} or len(mlp_kinds) > 1:
         raise ValueError(
@@ -120,11 +195,14 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
         kw["num_experts"] = experts or 8
         kw["experts_per_tok"] = hf.get("num_experts_per_tok", 2)
         kw["moe_intermediate_size"] = hf.get("moe_intermediate_size")
+        kw.update(_expert_layer_fields(hf, kw["num_experts"]))
     if dtype is not None:
         # int8 = weight quantization; activations/KV stay bf16
         kw["dtype"] = ("bfloat16" if dtype in ("int8", "q8", "int4", "q4")
                        else dtype)
 
+    if hf.get("linear_attn_config"):
+        kw.update(_linear_fields(hf, kw["num_layers"]))
     kinds = hf.get("layer_types")
     if kinds:
         unknown = set(kinds) - set(_LAYER_KINDS)
@@ -294,6 +372,11 @@ def load_params(
         dtype = "bfloat16"
     dtype = jnp.dtype(dtype) if dtype is not None else cfg.jdtype
 
+    if cfg.stacked_by_kind and not _is_synthetic(model_dir):
+        raise ValueError(
+            "no checkpoint of a model with linear-attention layers has been "
+            "at hand: its tensors' names are not known here, and only "
+            "synthetic weights (localai_synthetic) can be loaded")
     if _is_synthetic(model_dir):
         # benchmark checkpoints: config.json declares the geometry, weights
         # are deterministic random init on device — lets the serving path be
@@ -461,6 +544,32 @@ def _synthetic_params(cfg: LlamaConfig, *, dtype, mesh=None, qbits=None,
         return {"q": qbody(k, shape), "s": s}
 
     ks = jax.random.split(key, 12)
+    if cfg.stacked_by_kind:
+        from localai_tpu.models.llama import decay_init, layer_leaves
+
+        def leaf(k, name, shape, how):
+            if how == "ones":
+                return jnp.ones(shape, dtype)
+            if how in ("A_log", "dt_bias"):
+                return decay_init(k, name, shape)
+            if name.startswith("w") or name.startswith("moe_w"):
+                return qrand(k, shape, how)
+            x = jax.random.normal(k, shape, jnp.float32) * (how ** -0.5)
+            return x if name == "moe_gate" else x.astype(dtype)
+
+        layers = {}
+        for n, kind in enumerate(sorted(set(cfg.layer_types))):
+            leaves = layer_leaves(cfg, kind)
+            kk = jax.random.split(jax.random.fold_in(ks[0], n), len(leaves))
+            layers[kind] = {
+                name: leaf(kk[i], name, (cfg.layers_of(kind), *shape), how)
+                for i, (name, (shape, how)) in enumerate(leaves.items())}
+        return {
+            "embed": (jax.random.normal(ks[7], (cfg.vocab_size, h),
+                                        jnp.float32)
+                      * (h ** -0.5)).astype(dtype),
+            "layers": layers, "final_norm": jnp.ones((h,), dtype),
+            "lm_head": qrand(ks[8], (h, cfg.vocab_size), h)}
     layers = {
         "attn_norm": jnp.ones((L, h), dtype),
         "wq": qrand(ks[0], (L, h, nh * hd), h),

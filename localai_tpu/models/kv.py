@@ -15,9 +15,10 @@ over (`attend_window`), and how it rides the layer scan (`carried`).
     RingKV    a WINDOW layer's stack: position p lives in row p mod R
     PagedKV   the block pool [L, NB, KVH, BS, D] behind a block table
     TieredKV  the pool under a sink_window policy (engine/kvtier.py)
+    StateKV   a LINEAR layer's recurrent state and short-convolution tail
     NoKV      nothing is kept (hidden_states)
 
-A model with window and full layers gets a tuple of views, one per place in
+A model with several kinds of layer gets a tuple of views, one per place in
 its period of layer kinds. A new kind of cache is a new class here, not a
 branch in every forward.
 """
@@ -41,18 +42,21 @@ from localai_tpu.ops.paged import (
 )
 from localai_tpu.parallel.mesh import current_mesh, seq_axis_size
 
-FULL, WINDOW = "full", "window"     # LlamaConfig.layer_types entries
+FULL, WINDOW, LINEAR = "full", "window", "linear"   # LlamaConfig.layer_types
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PeriodKV:
-    """K (or V) of a model with window and full layers, as the engine holds
+    """K (or V) of a model with several kinds of layer, as the engine holds
     it: one cache per place in the period of layer kinds, `slots[j]` of
     [L/period, B, KVH, T_j, D] (dense or QuantKV) — T_j the served context
-    for a FULL layer, the ring for a WINDOW one. `view` makes a DenseKV or a
-    RingKV of each; the layer scan CARRIES them, and place j of period i
-    writes and reads slots[j][i] where it lies."""
+    for a FULL layer, the ring for a WINDOW one. A LINEAR layer's place
+    holds its state [L/period, B, H, Dk, Dv] float32 in the K tree and its
+    convolution tail [L/period, B, K-1, C] in the V tree (StateKV). `view`
+    makes a DenseKV, a RingKV or a StateKV of each; the layer scan CARRIES
+    them, and place j of period i writes and reads slots[j][i] where it
+    lies."""
     slots: tuple
 
 
@@ -295,6 +299,114 @@ class RingKV(DenseKV):
             q, dequant(kr), dequant(vr), positions, kv_pos, kv_pos >= 0,
             jnp.zeros((b,), jnp.int32),
             jnp.full((b,), self.window, jnp.int32))
+
+
+@dataclasses.dataclass
+class StateKV(NoKV):
+    """A LINEAR (gated delta rule) layer's cache: no keys and values but a
+    recurrent STATE, `k` = [L, B, H, Dk, Dv] float32, and the last K-1
+    inputs of the short convolution, `v` = [L, B, K-1, C] (C = the q, k and
+    v channels side by side, before the convolution). A token rewrites the
+    whole state, so nothing is addressed by position:
+
+    prompt  a prompt from position 0 (prefill): from a zero state; the
+            slot gets the state after its last real token and that token's
+            K-1 predecessors (padding past a row's end changes nothing).
+    chunk   a window from `start` (extend): from the slot's state, or from
+            zero where start is 0 — ADMISSION RESETS THE STATE HERE, on the
+            device, whatever the last tenant left; `n` [B] real tokens.
+    step    decode's token: conv, update and readout of the rows decoding
+            (`active`); an inactive row's state and tail are untouched.
+
+    The mathematics is ops/kda.py's; on a TPU `step` is one Pallas kernel a
+    layer (ops/pallas/kda.py: a live row's state read once, written once,
+    in place in the carried stack). No state is kept per position, so a
+    prefix of a slot's tokens cannot be lent to the next tenant (the engine
+    reuses none), and nothing can be rolled back."""
+    heads: int = 0
+    carried = True
+
+    def _qkv(self, y):
+        """The convolution's output [B, S, C] -> q, k, v [B, S, H, D]:
+        SiLU, then q and k L2-normalised a head (q also scaled D^-1/2)."""
+        b, s, _ = y.shape
+        q, k, v = jnp.split(jax.nn.silu(y.astype(jnp.float32)), 3, axis=-1)
+        q, k, v = (a.reshape(b, s, self.heads, -1) for a in (q, k, v))
+
+        def unit(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+        return unit(q) * q.shape[-1] ** -0.5, unit(k), v
+
+    def _mix(self, u, conv, g, beta, tail, state, n):
+        from localai_tpu.ops.kda import kda_chunk, short_conv
+
+        with jax.named_scope("conv"):
+            y, xx = short_conv(tail, u, conv)
+            q, k, v = self._qkv(y)
+        with jax.named_scope("kda_chunk"):
+            o, state = kda_chunk(q, k, v, g, beta, state, n_valid=n)
+        if n is None:
+            return o, state, xx[:, -tail.shape[1]:]
+        # the K-1 inputs ending at the row's last real token: token t is
+        # xx[t + K-1]
+        idx = n[:, None] + jnp.arange(tail.shape[1])[None, :]
+        return o, state, jnp.take_along_axis(xx, idx[..., None], axis=1)
+
+    def _zeros(self, u, taps):
+        b, _, c = u.shape
+        dv = c // (3 * self.heads)
+        return (jnp.zeros((b, self.heads, dv, dv), jnp.float32),
+                jnp.zeros((b, taps - 1, c), u.dtype))
+
+    @jax.named_scope("cache_update")
+    def _put(self, rows, state, tail):
+        return dataclasses.replace(
+            self,
+            k=self.k.at[self.layer, rows].set(state, unique_indices=False),
+            v=self.v.at[self.layer, rows].set(tail.astype(self.v.dtype),
+                                              unique_indices=False))
+
+    def prompt(self, u, conv, g, beta, rows, lengths):
+        state, tail = self._zeros(u, conv.shape[-1])
+        o, state, tail = self._mix(u, conv, g, beta, tail, state, lengths)
+        return o, (self if self.k is None else self._put(rows, state, tail))
+
+    def chunk(self, u, conv, g, beta, rows, start, n):
+        fresh = (start == 0)
+        state = jnp.where(fresh[:, None, None, None], 0.0,
+                          self.k[self.layer, rows])
+        tail = jnp.where(fresh[:, None, None], 0,
+                         self.v[self.layer, rows]).astype(u.dtype)
+        o, state, tail = self._mix(u, conv, g, beta, tail, state, n)
+        return o, self._put(rows, state, tail)
+
+    def step(self, u, conv, g, beta):
+        from localai_tpu.ops.kda import kda_step, short_conv
+
+        b = u.shape[0]
+        active = (jnp.ones((b,), bool) if self.active is None
+                  else self.active)
+        with jax.named_scope("conv"):
+            old = self.v[self.layer]
+            y, xx = short_conv(old, u, conv)
+            q, k, v = (a[:, 0] for a in self._qkv(y))
+            tail = jnp.where(active[:, None, None],
+                             xx[:, 1:].astype(old.dtype), old)
+            tails = self.v.at[self.layer].set(tail)
+        if _pallas_attention(current_mesh()):
+            from localai_tpu.ops.pallas.kda import kda_decode
+
+            o, states = kda_decode(q, k, v, g[:, 0], beta[:, 0], self.k,
+                                   self.layer, active)
+        else:
+            with jax.named_scope("kda_decode"):
+                old = self.k[self.layer]
+                o, new = kda_step(q, k, v, g[:, 0], beta[:, 0], old)
+                states = self.k.at[self.layer].set(
+                    jnp.where(active[:, None, None, None], new, old))
+        return o[:, None], dataclasses.replace(self, k=states, v=tails)
 
 
 def _kernel(name: str, quant: bool, sharded: bool = True):
@@ -567,8 +679,8 @@ class TieredKV(PagedKV):
 def no_mixed(cfg, what: str):
     if cfg.layer_types is not None:
         raise NotImplementedError(
-            f"{what} does not take a model with window and full layers "
-            "(layer_types): it knows one cache per layer stack")
+            f"{what} does not take a model with window and full layers, or "
+            "linear ones (layer_types): it knows one cache per layer stack")
 
 
 def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
@@ -597,16 +709,24 @@ def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
     if k_cache is None and cfg.period is None:
         return NoKV(window=window)
     if k_cache is None:
-        return tuple(NoKV(window=window if kind == WINDOW else None)
+        return tuple(StateKV(heads=cfg.linear_heads) if kind == LINEAR
+                     else NoKV(window=window if kind == WINDOW else None)
                      for kind in cfg.period)
     if cfg.layer_types is None:
         return DenseKV(k_cache, v_cache, window, active=active)
-    full_len = max(k.shape[-2] for k, kind in zip(k_cache.slots, cfg.period)
-                   if kind == FULL)
-    return tuple(
-        RingKV(k, v, window, active=active, full_len=full_len)
-        if kind == WINDOW else DenseKV(k, v, None, active=active)
-        for k, v, kind in zip(k_cache.slots, v_cache.slots, cfg.period))
+    full_len = max((k.shape[-2] for k, kind
+                    in zip(k_cache.slots, cfg.period) if kind == FULL),
+                   default=None)
+
+    def one(k, v, kind):
+        if kind == LINEAR:
+            return StateKV(k, v, active=active, heads=cfg.linear_heads)
+        if kind == WINDOW:
+            return RingKV(k, v, window, active=active, full_len=full_len)
+        return DenseKV(k, v, None, active=active)
+
+    return tuple(one(k, v, kind) for k, v, kind
+                 in zip(k_cache.slots, v_cache.slots, cfg.period))
 
 
 def _decode_dq(q, kc, vc, lengths, sliding_window=None, table=None,

@@ -20,6 +20,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -27,7 +28,7 @@ from jax.sharding import PartitionSpec as P
 
 from localai_tpu.models import kv
 from localai_tpu.models.kv import (  # noqa: F401 — the names tests and the
-    FULL, WINDOW, PeriodKV, _decode_dq,  # engine import from here
+    FULL, LINEAR, WINDOW, PeriodKV, _decode_dq,  # engine import from here
 )
 from localai_tpu.ops.norms import rms_norm
 from localai_tpu.ops.rope import RopeConfig, rope_table, apply_rope
@@ -75,26 +76,68 @@ class LlamaConfig:
     layer_types: tuple[str, ...] | None = None
     window_rope: RopeConfig | None = None
     dtype: str = "bfloat16"
+    # LINEAR layers (a gated delta rule with per-channel decay over a
+    # recurrent state, ops/kda.py) beside FULL ones: heads x head size of
+    # the state, the short convolution's taps, the rank of the two low-rank
+    # gates (decay, output), and whether beta is doubled (eigenvalues of
+    # I - beta k k^T in (-1, 1)). Such a model's weights are stacked BY KIND
+    # (params["layers"][kind]): the kinds' leaves differ in shape.
+    linear_heads: int = 0
+    linear_head_dim: int = 0
+    linear_conv: int = 4
+    linear_gate_rank: int = 0
+    linear_neg_eigval: bool = False
+    use_rope: bool = True               # False: no position encoding at all
+    attn_gate: bool = False             # sigmoid(W x) on softmax attention's
+                                        # output, elementwise, before wo
+    # an expert layer that holds a SHARE of the experts: num_experts are
+    # held here, experts [first_expert, first_expert + num_experts) of the
+    # router_experts the router scores (0: it holds them all); a shared
+    # expert of shared_expert_width beside them (0: none)
+    router_experts: int = 0
+    first_expert: int = 0
+    shared_expert_width: int = 0
+    routed_scale: float = 1.0
 
     def __post_init__(self):
+        if self.router_experts and not (
+                0 <= self.first_expert
+                <= self.router_experts - self.num_experts):
+            raise ValueError(
+                f"experts [{self.first_expert}, {self.first_expert} + "
+                f"{self.num_experts}) are not among the router's "
+                f"{self.router_experts}")
         if self.layer_types is None:
             return
         kinds = tuple(self.layer_types)
         object.__setattr__(self, "layer_types", kinds)
-        if len(kinds) != self.num_layers or set(kinds) - {FULL, WINDOW}:
+        if (len(kinds) != self.num_layers
+                or set(kinds) - {FULL, WINDOW, LINEAR}):
             raise ValueError(
                 f"layer_types needs {self.num_layers} entries of "
-                f"{FULL!r}/{WINDOW!r}, got {kinds}")
+                f"{FULL!r}/{WINDOW!r}/{LINEAR!r}, got {kinds}")
         if len(set(kinds)) == 1:
             raise ValueError(
                 "layer_types with one kind of layer: leave it None (and set "
                 "sliding_window for an all-window model)")
-        if not self.sliding_window or self.sliding_window < 1:
+        if WINDOW in kinds and (not self.sliding_window
+                                or self.sliding_window < 1):
             raise ValueError("window layers need a sliding_window")
+        if LINEAR in kinds and not (self.linear_heads and self.linear_head_dim
+                                    and self.linear_gate_rank):
+            raise ValueError("linear layers need linear_heads, "
+                             "linear_head_dim and linear_gate_rank")
 
     @property
     def expert_width(self) -> int:
         return self.moe_intermediate_size or self.intermediate_size
+
+    @property
+    def stacked_by_kind(self) -> bool:
+        return self.layer_types is not None and LINEAR in self.layer_types
+
+    def layers_of(self, kind: str) -> int:
+        return self.layer_types.count(kind)
 
     @property
     def period(self) -> tuple[str, ...] | None:
@@ -147,6 +190,26 @@ def init_params(cfg: LlamaConfig, key, dtype=None):
     def norm(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) * (fan_in ** -0.5)).astype(dtype)
 
+    if cfg.stacked_by_kind:
+        def leaf(k, name, shape, how):
+            if how == "ones":
+                return jnp.ones(shape, dtype)
+            if how in ("A_log", "dt_bias"):
+                return decay_init(k, name, shape)
+            out = norm(k, shape, how)
+            return out.astype(jnp.float32) if name == "moe_gate" else out
+
+        layers = {}
+        for n, kind in enumerate(sorted(set(cfg.layer_types))):
+            shapes = layer_leaves(cfg, kind)
+            kk = jax.random.split(jax.random.fold_in(ks[0], n), len(shapes))
+            layers[kind] = {
+                name: leaf(kk[i], name, (cfg.layers_of(kind), *shape), how)
+                for i, (name, (shape, how)) in enumerate(shapes.items())}
+        return {"embed": norm(ks[7], (cfg.vocab_size, h), h),
+                "layers": layers, "final_norm": jnp.ones((h,), dtype),
+                "lm_head": norm(ks[8], (h, cfg.vocab_size), h)}
+
     layers = {
         "attn_norm": jnp.ones((L, h), dtype),
         "wq": norm(ks[0], (L, h, nh * hd), h),
@@ -181,6 +244,60 @@ def init_params(cfg: LlamaConfig, key, dtype=None):
     return params
 
 
+def layer_leaves(cfg: LlamaConfig, kind: str) -> dict:
+    """One layer's leaves for a model whose weights are stacked by kind
+    (cfg.stacked_by_kind): name -> (shape without the layer axis, how it is
+    drawn: a matrix's fan-in, "ones", or "A_log" / "dt_bias"). Matrices are
+    the names that start with `w` or `moe_w` (ops/quant.quantize_params)."""
+    h, hd = cfg.hidden_size, cfg.head_dim
+    out = {"attn_norm": ((h,), "ones")}
+    if kind == LINEAR:
+        nh, d, r = cfg.linear_heads, cfg.linear_head_dim, cfg.linear_gate_rank
+        c = nh * d
+        out.update({
+            "wq": ((h, c), h), "wk": ((h, c), h), "wv": ((h, c), h),
+            "wo": ((c, h), c),
+            # the decay gate's and the output gate's low-rank pairs, beta
+            "w_f1": ((h, r), h), "w_f2": ((r, c), 16 * r),
+            "w_g1": ((h, r), h), "w_g2": ((r, c), r),
+            "w_b": ((h, nh), h),
+            # depthwise causal convolution over q, k and v's channels
+            "conv": ((3 * c, cfg.linear_conv), cfg.linear_conv),
+            "A_log": ((nh,), "A_log"), "dt_bias": ((c,), "dt_bias"),
+            "o_norm": ((d,), "ones"),
+        })
+    else:
+        nh, nkv = cfg.num_heads, cfg.num_kv_heads
+        out.update({"wq": ((h, nh * hd), h), "wk": ((h, nkv * hd), h),
+                    "wv": ((h, nkv * hd), h), "wo": ((nh * hd, h), nh * hd)})
+        if cfg.attn_gate:
+            out["w_agate"] = ((h, nh * hd), h)
+    out["mlp_norm"] = ((h,), "ones")
+    e, i = cfg.num_experts, cfg.expert_width
+    out.update({"moe_gate": ((h, cfg.router_experts or e), h),
+                "moe_w1": ((e, h, i), h), "moe_w2": ((e, i, h), i),
+                "moe_w3": ((e, h, i), h)})
+    if cfg.shared_expert_width:
+        w = cfg.shared_expert_width
+        out.update({"ws_gate": ((h, w), h), "ws_up": ((h, w), h),
+                    "ws_down": ((w, h), w)})
+    return out
+
+
+def decay_init(key, name: str, shape):
+    """The family's initialisation of a linear layer's decay: A_log =
+    log U(1, 16) a head; dt_bias such that softplus(dt_bias) is log-uniform
+    in 1e-3..1e-1 a channel. With w_f2 drawn small (layer_leaves: the
+    input's part moves the gate by a factor of about 1.3) a token's decay
+    exp(-A softplus(.)) lies between about 0.1 and 0.999 and mostly in
+    0.9-0.999: never 0 or 1, so a decay left out or misapplied shows."""
+    if name == "A_log":
+        return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+    dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                    jnp.log(1e-3), jnp.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))        # softplus^-1(dt)
+
+
 def param_specs(cfg: LlamaConfig, qbits: int | None = None):
     """PartitionSpecs over mesh axes ('data','model'): Megatron-style TP.
 
@@ -194,6 +311,20 @@ def param_specs(cfg: LlamaConfig, qbits: int | None = None):
     the reduced-away input axis — so a row-parallel wo keeps its scales
     whole on every chip while its int8 body shards on the input axis.
     """
+    if cfg.stacked_by_kind:
+        # replicated: such a model has been served on one chip only (its
+        # expert layer already holds one chip's share of a wider layout)
+        def rep(name, shape):
+            spec = P(*(None,) * (len(shape) + 1))
+            matrix = name.startswith("w") or name.startswith("moe_w")
+            return {"q": spec, "s": spec} if qbits and matrix else spec
+
+        head = P(None, None)
+        return {"embed": P(None, None), "final_norm": P(None),
+                "lm_head": {"q": head, "s": head} if qbits else head,
+                "layers": {kind: {name: rep(name, shape) for name, (shape, _)
+                                  in layer_leaves(cfg, kind).items()}
+                           for kind in sorted(set(cfg.layer_types))}}
     layers = {
         "attn_norm": P(None, None),
         "wq": P(None, None, "model"),
@@ -310,7 +441,9 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
 
     A model with layer_types gets a PeriodKV pair instead: FULL layers at
     max_len, WINDOW layers at ring_len (`prefill_chunk` is then required: the
-    longest window `extend` will be given).
+    longest window `extend` will be given), LINEAR layers their state
+    [L/p, B, H, D, D] float32 (in the K tree) and their short convolution's
+    last inputs [L/p, B, K-1, 3 H D] in `dtype` (in the V tree): kv.StateKV.
     """
     quant = is_quant_kind(cache_type)
     dtype = dtype or cfg.jdtype
@@ -324,13 +457,23 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
 
     if cfg.layer_types is None:
         return one(cfg.num_layers, max_len)
-    if prefill_chunk is None:
-        raise ValueError("a model with window layers sizes their ring from "
-                         "prefill_chunk")
-    ring = ring_len(cfg, max_len, prefill_chunk, cache_type)
+    ring = None
+    if WINDOW in cfg.layer_types:
+        if prefill_chunk is None:
+            raise ValueError("a model with window layers sizes their ring "
+                             "from prefill_chunk")
+        ring = ring_len(cfg, max_len, prefill_chunk, cache_type)
     period = cfg.period
-    pairs = [one(cfg.num_layers // len(period),
-                 ring if kind == WINDOW else max_len) for kind in period]
+    n = cfg.num_layers // len(period)
+
+    def state():
+        nh, d = cfg.linear_heads, cfg.linear_head_dim
+        return (jnp.zeros((n, batch, nh, d, d), jnp.float32),
+                jnp.zeros((n, batch, cfg.linear_conv - 1, 3 * nh * d), dtype))
+
+    pairs = [state() if kind == LINEAR
+             else one(n, ring if kind == WINDOW else max_len)
+             for kind in period]
     return (PeriodKV(tuple(k for k, _ in pairs)),
             PeriodKV(tuple(v for _, v in pairs)))
 
@@ -392,6 +535,10 @@ def _mlp(x, lp, cfg=None, spec_prefix=None):
     leading batch/seq sharding of the activation: when given, gate/up outputs
     are constrained ffn-parallel (…, 'model') and the down projection back to
     (…, None) — the hints that keep TP weights sharded through the scan."""
+    if "ws_gate" in lp or ("moe_gate" in lp and lp["moe_gate"].shape[-1]
+                           != _leading(lp["moe_w1"]).shape[-3]):
+        # a shared expert, or fewer experts held than the router is wide
+        return _moe_routed(x, lp, cfg)
     if "moe_gate" in lp:
         return _moe_mlp(x, lp, cfg.experts_per_tok if cfg else 2)
     up_spec = down_spec = None
@@ -436,6 +583,182 @@ def _moe_mlp(x, lp, k: int):
         h3 = jnp.einsum("bsh,ehi->bsei", x, w3)
         y = jnp.einsum("bsei,eih->bseh", jax.nn.silu(h1) * h3, w2)
         return jnp.einsum("bseh,bse->bsh", y, combine.astype(x.dtype))
+
+
+@dataclasses.dataclass
+class _InStack:
+    """A layer's expert weights left WHERE THEY LIE: the kind's whole stack
+    [L, E, in, out] (int8: {q, s}) and the layer's index in it. The routed
+    layer slices (layer, expert) out in one step, a tile at a time; sliced
+    a layer at a time first, the loop's operand is a copy of all the
+    layer's experts (0.6 GB a layer at the cell's widths: 12 ms a step)."""
+    stack: object
+    layer: object
+
+    def parts(self):
+        """(body [L, E, in, out], scales [L, E, out] or None)."""
+        from localai_tpu.ops.quant import is_quantized
+
+        if is_quantized(self.stack):
+            return self.stack["q"], self.stack["s"][..., 0, :]
+        return self.stack, None
+
+    def whole(self):
+        """This layer's (body [E, in, out], scales [E, out] or None)."""
+        return tuple(a if a is None else jax.lax.dynamic_index_in_dim(
+            a, self.layer, keepdims=False) for a in self.parts())
+
+
+def _leading(p):
+    """The array of a weight leaf that has its leading axes (int8: `q`)."""
+    if isinstance(p, _InStack):
+        p = p.stack
+    return p["q"] if isinstance(p, dict) else p
+
+
+def _grouped_experts(xt, token, rank, sizes, up, gate, down, tm: int):
+    """SwiGLU experts over P token-expert pairs SORTED BY EXPERT: the first
+    sizes[e] sorted rows are expert 0's, the next expert 1's, ...; rows past
+    sum(sizes) belong to no expert here. token [P]: the row of xt [N, h] a
+    sorted row computes on; rank [P]: where each pair (in its own order)
+    stands among the sorted rows. up, gate, down: _InStack (the stacks the
+    weights lie in, int8 or not, and the layer's index). Returns [P, h] in
+    xt's dtype, in PAIR order, zero for a pair of no expert here.
+
+    The rows are laid out in TILES of `tm` rows, each group padded to whole
+    tiles, so that a tile has one expert: a loop over the tiles IN USE (a
+    dynamic count: sum of ceil(sizes / tm), at most `tiles`) slices that
+    expert's three matrices out of the stacks where they lie and runs the
+    three products of the tile. Every pair is computed, none is dropped; the
+    static worst case is every group ending one row into a tile. An expert
+    no pair chose is not read at all. (jax.lax.ragged_dot lowers to a dense
+    product over every group on this chip's compiler: 40 times the
+    operations, PERF.md section 6.)"""
+    p, h = token.shape[0], xt.shape[-1]
+    held = sizes.shape[0]
+    tiles = -(-(p + held * (tm - 1)) // tm)
+    with jax.named_scope("dispatch"):
+        per = -(-sizes // tm)                              # tiles a group
+        tile_end = jnp.cumsum(per)                         # [E]
+        used = tile_end[-1]
+        ends = jnp.cumsum(sizes)
+        start = ends - sizes                               # a group's 1st row
+        tile_e = jnp.minimum(jnp.searchsorted(
+            tile_end, jnp.arange(tiles), side="right"), held - 1)
+        # padded slot -> sorted row (or none)
+        slot = jnp.arange(tiles * tm)
+        e_of = tile_e[slot // tm]
+        nth = slot - (tile_end[e_of] - per[e_of]) * tm
+        ok = (nth < sizes[e_of]) & (slot // tm < used)
+        src = token[jnp.where(ok, start[e_of] + nth, 0)]
+        xp = jnp.where(ok[:, None], xt[src], 0).reshape(tiles, tm, h)
+        # pair -> sorted row -> padded slot
+        total = ends[-1]
+        rows = rank
+        e_row = jnp.minimum(jnp.searchsorted(ends, rows, side="right"),
+                            held - 1)
+        back = (tile_end[e_row] - per[e_row]) * tm + rows - start[e_row]
+
+    def product(a, w, e):
+        body, scale = w.parts()
+        at = (jnp.asarray(w.layer, jnp.int32), e)
+        y = a @ jax.lax.dynamic_slice(
+            body, (*at, 0, 0), (1, 1, *body.shape[2:]))[0, 0].astype(a.dtype)
+        if scale is None:
+            return y
+        return y * jax.lax.dynamic_slice(
+            scale, (*at, 0), (1, 1, scale.shape[2]))[0, 0].astype(y.dtype)
+
+    def tile(t, out):
+        e = tile_e[t]
+        a = jax.lax.dynamic_index_in_dim(xp, t, keepdims=False)
+        act = jax.nn.silu(product(a, up, e)) * product(a, gate, e)
+        return jax.lax.dynamic_update_index_in_dim(
+            out, product(act, down, e), t, 0)
+
+    with jax.named_scope("expert_einsums"):
+        out = jax.lax.fori_loop(0, used, tile,
+                                jnp.zeros((tiles, tm, h), xt.dtype))
+    with jax.named_scope("dispatch"):
+        y = out.reshape(tiles * tm, h)[jnp.minimum(back, tiles * tm - 1)]
+        return jnp.where((rows < total)[:, None], y, 0)
+
+
+@jax.named_scope("experts")
+def _moe_routed(x, lp, cfg: LlamaConfig, grouped: bool = True):
+    """The expert layer of a model with a shared expert and/or a SHARE of
+    the routed experts: the layer holds experts [first, first + E) of the R
+    the router scores. The router, the top-k and the renormalisation are
+    over all R; the layer returns the shared expert plus the chosen experts
+    that are HERE, each under its weight among all k chosen. What the
+    absent experts would add is left out (the other chips of an
+    expert-parallel layout hold them; on one chip there is no exchange).
+
+    grouped (what is served, in prefill, extend and decode): the
+    token-expert pairs that land here, sorted by expert, as grouped
+    products over the int8 expert weights (_grouped_experts: every pair is
+    computed, the static worst case is all N k of them). Not grouped (the
+    tests' and the bench's twin): every held expert on every token under
+    the combine mask; on the chip it is slower at 512 tokens (3.7 against
+    2.3 ms a layer) and at a decode step's 32 rows (0.91 against 0.81).
+    """
+    b, s, h = x.shape
+    k = cfg.experts_per_tok
+    xt = x.reshape(b * s, h)
+    n = b * s
+    held = _leading(lp["moe_w1"]).shape[-3]
+    with jax.named_scope("router"):
+        logits = xt.astype(jnp.float32) @ lp["moe_gate"].astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)                # [N, R]
+        top_w, top_i = jax.lax.top_k(probs, k)
+        top_w = (top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+                 * cfg.routed_scale)
+        local = top_i - cfg.first_expert                       # [N, k]
+        here = (local >= 0) & (local < held)
+    w1, w2, w3 = (lp[n] if isinstance(lp[n], _InStack)
+                  else _InStack(jax.tree_util.tree_map(lambda a: a[None],
+                                                       lp[n]), 0)
+                  for n in ("moe_w1", "moe_w2", "moe_w3"))
+    if grouped:
+        with jax.named_scope("dispatch"):
+            # pairs sorted by the expert they chose; those that chose an
+            # expert held elsewhere sort last, into no group
+            eid = jnp.where(here, local, held).reshape(-1)     # [N k]
+            order = jnp.argsort(eid, stable=True)
+            sizes = jnp.bincount(eid, length=held + 1)[:held].astype(
+                jnp.int32)
+        # tile: about twice the pairs an expert gets if routing is even
+        even = n * k / lp["moe_gate"].shape[-1]
+        tm = int(min(128, max(8, 2 ** math.ceil(math.log2(2 * even)))))
+        y = _grouped_experts(xt, order // k, jnp.argsort(order), sizes,
+                             w1, w3, w2, tm)                   # [N k, h]
+        with jax.named_scope("dispatch"):
+            # a pair under its weight, a token's k pairs summed
+            y = (y.astype(jnp.float32).reshape(n, k, h)
+                 * top_w[..., None]).sum(1)
+    else:
+        with jax.named_scope("router"):
+            combine = jnp.einsum(
+                "nke,nk->ne",
+                jax.nn.one_hot(jnp.where(here, local, held), held,
+                               dtype=jnp.float32), top_w)
+        with jax.named_scope("expert_einsums"):
+            def dq(w):
+                w, scale = w.whole()
+                w = w.astype(x.dtype)
+                return w if scale is None else w * scale[:, None, :].astype(
+                    x.dtype)
+
+            h1 = jnp.einsum("nh,ehi->nei", xt, dq(w1))
+            h3 = jnp.einsum("nh,ehi->nei", xt, dq(w3))
+            y = jnp.einsum("nei,eih->neh", jax.nn.silu(h1) * h3, dq(w2))
+            y = jnp.einsum("neh,ne->nh", y.astype(jnp.float32), combine)
+    if "ws_gate" in lp:
+        with jax.named_scope("shared"):
+            y = y + qmatmul(jax.nn.silu(qmatmul(xt, lp["ws_gate"]))
+                            * qmatmul(xt, lp["ws_up"]),
+                            lp["ws_down"]).astype(jnp.float32)
+    return y.astype(x.dtype).reshape(b, s, h)
 
 
 # Activation sharding hints: hard constraints when a mesh is active (raises on
@@ -491,7 +814,7 @@ def rope_tables(cfg: LlamaConfig, max_len: int):
     if cfg.layer_types is None:
         return rope_table(cfg.rope, max_len)
     tabs = {kind: rope_table(cfg.rope_of(kind), max_len)
-            for kind in (FULL, WINDOW)}
+            for kind in (FULL, WINDOW) if cfg.use_rope}
     return ({kind: t[0] for kind, t in tabs.items()},
             {kind: t[1] for kind, t in tabs.items()})
 
@@ -530,22 +853,58 @@ def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
     (parallel/pipeline.py), where constraints are illegal."""
     b, s, _ = x.shape
     sharded = lambda *tail: spec and P(*spec, *tail)  # noqa: E731
-    lcos, lsin = (cos, sin) if kind is None else (cos[kind], sin[kind])
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
-    with _attn_scope(kind):
-        q, k, v = _qkv(h, lp, cfg, spec=sharded("model"))
-        q = apply_rope(q, lcos, lsin, positions)
-        k = apply_rope(k, lcos, lsin, positions)
-        if spec is not None:
-            q = _shard_act(q, sharded("model", None))
-    attn, out = attend(q, k, v)
-    with _attn_scope(kind), jax.named_scope("attention"):
-        x = x + qmatmul(attn.reshape(b, s, -1), lp["wo"], spec=sharded(None))
+    if kind == LINEAR:
+        x, out = _linear_mixer(cfg, x, h, lp, attend)
+    else:
+        with _attn_scope(kind):
+            q, k, v = _qkv(h, lp, cfg, spec=sharded("model"))
+            if cfg.use_rope:
+                lcos, lsin = ((cos, sin) if kind is None
+                              else (cos[kind], sin[kind]))
+                q = apply_rope(q, lcos, lsin, positions)
+                k = apply_rope(k, lcos, lsin, positions)
+            if spec is not None:
+                q = _shard_act(q, sharded("model", None))
+        attn, out = attend(q, k, v)
+        with _attn_scope(kind), jax.named_scope("attention"):
+            attn = attn.reshape(b, s, -1)
+            if "w_agate" in lp:
+                attn = attn * jax.nn.sigmoid(qmatmul(h, lp["w_agate"]))
+            x = x + qmatmul(attn, lp["wo"], spec=sharded(None))
     h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
     x = x + _mlp(h, lp, cfg, spec_prefix=spec)
     if spec is not None:
         x = _shard_act(x, sharded(None))
     return x, out
+
+
+def _linear_mixer(cfg: LlamaConfig, x, h, lp, attend):
+    """A LINEAR layer's mixer over the normed input h [B, S, H]: q, k, v
+    before their short convolution, side by side (the cache's view keeps
+    the convolution's tail and the state, and does the rest:
+    kv.StateKV); the per-channel log-decay g = -exp(A_log) softplus(W_f2
+    W_f1 h + dt_bias) <= 0 and beta = sigmoid(W_b h), doubled where the
+    config allows negative eigenvalues; `attend(u, conv, g, beta) ->
+    (o [B, S, heads, D], out)`; then RMSNorm a head, the low-rank sigmoid
+    output gate, and wo."""
+    b, s, _ = h.shape
+    nh = cfg.linear_heads
+    with _attn_scope(LINEAR):
+        u = jnp.concatenate([qmatmul(h, lp[w]) for w in ("wq", "wk", "wv")],
+                            axis=-1)
+        f32 = jnp.float32
+        z = qmatmul(qmatmul(h, lp["w_f1"]), lp["w_f2"]).astype(f32)
+        g = -jnp.exp(lp["A_log"].astype(f32))[:, None] * jax.nn.softplus(
+            (z + lp["dt_bias"].astype(f32)).reshape(b, s, nh, -1))
+        beta = jax.nn.sigmoid(qmatmul(h, lp["w_b"]).astype(f32))
+        if cfg.linear_neg_eigval:
+            beta = 2.0 * beta
+    o, out = attend(u, lp["conv"], g, beta)
+    with _attn_scope(LINEAR):
+        o = rms_norm(o, lp["o_norm"], cfg.rms_eps).astype(h.dtype)
+        gate = jax.nn.sigmoid(qmatmul(qmatmul(h, lp["w_g1"]), lp["w_g2"]))
+        return x + qmatmul(o.reshape(b, s, -1) * gate, lp["wo"]), out
 
 
 def _scan_layers(cfg: LlamaConfig, block, x, layers, cache):
@@ -586,11 +945,24 @@ def _scan_layers(cfg: LlamaConfig, block, x, layers, cache):
                             (layers, cache.k, cache.v, *cache.cold))
     p = len(period)
 
+    def weights(i, j, kind):
+        if not cfg.stacked_by_kind:
+            return _layer_params(layers, i * p + j)
+        # stacks by kind: this layer's place among its kind's. The routed
+        # experts stay in their stack (_InStack): sliced a tile at a time
+        n = i * period.count(kind) + period[:j].count(kind)
+        stack = layers[kind]
+        lp = _layer_params({k: v for k, v in stack.items()
+                            if not k.startswith("moe_w")}, n)
+        lp.update({k: _InStack(v, n) for k, v in stack.items()
+                   if k.startswith("moe_w")})
+        return lp
+
     def step(carry, i):
         x, ks, vs = carry
         ks, vs = list(ks), list(vs)
         for j, kind in enumerate(period):
-            x, view = block(x, _layer_params(layers, i * p + j),
+            x, view = block(x, weights(i, j, kind),
                             cache[j].at(ks[j], vs[j], i), kind)
             ks[j], vs[j] = view.k, view.v
         return (x, tuple(ks), tuple(vs)), None
@@ -631,6 +1003,14 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     x = _shard_act(_embed(params, cfg, tokens, inject), P("data", sax, None))
 
     def layer(x, lp, view, kind):
+        if kind == LINEAR:
+            def mix(u, conv, g, beta):
+                with _attn_scope(kind):
+                    return view.prompt(u, conv, g, beta, slot_map, lengths)
+
+            return _block(cfg, x, lp, kind, cos, sin, positions, mix,
+                          ("data", sax))
+
         def attend(q, k, v):
             with _attn_scope(kind):
                 return self_attention(q, k, v, lengths,
@@ -678,6 +1058,11 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
             wrote = view.append(k, v, lengths, positions)
             with _attn_scope(kind):
                 return wrote.decode(q, lengths + 1), wrote
+
+        if kind == LINEAR:
+            def attend(u, conv, g, beta):  # noqa: F811
+                with _attn_scope(kind):
+                    return view.step(u, conv, g, beta)
 
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,
                       ("data", None))
@@ -1026,6 +1411,11 @@ def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):
                 return self_attention(q, k, v, lengths,
                                       sliding_window=view.window), view
 
+        if kind == LINEAR:
+            def attend(u, conv, g, beta):  # noqa: F811
+                with _attn_scope(kind):
+                    return view.prompt(u, conv, g, beta, None, lengths)
+
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,
                       ("data", sax))
 
@@ -1065,6 +1455,13 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
             with _attn_scope(kind), jax.named_scope("attention"):
                 return wrote.attend_window(q, positions, start, rows,
                                            slot_map is not None), wrote
+
+        if kind == LINEAR:
+            def attend(u, conv, g, beta):  # noqa: F811
+                with _attn_scope(kind):
+                    return view.chunk(
+                        u, conv, g, beta, rows, start,
+                        None if last_pos is None else last_pos + 1)
 
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,
                       ("data", None))

@@ -29,6 +29,11 @@ formulae), not from the served code:
 Mixtral is the case "every layer full, 8 experts top-2"; Mellum2 "three
 window layers then a full one, each kind its own RoPE, 64 experts top-8".
 
+A model with linear-attention layers (a recurrent state beside the softmax
+layers' cache: Solar-Open2) has its reference in the sibling
+localai_tpu/testing/reference_linear.py: this file's code is held equal, to
+the letter, to benchmark/reference/mellum2.py.
+
 Departures from the published descriptions: none in the mathematics. The
 experts' sum is taken expert by expert over the tokens that chose the expert
 (a token's other experts add exact zeros), so that a block of positions is

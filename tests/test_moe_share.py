@@ -1,0 +1,158 @@
+"""The expert layer that holds a share of the routed experts
+(models/llama._moe_routed): the eight shares add up to the whole layer of
+the uncut reference, the grouped form equals the masked form on a share,
+and the programs of a share-holding model carry neither the dense dispatch
+nor a state-sized scan operand."""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from localai_tpu.models.llama import (
+    FULL, LINEAR, LlamaConfig, _moe_routed, extend, init_kv_cache,
+    init_params, rope_tables,
+)
+from localai_tpu.ops.quant import quantize
+from localai_tpu.testing import reference_linear as ref
+
+H, R, HELD, K, WIDTH = 32, 32, 4, 4, 16
+
+
+def _cfg(first=0, held=HELD, **over):
+    return LlamaConfig(**{**dict(
+        vocab_size=64, hidden_size=H, intermediate_size=64, num_layers=8,
+        num_heads=4, num_kv_heads=2, head_dim=8, max_position=512,
+        num_experts=held, experts_per_tok=K, moe_intermediate_size=WIDTH,
+        router_experts=R, first_expert=first, shared_expert_width=WIDTH,
+        layer_types=(FULL, LINEAR, LINEAR, LINEAR) * 2, linear_heads=2,
+        linear_head_dim=16, linear_gate_rank=16, linear_neg_eigval=True,
+        use_rope=False, attn_gate=True, dtype="float32"), **over})
+
+
+@pytest.fixture(scope="module")
+def whole():
+    """All 32 experts' weights and a router, one layer."""
+    rng = np.random.default_rng(0)
+    w = lambda *s: jnp.asarray(  # noqa: E731
+        rng.standard_normal(s) * s[-2] ** -0.5, jnp.float32)
+    return {"moe_gate": w(H, R), "moe_w1": w(R, H, WIDTH),
+            "moe_w3": w(R, H, WIDTH), "moe_w2": w(R, WIDTH, H),
+            "ws_gate": w(H, WIDTH), "ws_up": w(H, WIDTH),
+            "ws_down": w(WIDTH, H)}
+
+
+def _share(lp, first, held=HELD, shared=True):
+    out = {k: (v[first:first + held] if k.startswith("moe_w") else v)
+           for k, v in lp.items()}
+    return out if shared else {k: v for k, v in out.items()
+                               if not k.startswith("ws_")}
+
+
+def _x(n=24, seed=1):
+    return jnp.asarray(np.random.default_rng(seed).standard_normal(
+        (2, n // 2, H)), jnp.float32)
+
+
+def test_the_eight_shares_add_up_to_the_whole_layer(whole):
+    """Routed parts of all eight shares + the shared expert counted once =
+    the uncut reference's expert layer (router 32 wide, top-4, all held)."""
+    x = _x()
+    rcfg = ref.RefConfig(
+        vocab_size=64, hidden_size=H, num_layers=1, num_heads=4,
+        num_kv_heads=2, head_dim=8, rms_eps=1e-5, layer_types=(FULL,),
+        linear_heads=2, linear_head_dim=16, num_experts=R, experts_per_tok=K)
+    names = {"moe_gate": "router", "moe_w1": "w1", "moe_w2": "w2",
+             "moe_w3": "w3"}
+    want = ref.experts(x.reshape(-1, H),
+                       {names.get(k, k): v for k, v in whole.items()}, rcfg)
+    total = jnp.zeros_like(want)
+    for n in range(R // HELD):
+        part = _moe_routed(x, _share(whole, n * HELD, shared=(n == 0)),
+                           _cfg(first=n * HELD))
+        total = total + part.reshape(-1, H)
+    assert float(jnp.abs(total - want).max()) < 1e-5
+    # one share alone is far from the whole: nothing stands in for the rest
+    one = _moe_routed(x, _share(whole, 0), _cfg()).reshape(-1, H)
+    assert float(jnp.abs(one - want).max()) > 0.05
+
+
+@pytest.mark.parametrize("first", [0, 12, 28])
+@pytest.mark.parametrize("int8", [False, True])
+def test_grouped_equals_masked_on_a_share(whole, first, int8):
+    lp = _share(whole, first)
+    if int8:
+        lp = {k: quantize(v) if k.startswith(("moe_w", "ws_")) else v
+              for k, v in lp.items()}
+    x = _x(seed=first)
+    a = _moe_routed(x, lp, _cfg(first), grouped=True)
+    b = _moe_routed(x, lp, _cfg(first), grouped=False)
+    assert float(jnp.abs(a - b).max()) < 1e-5
+
+
+def test_the_whole_is_a_share_too(whole):
+    """Written for any share: all 32 held, first 0, against the reference."""
+    x = _x(seed=3)
+    got = _moe_routed(x, _share(whole, 0, held=R), _cfg(0, held=R))
+    again = _moe_routed(x, _share(whole, 0, held=R), _cfg(0, held=R),
+                        grouped=False)
+    assert float(jnp.abs(got - again).max()) < 1e-5
+
+
+def test_a_share_outside_the_router_is_refused():
+    with pytest.raises(ValueError, match="not among the router"):
+        _cfg(first=30)
+
+
+def _shapes(jaxpr):
+    """(every value's shape; every scan's xs and ys shapes)."""
+    values, operands = set(), set()
+
+    def visit(jx):
+        for eqn in jx.eqns:
+            for v in eqn.outvars:
+                if hasattr(v.aval, "shape"):
+                    values.add(tuple(v.aval.shape))
+            if eqn.primitive.name == "scan":
+                nc, nk = eqn.params["num_consts"], eqn.params["num_carry"]
+                for v in list(eqn.invars[nc + nk:]) + list(eqn.outvars[nk:]):
+                    operands.add(tuple(v.aval.shape))
+            for p in eqn.params.values():
+                for sub in (p if isinstance(p, (list, tuple)) else [p]):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        visit(sub)
+
+    visit(jaxpr.jaxpr)
+    return values, operands
+
+
+def test_extend_has_no_dense_dispatch_and_no_state_sized_scan_operand():
+    cfg = _cfg()
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    slots, tokens = 3, 32
+    kc, vc = init_kv_cache(cfg, slots, 128, jnp.float32, prefill_chunk=tokens)
+    cos, sin = rope_tables(cfg, 128)
+    jaxpr = jax.make_jaxpr(lambda p, kc, vc: extend(
+        p, cfg, jnp.zeros((1, tokens), jnp.int32), jnp.array([32]), cos, sin,
+        kc, vc, slot_map=jnp.array([1]), with_logits=False,
+        full_window=True))(params, kc, vc)
+    values, operands = _shapes(jaxpr)
+    # the dense dispatch's [tokens, experts held, expert width] is not there
+    assert (tokens, HELD, WIDTH) not in values
+    assert (1, tokens, HELD, WIDTH) not in values
+    # the routed one is, a tile of 8 sorted pairs at a time
+    assert (8, WIDTH) in values
+    # no scan takes or gives a state, or a layer's stack of states
+    state = (2, 16, 16)
+    per_place = cfg.num_layers // len(cfg.period)
+    for shape in operands:
+        assert shape[-3:] != state or len(shape) < 4, shape
+        assert shape != (per_place, slots, *state)
+    # the detector fires on the masked form
+    dense = jax.make_jaxpr(lambda x, lp: _moe_routed(
+        x, lp, cfg, grouped=False))(
+        jnp.zeros((1, tokens, H)),
+        jax.tree_util.tree_map(lambda a: a[0], params["layers"][FULL]))
+    assert (tokens, HELD, WIDTH) in _shapes(dense)[0]

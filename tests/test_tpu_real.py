@@ -400,6 +400,60 @@ def test_ragged_scatter_append_q8(H, KVH, D):
         np.testing.assert_array_equal(np.asarray(g[1:]), np.asarray(w[1:]))
 
 
+# ------------------------------------------------------------- kda.py
+
+def _kda_inputs(b, s, h, d, seed):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, s, h, d)))
+    v = jax.random.normal(ks[2], (b, s, h, d))
+    g = -jnp.exp(jax.random.uniform(ks[3], (b, s, h, d), minval=-7.0,
+                                    maxval=0.5))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    return q, k, v, g, beta, jax.random.normal(ks[5], (b, h, d, d))
+
+
+@pytest.mark.parametrize("live", ["all", "most", "one", "none"])
+@pytest.mark.parametrize("layer", [0, 1])
+def test_kda_decode_at_the_cells_shape(live, layer):
+    """ops/pallas/kda.py at the new cell's shape (32 slots, 64 heads x 128 x
+    128 float32, a stack of two layers): Mosaic lowers it, a live row's
+    state and output are the XLA twin's, a row that is not decoding keeps
+    the NaN its state holds, and the other layer is untouched."""
+    from localai_tpu.ops.kda import kda_step
+    from localai_tpu.ops.pallas.kda import kda_decode
+
+    b, h, d = 32, 64, 128
+    q, k, v, g, beta, _ = _kda_inputs(b, 1, h, d, seed=3)
+    q, k, v, g, beta = (a[:, 0] for a in (q, k, v, g, beta))
+    stack = jax.random.normal(jax.random.PRNGKey(4), (2, b, h, d, d))
+    mask = np.zeros((b,), bool)
+    mask[{"all": slice(None), "most": slice(3, 30), "one": slice(17, 18),
+          "none": slice(0, 0)}[live]] = True
+    planted = stack.at[layer].set(jnp.where(
+        jnp.asarray(mask)[:, None, None, None], stack[layer], jnp.nan))
+    o, out = kda_decode(q, k, v, g, beta, planted, layer, jnp.asarray(mask))
+    want_o, want_s = kda_step(q, k, v, g, beta, stack[layer])
+    _close(o[mask], want_o[mask], tol=1e-3)
+    _close(out[layer][mask], want_s[mask], tol=1e-3)
+    assert bool((o[~mask] == 0).all())
+    assert bool(jnp.isnan(out[layer][~mask]).all())
+    assert bool((out[1 - layer] == stack[1 - layer]).all())
+
+
+def test_kda_chunk_at_the_cells_shape():
+    """ops/kda.py's chunkwise form (XLA, float32 products at HIGHEST) over
+    one 512-token chunk of 64 heads against the token-by-token scan."""
+    from localai_tpu.ops.kda import kda_chunk, kda_recurrent
+
+    q, k, v, g, beta, state = _kda_inputs(1, 512, 64, 128, seed=5)
+    o, s = jax.jit(kda_chunk)(q, k, v, g, beta, state)
+    want_o, want_s = jax.jit(kda_recurrent)(q, k, v, g, beta, state)
+    _close(o, want_o, tol=1e-3)
+    _close(s, want_s, tol=1e-3)
+
+
 # ------------------------------------------------------------- engine
 
 def _tiny_cfg(H, KVH, D):

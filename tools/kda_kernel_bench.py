@@ -1,0 +1,185 @@
+#!/usr/bin/env python3
+"""The kernels a linear-attention layer adds, alone, on the chip, at the
+cell's shapes (solar-open2-250b-ep8-d8: 64 heads x 128 x 128 float32 state,
+32 slots, 512-token chunks; 40 experts of 4096 x 1280 int8 held, top-8 of
+320), each against its share of the roofline
+(benchmark/harness/roofline_kda.py, benchmark/peaks/):
+
+    python tools/kda_kernel_bench.py [--seed 31]
+
+- `kda_decode` (ops/pallas/kda.py) over a stack of two layers: every row
+  live, the cell's mix (`--live` rows of 32 live), and its XLA twin;
+- `kda_chunk` (ops/kda.py, the XLA form) over one 512-token chunk of one
+  row, products at HIGHEST precision (as served) and at the default;
+- the routed expert layer (models/llama._moe_routed) at a decode step's 32
+  rows and at a chunk's 512 tokens: the pairs sorted by expert as grouped
+  products (served) against every held expert under a mask.
+
+The table goes to stdout and to chiprun_out/kda_kernel_bench.json.
+`--cpu-rehearsal` proves the script at a tiny size on the CPU and times
+nothing.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, ROOT)
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--seed", type=int, default=31)
+    ap.add_argument("--live", type=int, default=27)
+    ap.add_argument("--reps", type=int, default=20)
+    ap.add_argument("--cpu-rehearsal", action="store_true")
+    ap.add_argument("--out", default=os.path.join(
+        ROOT, "chiprun_out", "kda_kernel_bench.json"))
+    args = ap.parse_args()
+    if args.cpu_rehearsal:
+        os.environ["JAX_PLATFORMS"] = "cpu"
+        os.environ["LOCALAI_FORCE_PALLAS"] = "1"
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmark.harness import roofline_kda as rk
+    from localai_tpu.models.llama import LlamaConfig, _moe_routed
+    from localai_tpu.ops import kda
+    from localai_tpu.ops.pallas.kda import kda_decode
+
+    rehearsal = args.cpu_rehearsal
+    if not rehearsal and jax.default_backend() != "tpu":
+        print("no TPU here: run it through the chip tool, or rehearse with "
+              "--cpu-rehearsal", file=sys.stderr)
+        return 1
+    with open(os.path.join(ROOT, "benchmark", "peaks",
+                           "TPU_v5_lite.json")) as f:
+        peaks = json.load(f)
+    B, H, D, T = (4, 32, 128, 128) if rehearsal else (32, 64, 128, 512)
+    dk = 16 if rehearsal else D
+    hidden, held, width, routers, topk = ((64, 4, 32, 16, 4) if rehearsal
+                                          else (4096, 40, 1280, 320, 8))
+    reps = 2 if rehearsal else args.reps
+    ks = jax.random.split(jax.random.PRNGKey(args.seed), 12)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+
+    def timed(fn, *a):
+        out = fn(*a)
+        jax.block_until_ready(out)
+        t = time.perf_counter()
+        for _ in range(reps):
+            out = fn(*a)
+        jax.block_until_ready(out)
+        return (time.perf_counter() - t) / reps, out
+
+    report = {"device": [jax.devices()[0].platform,
+                         jax.devices()[0].device_kind],
+              "rehearsal": rehearsal, "rows": []}
+
+    def row(name, seconds, cost=None, **more):
+        r = {"name": name, "ms": None if rehearsal else seconds * 1e3, **more}
+        if cost is not None and not rehearsal:
+            least = rk.least_seconds(cost, peaks)
+            r.update(roofline_pct=rk.roofline_share(cost, peaks, seconds),
+                     least_ms=least["seconds"] * 1e3, bound=least["bound"],
+                     gb_per_s=cost["bytes"] / seconds / 1e9)
+        report["rows"].append(r)
+        print(json.dumps(r), flush=True)
+
+    # ---- kda_decode
+    q = unit(jax.random.normal(ks[0], (B, H, dk))) * dk ** -0.5
+    k = unit(jax.random.normal(ks[1], (B, H, dk)))
+    v = jax.random.normal(ks[2], (B, H, D))
+    g = -jnp.exp(jax.random.uniform(ks[3], (B, H, dk), minval=-7., maxval=0.))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (B, H)))
+    stack = jax.random.normal(ks[5], (2, B, H, dk, D))
+    live_n = min(args.live, B)
+    live = np.zeros((B,), bool)
+    live[np.random.default_rng(args.seed).permutation(B)[:live_n]] = True
+    want_o, want_s = kda.kda_step(q, k, v, g, beta, stack[1])
+    for name, mask in (("all rows live", np.ones((B,), bool)),
+                       (f"{live_n} of {B} rows live", live)):
+        step = jax.jit(lambda s, m: kda_decode(q, k, v, g, beta, s, 1, m),
+                       donate_argnums=(0,))
+        o, s1 = step(stack + 0, jnp.asarray(mask))
+        err = float(jnp.abs(jnp.where(mask[:, None, None], o - want_o,
+                                      0)).max())
+        assert err < 1e-3, err
+        assert bool((s1[1][~mask] == stack[1][~mask]).all())
+        state = [stack + 0]
+
+        def run(m):
+            o, state[0] = step(state[0], m)
+            return o
+
+        sec, _ = timed(run, jnp.asarray(mask))
+        row(f"kda_decode, {name}", sec,
+            rk.kda_decode_cost(int(mask.sum()), H, dk, D), max_err=err)
+    twin = jax.jit(lambda s: kda.kda_step(q, k, v, g, beta, s))
+    sec, _ = timed(twin, stack[1])
+    row("kda_step (XLA twin), all rows", sec, rk.kda_decode_cost(B, H, dk, D))
+
+    # ---- kda_chunk
+    cq = unit(jax.random.normal(ks[6], (1, T, H, dk))) * dk ** -0.5
+    ck = unit(jax.random.normal(ks[7], (1, T, H, dk)))
+    cv = jax.random.normal(ks[8], (1, T, H, D))
+    cg = -jnp.exp(jax.random.uniform(ks[9], (1, T, H, dk), minval=-7.,
+                                     maxval=0.))
+    cb = 2 * jax.nn.sigmoid(jax.random.normal(ks[10], (1, T, H)))
+    s0 = stack[0, :1]
+    chunk_cost = rk.kda_chunk_cost(T, H, dk, D)
+    served = jax.jit(lambda s: kda.kda_chunk(cq, ck, cv, cg, cb, s))
+    sec, (o_hi, s_hi) = timed(served, s0)
+    ro, rs = kda.kda_recurrent(cq, ck, cv, cg, cb, s0)
+    row("kda_chunk (XLA, HIGHEST: as served)", sec, chunk_cost,
+        max_err=float(jnp.abs(o_hi - ro).max()))
+    hi = kda._HI
+    kda._HI = None
+    try:
+        loose = jax.jit(lambda s: kda.kda_chunk(cq, ck, cv, cg, cb, s))
+        sec, (o_lo, _) = timed(loose, s0)
+    finally:
+        kda._HI = hi
+    row("kda_chunk (XLA, default precision)", sec, chunk_cost,
+        max_err=float(jnp.abs(o_lo - ro).max()))
+
+    # ---- the routed expert layer
+    cfg = LlamaConfig(hidden_size=hidden, num_experts=held,
+                      experts_per_tok=topk, moe_intermediate_size=width,
+                      router_experts=routers, first_expert=0,
+                      shared_expert_width=0)
+    rng = jax.random.split(ks[11], 8)
+    qw = lambda kk, shape: {  # noqa: E731
+        "q": jax.random.randint(kk, shape, -127, 128, jnp.int8),
+        "s": jnp.full(shape[:-2] + (1, shape[-1]),
+                      shape[-2] ** -0.5 / 73, jnp.float32)}
+    lp = {"moe_gate": jax.random.normal(rng[0], (hidden, routers)) * 0.02,
+          "moe_w1": qw(rng[1], (held, hidden, width)),
+          "moe_w3": qw(rng[2], (held, hidden, width)),
+          "moe_w2": qw(rng[3], (held, width, hidden))}
+    for n in (B, T):
+        x = jax.random.normal(rng[4], (1, n, hidden), jnp.bfloat16)
+        outs = {}
+        for grouped in (True, False):
+            fn = jax.jit(lambda x, lp, grouped=grouped: _moe_routed(
+                x, lp, cfg, grouped=grouped))
+            sec, outs[grouped] = timed(fn, x, lp)
+            row(f"routed experts, {n} tokens, "
+                f"{'grouped (sorted pairs, a tile loop)' if grouped else 'masked dense'}",
+                sec)
+        err = float(jnp.abs(outs[True].astype(jnp.float32)
+                            - outs[False].astype(jnp.float32)).max())
+        print(f"  grouped against masked at {n} tokens: max abs {err:.4f}")
+    os.makedirs(os.path.dirname(args.out), exist_ok=True)
+    with open(args.out, "w") as f:
+        json.dump(report, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

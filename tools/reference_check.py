@@ -59,6 +59,24 @@ reading and the nearest fault's:
 Not planted: a window one token short or long (1 key of 1024 under nearly
 uniform attention moves nothing any metric here could see).
 
+A configuration with linear-attention layers (`gqa_layers`; PR 31: Solar-
+Open2 as one of eight chips that share each layer) runs the same script with
+other rows and other faults. Slot 1 first serves another tenant (600
+tokens), so that it holds a state; the short row (default 2500 tokens, past
+every prefill bucket) is then admitted into that slot half way through the
+long row's (12000 tokens) chunked prefill, a chunk of its own after each of
+the long row's. Faults, each given to the reference: the slot's state NOT
+RESET at admission (the reference starts the short row's linear layers from
+what the first tenant left), the decay gate off, beta not doubled, the GQA
+layers' output gate off, and the expert share offset by the experts held
+(another chip's experts); a third row of 96 tokens goes into another slot
+that served a tenant, because over 2500 tokens a stale state has decayed
+away and only a brief row can tell. Readings (my chip run, PR 31), long /
+short row: sound median 0.061 / 0.077, largest 0.161 / 0.129, top-1 0.89 /
+0.77; decay gate off 1.13 / 1.11, beta not doubled 0.465 / 0.476, GQA gate
+off 1.00 / 1.03, share offset 0.563 / 0.609: the limits above hold for this
+configuration too (PERF.md section 6).
+
 `--cpu-rehearsal` runs the same script on the configuration's tiny
 `rehearsal` geometry on the CPU: it proves the script, and that the served
 path is the reference's mathematics (float32, tight), never a speed.
@@ -91,8 +109,10 @@ def say(msg: str) -> None:
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--config", required=True)
-    ap.add_argument("--long", type=int, default=6000)
-    ap.add_argument("--short", type=int, default=300)
+    ap.add_argument("--long", type=int, default=None,
+                    help="default 6000; 12000 with linear-attention layers")
+    ap.add_argument("--short", type=int, default=None,
+                    help="default 300; 2500 with linear-attention layers")
     ap.add_argument("--steps", type=int, default=64)
     ap.add_argument("--seed", type=int, default=27)
     ap.add_argument("--cpu-rehearsal", action="store_true")
@@ -112,12 +132,17 @@ def main() -> int:
     from localai_tpu.engine import Engine, EngineConfig
     from localai_tpu.engine.loader import load_config, load_params
     from localai_tpu.ops.sampling import SamplingParams, sampler_row
-    from localai_tpu.testing import reference_lm as ref
-
     with open(args.config) as f:
         doc = json.load(f)
     hf = {k: v for k, v in doc.items() if k not in NOTES}
     srv = dict(doc["serving"])
+    linear = bool(hf.get("linear_attn_config"))
+    if linear:
+        from localai_tpu.testing import reference_linear as ref
+    else:
+        from localai_tpu.testing import reference_lm as ref
+    args.long = args.long or (12000 if linear else 6000)
+    args.short = args.short or (2500 if linear else 300)
     if args.cpu_rehearsal:
         hf.update(doc["rehearsal"]["geometry"])
         srv.update(doc["rehearsal"]["serving"])
@@ -125,6 +150,8 @@ def main() -> int:
         srv["prefill_chunk"] = 64
         srv["prefill_buckets"] = [64]
         args.long, args.short, args.steps = 400, 40, 16
+        if linear:          # the short row too goes through chunks
+            args.short = 150
     elif jax.default_backend() != "tpu":
         print("no TPU here: run it through the chip tool, or rehearse with "
               "--cpu-rehearsal", file=sys.stderr)
@@ -154,10 +181,18 @@ def main() -> int:
     vocab = cfg.vocab_size
     rows = {0: list(rng.integers(8, vocab, size=args.long)),
             1: list(rng.integers(8, vocab, size=args.short))}
-    bucket = min(b for b in srv["prefill_buckets"] if b >= args.short)
+    if linear:
+        # a third, brief row into a slot that served a tenant before: over
+        # 2500 tokens a stale state has decayed away, over 96 it has not
+        rows[2] = list(rng.integers(8, vocab,
+                                    size=30 if args.cpu_rehearsal else 96))
+    live = sorted(rows)
+    prompt_len = {r: len(ids) for r, ids in rows.items()}
+    fits = [b for b in srv["prefill_buckets"] if b >= args.short]
+    bucket = min(fits) if fits else None
     greedy = sampler_row(SamplingParams(temperature=0.0), vocab,
                          fallback_seed=1, include_bias=False)
-    served: dict = {0: {}, 1: {}}      # row -> position -> logits [V]
+    served: dict = {r: {} for r in rows}   # row -> position -> logits [V]
     B = srv["parallel"]
 
     def note(row: int):
@@ -172,40 +207,73 @@ def main() -> int:
             rows[r].append(int(tokens[r]))
             note(r)
 
-    long_ids = list(rows[0])
-    starts = list(range(0, args.long, chunk))
-    short_steps = 0
-    for n, pos in enumerate(starts):
-        part = long_ids[pos:pos + chunk]
+    def chunked(row: int, ids: list, pos: int) -> bool:
+        """One chunk of `ids` from `pos` into slot `row`; True at the last."""
+        part = ids[pos:pos + chunk]
         buf = np.zeros((1, chunk), np.int32)
         buf[0, :len(part)] = part
-        if pos + chunk >= args.long:
-            eng._dev_extend_final(buf, pos, len(part), 0, greedy, None)
+        if pos + chunk >= len(ids):
+            eng._dev_extend_final(buf, pos, len(part), row, greedy, None)
+            return True
+        eng._dev_extend_mid(buf, pos, row)
+        return False
+
+    first_tenant = None
+    if linear:
+        # slot 1 serves another tenant first, so that it holds a state the
+        # short row's admission has to reset
+        first_tenant = [int(t) for t in rng.integers(8, vocab, size=600
+                                                     if not args.cpu_rehearsal
+                                                     else 100)]
+        for slot in (1, 2):
+            for pos in range(0, len(first_tenant), chunk):
+                chunked(slot, first_tenant, pos)
+    long_ids, short_ids = list(rows[0]), list(rows[1])
+    starts = list(range(0, args.long, chunk))
+    short_steps, short_pos = 0, None
+    for n, pos in enumerate(starts):
+        if chunked(0, long_ids, pos):
             note(0)
-        else:
-            eng._dev_extend_mid(buf, pos, 0)
-        if n == len(starts) // 2:
+        if n == len(starts) // 2 and bucket:
             ids = np.zeros((1, bucket), np.int32)
             ids[0, :args.short] = rows[1]
             eng._dev_admit(ids, args.short, 1, greedy, None)
             note(1)
-        elif n > len(starts) // 2 and short_steps < args.steps // 4:
+        elif n == len(starts) // 2:
+            short_pos = 0       # too long for a bucket: chunks of its own
+        elif short_pos is None and n > len(starts) // 2 \
+                and short_steps < args.steps // 4:
             decode([1])                 # beside the long row's prefill
             short_steps += 1
+        if short_pos is not None and short_pos >= 0:
+            if chunked(1, short_ids, short_pos):
+                note(1)
+                short_pos = None
+            else:
+                short_pos += chunk
+    while short_pos is not None:        # the long row ended first
+        if chunked(1, short_ids, short_pos):
+            note(1)
+            short_pos = None
+        else:
+            short_pos += chunk
+    if linear:
+        chunked(2, [int(t) for t in rows[2]], 0)
+        note(2)
     # half of the steps one program a step, the other half inside the fused
     # loop (the program the served path decodes with), GROUP steps a
     # dispatch: the loop hands back its tokens and the last step's logits
     singles = args.steps // 2
     for _ in range(singles):
-        decode([0, 1])
+        decode(live)
     for _ in range((args.steps - singles) // GROUP):
         active = np.zeros((B,), bool)
-        active[:2] = True
+        active[:len(live)] = True
         remaining = np.zeros((B,), np.int32)
-        remaining[:2] = GROUP
+        remaining[:len(live)] = GROUP
         toks, _, n_out, _ = eng._dev_decode_loop(
             active, remaining, np.zeros((B,), bool)).wait()
-        for r in (0, 1):
+        for r in live:
             assert int(n_out[r]) == GROUP, (r, n_out)
             rows[r].extend(int(t) for t in np.asarray(toks)[:GROUP, r])
             note(r)
@@ -223,20 +291,53 @@ def main() -> int:
         setattr(eng, name, None)
     del eng
     rcfg = ref.RefConfig.from_hf(hf)
-    rparams = ref.from_served(params)
-    n_prompt = {0: args.long, 1: args.short}
+    # the embedding goes to the host and a comparison takes the rows its
+    # tokens name (a float32 copy of a 196608-row embedding beside the
+    # float32 head does not fit the chip); the served copies of both are
+    # freed once the reference has its own
+    embed_host = np.asarray(params["embed"])
+    params = dict(params, embed=params["embed"][:1])
+    lm_head = params["lm_head"]
+    if linear:
+        # the head stays int8 and is made float32 a slice of the vocabulary
+        # at a time (head_of): 196608 columns in float32 are 3.2 GB
+        params["lm_head"] = jnp.zeros((cfg.hidden_size, 1), jnp.float32)
+    rparams = (ref.from_served(params, rcfg.layer_types) if linear
+               else ref.from_served(params))
+    params.pop("lm_head", None)
 
-    def compare(row: int, cfg, precision: str = "highest") -> dict:
+    def head_of(cfg_v, hidden, precision):
+        if not linear:
+            return ref.head(rparams, cfg_v, hidden, precision=precision)
+        with jax.default_matmul_precision(precision):
+            if not isinstance(lm_head, dict):
+                return hidden @ jnp.asarray(lm_head, jnp.float32)
+            q, scale = lm_head["q"], lm_head["s"]
+            return jnp.concatenate(
+                [hidden @ (q[:, i:i + 24576].astype(jnp.float32)
+                           * scale[:, i:i + 24576])
+                 for i in range(0, q.shape[1], 24576)], axis=1)
+
+    def hidden_of(ids, cfg, **kw):
+        uniq, inv = np.unique(np.asarray(ids), return_inverse=True)
+        return ref.hidden_states(
+            dict(rparams, embed=embed_host[uniq].astype(np.float32)), cfg,
+            inv, block=128 if linear else 512, **kw)
+
+    n_prompt = prompt_len
+
+    def compare(row: int, cfg, precision: str = "highest",
+                carried=None) -> dict:
         """The served row against one computation of the reference: the
         logits where the served path showed them, and at every position a
         token was picked from, whether the reference would have picked it."""
         t = time.monotonic()
         ids = np.asarray(rows[row])
-        hidden = ref.hidden_states(rparams, cfg, ids, block=512,
-                                   precision=precision)
+        kw = {"carried": carried} if carried is not None else {}
+        hidden = hidden_of(ids, cfg, precision=precision, **kw)
         picked_at = np.arange(n_prompt[row] - 1, len(ids) - 1)
-        want = np.asarray(ref.head(rparams, cfg, hidden[
-            jnp.asarray(picked_at)], precision=precision), np.float32)
+        want = np.asarray(head_of(cfg, hidden[jnp.asarray(picked_at)],
+                                  precision), np.float32)
         order = np.argsort(-want, axis=1)
         picked = ids[picked_at + 1]
         rank = np.array([int(np.nonzero(order[i] == picked[i])[0][0])
@@ -263,13 +364,32 @@ def main() -> int:
                 "reference_seconds": time.monotonic() - t}
 
     in_loop = (args.steps - singles) // GROUP * GROUP
-    window = rcfg.sliding_window
+    window = getattr(rcfg, "sliding_window", None)
     # what each planted fault reads like, and one control: the reference is
     # given the fault (the served path stays as it is), so a reading is the
     # distance a served path WITH that fault would show, to first order
-    variants = {"sound": (rcfg, "highest", (0, 1))}
+    variants = {"sound": (rcfg, "highest", tuple(live))}
+    stale = None
     if not args.sound_only:
         variants["reference_in_bfloat16"] = (rcfg, "bfloat16", (0, 1))
+        if linear:
+            # what the first tenant left in slot 1's linear layers
+            stale = {}
+            hidden_of(first_tenant, rcfg, left=stale)
+            held = rcfg.num_experts
+            variants.update({
+                "fault_state_not_reset": (rcfg, "highest", (1, 2)),
+                "fault_decay_gate_off": (dataclasses.replace(
+                    rcfg, linear_decay=False), "highest", (0, 1)),
+                "fault_beta_not_doubled": (dataclasses.replace(
+                    rcfg, linear_beta_scale=1.0), "highest", (0, 1)),
+                "fault_gqa_gate_off": (dataclasses.replace(
+                    rcfg, attn_gate=False), "highest", (0, 1)),
+                "fault_share_offset": (dataclasses.replace(
+                    rcfg, first_expert=rcfg.first_expert + held
+                    if rcfg.first_expert == 0
+                    else rcfg.first_expert - held), "highest", (0, 1)),
+            })
         if window and ref.WINDOW in rcfg.layer_types:
             swapped = dict(rcfg.rope)
             swapped[ref.WINDOW] = rcfg.rope[ref.FULL]
@@ -289,7 +409,8 @@ def main() -> int:
     for name, (cfg_v, precision, which) in variants.items():
         report["readings"][name] = {}
         for row in which:
-            r = compare(row, cfg_v, precision)
+            r = compare(row, cfg_v, precision,
+                        stale if name == "fault_state_not_reset" else None)
             report["readings"][name][str(row)] = r
             say(f"{name} row {row}: {json.dumps(r)}")
     ok = all(r["rel_median"] <= MEDIAN_REL and r["rel_max"] <= WORST_REL

@@ -44,14 +44,17 @@ from benchmark.harness.tracefacts import (  # noqa: E402
 )
 
 PHASE = re.compile(r"^engine\.(dispatch|admit|emit|kv|device|idle)$")
-SCOPES = ("expert_einsums", "router", "experts", "attention", "mlp",
-          "lm_head", "sampling", "cache_update")
+SCOPES = ("expert_einsums", "router", "dispatch", "shared", "experts",
+          "attention", "mlp", "lm_head", "sampling", "cache_update")
 # Pallas kernels are traced under no scope (a scope would change their
 # compile-cache key, models/llama.py): they are told by their own jit name
-KERNELS = {"ragged_decode": "attention", "ragged_paged_attention": "attention",
+KERNELS = {"kda_decode": "attention/linear/kda_decode",
+           "ragged_decode": "attention", "ragged_paged_attention": "attention",
            "flash_prefill": "attention", "paged_scatter_append": "cache_update",
            "ragged_scatter_append": "cache_update"}
-LAYER_KINDS = ("window", "full")
+LAYER_KINDS = ("window", "full", "linear")
+# what a linear layer's mixer is made of (models/kv.py StateKV)
+LINEAR_PARTS = ("conv", "kda_chunk", "kda_decode")
 TOP = 10
 
 
@@ -179,11 +182,18 @@ def scope_of(name: str, stats: dict) -> str:
             if scope in parts:
                 if scope in ("router", "expert_einsums"):
                     return "experts/" + scope
+                if scope in ("dispatch", "shared"):
+                    # the routed expert layer's own (models/llama.py
+                    # _moe_routed); the bare words mean nothing elsewhere
+                    if "experts" in parts:
+                        return "experts/" + scope
+                    continue
                 # a model with window and full layers traces its attention
                 # under attention/<kind> (models/llama.py _attn_scope)
                 kind = parts[parts.index(scope) + 1:][:1]
                 if scope == "attention" and kind and kind[0] in LAYER_KINDS:
-                    return "attention/" + kind[0]
+                    inner = [p for p in parts if p in LINEAR_PARTS]
+                    return "/".join(["attention", kind[0]] + inner[:1])
                 return scope
         for part in parts:
             if part.startswith("jit("):
