@@ -45,6 +45,7 @@ from localai_tpu.models.llama import (
     LlamaConfig,
     cache_shift,
     decode_step,
+    expert_form,
     extend,
     init_kv_cache,
     prefill,
@@ -340,6 +341,13 @@ class _Slot:
                                      # soup's per-tenant dispatch attribution)
 
 
+# The engine thread's one tick may take this long (a cold compile of the
+# largest program is a few minutes); past it the device is taken for hung
+# (a kernel that never returns: PERF.md section 7.5, PR 32) and the engine
+# fails what it holds, with a message, instead of waiting for ever
+TICK_LIMIT_S = 900.0
+
+
 class _AsyncFetch:
     """Async, double-buffered device→host result streaming (PRESERVE-style
     overlap): the D2H copy of a dispatch's small outputs (tokens, logprobs,
@@ -587,6 +595,7 @@ class Engine:
         self._wake = threading.Event()
         self._running = False
         self._dead = False
+        self._tick_began = None
         # the last step failure the loop recovered from ("" = none): a
         # caller that only sees a request end "error" can name the cause
         self.last_error = ""
@@ -624,6 +633,12 @@ class Engine:
             "decode_dispatches_consumed": 0,
             "decode_steps_consumed": 0,
             "requests_admitted": 0,
+            # tokens x MoE layers, by the form the expert layer takes for
+            # the call's shape (models/llama.expert_form, what _mlp itself
+            # evaluates): a prompt's tokens at dispatch, a decode
+            # dispatch's at consume. 0 for a model without experts
+            "expert_tokens__routed": 0,
+            "expert_tokens__dense": 0,
             # cumulative ms the engine thread spent BLOCKED waiting for a
             # dispatch's results to land on the host (the async-fetch wait,
             # not the detok/stream fan-out) — per token this is the number
@@ -1524,6 +1539,7 @@ class Engine:
     def _dev_admit_many(self, ids, lens, slots, rows, counts_rows,
                         inject=None):
         self.metrics["admit_dispatches"] += 1
+        self._credit_experts(np.size(ids), np.sum(lens))
         self._bcast("admit_many", ids=ids, lens=lens, slots=slots,
                     rows={k: np.asarray(v) for k, v in rows.items()},
                     counts_rows=counts_rows, inject=self._inj_msg(inject))
@@ -1563,6 +1579,7 @@ class Engine:
         return (msg["extra"], msg["mask"])
 
     def _dev_extend_mid(self, buf, pos, idx, inject=None):
+        self._credit_experts(np.size(buf), np.size(buf))
         self._bcast("extend_mid", buf=buf, pos=pos, idx=idx,
                     inject=self._inj_msg(inject))
         with activate_mesh(self.mesh):
@@ -1573,6 +1590,7 @@ class Engine:
 
     def _dev_extend_final(self, buf, pos, nvalid, idx, row, counts_row,
                           inject=None):
+        self._credit_experts(np.size(buf), nvalid)
         self._bcast("extend_final", buf=buf, pos=pos, nvalid=nvalid, idx=idx,
                     row={k: np.asarray(v) for k, v in row.items()},
                     counts_row=counts_row, inject=self._inj_msg(inject))
@@ -1703,6 +1721,7 @@ class Engine:
         self.metrics["ragged_tokens_packed"] = (
             self.metrics.get("ragged_tokens_packed", 0)
             + int(pack["packed"]))
+        self._credit_experts(self._ragged_rows, pack["packed"])
         # non-decode rows actually packed (prefill-chunk tokens): the
         # dispatch-budget tripwire credits these against the per-token
         # budget, so mixed consolidation stays exempt-by-math while
@@ -1764,6 +1783,7 @@ class Engine:
         self.metrics["ragged_tokens_packed"] = (
             self.metrics.get("ragged_tokens_packed", 0)
             + int(pack["packed"]))
+        self._credit_experts(self._ragged_rows, pack["packed"])
         n_dec = int(np.sum(pack["is_decode"]))
         self.metrics["ragged_prefill_tokens"] = (
             self.metrics.get("ragged_prefill_tokens", 0)
@@ -1867,6 +1887,7 @@ class Engine:
         self.metrics["ragged_tokens_packed"] = (
             self.metrics.get("ragged_tokens_packed", 0)
             + int(pack["packed"]))
+        self._credit_experts(self._ragged_rows, pack["packed"])
         self.metrics["budget_utilization"] = (
             self.metrics["ragged_tokens_packed"]
             / max(self.metrics["ragged_dispatches"] * self._ragged_rows, 1))
@@ -3049,6 +3070,15 @@ class Engine:
         m["host_sync_wait_ms"] += m["engine_wait_ms__device"] - waited
         return out
 
+    def _credit_experts(self, call_tokens: int, tokens: int):
+        """`tokens` real tokens went through every MoE layer in a call of
+        `call_tokens` tokens (batch x sequence, padding and all): credit
+        them to the form that shape takes. Host arithmetic, once a call."""
+        if self.cfg.num_experts:
+            form = expert_form(self.cfg, call_tokens, self.mesh)
+            self.metrics[f"expert_tokens__{form}"] += (
+                int(tokens) * self.cfg.num_layers)
+
     def _credit_consumed(self, steps: int, entries=(), n_out=None):
         """One dispatch's results are on the host: credit it and the steps
         the device ran in it, together. For a model with window and full
@@ -3060,6 +3090,11 @@ class Engine:
         at dispatch)."""
         self.metrics["decode_dispatches_consumed"] += 1
         self.metrics["decode_steps_consumed"] += steps
+        if self.cfg.num_experts:
+            # a decode step is max_slots rows of one token
+            self._credit_experts(self.ec.max_slots, sum(
+                steps if n_out is None else int(n_out[i])
+                for i, _ in entries))
         if not self._mixed:
             return
         if self._linear:
@@ -4743,8 +4778,38 @@ class Engine:
             return
         self._running = True
         self._dead = False
+        self._tick_began = None
         self._thread = threading.Thread(target=self._loop, daemon=True)
         self._thread.start()
+        threading.Thread(target=self._watch, args=(self._thread,),
+                         daemon=True).start()
+
+    def _watch(self, thread):
+        """Beside the engine thread: a tick that has not ended after
+        TICK_LIMIT_S is a device that does not answer (the thread sits in a
+        transfer or a dispatch and cannot be told). Say so, with every
+        thread's stack, end the engine and fail what it holds: LoadModel's
+        warm requests and the clients get a terminal output and a
+        last_error, not silence. The engine thread is left where it is; if
+        it ever returns it finds the engine ended."""
+        while self._running and self._thread is thread:
+            began = self._tick_began
+            if began is not None and time.monotonic() - began > TICK_LIMIT_S:
+                import faulthandler
+                import sys
+
+                self.last_error = (
+                    f"engine tick has not ended after {TICK_LIMIT_S:.0f} s: "
+                    "the device does not answer (a program that hangs); "
+                    "the engine is ended")
+                print(f"FATAL: {self.last_error}", file=sys.stderr,
+                      flush=True)
+                faulthandler.dump_traceback(file=sys.stderr)
+                self._running = False
+                self._dead = True
+                self._fail_active("error")
+                return
+            time.sleep(min(5.0, TICK_LIMIT_S / 4))
 
     def stop(self):
         was_serving = self._thread is not None
@@ -4998,7 +5063,9 @@ class Engine:
         restarts = 0
         while self._running:
             try:
+                self._tick_began = time.monotonic()
                 busy = self.step()
+                self._tick_began = None
             except Exception as e:  # device OOM, compile failure, ...
                 import traceback
 

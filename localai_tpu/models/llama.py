@@ -530,17 +530,52 @@ def _lm_head(x32, params):
     return qmatmul(x32, head)
 
 
+ROUTED, DENSE = "routed", "dense"
+# A call of this many tokens or more takes the routed form (where the model
+# leaves a choice): the least prefill bucket served. PERF.md section 6, PR 32
+ROUTED_FROM_TOKENS = 64
+
+
+def expert_form(cfg: LlamaConfig, tokens: int, mesh=None,
+                in_stack: bool = True) -> str:
+    """Which form a call of `tokens` tokens (batch x sequence) takes through
+    an expert layer, from what the call can observe: ROUTED (_moe_routed:
+    only the pairs a token chose, a tile of one expert at a time) or DENSE
+    (_moe_mlp: every expert on every token under a mask). _scan_layers and
+    _mlp decide by it, and the engine counts expert tokens by it.
+
+    A model with a shared expert or a share of its experts is written as
+    the routed layer only. A mesh whose `model` axis shards the experts
+    keeps the dense form: its masked combine is what GSPMD turns into the
+    all-reduce, and a (layer, expert) slice out of a sharded stack is not.
+    in_stack: the experts reach the layer in their stacks, as _scan_layers
+    hands them to a forward over a cache (every program the engine
+    serves). A forward without a cache (forward_train, the embeddings, a
+    pipeline stage) keeps the dense form: it is what train.py takes a
+    gradient through, and the tile loop's dynamic count and the kernel
+    have none. Otherwise the shape decides: a decode step's rows read
+    every expert's weights whichever form runs, and the dense form does it
+    without the tiles; a prompt's tokens would compute E / k times what
+    they chose."""
+    if cfg.shared_expert_width or (
+            cfg.router_experts and cfg.router_experts != cfg.num_experts):
+        return ROUTED
+    if not in_stack or (mesh is not None and not cfg.stacked_by_kind
+                        and dict(mesh.shape).get("model", 1) > 1):
+        return DENSE
+    return ROUTED if tokens >= ROUTED_FROM_TOKENS else DENSE
+
+
 def _mlp(x, lp, cfg=None, spec_prefix=None):
     """Gated MLP. `spec_prefix` (optional tuple, e.g. ('data', None)) is the
     leading batch/seq sharding of the activation: when given, gate/up outputs
     are constrained ffn-parallel (…, 'model') and the down projection back to
     (…, None) — the hints that keep TP weights sharded through the scan."""
-    if "ws_gate" in lp or ("moe_gate" in lp and lp["moe_gate"].shape[-1]
-                           != _leading(lp["moe_w1"]).shape[-3]):
-        # a shared expert, or fewer experts held than the router is wide
-        return _moe_routed(x, lp, cfg)
     if "moe_gate" in lp:
-        return _moe_mlp(x, lp, cfg.experts_per_tok if cfg else 2)
+        if expert_form(cfg, x.shape[0] * x.shape[1], current_mesh(),
+                       isinstance(lp["moe_w1"], _InStack)) == ROUTED:
+            return _moe_routed(x, lp, cfg)
+        return _moe_mlp(x, lp, cfg.experts_per_tok)
     up_spec = down_spec = None
     if spec_prefix is not None:
         up_spec = P(*spec_prefix, "model")
@@ -560,8 +595,11 @@ def _moe_mlp(x, lp, k: int):
     Dense dispatch: every expert runs on every token and the top-k mask
     zeroes the rest — einsum-shaped for the MXU and for GSPMD expert
     parallelism (experts sharded on the `model` mesh axis; XLA turns the
-    masked combine into an all-reduce). Top-k gather/scatter dispatch is a
-    later optimization for large-E prefill."""
+    masked combine into an all-reduce). Served where expert_form says
+    DENSE: a decode step's rows of a model that holds every expert (all the
+    weights are read whichever form runs), any call under a mesh that
+    shards the experts, and a forward without a cache (forward_train: the
+    form with a gradient). A served prompt's tokens take _moe_routed."""
     from localai_tpu.ops.quant import dequantize, is_quantized
 
     def dq(p):
@@ -616,91 +654,143 @@ def _leading(p):
     return p["q"] if isinstance(p, dict) else p
 
 
-def _grouped_experts(xt, token, rank, sizes, up, gate, down, tm: int):
-    """SwiGLU experts over P token-expert pairs SORTED BY EXPERT: the first
-    sizes[e] sorted rows are expert 0's, the next expert 1's, ...; rows past
-    sum(sizes) belong to no expert here. token [P]: the row of xt [N, h] a
-    sorted row computes on; rank [P]: where each pair (in its own order)
-    stands among the sorted rows. up, gate, down: _InStack (the stacks the
-    weights lie in, int8 or not, and the layer's index). Returns [P, h] in
-    xt's dtype, in PAIR order, zero for a pair of no expert here.
+def _grouped_experts(xt, eid, weight, up, gate, down, tm: int):
+    """SwiGLU experts over the token-expert pairs of xt [N, h]: eid [N, k]
+    is the expert (among those held) each of a token's k pairs chose, or
+    the number held for a pair of no expert here; weight [N, k] float32 is
+    the pair's share of its token. up, gate, down: _InStack (the stacks the
+    weights lie in, int8 or not, and the layer's index). Returns the
+    tokens' weighted sums [N, h] float32.
 
-    The rows are laid out in TILES of `tm` rows, each group padded to whole
-    tiles, so that a tile has one expert: a loop over the tiles IN USE (a
-    dynamic count: sum of ceil(sizes / tm), at most `tiles`) slices that
-    expert's three matrices out of the stacks where they lie and runs the
-    three products of the tile. Every pair is computed, none is dropped; the
-    static worst case is every group ending one row into a tile. An expert
-    no pair chose is not read at all. (jax.lax.ragged_dot lowers to a dense
-    product over every group on this chip's compiler: 40 times the
-    operations, PERF.md section 6.)"""
-    p, h = token.shape[0], xt.shape[-1]
-    held = sizes.shape[0]
-    tiles = -(-(p + held * (tm - 1)) // tm)
+    Each expert's pairs get rows of their own in a layout of TILES of `tm`
+    rows, a group padded to whole tiles, so that a tile has one expert.
+    Over the tiles IN USE (a dynamic count: sum of ceil(group / tm), at
+    most `tiles`), that expert's three matrices are sliced out of the
+    stacks where they lie and multiplied with the tile: on a TPU by the
+    grouped product kernel (ops/pallas/grouped_matmul.py), else by a loop
+    in XLA. Every pair is computed, none is dropped; the static worst case
+    is every group ending one row into a tile. An expert no pair chose is
+    not read at all. (jax.lax.ragged_dot lowers to a dense product over
+    every group on this chip's compiler: 40 times the operations, PERF.md
+    section 6.)
+
+    No sort and no gather: a pair's row is its group's first row plus its
+    place in the group (a running count), and the rows are filled, and a
+    token's k results summed, by products with one 0/1 matrix on the
+    matrix unit (a gather of 12 k rows costs this chip 1 ms, the two
+    products 0.3; they cost tokens x rows, PERF.md section 6, PR 32). The
+    pair's weight is applied in float32, to the float32 activation of its
+    row before the down product (which is linear in it), so the way back
+    only adds. A 0/1 product would hand a NaN or an inf of ANY row to
+    every row (0 x NaN): a token or a result row that is not finite is
+    kept out of the products and its token given back as NaN, alone, as a
+    gather leaves it."""
+    n, k = eid.shape
+    h = xt.shape[-1]
+    held = _leading(up).shape[-3]
+    tiles = -(-(n * k + held * (tm - 1)) // tm)
+    rows = tiles * tm
+    # float32 activations (tests, a float32 load): the 0/1 products exact
+    exact = jax.lax.Precision.HIGHEST if xt.dtype == jnp.float32 else None
     with jax.named_scope("dispatch"):
+        chose = (eid.reshape(-1, 1) == jnp.arange(held)).astype(jnp.int32)
+        sizes = chose.sum(0)                               # [E] pairs each
+        nth = ((jnp.cumsum(chose, 0) - 1) * chose).sum(-1)  # place in group
         per = -(-sizes // tm)                              # tiles a group
-        tile_end = jnp.cumsum(per)                         # [E]
+        tile_end = jnp.cumsum(per)
         used = tile_end[-1]
-        ends = jnp.cumsum(sizes)
-        start = ends - sizes                               # a group's 1st row
-        tile_e = jnp.minimum(jnp.searchsorted(
-            tile_end, jnp.arange(tiles), side="right"), held - 1)
-        # padded slot -> sorted row (or none)
-        slot = jnp.arange(tiles * tm)
-        e_of = tile_e[slot // tm]
-        nth = slot - (tile_end[e_of] - per[e_of]) * tm
-        ok = (nth < sizes[e_of]) & (slot // tm < used)
-        src = token[jnp.where(ok, start[e_of] + nth, 0)]
-        xp = jnp.where(ok[:, None], xt[src], 0).reshape(tiles, tm, h)
-        # pair -> sorted row -> padded slot
-        total = ends[-1]
-        rows = rank
-        e_row = jnp.minimum(jnp.searchsorted(ends, rows, side="right"),
-                            held - 1)
-        back = (tile_end[e_row] - per[e_row]) * tm + rows - start[e_row]
+        row = (chose * ((tile_end - per) * tm)).sum(-1) + nth
+        row = jnp.where(chose.any(-1), row, -1).reshape(n, k)
+        tile_e = jnp.minimum(
+            (jnp.arange(tiles)[:, None] >= tile_end).sum(-1), held - 1)
+        at = row[..., None] == jnp.arange(rows)            # [N, k, rows]
+        sel = at.any(1).astype(xt.dtype)                   # [N, rows] 0/1
+        # a row's weight (0 for a row of no pair)
+        row_w = (at * weight[..., None]).sum((0, 1)).reshape(tiles, tm, 1)
+        bad = ~jnp.isfinite(xt).all(-1)                    # [N]
+        xp = jnp.dot(sel.T, jnp.where(bad[:, None], 0, xt),
+                     precision=exact).reshape(tiles, tm, h)
 
     def product(a, w, e):
         body, scale = w.parts()
-        at = (jnp.asarray(w.layer, jnp.int32), e)
+        le = (jnp.asarray(w.layer, jnp.int32), e)
         y = a @ jax.lax.dynamic_slice(
-            body, (*at, 0, 0), (1, 1, *body.shape[2:]))[0, 0].astype(a.dtype)
+            body, (*le, 0, 0), (1, 1, *body.shape[2:]))[0, 0].astype(a.dtype)
         if scale is None:
             return y
         return y * jax.lax.dynamic_slice(
-            scale, (*at, 0), (1, 1, scale.shape[2]))[0, 0].astype(y.dtype)
+            scale, (*le, 0), (1, 1, scale.shape[2]))[0, 0].astype(y.dtype)
+
+    def weighted(u, g, w):
+        """silu(u) g under the rows' weights, in float32."""
+        return (jax.nn.silu(u.astype(jnp.float32)) * g.astype(jnp.float32)
+                * w).astype(xt.dtype)
 
     def tile(t, out):
         e = tile_e[t]
         a = jax.lax.dynamic_index_in_dim(xp, t, keepdims=False)
-        act = jax.nn.silu(product(a, up, e)) * product(a, gate, e)
+        act = weighted(product(a, up, e), product(a, gate, e),
+                       jax.lax.dynamic_index_in_dim(row_w, t, keepdims=False))
         return jax.lax.dynamic_update_index_in_dim(
             out, product(act, down, e), t, 0)
 
+    def grouped(a, w):
+        from localai_tpu.ops.pallas.grouped_matmul import grouped_matmul
+        from localai_tpu.ops.quant import is_quantized
+
+        body, scale = ((w.stack["q"], w.stack["s"]) if is_quantized(w.stack)
+                       else (w.stack, None))
+        return grouped_matmul(a, body, scale, tile_e, used, w.layer)
+
     with jax.named_scope("expert_einsums"):
-        out = jax.lax.fori_loop(0, used, tile,
-                                jnp.zeros((tiles, tm, h), xt.dtype))
+        if kv._pallas(current_mesh() is None):
+            out = grouped(weighted(grouped(xp, up), grouped(xp, gate), row_w),
+                          down)
+        else:
+            out = jax.lax.fori_loop(0, used, tile,
+                                    jnp.zeros((tiles, tm, h), xt.dtype))
     with jax.named_scope("dispatch"):
-        y = out.reshape(tiles * tm, h)[jnp.minimum(back, tiles * tm - 1)]
-        return jnp.where((rows < total)[:, None], y, 0)
+        out = out.reshape(rows, h)
+        lost = ~jnp.isfinite(out).all(-1)                  # [rows]
+        y = jnp.dot(sel, jnp.where(lost[:, None], 0, out), precision=exact,
+                    preferred_element_type=jnp.float32)    # k rows summed
+        bad |= jnp.dot(sel, lost.astype(sel.dtype),
+                       preferred_element_type=jnp.float32) > 0
+        return jnp.where(bad[:, None], jnp.nan, y)
+
+
+def _tile_rows(tokens: int, k: int, experts: int) -> int:
+    """Rows of a tile of _grouped_experts, between 8 and 128 (about where a
+    tile's products stop waiting for its expert's weights): twice the
+    pairs an expert gets if routing is even, so that most groups are one
+    tile; from 512 tokens on once that, because the way into the padded
+    rows and back costs tokens x rows and by then outweighs the second
+    tile of every other group (Mellum2 at 512 tokens: 1.21 ms a layer at
+    64 rows, 1.50 at 128; at 256 tokens 0.82 at 64, 0.89 at 32; PERF.md
+    section 6, PR 32)."""
+    even = tokens * k / experts * (1 if tokens >= 512 else 2)
+    return int(min(128, max(8, 2 ** math.ceil(math.log2(even)))))
 
 
 @jax.named_scope("experts")
 def _moe_routed(x, lp, cfg: LlamaConfig, grouped: bool = True):
-    """The expert layer of a model with a shared expert and/or a SHARE of
-    the routed experts: the layer holds experts [first, first + E) of the R
-    the router scores. The router, the top-k and the renormalisation are
+    """The routed expert layer: the layer holds experts [first, first + E)
+    of the R the router scores, all of them (first 0, E = R: a prompt's
+    tokens of any MoE model on one chip, expert_form) or a SHARE, with or
+    without a shared expert. The router, the top-k and the renormalisation are
     over all R; the layer returns the shared expert plus the chosen experts
     that are HERE, each under its weight among all k chosen. What the
     absent experts would add is left out (the other chips of an
     expert-parallel layout hold them; on one chip there is no exchange).
 
-    grouped (what is served, in prefill, extend and decode): the
-    token-expert pairs that land here, sorted by expert, as grouped
-    products over the int8 expert weights (_grouped_experts: every pair is
-    computed, the static worst case is all N k of them). Not grouped (the
-    tests' and the bench's twin): every held expert on every token under
-    the combine mask; on the chip it is slower at 512 tokens (3.7 against
-    2.3 ms a layer) and at a decode step's 32 rows (0.91 against 0.81).
+    grouped (what is served): the token-expert pairs that land here, each
+    expert's in tiles of their own, as grouped products over the int8
+    expert weights (_grouped_experts: every pair is computed, the static
+    worst case is all N k of them). Not grouped (the tests' and the
+    bench's twin): every held expert on every token under the combine
+    mask; on the chip it is slower for a share at 512 tokens (3.7 against
+    1.4 ms a layer) and at a decode step's 32 rows (0.90 against 0.48);
+    tools/moe_layer_bench.py, PERF.md section 6, PR 32.
     """
     b, s, h = x.shape
     k = cfg.experts_per_tok
@@ -720,22 +810,9 @@ def _moe_routed(x, lp, cfg: LlamaConfig, grouped: bool = True):
                                                        lp[n]), 0)
                   for n in ("moe_w1", "moe_w2", "moe_w3"))
     if grouped:
-        with jax.named_scope("dispatch"):
-            # pairs sorted by the expert they chose; those that chose an
-            # expert held elsewhere sort last, into no group
-            eid = jnp.where(here, local, held).reshape(-1)     # [N k]
-            order = jnp.argsort(eid, stable=True)
-            sizes = jnp.bincount(eid, length=held + 1)[:held].astype(
-                jnp.int32)
-        # tile: about twice the pairs an expert gets if routing is even
-        even = n * k / lp["moe_gate"].shape[-1]
-        tm = int(min(128, max(8, 2 ** math.ceil(math.log2(2 * even)))))
-        y = _grouped_experts(xt, order // k, jnp.argsort(order), sizes,
-                             w1, w3, w2, tm)                   # [N k, h]
-        with jax.named_scope("dispatch"):
-            # a pair under its weight, a token's k pairs summed
-            y = (y.astype(jnp.float32).reshape(n, k, h)
-                 * top_w[..., None]).sum(1)
+        y = _grouped_experts(
+            xt, jnp.where(here, local, held), top_w, w1, w3, w2,
+            _tile_rows(n, k, lp["moe_gate"].shape[-1]))    # [N, h] float32
     else:
         with jax.named_scope("router"):
             combine = jnp.einsum(
@@ -804,6 +881,14 @@ def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
         # pair, selected like the paged decode write
         tiers["ragged_attention"] = "xla" if tiered else paged_kernel
         tiers["ragged_kv_write"] = paged_kernel
+    if cfg.num_experts:
+        # a prompt's tokens through the experts (expert_form): the grouped
+        # product kernel on one chip, its XLA loop under a mesh, the dense
+        # einsums where the mesh shards the experts
+        routed = expert_form(cfg, ROUTED_FROM_TOKENS, mesh) == ROUTED
+        tiers["prefill_experts"] = (
+            "xla-dense" if not routed
+            else pallas if kv._pallas(mesh is None) else "xla")
     return tiers
 
 
@@ -924,45 +1009,68 @@ def _scan_layers(cfg: LlamaConfig, block, x, layers, cache):
     place in the PERIOD of kinds and the scan's body is one period,
     unrolled: compile time grows with the period, not the depth."""
     period = cfg.period
-    if period is None and cache.carried:
-        def layer(carry, xs):
-            x, k, v = carry
-            lp, i = xs
-            x, view = block(x, lp, cache.at(k, v, i), None)
-            return (x, view.k, view.v), None
+    # a call that takes the routed form leaves the experts IN their stacks
+    # (_InStack: the tile loop slices (layer, expert) in one step); handed
+    # in as xs, or sliced a layer at a time, they reach the loop as a COPY
+    # of the layer's experts (1.4 GB a layer at Mixtral's widths)
+    views = cache if period else (cache,)
+    routed = bool(cfg.num_experts) and expert_form(
+        cfg, x.shape[0] * x.shape[1], current_mesh(),
+        any(c.k is not None for c in views)) == ROUTED
 
-        (x, k, v), _ = jax.lax.scan(
-            layer, (x, cache.k, cache.v),
-            (layers, jnp.arange(cfg.num_layers)))
-        return x, (k, v)
-    if period is None:
-        def layer(x, xs):
-            lp, k, v, *cold = xs
-            x, view = block(x, lp, cache.at(k, v, cold=cold), None)
-            return x, (view.k, view.v)
-
-        return jax.lax.scan(layer, x,
-                            (layers, cache.k, cache.v, *cache.cold))
-    p = len(period)
-
-    def weights(i, j, kind):
-        if not cfg.stacked_by_kind:
-            return _layer_params(layers, i * p + j)
-        # stacks by kind: this layer's place among its kind's. The routed
-        # experts stay in their stack (_InStack): sliced a tile at a time
-        n = i * period.count(kind) + period[:j].count(kind)
-        stack = layers[kind]
+    def weights(stack, n):
+        """Layer n's weights out of a [L, ...] stack."""
+        if not routed:
+            return _layer_params(stack, n)
         lp = _layer_params({k: v for k, v in stack.items()
                             if not k.startswith("moe_w")}, n)
         lp.update({k: _InStack(v, n) for k, v in stack.items()
                    if k.startswith("moe_w")})
         return lp
 
+    if period is None:
+        experts = {k: v for k, v in layers.items()
+                   if routed and k.startswith("moe_w")}
+        rest = {k: v for k, v in layers.items() if k not in experts}
+        in_stack = lambda lp, i: {  # noqa: E731
+            **lp, **{k: _InStack(v, i) for k, v in experts.items()}}
+    if period is None and cache.carried:
+        def layer(carry, xs):
+            x, k, v = carry
+            lp, i = xs
+            x, view = block(x, in_stack(lp, i), cache.at(k, v, i), None)
+            return (x, view.k, view.v), None
+
+        (x, k, v), _ = jax.lax.scan(
+            layer, (x, cache.k, cache.v),
+            (rest, jnp.arange(cfg.num_layers)))
+        return x, (k, v)
+    if period is None:
+        def layer(x, xs):
+            lp, k, v, *cold = xs
+            if experts:
+                lp = in_stack(*lp)
+            x, view = block(x, lp, cache.at(k, v, cold=cold), None)
+            return x, (view.k, view.v)
+
+        return jax.lax.scan(
+            layer, x,
+            ((rest, jnp.arange(cfg.num_layers)) if experts else rest,
+             cache.k, cache.v, *cache.cold))
+    p = len(period)
+
+    def place(i, j, kind):
+        if not cfg.stacked_by_kind:
+            return weights(layers, i * p + j)
+        # stacks by kind: this layer's place among its kind's
+        return weights(layers[kind],
+                       i * period.count(kind) + period[:j].count(kind))
+
     def step(carry, i):
         x, ks, vs = carry
         ks, vs = list(ks), list(vs)
         for j, kind in enumerate(period):
-            x, view = block(x, weights(i, j, kind),
+            x, view = block(x, place(i, j, kind),
                             cache[j].at(ks[j], vs[j], i), kind)
             ks[j], vs[j] = view.k, view.v
         return (x, tuple(ks), tuple(vs)), None

@@ -53,6 +53,12 @@ try:
     _XLA_COMPILES = Counter(
         "localai_xla_compiles_total",
         "XLA backend compiles of the model's backend process", ["model"])
+    # tokens x MoE layers by the form the expert layer took for the call
+    # (engine expert_tokens__routed / __dense, models/llama.expert_form)
+    _EXPERT_TOKENS = Counter(
+        "localai_expert_tokens_total",
+        "Tokens x MoE layers by the expert layer's form (routed or dense)",
+        ["model", "form"])
     # streams open against the model's backend now (the gate's count)
     _STREAMS_OPEN = Gauge("localai_streams_open",
                           "Streams open against the model's backend",
@@ -976,6 +982,10 @@ class API:
                     continue
                 if key == "streams_open":
                     _STREAMS_OPEN.labels(name).set(v)
+                    continue
+                if key.startswith("expert_tokens__"):
+                    _counter_sync(_EXPERT_TOKENS,
+                                  (name, key.split("__", 1)[1]), float(v))
                     continue
                 for kind in ("host", "wait"):
                     prefix = f"engine_{kind}_ms__"

@@ -293,9 +293,21 @@ def _dense(leaf):
     return jnp.asarray(leaf, jnp.float32)
 
 
+class _Experts:
+    """A layer's expert matrices [E, in, out], one expert made float32 when
+    it is indexed (a layer's experts whole are 5.6 GB of float32 at
+    Mixtral's widths, beside the served engine)."""
+
+    def __init__(self, leaf):
+        self._leaf = leaf
+
+    def __getitem__(self, e: int):
+        return _dense(jax.tree_util.tree_map(lambda a: a[e], self._leaf))
+
+
 class _Layers:
     """Layer i's weights, made float32 when asked for (one layer of a large
-    model at a time)."""
+    model at a time, and of its experts one at a time)."""
 
     _NAMES = {"moe_gate": "router", "moe_w1": "w1", "moe_w2": "w2",
               "moe_w3": "w3"}
@@ -305,7 +317,8 @@ class _Layers:
 
     def __getitem__(self, i: int) -> dict:
         pick = jax.tree_util.tree_map(lambda a: a[i], self._stacked)
-        return {self._NAMES.get(k, k): _dense(v) for k, v in pick.items()}
+        return {self._NAMES.get(k, k): _Experts(v) if k.startswith("moe_w")
+                else _dense(v) for k, v in pick.items()}
 
 
 def from_served(params: dict) -> dict:

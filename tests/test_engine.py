@@ -539,3 +539,40 @@ def test_engine_self_restart_after_fatal_step(loaded):
             eng.submit(GenRequest([1, 2, 3], SamplingParams(), max_tokens=2))
     finally:
         eng.stop()
+
+
+def test_a_tick_that_never_ends_fails_its_requests_with_a_message(
+        loaded, monkeypatch):
+    """The device does not answer (a program that hangs: the engine thread
+    sits in its tick for ever): past the limit the engine says so, ends, and
+    the request gets a terminal output instead of silence."""
+    import threading
+
+    from localai_tpu.engine import engine as engine_mod
+
+    cfg, params, tok = loaded
+    monkeypatch.setattr(engine_mod, "TICK_LIMIT_S", 0.3)
+    eng = Engine(cfg, params, tok, EngineConfig(
+        max_slots=2, max_context=64, prefill_buckets=(16,),
+        prefill_chunk=16))
+    hung, release = threading.Event(), threading.Event()
+
+    def step():
+        hung.set()
+        release.wait(30)
+        return False
+
+    monkeypatch.setattr(eng, "step", step)
+    eng.start()
+    try:
+        assert hung.wait(10)
+        _, q = eng.submit(GenRequest([1, 2, 3], SamplingParams(
+            temperature=0.0), max_tokens=4, ignore_eos=True))
+        o = q.get(timeout=10)
+        assert o.finished and o.finish_reason == "error"
+        assert "has not ended after" in eng.last_error
+        with pytest.raises(RuntimeError, match="terminated"):
+            eng.submit(GenRequest([1, 2, 3], SamplingParams(), max_tokens=2))
+    finally:
+        release.set()
+        eng.stop()

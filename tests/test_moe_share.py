@@ -142,7 +142,7 @@ def test_extend_has_no_dense_dispatch_and_no_state_sized_scan_operand():
     # the dense dispatch's [tokens, experts held, expert width] is not there
     assert (tokens, HELD, WIDTH) not in values
     assert (1, tokens, HELD, WIDTH) not in values
-    # the routed one is, a tile of 8 sorted pairs at a time
+    # the routed one is, a tile of 8 of one expert's pairs at a time
     assert (8, WIDTH) in values
     # no scan takes or gives a state, or a layer's stack of states
     state = (2, 16, 16)

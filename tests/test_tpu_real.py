@@ -454,6 +454,57 @@ def test_kda_chunk_at_the_cells_shape():
     _close(s, want_s, tol=1e-3)
 
 
+# the routed expert layer at the cells' shapes: (hidden, experts, expert
+# width, top-k, [batch, sequence]): a chunk of Mixtral's and of Mellum2's,
+# and _admit_many's four prompts at once
+ROUTED_SHAPES = {
+    "mixtral-8x7b, a 512-token chunk": (4096, 8, 14336, 2, (1, 512)),
+    "mixtral-8x7b, four 512-token prompts": (4096, 8, 14336, 2, (4, 512)),
+    "mellum2-12b-a2.5b, a 512-token chunk": (2304, 64, 896, 8, (1, 512)),
+    "mellum2-12b-a2.5b, a 64-token chunk": (2304, 64, 896, 8, (1, 64)),
+}
+
+
+@pytest.mark.parametrize("shape", list(ROUTED_SHAPES))
+def test_routed_experts_at_the_cells_shapes(shape):
+    """models/llama._moe_routed (the grouped product kernel over int8
+    experts in a two-layer stack, bf16 activations, as served) against the
+    dense form _moe_mlp, the layer these models ran before."""
+    from localai_tpu.models.llama import (
+        LlamaConfig, _InStack, _moe_mlp, _moe_routed,
+    )
+
+    hidden, experts, width, k, (b, s) = ROUTED_SHAPES[shape]
+    cfg = LlamaConfig(hidden_size=hidden, num_experts=experts,
+                      experts_per_tok=k, moe_intermediate_size=width)
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    qw = lambda kk, shape: {  # noqa: E731
+        "q": jax.random.randint(kk, shape, -127, 128, jnp.int8),
+        "s": jnp.full(shape[:-2] + (1, shape[-1]), shape[-2] ** -0.5 / 73,
+                      jnp.float32)}
+    gate = jax.random.normal(ks[0], (hidden, experts)) * 0.02
+    stacks = {"moe_w1": qw(ks[1], (2, experts, hidden, width)),
+              "moe_w3": qw(ks[2], (2, experts, hidden, width)),
+              "moe_w2": qw(ks[3], (2, experts, width, hidden))}
+    x = jax.random.normal(ks[4], (b, s, hidden), jnp.bfloat16)
+
+    def routed(x, gate, stacks):
+        return _moe_routed(x, {"moe_gate": gate, **{
+            n: _InStack(w, 1) for n, w in stacks.items()}}, cfg)
+
+    def dense(x, gate, stacks):
+        return _moe_mlp(x, {"moe_gate": gate, **jax.tree_util.tree_map(
+            lambda a: a[1], stacks)}, k)
+
+    got = jax.jit(routed)(x, gate, stacks).astype(jnp.float32)
+    want = jax.jit(dense)(x, gate, stacks).astype(jnp.float32)
+    assert bool(jnp.isfinite(got).all())
+    # bf16 activations either way; the two sum a token's experts in
+    # different orders
+    assert float(jnp.abs(got - want).max()) < 0.03 * float(
+        jnp.abs(want).max())
+
+
 # ------------------------------------------------------------- engine
 
 def _tiny_cfg(H, KVH, D):

@@ -1,8 +1,7 @@
 #!/usr/bin/env python3
 """The kernels a linear-attention layer adds, alone, on the chip, at the
 cell's shapes (solar-open2-250b-ep8-d8: 64 heads x 128 x 128 float32 state,
-32 slots, 512-token chunks; 40 experts of 4096 x 1280 int8 held, top-8 of
-320), each against its share of the roofline
+32 slots, 512-token chunks), each against its share of the roofline
 (benchmark/harness/roofline_kda.py, benchmark/peaks/):
 
     python tools/kda_kernel_bench.py [--seed 31]
@@ -10,10 +9,9 @@ cell's shapes (solar-open2-250b-ep8-d8: 64 heads x 128 x 128 float32 state,
 - `kda_decode` (ops/pallas/kda.py) over a stack of two layers: every row
   live, the cell's mix (`--live` rows of 32 live), and its XLA twin;
 - `kda_chunk` (ops/kda.py, the XLA form) over one 512-token chunk of one
-  row, products at HIGHEST precision (as served) and at the default;
-- the routed expert layer (models/llama._moe_routed) at a decode step's 32
-  rows and at a chunk's 512 tokens: the pairs sorted by expert as grouped
-  products (served) against every held expert under a mask.
+  row, products at HIGHEST precision (as served) and at the default.
+
+The routed expert layer at this cell's shapes: tools/moe_layer_bench.py.
 
 The table goes to stdout and to chiprun_out/kda_kernel_bench.json.
 `--cpu-rehearsal` proves the script at a tiny size on the CPU and times
@@ -48,7 +46,6 @@ def main() -> int:
     import numpy as np
 
     from benchmark.harness import roofline_kda as rk
-    from localai_tpu.models.llama import LlamaConfig, _moe_routed
     from localai_tpu.ops import kda
     from localai_tpu.ops.pallas.kda import kda_decode
 
@@ -62,8 +59,6 @@ def main() -> int:
         peaks = json.load(f)
     B, H, D, T = (4, 32, 128, 128) if rehearsal else (32, 64, 128, 512)
     dk = 16 if rehearsal else D
-    hidden, held, width, routers, topk = ((64, 4, 32, 16, 4) if rehearsal
-                                          else (4096, 40, 1280, 320, 8))
     reps = 2 if rehearsal else args.reps
     ks = jax.random.split(jax.random.PRNGKey(args.seed), 12)
     unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
@@ -148,33 +143,6 @@ def main() -> int:
     row("kda_chunk (XLA, default precision)", sec, chunk_cost,
         max_err=float(jnp.abs(o_lo - ro).max()))
 
-    # ---- the routed expert layer
-    cfg = LlamaConfig(hidden_size=hidden, num_experts=held,
-                      experts_per_tok=topk, moe_intermediate_size=width,
-                      router_experts=routers, first_expert=0,
-                      shared_expert_width=0)
-    rng = jax.random.split(ks[11], 8)
-    qw = lambda kk, shape: {  # noqa: E731
-        "q": jax.random.randint(kk, shape, -127, 128, jnp.int8),
-        "s": jnp.full(shape[:-2] + (1, shape[-1]),
-                      shape[-2] ** -0.5 / 73, jnp.float32)}
-    lp = {"moe_gate": jax.random.normal(rng[0], (hidden, routers)) * 0.02,
-          "moe_w1": qw(rng[1], (held, hidden, width)),
-          "moe_w3": qw(rng[2], (held, hidden, width)),
-          "moe_w2": qw(rng[3], (held, width, hidden))}
-    for n in (B, T):
-        x = jax.random.normal(rng[4], (1, n, hidden), jnp.bfloat16)
-        outs = {}
-        for grouped in (True, False):
-            fn = jax.jit(lambda x, lp, grouped=grouped: _moe_routed(
-                x, lp, cfg, grouped=grouped))
-            sec, outs[grouped] = timed(fn, x, lp)
-            row(f"routed experts, {n} tokens, "
-                f"{'grouped (sorted pairs, a tile loop)' if grouped else 'masked dense'}",
-                sec)
-        err = float(jnp.abs(outs[True].astype(jnp.float32)
-                            - outs[False].astype(jnp.float32)).max())
-        print(f"  grouped against masked at {n} tokens: max abs {err:.4f}")
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=1)

@@ -49,6 +49,7 @@ SCOPES = ("expert_einsums", "router", "dispatch", "shared", "experts",
 # Pallas kernels are traced under no scope (a scope would change their
 # compile-cache key, models/llama.py): they are told by their own jit name
 KERNELS = {"kda_decode": "attention/linear/kda_decode",
+           "grouped_matmul": "experts/expert_einsums",
            "ragged_decode": "attention", "ragged_paged_attention": "attention",
            "flash_prefill": "attention", "paged_scatter_append": "cache_update",
            "ragged_scatter_append": "cache_update"}
