@@ -667,7 +667,12 @@ class Engine:
             # kind holds (gauges), and the context tokens ONE layer of each
             # kind attended over in the decode dispatches consumed so far
             # (_credit_consumed): their ratio is what the window saves
-            kinds = cfg.period
+            # (a leading dense layer counts with its kind, and once more
+            # under layers__leading_dense)
+            kinds = cfg.cache_kinds
+            if cfg.leading_dense_layers:
+                self.metrics["layers__leading_dense"] = (
+                    cfg.leading_dense_layers)
             for kind in set(kinds):
                 self.metrics[f"layers__{kind}"] = (
                     cfg.layer_types.count(kind))
@@ -3076,8 +3081,8 @@ class Engine:
         them to the form that shape takes. Host arithmetic, once a call."""
         if self.cfg.num_experts:
             form = expert_form(self.cfg, call_tokens, self.mesh)
-            self.metrics[f"expert_tokens__{form}"] += (
-                int(tokens) * self.cfg.num_layers)
+            self.metrics[f"expert_tokens__{form}"] += int(tokens) * (
+                self.cfg.num_layers - self.cfg.leading_dense_layers)
 
     def _credit_consumed(self, steps: int, entries=(), n_out=None):
         """One dispatch's results are on the host: credit it and the steps

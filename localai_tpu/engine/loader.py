@@ -39,10 +39,43 @@ LLAMA_FAMILY = {
     # (gqa_layers), a shared expert, routed experts of which this process
     # may hold a share (Solar-Open2)
     "SolarOpen2ForCausalLM": {"moe": True},
+    # what no key of an afmoe config.json states, because every model of
+    # the architecture has it: RMSNorm on q and k a head, an elementwise
+    # sigmoid gate on attention's output, a norm on the attention's and the
+    # MLP's output (sandwich), window layers that rotate beside full layers
+    # that do not, a bias in the router's choice (Trinity)
+    "AfmoeForCausalLM": {"moe": True, "fields": {
+        "qk_norm": True, "attn_gate": True, "post_norms": True,
+        "nope_kinds": (FULL,), "router_bias": True},
+        # a checkpoint's tensors, leaf -> name under model.layers.N. ({e}:
+        # an expert of the router's). From memory of the architecture's
+        # modelling code; no checkpoint was at hand to check them against.
+        # post_attention_layernorm is the norm on attention's OUTPUT here
+        "tensors": {
+            "attn_norm": "input_layernorm.weight",
+            "wq": "self_attn.q_proj.weight", "wk": "self_attn.k_proj.weight",
+            "wv": "self_attn.v_proj.weight", "wo": "self_attn.o_proj.weight",
+            "w_agate": "self_attn.gate_proj.weight",
+            "q_norm": "self_attn.q_norm.weight",
+            "k_norm": "self_attn.k_norm.weight",
+            "attn_post_norm": "post_attention_layernorm.weight",
+            "mlp_norm": "pre_mlp_layernorm.weight",
+            "mlp_post_norm": "post_mlp_layernorm.weight",
+            "w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
+            "w_down": "mlp.down_proj.weight",
+            "moe_gate": "mlp.router.gate.weight",
+            "moe_bias": "mlp.expert_bias",
+            "moe_w1": "mlp.experts.{e}.gate_proj.weight",
+            "moe_w3": "mlp.experts.{e}.up_proj.weight",
+            "moe_w2": "mlp.experts.{e}.down_proj.weight",
+            "ws_gate": "mlp.shared_experts.gate_proj.weight",
+            "ws_up": "mlp.shared_experts.up_proj.weight",
+            "ws_down": "mlp.shared_experts.down_proj.weight"}},
 }
 # config.json files that name no architecture
 _ARCH_OF_MODEL_TYPE = {"mellum": "MellumForCausalLM",
-                       "solar_open2": "SolarOpen2ForCausalLM"}
+                       "solar_open2": "SolarOpen2ForCausalLM",
+                       "afmoe": "AfmoeForCausalLM"}
 _LAYER_KINDS = {"full_attention": FULL, "sliding_attention": WINDOW}
 
 
@@ -68,25 +101,46 @@ def _rope_fields(rs: dict | None, theta: float, max_position: int) -> dict:
     return kw
 
 
+def _either(hf: dict, *names, default=None):
+    """The first of `names` the config has (families spell a key their own
+    way: routed_scaling_factor / route_scale)."""
+    return next((hf[n] for n in names if hf.get(n) is not None), default)
+
+
 def _expert_layer_fields(hf: dict, held: int) -> dict:
     """What an expert layer has beside its routed experts: shared experts
     (n of them are one SwiGLU n times as wide), a scale on the routed sum,
-    and the SHARE this process holds (`localai_expert_share`: the published
-    router width and the first expert held; `n_routed_experts` then counts
-    the experts held). What the layer cannot honour is refused by name."""
-    if hf.get("first_k_dense_replace"):
+    softmax or sigmoid scores, leading layers with a dense MLP in the
+    experts' place, and the SHARE this process holds
+    (`localai_expert_share`: the published router width and the first
+    expert held; the experts' count then says how many are held). What the
+    layer cannot honour is refused by name."""
+    scoring = _either(hf, "scoring_func", "score_func", default="softmax")
+    if scoring not in ("softmax", "sigmoid"):
         raise ValueError(
-            "first_k_dense_replace > 0 is not supported: the layer stack is "
-            "one scan over one kind of MLP, and leading dense layers would "
-            "silently run as expert layers")
-    if hf.get("scoring_func", "softmax") != "softmax":
+            f"scoring_func {scoring!r} is not supported: the router scores "
+            "by a float32 softmax over all experts or a sigmoid of each")
+    for name in ("n_group", "topk_group", "num_expert_groups",
+                 "num_limited_groups"):
+        if (hf.get(name) or 1) > 1:
+            raise ValueError(
+                f"{name} {hf[name]} is not supported: the router chooses "
+                "its top k among all experts, not among groups of them")
+    if _either(hf, "norm_topk_prob", "route_norm") is False:
         raise ValueError(
-            f"scoring_func {hf['scoring_func']!r} is not supported: the "
-            "router is a float32 softmax over all experts")
+            "norm_topk_prob / route_norm: false is not supported: the "
+            "expert layer renormalises the top-k router weights "
+            "(models/llama.py _route), and would silently compute another "
+            "model")
     kw: dict[str, Any] = {
-        "shared_expert_width": (hf.get("n_shared_experts") or 0)
+        "shared_expert_width":
+        (_either(hf, "n_shared_experts", "num_shared_experts") or 0)
         * (hf.get("moe_intermediate_size") or hf["intermediate_size"]),
-        "routed_scale": float(hf.get("routed_scaling_factor", 1.0)),
+        "routed_scale": float(_either(hf, "routed_scaling_factor",
+                                      "route_scale", default=1.0)),
+        "router_sigmoid": scoring == "sigmoid",
+        "leading_dense_layers": int(_either(
+            hf, "first_k_dense_replace", "num_dense_layers", default=0)),
     }
     share = hf.get("localai_expert_share")
     if share:
@@ -95,7 +149,7 @@ def _expert_layer_fields(hf: dict, held: int) -> dict:
         if kw["router_experts"] < held:
             raise ValueError(
                 f"localai_expert_share: a router of {kw['router_experts']} "
-                f"cannot have {held} experts held (n_routed_experts)")
+                f"cannot have {held} experts held")
     return kw
 
 
@@ -135,15 +189,19 @@ def _linear_fields(hf: dict, n_layers: int) -> dict:
     }
 
 
+def _read_config(model_dir: str) -> tuple[dict, str]:
+    """config.json and the architecture it names (or its model_type does)."""
+    with open(os.path.join(model_dir, "config.json")) as f:
+        hf: dict[str, Any] = json.load(f)
+    return hf, (hf.get("architectures")
+                or [_ARCH_OF_MODEL_TYPE.get(hf.get("model_type"),
+                                            "LlamaForCausalLM")])[0]
+
+
 def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
     """Parse HF config.json into a LlamaConfig. `dtype` overrides the compute
     dtype (activations follow params; bf16 is the TPU default)."""
-    with open(os.path.join(model_dir, "config.json")) as f:
-        hf: dict[str, Any] = json.load(f)
-
-    arch = (hf.get("architectures")
-            or [_ARCH_OF_MODEL_TYPE.get(hf.get("model_type"),
-                                        "LlamaForCausalLM")])[0]
+    hf, arch = _read_config(model_dir)
     if hf.get("model_type") == "llava" or arch.startswith("Llava"):
         # vision-language checkpoint: the language side is a plain
         # Llama-family config nested under text_config (the vision side
@@ -178,24 +236,28 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
     )
     experts = hf.get("num_experts", hf.get("num_local_experts",
                                            hf.get("n_routed_experts")))
-    mlp_kinds = set(hf.get("mlp_layer_types") or ())
-    if mlp_kinds - {"sparse", "dense"} or len(mlp_kinds) > 1:
+    mlp_list = list(hf.get("mlp_layer_types") or ())
+    mlp_kinds = set(mlp_list)
+    # dense entries before the first sparse one: leading dense layers
+    dense_first = mlp_list.index("sparse") if "sparse" in mlp_list else 0
+    if mlp_kinds - {"sparse", "dense"} or (
+            "sparse" in mlp_kinds and "dense" in mlp_list[dense_first:]):
         raise ValueError(
-            f"mlp_layer_types {sorted(mlp_kinds)}: the layer stack is one "
-            "scan over one kind of MLP, so every entry must be 'sparse' or "
-            "every entry 'dense'")
+            f"mlp_layer_types {sorted(mlp_kinds)}: dense layers are taken "
+            "before the first sparse one only (leading dense layers), the "
+            "rest of the stack is one scan over one kind of MLP")
     if mlp_kinds == {"dense"}:
         experts = None
     if (extra.get("moe") or experts) and mlp_kinds != {"dense"}:
-        if hf.get("norm_topk_prob") is False:
-            raise ValueError(
-                "norm_topk_prob: false is not supported: the expert layer "
-                "renormalises the top-k router weights (models/llama.py "
-                "_moe_mlp), and would silently compute another model")
         kw["num_experts"] = experts or 8
         kw["experts_per_tok"] = hf.get("num_experts_per_tok", 2)
         kw["moe_intermediate_size"] = hf.get("moe_intermediate_size")
         kw.update(_expert_layer_fields(hf, kw["num_experts"]))
+        kw["leading_dense_layers"] = (kw["leading_dense_layers"]
+                                      or dense_first)
+    if hf.get("mup_enabled"):
+        kw["embed_scale"] = float(hf["hidden_size"]) ** 0.5
+    kw.update(extra.get("fields", {}))
     if dtype is not None:
         # int8 = weight quantization; activations/KV stay bf16
         kw["dtype"] = ("bfloat16" if dtype in ("int8", "q8", "int4", "q4")
@@ -219,6 +281,8 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
         else None
     if one_kind == "full_attention":
         kw["sliding_window"] = None
+    if one_kind and _LAYER_KINDS[one_kind] in kw.get("nope_kinds", ()):
+        kw["use_rope"] = False      # the one kind there is does not rotate
 
     theta = hf.get("rope_theta", 10000.0)
     rs = hf.get("rope_scaling") or hf.get("rope_parameters") or None
@@ -372,17 +436,26 @@ def load_params(
         dtype = "bfloat16"
     dtype = jnp.dtype(dtype) if dtype is not None else cfg.jdtype
 
-    if cfg.stacked_by_kind and not _is_synthetic(model_dir):
-        raise ValueError(
-            "no checkpoint of a model with linear-attention layers has been "
-            "at hand: its tensors' names are not known here, and only "
-            "synthetic weights (localai_synthetic) can be loaded")
     if _is_synthetic(model_dir):
         # benchmark checkpoints: config.json declares the geometry, weights
         # are deterministic random init on device — lets the serving path be
         # measured at flagship scale without writing tens of GB to disk
         return _synthetic_params(cfg, dtype=dtype, mesh=mesh,
                                  qbits=qbits, specs=specs)
+    if cfg.drawn_by_leaf:
+        tensors = LLAMA_FAMILY.get(_read_config(model_dir)[1], {}).get(
+            "tensors")
+        if tensors is None or cfg.stacked_by_kind or mesh is not None:
+            raise ValueError(
+                "no checkpoint of this architecture has been at hand: its "
+                "tensors' names are not known here (or not for a mesh), and "
+                "only synthetic weights (localai_synthetic) can be loaded")
+        params = _load_by_leaf(_TensorReader(model_dir), cfg, tensors, dtype)
+        if quantize:
+            from localai_tpu.ops.quant import quantize_params
+
+            params = quantize_params(params, bits=qbits)
+        return params
 
     r = _TensorReader(model_dir)
     if mesh is not None and specs is None:
@@ -501,6 +574,44 @@ def load_params(
     return params
 
 
+def _load_by_leaf(r: "_TensorReader", cfg: LlamaConfig, tensors: dict, dtype):
+    """A checkpoint's tensors into the stacks models/llama.layer_stacks
+    names, leaf by leaf: layer N of the checkpoint is the N-th leading layer
+    or the (N - leading)-th of the rest; of the router's experts the share
+    held is read ([first_expert, first_expert + num_experts)). Matrices are
+    transposed to [in, out]; the router and its bias stay float32."""
+    from localai_tpu.models.llama import layer_stacks
+
+    def read(name, first, shape):
+        def one(n, **kw):
+            t = r.get(f"model.layers.{n}." + tensors[name].format(**kw))
+            return t.T if t.ndim == 2 else t
+
+        x = np.stack([
+            np.stack([one(n, e=cfg.first_expert + e)
+                      for e in range(cfg.num_experts)])
+            if name.startswith("moe_w") else one(n)
+            for n in range(first, first + shape[0])])
+        if x.shape != shape:
+            raise ValueError(f"{name}: the checkpoint gives {x.shape}, the "
+                             f"config {shape}")
+        return jnp.asarray(x).astype(
+            jnp.float32 if name in ("moe_gate", "moe_bias") else dtype)
+
+    params = {
+        "embed": jnp.asarray(r.get("model.embed_tokens.weight")).astype(dtype),
+        "final_norm": jnp.asarray(r.get("model.norm.weight")).astype(dtype)}
+    if not cfg.tie_embeddings:
+        params["lm_head"] = jnp.asarray(r.get("lm_head.weight").T).astype(
+            dtype)
+    for (where,), (count, leaves) in layer_stacks(cfg).items():
+        first = 0 if where == "leading" else cfg.leading_dense_layers
+        params[where] = {name: read(name, first, (count, *shape))
+                         for name, (shape, _) in leaves.items()}
+    r.close()
+    return params
+
+
 def _synthetic_params(cfg: LlamaConfig, *, dtype, mesh=None, qbits=None,
                       specs=None):
     """Deterministic random params at any scale. The quantized case generates
@@ -544,32 +655,27 @@ def _synthetic_params(cfg: LlamaConfig, *, dtype, mesh=None, qbits=None,
         return {"q": qbody(k, shape), "s": s}
 
     ks = jax.random.split(key, 12)
-    if cfg.stacked_by_kind:
-        from localai_tpu.models.llama import decay_init, layer_leaves
+    if cfg.drawn_by_leaf:
+        from localai_tpu.models.llama import fill_stacks, special_init
 
         def leaf(k, name, shape, how):
             if how == "ones":
                 return jnp.ones(shape, dtype)
-            if how in ("A_log", "dt_bias"):
-                return decay_init(k, name, shape)
+            if how in ("A_log", "dt_bias", "moe_bias"):
+                return special_init(k, how, shape)
             if name.startswith("w") or name.startswith("moe_w"):
                 return qrand(k, shape, how)
             x = jax.random.normal(k, shape, jnp.float32) * (how ** -0.5)
             return x if name == "moe_gate" else x.astype(dtype)
 
-        layers = {}
-        for n, kind in enumerate(sorted(set(cfg.layer_types))):
-            leaves = layer_leaves(cfg, kind)
-            kk = jax.random.split(jax.random.fold_in(ks[0], n), len(leaves))
-            layers[kind] = {
-                name: leaf(kk[i], name, (cfg.layers_of(kind), *shape), how)
-                for i, (name, (shape, how)) in enumerate(leaves.items())}
-        return {
+        params = {
             "embed": (jax.random.normal(ks[7], (cfg.vocab_size, h),
                                         jnp.float32)
                       * (h ** -0.5)).astype(dtype),
-            "layers": layers, "final_norm": jnp.ones((h,), dtype),
-            "lm_head": qrand(ks[8], (h, cfg.vocab_size), h)}
+            "final_norm": jnp.ones((h,), dtype)}
+        if not cfg.tie_embeddings:
+            params["lm_head"] = qrand(ks[8], (h, cfg.vocab_size), h)
+        return fill_stacks(cfg, params, leaf, ks[0])
     layers = {
         "attn_norm": jnp.ones((L, h), dtype),
         "wq": qrand(ks[0], (L, h, nh * hd), h),

@@ -51,7 +51,9 @@ class PeriodKV:
     """K (or V) of a model with several kinds of layer, as the engine holds
     it: one cache per place in the period of layer kinds, `slots[j]` of
     [L/period, B, KVH, T_j, D] (dense or QuantKV) — T_j the served context
-    for a FULL layer, the ring for a WINDOW one. A LINEAR layer's place
+    for a FULL layer, the ring for a WINDOW one; after them one place of
+    [1, B, KVH, T_j, D] for each layer that runs before the scanned periods
+    (cfg.cache_kinds names every place's kind). A LINEAR layer's place
     holds its state [L/period, B, H, Dk, Dv] float32 in the K tree and its
     convolution tail [L/period, B, K-1, C] in the V tree (StateKV). `view`
     makes a DenseKV, a RingKV or a StateKV of each; the layer scan CARRIES
@@ -686,8 +688,9 @@ def no_mixed(cfg, what: str):
 def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
          active=None, redirect=None):
     """The view of the cache a forward was given — the one place its format
-    is told from the arguments (a model with layer_types: one per place in
-    the period). active [B] bool (decode): the rows decoding this step, the
+    is told from the arguments (a model with layer_types: one per place of
+    the cache, cfg.cache_kinds: the period's places, then a leading layer's
+    each). active [B] bool (decode): the rows decoding this step, the
     others' writes must land nowhere readable; redirect [B] bool (extend
     over a pool): rows whose whole window goes to the trash block."""
     pool = pool or table is not None or kvt is not None
@@ -711,11 +714,11 @@ def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
     if k_cache is None:
         return tuple(StateKV(heads=cfg.linear_heads) if kind == LINEAR
                      else NoKV(window=window if kind == WINDOW else None)
-                     for kind in cfg.period)
+                     for kind in cfg.cache_kinds)
     if cfg.layer_types is None:
         return DenseKV(k_cache, v_cache, window, active=active)
     full_len = max((k.shape[-2] for k, kind
-                    in zip(k_cache.slots, cfg.period) if kind == FULL),
+                    in zip(k_cache.slots, cfg.cache_kinds) if kind == FULL),
                    default=None)
 
     def one(k, v, kind):
@@ -726,7 +729,7 @@ def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
         return DenseKV(k, v, None, active=active)
 
     return tuple(one(k, v, kind) for k, v, kind
-                 in zip(k_cache.slots, v_cache.slots, cfg.period))
+                 in zip(k_cache.slots, v_cache.slots, cfg.cache_kinds))
 
 
 def _decode_dq(q, kc, vc, lengths, sliding_window=None, table=None,

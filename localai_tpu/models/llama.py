@@ -98,6 +98,21 @@ class LlamaConfig:
     first_expert: int = 0
     shared_expert_width: int = 0
     routed_scale: float = 1.0
+    # the router: sigmoid scores where not a softmax over the experts, and a
+    # per-expert bias (the leaf moe_bias) added to the scores for the CHOICE
+    # of the top k only, never to the weights
+    router_sigmoid: bool = False
+    router_bias: bool = False
+    # the first leading_dense_layers layers have a dense SwiGLU of
+    # intermediate_size where the rest have experts: a weight stack of their
+    # own (params["leading"]) and a cache place each, after the period's
+    # (cache_kinds); they run before the layer scan, unrolled (_scan_layers)
+    leading_dense_layers: int = 0
+    qk_norm: bool = False       # RMSNorm over head_dim on q and k, before RoPE
+    post_norms: bool = False    # attention's and the MLP's OUTPUT normalised
+                                # before the residual add (sandwich norms)
+    nope_kinds: tuple[str, ...] = ()    # layer kinds that do not rotate
+    embed_scale: float = 1.0    # on the token embeddings
 
     def __post_init__(self):
         if self.router_experts and not (
@@ -107,10 +122,18 @@ class LlamaConfig:
                 f"experts [{self.first_expert}, {self.first_expert} + "
                 f"{self.num_experts}) are not among the router's "
                 f"{self.router_experts}")
+        lead = self.leading_dense_layers
+        if lead and (self.layer_types is None or not self.num_experts
+                     or not 0 < lead < self.num_layers):
+            raise ValueError(
+                f"{lead} leading dense layers need a model with experts, "
+                "more layers than that, and layer_types (a one-kind stack "
+                "is one scan over one kind of MLP)")
         if self.layer_types is None:
             return
         kinds = tuple(self.layer_types)
         object.__setattr__(self, "layer_types", kinds)
+        object.__setattr__(self, "nope_kinds", tuple(self.nope_kinds))
         if (len(kinds) != self.num_layers
                 or set(kinds) - {FULL, WINDOW, LINEAR}):
             raise ValueError(
@@ -127,6 +150,9 @@ class LlamaConfig:
                                     and self.linear_gate_rank):
             raise ValueError("linear layers need linear_heads, "
                              "linear_head_dim and linear_gate_rank")
+        if lead and LINEAR in kinds:
+            raise ValueError("leading dense layers in a model with linear "
+                             "layers (weights stacked by kind) are not taken")
 
     @property
     def expert_width(self) -> int:
@@ -136,20 +162,47 @@ class LlamaConfig:
     def stacked_by_kind(self) -> bool:
         return self.layer_types is not None and LINEAR in self.layer_types
 
+    @property
+    def drawn_by_leaf(self) -> bool:
+        """Whether the weights are made leaf by leaf from layer_leaves (all
+        a layer of this config holds) and not as the flat dict a Llama,
+        Mixtral or Mellum2 stack always was (kept: those models' draws)."""
+        return bool(self.stacked_by_kind or self.leading_dense_layers
+                    or self.qk_norm or self.post_norms or self.attn_gate
+                    or self.shared_expert_width or self.router_bias
+                    or self.router_experts)
+
     def layers_of(self, kind: str) -> int:
         return self.layer_types.count(kind)
 
     @property
+    def leading_kinds(self) -> tuple[str, ...]:
+        """The layer kinds of the leading dense layers."""
+        return (self.layer_types or ())[:self.leading_dense_layers]
+
+    @property
     def period(self) -> tuple[str, ...] | None:
         """The shortest run of layer kinds that repeats to give layer_types
-        (the body of the layer scan); None for a one-kind model."""
-        kinds = self.layer_types
-        if kinds is None:
+        after the leading dense layers (the body of the layer scan); None
+        for a one-kind model."""
+        if self.layer_types is None:
             return None
+        kinds = self.layer_types[self.leading_dense_layers:]
         n = len(kinds)
         for p in range(1, n + 1):
             if n % p == 0 and kinds == kinds[:p] * (n // p):
                 return kinds[:p]
+
+    @property
+    def cache_kinds(self) -> tuple[str, ...] | None:
+        """The layer kind of each place of the cache (kv.PeriodKV.slots):
+        the period's places, each [L / period, ...], then one place of
+        [1, ...] a leading layer."""
+        return self.period and self.period + self.leading_kinds
+
+    def rotates(self, kind: str | None) -> bool:
+        """Whether a layer of this kind rotates q and k (RoPE)."""
+        return self.use_rope and kind not in self.nope_kinds
 
     def rope_of(self, kind: str | None) -> RopeConfig:
         if kind == WINDOW and self.window_rope is not None:
@@ -190,25 +243,20 @@ def init_params(cfg: LlamaConfig, key, dtype=None):
     def norm(k, shape, fan_in):
         return (jax.random.normal(k, shape, jnp.float32) * (fan_in ** -0.5)).astype(dtype)
 
-    if cfg.stacked_by_kind:
+    if cfg.drawn_by_leaf:
         def leaf(k, name, shape, how):
             if how == "ones":
                 return jnp.ones(shape, dtype)
-            if how in ("A_log", "dt_bias"):
-                return decay_init(k, name, shape)
+            if how in ("A_log", "dt_bias", "moe_bias"):
+                return special_init(k, how, shape)
             out = norm(k, shape, how)
             return out.astype(jnp.float32) if name == "moe_gate" else out
 
-        layers = {}
-        for n, kind in enumerate(sorted(set(cfg.layer_types))):
-            shapes = layer_leaves(cfg, kind)
-            kk = jax.random.split(jax.random.fold_in(ks[0], n), len(shapes))
-            layers[kind] = {
-                name: leaf(kk[i], name, (cfg.layers_of(kind), *shape), how)
-                for i, (name, (shape, how)) in enumerate(shapes.items())}
-        return {"embed": norm(ks[7], (cfg.vocab_size, h), h),
-                "layers": layers, "final_norm": jnp.ones((h,), dtype),
-                "lm_head": norm(ks[8], (h, cfg.vocab_size), h)}
+        params = {"embed": norm(ks[7], (cfg.vocab_size, h), h),
+                  "final_norm": jnp.ones((h,), dtype)}
+        if not cfg.tie_embeddings:
+            params["lm_head"] = norm(ks[8], (h, cfg.vocab_size), h)
+        return fill_stacks(cfg, params, leaf, ks[0])
 
     layers = {
         "attn_norm": jnp.ones((L, h), dtype),
@@ -244,11 +292,13 @@ def init_params(cfg: LlamaConfig, key, dtype=None):
     return params
 
 
-def layer_leaves(cfg: LlamaConfig, kind: str) -> dict:
-    """One layer's leaves for a model whose weights are stacked by kind
-    (cfg.stacked_by_kind): name -> (shape without the layer axis, how it is
-    drawn: a matrix's fan-in, "ones", or "A_log" / "dt_bias"). Matrices are
-    the names that start with `w` or `moe_w` (ops/quant.quantize_params)."""
+def layer_leaves(cfg: LlamaConfig, kind: str, dense: bool = False) -> dict:
+    """One layer's leaves for a model whose weights are drawn leaf by leaf
+    (cfg.drawn_by_leaf): name -> (shape without the layer axis, how it is
+    drawn: a matrix's fan-in, "ones", or special_init's "A_log" / "dt_bias"
+    / "moe_bias"). Matrices are the names that start with `w` or `moe_w`
+    (ops/quant.quantize_params). dense: a leading dense layer's (a SwiGLU of
+    intermediate_size where the others have their experts)."""
     h, hd = cfg.hidden_size, cfg.head_dim
     out = {"attn_norm": ((h,), "ones")}
     if kind == LINEAR:
@@ -270,13 +320,27 @@ def layer_leaves(cfg: LlamaConfig, kind: str) -> dict:
         nh, nkv = cfg.num_heads, cfg.num_kv_heads
         out.update({"wq": ((h, nh * hd), h), "wk": ((h, nkv * hd), h),
                     "wv": ((h, nkv * hd), h), "wo": ((nh * hd, h), nh * hd)})
+        if cfg.qk_norm:
+            out.update({"q_norm": ((hd,), "ones"), "k_norm": ((hd,), "ones")})
         if cfg.attn_gate:
             out["w_agate"] = ((h, nh * hd), h)
+    if cfg.post_norms:
+        out["attn_post_norm"] = ((h,), "ones")
     out["mlp_norm"] = ((h,), "ones")
+    if cfg.post_norms:
+        out["mlp_post_norm"] = ((h,), "ones")
     e, i = cfg.num_experts, cfg.expert_width
-    out.update({"moe_gate": ((h, cfg.router_experts or e), h),
+    if dense or not e:
+        i = cfg.intermediate_size
+        out.update({"w_gate": ((h, i), h), "w_up": ((h, i), h),
+                    "w_down": ((i, h), i)})
+        return out
+    routers = cfg.router_experts or e
+    out.update({"moe_gate": ((h, routers), h),
                 "moe_w1": ((e, h, i), h), "moe_w2": ((e, i, h), i),
                 "moe_w3": ((e, h, i), h)})
+    if cfg.router_bias:
+        out["moe_bias"] = ((routers,), "moe_bias")
     if cfg.shared_expert_width:
         w = cfg.shared_expert_width
         out.update({"ws_gate": ((h, w), h), "ws_up": ((h, w), h),
@@ -284,13 +348,49 @@ def layer_leaves(cfg: LlamaConfig, kind: str) -> dict:
     return out
 
 
-def decay_init(key, name: str, shape):
-    """The family's initialisation of a linear layer's decay: A_log =
-    log U(1, 16) a head; dt_bias such that softplus(dt_bias) is log-uniform
-    in 1e-3..1e-1 a channel. With w_f2 drawn small (layer_leaves: the
-    input's part moves the gate by a factor of about 1.3) a token's decay
-    exp(-A softplus(.)) lies between about 0.1 and 0.999 and mostly in
-    0.9-0.999: never 0 or 1, so a decay left out or misapplied shows."""
+def layer_stacks(cfg: LlamaConfig) -> dict:
+    """The weight stacks of a model drawn leaf by leaf: where the stack lies
+    in the params -> (layers in it, one layer's leaves). By kind where the
+    kinds' leaves differ in shape (linear layers); else the scanned layers
+    under "layers" and the leading dense layers under "leading"."""
+    if cfg.stacked_by_kind:
+        return {("layers", kind): (cfg.layers_of(kind),
+                                   layer_leaves(cfg, kind))
+                for kind in sorted(set(cfg.layer_types))}
+    lead = cfg.leading_dense_layers
+    out = {("layers",): (cfg.num_layers - lead, layer_leaves(cfg, FULL))}
+    if lead:
+        out[("leading",)] = (lead, layer_leaves(cfg, FULL, dense=True))
+    return out
+
+
+def fill_stacks(cfg: LlamaConfig, params: dict, leaf, key=None) -> dict:
+    """`params` with layer_stacks' stacks in their places, each leaf made
+    by `leaf(its key or None, name, shape with the layer axis, how)`."""
+    for n, (path, (count, leaves)) in enumerate(layer_stacks(cfg).items()):
+        kk = (len(leaves) * [None] if key is None else
+              jax.random.split(jax.random.fold_in(key, n), len(leaves)))
+        stack = {name: leaf(kk[i], name, (count, *shape), how)
+                 for i, (name, (shape, how)) in enumerate(leaves.items())}
+        at = params
+        for step in path[:-1]:
+            at = at.setdefault(step, {})
+        at[path[-1]] = stack
+    return params
+
+
+def special_init(key, name: str, shape):
+    """The leaves that are no matrix and no gain. A linear layer's decay,
+    by the family's initialisation: A_log = log U(1, 16) a head; dt_bias
+    such that softplus(dt_bias) is log-uniform in 1e-3..1e-1 a channel.
+    With w_f2 drawn small (layer_leaves: the input's part moves the gate by
+    a factor of about 1.3) a token's decay exp(-A softplus(.)) lies between
+    about 0.1 and 0.999 and mostly in 0.9-0.999: never 0 or 1, so a decay
+    left out or misapplied shows. The router's selection bias (moe_bias):
+    N(0, 0.02^2), small and not zero, so that a bias left out of the choice,
+    or added to the weights, shows."""
+    if name == "moe_bias":
+        return 0.02 * jax.random.normal(key, shape, jnp.float32)
     if name == "A_log":
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
     dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
@@ -311,7 +411,7 @@ def param_specs(cfg: LlamaConfig, qbits: int | None = None):
     the reduced-away input axis — so a row-parallel wo keeps its scales
     whole on every chip while its int8 body shards on the input axis.
     """
-    if cfg.stacked_by_kind:
+    if cfg.drawn_by_leaf:
         # replicated: such a model has been served on one chip only (its
         # expert layer already holds one chip's share of a wider layout)
         def rep(name, shape):
@@ -320,11 +420,11 @@ def param_specs(cfg: LlamaConfig, qbits: int | None = None):
             return {"q": spec, "s": spec} if qbits and matrix else spec
 
         head = P(None, None)
-        return {"embed": P(None, None), "final_norm": P(None),
-                "lm_head": {"q": head, "s": head} if qbits else head,
-                "layers": {kind: {name: rep(name, shape) for name, (shape, _)
-                                  in layer_leaves(cfg, kind).items()}
-                           for kind in sorted(set(cfg.layer_types))}}
+        specs = {"embed": P(None, None), "final_norm": P(None)}
+        if not cfg.tie_embeddings:
+            specs["lm_head"] = {"q": head, "s": head} if qbits else head
+        return fill_stacks(
+            cfg, specs, lambda _, name, shape, how: rep(name, shape[1:]))
     layers = {
         "attn_norm": P(None, None),
         "wq": P(None, None, "model"),
@@ -444,6 +544,8 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
     longest window `extend` will be given), LINEAR layers their state
     [L/p, B, H, D, D] float32 (in the K tree) and their short convolution's
     last inputs [L/p, B, K-1, 3 H D] in `dtype` (in the V tree): kv.StateKV.
+    Leading dense layers have a place each after the period's
+    (cfg.cache_kinds), sized by their kind.
     """
     quant = is_quant_kind(cache_type)
     dtype = dtype or cfg.jdtype
@@ -464,16 +566,18 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
                              "from prefill_chunk")
         ring = ring_len(cfg, max_len, prefill_chunk, cache_type)
     period = cfg.period
-    n = cfg.num_layers // len(period)
+    n = (cfg.num_layers - cfg.leading_dense_layers) // len(period)
 
     def state():
         nh, d = cfg.linear_heads, cfg.linear_head_dim
         return (jnp.zeros((n, batch, nh, d, d), jnp.float32),
                 jnp.zeros((n, batch, cfg.linear_conv - 1, 3 * nh * d), dtype))
 
+    # a place of the period holds its n layers, a leading layer's place one
     pairs = [state() if kind == LINEAR
-             else one(n, ring if kind == WINDOW else max_len)
-             for kind in period]
+             else one(n if j < len(period) else 1,
+                      ring if kind == WINDOW else max_len)
+             for j, kind in enumerate(cfg.cache_kinds)]
     return (PeriodKV(tuple(k for k, _ in pairs)),
             PeriodKV(tuple(v for _, v in pairs)))
 
@@ -575,19 +679,38 @@ def _mlp(x, lp, cfg=None, spec_prefix=None):
         if expert_form(cfg, x.shape[0] * x.shape[1], current_mesh(),
                        isinstance(lp["moe_w1"], _InStack)) == ROUTED:
             return _moe_routed(x, lp, cfg)
-        return _moe_mlp(x, lp, cfg.experts_per_tok)
+        return _moe_mlp(x, lp, cfg)
     up_spec = down_spec = None
     if spec_prefix is not None:
         up_spec = P(*spec_prefix, "model")
         down_spec = P(*spec_prefix, None)
-    with jax.named_scope("mlp"):
+    # a dense layer of a model with experts: one of its leading layers
+    with jax.named_scope("mlp/leading_dense" if cfg is not None
+                         and cfg.num_experts else "mlp"):
         return qmatmul(jax.nn.silu(qmatmul(x, lp["w_gate"], up_spec))
                        * qmatmul(x, lp["w_up"], up_spec),
                        lp["w_down"], down_spec)
 
 
+def _route(logits, lp, cfg: LlamaConfig):
+    """Router logits [..., R] float32 -> (weights [..., k] float32, experts
+    [..., k]): softmax scores over the R experts, or a sigmoid of each; the
+    k best, by score plus the selection bias where the layer has one (the
+    bias chooses and never weighs); the chosen experts' scores renormalised
+    to sum to 1 (times cfg.routed_scale in the callers)."""
+    k = cfg.experts_per_tok
+    scores = (jax.nn.sigmoid(logits) if cfg.router_sigmoid
+              else jax.nn.softmax(logits, axis=-1))
+    if "moe_bias" in lp:
+        _, top_i = jax.lax.top_k(scores + lp["moe_bias"], k)
+        top_w = jnp.take_along_axis(scores, top_i, axis=-1)
+    else:
+        top_w, top_i = jax.lax.top_k(scores, k)
+    return top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9), top_i
+
+
 @jax.named_scope("experts")
-def _moe_mlp(x, lp, k: int):
+def _moe_mlp(x, lp, cfg: LlamaConfig):
     """Mixtral top-k routed experts (reference: the MoE GGUFs llama.cpp
     serves within ggml — SURVEY §2.4 expert-parallel row; HF semantics:
     softmax router → top-k → renormalize → weighted expert sum).
@@ -607,10 +730,9 @@ def _moe_mlp(x, lp, k: int):
 
     with jax.named_scope("router"):
         gate = lp["moe_gate"].astype(jnp.float32)
-        logits = x.astype(jnp.float32) @ gate                  # [B, S, E]
-        probs = jax.nn.softmax(logits, axis=-1)
-        top_w, top_i = jax.lax.top_k(probs, k)
-        top_w = top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
+        top_w, top_i = _route(x.astype(jnp.float32) @ gate, lp, cfg)
+        if cfg.routed_scale != 1.0:     # (1.0: the program Mixtral had)
+            top_w = top_w * cfg.routed_scale
         E = gate.shape[-1]
         combine = jnp.einsum(
             "bske,bsk->bse",
@@ -798,11 +920,10 @@ def _moe_routed(x, lp, cfg: LlamaConfig, grouped: bool = True):
     n = b * s
     held = _leading(lp["moe_w1"]).shape[-3]
     with jax.named_scope("router"):
-        logits = xt.astype(jnp.float32) @ lp["moe_gate"].astype(jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)                # [N, R]
-        top_w, top_i = jax.lax.top_k(probs, k)
-        top_w = (top_w / jnp.maximum(top_w.sum(-1, keepdims=True), 1e-9)
-                 * cfg.routed_scale)
+        top_w, top_i = _route(                                 # over [N, R]
+            xt.astype(jnp.float32) @ lp["moe_gate"].astype(jnp.float32),
+            lp, cfg)
+        top_w = top_w * cfg.routed_scale
         local = top_i - cfg.first_expert                       # [N, k]
         here = (local >= 0) & (local < held)
     w1, w2, w3 = (lp[n] if isinstance(lp[n], _InStack)
@@ -895,11 +1016,12 @@ def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
 def rope_tables(cfg: LlamaConfig, max_len: int):
     """The (cos, sin) the forwards take: one pair of tables for a one-kind
     model; for a model with layer_types a pair of dicts keyed by layer kind
-    (window and full layers rotate differently)."""
+    (window and full layers rotate differently, or one kind not at all:
+    cfg.rotates)."""
     if cfg.layer_types is None:
         return rope_table(cfg.rope, max_len)
     tabs = {kind: rope_table(cfg.rope_of(kind), max_len)
-            for kind in (FULL, WINDOW) if cfg.use_rope}
+            for kind in (FULL, WINDOW) if cfg.rotates(kind)}
     return ({kind: t[0] for kind, t in tabs.items()},
             {kind: t[1] for kind, t in tabs.items()})
 
@@ -922,8 +1044,10 @@ def _layer_params(layers, i):
 
 
 def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
-    """The transformer block, once: attn_norm → QKV → RoPE by layer kind →
-    `attend` → wo → mlp_norm → MLP over the residual x [B, S, H]. Every
+    """The transformer block, once: attn_norm → QKV (→ RMSNorm of q and k a
+    head) → RoPE by layer kind → `attend` (→ output gate) → wo → mlp_norm →
+    MLP over the residual x [B, S, H]; where the layer has post-norms, wo's
+    and the MLP's outputs are normalised before they are added. Every
     forward (and a pipeline stage) is this block over its own
     `attend(q, k, v) -> (attn [B, S, H, D], out)`: self-attention over a
     prompt, or a write into the cache's view and a read back; `out` goes
@@ -944,7 +1068,11 @@ def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
     else:
         with _attn_scope(kind):
             q, k, v = _qkv(h, lp, cfg, spec=sharded("model"))
-            if cfg.use_rope:
+            if "q_norm" in lp:
+                with jax.named_scope("qk_norm"):
+                    q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
+                    k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
+            if cfg.rotates(kind):
                 lcos, lsin = ((cos, sin) if kind is None
                               else (cos[kind], sin[kind]))
                 q = apply_rope(q, lcos, lsin, positions)
@@ -956,9 +1084,15 @@ def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
             attn = attn.reshape(b, s, -1)
             if "w_agate" in lp:
                 attn = attn * jax.nn.sigmoid(qmatmul(h, lp["w_agate"]))
-            x = x + qmatmul(attn, lp["wo"], spec=sharded(None))
-    h = rms_norm(x, lp["mlp_norm"], cfg.rms_eps)
-    x = x + _mlp(h, lp, cfg, spec_prefix=spec)
+            o = qmatmul(attn, lp["wo"], spec=sharded(None))
+            if "attn_post_norm" in lp:
+                o = rms_norm(o, lp["attn_post_norm"], cfg.rms_eps)
+            x = x + o
+    m = _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_eps), lp, cfg,
+             spec_prefix=spec)
+    if "mlp_post_norm" in lp:
+        m = rms_norm(m, lp["mlp_post_norm"], cfg.rms_eps)
+    x = x + m
     if spec is not None:
         x = _shard_act(x, sharded(None))
     return x, out
@@ -992,9 +1126,9 @@ def _linear_mixer(cfg: LlamaConfig, x, h, lp, attend):
         return x + qmatmul(o.reshape(b, s, -1) * gate, lp["wo"]), out
 
 
-def _scan_layers(cfg: LlamaConfig, block, x, layers, cache):
-    """Run `block(x, lp, view, kind) -> (x, view)` over the layer stack and
-    return (x, (k_cache, v_cache)) as the engine holds them. `cache` is the
+def _scan_layers(cfg: LlamaConfig, block, x, params, cache):
+    """Run `block(x, lp, view, kind) -> (x, view)` over the layer stacks of
+    `params` and return (x, (k_cache, v_cache)) as the engine holds them. `cache` is the
     forward's kv.view; how it rides the scan is its `carried`, read here
     and nowhere else. Carried (a dense stack, a ring): the stacks are the
     scan's CARRY and the block gets the view `at` the layer's index — no
@@ -1007,7 +1141,11 @@ def _scan_layers(cfg: LlamaConfig, block, x, layers, cache):
     One kind of layer: lax.scan over [L, ...] with kind None, the program a
     one-kind model always had. With layer_types `cache` is one view per
     place in the PERIOD of kinds and the scan's body is one period,
-    unrolled: compile time grows with the period, not the depth."""
+    unrolled: compile time grows with the period, not the depth. Leading
+    dense layers (params["leading"], the cache places after the period's)
+    run before the scan as an unrolled prefix: their kinds need not follow
+    the period, each has a cache place of its own, and they are few."""
+    layers = params["layers"]
     period = cfg.period
     # a call that takes the routed form leaves the experts IN their stacks
     # (_InStack: the tile loop slices (layer, expert) in one step); handed
@@ -1058,6 +1196,10 @@ def _scan_layers(cfg: LlamaConfig, block, x, layers, cache):
             ((rest, jnp.arange(cfg.num_layers)) if experts else rest,
              cache.k, cache.v, *cache.cold))
     p = len(period)
+    lead = list(cache[p:])
+    for j, kind in enumerate(cfg.leading_kinds):
+        x, lead[j] = block(x, _layer_params(params["leading"], j),
+                           lead[j].at(lead[j].k, lead[j].v, 0), kind)
 
     def place(i, j, kind):
         if not cfg.stacked_by_kind:
@@ -1076,15 +1218,18 @@ def _scan_layers(cfg: LlamaConfig, block, x, layers, cache):
         return (x, tuple(ks), tuple(vs)), None
 
     (x, ks, vs), _ = jax.lax.scan(
-        step, (x, tuple(c.k for c in cache), tuple(c.v for c in cache)),
-        jnp.arange(cfg.num_layers // p))
-    return x, (PeriodKV(ks), PeriodKV(vs))
+        step, (x, tuple(c.k for c in cache[:p]), tuple(c.v for c in cache[:p])),
+        jnp.arange((cfg.num_layers - cfg.leading_dense_layers) // p))
+    return x, (PeriodKV(ks + tuple(c.k for c in lead)),
+               PeriodKV(vs + tuple(c.v for c in lead)))
 
 
 def _embed(params, cfg: LlamaConfig, tokens, inject=None):
     """Token embeddings; where inject's is_embed is set, its `extra` rows
     (the multimodal path, models/llava.py, splices image features here)."""
     x = params["embed"].astype(cfg.jdtype)[tokens]
+    if cfg.embed_scale != 1.0:
+        x = x * cfg.embed_scale
     if inject is not None:
         extra, is_embed = inject
         x = jnp.where(is_embed[..., None], extra.astype(x.dtype), x)
@@ -1131,8 +1276,7 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
         return x, view.write(k, v, slot_map, positions, end=lengths,
                              unique=False)
 
-    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params["layers"],
-                                         cache)
+    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params, cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     last = jnp.take_along_axis(
         x, jnp.maximum(lengths - 1, 0)[:, None, None], axis=1)[:, 0]
@@ -1175,8 +1319,7 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,
                       ("data", None))
 
-    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params["layers"],
-                                         cache)
+    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params, cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     logits = _lm_head(x[:, 0].astype(jnp.float32), params)
     return logits, k_cache, v_cache
@@ -1251,8 +1394,7 @@ def ragged_forward(params, cfg: LlamaConfig, tokens, cos, sin,
         return _block(cfg, x, lp, kind, cos, sin, pos[None], attend,
                       (None, None))
 
-    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params["layers"],
-                                         cache)
+    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params, cache)
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)
     # [NSEQ, H] for 1-D logit_rows, [NSEQ, R, H] for the 2-D spec windows
     last = x[0][logit_rows.astype(jnp.int32)]
@@ -1527,7 +1669,7 @@ def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,
                       ("data", sax))
 
-    x, _ = _scan_layers(cfg, layer, x, params["layers"], cache)
+    x, _ = _scan_layers(cfg, layer, x, params, cache)
     return rms_norm(x, params["final_norm"], cfg.rms_eps)
 
 
@@ -1574,8 +1716,7 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,
                       ("data", None))
 
-    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params["layers"],
-                                         cache)
+    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params, cache)
     if not with_logits:
         return None, k_cache, v_cache
     x = rms_norm(x, params["final_norm"], cfg.rms_eps)

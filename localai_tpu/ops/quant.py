@@ -90,8 +90,8 @@ def quantize_params(params, *, bits: int = 8, skip=("embed", "final_norm")):
     and embeddings stay high-precision, like llama.cpp's mixed layouts)."""
     out = {}
     for k, v in params.items():
-        if k == "layers":
-            out[k] = {
+        if k in ("layers", "leading"):      # the scanned stack, and the
+            out[k] = {                       # leading dense layers' own
                 lk: (quantize(lv, bits)
                      if lk.startswith("w") or lk.startswith("moe_w") else lv)
                 for lk, lv in v.items()

@@ -316,9 +316,11 @@ def test_gauges_and_counters(engine):
 # ------------------------------------------------ refused, each by name
 
 @pytest.mark.parametrize("over, match", [
-    (dict(first_k_dense_replace=1), "first_k_dense_replace"),
+    # leading dense layers are computed beside window and full layers, not
+    # in a model whose weights are stacked by kind
+    (dict(first_k_dense_replace=1), "leading dense layers"),
     (dict(kda_use_full_proj=True), "kda_use_full_proj"),
-    (dict(scoring_func="sigmoid"), "scoring_func"),
+    (dict(scoring_func="tanh"), "scoring_func"),
     (dict(norm_topk_prob=False), "norm_topk_prob"),
     (dict(gqa_layers=[0, 1, 5]), "not periodic"),
     (dict(gqa_layers=[]), "gqa_layers"),

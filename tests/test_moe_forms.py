@@ -54,7 +54,7 @@ def test_routed_equals_dense_where_every_expert_is_held(experts, k, int8,
                                                         tokens):
     lp, x = _layer(experts, int8), _tokens(tokens)
     a = _moe_routed(x, lp, _cfg(experts, k))
-    b = _moe_mlp(x, lp, k)
+    b = _moe_mlp(x, lp, _cfg(experts, k))
     assert float(jnp.abs(a - b).max()) < 1e-5
 
 
@@ -76,7 +76,7 @@ def test_one_expert_gets_every_token_and_one_gets_none(experts, k, int8,
     chosen = jax.lax.top_k(x[0] @ lp["moe_gate"], k)[1]
     assert bool((chosen == 1).any(-1).all()) and not bool((chosen == 2).any())
     a = _moe_routed(x, lp, _cfg(experts, k))
-    b = _moe_mlp(x, lp, k)
+    b = _moe_mlp(x, lp, _cfg(experts, k))
     assert float(jnp.abs(a - b).max()) < 1e-5
 
 
@@ -92,7 +92,7 @@ def test_a_row_that_is_not_finite_stays_alone(kernel, bad, monkeypatch):
         "LOCALAI_FORCE_PALLAS" if kernel else "LOCALAI_NO_PALLAS", "1")
     lp, x = _layer(8, True), _tokens(32)
     got = _moe_routed(x.at[0, 5, 3].set(bad), lp, _cfg(8, 2))
-    want = _moe_mlp(x, lp, 2)
+    want = _moe_mlp(x, lp, _cfg(8, 2))
     others = jnp.arange(32) != 5
     assert float(jnp.abs(got - want)[0, others].max()) < 1e-5
     assert not bool(jnp.isfinite(got[0, 5]).any())
@@ -107,7 +107,7 @@ def test_an_expert_that_overflows_loses_its_own_tokens_only(kernel,
     monkeypatch.setenv(
         "LOCALAI_FORCE_PALLAS" if kernel else "LOCALAI_NO_PALLAS", "1")
     lp, x = _layer(8, False), _tokens(32)
-    want = _moe_mlp(x, lp, 2)
+    want = _moe_mlp(x, lp, _cfg(8, 2))
     lp["moe_w2"] = lp["moe_w2"].at[3].set(jnp.inf)
     chosen = jax.lax.top_k(x[0] @ lp["moe_gate"], 2)[1]
     hit = (chosen == 3).any(-1)

@@ -78,6 +78,41 @@ def test_the_eight_shares_add_up_to_the_whole_layer(whole):
     assert float(jnp.abs(one - want).max()) > 0.05
 
 
+@pytest.mark.parametrize("grouped", [True, False],
+                         ids=["grouped", "masked"])
+def test_the_eight_shares_of_a_sigmoid_router_add_up_too(whole, grouped):
+    """The same sum under Trinity's router (PR 35): sigmoid scores, a
+    per-expert bias in the choice only, route_scale, against the afmoe
+    reference's uncut layer. The bias is large enough to move the choice of
+    most tokens; weighing by it, or leaving it out, is far from the sum."""
+    from localai_tpu.testing import reference_afmoe as afmoe
+
+    x = _x(seed=4)
+    bias = jnp.asarray(np.random.default_rng(5).standard_normal(R) * 0.1,
+                       jnp.float32)
+    rcfg = afmoe.RefConfig(
+        vocab_size=64, hidden_size=H, num_layers=1, num_heads=4,
+        num_kv_heads=2, head_dim=8, rms_eps=1e-5, layer_types=(FULL,),
+        sliding_window=8, rope_theta=1e4, num_dense_layers=0, num_experts=R,
+        experts_per_tok=K, route_scale=2.448)
+    names = {"moe_gate": "router", "moe_w1": "w1", "moe_w2": "w2",
+             "moe_w3": "w3"}
+    rp = {**{names.get(k, k): v for k, v in whole.items()}, "bias": bias}
+    want = afmoe.experts(x.reshape(-1, H), rp, rcfg)
+    over = dict(router_sigmoid=True, router_bias=True, routed_scale=2.448)
+    total = jnp.zeros_like(want)
+    for n in range(R // HELD):
+        lp = dict(_share(whole, n * HELD, shared=(n == 0)), moe_bias=bias)
+        total = total + _moe_routed(x, lp, _cfg(first=n * HELD, **over),
+                                    grouped=grouped).reshape(-1, H)
+    assert float(jnp.abs(total - want).max()) < 1e-5
+    for fault in (dict(bias_in_weights=True), dict(bias_in_choice=False),
+                  dict(scoring="softmax"), dict(route_scale=1.0)):
+        other = afmoe.experts(x.reshape(-1, H), rp,
+                              dataclasses.replace(rcfg, **fault))
+        assert float(jnp.abs(total - other).max()) > 0.02, fault
+
+
 @pytest.mark.parametrize("first", [0, 12, 28])
 @pytest.mark.parametrize("int8", [False, True])
 def test_grouped_equals_masked_on_a_share(whole, first, int8):

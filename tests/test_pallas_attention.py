@@ -245,7 +245,9 @@ def _edges(T, bk, ring):
             *((T + 1, 3 * T + 77) if ring else (T,))][-8:]
 
 
-@pytest.mark.parametrize("G,T", [(1, 640), (4, 1536), (8, 2048)])
+# (6, 4608): 48 query heads over 8 KV heads, a ring of 4096 + 512 (PR 35):
+# the first group that is no power of two
+@pytest.mark.parametrize("G,T", [(1, 640), (4, 1536), (8, 2048), (6, 4608)])
 @pytest.mark.parametrize("form", ["dense", "layer0", "layer2", "ring",
                                   "window"])
 @pytest.mark.parametrize("quant", [False, True], ids=["f32", "q8"])
@@ -255,7 +257,7 @@ def test_dense_grid_matches_the_xla_twin(quant, form, G, T):
 
     q, k, v, kw = _dense_case(quant, form, G, T)
     bk = _dense_block_k(T, 2, 16, 1 if quant else 4)
-    assert bk == {640: 128, 1536: 512, 2048: 1024}[T]
+    assert bk == {640: 128, 1536: 512, 2048: 1024, 4608: 512}[T]
     lengths = _edges(T, bk, form == "ring")
     got = _run(q, k, v, lengths, **kw)
     want = np.asarray(_decode_dq(q, k, v, jnp.asarray(lengths, jnp.int32),

@@ -173,13 +173,14 @@ def _router_spy(monkeypatch):
 
     seen, served = [], llama._moe_mlp
 
-    def spy(x, lp, k):
+    def spy(x, lp, cfg):
+        k = cfg.experts_per_tok
         probs = jax.nn.softmax(
             x.astype(jnp.float32) @ lp["moe_gate"].astype(jnp.float32), -1)
         jax.debug.callback(
             lambda p, e: seen.append((np.asarray(p), np.asarray(e))),
             *jax.lax.top_k(probs, k + 1), ordered=True)
-        return served(x, lp, k)
+        return served(x, lp, cfg)
 
     monkeypatch.setattr(llama, "_moe_mlp", spy)
     return seen

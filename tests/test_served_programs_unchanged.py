@@ -1,0 +1,193 @@
+"""The programs the engine serves the OLDER models with are the ones it
+served them with before Trinity (PR 35): a one-kind model with experts
+(Mixtral), window and full layers with a RoPE each (Mellum2), linear layers
+beside gated NoPE layers with a share of the experts (Solar-Open2), each
+loaded from its config.json's keys with synthetic int8 weights and an int8
+cache, as the benchmark's cells serve them. For batched admission
+(`_admit_many`), a middle prefill chunk (`extend`), the single decode step
+and the fused decode loop, the jaxpr the engine's own call traces is hashed
+and compared with the hash the same code gave on the parent commit (8aabf74;
+`python tests/test_served_programs_unchanged.py` prints the table, run with
+PYTHONPATH at a checkout). A kernel's compile-cache key, and with it the old
+cells' `setup_s`, rides on these programs: a change that has to move one
+replaces its hash here and says so in PERF.md.
+
+The hashes are of this container's JAX (0.9.0); another version prints
+other jaxprs, and the table is then made again on both commits.
+"""
+import hashlib
+import json
+import os
+import re
+
+import numpy as np
+import pytest
+
+MODELS = {
+    "mixtral": dict(
+        vocab_size=96, hidden_size=64, intermediate_size=32,
+        num_hidden_layers=3, num_attention_heads=4, num_key_value_heads=2,
+        max_position_embeddings=1024, rms_norm_eps=1e-5, rope_theta=1e6,
+        num_local_experts=4, num_experts_per_tok=2, sliding_window=None,
+        architectures=["MixtralForCausalLM"], tie_word_embeddings=False),
+    "mellum2": dict(
+        vocab_size=96, hidden_size=64, intermediate_size=48,
+        num_hidden_layers=8, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=16, max_position_embeddings=1024, rms_norm_eps=1e-6,
+        sliding_window=8, num_experts=8, num_experts_per_tok=2,
+        moe_intermediate_size=32, norm_topk_prob=True,
+        layer_types=(["sliding_attention"] * 3 + ["full_attention"]) * 2,
+        mlp_layer_types=["sparse"] * 8,
+        rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 16, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.2},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 500000}},
+        model_type="mellum", tie_word_embeddings=False),
+    "solar-open2": dict(
+        model_type="solar_open2", hidden_size=64, num_hidden_layers=8,
+        num_attention_heads=4, head_dim=16, num_key_value_heads=2,
+        vocab_size=128, intermediate_size=128, moe_intermediate_size=32,
+        rms_norm_eps=1e-5, max_position_embeddings=4096,
+        # (a state of 128 x 128 a head: the least the decode kernel tiles)
+        linear_attn_config=dict(short_conv_kernel_size=4, head_dim=128,
+                                num_heads=4, num_kv_heads=None),
+        gqa_layers=[0, 4], use_rope=False, use_gqa_gate=True,
+        kda_use_full_proj=False, kda_allow_neg_eigval=True,
+        n_routed_experts=8, n_shared_experts=1, norm_topk_prob=True,
+        routed_scaling_factor=1, num_experts_per_tok=4,
+        first_k_dense_replace=0, tie_word_embeddings=False,
+        localai_expert_share=dict(router_experts=16, first_expert=4)),
+}
+PROGRAMS = ("_admit_many_fn", "_extend_mid_fn", "_decode_nomask_fn",
+            "_decode_loop_fn")
+# model -> kernels -> program -> sha256 of its jaxpr, on the parent commit
+PARENT = {
+    "mixtral": {
+        "xla": {
+            "_admit_many_fn": "fbfb20269f039a48",
+            "_extend_mid_fn": "3459fbd32e02d7e9",
+            "_decode_nomask_fn": "e3cec072d822b9bd",
+            "_decode_loop_fn": "8a860900cd45289a",
+        },
+        "pallas": {
+            "_admit_many_fn": "8f4729f85dc0ea26",
+            "_extend_mid_fn": "2b01d3486cd1471d",
+            "_decode_nomask_fn": "2f0cbca457f87e88",
+            "_decode_loop_fn": "638f5517a3e82122",
+        },
+    },
+    "mellum2": {
+        "xla": {
+            "_admit_many_fn": "893396ee4f39a1a6",
+            "_extend_mid_fn": "4379ddccef7b3e90",
+            "_decode_nomask_fn": "ecbe0c77ee57a8d4",
+            "_decode_loop_fn": "ba6b6e51f0014472",
+        },
+        "pallas": {
+            "_admit_many_fn": "f28fb2fdb76012f2",
+            "_extend_mid_fn": "847378bead3f36f5",
+            "_decode_nomask_fn": "b65452d32874300b",
+            "_decode_loop_fn": "20075d0627bfddc4",
+        },
+    },
+    "solar-open2": {
+        "xla": {
+            "_admit_many_fn": "dfd70030e9cb5d93",
+            "_extend_mid_fn": "5a474de416f5627c",
+            "_decode_nomask_fn": "ee68658b5c841909",
+            "_decode_loop_fn": "8f3773a8464a0440",
+        },
+        "pallas": {
+            "_admit_many_fn": "7983ec38f42576db",
+            "_extend_mid_fn": "1a8361e3a43cde2b",
+            "_decode_nomask_fn": "3e502a281b398fa4",
+            "_decode_loop_fn": "aff8bda03d3e95e8",
+        },
+    },
+}
+
+
+def _text(jaxpr) -> str:
+    """A jaxpr's text without what differs between two checkouts or two
+    processes: addresses, and the path above the package."""
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    return re.sub(r"[^\s'\"=(]*/(localai_tpu/)", r"\1", text)
+
+
+def _drive(name: str, workdir: str) -> dict:
+    """program -> hash, for one model: the engine is built as the backend
+    builds it and driven once through each program."""
+    from localai_tpu.engine import Engine, EngineConfig
+    from localai_tpu.engine.loader import load_config, load_params
+    from localai_tpu.ops.sampling import SamplingParams, sampler_row
+
+    os.makedirs(workdir, exist_ok=True)
+    with open(os.path.join(workdir, "config.json"), "w") as f:
+        json.dump(dict(MODELS[name], localai_synthetic=True), f)
+    cfg = load_config(workdir, dtype="int8")
+    params = load_params(workdir, cfg, dtype="int8")
+    eng = Engine(cfg, params, None, EngineConfig(
+        max_slots=4, max_context=256, prefill_buckets=(64,),
+        prefill_chunk=64, cache_type="int8"))
+    seen = {}
+
+    def recorded(attr):
+        fn = getattr(eng, attr)
+
+        def call(*args, **kw):
+            seen[attr] = hashlib.sha256(_text(
+                fn.trace(*args, **kw).jaxpr).encode()).hexdigest()[:16]
+            return fn(*args, **kw)
+
+        setattr(eng, attr, call)
+
+    for attr in PROGRAMS:
+        recorded(attr)
+    greedy = sampler_row(SamplingParams(temperature=0.0), cfg.vocab_size,
+                         fallback_seed=1, include_bias=False)
+    ids = np.ones((1, 64), np.int32)
+    eng._dev_admit(ids, 40, 1, greedy, None)
+    eng._dev_extend_mid(ids, 0, 2)
+    active = np.array([False, True, False, False])
+    eng._dev_decode(active).wait()
+    eng._dev_decode_loop(active, np.array([0, 4, 0, 0], np.int32),
+                         np.zeros((4,), bool)).wait()
+    assert set(seen) == set(PROGRAMS), sorted(seen)
+    return seen
+
+
+def hashes(name: str, kernels: str, workdir: str) -> dict:
+    """_drive as a served backend would trace it: synthetic weights
+    allowed, the Pallas kernels forced or not, and the default precision of
+    products (tests/conftest.py asks for float32 ones)."""
+    import jax
+
+    os.environ["LOCALAI_ALLOW_SYNTHETIC"] = "1"
+    os.environ.pop("LOCALAI_FORCE_PALLAS", None)
+    if kernels == "pallas":
+        os.environ["LOCALAI_FORCE_PALLAS"] = "1"
+    try:
+        with jax.default_matmul_precision(None):
+            return _drive(name, workdir)
+    finally:
+        os.environ.pop("LOCALAI_FORCE_PALLAS", None)
+
+
+@pytest.mark.parametrize("kernels", ["xla", "pallas"])
+@pytest.mark.parametrize("name", list(MODELS))
+def test_the_older_models_programs_are_the_parents(name, kernels, tmp_path):
+    assert hashes(name, kernels, str(tmp_path)) == PARENT[name][kernels]
+
+
+if __name__ == "__main__":
+    import tempfile
+
+    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    os.environ.setdefault("LOCALAI_NO_PREWARM", "1")
+    with tempfile.TemporaryDirectory() as tmp:
+        print(json.dumps({name: {k: hashes(name, k, os.path.join(tmp, name, k))
+                                 for k in ("xla", "pallas")}
+                          for name in MODELS}, indent=1))

@@ -1,0 +1,94 @@
+"""The kernels of Trinity's cell, compiled at their real sizes for a v5e that
+is described and not attached (the TPU's compiler is installed here; nothing
+runs): what the interpreter cannot say, whether Mosaic takes the shapes. The
+decode attention kernel at 48 query heads over 8 KV heads (a group of 6, the
+first that is no power of two) over a ring of 4096 + 512 tokens and over a
+16384-token cache, int8; the routed expert layer at 3072 x 3072, 32 held of
+256, top-4, for a chunk and for a decode step's rows. The topology is
+described inside a fixture (one process at a time may load the TPU's
+library, and a worker imports every test file)."""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler here, or its library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The kernels as a TPU backend would trace them: not the interpreter."""
+    from localai_tpu.ops.pallas import flash_attention, grouped_matmul
+
+    monkeypatch.setenv("LOCALAI_FORCE_PALLAS", "1")
+    for mod in (flash_attention, grouped_matmul):
+        monkeypatch.setattr(mod, "_interpret", lambda: False)
+
+
+def _compile(fn, *shapes):
+    with jax.default_matmul_precision(None):    # conftest asks for float32
+        return jax.jit(fn).lower(*shapes).compile()
+
+
+@pytest.mark.parametrize("t,kw", [
+    (16384, {}), (4608, dict(sliding_window=4096, ring=True))],
+    ids=["a full layer's cache", "a window layer's ring"])
+def test_decode_kernel_at_a_group_of_six(one_chip, mosaic, t, kw):
+    from localai_tpu.ops.pallas import ragged_decode_q8
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    b, kvh, group, d, layers = 32, 8, 6, 128, 4
+    body = shape((layers, b, kvh, t, d), jnp.int8)
+    scale = shape((layers, b, kvh, t // 128, 128), jnp.float32)
+    out = _compile(
+        lambda q, kq, ks, vq, vs, lengths, layer: ragged_decode_q8(
+            q, kq, ks, vq, vs, lengths, layer=layer, **kw),
+        shape((b, 1, kvh * group, d), jnp.bfloat16), body, scale, body,
+        scale, shape((b,), jnp.int32), shape((), jnp.int32))
+    assert out.output_shardings is not None
+
+
+@pytest.mark.parametrize("rows", [(1, 512), (32, 1)],
+                         ids=["a chunk", "a decode step"])
+def test_routed_share_at_the_cells_widths(one_chip, mosaic, rows):
+    from localai_tpu.models.llama import LlamaConfig, _InStack, _moe_routed
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def int8(dims):
+        return {"q": shape(dims, jnp.int8),
+                "s": shape(dims[:-2] + (1, dims[-1]), jnp.float32)}
+
+    h, held, routers = 3072, 32, 256
+    cfg = LlamaConfig(hidden_size=h, num_experts=held, experts_per_tok=4,
+                      moe_intermediate_size=h, router_experts=routers,
+                      shared_expert_width=h, routed_scale=2.448,
+                      router_sigmoid=True, router_bias=True)
+    rest = {"moe_gate": shape((h, routers), jnp.float32),
+            "moe_bias": shape((routers,), jnp.float32),
+            "ws_gate": int8((h, h)), "ws_up": int8((h, h)),
+            "ws_down": int8((h, h))}
+    stacks = {n: int8((4, held, h, h)) for n in ("moe_w1", "moe_w2",
+                                                  "moe_w3")}
+    out = _compile(
+        lambda x, rest, stacks: _moe_routed(
+            x, {**rest, **{n: _InStack(w, 1) for n, w in stacks.items()}},
+            cfg),
+        shape((*rows, h), jnp.bfloat16), rest, stacks)
+    assert out.output_shardings is not None

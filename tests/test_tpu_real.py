@@ -172,6 +172,10 @@ CELL_SHAPES = {
     "mixtral": (8, 4, 1536, None, 1536),
     "mellum2-full": (4, 8, 8192, None, 7680),
     "mellum2-ring": (4, 8, 1536, 1024, 6000),
+    # 48 query heads over 8 KV heads: a group of 6, the first that is no
+    # power of two (PR 35); a ring of 4096 + 512 tokens, rows of 16384
+    "trinity-full": (8, 6, 16384, None, 16383),
+    "trinity-ring": (8, 6, 4608, 4096, 16000),
 }
 
 
@@ -494,7 +498,7 @@ def test_routed_experts_at_the_cells_shapes(shape):
 
     def dense(x, gate, stacks):
         return _moe_mlp(x, {"moe_gate": gate, **jax.tree_util.tree_map(
-            lambda a: a[1], stacks)}, k)
+            lambda a: a[1], stacks)}, cfg)
 
     got = jax.jit(routed)(x, gate, stacks).astype(jnp.float32)
     want = jax.jit(dense)(x, gate, stacks).astype(jnp.float32)
@@ -513,6 +517,51 @@ def _tiny_cfg(H, KVH, D):
     return LlamaConfig(vocab_size=256, hidden_size=H * D // 4,
                        intermediate_size=512, num_layers=2, num_heads=H,
                        num_kv_heads=KVH, head_dim=D, max_position=512)
+
+
+@pytest.mark.parametrize("rows", [(1, 512), (32, 1)],
+                         ids=["a 512-token chunk", "a decode step's 32 rows"])
+def test_routed_share_at_trinitys_widths(rows):
+    """models/llama._moe_routed as Trinity's cell serves it (experts of
+    3072 x 3072 int8 in a two-layer stack, 32 held of the 256 a sigmoid
+    router with a selection bias scores, top-4, route_scale, a shared
+    expert; bf16 activations), the grouped product kernel against the
+    masked twin. If the compile hangs in warm-up as PR 32's first kernel
+    did at 1024-row tiles (vmem_limit_bytes; PERF.md section 6), this is
+    the shape that brings it back."""
+    from localai_tpu.models.llama import LlamaConfig, _InStack, _moe_routed
+
+    hidden, held, routers, b_s = 3072, 32, 256, rows
+    cfg = LlamaConfig(hidden_size=hidden, num_experts=held, experts_per_tok=4,
+                      moe_intermediate_size=hidden, router_experts=routers,
+                      shared_expert_width=hidden, routed_scale=2.448,
+                      router_sigmoid=True, router_bias=True)
+    ks = jax.random.split(jax.random.PRNGKey(35), 9)
+    qw = lambda kk, shape: {  # noqa: E731
+        "q": jax.random.randint(kk, shape, -127, 128, jnp.int8),
+        "s": jnp.full(shape[:-2] + (1, shape[-1]), shape[-2] ** -0.5 / 73,
+                      jnp.float32)}
+    rest = {"moe_gate": jax.random.normal(ks[0], (hidden, routers))
+            * hidden ** -0.5,
+            "moe_bias": 0.02 * jax.random.normal(ks[1], (routers,)),
+            "ws_gate": qw(ks[2], (hidden, hidden)),
+            "ws_up": qw(ks[3], (hidden, hidden)),
+            "ws_down": qw(ks[4], (hidden, hidden))}
+    stacks = {"moe_w1": qw(ks[5], (2, held, hidden, hidden)),
+              "moe_w3": qw(ks[6], (2, held, hidden, hidden)),
+              "moe_w2": qw(ks[7], (2, held, hidden, hidden))}
+    x = jax.random.normal(ks[8], (*b_s, hidden), jnp.bfloat16)
+
+    def layer(grouped):
+        return jax.jit(lambda x, rest, stacks: _moe_routed(
+            x, {**rest, **{n: _InStack(w, 1) for n, w in stacks.items()}},
+            cfg, grouped=grouped))
+
+    got = layer(True)(x, rest, stacks).astype(jnp.float32)
+    want = layer(False)(x, rest, stacks).astype(jnp.float32)
+    assert bool(jnp.isfinite(got).all())
+    assert float(jnp.abs(got - want).max()) < 0.03 * float(
+        jnp.abs(want).max())
 
 
 @pytest.mark.parametrize("H,KVH,D", GEOMS)

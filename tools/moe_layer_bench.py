@@ -121,7 +121,7 @@ def main(argv=None) -> int:
                 stacks)}
             if routers != held:
                 return llama._moe_routed(x, lp, cfg, grouped=False)
-            return llama._moe_mlp(x, lp, topk)
+            return llama._moe_mlp(x, lp, cfg)
 
         for b, s in calls:
             n = b * s

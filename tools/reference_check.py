@@ -77,6 +77,33 @@ short row: sound median 0.061 / 0.077, largest 0.161 / 0.129, top-1 0.89 /
 off 1.00 / 1.03, share offset 0.563 / 0.609: the limits above hold for this
 configuration too (PERF.md section 6).
 
+A configuration of the `afmoe` architecture (PR 35: Trinity-Large-Preview as
+one of eight chips that share each layer) runs the same script against
+localai_tpu/testing/reference_afmoe.py with the three rows above (12000
+tokens through chunked prefill past the 4096 + 512 ring, 2500 through chunks
+of its own, 96 through one chunk; no first tenant: no layer holds a state)
+and twelve faults, each given to the reference: the window mask off (the
+long row), and on the 2500-token row the full layers rotated, q/k norm off,
+the output gate off, softmax for sigmoid scores, the selection bias added
+to the weights, the bias left out of the choice, route_scale off, the share
+offset by the experts held, the post-norms off, the embedding's scale off,
+the leading layer run as an expert layer. Readings (my chip run, PR 35),
+12000- / 2500- / 96-token row: sound median 0.0126 / 0.0135 / 0.0165, largest
+0.159 / 0.015 / 0.175 (router ties; 0.224 in another run), top-1 0.94 / 0.94
+/ 0.97; the reference in bfloat16 products 0.0142 / 0.0150 (the served path
+is as far from it as from the float32 one: its 1.3% is int8, not the
+products); the faults' medians: window mask off 1.02, full layers rotated
+0.093 / 0.040 (largest 0.18 / 0.16, top-1 0.75 / 0.91: the nearest), q/k
+norm off 0.147, gate off 0.50, softmax 0.22, route_scale off 0.119, share
+offset 0.33, post-norms off 1.16, embedding's scale off 0.83, leading layer
+as expert layer 0.81; the bias left out of the choice 0.018 (largest 0.357)
+and the bias added to the weights 0.0139 (largest 0.019: no whole-path
+reading tells it) are told by the router alone. Limits for this
+architecture: MEDIAN_REL_AFMOE 0.025 (between 0.0165 and 0.040; the other
+configurations' 0.15 would pass the rotation), WORST_REL and TOP1_SHARE as
+above, ROUTER_REL 0.006 (sound 0.00015, the bias in the weights 0.018),
+ROUTER_CHOICE 0.15 (sound 0.014, the bias out of the choice 0.74).
+
 `--cpu-rehearsal` runs the same script on the configuration's tiny
 `rehearsal` geometry on the CPU: it proves the script, and that the served
 path is the reference's mathematics (float32, tight), never a speed.
@@ -95,6 +122,12 @@ sys.path.insert(0, ROOT)
 NOTES = ("source", "reduced", "published", "assumed", "deployment", "serving",
          "rehearsal")                       # benchmark/harness/server.py
 MEDIAN_REL, WORST_REL, TOP1_SHARE = 0.15, 0.25, 0.5
+# afmoe (five layers, sandwich norms) reads a median of 0.013-0.017 sound and
+# 0.040 under its nearest fault, full layers rotated on the 2500-token row
+MEDIAN_REL_AFMOE = 0.025
+# the router alone (afmoe; router_reading): a token's weights, and the share
+# of tokens whose chosen experts differ
+ROUTER_REL, ROUTER_CHOICE = 0.006, 0.15
 GROUP = 8                   # decode steps a dispatch of the fused loop
 
 
@@ -137,12 +170,18 @@ def main() -> int:
     hf = {k: v for k, v in doc.items() if k not in NOTES}
     srv = dict(doc["serving"])
     linear = bool(hf.get("linear_attn_config"))
+    afmoe = hf.get("model_type") == "afmoe"
+    # a vocabulary near 200 k, 16 k of context: three rows, the short one
+    # past every bucket, the head made float32 a slice at a time
+    large = linear or afmoe
     if linear:
         from localai_tpu.testing import reference_linear as ref
+    elif afmoe:
+        from localai_tpu.testing import reference_afmoe as ref
     else:
         from localai_tpu.testing import reference_lm as ref
-    args.long = args.long or (12000 if linear else 6000)
-    args.short = args.short or (2500 if linear else 300)
+    args.long = args.long or (12000 if large else 6000)
+    args.short = args.short or (2500 if large else 300)
     if args.cpu_rehearsal:
         hf.update(doc["rehearsal"]["geometry"])
         srv.update(doc["rehearsal"]["serving"])
@@ -150,7 +189,7 @@ def main() -> int:
         srv["prefill_chunk"] = 64
         srv["prefill_buckets"] = [64]
         args.long, args.short, args.steps = 400, 40, 16
-        if linear:          # the short row too goes through chunks
+        if large:           # the short row too goes through chunks
             args.short = 150
     elif jax.default_backend() != "tpu":
         print("no TPU here: run it through the chip tool, or rehearse with "
@@ -181,9 +220,10 @@ def main() -> int:
     vocab = cfg.vocab_size
     rows = {0: list(rng.integers(8, vocab, size=args.long)),
             1: list(rng.integers(8, vocab, size=args.short))}
-    if linear:
-        # a third, brief row into a slot that served a tenant before: over
-        # 2500 tokens a stale state has decayed away, over 96 it has not
+    if large:
+        # a third, brief row (with linear layers: into a slot that served a
+        # tenant before: over 2500 tokens a stale state has decayed away,
+        # over 96 it has not)
         rows[2] = list(rng.integers(8, vocab,
                                     size=30 if args.cpu_rehearsal else 96))
     live = sorted(rows)
@@ -241,9 +281,12 @@ def main() -> int:
             note(1)
         elif n == len(starts) // 2:
             short_pos = 0       # too long for a bucket: chunks of its own
-        elif short_pos is None and n > len(starts) // 2 \
+        elif short_pos is None and len(starts) // 2 < n < len(starts) - 1 \
                 and short_steps < args.steps // 4:
-            decode([1])                 # beside the long row's prefill
+            # beside the long row's prefill, and not after its last chunk: a
+            # step in which row 0 idles writes an idle row's logits over the
+            # ones row 0 is about to pick its first token from
+            decode([1])
             short_steps += 1
         if short_pos is not None and short_pos >= 0:
             if chunked(1, short_ids, short_pos):
@@ -257,7 +300,7 @@ def main() -> int:
             short_pos = None
         else:
             short_pos += chunk
-    if linear:
+    if large:
         chunked(2, [int(t) for t in rows[2]], 0)
         note(2)
     # half of the steps one program a step, the other half inside the fused
@@ -298,7 +341,7 @@ def main() -> int:
     embed_host = np.asarray(params["embed"])
     params = dict(params, embed=params["embed"][:1])
     lm_head = params["lm_head"]
-    if linear:
+    if large:
         # the head stays int8 and is made float32 a slice of the vocabulary
         # at a time (head_of): 196608 columns in float32 are 3.2 GB
         params["lm_head"] = jnp.zeros((cfg.hidden_size, 1), jnp.float32)
@@ -307,7 +350,7 @@ def main() -> int:
     params.pop("lm_head", None)
 
     def head_of(cfg_v, hidden, precision):
-        if not linear:
+        if not large:
             return ref.head(rparams, cfg_v, hidden, precision=precision)
         with jax.default_matmul_precision(precision):
             if not isinstance(lm_head, dict):
@@ -322,7 +365,7 @@ def main() -> int:
         uniq, inv = np.unique(np.asarray(ids), return_inverse=True)
         return ref.hidden_states(
             dict(rparams, embed=embed_host[uniq].astype(np.float32)), cfg,
-            inv, block=128 if linear else 512, **kw)
+            inv, block=128 if large else 512, **kw)
 
     n_prompt = prompt_len
 
@@ -361,6 +404,12 @@ def main() -> int:
                 "top5_share": float(np.mean(rank < 5)),
                 "top5_share_in_loop": float(np.mean(rank[-in_loop:] < 5)),
                 "rank_max": int(rank.max()),
+                "rank_max_at_position": int(picked_at[int(np.argmax(rank))]),
+                # of the picks made from logits that were shown: the share
+                # that are those logits' largest (a greedy sampler's must be)
+                "picked_is_served_argmax": float(np.mean(
+                    [int(served[row][p].argmax()) == int(ids[p + 1])
+                     for p in at if p + 1 < len(ids)])),
                 "reference_seconds": time.monotonic() - t}
 
     in_loop = (args.steps - singles) // GROUP * GROUP
@@ -390,7 +439,34 @@ def main() -> int:
                     if rcfg.first_expert == 0
                     else rcfg.first_expert - held), "highest", (0, 1)),
             })
-        if window and ref.WINDOW in rcfg.layer_types:
+        if afmoe:
+            held, both = rcfg.num_experts, (ref.WINDOW, ref.FULL)
+            faults = {
+                "full_layers_rotated": dict(rotating=both),
+                "qk_norm_off": dict(qk_norm=False),
+                "output_gate_off": dict(attn_gate=False),
+                "softmax_for_sigmoid": dict(scoring="softmax"),
+                "bias_added_to_the_weights": dict(bias_in_weights=True),
+                "bias_left_out_of_the_choice": dict(bias_in_choice=False),
+                "route_scale_off": dict(route_scale=1.0),
+                "share_offset": dict(
+                    first_expert=rcfg.first_expert + held
+                    if rcfg.first_expert == 0
+                    else rcfg.first_expert - held),
+                "post_norms_off": dict(post_norms=False),
+                "embed_scale_off": dict(embed_scale=1.0),
+                "leading_layer_as_expert_layer": dict(leading_dense=False),
+            }
+            # a row no longer than the window cannot tell the mask; the
+            # others are read on the 2500-token row (a fifth of the time)
+            variants["fault_window_mask_off"] = (dataclasses.replace(
+                rcfg, sliding_window=1 << 30), "highest", (0,))
+            variants.update({
+                f"fault_{name}": (dataclasses.replace(rcfg, **over),
+                                  "highest",
+                                  (0, 1) if "rotated" in name else (1,))
+                for name, over in faults.items()})
+        elif window and ref.WINDOW in rcfg.layer_types:
             swapped = dict(rcfg.rope)
             swapped[ref.WINDOW] = rcfg.rope[ref.FULL]
             variants.update({
@@ -406,26 +482,70 @@ def main() -> int:
               "device": [dev.platform, dev.device_kind],
               "peak_bytes_in_use": peak, "steps_in_fused_loop": in_loop,
               "readings": {}}
+
+    def router_reading(cfg_v) -> dict:
+        """The router ALONE, served (models/llama._route as _moe_routed
+        calls it, over the first expert layer's router and bias) against
+        the reference's `route`, on 512 inputs of unit RMS: per token the
+        distance between the two rows of weights over the router's width,
+        over the reference's. A selection bias of N(0, 0.02^2) added to the
+        weights moves a token's weights by 2% and, through the eighth of
+        the experts held here, the logits by a tenth of what int8 KV and
+        bfloat16 do: no whole-path reading can see it, this one does."""
+        from localai_tpu.models.llama import _route
+
+        lp = {"moe_gate": params["layers"]["moe_gate"][0],
+              "moe_bias": params["layers"]["moe_bias"][0]}
+        x = jax.random.normal(jax.random.PRNGKey(args.seed),
+                              (512, cfg.hidden_size), jnp.float32)
+
+        def served_router(x, lp):
+            w, e = _route(x @ lp["moe_gate"].astype(jnp.float32), lp, cfg)
+            return e, w * cfg.routed_scale
+
+        def rows_of(e, w):
+            out = np.zeros((x.shape[0], lp["moe_gate"].shape[-1]), np.float32)
+            np.put_along_axis(out, np.asarray(e), np.asarray(w), axis=1)
+            return out
+
+        got = rows_of(*jax.jit(served_router)(x, lp))
+        with jax.default_matmul_precision("highest"):
+            want = rows_of(*ref.route(
+                x, {"router": lp["moe_gate"], "bias": lp["moe_bias"]}, cfg_v))
+        rel = (np.linalg.norm(got - want, axis=1)
+               / np.linalg.norm(want, axis=1))
+        return {"router_rel_median": float(np.median(rel)),
+                "router_choice_differs": float(np.mean(
+                    ((got != 0) != (want != 0)).any(1)))}
+
     for name, (cfg_v, precision, which) in variants.items():
         report["readings"][name] = {}
         for row in which:
             r = compare(row, cfg_v, precision,
                         stale if name == "fault_state_not_reset" else None)
+            if afmoe and precision == "highest":
+                r.update(router_reading(cfg_v))
             report["readings"][name][str(row)] = r
             say(f"{name} row {row}: {json.dumps(r)}")
-    ok = all(r["rel_median"] <= MEDIAN_REL and r["rel_max"] <= WORST_REL
+    median_rel = MEDIAN_REL_AFMOE if afmoe else MEDIAN_REL
+    ok = all(r["rel_median"] <= median_rel and r["rel_max"] <= WORST_REL
              and r["top1_share"] >= TOP1_SHARE
+             and r.get("router_rel_median", 0.0) <= ROUTER_REL
+             and r.get("router_choice_differs", 0.0) <= ROUTER_CHOICE
              for r in report["readings"]["sound"].values())
-    caught = {name: any(r["rel_median"] > MEDIAN_REL
+    caught = {name: any(r["rel_median"] > median_rel
                         or r["rel_max"] > WORST_REL
                         or r["top1_share"] < TOP1_SHARE
+                        or r.get("router_rel_median", 0.0) > ROUTER_REL
+                        or r.get("router_choice_differs", 0.0) > ROUTER_CHOICE
                         for r in rs.values())
               for name, rs in report["readings"].items()
               if name.startswith("fault_")}
     report["within_limits"] = ok
     report["faults_beyond_limits"] = caught
-    report["limits"] = {"median_rel": MEDIAN_REL, "worst_rel": WORST_REL,
-                        "top1_share": TOP1_SHARE}
+    report["limits"] = {"median_rel": median_rel, "worst_rel": WORST_REL,
+                        "top1_share": TOP1_SHARE, "router_rel": ROUTER_REL,
+                        "router_choice": ROUTER_CHOICE}
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "w") as f:
         json.dump(report, f, indent=1)
