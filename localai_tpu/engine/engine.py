@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from localai_tpu.models.kv import chunk_rows
 from localai_tpu.models.llama import (
     FULL,
     LINEAR,
@@ -639,6 +640,14 @@ class Engine:
             # dispatch's at consume. 0 for a model without experts
             "expert_tokens__routed": 0,
             "expert_tokens__dense": 0,
+            # once a prompt chunk (_extend_mid / _extend_final), for ONE
+            # full-attention layer: the rows of the slot's cache row the
+            # chunk's attention visits (a dense cache: the blocks up to the
+            # context the chunk has, kv.chunk_rows; a pool, a tier or a
+            # sequence axis: the whole row) and the row's capacity. Their
+            # ratio says how far the work follows the context
+            "chunk_ctx_tokens__attended": 0,
+            "chunk_ctx_tokens__capacity": 0,
             # cumulative ms the engine thread spent BLOCKED waiting for a
             # dispatch's results to land on the host (the async-fetch wait,
             # not the detok/stream fan-out) — per token this is the number
@@ -662,6 +671,19 @@ class Engine:
         if self._draft is not None:
             self.metrics["draft_proposed"] = 0
             self.metrics["draft_accepted"] = 0
+        # a full layer's cache row as a chunk's attention sees it
+        # (_credit_chunk_ctx): capacity, the view's window (kv.view), and
+        # whether it is read whole whatever the chunk's context (what
+        # kernel_tiers reports of the view)
+        if self._mixed:
+            t, window = max((k.shape[-2] for k, kind in zip(
+                self._kc.slots, cfg.cache_kinds) if kind == FULL),
+                default=0), None
+        else:
+            t = T if self._paged else self._kc.shape[-2]
+            window = cfg.sliding_window
+        self._full_row = (t, window, not t or self.kernel_tiers()[
+            "chunk_attention"] != "xla-blocks")
         if self._mixed:
             # two kinds of cache: how many layers and bytes (K and V) each
             # kind holds (gauges), and the context tokens ONE layer of each
@@ -1585,6 +1607,7 @@ class Engine:
 
     def _dev_extend_mid(self, buf, pos, idx, inject=None):
         self._credit_experts(np.size(buf), np.size(buf))
+        self._credit_chunk_ctx(pos)
         self._bcast("extend_mid", buf=buf, pos=pos, idx=idx,
                     inject=self._inj_msg(inject))
         with activate_mesh(self.mesh):
@@ -1596,6 +1619,7 @@ class Engine:
     def _dev_extend_final(self, buf, pos, nvalid, idx, row, counts_row,
                           inject=None):
         self._credit_experts(np.size(buf), nvalid)
+        self._credit_chunk_ctx(pos)
         self._bcast("extend_final", buf=buf, pos=pos, nvalid=nvalid, idx=idx,
                     row={k: np.asarray(v) for k, v in row.items()},
                     counts_row=counts_row, inject=self._inj_msg(inject))
@@ -3083,6 +3107,15 @@ class Engine:
             form = expert_form(self.cfg, call_tokens, self.mesh)
             self.metrics[f"expert_tokens__{form}"] += int(tokens) * (
                 self.cfg.num_layers - self.cfg.leading_dense_layers)
+
+    def _credit_chunk_ctx(self, pos: int):
+        """A chunk from position `pos` is dispatched: the rows of a full
+        layer's cache row its attention visits, and the row's capacity.
+        Host arithmetic, once a chunk."""
+        t, window, whole = self._full_row
+        self.metrics["chunk_ctx_tokens__attended"] += (
+            t if whole else chunk_rows(t, window, pos, self._chunk))
+        self.metrics["chunk_ctx_tokens__capacity"] += t
 
     def _credit_consumed(self, steps: int, entries=(), n_out=None):
         """One dispatch's results are on the host: credit it and the steps

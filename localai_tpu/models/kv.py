@@ -30,12 +30,15 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from localai_tpu.ops.attention import (
-    mha_decode, mha_decode_masked, mha_extend, mha_extend_tiered, mha_prefill,
-    mha_prefill_tiered,
+    block_span, mha_decode, mha_decode_masked, mha_extend, mha_extend_blocks,
+    mha_extend_tiered, mha_prefill, mha_prefill_tiered,
 )
-from localai_tpu.ops.kvcache import QuantKV, cache_scatter, dequant
+from localai_tpu.ops.kvcache import (
+    SCALE_TILE, QuantKV, cache_scatter, dequant,
+)
 from localai_tpu.ops.paged import (
     BLOCK, paged_view, resident_block_positions, resident_row_positions,
     ring_block_map,
@@ -43,6 +46,9 @@ from localai_tpu.ops.paged import (
 from localai_tpu.parallel.mesh import current_mesh, seq_axis_size
 
 FULL, WINDOW, LINEAR = "full", "window", "linear"   # LlamaConfig.layer_types
+# rows of a full-length dense cache a chunk's attention visits at a time
+# (DenseKV.attend_window): whole scale tiles, and it divides every served T
+CHUNK_BLOCK = 512
 
 
 @jax.tree_util.register_dataclass
@@ -237,6 +243,59 @@ class DenseKV(NoKV):
         idx = tuple(i for i in (self.layer, rows if gathered else None)
                     if i is not None)
         return (self.k[idx], self.v[idx]) if idx else (self.k, self.v)
+
+    def _block(self, a, rows, first, size):
+        """[B, KVH, size, D'] of a stack's array `a`: rows first .. first +
+        size of (layer, slot, :) for every slot, or for `rows`, in ONE slice
+        of the stack (slicing the layer out first copies it, PERF.md)."""
+        lead = () if self.layer is None else (self.layer,)
+
+        def cut(slot, n):
+            out = jax.lax.dynamic_slice(
+                a, (*lead, slot, 0, first, 0),
+                (*(1,) * len(lead), n, a.shape[-3], size, a.shape[-1]))
+            return out.reshape(out.shape[len(lead):])
+
+        if rows is None:
+            return cut(0, a.shape[-4])
+        return jax.vmap(lambda slot: cut(slot, 1)[0])(rows)
+
+    def attend_window(self, q, positions, start, rows, gathered):
+        """NoKV's, in work proportional to the context the chunk has and
+        not to the row's capacity: blocks of CHUNK_BLOCK rows, read and
+        dequantised where they lie, up to the one that holds the newest
+        position (ops/attention.mha_extend_blocks). A mesh with a sequence
+        axis keeps the reference."""
+        if seq_axis_size(current_mesh()) > 1:
+            return NoKV.attend_window(self, q, positions, start, rows,
+                                      gathered)
+        t = self.k.shape[-2]
+        block = min(CHUNK_BLOCK, t)
+        rows = rows if gathered else None
+
+        def fetch(first):
+            def of(c):
+                if not self.quant:
+                    return self._block(c, rows, first, block)
+                s = self._block(c.s, rows, first // SCALE_TILE,
+                                block // SCALE_TILE)
+                return dequant(QuantKV(self._block(c.q, rows, first, block),
+                                       s))
+            return of(self.k), of(self.v)
+
+        return mha_extend_blocks(q, fetch, self.k.shape[-3], t, positions,
+                                 start, block=block,
+                                 sliding_window=self.window)
+
+
+def chunk_rows(t: int, window, start: int, s: int) -> int:
+    """The rows of a T-row dense cache row DenseKV.attend_window visits for
+    a window of s tokens from `start`: the host's count (the engine's
+    chunk_ctx_tokens__attended), by the arithmetic the device loops by."""
+    block = min(CHUNK_BLOCK, t)
+    first, end = block_span(np.int64(start - window + 1 if window else 0),
+                            np.int64(start + s - 1), t, block)
+    return min(int(end - first) * block, t)
 
 
 @dataclasses.dataclass

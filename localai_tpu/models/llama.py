@@ -990,8 +990,12 @@ def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
         # masks it per slot on XLA
         "prefill_attention": "xla" if tiered else prefill,
         # later chunks of a long prompt and spec verify windows (extend)
-        # attend against the cache on XLA in every configuration
-        "chunk_attention": "xla",
+        # attend against the cache on XLA: a dense cache's full-length rows
+        # block by block up to the context the chunk has
+        # (kv.DenseKV.attend_window; a ring beside them whole), a pool's, a
+        # tier's and any row under a sequence axis whole (mha_extend)
+        "chunk_attention": "xla" if paged or tiered
+        or seq_axis_size(mesh) > 1 else "xla-blocks",
         # the tiered (ring-mapped) cache read has no kernel yet
         "decode_attention": "xla" if tiered else attn,
         "decode_kv_write": paged_kernel,

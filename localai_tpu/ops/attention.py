@@ -13,7 +13,10 @@ sharding layer can shard the head axis over the `model` mesh axis):
 Softmax is computed in float32; matmuls stay in the input dtype (bf16).
 These XLA versions are the semantic reference and the CPU-mesh test path;
 Pallas TPU kernels (when present under localai_tpu/ops/pallas/) are selected
-by the engine on TPU and validated against these in tests.
+by the engine on TPU and validated against these in tests. One is served as
+it stands: `mha_extend_blocks`, a prompt chunk's attention over a dense cache
+in blocks up to the context it has (models/kv.py: DenseKV.attend_window),
+held to `mha_extend` in tests/test_chunk_attention.py.
 """
 from __future__ import annotations
 
@@ -89,6 +92,73 @@ def mha_extend(q, k_cache, v_cache, q_positions, *, scale=None,
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     out = jnp.einsum("bkgst,bktd->bskgd", probs, v_cache)
     return out.reshape(b, s, h, d)
+
+
+def block_span(lo_pos, hi_pos, t: int, block: int):
+    """The blocks of `block` rows a window of queries visits in a row of T:
+    [first, end) holds every position from lo_pos (the oldest any query
+    sees) to hi_pos (the newest). NumPy or traced scalars (both clip): the
+    engine counts with the arithmetic the device loops by."""
+    blocks = -(-t // block)
+    return (lo_pos // block).clip(0, blocks - 1), \
+        (hi_pos // block + 1).clip(1, blocks)
+
+
+def mha_extend_blocks(q, fetch, num_kv_heads, t, q_positions, start, *,
+                      block, scale=None, sliding_window=None):
+    """mha_extend in work proportional to the context the window HAS: K and
+    V are visited `block` rows at a time, from the block that holds the
+    oldest position a query can see to the one that holds the newest
+    (max(start) + S - 1), under a running maximum and sum (online softmax).
+    No [S, T] score array exists and a block past the bound is never read;
+    the trip count is a traced value, so one program serves every `start`.
+
+    fetch(first) -> (k, v) [B, KVH, block, D]: rows first .. first + block
+    of every query row's cache, dequantised; t: the cache's length T. Where
+    `block` does not divide T the last block is moved back inside and the
+    rows it shares with the one before are masked. Same mask and float32
+    statistics as mha_extend, to which it agrees to the rounding of the
+    products' dtype. A row past its query row's newest position weighs 0
+    and its V is not multiplied (what an earlier tenant left there may not
+    be finite). Returns [B, S, H, D]."""
+    b, s, h, d = q.shape
+    scale = scale if scale is not None else d ** -0.5
+    windowed = sliding_window is not None and sliding_window > 0
+    qg = _group_query_heads(q, num_kv_heads).transpose(0, 2, 3, 1, 4)
+    newest = start + s - 1                                      # [B]
+    oldest = jnp.min(start) - sliding_window + 1 if windowed else jnp.int32(0)
+    lo, hi = block_span(oldest, jnp.max(newest), t, block)
+
+    def visit(j, carry):
+        m, l, acc = carry                   # [B,KVH,G,S] x 2, [B,KVH,G,S,D]
+        first = jnp.minimum(j * block, t - block)
+        k, v = fetch(first)
+        pos = first + jnp.arange(block)
+        logits = jnp.einsum("bkgsd,bktd->bkgst", qg, k,
+                            preferred_element_type=jnp.float32) * scale
+        mask = ((pos >= j * block)[None, None, :]
+                & (pos[None, None, :] <= q_positions[:, :, None]))  # [B,S,t]
+        if windowed:
+            mask = mask & (pos[None, None, :]
+                           > q_positions[:, :, None] - sliding_window)
+        mask = mask[:, None, None, :, :]
+        logits = jnp.where(mask, logits, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(logits, axis=-1))
+        p = jnp.where(mask, jnp.exp(logits - m_new[..., None]), 0.0)
+        fade = jnp.exp(m - m_new)
+        v = jnp.where((pos[None, :] <= newest[:, None])[:, None, :, None],
+                      v, 0)
+        acc = fade[..., None] * acc + jnp.einsum(
+            "bkgst,bktd->bkgsd", p.astype(q.dtype), v,
+            preferred_element_type=jnp.float32)
+        return m_new, fade * l + jnp.sum(p, axis=-1), acc
+
+    _, l, acc = jax.lax.fori_loop(lo, hi, visit, (
+        jnp.full(qg.shape[:-1], NEG_INF, jnp.float32),
+        jnp.zeros(qg.shape[:-1], jnp.float32),
+        jnp.zeros(qg.shape, jnp.float32)))
+    out = acc / jnp.where(l > 0, l, 1.0)[..., None]
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d).astype(q.dtype)
 
 
 def mha_prefill_tiered(q, k, v, lengths, sinks, window, *, scale=None,

@@ -59,6 +59,12 @@ try:
         "localai_expert_tokens_total",
         "Tokens x MoE layers by the expert layer's form (routed or dense)",
         ["model", "form"])
+    # rows of a full layer's cache row a prompt chunk's attention visited,
+    # and the row's capacity (engine chunk_ctx_tokens__attended / __capacity)
+    _CHUNK_CTX = Counter(
+        "localai_chunk_ctx_tokens_total",
+        "Cache rows a prompt chunk's attention visited (attended) and the "
+        "row's capacity, a full-attention layer a chunk", ["model", "rows"])
     # streams open against the model's backend now (the gate's count)
     _STREAMS_OPEN = Gauge("localai_streams_open",
                           "Streams open against the model's backend",
@@ -985,6 +991,10 @@ class API:
                     continue
                 if key.startswith("expert_tokens__"):
                     _counter_sync(_EXPERT_TOKENS,
+                                  (name, key.split("__", 1)[1]), float(v))
+                    continue
+                if key.startswith("chunk_ctx_tokens__"):
+                    _counter_sync(_CHUNK_CTX,
                                   (name, key.split("__", 1)[1]), float(v))
                     continue
                 for kind in ("host", "wait"):

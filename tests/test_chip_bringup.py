@@ -252,8 +252,11 @@ def test_kernel_tiers_name_the_interpreter(monkeypatch):
     assert t["prefill_attention"] == t["decode_attention"] \
         == t["decode_kv_write"] == t["ragged_attention"] == "pallas-interpret"
     assert t["chunk_attention"] == "xla"
-    # dense KV has no paged write; the tiered read has no kernel yet
-    assert kernel_tiers(cfg, None, paged=False)["decode_kv_write"] == "xla"
+    # dense KV has no paged write, and a chunk attends over the blocks its
+    # context fills; the tiered read has no kernel yet
+    dense = kernel_tiers(cfg, None, paged=False)
+    assert dense["decode_kv_write"] == "xla"
+    assert dense["chunk_attention"] == "xla-blocks"
     assert kernel_tiers(cfg, None, paged=True,
                         tiered=True)["decode_attention"] == "xla"
 

@@ -10,7 +10,9 @@ and compared with the hash the same code gave on the parent commit (8aabf74;
 `python tests/test_served_programs_unchanged.py` prints the table, run with
 PYTHONPATH at a checkout). A kernel's compile-cache key, and with it the old
 cells' `setup_s`, rides on these programs: a change that has to move one
-replaces its hash here and says so in PERF.md.
+replaces its hash here and says so in PERF.md. PR 36 moved the six `extend`
+hashes on purpose (a chunk's attention visits the blocks its context fills,
+kv.DenseKV.attend_window); the other eighteen are 8aabf74's still.
 
 The hashes are of this container's JAX (0.9.0); another version prints
 other jaxprs, and the table is then made again on both commits.
@@ -68,13 +70,13 @@ PARENT = {
     "mixtral": {
         "xla": {
             "_admit_many_fn": "fbfb20269f039a48",
-            "_extend_mid_fn": "3459fbd32e02d7e9",
+            "_extend_mid_fn": "187c8419e7c14645",
             "_decode_nomask_fn": "e3cec072d822b9bd",
             "_decode_loop_fn": "8a860900cd45289a",
         },
         "pallas": {
             "_admit_many_fn": "8f4729f85dc0ea26",
-            "_extend_mid_fn": "2b01d3486cd1471d",
+            "_extend_mid_fn": "0d164d65a1d721f4",
             "_decode_nomask_fn": "2f0cbca457f87e88",
             "_decode_loop_fn": "638f5517a3e82122",
         },
@@ -82,13 +84,13 @@ PARENT = {
     "mellum2": {
         "xla": {
             "_admit_many_fn": "893396ee4f39a1a6",
-            "_extend_mid_fn": "4379ddccef7b3e90",
+            "_extend_mid_fn": "497555f18f24d9d7",
             "_decode_nomask_fn": "ecbe0c77ee57a8d4",
             "_decode_loop_fn": "ba6b6e51f0014472",
         },
         "pallas": {
             "_admit_many_fn": "f28fb2fdb76012f2",
-            "_extend_mid_fn": "847378bead3f36f5",
+            "_extend_mid_fn": "087969ebba11f2fc",
             "_decode_nomask_fn": "b65452d32874300b",
             "_decode_loop_fn": "20075d0627bfddc4",
         },
@@ -96,13 +98,13 @@ PARENT = {
     "solar-open2": {
         "xla": {
             "_admit_many_fn": "dfd70030e9cb5d93",
-            "_extend_mid_fn": "5a474de416f5627c",
+            "_extend_mid_fn": "9505c659ffe878f2",
             "_decode_nomask_fn": "ee68658b5c841909",
             "_decode_loop_fn": "8f3773a8464a0440",
         },
         "pallas": {
             "_admit_many_fn": "7983ec38f42576db",
-            "_extend_mid_fn": "1a8361e3a43cde2b",
+            "_extend_mid_fn": "f82f638d423e1b4a",
             "_decode_nomask_fn": "3e502a281b398fa4",
             "_decode_loop_fn": "aff8bda03d3e95e8",
         },

@@ -364,7 +364,8 @@ def test_gate_wait_and_counters_in_backend_monitor_and_metrics(traced_stack):
               "xla_compile_ms_total", "engine_host_ms__dispatch",
               "engine_host_ms__admit", "engine_host_ms__emit",
               "engine_host_ms__kv", "engine_wait_ms__device",
-              "engine_wait_ms__idle"):
+              "engine_wait_ms__idle", "chunk_ctx_tokens__attended",
+              "chunk_ctx_tokens__capacity"):
         assert k in m, k
     assert m["requests_admitted"] >= 1 and m["xla_compiles_total"] >= 1
     assert any(k.startswith("xla_compiles__") for k in m)
@@ -378,6 +379,8 @@ def test_gate_wait_and_counters_in_backend_monitor_and_metrics(traced_stack):
     assert 'localai_engine_phase_seconds_total{kind="wait",model="tiny",' \
            'phase="device"}' in prom
     assert 'localai_xla_compiles_total{model="tiny"}' in prom
+    assert 'localai_chunk_ctx_tokens_total{model="tiny",rows="capacity"}' \
+        in prom
     assert "localai_engine_stage_" not in prom
 
     # /backend/monitor's two RPCs leave a ring span on each side: what the

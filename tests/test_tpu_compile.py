@@ -4,7 +4,8 @@ runs): what the interpreter cannot say, whether Mosaic takes the shapes. The
 decode attention kernel at 48 query heads over 8 KV heads (a group of 6, the
 first that is no power of two) over a ring of 4096 + 512 tokens and over a
 16384-token cache, int8; the routed expert layer at 3072 x 3072, 32 held of
-256, top-4, for a chunk and for a decode step's rows. The topology is
+256, top-4, for a chunk and for a decode step's rows; a chunk's attention
+over the long-document cell's cache row (XLA: what it holds). The topology is
 described inside a fixture (one process at a time may load the TPU's
 library, and a worker imports every test file)."""
 import os
@@ -92,3 +93,36 @@ def test_routed_share_at_the_cells_widths(one_chip, mosaic, rows):
             cfg),
         shape((*rows, h), jnp.bfloat16), rest, stacks)
     assert out.output_shardings is not None
+
+
+def test_a_chunks_attention_holds_no_score_array_of_the_whole_row(one_chip):
+    """The long-document cell's chunk (512 queries of 64 heads over 8 KV
+    heads, one gathered row of an int8 stack of 32 x 16384) compiled as it
+    is served: the blockwise loop's temporaries are a few blocks' worth,
+    where the whole-row form holds the scores [8, 8, 512, 16384] (1.1 GB
+    in bfloat16) and a dequantised row."""
+    from localai_tpu.models import kv
+    from localai_tpu.ops.kvcache import QuantKV
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    layers, b, kvh, group, t, d, s = 2, 32, 8, 8, 16384, 128, 512
+    stack = QuantKV(shape((layers, b, kvh, t, d), jnp.int8),
+                    shape((layers, b, kvh, t // 128, 128), jnp.float32))
+
+    def attend(form):
+        def call(q, k, v, start, rows, layer):
+            view = kv.DenseKV(k, v, None, layer=layer)
+            positions = start[:, None] + jnp.arange(s)[None, :]
+            return form(view, q, positions, start, rows, True)
+
+        return _compile(
+            call, shape((1, s, kvh * group, d), jnp.bfloat16), stack, stack,
+            shape((1,), jnp.int32), shape((1,), jnp.int32),
+            shape((), jnp.int32)).memory_analysis().temp_size_in_bytes
+
+    scores = kvh * group * s * t * 2        # as XLA keeps them: bfloat16
+    assert attend(kv.NoKV.attend_window) > scores
+    served = attend(kv.DenseKV.attend_window)
+    assert served < scores // 16, served

@@ -213,6 +213,42 @@ def test_ragged_decode_at_the_cells_shapes(shape, quant):
     _close(got[active], np.asarray(want, np.float32)[active])
 
 
+@pytest.mark.parametrize("quant", [True, False], ids=["q8", "bf16"])
+@pytest.mark.parametrize("shape", ["mixtral", "mellum2-full", "trinity-full"])
+def test_chunk_attention_at_the_cells_shapes(shape, quant):
+    """A 512-token chunk of one gathered row over the cell's full-length
+    stack (XLA, PR 36): the blocks up to the context the chunk has against
+    the whole row, rows past it NaN (an int8 row's scale)."""
+    from localai_tpu.models import kv
+    from localai_tpu.ops.kvcache import QuantKV
+
+    KVH, G, T, _, _ = CELL_SHAPES[shape]
+    B, D, L, S = 4, 128, 2, 512
+    q = _bf16(50, (1, S, KVH * G, D))
+    rows = jnp.array([B - 1])
+    past = jnp.arange(T)
+    for at in (0, T // 2 - 100, T - S):
+        start = jnp.array([at], jnp.int32)
+        dead = past >= at + S
+        if quant:
+            k, v = _quant(51, (L, B, KVH, T, D)), _quant(52, (L, B, KVH, T, D))
+            bad = [QuantKV(c.q, jnp.where(dead.reshape(T // 128, 128),
+                                          jnp.nan, c.s)) for c in (k, v)]
+        else:
+            k, v = _bf16(51, (L, B, KVH, T, D)), _bf16(52, (L, B, KVH, T, D))
+            bad = [jnp.where(dead[:, None], jnp.nan, c) for c in (k, v)]
+
+        def attend(form, k, v):
+            return jax.jit(lambda k, v, q, start: form(
+                kv.DenseKV(k, v, None, layer=jnp.int32(1)), q,
+                start[:, None] + jnp.arange(S)[None, :], start, rows,
+                True))(k, v, q, start)
+
+        got = np.asarray(attend(kv.DenseKV.attend_window, *bad), np.float32)
+        assert np.isfinite(got).all()
+        _close(got, attend(kv.NoKV.attend_window, k, v))
+
+
 @pytest.mark.parametrize("H,KVH,D", GEOMS)
 def test_ragged_decode_q8_paged(H, KVH, D):
     from localai_tpu.ops.attention import mha_decode
@@ -614,6 +650,8 @@ def test_engine_runs_on_tpu(H, KVH, D, kind):
         prefill_chunk=64, decode_block=8, **ENGINES[kind]))
     tiers = eng.kernel_tiers()
     assert tiers["prefill_attention"] == tiers["decode_attention"] == "pallas"
+    assert tiers["chunk_attention"] == (
+        "xla" if "kv_pages" in ENGINES[kind] else "xla-blocks")
     if "kv_pages" in ENGINES[kind]:
         assert tiers["decode_kv_write"] == "pallas"
     if kind.startswith("ragged"):
