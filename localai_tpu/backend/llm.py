@@ -51,6 +51,28 @@ def _request_id(context) -> str:
     return _metadata(context, REQUEST_ID_KEY)
 
 
+def _finish_to_reply(o, trace_id: str) -> None:
+    """gRPC has taken a stream's final reply: observe the time since the
+    engine's finish decision (`StepOutput.finished_t`, where hist_e2e ends)
+    as `hist_finish_to_reply__<decode path>`, once a request that ran to its
+    end. A request the engine did not finish in _emit (refused, dead on
+    arrival, failed) has no such time; one it ended for a client that had
+    gone, or for a preemption, would time a reply to nobody."""
+    if o.finished_t is None or o.finish_reason in ("cancelled", "preempted"):
+        return
+    slo = telemetry.maybe_slo()
+    if slo is None:
+        return
+    took = time.monotonic() - o.finished_t
+    slo.observe("finish_to_reply", (o.timings or {}).get("path", "all"), took)
+    tr = telemetry.maybe_tracer()
+    if tr is not None:
+        tr.add_complete("engine.stage.finish_to_reply", o.finished_t, took,
+                        cat="engine",
+                        args={"request_id": trace_id
+                              or f"rid-{o.request_id}"})
+
+
 class LLMServicer(BackendServicer):
     def __init__(self, preloaded=None):
         """`preloaded=(engine, cfg, tok, name)` serves an engine built by the
@@ -608,6 +630,9 @@ class LLMServicer(BackendServicer):
                     resume_json=resume_json,
                 )
                 if o.finished:
+                    # control is back here once gRPC has taken the reply; a
+                    # stream the client cut is closed at the yield instead
+                    _finish_to_reply(o, trace_id)
                     return
                 if emitted and pre_grace is not None:
                     import signal
@@ -721,7 +746,7 @@ class LLMServicer(BackendServicer):
 
     def _get_metrics(self):
         """Host-side counters only: nothing here touches the device."""
-        m = dict(self.engine.metrics) if self.engine else {}
+        m = self.engine.metrics_snapshot() if self.engine else {}
         # XLA compiles of this process (xla_compiles_total,
         # xla_compile_ms_total, xla_compiles__<jit name>): there from the
         # first scrape, at whatever the load has compiled so far

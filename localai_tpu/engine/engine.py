@@ -270,6 +270,11 @@ class StepOutput:
                                        # terminal "preempted" chunk — the
                                        # spill-drain's checkpoint of this
                                        # request (ISSUE 19); None otherwise
+    finished_t: float | None = None    # time.monotonic() of the host's finish
+                                       # decision in _emit (where hist_e2e
+                                       # ends), on the FINAL chunk of a
+                                       # request the engine finished; the
+                                       # gRPC handler times its reply from it
 
 
 @dataclasses.dataclass
@@ -634,6 +639,21 @@ class Engine:
             "decode_dispatches_consumed": 0,
             "decode_steps_consumed": 0,
             "requests_admitted": 0,
+            # rows x steps, tiled (_credit_consumed): the max_slots rows of
+            # every consumed decode dispatch, each put down to one state for
+            # each step the device ran. live: a token _emit took. spent: an
+            # active row's step that gave none (after its last token inside
+            # a block or loop, a whole pipelined dispatch after the finish,
+            # a cancelled or rolled-back row, a rejected draft). prefill:
+            # the slot held a request that was not prefilled at dispatch.
+            # free_queued / free_starved: an empty slot, by whether the
+            # engine had a request to admit at dispatch (_queue, _deferred).
+            # Their sum is max_slots x decode_steps_consumed, exactly
+            "decode_row_steps__live": 0,
+            "decode_row_steps__spent": 0,
+            "decode_row_steps__prefill": 0,
+            "decode_row_steps__free_queued": 0,
+            "decode_row_steps__free_starved": 0,
             # tokens x MoE layers, by the form the expert layer takes for
             # the call's shape (models/llama.expert_form, what _mlp itself
             # evaluates): a prompt's tokens at dispatch, a decode
@@ -668,6 +688,10 @@ class Engine:
             "resume_readmits": 0,
             "resume_reprefills": 0,
         }
+        self._live_from = 0   # tokens_generated at the last _credit_consumed
+        # odd while a consumed dispatch's tokens are being emitted (between
+        # _credit_consumed and _credit_live): metrics_snapshot waits it out
+        self._consume_seq = 0
         if self._draft is not None:
             self.metrics["draft_proposed"] = 0
             self.metrics["draft_accepted"] = 0
@@ -2912,6 +2936,40 @@ class Engine:
         return np.array([s is not None and s.prefilled for s in self._slots],
                         bool)
 
+    def _row_states(self) -> tuple:
+        """The max_slots rows as they stand now: (active, prefill,
+        free_queued, free_starved). A slot that holds a prefilled request is
+        active, one that holds a request mid-prefill (in _prefillq, or
+        admitted and awaiting _flush_admits) is in prefill, and the empty
+        ones are free: all queued if the engine has a request it could admit
+        (_queue, _deferred), all starved if it has none."""
+        active = prefill = 0
+        for s in self._slots:
+            if s is not None:
+                if s.prefilled:
+                    active += 1
+                else:
+                    prefill += 1
+        free = len(self._slots) - active - prefill
+        if self._deferred is not None or not self._queue.empty():
+            return active, prefill, free, 0
+        return active, prefill, 0, free
+
+    def _rows_at_dispatch(self) -> tuple:
+        """_row_states as a decode dispatch is enqueued: carried in `pend`
+        to _credit_consumed, which multiplies it by the steps the device
+        ran, noted in the tick ledger's record of the dispatch and, while
+        GET /debug/xprof traces, on the tick's next annotation."""
+        rows = self._row_states()
+        if self._sched is not None:
+            self._sched.rows(*rows)
+        if self._phases.tracing():
+            active, prefill, queued, starved = rows
+            self._phases.note(rows_active=active, rows_prefill=prefill,
+                              rows_free=queued + starved,
+                              queued=self._queue.qsize())
+        return rows
+
     def _block_steps(self) -> int:
         """How many decode steps the next dispatch may fuse. 1 whenever a
         per-token host decision is live: pending admissions or chunked
@@ -3024,6 +3082,7 @@ class Engine:
             # invariant bench.py's dense_fallback_reasons relies on)
             self._sched.reason("loop_native")
         gstate = self._gstate.copy() if self._grammar_slots > 0 else None
+        rows = self._rows_at_dispatch()
         if self._ragged_loop_fn is not None:
             # ragged engines with the fused loop: pure-decode dispatches
             # ride the pack-free ragged-loop variant — same stop semantics
@@ -3032,16 +3091,17 @@ class Engine:
             # loop; G above already capped reservations at its step budget)
             fetch = self._dev_rloop_decode(active, remaining, check_eos,
                                            fast, gstate=gstate)
-            return ("rloop", fetch, live, res)
+            return ("rloop", fetch, live, res, rows)
         fetch = self._dev_decode_loop(active, remaining, check_eos, fast,
                                       gstate=gstate)
-        return ("loop", fetch, live, res)
+        return ("loop", fetch, live, res, rows)
 
     def _dispatch(self):
         """Dispatch one decode step, a fused scan block, or a single-dispatch
         while loop for the currently-active slots; returns a tagged pend
-        ("loop"|"block", async fetch, [(slot_idx, request_id)], ...) without
-        waiting for the device — or None if nothing can run."""
+        ("loop"|"block", async fetch, [(slot_idx, request_id)], ..., the
+        rows' states at this moment) without waiting for the device — or
+        None if nothing can run."""
         active = self._active_mask()
         if not active.any():
             return None
@@ -3081,11 +3141,12 @@ class Engine:
             res[i] = steps
             self._slots[i].inflight += steps
         self._mark_join(entries)
+        rows = self._rows_at_dispatch()
         if steps > 1:
             fetch = self._dev_decode_block(active, steps, fast, gmask)
         else:
             fetch = self._dev_decode(active, gmask, fast)
-        return ("block", fetch, entries, gmask, res)
+        return ("block", fetch, entries, gmask, res, rows)
 
     def _await(self, fetch):
         """Block for a dispatch's results: phase `device` for the wait (the
@@ -3117,17 +3178,29 @@ class Engine:
             t if whole else chunk_rows(t, window, pos, self._chunk))
         self.metrics["chunk_ctx_tokens__capacity"] += t
 
-    def _credit_consumed(self, steps: int, entries=(), n_out=None):
+    def _credit_consumed(self, steps: int, entries=(), n_out=None,
+                         rows=None):
         """One dispatch's results are on the host: credit it and the steps
-        the device ran in it, together. For a model with window and full
-        layers also the context its live rows (`entries`, each with
+        the device ran in it, together, and its max_slots rows x those steps
+        by the state each row was in when the dispatch was enqueued (`rows`,
+        _rows_at_dispatch; the active rows all as `spent` until _credit_live
+        moves what _emit takes of them to `live`). For a model with window
+        and full layers also the context its live rows (`entries`, each with
         `n_out[i]` steps, or all `steps`) attended over, in one layer of each
         kind: a row that stood at n tokens attends n + 1, n + 2, ... in a
         full layer and min(that, window) in a window layer. Call it BEFORE
         the dispatch's tokens are emitted (`generated` is then what it was
-        at dispatch)."""
-        self.metrics["decode_dispatches_consumed"] += 1
-        self.metrics["decode_steps_consumed"] += steps
+        at dispatch), and _credit_live after them."""
+        m = self.metrics
+        m["decode_dispatches_consumed"] += 1
+        m["decode_steps_consumed"] += steps
+        active, prefill, queued, starved = rows or self._row_states()
+        m["decode_row_steps__spent"] += active * steps
+        m["decode_row_steps__prefill"] += prefill * steps
+        m["decode_row_steps__free_queued"] += queued * steps
+        m["decode_row_steps__free_starved"] += starved * steps
+        self._live_from = m["tokens_generated"]
+        self._consume_seq |= 1
         if self.cfg.num_experts:
             # a decode step is max_slots rows of one token
             self._credit_experts(self.ec.max_slots, sum(
@@ -3150,6 +3223,32 @@ class Engine:
             win += k * lo + k * (k + 1) // 2 + (n - k) * window
         self.metrics["decode_ctx_tokens__full"] += full
         self.metrics["decode_ctx_tokens__window"] += win
+
+    def _credit_live(self):
+        """The consumed dispatch's tokens are emitted: what _emit took since
+        _credit_consumed were steps of active rows that gave a token."""
+        m = self.metrics
+        took = m["tokens_generated"] - self._live_from
+        self._live_from += took
+        m["decode_row_steps__live"] += took
+        m["decode_row_steps__spent"] -= took
+        self._consume_seq += self._consume_seq & 1
+
+    def metrics_snapshot(self, patience_s: float = 0.25) -> dict:
+        """A copy of `metrics` for a scrape, from any thread. It is taken
+        between two consumes where it can be: while a dispatch's tokens are
+        emitted `tokens_generated` runs ahead of `decode_row_steps__live`,
+        so a copy from inside that stretch (a few ms of each dispatch) is
+        retaken, for up to `patience_s`; after that, or if a failed tick
+        left the stretch open, the copy is served as it is."""
+        deadline = time.monotonic() + patience_s
+        while True:
+            seq = self._consume_seq
+            snap = dict(self.metrics)
+            if (not seq & 1 and seq == self._consume_seq) \
+                    or time.monotonic() >= deadline:
+                return snap
+            time.sleep(0.001)
 
     def _credit_cache_bytes(self, steps: int, entries, n_out):
         """_credit_consumed for a model with linear-attention layers, in
@@ -3225,7 +3324,7 @@ class Engine:
         re-derives every finish decision in _emit — cancel/deadline can
         terminate a slot mid-buffer, and the rest of its tokens are dropped
         by the request-id check exactly as on the block path."""
-        tag, fetch, entries, res = pend
+        tag, fetch, entries, res, rows = pend
         out = self._await(fetch)
         if tag == "rloop":
             # fused ragged loop (pack-free variant): the fetch carries the
@@ -3237,7 +3336,7 @@ class Engine:
             tokens, logprobs, n_out, steps = out
         steps = int(steps)
         self.metrics["decode_steps_dispatched"] += steps
-        self._credit_consumed(steps, entries, n_out)
+        self._credit_consumed(steps, entries, n_out, rows)
         self._release_reservations(entries, res)
         now = time.monotonic()
         if self._slo is not None:
@@ -3255,6 +3354,7 @@ class Engine:
                 self._emit(i, slot, int(tokens[g, i]),
                            float(logprobs[g, i]), now,
                            path="rloop" if tag == "rloop" else "loop")
+        self._credit_live()
 
     def _consume(self, pend):
         """Block on a dispatched step's results and run the host-side token
@@ -3266,14 +3366,14 @@ class Engine:
         if pend[0] in ("loop", "rloop"):
             self._consume_loop(pend)
             return
-        _, fetch, entries, gmask, res = pend
+        _, fetch, entries, gmask, res, rows = pend
         tokens, logprobs = self._await(fetch)
         self._release_reservations(entries, res)
         now = time.monotonic()
         if tokens.ndim == 1:
             tokens, logprobs = tokens[None], logprobs[None]
         steps = tokens.shape[0]
-        self._credit_consumed(steps, entries)
+        self._credit_consumed(steps, entries, rows=rows)
         if self._slo is not None:
             for i, rid in entries:
                 s = self._slots[i]
@@ -3301,6 +3401,7 @@ class Engine:
                         and slot.matcher is not None
                         and np.any(self._mask_host[i] & ~gmask[i])):
                     rolled.append(i)
+        self._credit_live()
         for i in rolled:
             slot = self._slots[i]
             if slot is not None:
@@ -3342,12 +3443,13 @@ class Engine:
             entries = [(int(i), self._slots[i].request_id)
                        for i in np.where(active)[0]]
             self._mark_join(entries)
+            rows = self._rows_at_dispatch()
             pend = self._dev_spec_decode(active)
             self._admit_phase()    # admission overlaps the device step
             tokens_out, n_out, logprobs_out, n_extra = self._await(pend)
             now = time.monotonic()
             G = self.ec.gamma
-            self._credit_consumed(G + 1)
+            self._credit_consumed(G + 1, rows=rows)
             for i, rid in entries:
                 slot = self._slots[i]
                 if slot is None or slot.request_id != rid:
@@ -3362,6 +3464,7 @@ class Engine:
                         break  # finished mid-window (EOS/length/stop)
                     self._emit(i, slot, int(tokens_out[i, j]),
                                float(logprobs_out[i, j]), now, path="spec")
+            self._credit_live()
         else:
             self._admit_phase()
         return (any(s is not None for s in self._slots)
@@ -3477,6 +3580,7 @@ class Engine:
                     inject=(None if inj_extra is None
                             else (inj_extra, inj_mask)))
         self._mark_join(entries)
+        rows = self._rows_at_dispatch()
         fetch = self._dev_spec_ragged(pack)
         # chunk bookkeeping overlaps the device step; the draft ingests each
         # chunk's token ids through its own (tiny) prefill program
@@ -3500,7 +3604,7 @@ class Engine:
                 s.path = "ragged"
         tokens_out, n_out, logprobs_out, n_extra = self._await(fetch)
         now = time.monotonic()
-        self._credit_consumed(G + 1)
+        self._credit_consumed(G + 1, rows=rows)
         for i, rid in entries:
             slot = self._slots[i]
             if slot is None or slot.request_id != rid:
@@ -3515,6 +3619,7 @@ class Engine:
                     break  # finished mid-window (EOS/length/stop)
                 self._emit(i, slot, int(tokens_out[i, j]),
                            float(logprobs_out[i, j]), now, path="spec")
+        self._credit_live()
 
     # ------------------------------------------------------ ragged scheduling
 
@@ -3673,6 +3778,7 @@ class Engine:
         use_loop = (self._ragged_loop_fn is not None and bool(entries)
                     and inj_extra is None and not arbitration)
         self._mark_join(entries)
+        rows = self._rows_at_dispatch()
         if use_loop:
             remaining = np.zeros((B,), np.int32)
             check_eos = np.zeros((B,), bool)
@@ -3721,7 +3827,7 @@ class Engine:
             self._release_reservations(entries, res)
         else:
             tokens_out, logprobs = self._await(fetch)
-        self._credit_consumed(steps)
+        self._credit_consumed(steps, rows=rows)
         now = time.monotonic()
         if self._slo is not None:
             # dispatch attribution: every slot packed into this ragged tick
@@ -3755,6 +3861,7 @@ class Engine:
                     continue
                 self._emit(i, s, int(tokens_out[i]), float(logprobs[i]),
                            now, path="ragged")
+        self._credit_live()
 
     def _kv_tick(self):
         """Advance the hot→cold→evicted lifecycle for windowed slots.
@@ -4097,7 +4204,7 @@ class Engine:
             request_id=slot.request_id, text=emit_text, token_id=token_id,
             logprob=logprob, finished=finish is not None, finish_reason=finish,
             generated_tokens=slot.generated, prompt_tokens=slot.prompt_len,
-            timings=timings,
+            timings=timings, finished_t=None if finish is None else now,
         ))
         if finish is not None:
             dur = now - slot.start_time

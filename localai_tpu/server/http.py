@@ -247,18 +247,59 @@ class _AdmissionGate:
         # both written on the loop's thread only
         self.start_hist = telemetry.Hist()
         self.streams_open = 0
+        # a permit's tail and its whole life, one observation each per
+        # stream that ran to the backend's finished reply (release):
+        # hist_reply_to_release__all__*, hist_permit_hold__all__*
+        self.tail_hist = telemetry.Hist()
+        self.hold_hist = telemetry.Hist()
+
+    def release(self, permit: "_Permit", model: str) -> None:
+        """Hand the permit back. If its stream ran to the backend's finished
+        reply, observe the tail (the pump thread read the stream's end ->
+        now: the queue to the loop, SSE's last chunks and `[DONE]`) and the
+        permit's whole life (granted -> now). A stream that was cancelled or
+        cut, and a request that streamed nothing, observe neither: a permit
+        held for a client that has gone is no measure of a request's life.
+        On the loop's thread, like every write to the gate."""
+        self.sem.release()
+        if permit.ended is None:
+            return
+        now = time.monotonic()
+        self.tail_hist.observe(now - permit.ended)
+        self.hold_hist.observe(now - permit.at)
+        tr = telemetry.maybe_tracer()
+        if tr is not None:
+            args = {"model": model,
+                    "request_id": telemetry.current_request_id()}
+            tr.add_complete("http.stage.reply_to_release", permit.ended,
+                            now - permit.ended, cat="http", args=args)
+            tr.add_complete("http.stage.permit_hold", permit.at,
+                            now - permit.at, cat="http", args=args)
 
     def metrics(self) -> dict:
         """This process's share of the model's metrics, under the flat keys
         the backend's GetMetrics uses."""
         return {**self.wait_hist.flat("gate_wait"),
                 **self.start_hist.flat("stream_start"),
+                **self.tail_hist.flat("reply_to_release"),
+                **self.hold_hist.flat("permit_hold"),
                 "streams_open": float(self.streams_open)}
 
 
-# when the request running in this context got its gate permit
-_PERMIT_AT: contextvars.ContextVar[float] = contextvars.ContextVar(
-    "localai_permit_at")
+class _Permit:
+    """One request's hold of a gate permit: when it was granted (`at`), and
+    when its stream's pump thread read the stream's end after the backend's
+    finished reply (`ended`; None while no stream of it ran to that)."""
+
+    __slots__ = ("at", "ended")
+
+    def __init__(self, at: float):
+        self.at, self.ended = at, None
+
+
+# the gate permit of the request running in this context
+_PERMIT: contextvars.ContextVar[_Permit] = contextvars.ContextVar(
+    "localai_permit")
 
 
 class API:
@@ -568,12 +609,13 @@ class API:
         if tr is not None:
             tr.add_complete("http.gate_wait", t0, waited, cat="http",
                             args={"model": cfg.name})
-        permit = _PERMIT_AT.set(now)
+        permit = _Permit(now)
+        token = _PERMIT.set(permit)
         try:
             yield
         finally:
-            _PERMIT_AT.reset(permit)
-            gate.sem.release()
+            _PERMIT.reset(token)
+            gate.release(permit, cfg.name)
 
     async def _unary(self, cfg: ModelConfig, method: str,
                      timeout: float = 600.0, **kw):
@@ -679,7 +721,8 @@ class API:
         gate = self._gate(cfg)
         # the stream's start is timed from its permit, once: a retry or a
         # resume opens another RPC of the same stream
-        since: float | None = _PERMIT_AT.get(time.monotonic())
+        permit = _PERMIT.get(None) or _Permit(time.monotonic())
+        since: float | None = permit.at
         while True:
             if attempt:
                 await asyncio.sleep(resilience.backoff(attempt))
@@ -688,7 +731,7 @@ class API:
             streamed = bool(emitted or sent_chars)
             preempted = False
             err: Exception | None = None
-            pump = self._pump_stream(gate, handle, cur, since)
+            pump = self._pump_stream(gate, handle, cur, permit, since)
             since = None
             try:
                 async for reply in pump:
@@ -828,9 +871,11 @@ class API:
         return None
 
     async def _pump_stream(self, gate: _AdmissionGate, handle, opts: dict,
-                           since: float | None = None):
+                           permit: _Permit, since: float | None = None):
         """Bridge the blocking gRPC stream into an async queue, on one of
-        the gate's pump threads. `since`: when the stream got its permit."""
+        the gate's pump threads. `since`: when the stream got its permit,
+        for the first RPC of a stream; `permit` learns when the pump thread
+        read the stream's end, if the backend's finished reply came first."""
         loop = asyncio.get_running_loop()
         # Bounded queue + BLOCKING put from the pump thread: backpressure
         # propagates to the gRPC stream instead of dropping chunks (or the
@@ -865,9 +910,13 @@ class API:
                 if since is not None:
                     loop.call_soon_threadsafe(gate.start_hist.observe,
                                               time.monotonic() - since)
+                reply = None
                 for reply in call:
                     if not _put(("chunk", reply)):
                         return
+                if reply is not None and reply.finish_reason not in (
+                        "", "cancelled", "preempted"):
+                    permit.ended = time.monotonic()
                 _put(("done", None))
             except Exception as e:
                 if not stopped.is_set():
@@ -1078,14 +1127,20 @@ class API:
 
     async def _debug_slo(self, request):
         """GET /debug/slo[?model=x] → per-model p50/p95/p99 snapshot of the
-        serving SLO histograms (ttft/tpot/queue_wait/prefill/e2e, split by
-        decode path), straight from each backend engine's registry. Empty
+        serving SLO histograms (ttft and its stages, tpot, e2e,
+        finish_to_reply, split by decode path), straight from each backend
+        engine's registry, beside this process's own of the model's gate
+        (gate_wait, stream_start, reply_to_release, permit_hold). Empty
         per-model blocks when LOCALAI_METRICS=0."""
         models = {}
         kv_host = {}
         for payload in await self._backend_traces(
                 request.query.get("model", "")):
-            models[payload["model"]] = payload.get("slo") or {}
+            models[payload["model"]] = {
+                **(payload.get("slo") or {}),
+                **(telemetry.snapshot_from_hists(telemetry.parse_flat(
+                    self._gate_metrics(payload["model"])))
+                   if telemetry.metrics_enabled() else {})}
             if payload.get("kvhost"):
                 # host KV tier occupancy/hit stats (ISSUE 17) — present
                 # only for backends running with kv_host_bytes > 0

@@ -17,7 +17,9 @@ engine tick or per request, never per token or per step.
   per-request histograms over `BUCKETS_S`: `ttft` and its stages
   (`gate_wait`, `stream_start` in the HTTP process; `queue_wait`,
   `admit_to_join`, `join_to_first` in the engine, summing to `ttft`
-  exactly), `tpot`, `e2e`,
+  exactly), `tpot`, `e2e`, and a finished stream's tail (`finish_to_reply`
+  in the backend's handler, `reply_to_release` and the permit's whole
+  `permit_hold` at the HTTP gate),
   labeled by decode path; exported as GetMetrics `hist_*` keys, true
   Prometheus histogram series and `/debug/slo`. Plus the crash/tripwire
   flight recorder (`/debug/flightrec`, auto post-mortem dumps) and
@@ -30,7 +32,8 @@ engine tick or per request, never per token or per step.
   disables it alone).
 
 The engine's own counters (`engine.metrics`: dispatches and steps consumed,
-requests admitted, tokens by path, ...) ride the same GetMetrics map.
+rows x steps by state (`decode_row_steps__*`), requests admitted, tokens by
+path, ...) ride the same GetMetrics map.
 """
 from localai_tpu.telemetry.trace import (  # noqa: F401
     XPROF_MAX_S,

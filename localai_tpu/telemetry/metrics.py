@@ -8,8 +8,11 @@ Two pieces, both process-wide singletons the way `trace.py`'s tracer is:
   stages (queue wait / admit→join / join→first token), inter-token latency
   (TPOT) and e2e per request, labeled by the decode path that served it
   (loop / dense / ragged / spec); the HTTP process keeps the wait at its
-  admission gate (`gate_wait`) and from the permit to the stream's pump
-  thread running (`stream_start`) in one `Hist` each per model.
+  admission gate (`gate_wait`), from the permit to the stream's pump
+  thread running (`stream_start`), from the stream's end to the permit's
+  release (`reply_to_release`) and the permit's whole life (`permit_hold`)
+  in one `Hist` each per model; the backend's gRPC handler adds
+  `finish_to_reply`.
   Observations are plain int increments under the GIL — no lock on the hot
   path; snapshot readers (GetMetrics scrape, /debug/slo) tolerate a
   half-landed observation the same way the span ring does. Percentiles come
@@ -59,9 +62,15 @@ BUCKETS_S: tuple[float, ...] = (
 # TTFT splits, in order, into gate_wait and stream_start (HTTP process; the
 # second is streams only) and then queue_wait + admit_to_join +
 # join_to_first (engine; these three sum to ttft exactly: they share their
-# boundary timestamps).
+# boundary timestamps). Its tail, once a stream that ran to the backend's
+# finished reply: finish_to_reply (backend: the engine's finish decision,
+# where e2e ends, -> gRPC has taken the final reply) and reply_to_release
+# (HTTP process: the pump thread reads the stream's end -> the gate's permit
+# is released); permit_hold (HTTP process) is the permit's whole life,
+# granted -> released, the check on the sum of the stages.
 METRICS = ("ttft", "tpot", "gate_wait", "stream_start", "queue_wait",
-           "admit_to_join", "join_to_first", "e2e")
+           "admit_to_join", "join_to_first", "e2e", "finish_to_reply",
+           "reply_to_release", "permit_hold")
 
 _FORCED: bool | None = None
 

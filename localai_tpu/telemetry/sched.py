@@ -255,6 +255,7 @@ class TickLedger:
         # the AOT cost-analysis pass; flat()/snapshot() then export them)
         self.rooflines: dict[str, dict] = {}
         self._cur: dict | None = None
+        self._rows: dict | None = None   # rows(): for the next pack's record
         self._lock = lockdep_lock("telemetry.sched")
 
     def reset(self) -> None:
@@ -269,6 +270,7 @@ class TickLedger:
             self.n_ticks = 0
             self.n_dispatches = 0
             self._cur = None
+            self._rows = None
 
     # ------------------------------------------------------------ recording
 
@@ -290,12 +292,29 @@ class TickLedger:
             cur["reasons"].append(
                 dict(fields, code=code) if fields else code)
 
+    def rows(self, active: int, prefill: int, free_queued: int,
+             free_starved: int) -> None:
+        """The engine's max_slots rows by state as the next decode dispatch
+        is enqueued (engine._rows_at_dispatch): kept in that dispatch's pack
+        record, so /debug/sched and the flight recorder show where each
+        dispatch's rows were. Not summed here: rows weigh by the steps a
+        dispatch runs, known at consume, and the engine's cumulative
+        `decode_row_steps__*` counters carry that."""
+        self._rows = {"rows_active": active, "rows_prefill": prefill,
+                      "rows_free_queued": free_queued,
+                      "rows_free_starved": free_starved}
+
     def pack(self, variant: str, *, decode_rows: int = 0,
              prefill_tokens: int = 0, spec_windows: int = 0,
              mm_rows: int = 0, pad_rows: int = 0, rows_used: int = 0,
              budget_rows: int = 0, packed: int = 0) -> None:
         """Record one dispatch's pack composition under its compiled program
-        variant name (the same name engine.rooflines() costs)."""
+        variant name (the same name engine.rooflines() costs). Every field
+        counts ONCE A DISPATCH, whatever its steps: `pad_rows` of a dense
+        dispatch is max_slots less its decode rows, so a 64-step loop's
+        empty rows weigh in `sched_pack__pad_rows` as one step's. Rows by
+        step, and by why they were empty: the engine's
+        `decode_row_steps__*`."""
         self.n_dispatches += 1
         self.variants[variant] = self.variants.get(variant, 0) + 1
         comp = {"decode_rows": decode_rows, "prefill_tokens": prefill_tokens,
@@ -308,9 +327,10 @@ class TickLedger:
         t = self.totals
         for k, v in comp.items():
             t[k] += v
+        rows, self._rows = self._rows, None
         cur = self._cur
         if cur is not None:
-            cur["packs"].append(dict(comp, variant=variant))
+            cur["packs"].append(dict(comp, variant=variant, **(rows or {})))
 
     def commit(self, **meta) -> dict:
         """Seal the current tick record (begin() must have run) and append
@@ -337,7 +357,8 @@ class TickLedger:
 
     def pad_rows_frac(self) -> float:
         """Fraction of ALLOCATED q rows that were QBLK-alignment padding —
-        the cost of the one-row-per-decode-slot layout contract."""
+        the cost of the one-row-per-decode-slot layout contract. Counted
+        once a dispatch (see pack): no measure of rows idle by step."""
         return self.totals["pad_rows"] / max(self.totals["rows_used"], 1)
 
     def flat(self, prefix: str = "sched_") -> dict[str, float]:

@@ -214,7 +214,11 @@ class PhaseClock:
         device trace of this process holds the phases on the trace's own
         clock (host tracer level 1); the first annotation of each tick also
         carries `unix_us`, which places ring spans of any process on that
-        clock. No trace running: about half a microsecond;
+        clock; the next annotation after `note` carries what the caller
+        noted (the engine, while `device_trace` runs, at each decode
+        dispatch: `rows_active`, `rows_prefill`, `rows_free`, `queued`;
+        tools/trace_gaps.py prints them beside each idle gap and program).
+        No trace running: about half a microsecond;
     (c) a ring span `engine.<phase>` when LOCALAI_TRACE is on — waits of the
         idle loop and phases under RING_MIN_S stay out of the ring, so an
         idle engine does not wash the request spans out of it.
@@ -239,8 +243,20 @@ class PhaseClock:
         self.phase = "idle"
         self.tick = 0
         self._stamped = -1       # the last tick whose annotation has unix_us
+        self._noted = None       # note(): arguments for the next annotation
         self._open = None        # the running phase's annotation
         self._t0 = time.perf_counter()
+
+    @staticmethod
+    def tracing() -> bool:
+        """True while `device_trace` traces this process (see `note`)."""
+        return _XPROF_ON
+
+    def note(self, **args) -> None:
+        """Integers for the next annotation opened, on top of its tick: what
+        the caller wants read beside the device's ops in a trace. Worth
+        computing only while `tracing()`."""
+        self._noted = args
 
     def switch(self, phase: str, tick: int | None = None) -> str:
         """Close the running phase, open `phase`; returns the one closed."""
@@ -261,6 +277,10 @@ class PhaseClock:
             self._stamped = self.tick
             a = self._annotation("engine." + phase, tick=self.tick,
                                  unix_us=int(now * 1e6) + _EPOCH_US)
+        elif self._noted is not None:
+            a = self._annotation("engine." + phase, tick=self.tick,
+                                 **self._noted)
+            self._noted = None
         else:
             a = self._annotation("engine." + phase, tick=self.tick)
         a.__enter__()
@@ -283,6 +303,7 @@ class PhaseClock:
 
 XPROF_MAX_S = 10.0
 _XPROF_LOCK = lockdep_lock("telemetry.xprof")
+_XPROF_ON = False     # device_trace is tracing: PhaseClock.tracing()
 
 
 def device_trace(seconds: float) -> dict:
@@ -308,11 +329,14 @@ def device_trace(seconds: float) -> dict:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 1
+        global _XPROF_ON
         out = tempfile.mkdtemp(prefix="localai_xprof_")
         jax.profiler.start_trace(out, profiler_options=opts)
+        _XPROF_ON = True
         try:
             time.sleep(seconds)
         finally:
+            _XPROF_ON = False
             jax.profiler.stop_trace()
         return {"dir": out, "seconds": seconds, "pid": os.getpid(),
                 "xplane": sorted(glob.glob(os.path.join(

@@ -304,7 +304,7 @@ def test_gauges_and_counters(engine):
     assert m["kv_bytes__full"] == 2 * 3 * 2 * 2 * 256 * 16 * 4
     assert "decode_ctx_tokens__full" not in m
     engine._slots[1] = type("S", (), dict(
-        request_id=7, prompt_len=10, generated=2))()
+        request_id=7, prompt_len=10, generated=2, prefilled=True))()
     engine._credit_consumed(3, [(1, 7)])
     per_token = 2 * 2 * 16 * 4
     assert m["decode_cache_bytes__full"] == (13 + 14 + 15) * per_token * 2

@@ -265,3 +265,128 @@ def test_stream_start_is_observed_once_a_stream_and_streams_open_returns():
     assert 0 <= m["hist_stream_start__all__sum"] < 9 * LIMIT_S
     assert m["hist_gate_wait__all__count"] == 9
     assert m["streams_open"] == 0
+
+
+# ------------------------------------------------- a permit's life (ISSUE 37)
+
+TAIL = ("hist_reply_to_release__all__", "hist_permit_hold__all__")
+
+
+def _calls_are(handle, cls):
+    """Make the handle's next streams calls of `cls` (a FakeCall)."""
+    def predict_stream(**opts):
+        handle.calls.append(cls(handle._gate, handle._chunks))
+        return handle.calls[-1]
+
+    handle.predict_stream = predict_stream
+
+
+def test_permit_tail_and_hold_are_observed_once_a_finished_stream():
+    """`hist_reply_to_release` (the pump thread reads the stream's end ->
+    the permit is released) and `hist_permit_hold` (granted -> released):
+    one observation each per stream that ran to the backend's finished
+    reply, under the flat keys the backend's metrics use, there at 0 from
+    the gate's first use."""
+    api, cfg, _ = _api(4, chunks=3)
+
+    async def main():
+        try:
+            api._gate(cfg)
+            zero = api._gate_metrics("m")
+            assert await asyncio.gather(
+                *(_stream(api, cfg) for _ in range(6))) == [3] * 6
+            return zero, api._gate_metrics("m")
+        finally:
+            await _close(api)
+
+    zero, m = _run(main)
+    for base in TAIL:
+        assert zero[base + "count"] == 0 and zero[base + "sum"] == 0.0
+        assert m[base + "count"] == 6
+    assert m["hist_gate_wait__all__count"] == 6
+    # the tail lies inside the hold, for every stream
+    assert 0 <= m[TAIL[0] + "sum"] <= m[TAIL[1] + "sum"] < 6 * LIMIT_S
+
+
+@pytest.mark.parametrize("how", ["client closes", "backend says cancelled",
+                                 "stream dies", "nothing streamed"])
+def test_a_stream_that_did_not_finish_observes_no_tail_and_no_hold(how):
+    """A permit handed to a client that has gone, a stream the backend
+    ended as cancelled, one that died, a request that streamed nothing:
+    none is a measure of a request's life, and the gate's queue at a
+    window's end is full of them."""
+    api, cfg, handle = _api(2, chunks=4)
+
+    class Tagged(FakeCall):
+        def __next__(self):
+            reply = super().__next__()
+            if how == "backend says cancelled" and reply.finish_reason:
+                reply.finish_reason = "cancelled"
+            if how == "stream dies" and reply.finish_reason:
+                raise grpc.RpcError("backend died")
+            return reply
+
+    _calls_are(handle, Tagged)
+    api.manager.classify_failure = lambda h, e: (False, e)
+
+    async def main():
+        try:
+            async with api._admit(cfg):
+                if how == "nothing streamed":
+                    return api._gate_metrics("m")
+                stream = api._stream_rpc(cfg, {})
+                try:
+                    reply = await stream.__anext__()
+                    assert list(reply.token_ids) == [7]
+                    if how != "client closes":
+                        async for reply in stream:
+                            pass
+                except grpc.RpcError:
+                    assert how == "stream dies"
+                finally:
+                    await stream.aclose()
+            return api._gate_metrics("m")
+        finally:
+            await _close(api)
+
+    m = _run(main)
+    assert m["hist_gate_wait__all__count"] == 1
+    for base in TAIL:
+        assert m[base + "count"] == 0 and m[base + "sum"] == 0.0, (how, base)
+
+
+def test_permit_hold_is_at_least_the_sum_of_its_stages_on_a_stubbed_backend():
+    """One request on a stub that times its own part (the first read of the
+    stream -> its finished reply handed over) as the backend's spans do:
+    `permit_hold` >= `stream_start` + the backend's part +
+    `reply_to_release`. What is left is the two crossings between the
+    processes, which no single clock times."""
+    spans = []
+
+    class Timed(FakeCall):
+        def __next__(self):
+            if self._first:
+                self.t0 = time.monotonic()
+                time.sleep(0.03)        # queue, prefill, decode
+            reply = super().__next__()
+            if reply.finish_reason:
+                spans.append(time.monotonic() - self.t0)
+            return reply
+
+    api, cfg, handle = _api(1, chunks=3)
+    _calls_are(handle, Timed)
+
+    async def main():
+        try:
+            assert await _stream(api, cfg) == 3
+            return api._gate_metrics("m")
+        finally:
+            await _close(api)
+
+    m = _run(main)
+    assert len(spans) == 1 and spans[0] >= 0.03
+    assert m["hist_permit_hold__all__count"] == 1
+    stages = (m["hist_stream_start__all__sum"] + spans[0]
+              + m["hist_reply_to_release__all__sum"])
+    assert m["hist_permit_hold__all__sum"] >= stages
+    assert m["hist_permit_hold__all__sum"] - stages < 1.0

@@ -440,3 +440,112 @@ def test_util_trace_cli(traced_stack, tmp_path, capsys):
     printed = capsys.readouterr().out
     assert "events" in printed
     assert "admit" in printed and "engine thread" in printed
+
+
+# ------------------------------------------------ a permit's life (ISSUE 37)
+
+ROW_STATES = ("live", "spent", "prefill", "free_queued", "free_starved")
+
+
+def _metrics(base) -> dict:
+    return requests.get(base + "/backend/monitor",
+                        timeout=60).json()["tiny"]["metrics"]
+
+
+def _hist(m: dict, name: str, field: str) -> float:
+    """A histogram's count or sum over every decode path."""
+    return sum(v for k, v in m.items()
+               if k.startswith(f"hist_{name}__") and k.endswith("__" + field))
+
+
+def _stream_chat(base, rid: str, max_tokens: int, cut_after: int = 0) -> int:
+    """Stream one chat completion; with `cut_after`, close the connection
+    after that many data lines (what a client that goes away does)."""
+    n = 0
+    with requests.post(base + "/v1/chat/completions", json={
+            "model": "tiny", "stream": True, "ignore_eos": True,
+            "messages": [{"role": "user", "content": f"hello from {rid}"}],
+            "max_tokens": max_tokens},
+            headers={"X-Request-Id": rid}, stream=True, timeout=300) as r:
+        assert r.status_code == 200, r.text
+        for line in r.iter_lines():
+            if line.startswith(b"data: "):
+                n += 1
+                if cut_after and n >= cut_after:
+                    break
+    return n
+
+
+def test_a_finished_streams_tail_in_monitor_metrics_and_the_ring(
+        traced_stack):
+    """The three spans of a request's tail are observed once for a stream
+    that runs to its end, ride /backend/monitor and /metrics beside
+    hist_gate_wait, and go into the ring by request id; with the head's
+    stages they stay under the permit's whole life."""
+    base, _ = traced_stack
+    _warm(base)
+    before = _metrics(base)
+    assert _stream_chat(base, "it-tail-1", max_tokens=6) > 6
+    m = _metrics(base)
+    new = {name: (_hist(m, name, "count") - _hist(before, name, "count"),
+                  _hist(m, name, "sum") - _hist(before, name, "sum"))
+           for name in ("gate_wait", "stream_start", "e2e", "finish_to_reply",
+                        "reply_to_release", "permit_hold")}
+    for name, (count, total) in new.items():
+        assert count == 1 and total >= 0, (name, count, total)
+    assert any(k.startswith("hist_finish_to_reply__") and "__all__" not in k
+               for k in m)                     # by the decode path, as e2e
+    # one request: e2e runs from the engine's queue to its finish decision
+    # (queue_wait + admit_to_join + the engine's time to the finish), so
+    # the stages are disjoint and all inside the permit's life
+    stages = sum(new[n][1] for n in ("stream_start", "e2e",
+                                     "finish_to_reply", "reply_to_release"))
+    assert new["permit_hold"][1] >= stages
+    assert new["permit_hold"][1] - stages < 5.0     # the two crossings
+
+    prom = requests.get(base + "/metrics", timeout=60).text
+    for name in ("finish_to_reply", "reply_to_release", "permit_hold"):
+        assert f'localai_request_{name}_seconds_count{{model="tiny"' in prom
+    slo = requests.get(base + "/debug/slo", timeout=60).json()
+    for name in ("gate_wait", "finish_to_reply", "permit_hold"):
+        assert slo["models"]["tiny"][name]["count"] >= 1, name
+
+    trace = requests.get(base + "/debug/trace", timeout=60).json()
+    mine = {e["name"] for e in trace["traceEvents"]
+            if e.get("args", {}).get("request_id") == "it-tail-1"}
+    assert {"engine.stage.queue_wait", "engine.stage.join_to_first",
+            "engine.stage.finish_to_reply", "http.stage.reply_to_release",
+            "http.stage.permit_hold"} <= mine
+
+
+def test_a_stream_the_client_cuts_observes_no_tail(traced_stack):
+    base, _ = traced_stack
+    _warm(base)
+    before = _metrics(base)
+    assert _stream_chat(base, "it-cut-1", max_tokens=100, cut_after=3) == 3
+    # the engine ends the request as cancelled at its next token
+    deadline = time.monotonic() + 60
+    while time.monotonic() < deadline:
+        m = _metrics(base)
+        if m["streams_open"] == 0 and (
+                m["requests_completed"] > before["requests_completed"]):
+            break
+        time.sleep(0.1)
+    assert _hist(m, "gate_wait", "count") == \
+        _hist(before, "gate_wait", "count") + 1
+    for name in ("finish_to_reply", "reply_to_release", "permit_hold"):
+        assert _hist(m, name, "count") == _hist(before, name, "count"), name
+
+
+def test_row_states_ride_the_monitor_and_tile_the_steps(traced_stack):
+    base, _ = traced_stack
+    _warm(base)
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:       # every dispatch consumed
+        m = _metrics(base)
+        if m["decode_dispatches_consumed"] == m["decode_dispatches"]:
+            break
+        time.sleep(0.1)
+    rows = {s: m[f"decode_row_steps__{s}"] for s in ROW_STATES}
+    assert sum(rows.values()) == 4 * m["decode_steps_consumed"] > 0, rows
+    assert rows["live"] == m["tokens_generated"]

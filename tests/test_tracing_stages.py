@@ -66,6 +66,7 @@ def test_phase_clock_switch_within_and_ring_rules():
     tr = telemetry.Tracer(64)
     m: dict = {}
     pc = telemetry.PhaseClock(m, tr)
+    pc.RING_MIN_S = 1e-3       # a busy machine can hold a thread 100 us
     assert sorted(m) == sorted(PHASE_KEYS) and not any(m.values())
     assert pc.switch("dispatch", tick=7) == "idle"
     with pc.within("admit"):
@@ -321,6 +322,11 @@ def test_profiler_trace_holds_engine_annotations_with_tick(ckpt):
     stamped = [s for s in stats if "unix_us" in s]
     assert stamped
     assert abs(stamped[0]["unix_us"] / 1e6 - time.time()) < 600
+    # a dispatch enqueued while device_trace ran says what the engine held
+    held = [s for s in stats if "rows_active" in s]
+    assert held and all(
+        s["rows_active"] + s["rows_prefill"] + s["rows_free"] == 4
+        and s["queued"] >= 0 for s in held)
     # at most one stamp a tick
     assert len(stamped) == len({s["tick"] for s in stamped})
 

@@ -10,7 +10,14 @@ profiler's own clock, so no clock is guessed. Printed, and returned by
 
 - per engine phase: its time in the trace, and the device-idle time under it
   (where no op ran on the device while the thread was in that phase);
-- the ten longest device gaps, each with the phase that covers most of it;
+- the ten longest device gaps, each with the phase that covers most of it
+  and the rows the engine held as that tick's decode dispatch was enqueued
+  (active / in prefill / free, and the queue's length: arguments of the
+  annotation opened after it, there in a `GET /debug/xprof` trace);
+- per program (XLA module) its calls, mean time a call, and the mean of
+  those rows over the ticks its calls started under. A program runs on the
+  device up to a dispatch after the tick that enqueued it, so read the rows
+  beside a program as "what the engine held while it ran";
 - per phase, the host events the profiler recorded on the engine's thread
   inside it (jitted calls, transfers, waits), by inclusive time: what the
   thread was doing there;
@@ -39,8 +46,8 @@ import sys
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 from benchmark.harness.tracefacts import (  # noqa: E402
-    DEVICE_PLANE, OP_LINE, clip, complement, intersect, merge, op_short,
-    total,
+    DEVICE_PLANE, MODULE_LINE, OP_LINE, clip, complement, intersect, merge,
+    module_base, op_short, total,
 )
 
 PHASE = re.compile(r"^engine\.(dispatch|admit|emit|kv|device|idle)$")
@@ -57,6 +64,9 @@ LAYER_KINDS = ("window", "full", "linear")
 # what a linear layer's mixer is made of (models/kv.py StateKV)
 LINEAR_PARTS = ("conv", "kda_chunk", "kda_decode")
 TOP = 10
+# what an annotation says the engine held as its tick's dispatch was enqueued
+ROWS = {"active": "rows_active", "prefill": "rows_prefill",
+        "free": "rows_free", "queued": "queued"}
 
 
 def _varint(buf: bytes, i: int) -> tuple:
@@ -133,9 +143,9 @@ def op_paths(path: str) -> dict:
 
 def load_xplane(path: str) -> dict:
     """{"planes": [{"name", "lines": [{"name", "events": [[name, start_ns,
-    dur_ns, {stat: value}], ...]}]}]}: the device planes' op line and every
-    host line that holds an engine.<phase> annotation (the engine's thread,
-    with everything else the profiler recorded on it)."""
+    dur_ns, {stat: value}], ...]}]}]}: the device planes' op and module
+    lines and every host line that holds an engine.<phase> annotation (the
+    engine's thread, with everything else the profiler recorded on it)."""
     from jax.profiler import ProfileData
 
     paths = op_paths(path)
@@ -144,13 +154,15 @@ def load_xplane(path: str) -> dict:
         is_dev = bool(DEVICE_PLANE.match(plane.name))
         lines = []
         for line in plane.lines:
-            if is_dev and line.name != OP_LINE:
+            if is_dev and line.name not in (OP_LINE, MODULE_LINE):
                 continue
             events = [[ev.name, int(ev.start_ns), int(ev.duration_ns),
                        {k: v for k, v in ev.stats
                         if isinstance(v, (str, int, float))}]
                       for ev in line.events]
-            if is_dev:      # the op's path; its own stats are not read
+            if is_dev and line.name == MODULE_LINE:
+                events = [e[:3] + [{}] for e in events]
+            elif is_dev:    # the op's path; its own stats are not read
                 for e in events:
                     e[3] = {"tf_op": paths[e[0]]} if e[0] in paths else {}
             if is_dev or any(PHASE.match(e[0]) for e in events):
@@ -205,17 +217,18 @@ def scope_of(name: str, stats: dict) -> str:
 
 
 def reduce(raw: dict) -> dict:
-    phases, ops, host = [], [], []
+    phases, ops, host, modules = [], [], [], []
     for plane in raw.get("planes", []):
         dev = bool(DEVICE_PLANE.match(plane["name"]))
         for line in plane["lines"]:
+            if dev:
+                if line["name"] == OP_LINE:
+                    ops.extend(line["events"])
+                elif line["name"] == MODULE_LINE:
+                    modules.extend(line["events"])
+                continue
             for ev in line["events"]:
-                if dev and line["name"] == OP_LINE:
-                    ops.append(ev)
-                elif not dev and PHASE.match(ev[0]):
-                    phases.append(ev)
-                elif not dev:
-                    host.append(ev)
+                (phases if PHASE.match(ev[0]) else host).append(ev)
     if not ops:
         raise SystemExit("the trace holds no device op")
     if not phases:
@@ -254,17 +267,36 @@ def reduce(raw: dict) -> dict:
             "spans": len(spans)}
     covered = merge([s for spans in by_phase.values() for s in spans])
 
+    # the rows of a tick's dispatch, by tick, and the tick open at a time (a
+    # tick lasts from its first annotation to the next tick's)
+    tick_rows = {e[3]["tick"]: {k: e[3][v] for k, v in ROWS.items()}
+                 for e in phases if ROWS["active"] in e[3]}
+    starts = [e[1] for e in phases]
+
+    def rows_at(t: float) -> dict | None:
+        i = bisect.bisect_right(starts, t) - 1
+        return tick_rows.get(phases[i][3].get("tick")) if i >= 0 else None
+
     gaps = []
     for a, b in sorted(idle, key=lambda g: g[0] - g[1])[:TOP]:
         under = {phase: total(intersect(merge(spans), [[a, b]]))
                  for phase, spans in by_phase.items()}
         phase = max(under, key=under.get) if any(under.values()) else "none"
         gaps.append({"phase": phase, "ms": (b - a) / 1e6,
-                     "at_ms": (a - lo) / 1e6})
+                     "at_ms": (a - lo) / 1e6, "rows": rows_at(a)})
+
+    programs: dict = {}
+    for name, start, dur, _ in modules:
+        p = programs.setdefault(module_base(name), {"calls": 0, "ns": 0,
+                                                    "rows": []})
+        p["calls"] += 1
+        p["ns"] += dur
+        held = rows_at(start)
+        if held is not None:
+            p["rows"].append(held)
 
     # what the engine's thread did inside each phase: the other host events
     # of its line, each under the phase that holds its start
-    starts = [e[1] for e in phases]
     under: dict = {}
     for name, start, dur, _ in host:
         i = bisect.bisect_right(starts, start) - 1
@@ -307,6 +339,13 @@ def reduce(raw: dict) -> dict:
         "idle_outside_any_phase_s": total(intersect(
             idle, complement(clip(covered, lo, hi), lo, hi))) / 1e9,
         "gaps": gaps,
+        "programs": [{
+            "program": name, "calls": p["calls"],
+            "ms": p["ns"] / p["calls"] / 1e6,
+            "rows": {k: sum(r[k] for r in p["rows"]) / len(p["rows"])
+                     for k in ROWS} if p["rows"] else None}
+            for name, p in sorted(programs.items(),
+                                  key=lambda kv: -kv[1]["ns"])],
         "host_under": {phase: [[n, ns / 1e9] for n, ns in sorted(
             d.items(), key=lambda kv: -kv[1])[:5]]
             for phase, d in under.items()},
@@ -318,6 +357,13 @@ def reduce(raw: dict) -> dict:
                            if offsets else None),
         "ticks": len({e[3].get("tick") for e in phases}),
     }
+
+
+def _rows(rows: dict | None) -> str:
+    if rows is None:
+        return "rows not in the trace"
+    return ("rows {active:.3g} active / {prefill:.3g} prefill / {free:.3g} "
+            "free, {queued:.3g} queued".format(**rows))
 
 
 def render(facts: dict) -> str:
@@ -334,9 +380,16 @@ def render(facts: dict) -> str:
                f"{facts['idle_outside_any_phase_s']:.6f} s; the phases sum "
                f"to {facts['phases_sum_s']:.4f} s over a span of "
                f"{facts['phases_span_s']:.4f} s)")
-    out.append("longest device gaps (ms, at ms from the first op, phase)")
+    out.append("longest device gaps (ms, at ms from the first op, phase, "
+               "the rows of its tick's dispatch)")
     for g in facts["gaps"]:
-        out.append(f"  {g['ms']:>10.4f}  {g['at_ms']:>12.3f}  {g['phase']}")
+        out.append(f"  {g['ms']:>10.4f}  {g['at_ms']:>12.3f}  "
+                   f"{g['phase']:<9} {_rows(g['rows'])}")
+    out.append("programs (calls, mean ms a call, mean rows of the ticks "
+               "their calls started under)")
+    for p in facts["programs"]:
+        out.append(f"  {p['program']:<24} {p['calls']:>5}  {p['ms']:>10.4f}  "
+                   f"{_rows(p['rows'])}")
     out.append("host events on the engine's thread inside a phase "
                "(inclusive s)")
     for phase, rows in facts["host_under"].items():
