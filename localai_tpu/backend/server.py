@@ -84,13 +84,16 @@ def _make_base():
 
 
 # A request holds a handler thread until it is answered, a stream for its
-# whole life. The HTTP process sends a model's backend at most `parallel`
-# requests at once (its admission gate; one pump thread a stream), so 40
-# handlers serve a `parallel` of up to 36 and leave Status, GetMetrics,
-# GetTrace and Health a thread each: with 16, /backend/monitor waited seconds
-# behind 16 open streams for a handler that then ran for 0.3 ms. A `parallel`
-# above 36 is capped here, not at the gate.
-HANDLER_THREADS = 40
+# whole life. The HTTP process sends a model's backend at most `limit`
+# requests at once, its admission gate's permits: `parallel` (the engine's
+# slots) + max(2, parallel // 4) ahead of them, one pump thread a stream
+# (`server/http.py:_AdmissionGate`). A `parallel` of 32 opens 40 streams and
+# one of 48 opens 60, so 64 handlers serve a `parallel` of up to 48 and leave
+# Status, GetMetrics, GetTrace and Health a thread each: with 16,
+# /backend/monitor waited seconds behind 16 open streams for a handler that
+# then ran for 0.3 ms. A gate of more than 60 permits is capped here, not at
+# the gate.
+HANDLER_THREADS = 64
 
 
 def serve(addr: str = "127.0.0.1:50051", backend: str = "llm",

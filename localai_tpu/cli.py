@@ -23,7 +23,11 @@ def _add_run(sub):
     p.add_argument("--watchdog-idle-timeout", default=None)
     p.add_argument("--watchdog-busy-timeout", default=None)
     p.add_argument("--single-active-backend", action="store_true")
-    p.add_argument("--parallel-requests", type=int, default=8)
+    p.add_argument("--parallel-requests", type=int, default=8,
+                   help="a model's engine slots where its YAML gives no "
+                        "`parallel`; its admission gate lets through that "
+                        "many requests + max(2, that // 4) ahead of them, "
+                        "which wait tokenised in the engine's queue")
     p.add_argument("--tensor-parallel", type=int, default=None,
                    help="shard each model over N chips (Megatron-style TP "
                         "on the 'model' mesh axis; int8 weights shard too). "

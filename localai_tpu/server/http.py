@@ -224,20 +224,35 @@ def _fetch_image(url: str) -> str:
 
 
 class _AdmissionGate:
-    """Per-model admission state: `limit` concurrent requests against the
-    backend plus at most `depth` waiters; the rest shed with 429. Nothing
-    else bounds the streams open against the backend: every permit has a
-    pump thread of its own (started on first use, asleep in a gRPC read
-    while its stream is open), so a stream never waits for a thread that
-    another stream holds."""
+    """Per-model admission state. `slots` is the model's `parallel`, the
+    engine's decode rows; the gate grants `limit` = `slots` + `ahead`
+    permits, `ahead` = max(2, slots // 4), so that when a row frees the next
+    prompt already stands tokenised in the engine's queue and has not its
+    whole way in (gRPC, the chat template, the tokeniser) still before it.
+    A request ahead of the slots holds its prompt's ids in the backend and
+    nothing else: no slot, no KV; the engine's queue is FIFO, as the gate
+    is, and checks its deadline and its cancellation as before. The extra
+    permits are only ever out when `slots` are (`gate_grants_ahead` counts
+    them): under capacity this is a gate of `slots`. Past `limit` at most
+    `depth` requests wait; the rest shed with 429. Nothing else bounds the
+    streams open against the backend: every permit has a pump thread of its
+    own (started on first use, asleep in a gRPC read while its stream is
+    open), so a stream never waits for a thread that another stream holds."""
 
-    def __init__(self, name: str, limit: int, depth: int):
-        self.limit = max(1, int(limit))
+    def __init__(self, name: str, slots: int, depth: int):
+        self.slots = max(1, int(slots))
+        self.limit = self.slots + max(2, self.slots // 4)
         self.depth = max(0, int(depth))
         self.sem = asyncio.Semaphore(self.limit)
         self.waiting = 0
         self.pumps = concurrent.futures.ThreadPoolExecutor(
             max_workers=self.limit, thread_name_prefix=f"pump-{name}")
+        # permits out now; grants ever made, and those of them made while
+        # `slots` or more permits were out (a request let in ahead of a
+        # slot). All written on the loop's thread, like the rest of the gate
+        self.out = 0
+        self.grants = 0
+        self.grants_ahead = 0
         # the wait at this gate, one observation per request (0 included):
         # the first stage of a request's TTFT, merged into the model's
         # metrics as hist_gate_wait__all__* (/backend/monitor, /metrics)
@@ -253,6 +268,13 @@ class _AdmissionGate:
         self.tail_hist = telemetry.Hist()
         self.hold_hist = telemetry.Hist()
 
+    def granted(self) -> None:
+        """Count the permit just taken from `sem`."""
+        self.grants += 1
+        if self.out >= self.slots:
+            self.grants_ahead += 1
+        self.out += 1
+
     def release(self, permit: "_Permit", model: str) -> None:
         """Hand the permit back. If its stream ran to the backend's finished
         reply, observe the tail (the pump thread read the stream's end ->
@@ -262,6 +284,7 @@ class _AdmissionGate:
         held for a client that has gone is no measure of a request's life.
         On the loop's thread, like every write to the gate."""
         self.sem.release()
+        self.out -= 1
         if permit.ended is None:
             return
         now = time.monotonic()
@@ -283,7 +306,11 @@ class _AdmissionGate:
                 **self.start_hist.flat("stream_start"),
                 **self.tail_hist.flat("reply_to_release"),
                 **self.hold_hist.flat("permit_hold"),
-                "streams_open": float(self.streams_open)}
+                "streams_open": float(self.streams_open),
+                "gate_grants": float(self.grants),
+                "gate_grants_ahead": float(self.grants_ahead),
+                "gate_slots": float(self.slots),
+                "gate_limit": float(self.limit)}
 
 
 class _Permit:
@@ -587,7 +614,8 @@ class API:
         if gate.sem.locked() and gate.waiting >= gate.depth:
             raise resilience.RequestShed(
                 f"model {cfg.name!r} is at capacity "
-                f"({gate.limit} in flight, {gate.waiting} queued)",
+                f"({gate.limit} in flight for {gate.slots} slots, "
+                f"{gate.waiting} queued)",
                 model=cfg.name, reason="queue_full", retry_after=1.0)
         gate.waiting += 1
         t0 = time.monotonic()
@@ -602,6 +630,7 @@ class API:
                     model=cfg.name, reason="queue_timeout", retry_after=1.0)
         finally:
             gate.waiting -= 1
+        gate.granted()
         now = time.monotonic()
         waited = now - t0
         gate.wait_hist.observe(waited)

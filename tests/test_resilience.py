@@ -9,6 +9,7 @@ scoped per model name and counted across process boundaries through
 LOCALAI_FAULT_DIR, so each scenario is deterministic.
 """
 import asyncio
+import contextlib
 import json
 import os
 import signal
@@ -231,13 +232,18 @@ def test_admission_gate_sheds_and_recovers():
     cfg = ModelConfig(name="m", backend="llm", parallel=1)
 
     async def main():
-        async with api._admit(cfg):
+        # one slot and two permits ahead of it (ISSUE 39): the gate is full
+        # with `limit` = 3 requests inside
+        assert api._gate(cfg).limit == 3
+        async with contextlib.AsyncExitStack() as held:
+            for _ in range(3):
+                await held.enter_async_context(api._admit(cfg))
             with pytest.raises(RequestShed) as ei:
                 async with api._admit(cfg):
                     pass
             assert ei.value.status == 429 and ei.value.model == "m"
             assert ei.value.reason == "queue_full"
-        # slot released → admitted again
+        # permits released → admitted again
         async with api._admit(cfg):
             pass
 
@@ -245,8 +251,8 @@ def test_admission_gate_sheds_and_recovers():
 
 
 def test_admission_gate_bounded_queue():
-    """depth=1: one waiter queues (and runs once the slot frees), the next
-    is shed."""
+    """depth=1: with every permit out one waiter queues (and runs once a
+    permit comes back), the next is shed."""
     from localai_tpu.config import AppConfig, ModelConfig
     from localai_tpu.core.manager import ModelManager
     from localai_tpu.core.resilience import RequestShed
@@ -269,7 +275,8 @@ def test_admission_gate_bounded_queue():
             async with api._admit(cfg):
                 order.append("waiter")
 
-        h = asyncio.create_task(holder())
+        hs = [asyncio.create_task(holder())
+              for _ in range(api._gate(cfg).limit)]
         await asyncio.sleep(0.05)
         w = asyncio.create_task(waiter())
         await asyncio.sleep(0.05)          # waiter now queued (depth 1 full)
@@ -277,10 +284,10 @@ def test_admission_gate_bounded_queue():
             async with api._admit(cfg):
                 pass
         release.set()
-        await asyncio.gather(h, w)
+        await asyncio.gather(w, *hs)
 
     asyncio.run(main())
-    assert order == ["holder", "waiter"]
+    assert order == ["holder"] * 3 + ["waiter"]
 
 
 def test_federation_breaker_skips_open_worker():

@@ -192,6 +192,49 @@ def test_free_rows_split_by_whether_a_request_was_queued(ckpt):
     assert eng.metrics["decode_row_steps__live"] == 46
 
 
+def test_a_request_queued_before_a_row_frees_takes_it_on_the_next_tick(ckpt):
+    """What a gate that admits ahead of the slots gives the engine (ISSUE
+    39): two requests stand in the queue while both rows decode. When a row
+    finishes, the first of them has the row by the end of the next tick and
+    the second still waits (FIFO); the second takes the next row that
+    finishes. While a request stands queued a free row reads `free_queued`,
+    never `free_starved`."""
+    eng, tok = _engine(ckpt, max_slots=2, decode_loop=8)
+    a, _ = _submit(eng, tok, 0, max_tokens=10)
+    b, _ = _submit(eng, tok, 1, max_tokens=30)
+    eng.step()                       # both admitted
+    c, _ = _submit(eng, tok, 2, max_tokens=20)
+    d, _ = _submit(eng, tok, 3, max_tokens=6)
+
+    def held():
+        return {s.request_id for s in eng._slots if s is not None}
+
+    assert held() == {a, b} and eng._queue.qsize() == 2
+    ticks, left_at, joined_at = 0, {}, {}
+    while len(joined_at) < 2:
+        assert eng.step()
+        ticks += 1
+        now = held()
+        for rid in (a, b):
+            if rid not in now:
+                left_at.setdefault(rid, ticks)
+        for rid in (c, d):
+            if rid in now:
+                joined_at.setdefault(rid, ticks)
+    # in arrival order, each at most a tick after the row it took was freed
+    assert left_at[a] <= joined_at[c] <= left_at[a] + 1
+    assert left_at[b] <= joined_at[d] <= left_at[b] + 1
+    assert joined_at[c] < joined_at[d]
+    # every dispatch enqueued so far had a request queued behind it
+    rows = _rows(eng)
+    assert rows["free_starved"] == 0, rows
+    assert rows["free_queued"] >= 1, rows
+    while eng.step():
+        pass
+    assert eng.metrics["decode_row_steps__live"] == 66
+    assert eng.metrics["requests_admitted"] == 4
+
+
 def test_dispatch_record_carries_the_rows(ckpt):
     """The tick ledger's record of a dispatch holds the states it was
     enqueued with; they are not summed there (sched_pack__* count once a

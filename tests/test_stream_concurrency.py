@@ -1,7 +1,8 @@
 """How many streams are open against a model's backend at once is decided by
-the model's admission gate (limit = `parallel`) and by nothing else: each
-permit has a pump thread of the gate's own, and the loop's default executor
-is left to the short blocking calls.
+the model's admission gate (`slots` = `parallel`, `limit` = `slots` +
+max(2, slots // 4): a few requests ahead of the engine's slots) and by
+nothing else: each permit has a pump thread of the gate's own, and the
+loop's default executor is left to the short blocking calls.
 
 No engine, no gRPC: a fake handle whose `predict_stream` keeps the iterator
 contract of `backend/client.py` (an iterator of `pb.Reply` with `cancel()`),
@@ -12,6 +13,7 @@ behind.
 """
 import asyncio
 import concurrent.futures
+import queue
 import threading
 import time
 from collections import Counter
@@ -88,9 +90,10 @@ class FakeManager:
         pass
 
 
-def _api(parallel: int, gate=lambda call: None, chunks: int = 2):
+def _api(parallel: int, gate=lambda call: None, chunks: int = 2,
+         depth: int = 64):
     handle = FakeHandle(gate, chunks)
-    api = API(AppConfig(queue_depth=64), None, FakeManager(handle))
+    api = API(AppConfig(queue_depth=depth), None, FakeManager(handle))
     return api, ModelConfig(name="m", backend="llm", parallel=parallel), handle
 
 
@@ -181,9 +184,9 @@ def test_a_unary_call_returns_while_parallel_streams_are_open():
 
 
 def test_a_stream_the_client_closes_frees_its_thread_and_cancels_its_call():
-    """`parallel: 1` is one pump thread: a stream closed after its first
-    chunk (what a client's disconnect does) must cancel its RPC and give
-    the thread back, or the next stream never starts."""
+    """A stream closed after its first chunk (what a client's disconnect
+    does) must cancel its RPC and give its pump thread back: the next
+    stream runs on that thread, not on one more."""
     def until_cancelled(call):
         if len(handle.calls) == 1:
             return
@@ -390,3 +393,198 @@ def test_permit_hold_is_at_least_the_sum_of_its_stages_on_a_stubbed_backend():
               + m["hist_reply_to_release__all__sum"])
     assert m["hist_permit_hold__all__sum"] >= stages
     assert m["hist_permit_hold__all__sum"] - stages < 1.0
+
+
+# ------------------------------------- ahead of the slots (ISSUE 39)
+
+def _held_streams(parallel: int, n: int, depth: int = 64):
+    """`n` streams started one after another against a gate of `parallel`
+    slots, each held open on its pump thread until `release(k)` lets `k` of
+    them run to their end."""
+    go, opened = queue.SimpleQueue(), queue.SimpleQueue()
+
+    def hold(call):
+        opened.put(call)
+        try:
+            go.get(timeout=WAIT_S)
+        except queue.Empty:
+            pass
+
+    def release(k: int):
+        for _ in range(k):
+            go.put(None)
+
+    api, cfg, handle = _api(parallel, gate=hold, depth=depth)
+
+    async def start():
+        tasks = []
+        for _ in range(n):
+            tasks.append(asyncio.create_task(_stream(api, cfg)))
+            await asyncio.sleep(0)          # in arrival order at the gate
+        return tasks
+
+    async def opened_are(k: int):
+        for _ in range(k):
+            assert await asyncio.to_thread(opened.get, True, WAIT_S)
+        await asyncio.sleep(0.05)           # one more would have shown
+        assert opened.empty()
+
+    return api, cfg, handle, release, start, opened_are
+
+
+@pytest.mark.parametrize("parallel, limit", [(1, 3), (4, 6), (8, 10),
+                                             (32, 40), (48, 60)])
+def test_the_gate_grants_slots_plus_ahead(parallel, limit):
+    """`slots` is the model's `parallel`; `limit` = `slots` +
+    max(2, slots // 4), derived, and the semaphore and the pump pool go by
+    `limit`."""
+    api, cfg, _ = _api(parallel)
+
+    async def main():
+        try:
+            gate = api._gate(cfg)
+            return gate.slots, gate.limit, gate.pumps._max_workers
+        finally:
+            await _close(api)
+
+    assert _run(main) == (parallel, limit, limit)
+
+
+def test_six_permits_are_out_for_four_slots_and_the_seventh_waits():
+    """With `slots` 4 the gate has 6 permits out, each with its stream open
+    on a pump thread of its own (the pool runs `limit` streams at once), and
+    the 7th request waits at the gate until one is handed back."""
+    api, cfg, handle, release, start, opened_are = _held_streams(4, 7)
+
+    async def main():
+        tasks = await start()
+        try:
+            await opened_are(6)
+            gate = api._gate(cfg)
+            seen = (gate.out, gate.streams_open, gate.waiting,
+                    gate.sem.locked(), len(handle.calls),
+                    len({c.thread for c in handle.calls}))
+            release(1)
+            await opened_are(1)             # the 7th, on the permit freed
+            assert (gate.out, gate.waiting) == (6, 0)
+            release(7)
+            assert await asyncio.gather(*tasks) == [2] * 7
+            return seen, (gate.out, gate.waiting), api._gate_metrics("m")
+        finally:
+            release(7)
+            await _close(api)
+
+    seen, after, m = _run(main)
+    assert seen == (6, 6, 1, True, 6, 6)
+    assert after == (0, 0)
+    assert m["gate_grants"] == 7
+    assert m["hist_gate_wait__all__count"] == 7
+
+
+def test_grants_ahead_counts_the_grants_made_at_or_above_slots():
+    """Of 9 requests that arrive at once on 4 slots, the first 4 are
+    granted with fewer than `slots` permits out and the next 2 with 4 and
+    5 out; each of the three that waited is granted as one permit comes
+    back, with 5 out. Requests one after another never see `slots` permits
+    out and add none."""
+    api, cfg, _, release, start, opened_are = _held_streams(4, 9)
+
+    async def main():
+        tasks = await start()
+        try:
+            await opened_are(6)
+            first = api._gate_metrics("m")
+            for _ in range(3):
+                release(1)
+                await opened_are(1)
+            burst = api._gate_metrics("m")
+            release(9)
+            assert await asyncio.gather(*tasks) == [2] * 9
+            release(5)
+            for _ in range(5):
+                assert await _stream(api, cfg) == 2
+            return first, burst, api._gate_metrics("m")
+        finally:
+            release(9)
+            await _close(api)
+
+    first, burst, after = _run(main)
+    assert (first["gate_grants"], first["gate_grants_ahead"]) == (6, 2)
+    assert (burst["gate_grants"], burst["gate_grants_ahead"]) == (9, 5)
+    assert (after["gate_grants"], after["gate_grants_ahead"]) == (14, 5)
+
+
+def test_sequential_requests_are_never_ahead():
+    api, cfg, _ = _api(4)
+
+    async def main():
+        try:
+            for _ in range(8):
+                assert await _stream(api, cfg) == 2
+            return api._gate_metrics("m")
+        finally:
+            await _close(api)
+
+    m = _run(main)
+    assert (m["gate_grants"], m["gate_grants_ahead"]) == (8, 0)
+
+
+def test_the_gate_sheds_at_limit_plus_depth_and_names_both_numbers():
+    """The shed rule keeps its form: full semaphore and `depth` waiters.
+    With 4 slots and a depth of 3 that is the 10th request (6 + 3 stand),
+    not the 8th, and the 429 says how many are in flight for how many
+    slots."""
+    from localai_tpu.core import resilience
+
+    api, cfg, _, release, start, opened_are = _held_streams(4, 9, depth=3)
+
+    async def main():
+        tasks = await start()
+        try:
+            await opened_are(6)
+            gate = api._gate(cfg)
+            assert (gate.out, gate.waiting) == (6, 3)
+            with pytest.raises(resilience.RequestShed) as shed:
+                async with api._admit(cfg):
+                    pass
+            release(9)
+            assert await asyncio.gather(*tasks) == [2] * 9
+            return str(shed.value), api._gate_metrics("m")
+        finally:
+            release(9)
+            await _close(api)
+
+    text, m = _run(main)
+    assert "6 in flight for 4 slots, 3 queued" in text
+    assert m["gate_grants"] == 9            # the shed request was no grant
+
+
+def test_the_gates_counters_ride_backend_monitor():
+    """`gate.metrics()` carries the two counters and the two gauges into the
+    model's metrics in `/backend/monitor`, beside `streams_open` and over
+    the backend's own keys."""
+    import json
+    import types
+
+    api, cfg, handle = _api(4)
+    handle.busy = False
+    handle.status = lambda: types.SimpleNamespace(
+        state=1, memory=types.SimpleNamespace(total=0), device_json="{}")
+    handle.metrics = lambda: {"tokens_generated": 3.0}
+    api.manager.loaded = lambda: ["m"]
+    api.manager.get = lambda name: handle
+
+    async def main():
+        try:
+            assert await asyncio.gather(
+                *(_stream(api, cfg) for _ in range(6))) == [2] * 6
+            reply = await api._backend_monitor(None)
+            return json.loads(reply.body)["m"]["metrics"]
+        finally:
+            await _close(api)
+
+    m = _run(main, default_threads=4)
+    assert m["tokens_generated"] == 3.0
+    assert m["gate_slots"] == 4 and m["gate_limit"] == 6
+    assert m["gate_grants"] == 6 and 0 <= m["gate_grants_ahead"] <= 2
+    assert m["streams_open"] == 0

@@ -16,7 +16,9 @@ window's start, its end, and the one after the last greedy check) in
   `decode_batch.over` (tokens per step DISPATCHED in the window);
 - a permit's life: the mean of each stage the program times, their sum
   against `permit_hold`, and what is left (the two crossings between the
-  HTTP process and the backend, which no single clock times).
+  HTTP process and the backend, which no single clock times);
+- the gate: its permits and the model's slots, the grants of the window
+  and how many of them were made ahead of a slot.
 
     python tools/rows_report.py --read chiprun_out/rows/<cell>.<seed>.json
 
@@ -92,6 +94,11 @@ def report(samples: list, slots: int) -> dict:
         "observations": {k: v[1] for k, v in mean.items()},
         "stages_ms": stage, "stages_sum_ms": total,
         "permit_hold_ms": hold,
+        # the gate's grants over the window and those made ahead of a slot
+        # (ISSUE 39; a tree without the counters reads nothing)
+        "gate": ({"grants": d("gate_grants"), "ahead": d("gate_grants_ahead"),
+                  "slots": after["gate_slots"], "limit": after["gate_limit"]}
+                 if "gate_grants" in after else None),
         "remainder_ms": (hold - total if None not in (hold, total)
                          else None)}
 
@@ -123,6 +130,12 @@ def render(r: dict, slots: int) -> str:
           f"{ms(r['permit_hold_ms'])}: {ms(r['remainder_ms'])} left; "
           f"gate_wait {ms(r['mean_ms']['gate_wait'])}; observations "
           f"{r['observations']}")
+    g = r.get("gate")
+    if g and g["grants"]:
+        out.append(f"the gate: {g['limit']:.0f} permits for {g['slots']:.0f} "
+                   f"slots; {g['grants']:.0f} grants in the window, "
+                   f"{g['ahead']:.0f} ahead of a slot "
+                   f"({g['ahead'] / g['grants']:.3f})")
     return "\n".join(out)
 
 
