@@ -41,6 +41,7 @@ import numpy as np
 from localai_tpu.models.kv import chunk_rows
 from localai_tpu.models.llama import (
     FULL,
+    LATENT,
     LINEAR,
     WINDOW,
     LlamaConfig,
@@ -437,8 +438,18 @@ class Engine:
         # (models/kv.py StateKV). It is not kept per position, so nothing
         # that lends, saves, shifts or takes back a prefix can serve it.
         self._linear = self._mixed and LINEAR in cfg.layer_types
+        # latent-attention layers: one buffer of latent rows a layer
+        # (models/kv.py LatentKV), kept per position, bfloat16
+        self._latent = self._mixed and LATENT in cfg.layer_types
         mixed_name = ("a model with linear-attention layers" if self._linear
+                      else "a model with latent-attention layers"
+                      if self._latent
                       else "a model with window and full attention layers")
+        if self._latent and self.mesh is not None:
+            raise ValueError(
+                f"{mixed_name} (kv_lora_rank) cannot be served under a "
+                "mesh: its weights and its cache have no sharding rule "
+                "(heads sharded against data-parallel attention)")
         if self._mixed and self._paged:
             raise ValueError(
                 f"{mixed_name} "
@@ -452,7 +463,8 @@ class Engine:
                 f"{mixed_name} "
                 "(layer_types) cannot be served with speculative decoding "
                 "(a draft model): the verify window writes ahead into a "
-                "ring, or a recurrent state, it may have to take back")
+                "ring, or a recurrent state, it may have to take back, and "
+                "a latent layer's verify window has no kernel")
         if self._paged:
             if self.ec.kv_pages < 2:
                 raise ValueError("kv_pages must be >= 2 (block 0 is trash)")
@@ -701,7 +713,7 @@ class Engine:
         # kernel_tiers reports of the view)
         if self._mixed:
             t, window = max((k.shape[-2] for k, kind in zip(
-                self._kc.slots, cfg.cache_kinds) if kind == FULL),
+                self._kc.slots, cfg.cache_kinds) if kind in (FULL, LATENT)),
                 default=0), None
         else:
             t = T if self._paged else self._kc.shape[-2]
@@ -729,6 +741,12 @@ class Engine:
                     for leaf in jax.tree_util.tree_leaves(slot))
                 if not self._linear:
                     self.metrics[f"decode_ctx_tokens__{kind}"] = 0
+            if self._latent:
+                # context rows a prompt chunk put through the layer's
+                # up-projection (W_kvb), for ONE latent layer: the blocks
+                # its attention visits, each expanded once a chunk
+                # (_credit_chunk_ctx); 0 for a chunk attended absorbed
+                self.metrics["chunk_latent_rows__expanded"] = 0
             if self._linear:
                 # what the decode steps consumed so far moved of each kind
                 # of cache (_credit_consumed): K and V bytes the softmax
@@ -2366,9 +2384,10 @@ class Engine:
         if req.context_shift and self._mixed:
             raise ValueError(
                 "context_shift is not supported for a model with window and "
-                "full attention layers, or linear-attention ones "
-                "(cache_shift moves one full-length cache, not a ring or a "
-                "recurrent state)")
+                "full attention layers, or linear-attention or "
+                "latent-attention ones (cache_shift moves one full-length "
+                "cache of keys and values, not a ring, a recurrent state or "
+                "a buffer of latents)")
         if req.prompt_cache_path and self._linear:
             raise ValueError(
                 "prompt_cache_path is not supported for a model with "
@@ -3174,9 +3193,11 @@ class Engine:
         layer's cache row its attention visits, and the row's capacity.
         Host arithmetic, once a chunk."""
         t, window, whole = self._full_row
-        self.metrics["chunk_ctx_tokens__attended"] += (
-            t if whole else chunk_rows(t, window, pos, self._chunk))
+        rows = t if whole else chunk_rows(t, window, pos, self._chunk)
+        self.metrics["chunk_ctx_tokens__attended"] += rows
         self.metrics["chunk_ctx_tokens__capacity"] += t
+        if self._latent:
+            self.metrics["chunk_latent_rows__expanded"] += rows
 
     def _credit_consumed(self, steps: int, entries=(), n_out=None,
                          rows=None):
@@ -3210,7 +3231,9 @@ class Engine:
             return
         if self._linear:
             return self._credit_cache_bytes(steps, entries, n_out)
-        window = self.cfg.sliding_window
+        # (a model without window layers: every step is "not yet a window
+        # long", and nothing is credited to a kind it does not have)
+        window = self.cfg.sliding_window or self.ec.max_context
         full = win = 0
         for i, rid in entries:
             slot = self._slots[i]
@@ -3221,8 +3244,9 @@ class Engine:
             k = min(n, max(window - lo, 0))     # steps not yet a window long
             full += n * lo + n * (n + 1) // 2
             win += k * lo + k * (k + 1) // 2 + (n - k) * window
-        self.metrics["decode_ctx_tokens__full"] += full
-        self.metrics["decode_ctx_tokens__window"] += win
+        for kind, tokens in ((FULL, full), (LATENT, full), (WINDOW, win)):
+            if f"decode_ctx_tokens__{kind}" in m:
+                m[f"decode_ctx_tokens__{kind}"] += tokens
 
     def _credit_live(self):
         """The consumed dispatch's tokens are emitted: what _emit took since
@@ -4508,6 +4532,8 @@ class Engine:
         the host counts: the dispatches in flight when a request ends (up to
         two of decode_loop or decode_block steps). Else the prompt is
         prefilled from 0, never over a stale ring."""
+        if WINDOW not in self.cfg.period:
+            return True
         ring = self._kc.slots[self.cfg.period.index("window")].shape[3]
         written = cached + 2 * max(self.ec.decode_loop, self.ec.decode_block,
                                    1)

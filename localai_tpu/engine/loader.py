@@ -22,7 +22,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from localai_tpu.models.llama import (
-    FULL, LINEAR, WINDOW, LlamaConfig, param_specs,
+    FULL, LATENT, LINEAR, WINDOW, LlamaConfig, param_specs,
 )
 
 # HF architectures the Llama-family decoder covers (SURVEY §2.2 row 1 scope).
@@ -71,11 +71,44 @@ LLAMA_FAMILY = {
             "ws_gate": "mlp.shared_experts.gate_proj.weight",
             "ws_up": "mlp.shared_experts.up_proj.weight",
             "ws_down": "mlp.shared_experts.down_proj.weight"}},
+    # latent attention (the config's kv_lora_rank and its kin say so), a
+    # leading dense layer, sandwich norms where `sandwich_norm` says so. No
+    # key of a pangu_ultra_moe config.json names the router's scores: every
+    # model of the architecture takes a sigmoid of each, without a
+    # selection bias (from memory of the modelling code; a softmax reading
+    # would drop this one field and nothing else)
+    "PanguUltraMoEForCausalLM": {"moe": True, "fields": {
+        "router_sigmoid": True},
+        # (from memory of the architecture's modelling code, unchecked;
+        # pre_mlp_layernorm and post_mlp_layernorm only under sandwich_norm,
+        # where post_attention_layernorm is the norm on attention's OUTPUT)
+        "tensors": {
+            "attn_norm": "input_layernorm.weight",
+            "wq_a": "self_attn.q_a_proj.weight",
+            "q_a_norm": "self_attn.q_a_layernorm.weight",
+            "wq_b": "self_attn.q_b_proj.weight",
+            "wkv_a": "self_attn.kv_a_proj_with_mqa.weight",
+            "kv_a_norm": "self_attn.kv_a_layernorm.weight",
+            "wkv_b": "self_attn.kv_b_proj.weight",
+            "wo": "self_attn.o_proj.weight",
+            "attn_post_norm": "post_attention_layernorm.weight",
+            "mlp_norm": "pre_mlp_layernorm.weight",
+            "mlp_post_norm": "post_mlp_layernorm.weight",
+            "w_gate": "mlp.gate_proj.weight", "w_up": "mlp.up_proj.weight",
+            "w_down": "mlp.down_proj.weight",
+            "moe_gate": "mlp.gate.weight",
+            "moe_w1": "mlp.experts.{e}.gate_proj.weight",
+            "moe_w3": "mlp.experts.{e}.up_proj.weight",
+            "moe_w2": "mlp.experts.{e}.down_proj.weight",
+            "ws_gate": "mlp.shared_experts.gate_proj.weight",
+            "ws_up": "mlp.shared_experts.up_proj.weight",
+            "ws_down": "mlp.shared_experts.down_proj.weight"}},
 }
 # config.json files that name no architecture
 _ARCH_OF_MODEL_TYPE = {"mellum": "MellumForCausalLM",
                        "solar_open2": "SolarOpen2ForCausalLM",
-                       "afmoe": "AfmoeForCausalLM"}
+                       "afmoe": "AfmoeForCausalLM",
+                       "pangu_ultra_moe": "PanguUltraMoEForCausalLM"}
 _LAYER_KINDS = {"full_attention": FULL, "sliding_attention": WINDOW}
 
 
@@ -189,6 +222,35 @@ def _linear_fields(hf: dict, n_layers: int) -> dict:
     }
 
 
+def _latent_fields(hf: dict, n_layers: int) -> dict:
+    """Latent attention (MLA), where the config has kv_lora_rank: every
+    layer is LATENT. What the layer cannot honour is refused by name."""
+    missing = [k for k in ("q_lora_rank", "qk_nope_head_dim",
+                           "qk_rope_head_dim", "v_head_dim")
+               if not hf.get(k)]
+    if missing:
+        raise ValueError(
+            f"kv_lora_rank without {missing}: a latent layer's query goes "
+            "through a low-rank pair too, and its heads' widths are stated")
+    if hf.get("rope_scaling") or hf.get("rope_parameters"):
+        raise ValueError(
+            "rope_scaling on a latent layer is not supported: the position "
+            "key rotates by rope_theta alone (a YaRN factor would also "
+            "scale the softmax, which the layer does not)")
+    if hf.get("num_key_value_heads") not in (None, hf["num_attention_heads"]):
+        raise ValueError(
+            "num_key_value_heads other than num_attention_heads on a latent "
+            "layer: the up-projection makes keys and values for every head")
+    return {"layer_types": (LATENT,) * n_layers,
+            "kv_lora_rank": hf["kv_lora_rank"],
+            "q_lora_rank": hf["q_lora_rank"],
+            "qk_nope_head_dim": hf["qk_nope_head_dim"],
+            "qk_rope_head_dim": hf["qk_rope_head_dim"],
+            "v_head_dim": hf["v_head_dim"],
+            # a query head's width (the softmax scale is its -1/2 power)
+            "head_dim": hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"]}
+
+
 def _read_config(model_dir: str) -> tuple[dict, str]:
     """config.json and the architecture it names (or its model_type does)."""
     with open(os.path.join(model_dir, "config.json")) as f:
@@ -257,6 +319,8 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
                                       or dense_first)
     if hf.get("mup_enabled"):
         kw["embed_scale"] = float(hf["hidden_size"]) ** 0.5
+    if hf.get("sandwich_norm"):
+        kw["post_norms"] = True
     kw.update(extra.get("fields", {}))
     if dtype is not None:
         # int8 = weight quantization; activations/KV stay bf16
@@ -265,6 +329,8 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
 
     if hf.get("linear_attn_config"):
         kw.update(_linear_fields(hf, kw["num_layers"]))
+    if hf.get("kv_lora_rank"):
+        kw.update(_latent_fields(hf, kw["num_layers"]))
     kinds = hf.get("layer_types")
     if kinds:
         unknown = set(kinds) - set(_LAYER_KINDS)

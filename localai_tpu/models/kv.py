@@ -16,6 +16,7 @@ over (`attend_window`), and how it rides the layer scan (`carried`).
     PagedKV   the block pool [L, NB, KVH, BS, D] behind a block table
     TieredKV  the pool under a sink_window policy (engine/kvtier.py)
     StateKV   a LINEAR layer's recurrent state and short-convolution tail
+    LatentKV  a LATENT layer's stack of latent rows, one buffer, no heads
     NoKV      nothing is kept (hidden_states)
 
 A model with several kinds of layer gets a tuple of views, one per place in
@@ -46,6 +47,7 @@ from localai_tpu.ops.paged import (
 from localai_tpu.parallel.mesh import current_mesh, seq_axis_size
 
 FULL, WINDOW, LINEAR = "full", "window", "linear"   # LlamaConfig.layer_types
+LATENT = "latent"
 # rows of a full-length dense cache a chunk's attention visits at a time
 # (DenseKV.attend_window): whole scale tiles, and it divides every served T
 CHUNK_BLOCK = 512
@@ -150,6 +152,16 @@ class NoKV:
         """This view over one layer's arrays (carried: the stack and the
         layer's index in it)."""
         return dataclasses.replace(self, k=k, v=v, layer=layer)
+
+    def of_layer(self, lp):
+        """This view for the layer whose weights are `lp`: a view that
+        needs none of them is itself (LatentKV takes the up-projection)."""
+        return self
+
+    def self_attend(self, fn, q, k, v, lengths):
+        """A prompt's self-attention by `fn` (prompt_attention) over the
+        K and V the layer handed to `attend`."""
+        return fn(q, k, v, lengths, sliding_window=self.window)
 
     quant = property(lambda self: isinstance(self.k, QuantKV))
 
@@ -470,6 +482,130 @@ class StateKV(NoKV):
         return o[:, None], dataclasses.replace(self, k=states, v=tails)
 
 
+def latent_row_width(rank: int, rope: int) -> int:
+    """Columns of a latent layer's cache row: the latent and the position
+    key side by side, padded with zeros to whole 128-lane tiles. A row of
+    576 is laid out token-minor by the compiler (576 is no multiple of 128,
+    the token axis is) and then copied whole, 2.5 GB at the served size, for
+    every call of a kernel that reads rows (PERF.md section 6, PR 40)."""
+    return -(-(rank + rope) // 128) * 128
+
+
+@dataclasses.dataclass
+class LatentKV(NoKV):
+    """A LATENT (latent attention, MLA) layer's cache: ONE buffer, `k` =
+    [L, B, T, W], slot b's position p in row (b, p): the normalised latent
+    c (R columns), the rotated position key k_pe every head shares (P), and
+    zeros up to W (latent_row_width). No heads axis and no V: `v` is None
+    (an empty place of the V tree); a head's keys and values are made of a
+    row by the layer's up-projection `w_kvb` (ops/mla.py), which the view is
+    given a layer (`of_layer`). The layer hands `attend` q [B, S, H, N + P]
+    and, as k, the rows to cache [B, S, R + P]; v is None.
+
+    decode         absorbed: the query takes W_UK in, the kernel
+                   (ops/pallas/mla.py; XLA twin ops/mla.mla_decode_xla)
+                   reads each block of rows once, as keys and as values of
+                   all H heads, and the output goes through W_UV;
+    attend_window  expanding: each CHUNK_BLOCK of rows up to the context the
+                   chunk has is put through W_kvb once and attended by
+                   heads of N + P / V (ops/attention.mha_extend_blocks). At
+                   H 128 a chunk of S tokens costs S x 278.5 k operations a
+                   cached row absorbed, 33.6 M + S x 81.9 k expanding: the
+                   expanding form wins from 171 tokens a chunk, at every
+                   context (measured: tools/mla_kernel_bench.py, PERF.md);
+    self_attend    a prompt from position 0: its own rows, expanded."""
+    heads: int = 0      # H
+    nope: int = 0       # N: a head's key columns made of the latent
+    rope: int = 0       # P: the position key's
+    rank: int = 0       # R: the latent's
+    vdim: int = 0       # V: a head's value columns
+    w_kvb: object = None
+    carried = True
+
+    scale = property(lambda self: (self.nope + self.rope) ** -0.5)
+
+    def of_layer(self, lp):
+        return dataclasses.replace(self, w_kvb=lp["wkv_b"])
+
+    def _expand(self, rows):
+        from localai_tpu.ops import mla
+
+        with jax.named_scope("expand"):
+            return mla.expand(rows[..., :self.rank + self.rope], self.w_kvb,
+                              self.heads, self.nope, self.rank)
+
+    def self_attend(self, fn, q, k, v, lengths):
+        kx, vx = (a.transpose(0, 2, 1, 3) for a in self._expand(k))
+        # `fn` takes values as wide as the keys: zeros beside them
+        vx = jnp.pad(vx, ((0, 0),) * 3 + ((0, kx.shape[-1] - self.vdim),))
+        return fn(q, kx, vx, lengths, sliding_window=None)[..., :self.vdim]
+
+    @jax.named_scope("cache_update")
+    def write(self, k, v, rows, positions, *, unique=True, **_):
+        """Rows [B, S, R + P] to (layer, rows[b], positions[b, s]); padding
+        lands past the slot's length, as in DenseKV.write."""
+        row = jnp.pad(k, ((0, 0), (0, 0),
+                          (0, self.k.shape[-1] - k.shape[-1])))
+        return dataclasses.replace(self, k=self.k.at[
+            self.layer, rows[:, None], positions].set(
+                row.astype(self.k.dtype), unique_indices=unique))
+
+    def append(self, k, v, lengths, positions):
+        """Decode's write; an inactive row aims at T - 1 (DenseKV.append)."""
+        if self.active is not None:
+            positions = jnp.where(self.active[:, None], positions,
+                                  self.k.shape[-2] - 1)
+        return self.write(k, v, jnp.arange(k.shape[0]), positions)
+
+    def _absorbed(self, q, decode):
+        """q [B, 1, H, N + P] through W_UK, the heads' sums of latents
+        `decode` makes of it [B, H, R] through W_UV -> [B, 1, H, V]."""
+        from localai_tpu.ops import mla
+
+        with jax.named_scope("absorb"):
+            q = mla.absorb(q, self.w_kvb, self.nope)[:, 0]
+            q = jnp.pad(q, ((0, 0), (0, 0),
+                            (0, self.k.shape[-1] - q.shape[-1])))
+        o = decode(q)
+        with jax.named_scope("absorb"):
+            return mla.unabsorb(o[:, None], self.w_kvb, self.nope)
+
+    def decode(self, q, lengths):
+        if not _pallas_attention(current_mesh()):
+            return self.decode_xla(q, lengths)
+        from localai_tpu.ops.pallas.mla import mla_decode
+
+        if self.active is not None:
+            lengths = jnp.where(self.active, lengths, 0)
+        return self._absorbed(q, lambda q: mla_decode(
+            q, self.k, lengths, self.layer, rank=self.rank,
+            scale=self.scale))
+
+    def decode_xla(self, q, lengths):
+        from localai_tpu.ops import mla
+
+        return self._absorbed(q, lambda q: mla.mla_decode_xla(
+            q, self.k[self.layer], lengths, self.rank, self.scale))
+
+    def attend_window(self, q, positions, start, rows, gathered):
+        t, width = self.k.shape[-2:]
+        block = min(CHUNK_BLOCK, t)
+
+        def cut(slot, n, first):
+            return jax.lax.dynamic_slice(
+                self.k, (self.layer, slot, first, 0),
+                (1, n, block, width))[0]
+
+        def fetch(first):
+            return self._expand(
+                jax.vmap(lambda slot: cut(slot, 1, first)[0])(rows)
+                if gathered else cut(0, self.k.shape[1], first))
+
+        return mha_extend_blocks(q, fetch, self.heads, t, positions, start,
+                                 block=block, scale=self.scale,
+                                 v_dim=self.vdim)
+
+
 def _kernel(name: str, quant: bool, sharded: bool = True):
     """ops/pallas's `name` for this cache: `name_q8` takes int8 bodies and
     scales; `name[_q8]_sharded` is the shard_map twin, per KV-head shard of a
@@ -741,7 +877,8 @@ def no_mixed(cfg, what: str):
     if cfg.layer_types is not None:
         raise NotImplementedError(
             f"{what} does not take a model with window and full layers, or "
-            "linear ones (layer_types): it knows one cache per layer stack")
+            "linear or latent ones (layer_types): it knows one cache of "
+            "keys and values per layer stack")
 
 
 def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
@@ -770,8 +907,15 @@ def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
                    redirect=redirect, **tier)
     if k_cache is None and cfg.period is None:
         return NoKV(window=window)
+
+    def latent(k=None):
+        return LatentKV(k, None, active=active, heads=cfg.num_heads,
+                        nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
+                        rank=cfg.kv_lora_rank, vdim=cfg.v_head_dim)
+
     if k_cache is None:
         return tuple(StateKV(heads=cfg.linear_heads) if kind == LINEAR
+                     else latent() if kind == LATENT
                      else NoKV(window=window if kind == WINDOW else None)
                      for kind in cfg.cache_kinds)
     if cfg.layer_types is None:
@@ -783,6 +927,8 @@ def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
     def one(k, v, kind):
         if kind == LINEAR:
             return StateKV(k, v, active=active, heads=cfg.linear_heads)
+        if kind == LATENT:
+            return latent(k)
         if kind == WINDOW:
             return RingKV(k, v, window, active=active, full_len=full_len)
         return DenseKV(k, v, None, active=active)

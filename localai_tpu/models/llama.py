@@ -27,8 +27,9 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from localai_tpu.models import kv
-from localai_tpu.models.kv import (  # noqa: F401 — the names tests and the
-    FULL, LINEAR, WINDOW, PeriodKV, _decode_dq,  # engine import from here
+# (the names tests and the engine import from here)
+from localai_tpu.models.kv import (  # noqa: F401
+    FULL, LATENT, LINEAR, WINDOW, PeriodKV, _decode_dq,
 )
 from localai_tpu.ops.norms import rms_norm
 from localai_tpu.ops.rope import RopeConfig, rope_table, apply_rope
@@ -113,6 +114,19 @@ class LlamaConfig:
                                 # before the residual add (sandwich norms)
     nope_kinds: tuple[str, ...] = ()    # layer kinds that do not rotate
     embed_scale: float = 1.0    # on the token embeddings
+    # LATENT layers (latent attention, MLA): the query through a low-rank
+    # pair with an RMSNorm between (q_lora_rank), keys and values made of a
+    # cached LATENT of kv_lora_rank (RMSNorm'd) by an up-projection, heads
+    # of qk_nope_head_dim + qk_rope_head_dim key columns (the last rotated,
+    # and shared by every head) and v_head_dim value columns. layer_types is
+    # then LATENT for every layer: the one kind that may stand alone there
+    # (it has a cache class of its own, kv.LatentKV, which the layer scan
+    # reaches by kind), with or without leading dense layers
+    kv_lora_rank: int = 0
+    q_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     def __post_init__(self):
         if self.router_experts and not (
@@ -130,15 +144,30 @@ class LlamaConfig:
                 "more layers than that, and layer_types (a one-kind stack "
                 "is one scan over one kind of MLP)")
         if self.layer_types is None:
+            if self.kv_lora_rank:
+                raise ValueError("kv_lora_rank (latent attention) needs "
+                                 f"layer_types of {LATENT!r}")
             return
         kinds = tuple(self.layer_types)
         object.__setattr__(self, "layer_types", kinds)
         object.__setattr__(self, "nope_kinds", tuple(self.nope_kinds))
         if (len(kinds) != self.num_layers
-                or set(kinds) - {FULL, WINDOW, LINEAR}):
+                or set(kinds) - {FULL, WINDOW, LINEAR, LATENT}):
             raise ValueError(
                 f"layer_types needs {self.num_layers} entries of "
-                f"{FULL!r}/{WINDOW!r}/{LINEAR!r}, got {kinds}")
+                f"{FULL!r}/{WINDOW!r}/{LINEAR!r}/{LATENT!r}, got {kinds}")
+        if LATENT in kinds:
+            if set(kinds) != {LATENT}:
+                raise ValueError(
+                    "latent layers beside layers of another kind are not "
+                    "taken: their weights differ in shape and are one stack")
+            if not (self.kv_lora_rank and self.q_lora_rank
+                    and self.qk_nope_head_dim and self.qk_rope_head_dim
+                    and self.v_head_dim):
+                raise ValueError(
+                    "latent layers need kv_lora_rank, q_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim and v_head_dim")
+            return
         if len(set(kinds)) == 1:
             raise ValueError(
                 "layer_types with one kind of layer: leave it None (and set "
@@ -168,6 +197,7 @@ class LlamaConfig:
         a layer of this config holds) and not as the flat dict a Llama,
         Mixtral or Mellum2 stack always was (kept: those models' draws)."""
         return bool(self.stacked_by_kind or self.leading_dense_layers
+                    or self.kv_lora_rank
                     or self.qk_norm or self.post_norms or self.attn_gate
                     or self.shared_expert_width or self.router_bias
                     or self.router_experts)
@@ -207,6 +237,9 @@ class LlamaConfig:
     def rope_of(self, kind: str | None) -> RopeConfig:
         if kind == WINDOW and self.window_rope is not None:
             return self.window_rope
+        if kind == LATENT:      # the position key's columns alone rotate
+            return dataclasses.replace(self.rope,
+                                       head_dim=self.qk_rope_head_dim)
         return self.rope
 
     @property
@@ -316,6 +349,17 @@ def layer_leaves(cfg: LlamaConfig, kind: str, dense: bool = False) -> dict:
             "A_log": ((nh,), "A_log"), "dt_bias": ((c,), "dt_bias"),
             "o_norm": ((d,), "ones"),
         })
+    elif kind == LATENT:
+        nh, r, qr = cfg.num_heads, cfg.kv_lora_rank, cfg.q_lora_rank
+        n, p, v = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+        out.update({
+            "wq_a": ((h, qr), h), "q_a_norm": ((qr,), "ones"),
+            "wq_b": ((qr, nh * (n + p)), qr),
+            # the latent and, beside it, the position key all heads share
+            "wkv_a": ((h, r + p), h), "kv_a_norm": ((r,), "ones"),
+            # a head's k_nope and v side by side, head-major
+            "wkv_b": ((r, nh * (n + v)), r),
+            "wo": ((nh * v, h), nh * v)})
     else:
         nh, nkv = cfg.num_heads, cfg.num_kv_heads
         out.update({"wq": ((h, nh * hd), h), "wk": ((h, nkv * hd), h),
@@ -358,9 +402,11 @@ def layer_stacks(cfg: LlamaConfig) -> dict:
                                    layer_leaves(cfg, kind))
                 for kind in sorted(set(cfg.layer_types))}
     lead = cfg.leading_dense_layers
-    out = {("layers",): (cfg.num_layers - lead, layer_leaves(cfg, FULL))}
+    # (window and full layers have the same leaves; latent ones stand alone)
+    kind = LATENT if cfg.kv_lora_rank else FULL
+    out = {("layers",): (cfg.num_layers - lead, layer_leaves(cfg, kind))}
     if lead:
-        out[("leading",)] = (lead, layer_leaves(cfg, FULL, dense=True))
+        out[("leading",)] = (lead, layer_leaves(cfg, kind, dense=True))
     return out
 
 
@@ -544,8 +590,10 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
     longest window `extend` will be given), LINEAR layers their state
     [L/p, B, H, D, D] float32 (in the K tree) and their short convolution's
     last inputs [L/p, B, K-1, 3 H D] in `dtype` (in the V tree): kv.StateKV.
-    Leading dense layers have a place each after the period's
-    (cfg.cache_kinds), sized by their kind.
+    LATENT layers one buffer [L/p, B, T, W] in the K tree (kv.LatentKV: the
+    latent and the position key a token, W = kv.latent_row_width) and None
+    in the V tree; never int8. Leading dense layers have a place each after
+    the period's (cfg.cache_kinds), sized by their kind.
     """
     quant = is_quant_kind(cache_type)
     dtype = dtype or cfg.jdtype
@@ -573,8 +621,18 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
         return (jnp.zeros((n, batch, nh, d, d), jnp.float32),
                 jnp.zeros((n, batch, cfg.linear_conv - 1, 3 * nh * d), dtype))
 
+    def latent(layers):
+        if quant:
+            raise ValueError(
+                f"cache_type {cache_type!r} is not supported for latent "
+                "layers: an int8 latent needs a kernel and a tolerance of "
+                "its own; serve it with cache_type_k: \"\"")
+        width = kv.latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)
+        return jnp.zeros((layers, batch, max_len, width), dtype), None
+
     # a place of the period holds its n layers, a leading layer's place one
     pairs = [state() if kind == LINEAR
+             else latent(n if j < len(period) else 1) if kind == LATENT
              else one(n if j < len(period) else 1,
                       ring if kind == WINDOW else max_len)
              for j, kind in enumerate(cfg.cache_kinds)]
@@ -1025,7 +1083,8 @@ def rope_tables(cfg: LlamaConfig, max_len: int):
     if cfg.layer_types is None:
         return rope_table(cfg.rope, max_len)
     tabs = {kind: rope_table(cfg.rope_of(kind), max_len)
-            for kind in (FULL, WINDOW) if cfg.rotates(kind)}
+            for kind in ((LATENT,) if LATENT in cfg.layer_types
+                         else (FULL, WINDOW)) if cfg.rotates(kind)}
     return ({kind: t[0] for kind, t in tabs.items()},
             {kind: t[1] for kind, t in tabs.items()})
 
@@ -1049,7 +1108,8 @@ def _layer_params(layers, i):
 
 def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
     """The transformer block, once: attn_norm → QKV (→ RMSNorm of q and k a
-    head) → RoPE by layer kind → `attend` (→ output gate) → wo → mlp_norm →
+    head) → RoPE by layer kind (a LATENT layer: the query and the latent row
+    it caches, _latent_qk) → `attend` (→ output gate) → wo → mlp_norm →
     MLP over the residual x [B, S, H]; where the layer has post-norms, wo's
     and the MLP's outputs are normalised before they are added. Every
     forward (and a pipeline stage) is this block over its own
@@ -1071,16 +1131,21 @@ def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
         x, out = _linear_mixer(cfg, x, h, lp, attend)
     else:
         with _attn_scope(kind):
-            q, k, v = _qkv(h, lp, cfg, spec=sharded("model"))
-            if "q_norm" in lp:
-                with jax.named_scope("qk_norm"):
-                    q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
-                    k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
-            if cfg.rotates(kind):
-                lcos, lsin = ((cos, sin) if kind is None
-                              else (cos[kind], sin[kind]))
-                q = apply_rope(q, lcos, lsin, positions)
-                k = apply_rope(k, lcos, lsin, positions)
+            if kind == LATENT:
+                q, k = _latent_qk(cfg, h, lp, cos[kind], sin[kind],
+                                  positions)
+                v = None    # (the view makes values of the rows it holds)
+            else:
+                q, k, v = _qkv(h, lp, cfg, spec=sharded("model"))
+                if "q_norm" in lp:
+                    with jax.named_scope("qk_norm"):
+                        q = rms_norm(q, lp["q_norm"], cfg.rms_eps)
+                        k = rms_norm(k, lp["k_norm"], cfg.rms_eps)
+                if cfg.rotates(kind):
+                    lcos, lsin = ((cos, sin) if kind is None
+                                  else (cos[kind], sin[kind]))
+                    q = apply_rope(q, lcos, lsin, positions)
+                    k = apply_rope(k, lcos, lsin, positions)
             if spec is not None:
                 q = _shard_act(q, sharded("model", None))
         attn, out = attend(q, k, v)
@@ -1100,6 +1165,29 @@ def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
     if spec is not None:
         x = _shard_act(x, sharded(None))
     return x, out
+
+
+def _latent_qk(cfg: LlamaConfig, h, lp, cos, sin, positions):
+    """A LATENT layer's query and the row it caches, of the normed input h
+    [B, S, H]: q [B, S, heads, N + P] = W_qb RMSNorm(W_qa h), its last P
+    columns rotated; row [B, S, R + P] = W_kva h, its first R (the latent)
+    RMSNorm'd, its last P (the position key ALL heads share) rotated. Keys
+    and values are made of rows by W_kvb where they are attended over
+    (kv.LatentKV), never here."""
+    b, s, _ = h.shape
+    n, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("q_lora"):
+        q = qmatmul(rms_norm(qmatmul(h, lp["wq_a"]), lp["q_a_norm"],
+                             cfg.rms_eps), lp["wq_b"])
+        q = q.reshape(b, s, cfg.num_heads, -1)
+        q = jnp.concatenate(
+            [q[..., :n], apply_rope(q[..., n:], cos, sin, positions)], -1)
+    with jax.named_scope("kv_lora"):
+        row = qmatmul(h, lp["wkv_a"])
+        k_pe = apply_rope(row[..., None, r:], cos, sin, positions)[..., 0, :]
+        row = jnp.concatenate(
+            [rms_norm(row[..., :r], lp["kv_a_norm"], cfg.rms_eps), k_pe], -1)
+    return q, row
 
 
 def _linear_mixer(cfg: LlamaConfig, x, h, lp, attend):
@@ -1202,8 +1290,9 @@ def _scan_layers(cfg: LlamaConfig, block, x, params, cache):
     p = len(period)
     lead = list(cache[p:])
     for j, kind in enumerate(cfg.leading_kinds):
-        x, lead[j] = block(x, _layer_params(params["leading"], j),
-                           lead[j].at(lead[j].k, lead[j].v, 0), kind)
+        lp = _layer_params(params["leading"], j)
+        x, lead[j] = block(
+            x, lp, lead[j].at(lead[j].k, lead[j].v, 0).of_layer(lp), kind)
 
     def place(i, j, kind):
         if not cfg.stacked_by_kind:
@@ -1216,8 +1305,9 @@ def _scan_layers(cfg: LlamaConfig, block, x, params, cache):
         x, ks, vs = carry
         ks, vs = list(ks), list(vs)
         for j, kind in enumerate(period):
-            x, view = block(x, place(i, j, kind),
-                            cache[j].at(ks[j], vs[j], i), kind)
+            lp = place(i, j, kind)
+            x, view = block(x, lp, cache[j].at(ks[j], vs[j], i).of_layer(lp),
+                            kind)
             ks[j], vs[j] = view.k, view.v
         return (x, tuple(ks), tuple(vs)), None
 
@@ -1270,8 +1360,8 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
 
         def attend(q, k, v):
             with _attn_scope(kind):
-                return self_attention(q, k, v, lengths,
-                                      sliding_window=view.window), (k, v)
+                return view.self_attend(self_attention, q, k, v,
+                                        lengths), (k, v)
 
         x, (k, v) = _block(cfg, x, lp, kind, cos, sin, positions, attend,
                            ("data", sax))
@@ -1662,8 +1752,8 @@ def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):
     def layer(x, lp, view, kind):
         def attend(q, k, v):
             with _attn_scope(kind):
-                return self_attention(q, k, v, lengths,
-                                      sliding_window=view.window), view
+                return view.self_attend(self_attention, q, k, v,
+                                        lengths), view
 
         if kind == LINEAR:
             def attend(u, conv, g, beta):  # noqa: F811

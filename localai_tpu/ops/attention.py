@@ -105,7 +105,7 @@ def block_span(lo_pos, hi_pos, t: int, block: int):
 
 
 def mha_extend_blocks(q, fetch, num_kv_heads, t, q_positions, start, *,
-                      block, scale=None, sliding_window=None):
+                      block, scale=None, sliding_window=None, v_dim=None):
     """mha_extend in work proportional to the context the window HAS: K and
     V are visited `block` rows at a time, from the block that holds the
     oldest position a query can see to the one that holds the newest
@@ -120,9 +120,11 @@ def mha_extend_blocks(q, fetch, num_kv_heads, t, q_positions, start, *,
     statistics as mha_extend, to which it agrees to the rounding of the
     products' dtype. A row past its query row's newest position weighs 0
     and its V is not multiplied (what an earlier tenant left there may not
-    be finite). Returns [B, S, H, D]."""
+    be finite). v_dim: the values' width where it is not the keys' (a
+    latent layer's heads). Returns [B, S, H, v_dim or D]."""
     b, s, h, d = q.shape
     scale = scale if scale is not None else d ** -0.5
+    v_dim = v_dim or d
     windowed = sliding_window is not None and sliding_window > 0
     qg = _group_query_heads(q, num_kv_heads).transpose(0, 2, 3, 1, 4)
     newest = start + s - 1                                      # [B]
@@ -156,9 +158,10 @@ def mha_extend_blocks(q, fetch, num_kv_heads, t, q_positions, start, *,
     _, l, acc = jax.lax.fori_loop(lo, hi, visit, (
         jnp.full(qg.shape[:-1], NEG_INF, jnp.float32),
         jnp.zeros(qg.shape[:-1], jnp.float32),
-        jnp.zeros(qg.shape, jnp.float32)))
+        jnp.zeros((*qg.shape[:-1], v_dim), jnp.float32)))
     out = acc / jnp.where(l > 0, l, 1.0)[..., None]
-    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d).astype(q.dtype)
+    return out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, v_dim).astype(
+        q.dtype)
 
 
 def mha_prefill_tiered(q, k, v, lengths, sinks, window, *, scale=None,

@@ -65,6 +65,18 @@ try:
         "localai_chunk_ctx_tokens_total",
         "Cache rows a prompt chunk's attention visited (attended) and the "
         "row's capacity, a full-attention layer a chunk", ["model", "rows"])
+    # context tokens ONE layer of each kind attended over in decode (engine
+    # decode_ctx_tokens__full / __window / __latent), and the context rows a
+    # latent layer's prompt chunks put through its up-projection
+    # (chunk_latent_rows__expanded)
+    _DECODE_CTX = Counter(
+        "localai_decode_ctx_tokens_total",
+        "Context tokens a decode step's live rows attended over, one layer "
+        "of each kind", ["model", "kind"])
+    _LATENT_ROWS = Counter(
+        "localai_chunk_latent_rows_total",
+        "Cached latent rows a prompt chunk expanded into keys and values, "
+        "a latent layer a chunk", ["model"])
     # streams open against the model's backend now (the gate's count)
     _STREAMS_OPEN = Gauge("localai_streams_open",
                           "Streams open against the model's backend",
@@ -1074,6 +1086,13 @@ class API:
                 if key.startswith("chunk_ctx_tokens__"):
                     _counter_sync(_CHUNK_CTX,
                                   (name, key.split("__", 1)[1]), float(v))
+                    continue
+                if key.startswith("decode_ctx_tokens__"):
+                    _counter_sync(_DECODE_CTX,
+                                  (name, key.split("__", 1)[1]), float(v))
+                    continue
+                if key == "chunk_latent_rows__expanded":
+                    _counter_sync(_LATENT_ROWS, (name,), float(v))
                     continue
                 for kind in ("host", "wait"):
                     prefix = f"engine_{kind}_ms__"

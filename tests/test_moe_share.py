@@ -113,6 +113,45 @@ def test_the_eight_shares_of_a_sigmoid_router_add_up_too(whole, grouped):
         assert float(jnp.abs(total - other).max()) > 0.02, fault
 
 
+@pytest.mark.parametrize("grouped", [True, False],
+                         ids=["grouped", "masked"])
+def test_sixteen_shares_of_a_sigmoid_router_without_bias_add_up(whole,
+                                                                grouped):
+    """The sum under openPangu-Ultra-MoE's router (PR 40): sigmoid scores,
+    NO selection bias, routed_scaling_factor 2.5, two experts a share, the
+    shared expert counted once, against the pangu reference's uncut layer."""
+    from localai_tpu.testing import reference_pangu as pangu
+
+    x = _x(seed=6)
+    rcfg = pangu.RefConfig(
+        vocab_size=64, hidden_size=H, num_layers=1, num_heads=4,
+        kv_lora_rank=16, qk_nope_head_dim=8, qk_rope_head_dim=4,
+        v_head_dim=8, rms_eps=1e-5, rope_theta=1e4, num_dense_layers=0,
+        num_experts=R, experts_per_tok=K, route_scale=2.5)
+    names = {"moe_gate": "router", "moe_w1": "w1", "moe_w2": "w2",
+             "moe_w3": "w3"}
+    rp = {names.get(k, k): v for k, v in whole.items()}
+    want = pangu.experts(x.reshape(-1, H), rp, rcfg)
+    over = dict(router_sigmoid=True, routed_scale=2.5)
+    total = jnp.zeros_like(want)
+    for n in range(16):
+        lp = _share(whole, 2 * n, held=2, shared=(n == 0))
+        total = total + _moe_routed(
+            x, lp, _cfg(first=2 * n, held=2, **over),
+            grouped=grouped).reshape(-1, H)
+    assert float(jnp.abs(total - want).max()) < 1e-5
+    for fault in (dict(scoring="softmax"), dict(route_scale=1.0)):
+        other = pangu.experts(x.reshape(-1, H), rp,
+                              dataclasses.replace(rcfg, **fault))
+        assert float(jnp.abs(total - other).max()) > 0.02, fault
+    # the shared expert counted sixteen times is far from it
+    twice = total + 15 * (_moe_routed(x, _share(whole, 0, held=2), _cfg(
+        held=2, **over)) - _moe_routed(x, _share(
+            whole, 0, held=2, shared=False), _cfg(
+                held=2, shared_expert_width=0, **over))).reshape(-1, H)
+    assert float(jnp.abs(twice - want).max()) > 0.05
+
+
 @pytest.mark.parametrize("first", [0, 12, 28])
 @pytest.mark.parametrize("int8", [False, True])
 def test_grouped_equals_masked_on_a_share(whole, first, int8):

@@ -56,13 +56,16 @@ SCOPES = ("expert_einsums", "router", "dispatch", "shared", "experts",
 # Pallas kernels are traced under no scope (a scope would change their
 # compile-cache key, models/llama.py): they are told by their own jit name
 KERNELS = {"kda_decode": "attention/linear/kda_decode",
+           "mla_decode": "attention/latent/mla_decode",
            "grouped_matmul": "experts/expert_einsums",
            "ragged_decode": "attention", "ragged_paged_attention": "attention",
            "flash_prefill": "attention", "paged_scatter_append": "cache_update",
            "ragged_scatter_append": "cache_update"}
-LAYER_KINDS = ("window", "full", "linear")
-# what a linear layer's mixer is made of (models/kv.py StateKV)
+LAYER_KINDS = ("window", "full", "linear", "latent")
+# what a linear layer's mixer is made of (models/kv.py StateKV), and a latent
+# layer's attention (models/llama.py _latent_qk, models/kv.py LatentKV)
 LINEAR_PARTS = ("conv", "kda_chunk", "kda_decode")
+LATENT_PARTS = ("q_lora", "kv_lora", "absorb", "expand")
 TOP = 10
 # what an annotation says the engine held as its tick's dispatch was enqueued
 ROWS = {"active": "rows_active", "prefill": "rows_prefill",
@@ -205,7 +208,8 @@ def scope_of(name: str, stats: dict) -> str:
                 # under attention/<kind> (models/llama.py _attn_scope)
                 kind = parts[parts.index(scope) + 1:][:1]
                 if scope == "attention" and kind and kind[0] in LAYER_KINDS:
-                    inner = [p for p in parts if p in LINEAR_PARTS]
+                    inner = [p for p in parts
+                             if p in LINEAR_PARTS + LATENT_PARTS]
                     return "/".join(["attention", kind[0]] + inner[:1])
                 return scope
         for part in parts:
