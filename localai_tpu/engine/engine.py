@@ -59,6 +59,7 @@ from localai_tpu.ops.sampling import (
     SamplingParams,
     sample,
     sampler_row,
+    topk_by_blocks,
 )
 from localai_tpu.parallel.mesh import activate_mesh
 from localai_tpu.testing import faults
@@ -650,6 +651,9 @@ class Engine:
             # requests it let in.
             "decode_dispatches_consumed": 0,
             "decode_steps_consumed": 0,
+            # ... and those of them whose program took the sort-free
+            # sampler's top-k in two stages (sampling.topk_by_blocks)
+            "decode_steps__topk_blocks": 0,
             "requests_admitted": 0,
             # rows x steps, tiled (_credit_consumed): the max_slots rows of
             # every consumed decode dispatch, each put down to one state for
@@ -2974,11 +2978,13 @@ class Engine:
             return active, prefill, free, 0
         return active, prefill, 0, free
 
-    def _rows_at_dispatch(self) -> tuple:
+    def _rows_at_dispatch(self, fast_width=None) -> tuple:
         """_row_states as a decode dispatch is enqueued: carried in `pend`
         to _credit_consumed, which multiplies it by the steps the device
         ran, noted in the tick ledger's record of the dispatch and, while
-        GET /debug/xprof traces, on the tick's next annotation."""
+        GET /debug/xprof traces, on the tick's next annotation. With them,
+        last, whether the dispatch's program samples at `fast_width` and
+        takes that top-k by blocks."""
         rows = self._row_states()
         if self._sched is not None:
             self._sched.rows(*rows)
@@ -2987,7 +2993,8 @@ class Engine:
             self._phases.note(rows_active=active, rows_prefill=prefill,
                               rows_free=queued + starved,
                               queued=self._queue.qsize())
-        return rows
+        return (*rows, bool(fast_width) and topk_by_blocks(
+            self.cfg.vocab_size, fast_width))
 
     def _block_steps(self) -> int:
         """How many decode steps the next dispatch may fuse. 1 whenever a
@@ -3101,7 +3108,7 @@ class Engine:
             # invariant bench.py's dense_fallback_reasons relies on)
             self._sched.reason("loop_native")
         gstate = self._gstate.copy() if self._grammar_slots > 0 else None
-        rows = self._rows_at_dispatch()
+        rows = self._rows_at_dispatch(fast)
         if self._ragged_loop_fn is not None:
             # ragged engines with the fused loop: pure-decode dispatches
             # ride the pack-free ragged-loop variant — same stop semantics
@@ -3160,7 +3167,7 @@ class Engine:
             res[i] = steps
             self._slots[i].inflight += steps
         self._mark_join(entries)
-        rows = self._rows_at_dispatch()
+        rows = self._rows_at_dispatch(fast)
         if steps > 1:
             fetch = self._dev_decode_block(active, steps, fast, gmask)
         else:
@@ -3215,7 +3222,9 @@ class Engine:
         m = self.metrics
         m["decode_dispatches_consumed"] += 1
         m["decode_steps_consumed"] += steps
-        active, prefill, queued, starved = rows or self._row_states()
+        active, prefill, queued, starved, by_blocks = (
+            rows or (*self._row_states(), False))
+        m["decode_steps__topk_blocks"] += steps * by_blocks
         m["decode_row_steps__spent"] += active * steps
         m["decode_row_steps__prefill"] += prefill * steps
         m["decode_row_steps__free_queued"] += queued * steps

@@ -21,6 +21,7 @@ import jax
 import jax.numpy as jnp
 
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
+TOPK_BLOCK = 128   # lanes a block of _top_k's first stage (a vector register's)
 
 
 @dataclasses.dataclass
@@ -282,13 +283,45 @@ def _draw(state: SamplerState, masked):
     return sampled_rank, carry_keys
 
 
+def topk_by_blocks(vocab: int, width: int) -> bool:
+    """Whether the top-`width` of a [B, vocab] row is taken in two stages
+    (_top_k): only where the `width` gathered blocks are less than the row.
+    A static fact of the program; the engine's counter asks it too."""
+    return vocab > width * TOPK_BLOCK
+
+
+def _top_k(logits, width: int):
+    """lax.top_k(logits, width) over float32 [B, V], to the bit, without
+    its pass over the whole row (a custom call at 40 x the row's read on a
+    200 k vocabulary): the `width` largest elements lie in the `width`
+    blocks of TOPK_BLOCK lanes with the largest maxima, since an element
+    with fewer than `width` elements ahead of it has fewer than `width`
+    blocks ahead of its own (each holds an element ahead of it, ties to the
+    lower index in both orders). The chosen blocks are gathered in the
+    row's own order, so the second call breaks ties as the one call does."""
+    b, v = logits.shape
+    if not topk_by_blocks(v, width):
+        return jax.lax.top_k(logits, width)
+    n = -(-v // TOPK_BLOCK)
+    # a ragged last block: lanes that come after every lane of the row
+    blocks = jnp.pad(logits, ((0, 0), (0, n * TOPK_BLOCK - v)),
+                     constant_values=-jnp.inf).reshape(b, n, TOPK_BLOCK)
+    _, ids = jax.lax.top_k(blocks.max(axis=-1), width)
+    ids = jnp.sort(ids, axis=-1)
+    cand = jnp.take_along_axis(blocks, ids[:, :, None], axis=1)
+    vals, pos = jax.lax.top_k(cand.reshape(b, width * TOPK_BLOCK), width)
+    order = (jnp.take_along_axis(ids, pos // TOPK_BLOCK, axis=-1)
+             * TOPK_BLOCK + pos % TOPK_BLOCK)
+    return vals, order
+
+
 def _sample_topk(logits, state: SamplerState, width: int):
     """Sort-free decode sampling over the top-`width` logits (see sample).
     Sequential-chain semantics identical to _filtered_sorted for any slot
     with 0 < top_k <= width and typical_p disabled."""
     b, v = logits.shape
     logits = pipeline_logits(logits, state, None)
-    vals, order = jax.lax.top_k(logits, width)                 # [B, W] desc
+    vals, order = _top_k(logits, width)                        # [B, W] desc
     rank = jnp.arange(width)[None, :]
     k = jnp.where(state.top_k > 0, state.top_k, width)[:, None]
     keep = rank < k

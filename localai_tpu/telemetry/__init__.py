@@ -32,8 +32,10 @@ engine tick or per request, never per token or per step.
   disables it alone).
 
 The engine's own counters (`engine.metrics`: dispatches and steps consumed,
-rows x steps by state (`decode_row_steps__*`), requests admitted, tokens by
-path, ...) ride the same GetMetrics map.
+the steps whose program took sampling's top-k by blocks
+(`decode_steps__topk_blocks`), rows x steps by state
+(`decode_row_steps__*`), requests admitted, tokens by path, ...) ride the
+same GetMetrics map.
 """
 from localai_tpu.telemetry.trace import (  # noqa: F401
     XPROF_MAX_S,

@@ -15,6 +15,7 @@ from fixtures import tiny_checkpoint
 
 STATES = ("live", "spent", "prefill", "free_queued", "free_starved")
 SLOTS = 4
+GREEDY = dict(temperature=0.0)
 
 
 @pytest.fixture(scope="module")
@@ -96,6 +97,45 @@ def test_identity_on_the_dense_paths(ckpt, path, ec_kw, want_variant):
     # the empty fourth slot, every step, with nothing queued to fill it
     assert rows["free_starved"] >= m["decode_steps_consumed"]
     assert rows["spent"] > 0
+
+
+@pytest.mark.parametrize("case, ec_kw, params, by_blocks", [
+    # a width of 1 puts the tiny vocabulary above width x 128 lanes
+    ("by blocks, single step",
+     dict(sampling_topk_width=1, decode_loop=0, decode_block=1), GREEDY, True),
+    ("by blocks, fused block",
+     dict(sampling_topk_width=1, decode_loop=0, decode_block=4), GREEDY, True),
+    ("by blocks, fused loop",
+     dict(sampling_topk_width=1, decode_loop=8), GREEDY, True),
+    ("the plain call at width 64", dict(decode_loop=8), GREEDY, False),
+    ("the full sort", dict(sampling_topk_width=1, decode_loop=8),
+     dict(temperature=0.8, top_k=0, seed=7), False),
+])
+def test_steps_whose_top_k_went_by_blocks_are_counted(ckpt, case, ec_kw,
+                                                      params, by_blocks):
+    """`decode_steps__topk_blocks` is `decode_steps_consumed` on a run whose
+    every dispatch sampled on the sort-free path at a vocabulary above
+    width x 128 (sampling.topk_by_blocks), and stays 0 on the plain call and
+    on the full sort; greedy tokens are the plain call's either way."""
+    from localai_tpu.ops.sampling import SamplingParams, topk_by_blocks
+
+    def run(**kw):
+        eng, tok = _engine(ckpt, **kw)
+        qs = [_submit(eng, tok, i, max_tokens=n,
+                      params=SamplingParams(**params))[1]
+              for i, n in enumerate((5, 11))]
+        _drain(eng)
+        return eng, [[o.token_id for o in q.queue] for q in qs]
+
+    eng, tokens = run(**ec_kw)
+    m = eng.metrics
+    width = eng.ec.sampling_topk_width
+    assert topk_by_blocks(eng.cfg.vocab_size, width) == (width == 1)
+    assert m["decode_steps_consumed"] > 0
+    assert m["decode_steps__topk_blocks"] == (
+        m["decode_steps_consumed"] if by_blocks else 0)
+    if by_blocks:
+        assert tokens == run(decode_loop=8)[1]
 
 
 def test_loop_with_an_early_finish_spends_the_rows_steps_after_it(ckpt):
