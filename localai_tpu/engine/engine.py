@@ -43,6 +43,7 @@ from localai_tpu.models.llama import (
     FULL,
     LATENT,
     LINEAR,
+    SSM,
     WINDOW,
     LlamaConfig,
     cache_shift,
@@ -438,11 +439,18 @@ class Engine:
         # linear-attention layers: a recurrent state beside the KV cache
         # (models/kv.py StateKV). It is not kept per position, so nothing
         # that lends, saves, shifts or takes back a prefix can serve it.
-        self._linear = self._mixed and LINEAR in cfg.layer_types
+        # (state-space layers likewise: kv.SsmKV; _state_kind names which)
+        self._state_kind = next(
+            (k for k in (LINEAR, SSM) if self._mixed
+             and k in cfg.layer_types), None)
+        self._linear = self._state_kind is not None
         # latent-attention layers: one buffer of latent rows a layer
         # (models/kv.py LatentKV), kept per position, bfloat16
         self._latent = self._mixed and LATENT in cfg.layer_types
-        mixed_name = ("a model with linear-attention layers" if self._linear
+        mixed_name = ("a model with state-space layers"
+                      if self._state_kind == SSM
+                      else "a model with linear-attention layers"
+                      if self._linear
                       else "a model with latent-attention layers"
                       if self._latent
                       else "a model with window and full attention layers")
@@ -451,6 +459,11 @@ class Engine:
                 f"{mixed_name} (kv_lora_rank) cannot be served under a "
                 "mesh: its weights and its cache have no sharding rule "
                 "(heads sharded against data-parallel attention)")
+        if self._state_kind == SSM and self.mesh is not None:
+            raise ValueError(
+                f"{mixed_name} (hybrid_override_pattern) cannot be served "
+                "under a mesh: its weights and its state have no sharding "
+                "rule")
         if self._mixed and self._paged:
             raise ValueError(
                 f"{mixed_name} "
@@ -761,9 +774,9 @@ class Engine:
                     / (cfg.layer_types.count(kind) * B)
                     / (self._kc.slots[kinds.index(kind)].shape[-2]
                        if kind == FULL else 1)
-                    for kind in (FULL, LINEAR)}
+                    for kind in (FULL, self._state_kind)}
                 self.metrics["decode_cache_bytes__full"] = 0
-                self.metrics["decode_cache_bytes__linear"] = 0
+                self.metrics[f"decode_cache_bytes__{self._state_kind}"] = 0
         if self._ragged:
             # token-budget utilization = ragged_tokens_packed /
             # (ragged_dispatches * ragged rows) — how full the flat stream
@@ -2388,14 +2401,15 @@ class Engine:
         if req.context_shift and self._mixed:
             raise ValueError(
                 "context_shift is not supported for a model with window and "
-                "full attention layers, or linear-attention or "
+                "full attention layers, or linear-attention, state-space or "
                 "latent-attention ones (cache_shift moves one full-length "
                 "cache of keys and values, not a ring, a recurrent state or "
                 "a buffer of latents)")
         if req.prompt_cache_path and self._linear:
             raise ValueError(
                 "prompt_cache_path is not supported for a model with "
-                "linear-attention layers: the disk prompt cache saves K and "
+                "linear-attention or state-space layers: the disk prompt "
+                "cache saves K and "
                 "V per position, and a recurrent state at a prefix's end is "
                 "not held")
         if req.context_shift and self._draft is not None:
@@ -3193,7 +3207,7 @@ class Engine:
         if self.cfg.num_experts:
             form = expert_form(self.cfg, call_tokens, self.mesh)
             self.metrics[f"expert_tokens__{form}"] += int(tokens) * (
-                self.cfg.num_layers - self.cfg.leading_dense_layers)
+                self.cfg.expert_layers)
 
     def _credit_chunk_ctx(self, pos: int):
         """A chunk from position `pos` is dispatched: the rows of a full
@@ -3301,8 +3315,9 @@ class Engine:
         m, per, count = (self.metrics, self._cache_bytes,
                          self.cfg.layer_types.count)
         m["decode_cache_bytes__full"] += int(full * per[FULL] * count(FULL))
-        m["decode_cache_bytes__linear"] += int(
-            2 * lin * per[LINEAR] * count(LINEAR))
+        state = self._state_kind
+        m[f"decode_cache_bytes__{state}"] += int(
+            2 * lin * per[state] * count(state))
 
     def _mark_join(self, entries):
         """Stamp the slots this decode dispatch is the first to carry, just

@@ -22,7 +22,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from localai_tpu.models.llama import (
-    FULL, LATENT, LINEAR, WINDOW, LlamaConfig, param_specs,
+    EXPERTS, FULL, LATENT, LINEAR, SSM, WINDOW, LlamaConfig, param_specs,
 )
 
 # HF architectures the Llama-family decoder covers (SURVEY §2.2 row 1 scope).
@@ -103,12 +103,27 @@ LLAMA_FAMILY = {
             "ws_gate": "mlp.shared_experts.gate_proj.weight",
             "ws_up": "mlp.shared_experts.up_proj.weight",
             "ws_down": "mlp.shared_experts.down_proj.weight"}},
+    # Mamba-2 state-space layers, NoPE GQA layers and latent expert layers,
+    # ONE part a layer (hybrid_override_pattern: M, *, E). What no key of a
+    # nemotron_h config.json states: the attention layers apply no position
+    # encoding (rope_theta and partial_rotary_factor are not used), and the
+    # router takes a sigmoid of each score and chooses by score + a
+    # selection bias (from the family's description; the modelling code was
+    # not at hand). Synthetic weights only: the weights are stacked by kind
+    # (load_params), and a checkpoint's names (backbone.layers.N.mixer.*,
+    # by the family's convention, unchecked) are in the benchmark's
+    # configuration file, not here
+    "NemotronHForCausalLM": {"moe": True, "fields": {
+        "router_sigmoid": True, "router_bias": True, "use_rope": False,
+        "expert_act": "relu2"}},
 }
 # config.json files that name no architecture
 _ARCH_OF_MODEL_TYPE = {"mellum": "MellumForCausalLM",
                        "solar_open2": "SolarOpen2ForCausalLM",
                        "afmoe": "AfmoeForCausalLM",
-                       "pangu_ultra_moe": "PanguUltraMoEForCausalLM"}
+                       "pangu_ultra_moe": "PanguUltraMoEForCausalLM",
+                       "nemotron_h": "NemotronHForCausalLM"}
+_PATTERN_KINDS = {"M": SSM, "*": FULL, "E": EXPERTS}
 _LAYER_KINDS = {"full_attention": FULL, "sliding_attention": WINDOW}
 
 
@@ -251,6 +266,57 @@ def _latent_fields(hf: dict, n_layers: int) -> dict:
             "head_dim": hf["qk_nope_head_dim"] + hf["qk_rope_head_dim"]}
 
 
+def _ssm_fields(hf: dict, n_layers: int) -> dict:
+    """A model of state-space, attention and expert layers, one part a
+    layer: `hybrid_override_pattern` has a letter a layer, M (Mamba-2), *
+    (attention) or E (experts). What the layers cannot honour is refused by
+    name."""
+    pattern = hf["hybrid_override_pattern"]
+    if "-" in pattern:
+        raise ValueError(
+            "hybrid_override_pattern with a `-` (a dense MLP layer) is not "
+            "supported: a layer that is a feed-forward part alone has "
+            "experts")
+    if set(pattern) - set(_PATTERN_KINDS) or len(pattern) != n_layers:
+        raise ValueError(
+            f"hybrid_override_pattern: {n_layers} letters of "
+            f"{sorted(_PATTERN_KINDS)} expected, got {pattern!r}")
+    for name in ("mamba_proj_bias", "use_bias", "mlp_bias"):
+        if hf.get(name):
+            raise ValueError(
+                f"{name}: true is not supported: no projection of a "
+                "state-space, attention or expert layer has a bias")
+    if (hf.get("num_nextn_predict_layers") or 0) > 0:
+        raise ValueError(
+            f"num_nextn_predict_layers {hf['num_nextn_predict_layers']} is "
+            "not supported: the multi-token-prediction layers are a draft "
+            "head, and speculative decoding takes a separate draft model "
+            "(set it to 0: they are not part of the forward pass)")
+    if hf.get("use_conv_bias") is False:
+        raise ValueError("use_conv_bias: false is not supported: the "
+                         "state-space layer's convolution has a bias")
+    for name, want in (("mamba_hidden_act", "silu"),
+                       ("mlp_hidden_act", "relu2")):
+        if hf.get(name, want) != want:
+            raise ValueError(f"{name} {hf[name]!r} is not supported: "
+                             f"{want} is what the layer computes")
+    kw = {"layer_types": tuple(_PATTERN_KINDS[c] for c in pattern),
+          "ssm_heads": hf["mamba_num_heads"],
+          "ssm_head_dim": hf["mamba_head_dim"],
+          "ssm_groups": hf.get("n_groups", 1),
+          "ssm_state": hf["ssm_state_size"],
+          "ssm_conv": hf.get("conv_kernel", 4),
+          "ssm_chunk": hf.get("chunk_size", 128),
+          "moe_latent": hf.get("moe_latent_size") or 0,
+          "rms_eps": _either(hf, "norm_eps", "layer_norm_epsilon",
+                             default=1e-5)}
+    if hf.get("moe_shared_expert_intermediate_size"):
+        kw["shared_expert_width"] = (
+            (hf.get("n_shared_experts") or 1)
+            * hf["moe_shared_expert_intermediate_size"])
+    return kw
+
+
 def _read_config(model_dir: str) -> tuple[dict, str]:
     """config.json and the architecture it names (or its model_type does)."""
     with open(os.path.join(model_dir, "config.json")) as f:
@@ -331,6 +397,8 @@ def load_config(model_dir: str, dtype: str | None = None) -> LlamaConfig:
         kw.update(_linear_fields(hf, kw["num_layers"]))
     if hf.get("kv_lora_rank"):
         kw.update(_latent_fields(hf, kw["num_layers"]))
+    if hf.get("hybrid_override_pattern"):
+        kw.update(_ssm_fields(hf, kw["num_layers"]))
     kinds = hf.get("layer_types")
     if kinds:
         unknown = set(kinds) - set(_LAYER_KINDS)
@@ -727,7 +795,7 @@ def _synthetic_params(cfg: LlamaConfig, *, dtype, mesh=None, qbits=None,
         def leaf(k, name, shape, how):
             if how == "ones":
                 return jnp.ones(shape, dtype)
-            if how in ("A_log", "dt_bias", "moe_bias"):
+            if isinstance(how, str):
                 return special_init(k, how, shape)
             if name.startswith("w") or name.startswith("moe_w"):
                 return qrand(k, shape, how)

@@ -16,6 +16,7 @@ over (`attend_window`), and how it rides the layer scan (`carried`).
     PagedKV   the block pool [L, NB, KVH, BS, D] behind a block table
     TieredKV  the pool under a sink_window policy (engine/kvtier.py)
     StateKV   a LINEAR layer's recurrent state and short-convolution tail
+    SsmKV     an SSM (Mamba-2) layer's state and convolution tail
     LatentKV  a LATENT layer's stack of latent rows, one buffer, no heads
     NoKV      nothing is kept (hidden_states)
 
@@ -48,6 +49,10 @@ from localai_tpu.parallel.mesh import current_mesh, seq_axis_size
 
 FULL, WINDOW, LINEAR = "full", "window", "linear"   # LlamaConfig.layer_types
 LATENT = "latent"
+SSM = "ssm"             # a Mamba-2 state-space mixer (ops/ssd.py)
+# a layer that is a feed-forward part ALONE (an expert layer without a mixer,
+# in a model whose other layers are a mixer alone): no cache place
+EXPERTS = "experts"
 # rows of a full-length dense cache a chunk's attention visits at a time
 # (DenseKV.attend_window): whole scale tiles, and it divides every served T
 CHUNK_BLOCK = 512
@@ -375,7 +380,34 @@ class RingKV(DenseKV):
 
 
 @dataclasses.dataclass
-class StateKV(NoKV):
+class _SlotState(NoKV):
+    """What the caches of a recurrent layer share (StateKV, SsmKV): `k` a
+    state a slot and `v` the short convolution's last inputs, both carried
+    by the layer scan and put back whole, a slot at a time."""
+    carried = True
+
+    @jax.named_scope("cache_update")
+    def _put(self, rows, state, tail):
+        return dataclasses.replace(
+            self,
+            k=self.k.at[self.layer, rows].set(state, unique_indices=False),
+            v=self.v.at[self.layer, rows].set(tail.astype(self.v.dtype),
+                                              unique_indices=False))
+
+    def _resume(self, rows, start, dtype):
+        """The state and the tail a window from `start` [B] goes on from:
+        the slots', or zeros where start is 0 (ADMISSION RESETS THE STATE
+        HERE, on the device, whatever the last tenant left)."""
+        fresh = (start == 0)
+        state = jnp.where(fresh[:, None, None, None], 0.0,
+                          self.k[self.layer, rows])
+        tail = jnp.where(fresh[:, None, None], 0,
+                         self.v[self.layer, rows]).astype(dtype)
+        return state, tail
+
+
+@dataclasses.dataclass
+class StateKV(_SlotState):
     """A LINEAR (gated delta rule) layer's cache: no keys and values but a
     recurrent STATE, `k` = [L, B, H, Dk, Dv] float32, and the last K-1
     inputs of the short convolution, `v` = [L, B, K-1, C] (C = the q, k and
@@ -397,7 +429,6 @@ class StateKV(NoKV):
     prefix of a slot's tokens cannot be lent to the next tenant (the engine
     reuses none), and nothing can be rolled back."""
     heads: int = 0
-    carried = True
 
     def _qkv(self, y):
         """The convolution's output [B, S, C] -> q, k, v [B, S, H, D]:
@@ -433,25 +464,13 @@ class StateKV(NoKV):
         return (jnp.zeros((b, self.heads, dv, dv), jnp.float32),
                 jnp.zeros((b, taps - 1, c), u.dtype))
 
-    @jax.named_scope("cache_update")
-    def _put(self, rows, state, tail):
-        return dataclasses.replace(
-            self,
-            k=self.k.at[self.layer, rows].set(state, unique_indices=False),
-            v=self.v.at[self.layer, rows].set(tail.astype(self.v.dtype),
-                                              unique_indices=False))
-
     def prompt(self, u, conv, g, beta, rows, lengths):
         state, tail = self._zeros(u, conv.shape[-1])
         o, state, tail = self._mix(u, conv, g, beta, tail, state, lengths)
         return o, (self if self.k is None else self._put(rows, state, tail))
 
     def chunk(self, u, conv, g, beta, rows, start, n):
-        fresh = (start == 0)
-        state = jnp.where(fresh[:, None, None, None], 0.0,
-                          self.k[self.layer, rows])
-        tail = jnp.where(fresh[:, None, None], 0,
-                         self.v[self.layer, rows]).astype(u.dtype)
+        state, tail = self._resume(rows, start, u.dtype)
         o, state, tail = self._mix(u, conv, g, beta, tail, state, n)
         return o, self._put(rows, state, tail)
 
@@ -480,6 +499,119 @@ class StateKV(NoKV):
                 states = self.k.at[self.layer].set(
                     jnp.where(active[:, None, None, None], new, old))
         return o[:, None], dataclasses.replace(self, k=states, v=tails)
+
+
+@dataclasses.dataclass
+class SsmKV(_SlotState):
+    """An SSM (Mamba-2) layer's cache: a recurrent STATE a head, `k` =
+    [L, B, H, P, N] float32 (P the head's channels, N the state size), and
+    the last K-1 inputs of the causal convolution, `v` = [L, B, K-1, C]
+    (C = the x, B and C channels side by side, before the convolution).
+    `of_layer` hands it the layer's small leaves (conv, conv_bias, dt_bias,
+    A_log, D); the forwards give it xBC [B, S, C] and the raw dt [B, S, H]
+    of W_in and get y [B, S, H, P] float32 (D x included) back. As StateKV:
+
+    prompt  from position 0, from a zero state; padding past a row's end
+            changes nothing.
+    chunk   a window from `start`: from the slot's state, or from zero
+            where start is 0 (ADMISSION RESETS THE STATE HERE, on the
+            device); `n` [B] real tokens.
+    step    decode's token, of the rows decoding (`active`); an inactive
+            row's state and tail are untouched.
+
+    The mathematics is ops/ssd.py's: the chunked form for a prompt or a
+    chunk, and on a TPU `step` is one Pallas kernel a layer
+    (ops/pallas/ssd.py: a live row's state read once, written once, in place
+    in the carried stack). Nothing is kept per position: no prefix is lent,
+    nothing is rolled back."""
+    heads: int = 0
+    groups: int = 0
+    state: int = 0          # N
+    chunk_size: int = 128
+    lp: object = None       # the layer's weights (of_layer)
+
+    def of_layer(self, lp):
+        return dataclasses.replace(self, lp=lp)
+
+    def _split(self, y, dt):
+        """The convolution's output [B, S, C] float32 and the raw dt ->
+        x [B, S, H, P], bm, cm [B, S, G, N], dt = softplus(dt + dt_bias)
+        [B, S, H], a = -exp(A_log) [H]."""
+        f32 = jnp.float32
+        b, s, c = y.shape
+        gn = self.groups * self.state
+        y = jax.nn.silu(y)
+        x = y[..., :c - 2 * gn].reshape(b, s, self.heads, -1)
+        bm = y[..., c - 2 * gn:c - gn].reshape(b, s, self.groups, self.state)
+        cm = y[..., c - gn:].reshape(b, s, self.groups, self.state)
+        dt = jax.nn.softplus(dt.astype(f32) + self.lp["dt_bias"].astype(f32))
+        return x, bm, cm, dt, -jnp.exp(self.lp["A_log"].astype(f32))
+
+    def _skip(self, y, x):
+        return y + self.lp["D"].astype(jnp.float32)[:, None] * x
+
+    def _mix(self, u, dt, tail, state, n):
+        from localai_tpu.ops.ssd import causal_conv, ssd_chunk
+
+        with jax.named_scope("conv"):
+            y, xx = causal_conv(tail, u, self.lp["conv"],
+                                self.lp["conv_bias"])
+            x, bm, cm, dt, a = self._split(y, dt)
+        with jax.named_scope("ssd_chunk"):
+            y, state = ssd_chunk(x, dt, a, bm, cm, state, n_valid=n,
+                                 chunk=self.chunk_size)
+            y = self._skip(y, x)
+        if n is None:
+            return y, state, xx[:, -tail.shape[1]:]
+        # the K-1 inputs ending at the row's last real token: token t is
+        # xx[t + K-1]
+        idx = n[:, None] + jnp.arange(tail.shape[1])[None, :]
+        return y, state, jnp.take_along_axis(xx, idx[..., None], axis=1)
+
+    def _zeros(self, u):
+        b, _, c = u.shape
+        p = (c - 2 * self.groups * self.state) // self.heads
+        return (jnp.zeros((b, self.heads, p, self.state), jnp.float32),
+                jnp.zeros((b, self.lp["conv"].shape[-1] - 1, c), u.dtype))
+
+    def prompt(self, u, dt, rows, lengths):
+        state, tail = self._zeros(u)
+        y, state, tail = self._mix(u, dt, tail, state, lengths)
+        return y, (self if self.k is None else self._put(rows, state, tail))
+
+    def chunk(self, u, dt, rows, start, n):
+        state, tail = self._resume(rows, start, u.dtype)
+        y, state, tail = self._mix(u, dt, tail, state, n)
+        return y, self._put(rows, state, tail)
+
+    def step(self, u, dt):
+        from localai_tpu.ops.ssd import causal_conv, ssd_step
+
+        b = u.shape[0]
+        active = (jnp.ones((b,), bool) if self.active is None
+                  else self.active)
+        with jax.named_scope("conv"):
+            old = self.v[self.layer]
+            y, xx = causal_conv(old, u, self.lp["conv"],
+                                self.lp["conv_bias"])
+            x, bm, cm, dt, a = (v if v.ndim == 1 else v[:, 0]
+                                for v in self._split(y, dt))
+            tail = jnp.where(active[:, None, None],
+                             xx[:, 1:].astype(old.dtype), old)
+            tails = self.v.at[self.layer].set(tail)
+        if _pallas_attention(current_mesh()):
+            from localai_tpu.ops.pallas.ssd import ssd_decode
+
+            y, states = ssd_decode(x, dt, a, bm, cm, self.k, self.layer,
+                                   active)
+        else:
+            with jax.named_scope("ssd_decode"):
+                old = self.k[self.layer]
+                y, new = ssd_step(x, dt, a, bm, cm, old)
+                states = self.k.at[self.layer].set(
+                    jnp.where(active[:, None, None, None], new, old))
+        return self._skip(y, x)[:, None], dataclasses.replace(
+            self, k=states, v=tails)
 
 
 def latent_row_width(rank: int, rope: int) -> int:
@@ -877,8 +1009,8 @@ def no_mixed(cfg, what: str):
     if cfg.layer_types is not None:
         raise NotImplementedError(
             f"{what} does not take a model with window and full layers, or "
-            "linear or latent ones (layer_types): it knows one cache of "
-            "keys and values per layer stack")
+            "linear, latent or state-space ones (layer_types): it knows one "
+            "cache of keys and values per layer stack")
 
 
 def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
@@ -913,9 +1045,15 @@ def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
                         nope=cfg.qk_nope_head_dim, rope=cfg.qk_rope_head_dim,
                         rank=cfg.kv_lora_rank, vdim=cfg.v_head_dim)
 
+    def ssm(k=None, v=None):
+        return SsmKV(k, v, active=active, heads=cfg.ssm_heads,
+                     groups=cfg.ssm_groups, state=cfg.ssm_state,
+                     chunk_size=cfg.ssm_chunk)
+
     if k_cache is None:
         return tuple(StateKV(heads=cfg.linear_heads) if kind == LINEAR
                      else latent() if kind == LATENT
+                     else ssm() if kind == SSM
                      else NoKV(window=window if kind == WINDOW else None)
                      for kind in cfg.cache_kinds)
     if cfg.layer_types is None:
@@ -929,6 +1067,8 @@ def view(cfg, k_cache, v_cache, table=None, kvt=None, *, pool=False,
             return StateKV(k, v, active=active, heads=cfg.linear_heads)
         if kind == LATENT:
             return latent(k)
+        if kind == SSM:
+            return ssm(k, v)
         if kind == WINDOW:
             return RingKV(k, v, window, active=active, full_len=full_len)
         return DenseKV(k, v, None, active=active)

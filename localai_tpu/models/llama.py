@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 from localai_tpu.models import kv
 # (the names tests and the engine import from here)
 from localai_tpu.models.kv import (  # noqa: F401
-    FULL, LATENT, LINEAR, WINDOW, PeriodKV, _decode_dq,
+    EXPERTS, FULL, LATENT, LINEAR, SSM, WINDOW, PeriodKV, _decode_dq,
 )
 from localai_tpu.ops.norms import rms_norm
 from localai_tpu.ops.rope import RopeConfig, rope_table, apply_rope
@@ -127,6 +127,26 @@ class LlamaConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # SSM layers (Mamba-2 state-space mixers, ops/ssd.py): heads x channels
+    # a head (the inner width), the groups that share B and C, the state
+    # size, the causal convolution's taps (with a bias), the chunk of the
+    # chunked form. Such a model's layers are ONE of a mixer (SSM, FULL) or
+    # a feed-forward part (EXPERTS) each, x + part(norm(x)): layer_types
+    # names all three, the weights are stacked by kind, and only the mixers
+    # have a place in the cache (cache_places)
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 1
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # the experts' form: "swiglu" (three matrices, silu(W1 x) W3 x) or
+    # "relu2" (two, W2 relu(W1 x)^2, the shared expert too); and the width
+    # of the latent the routed experts work in (0: the hidden size): two
+    # more matrices a layer, in before the experts and out after their
+    # weighted sum (the router and the shared expert read the hidden state)
+    expert_act: str = "swiglu"
+    moe_latent: int = 0
 
     def __post_init__(self):
         if self.router_experts and not (
@@ -152,10 +172,30 @@ class LlamaConfig:
         object.__setattr__(self, "layer_types", kinds)
         object.__setattr__(self, "nope_kinds", tuple(self.nope_kinds))
         if (len(kinds) != self.num_layers
-                or set(kinds) - {FULL, WINDOW, LINEAR, LATENT}):
+                or set(kinds) - {FULL, WINDOW, LINEAR, LATENT, SSM, EXPERTS}):
             raise ValueError(
                 f"layer_types needs {self.num_layers} entries of "
-                f"{FULL!r}/{WINDOW!r}/{LINEAR!r}/{LATENT!r}, got {kinds}")
+                f"{FULL!r}/{WINDOW!r}/{LINEAR!r}/{LATENT!r}/{SSM!r}/"
+                f"{EXPERTS!r}, got {kinds}")
+        if self.expert_act not in ("swiglu", "relu2"):
+            raise ValueError(f"expert_act {self.expert_act!r}: swiglu or "
+                             "relu2")
+        if SSM in kinds or EXPERTS in kinds:
+            if (set(kinds) - {FULL, SSM, EXPERTS} or EXPERTS not in kinds
+                    or not self.num_experts or lead):
+                raise ValueError(
+                    "state-space layers and layers that are a feed-forward "
+                    "part alone come together, beside full-attention "
+                    f"layers, with experts and no leading layers; got "
+                    f"{kinds}")
+            if SSM in kinds and not (self.ssm_heads and self.ssm_head_dim
+                                     and self.ssm_state
+                                     and self.ssm_heads % self.ssm_groups
+                                     == 0):
+                raise ValueError(
+                    "state-space layers need ssm_heads (a multiple of "
+                    "ssm_groups), ssm_head_dim and ssm_state")
+            return
         if LATENT in kinds:
             if set(kinds) != {LATENT}:
                 raise ValueError(
@@ -189,7 +229,22 @@ class LlamaConfig:
 
     @property
     def stacked_by_kind(self) -> bool:
-        return self.layer_types is not None and LINEAR in self.layer_types
+        return self.layer_types is not None and bool(
+            {LINEAR, SSM, EXPERTS} & set(self.layer_types))
+
+    @property
+    def split_layers(self) -> bool:
+        """Whether a layer is a mixer or a feed-forward part ALONE (its own
+        norm, its own residual add): the kinds then name both."""
+        return self.layer_types is not None and EXPERTS in self.layer_types
+
+    @property
+    def expert_layers(self) -> int:
+        """How many layers have experts."""
+        if not self.num_experts:
+            return 0
+        return (self.layers_of(EXPERTS) if self.split_layers
+                else self.num_layers - self.leading_dense_layers)
 
     @property
     def drawn_by_leaf(self) -> bool:
@@ -228,7 +283,17 @@ class LlamaConfig:
         """The layer kind of each place of the cache (kv.PeriodKV.slots):
         the period's places, each [L / period, ...], then one place of
         [1, ...] a leading layer."""
-        return self.period and self.period + self.leading_kinds
+        return self.period and tuple(
+            k for k in self.period if k != EXPERTS) + self.leading_kinds
+
+    @property
+    def cache_places(self) -> tuple | None:
+        """For each place of the period, its place in the cache, or None
+        for a layer without a mixer (EXPERTS)."""
+        if self.period is None:
+            return None
+        at = iter(range(len(self.period)))
+        return tuple(None if k == EXPERTS else next(at) for k in self.period)
 
     def rotates(self, kind: str | None) -> bool:
         """Whether a layer of this kind rotates q and k (RoPE)."""
@@ -280,7 +345,7 @@ def init_params(cfg: LlamaConfig, key, dtype=None):
         def leaf(k, name, shape, how):
             if how == "ones":
                 return jnp.ones(shape, dtype)
-            if how in ("A_log", "dt_bias", "moe_bias"):
+            if isinstance(how, str):
                 return special_init(k, how, shape)
             out = norm(k, shape, how)
             return out.astype(jnp.float32) if name == "moe_gate" else out
@@ -328,12 +393,27 @@ def init_params(cfg: LlamaConfig, key, dtype=None):
 def layer_leaves(cfg: LlamaConfig, kind: str, dense: bool = False) -> dict:
     """One layer's leaves for a model whose weights are drawn leaf by leaf
     (cfg.drawn_by_leaf): name -> (shape without the layer axis, how it is
-    drawn: a matrix's fan-in, "ones", or special_init's "A_log" / "dt_bias"
-    / "moe_bias"). Matrices are the names that start with `w` or `moe_w`
+    drawn: a matrix's fan-in, "ones", or one of special_init's names).
+    Matrices are the names that start with `w` or `moe_w`
     (ops/quant.quantize_params). dense: a leading dense layer's (a SwiGLU of
     intermediate_size where the others have their experts)."""
     h, hd = cfg.hidden_size, cfg.head_dim
+    if kind == EXPERTS:
+        return {"mlp_norm": ((h,), "ones"), **_expert_leaves(cfg)}
     out = {"attn_norm": ((h,), "ones")}
+    if kind == SSM:
+        nh, gn = cfg.ssm_heads, cfg.ssm_groups * cfg.ssm_state
+        inner = nh * cfg.ssm_head_dim
+        c = inner + 2 * gn
+        out.update({
+            # z (the gate) | x, B, C (through the convolution) | dt a head
+            "w_in": ((h, inner + c + nh), h),
+            "conv": ((c, cfg.ssm_conv), cfg.ssm_conv),
+            "conv_bias": ((c,), 16),
+            "dt_bias": ((nh,), "dt_bias"), "A_log": ((nh,), "A_log"),
+            "D": ((nh,), "D"), "ssm_norm": ((inner,), "ones"),
+            "w_out": ((inner, h), inner)})
+        return out
     if kind == LINEAR:
         nh, d, r = cfg.linear_heads, cfg.linear_head_dim, cfg.linear_gate_rank
         c = nh * d
@@ -368,27 +448,44 @@ def layer_leaves(cfg: LlamaConfig, kind: str, dense: bool = False) -> dict:
             out.update({"q_norm": ((hd,), "ones"), "k_norm": ((hd,), "ones")})
         if cfg.attn_gate:
             out["w_agate"] = ((h, nh * hd), h)
+    if cfg.split_layers:
+        return out
     if cfg.post_norms:
         out["attn_post_norm"] = ((h,), "ones")
     out["mlp_norm"] = ((h,), "ones")
     if cfg.post_norms:
         out["mlp_post_norm"] = ((h,), "ones")
-    e, i = cfg.num_experts, cfg.expert_width
-    if dense or not e:
+    if dense or not cfg.num_experts:
         i = cfg.intermediate_size
         out.update({"w_gate": ((h, i), h), "w_up": ((h, i), h),
                     "w_down": ((i, h), i)})
         return out
+    out.update(_expert_leaves(cfg))
+    return out
+
+
+def _expert_leaves(cfg: LlamaConfig) -> dict:
+    """An expert layer's leaves beside its norm: the router (and its
+    selection bias), the routed experts held (two matrices each under
+    relu2, three under swiglu; over the latent where the model has one, and
+    then the pair of matrices into and out of it), the shared expert."""
+    h, e, i = cfg.hidden_size, cfg.num_experts, cfg.expert_width
+    gated = cfg.expert_act == "swiglu"
     routers = cfg.router_experts or e
-    out.update({"moe_gate": ((h, routers), h),
-                "moe_w1": ((e, h, i), h), "moe_w2": ((e, i, h), i),
-                "moe_w3": ((e, h, i), h)})
+    lat = cfg.moe_latent or h
+    out = {"moe_gate": ((h, routers), h),
+           "moe_w1": ((e, lat, i), lat), "moe_w2": ((e, i, lat), i)}
+    if gated:
+        out["moe_w3"] = ((e, lat, i), lat)
     if cfg.router_bias:
         out["moe_bias"] = ((routers,), "moe_bias")
+    if cfg.moe_latent:
+        out.update({"w_lat_in": ((h, lat), h), "w_lat_out": ((lat, h), lat)})
     if cfg.shared_expert_width:
         w = cfg.shared_expert_width
-        out.update({"ws_gate": ((h, w), h), "ws_up": ((h, w), h),
-                    "ws_down": ((w, h), w)})
+        if gated:
+            out["ws_gate"] = ((h, w), h)
+        out.update({"ws_up": ((h, w), h), "ws_down": ((w, h), w)})
     return out
 
 
@@ -434,9 +531,14 @@ def special_init(key, name: str, shape):
     about 0.1 and 0.999 and mostly in 0.9-0.999: never 0 or 1, so a decay
     left out or misapplied shows. The router's selection bias (moe_bias):
     N(0, 0.02^2), small and not zero, so that a bias left out of the choice,
-    or added to the weights, shows."""
+    or added to the weights, shows. A state-space layer's A_log and dt_bias
+    are a head's and drawn the same way (its family's initialisation too:
+    time_step_min / time_step_max are 1e-3 / 1e-1); its skip D is U(0.5,
+    1.5), not the family's ones, so that a D term left out shows."""
     if name == "moe_bias":
         return 0.02 * jax.random.normal(key, shape, jnp.float32)
+    if name == "D":
+        return jax.random.uniform(key, shape, jnp.float32, 0.5, 1.5)
     if name == "A_log":
         return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
     dt = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
@@ -592,8 +694,11 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
     last inputs [L/p, B, K-1, 3 H D] in `dtype` (in the V tree): kv.StateKV.
     LATENT layers one buffer [L/p, B, T, W] in the K tree (kv.LatentKV: the
     latent and the position key a token, W = kv.latent_row_width) and None
-    in the V tree; never int8. Leading dense layers have a place each after
-    the period's (cfg.cache_kinds), sized by their kind.
+    in the V tree; never int8. SSM layers their state [L/p, B, H, P, N]
+    float32 and their convolution's last inputs [L/p, B, K-1, C]
+    (kv.SsmKV); a layer that is a feed-forward part alone has no place.
+    Leading dense layers have a place each after the period's
+    (cfg.cache_kinds), sized by their kind.
     """
     quant = is_quant_kind(cache_type)
     dtype = dtype or cfg.jdtype
@@ -621,6 +726,13 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
         return (jnp.zeros((n, batch, nh, d, d), jnp.float32),
                 jnp.zeros((n, batch, cfg.linear_conv - 1, 3 * nh * d), dtype))
 
+    def ssm():
+        c = (cfg.ssm_heads * cfg.ssm_head_dim
+             + 2 * cfg.ssm_groups * cfg.ssm_state)
+        return (jnp.zeros((n, batch, cfg.ssm_heads, cfg.ssm_head_dim,
+                           cfg.ssm_state), jnp.float32),
+                jnp.zeros((n, batch, cfg.ssm_conv - 1, c), dtype))
+
     def latent(layers):
         if quant:
             raise ValueError(
@@ -631,7 +743,7 @@ def init_kv_cache(cfg: LlamaConfig, batch: int, max_len: int, dtype=None,
         return jnp.zeros((layers, batch, max_len, width), dtype), None
 
     # a place of the period holds its n layers, a leading layer's place one
-    pairs = [state() if kind == LINEAR
+    pairs = [state() if kind == LINEAR else ssm() if kind == SSM
              else latent(n if j < len(period) else 1) if kind == LATENT
              else one(n if j < len(period) else 1,
                       ring if kind == WINDOW else max_len)
@@ -690,6 +802,11 @@ def _lm_head(x32, params):
                     preferred_element_type=jnp.float32)
         return y * head["s"].astype(jnp.float32)
     return qmatmul(x32, head)
+
+
+def _relu2(a):
+    """relu(a)^2: the activation of experts that are not gated."""
+    return jnp.square(jax.nn.relu(a))
 
 
 ROUTED, DENSE = "routed", "dense"
@@ -835,7 +952,8 @@ def _leading(p):
 
 
 def _grouped_experts(xt, eid, weight, up, gate, down, tm: int):
-    """SwiGLU experts over the token-expert pairs of xt [N, h]: eid [N, k]
+    """SwiGLU experts (gate None: relu^2 experts of two matrices, up and
+    down) over the token-expert pairs of xt [N, h]: eid [N, k]
     is the expert (among those held) each of a token's k pairs chose, or
     the number held for a pair of no expert here; weight [N, k] float32 is
     the pair's share of its token. up, gate, down: _InStack (the stacks the
@@ -902,14 +1020,18 @@ def _grouped_experts(xt, eid, weight, up, gate, down, tm: int):
             scale, (*le, 0), (1, 1, scale.shape[2]))[0, 0].astype(y.dtype)
 
     def weighted(u, g, w):
-        """silu(u) g under the rows' weights, in float32."""
-        return (jax.nn.silu(u.astype(jnp.float32)) * g.astype(jnp.float32)
-                * w).astype(xt.dtype)
+        """silu(u) g (no gate: relu(u)^2) under the rows' weights, in
+        float32."""
+        u = u.astype(jnp.float32)
+        if g is None:
+            return (_relu2(u) * w).astype(xt.dtype)
+        return (jax.nn.silu(u) * g.astype(jnp.float32) * w).astype(xt.dtype)
 
     def tile(t, out):
         e = tile_e[t]
         a = jax.lax.dynamic_index_in_dim(xp, t, keepdims=False)
-        act = weighted(product(a, up, e), product(a, gate, e),
+        act = weighted(product(a, up, e),
+                       None if gate is None else product(a, gate, e),
                        jax.lax.dynamic_index_in_dim(row_w, t, keepdims=False))
         return jax.lax.dynamic_update_index_in_dim(
             out, product(act, down, e), t, 0)
@@ -924,8 +1046,9 @@ def _grouped_experts(xt, eid, weight, up, gate, down, tm: int):
 
     with jax.named_scope("expert_einsums"):
         if kv._pallas(current_mesh() is None):
-            out = grouped(weighted(grouped(xp, up), grouped(xp, gate), row_w),
-                          down)
+            out = grouped(weighted(
+                grouped(xp, up), None if gate is None else grouped(xp, gate),
+                row_w), down)
         else:
             out = jax.lax.fori_loop(0, used, tile,
                                     jnp.zeros((tiles, tm, h), xt.dtype))
@@ -984,10 +1107,15 @@ def _moe_routed(x, lp, cfg: LlamaConfig, grouped: bool = True):
         top_w = top_w * cfg.routed_scale
         local = top_i - cfg.first_expert                       # [N, k]
         here = (local >= 0) & (local < held)
-    w1, w2, w3 = (lp[n] if isinstance(lp[n], _InStack)
+    w1, w2, w3 = (None if n not in lp
+                  else lp[n] if isinstance(lp[n], _InStack)
                   else _InStack(jax.tree_util.tree_map(lambda a: a[None],
                                                        lp[n]), 0)
                   for n in ("moe_w1", "moe_w2", "moe_w3"))
+    hidden = xt
+    if "w_lat_in" in lp:    # the routed experts work in a latent
+        with jax.named_scope("latent_in"):
+            xt = qmatmul(hidden, lp["w_lat_in"])
     if grouped:
         y = _grouped_experts(
             xt, jnp.where(here, local, held), top_w, w1, w3, w2,
@@ -1006,14 +1134,25 @@ def _moe_routed(x, lp, cfg: LlamaConfig, grouped: bool = True):
                     x.dtype)
 
             h1 = jnp.einsum("nh,ehi->nei", xt, dq(w1))
-            h3 = jnp.einsum("nh,ehi->nei", xt, dq(w3))
-            y = jnp.einsum("nei,eih->neh", jax.nn.silu(h1) * h3, dq(w2))
+            if w3 is None:
+                act = _relu2(h1)
+            else:
+                h3 = jnp.einsum("nh,ehi->nei", xt, dq(w3))
+                act = jax.nn.silu(h1) * h3
+            y = jnp.einsum("nei,eih->neh", act, dq(w2))
             y = jnp.einsum("neh,ne->nh", y.astype(jnp.float32), combine)
-    if "ws_gate" in lp:
+    if "w_lat_out" in lp:
+        with jax.named_scope("latent_out"):
+            y = qmatmul(y.astype(x.dtype), lp["w_lat_out"]).astype(
+                jnp.float32)
+    if "ws_up" in lp:
         with jax.named_scope("shared"):
-            y = y + qmatmul(jax.nn.silu(qmatmul(xt, lp["ws_gate"]))
-                            * qmatmul(xt, lp["ws_up"]),
-                            lp["ws_down"]).astype(jnp.float32)
+            if "ws_gate" in lp:
+                act = (jax.nn.silu(qmatmul(hidden, lp["ws_gate"]))
+                       * qmatmul(hidden, lp["ws_up"]))
+            else:
+                act = _relu2(qmatmul(hidden, lp["ws_up"]))
+            y = y + qmatmul(act, lp["ws_down"]).astype(jnp.float32)
     return y.astype(x.dtype).reshape(b, s, h)
 
 
@@ -1129,6 +1268,8 @@ def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
     h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
     if kind == LINEAR:
         x, out = _linear_mixer(cfg, x, h, lp, attend)
+    elif kind == SSM:
+        x, out = _ssm_mixer(cfg, x, h, lp, attend)
     else:
         with _attn_scope(kind):
             if kind == LATENT:
@@ -1157,6 +1298,8 @@ def _block(cfg: LlamaConfig, x, lp, kind, cos, sin, positions, attend, spec):
             if "attn_post_norm" in lp:
                 o = rms_norm(o, lp["attn_post_norm"], cfg.rms_eps)
             x = x + o
+    if "mlp_norm" not in lp:    # a layer that is its mixer alone
+        return x, out
     m = _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_eps), lp, cfg,
              spec_prefix=spec)
     if "mlp_post_norm" in lp:
@@ -1216,6 +1359,38 @@ def _linear_mixer(cfg: LlamaConfig, x, h, lp, attend):
         o = rms_norm(o, lp["o_norm"], cfg.rms_eps).astype(h.dtype)
         gate = jax.nn.sigmoid(qmatmul(qmatmul(h, lp["w_g1"]), lp["w_g2"]))
         return x + qmatmul(o.reshape(b, s, -1) * gate, lp["wo"]), out
+
+
+def _ssm_mixer(cfg: LlamaConfig, x, h, lp, attend):
+    """An SSM (Mamba-2) layer's mixer over the normed input h [B, S, H]:
+    [z | xBC | dt] = W_in h; `attend(xBC, dt) -> (y [B, S, heads, P]
+    float32, out)` is the cache's view's (kv.SsmKV: the convolution with
+    its tail, the state, D x); then y silu(z), RMSNorm over each of the
+    groups' channels with the layer's gain, and W_out."""
+    b, s, _ = h.shape
+    nh = cfg.ssm_heads
+    inner = nh * cfg.ssm_head_dim
+    f32 = jnp.float32
+    with _attn_scope(SSM), jax.named_scope("in_proj"):
+        zxd = qmatmul(h, lp["w_in"])
+        z, xbc, dt = (zxd[..., :inner], zxd[..., inner:-nh], zxd[..., -nh:])
+    y, out = attend(xbc, dt)
+    with _attn_scope(SSM):
+        with jax.named_scope("gated_norm"):
+            y = y.reshape(b, s, cfg.ssm_groups, -1) * jax.nn.silu(
+                z.astype(f32)).reshape(b, s, cfg.ssm_groups, -1)
+            y = y * jax.lax.rsqrt(
+                jnp.mean(y * y, axis=-1, keepdims=True) + cfg.rms_eps)
+            y = (y.reshape(b, s, inner)
+                 * lp["ssm_norm"].astype(f32)).astype(h.dtype)
+        with jax.named_scope("out_proj"):
+            return x + qmatmul(y, lp["w_out"]), out
+
+
+def _expert_layer(cfg: LlamaConfig, x, lp):
+    """A layer that is a feed-forward part alone (EXPERTS): x +
+    MLP(RMSNorm(x)); no mixer, no cache."""
+    return x + _mlp(rms_norm(x, lp["mlp_norm"], cfg.rms_eps), lp, cfg)
 
 
 def _scan_layers(cfg: LlamaConfig, block, x, params, cache):
@@ -1287,7 +1462,8 @@ def _scan_layers(cfg: LlamaConfig, block, x, params, cache):
             layer, x,
             ((rest, jnp.arange(cfg.num_layers)) if experts else rest,
              cache.k, cache.v, *cache.cold))
-    p = len(period)
+    p = len([c for c in cfg.cache_places if c is not None])
+    periods = (cfg.num_layers - cfg.leading_dense_layers) // len(period)
     lead = list(cache[p:])
     for j, kind in enumerate(cfg.leading_kinds):
         lp = _layer_params(params["leading"], j)
@@ -1296,7 +1472,7 @@ def _scan_layers(cfg: LlamaConfig, block, x, params, cache):
 
     def place(i, j, kind):
         if not cfg.stacked_by_kind:
-            return weights(layers, i * p + j)
+            return weights(layers, i * len(period) + j)
         # stacks by kind: this layer's place among its kind's
         return weights(layers[kind],
                        i * period.count(kind) + period[:j].count(kind))
@@ -1304,16 +1480,19 @@ def _scan_layers(cfg: LlamaConfig, block, x, params, cache):
     def step(carry, i):
         x, ks, vs = carry
         ks, vs = list(ks), list(vs)
-        for j, kind in enumerate(period):
+        for j, (kind, c) in enumerate(zip(period, cfg.cache_places)):
             lp = place(i, j, kind)
-            x, view = block(x, lp, cache[j].at(ks[j], vs[j], i).of_layer(lp),
+            if c is None:       # no mixer, no place in the cache
+                x = _expert_layer(cfg, x, lp)
+                continue
+            x, view = block(x, lp, cache[c].at(ks[c], vs[c], i).of_layer(lp),
                             kind)
-            ks[j], vs[j] = view.k, view.v
+            ks[c], vs[c] = view.k, view.v
         return (x, tuple(ks), tuple(vs)), None
 
     (x, ks, vs), _ = jax.lax.scan(
         step, (x, tuple(c.k for c in cache[:p]), tuple(c.v for c in cache[:p])),
-        jnp.arange((cfg.num_layers - cfg.leading_dense_layers) // p))
+        jnp.arange(periods))
     return x, (PeriodKV(ks + tuple(c.k for c in lead)),
                PeriodKV(vs + tuple(c.v for c in lead)))
 
@@ -1350,10 +1529,10 @@ def prefill(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     x = _shard_act(_embed(params, cfg, tokens, inject), P("data", sax, None))
 
     def layer(x, lp, view, kind):
-        if kind == LINEAR:
-            def mix(u, conv, g, beta):
+        if kind in (LINEAR, SSM):   # (what the kind's mixer hands over)
+            def mix(*a):
                 with _attn_scope(kind):
-                    return view.prompt(u, conv, g, beta, slot_map, lengths)
+                    return view.prompt(*a, slot_map, lengths)
 
             return _block(cfg, x, lp, kind, cos, sin, positions, mix,
                           ("data", sax))
@@ -1405,10 +1584,10 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
             with _attn_scope(kind):
                 return wrote.decode(q, lengths + 1), wrote
 
-        if kind == LINEAR:
-            def attend(u, conv, g, beta):  # noqa: F811
+        if kind in (LINEAR, SSM):
+            def attend(*a):  # noqa: F811
                 with _attn_scope(kind):
-                    return view.step(u, conv, g, beta)
+                    return view.step(*a)
 
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,
                       ("data", None))
@@ -1755,10 +1934,10 @@ def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):
                 return view.self_attend(self_attention, q, k, v,
                                         lengths), view
 
-        if kind == LINEAR:
-            def attend(u, conv, g, beta):  # noqa: F811
+        if kind in (LINEAR, SSM):
+            def attend(*a):  # noqa: F811
                 with _attn_scope(kind):
-                    return view.prompt(u, conv, g, beta, None, lengths)
+                    return view.prompt(*a, None, lengths)
 
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,
                       ("data", sax))
@@ -1800,11 +1979,11 @@ def extend(params, cfg: LlamaConfig, tokens, start, cos, sin,
                 return wrote.attend_window(q, positions, start, rows,
                                            slot_map is not None), wrote
 
-        if kind == LINEAR:
-            def attend(u, conv, g, beta):  # noqa: F811
+        if kind in (LINEAR, SSM):
+            def attend(*a):  # noqa: F811
                 with _attn_scope(kind):
                     return view.chunk(
-                        u, conv, g, beta, rows, start,
+                        *a, rows, start,
                         None if last_pos is None else last_pos + 1)
 
         return _block(cfg, x, lp, kind, cos, sin, positions, attend,

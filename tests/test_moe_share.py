@@ -152,6 +152,60 @@ def test_sixteen_shares_of_a_sigmoid_router_without_bias_add_up(whole,
     assert float(jnp.abs(twice - want).max()) > 0.05
 
 
+@pytest.mark.parametrize("grouped", [True, False],
+                         ids=["grouped", "masked"])
+def test_four_shares_of_latent_relu2_experts_add_up(grouped):
+    """The sum under Nemotron-3-Super's expert layer (PR 42): sigmoid scores
+    with a selection bias, top-22 of a router 32 wide, x 5, relu^2 experts of
+    two matrices in a latent of 24, a relu^2 shared expert over the hidden
+    state; four shares of eight experts, each through the latent pair (the
+    way out is linear, so the shares' outputs add), the shared expert
+    counted once, against the nemotron_h reference's uncut layer."""
+    from localai_tpu.testing import reference_nemotron_h as nemo
+
+    lat, k, wide = 24, 22, 40
+    rng = np.random.default_rng(8)
+    w = lambda *s: jnp.asarray(  # noqa: E731
+        rng.standard_normal(s) * s[-2] ** -0.5, jnp.float32)
+    whole = {"moe_gate": w(H, R), "moe_w1": w(R, lat, WIDTH),
+             "moe_w2": w(R, WIDTH, lat), "w_lat_in": w(H, lat),
+             "w_lat_out": w(lat, H), "ws_up": w(H, wide),
+             "ws_down": w(wide, H),
+             "moe_bias": jnp.asarray(0.05 * rng.standard_normal(R),
+                                     jnp.float32)}
+    x = _x(seed=9)
+    rcfg = nemo.RefConfig(
+        vocab_size=64, hidden_size=H, pattern="E", num_heads=4,
+        num_kv_heads=2, head_dim=8, ssm_heads=2, ssm_head_dim=8,
+        ssm_groups=1, ssm_state=8, rms_eps=1e-5, num_experts=R,
+        experts_per_tok=k, route_scale=5.0)
+    names = {"moe_gate": "router", "moe_bias": "router_bias",
+             "moe_w1": "w1", "moe_w2": "w2"}
+    rp = {names.get(n, n): v for n, v in whole.items()}
+    want = nemo.experts(x.reshape(-1, H), rp, rcfg)
+    over = dict(router_sigmoid=True, router_bias=True, routed_scale=5.0,
+                expert_act="relu2", moe_latent=lat, experts_per_tok=k)
+
+    def share(n, shared):
+        return _moe_routed(
+            x, _share(whole, 8 * n, held=8, shared=shared),
+            _cfg(first=8 * n, held=8, **over,
+                 shared_expert_width=wide if shared else 0),
+            grouped=grouped).reshape(-1, H)
+
+    total = sum(share(n, n == 0) for n in range(4))
+    assert float(jnp.abs(total - want).max()) < 2e-5
+    for fault in (dict(squared=False), dict(route_scale=1.0),
+                  dict(experts_per_tok=k - 1), dict(latent_in=False),
+                  dict(bias_in_choice=False)):
+        other = nemo.experts(x.reshape(-1, H), rp,
+                             dataclasses.replace(rcfg, **fault))
+        assert float(jnp.abs(total - other).max()) > 0.02, fault
+    # the shared expert counted four times is far from it
+    assert float(jnp.abs(sum(share(n, True) for n in range(4))
+                         - want).max()) > 0.05
+
+
 @pytest.mark.parametrize("first", [0, 12, 28])
 @pytest.mark.parametrize("int8", [False, True])
 def test_grouped_equals_masked_on_a_share(whole, first, int8):

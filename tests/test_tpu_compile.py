@@ -7,7 +7,9 @@ first that is no power of two) over a ring of 4096 + 512 tokens and over a
 256, top-4, for a chunk and for a decode step's rows; a chunk's attention
 over the long-document cell's cache row (XLA: what it holds). The topology is
 described inside a fixture (one process at a time may load the TPU's
-library, and a worker imports every test file)."""
+library, and a worker imports every test file). PR 42: Nemotron-3-Super's
+cell: the same kernels at a group of 16 over 2 KV heads, the state-space
+decode kernel, the latent relu^2 share."""
 import os
 
 import jax
@@ -62,6 +64,93 @@ def test_decode_kernel_at_a_group_of_six(one_chip, mosaic, t, kw):
         shape((b, 1, kvh * group, d), jnp.bfloat16), body, scale, body,
         scale, shape((b,), jnp.int32), shape((), jnp.int32))
     assert out.output_shardings is not None
+
+
+def test_decode_and_prompt_kernels_at_a_group_of_sixteen(one_chip, mosaic):
+    """Nemotron-3-Super's attention layers (PR 42): 32 query heads over 2 KV
+    heads, the widest group served; the int8 decode kernel over the cell's
+    12288-token cache with its scales at 2 heads, and a 512-token prompt's
+    flash attention."""
+    from localai_tpu.ops.pallas import flash_prefill, ragged_decode_q8
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    b, kvh, group, d, layers, t = 32, 2, 16, 128, 2, 12288
+    body = shape((layers, b, kvh, t, d), jnp.int8)
+    scale = shape((layers, b, kvh, t // 128, 128), jnp.float32)
+    out = _compile(
+        lambda q, kq, ks, vq, vs, lengths, layer: ragged_decode_q8(
+            q, kq, ks, vq, vs, lengths, layer=layer),
+        shape((b, 1, kvh * group, d), jnp.bfloat16), body, scale, body,
+        scale, shape((b,), jnp.int32), shape((), jnp.int32))
+    assert out.output_shardings is not None
+    out = _compile(
+        flash_prefill, shape((8, 512, kvh * group, d), jnp.bfloat16),
+        shape((8, 512, kvh, d), jnp.bfloat16),
+        shape((8, 512, kvh, d), jnp.bfloat16), shape((8,), jnp.int32))
+    assert out.output_shardings is not None
+
+
+def test_state_space_decode_kernel_at_the_cells_shape(one_chip, mosaic,
+                                                      monkeypatch):
+    """ssd_decode at 32 rows of 128 heads x 64 x 128 float32 in a stack of 2
+    layers: Mosaic takes the blocks (32 heads: 1 MiB of state in, 1 MiB
+    out), and the state is updated in place when the caller gives it away."""
+    from localai_tpu.ops.pallas import ssd
+
+    monkeypatch.setattr(ssd, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    b, h, p, n, g, layers = 32, 128, 64, 128, 8, 2
+    with jax.default_matmul_precision(None):
+        out = jax.jit(ssd.ssd_decode.__wrapped__, donate_argnums=(5,)).lower(
+            shape((b, h, p)), shape((b, h)), shape((h,)), shape((b, g, n)),
+            shape((b, g, n)), shape((layers, b, h, p, n)),
+            shape((), jnp.int32), shape((b,), jnp.bool_)).compile()
+    text = out.as_text()
+    assert "tpu_custom_call" in text
+    # no copy of the 268 MB stack beside the kernel
+    assert out.memory_analysis().temp_size_in_bytes < 16 << 20
+
+
+@pytest.mark.parametrize("rows", [(1, 512), (32, 1)],
+                         ids=["a chunk", "a decode step"])
+def test_latent_relu2_share_at_the_cells_widths(one_chip, mosaic, rows):
+    """Nemotron-3-Super's expert layer (PR 42): 128 held of a router 512
+    wide, top-22, relu^2 experts of 2688 in a latent of 1024, a relu^2
+    shared expert of 5376 over 4096."""
+    from localai_tpu.models.llama import LlamaConfig, _InStack, _moe_routed
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def int8(dims):
+        return {"q": shape(dims, jnp.int8),
+                "s": shape(dims[:-2] + (1, dims[-1]), jnp.float32)}
+
+    h, lat, wide, shared, held, routers = 4096, 1024, 2688, 5376, 128, 512
+    cfg = LlamaConfig(hidden_size=h, num_experts=held, experts_per_tok=22,
+                      moe_intermediate_size=wide, router_experts=routers,
+                      shared_expert_width=shared, routed_scale=5.0,
+                      router_sigmoid=True, router_bias=True,
+                      expert_act="relu2", moe_latent=lat)
+    rest = {"moe_gate": shape((h, routers), jnp.float32),
+            "moe_bias": shape((routers,), jnp.float32),
+            "w_lat_in": int8((h, lat)), "w_lat_out": int8((lat, h)),
+            "ws_up": int8((h, shared)), "ws_down": int8((shared, h))}
+    stacks = {"moe_w1": int8((2, held, lat, wide)),
+              "moe_w2": int8((2, held, wide, lat))}
+    out = _compile(
+        lambda x, rest, stacks: _moe_routed(
+            x, {**rest, **{n: _InStack(w, 1) for n, w in stacks.items()}},
+            cfg),
+        shape((*rows, h), jnp.bfloat16), rest, stacks)
+    assert out.output_shardings is not None
+    # the way into the tiles and back holds no [tokens, k, rows] array
+    assert out.memory_analysis().temp_size_in_bytes < 1 << 30
 
 
 @pytest.mark.parametrize("rows", [(1, 512), (32, 1)],

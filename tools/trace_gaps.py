@@ -51,21 +51,25 @@ from benchmark.harness.tracefacts import (  # noqa: E402
 )
 
 PHASE = re.compile(r"^engine\.(dispatch|admit|emit|kv|device|idle)$")
-SCOPES = ("expert_einsums", "router", "dispatch", "shared", "experts",
+SCOPES = ("expert_einsums", "router", "dispatch", "shared", "latent_in",
+          "latent_out", "experts",
           "attention", "mlp", "lm_head", "sampling", "cache_update")
 # Pallas kernels are traced under no scope (a scope would change their
 # compile-cache key, models/llama.py): they are told by their own jit name
 KERNELS = {"kda_decode": "attention/linear/kda_decode",
+           "ssd_decode": "attention/ssm/ssd_decode",
            "mla_decode": "attention/latent/mla_decode",
            "grouped_matmul": "experts/expert_einsums",
            "ragged_decode": "attention", "ragged_paged_attention": "attention",
            "flash_prefill": "attention", "paged_scatter_append": "cache_update",
            "ragged_scatter_append": "cache_update"}
-LAYER_KINDS = ("window", "full", "linear", "latent")
+LAYER_KINDS = ("window", "full", "linear", "latent", "ssm")
 # what a linear layer's mixer is made of (models/kv.py StateKV), and a latent
 # layer's attention (models/llama.py _latent_qk, models/kv.py LatentKV)
 LINEAR_PARTS = ("conv", "kda_chunk", "kda_decode")
 LATENT_PARTS = ("q_lora", "kv_lora", "absorb", "expand")
+# a state-space layer's mixer (models/llama.py _ssm_mixer, models/kv.py SsmKV)
+SSM_PARTS = ("in_proj", "ssd_chunk", "ssd_decode", "gated_norm", "out_proj")
 TOP = 10
 # what an annotation says the engine held as its tick's dispatch was enqueued
 ROWS = {"active": "rows_active", "prefill": "rows_prefill",
@@ -198,7 +202,8 @@ def scope_of(name: str, stats: dict) -> str:
             if scope in parts:
                 if scope in ("router", "expert_einsums"):
                     return "experts/" + scope
-                if scope in ("dispatch", "shared"):
+                if scope in ("dispatch", "shared", "latent_in",
+                             "latent_out"):
                     # the routed expert layer's own (models/llama.py
                     # _moe_routed); the bare words mean nothing elsewhere
                     if "experts" in parts:
@@ -208,8 +213,11 @@ def scope_of(name: str, stats: dict) -> str:
                 # under attention/<kind> (models/llama.py _attn_scope)
                 kind = parts[parts.index(scope) + 1:][:1]
                 if scope == "attention" and kind and kind[0] in LAYER_KINDS:
-                    inner = [p for p in parts
-                             if p in LINEAR_PARTS + LATENT_PARTS]
+                    # (a kernel under the scope goes by its jit name)
+                    inner = [p for p in (
+                        q[4:-1] if q.startswith("jit(") and q.endswith(")")
+                        else q for q in parts)
+                        if p in LINEAR_PARTS + LATENT_PARTS + SSM_PARTS]
                     return "/".join(["attention", kind[0]] + inner[:1])
                 return scope
         for part in parts:
