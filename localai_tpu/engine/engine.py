@@ -735,8 +735,8 @@ class Engine:
         else:
             t = T if self._paged else self._kc.shape[-2]
             window = cfg.sliding_window
-        self._full_row = (t, window, not t or self.kernel_tiers()[
-            "chunk_attention"] != "xla-blocks")
+        tier = self.kernel_tiers()["chunk_attention"]
+        self._full_row = (t, window, not t or tier == "xla")
         if self._mixed:
             # two kinds of cache: how many layers and bytes (K and V) each
             # kind holds (gauges), and the context tokens ONE layer of each
@@ -764,6 +764,11 @@ class Engine:
                 # its attention visits, each expanded once a chunk
                 # (_credit_chunk_ctx); 0 for a chunk attended absorbed
                 self.metrics["chunk_latent_rows__expanded"] = 0
+                # the same rows, where the chunk's program holds the kernel
+                # (kv.LatentKV.attend_window, by the rule kernel_tiers
+                # reports) and not its twin, the XLA block loop
+                self.metrics["chunk_latent_rows__kernel"] = 0
+                self._chunk_kernel = tier.startswith("pallas")
             if self._linear:
                 # what the decode steps consumed so far moved of each kind
                 # of cache (_credit_consumed): K and V bytes the softmax
@@ -3219,6 +3224,8 @@ class Engine:
         self.metrics["chunk_ctx_tokens__capacity"] += t
         if self._latent:
             self.metrics["chunk_latent_rows__expanded"] += rows
+            self.metrics["chunk_latent_rows__kernel"] += (
+                rows * self._chunk_kernel)
 
     def _credit_consumed(self, steps: int, entries=(), n_out=None,
                          rows=None):

@@ -640,7 +640,11 @@ class LatentKV(NoKV):
                    all H heads, and the output goes through W_UV;
     attend_window  expanding: each CHUNK_BLOCK of rows up to the context the
                    chunk has is put through W_kvb once and attended by
-                   heads of N + P / V (ops/attention.mha_extend_blocks). At
+                   heads of N + P / V: on one chip in the kernel
+                   ops/pallas/mla.py: mla_chunk, a block's scores and the
+                   expanded rows never leaving VMEM; its twin
+                   (attend_window_xla: ops/attention.mha_extend_blocks) on
+                   a CPU, under a mesh and where the tests compare. At
                    H 128 a chunk of S tokens costs S x 278.5 k operations a
                    cached row absorbed, 33.6 M + S x 81.9 k expanding: the
                    expanding form wins from 171 tokens a chunk, at every
@@ -720,6 +724,22 @@ class LatentKV(NoKV):
             q, self.k[self.layer], lengths, self.rank, self.scale))
 
     def attend_window(self, q, positions, start, rows, gathered):
+        """The chunk's queries (positions = start + 0 .. S - 1, as `extend`
+        hands them) over their slots' rows, block by block up to the
+        chunk's context: the kernel (ops/pallas/mla.py: mla_chunk) where
+        decode takes one, else its twin, the XLA block loop."""
+        if _pallas_attention(current_mesh()):
+            from localai_tpu.ops.pallas.mla import mla_chunk
+
+            with jax.named_scope("chunk_kernel"):
+                return mla_chunk(
+                    q, self.k, self.w_kvb, start,
+                    rows if gathered else jnp.arange(q.shape[0]), self.layer,
+                    rank=self.rank, nope=self.nope, scale=self.scale,
+                    block=CHUNK_BLOCK)
+        return self.attend_window_xla(q, positions, start, rows, gathered)
+
+    def attend_window_xla(self, q, positions, start, rows, gathered):
         t, width = self.k.shape[-2:]
         block = min(CHUNK_BLOCK, t)
 

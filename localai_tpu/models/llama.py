@@ -1190,9 +1190,13 @@ def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
         # attend against the cache on XLA: a dense cache's full-length rows
         # block by block up to the context the chunk has
         # (kv.DenseKV.attend_window; a ring beside them whole), a pool's, a
-        # tier's and any row under a sequence axis whole (mha_extend)
-        "chunk_attention": "xla" if paged or tiered
-        or seq_axis_size(mesh) > 1 else "xla-blocks",
+        # tier's and any row under a sequence axis whole (mha_extend). A
+        # latent layer's rows by the same blocks in the kernel where decode
+        # takes one (kv.LatentKV.attend_window)
+        "chunk_attention": (
+            "xla" if paged or tiered or seq_axis_size(mesh) > 1
+            else attn if attn != "xla" and LATENT in (cfg.layer_types or ())
+            else "xla-blocks"),
         # the tiered (ring-mapped) cache read has no kernel yet
         "decode_attention": "xla" if tiered else attn,
         "decode_kv_write": paged_kernel,

@@ -7,7 +7,10 @@ or an int8 stack, the layer named or sliced out, one gathered row or every
 slot, `start` at 0, at a block's edge, one short of it and at T - S, rows of
 one program at different starts, a speculative-verification window, a window
 on a full-length cache — and what proves the bound: every cache row past
-start + S poisoned with NaN, and the output finite and equal."""
+start + S poisoned with NaN, and the output finite and equal. PR 46: a
+LATENT layer's chunk (kv.LatentKV.attend_window) in its kernel
+(ops/pallas/mla.py: mla_chunk, the interpreter here) against its twin, the
+XLA block loop, and against mha_extend over the whole row expanded."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -226,6 +229,208 @@ def test_chunks_under_a_mesh_match_unmeshed(cache_type):
         return ids
 
     assert run(None) == run(mesh)
+
+
+# ------------------------------------------- a latent layer's chunk (PR 46)
+
+LH, LR, LP, LN, LV = 4, 64, 16, 32, 32      # heads, rank, rope, nope, vdim
+LATENT_CASES = {
+    # name: (T, starts, gathered, what lies past each row's newest position)
+    "a context that ends inside a block": (T, [BLOCK + 100], True, None),
+    "start differs by row, gathered": (
+        T, [3, 2 * BLOCK - 1, T - S - 3], True, None),
+    "start differs by row, every slot": (
+        T, [3, 2 * BLOCK - 1, T - S - 3, BLOCK], False, None),
+    "512 does not divide T": (1000, [936, 500], True, None),
+    "a padded chunk runs past the end of such a T": (
+        1000, [960, 400], True, None),
+    "an earlier tenant's inf and nan": (
+        T, [BLOCK - S, 2 * BLOCK + 7], True, (jnp.inf, jnp.nan)),
+    "position 0 of the first block": (T, [0], True, jnp.nan),
+}
+
+
+def _latent(t, starts, gathered, past, dtype, quant):
+    """(the kernel, the XLA loop, mha_extend over the expanded row) for a
+    latent layer's chunk of S tokens a row."""
+    import dataclasses
+
+    from localai_tpu.ops import mla
+    from localai_tpu.ops.attention import mha_extend
+    from localai_tpu.ops.pallas.mla import mla_chunk
+    from localai_tpu.ops.quant import quantize
+
+    width = kv.latent_row_width(LR, LP)
+    ks = jax.random.split(jax.random.PRNGKey(46), 3)
+    cache = jnp.concatenate([
+        jax.random.normal(ks[0], (L, SLOTS, t, LR + LP)),
+        jnp.zeros((L, SLOTS, t, width - LR - LP))], -1).astype(dtype)
+    w = quantize(jax.random.normal(ks[1], (LR, LH * (LN + LV))) * LR ** -0.5)
+    if not quant:
+        w = (w["q"] * w["s"]).astype(dtype)
+    b = len(starts)
+    start = jnp.asarray(starts, jnp.int32)
+    positions = start[:, None] + jnp.arange(S)[None, :]
+    rows = jnp.arange(SLOTS - 1, SLOTS - 1 - b, -1)
+    slots = np.asarray(rows) if gathered else np.arange(b)
+    q = jax.random.normal(ks[2], (b, S, LH, LN + LP)).astype(dtype)
+    view = kv.LatentKV(cache, None, layer=jnp.int32(LAYER), heads=LH,
+                       nope=LN, rope=LP, rank=LR, vdim=LV, w_kvb=w)
+    kx, vx = view._expand(cache[LAYER][slots])
+    vx = jnp.pad(vx, ((0, 0),) * 3 + ((0, LN + LP - LV),))
+    whole = mha_extend(q, kx, vx, positions, scale=view.scale)[..., :LV]
+    if past is not None:
+        bad = np.zeros((SLOTS, t), bool)
+        for i, (slot, at) in enumerate(zip(slots, starts)):
+            bad[slot, at + S:] = True
+        fill = jnp.asarray(np.resize(np.asarray(past, np.float32), t))
+        cache = jnp.where(jnp.asarray(bad)[None, :, :, None],
+                          fill[None, None, :, None].astype(dtype), cache)
+        view = dataclasses.replace(view, k=cache)
+    loop = jax.jit(lambda c, q, start, rows: dataclasses.replace(
+        view, k=c).attend_window_xla(
+            q, start[:, None] + jnp.arange(S)[None, :], start, rows,
+            gathered))(cache, q, start, rows)
+    kernel = mla_chunk(q, cache, w, start,
+                       rows if gathered else jnp.arange(b), view.layer,
+                       rank=LR, nope=LN, scale=view.scale,
+                       block=min(BLOCK, t))
+    return [np.asarray(a, np.float32) for a in (kernel, loop, whole)]
+
+
+@pytest.mark.parametrize("precision", ["float32", "bfloat16-int8"])
+@pytest.mark.parametrize("case", LATENT_CASES.values(),
+                         ids=list(LATENT_CASES))
+def test_a_latent_chunks_kernel_equals_its_twin_and_the_whole_row(
+        case, precision):
+    """float32: to rounding. As served (a bfloat16 cache, int8 W_kvb): the
+    median relative error under tools/reference_check.py's latent limit,
+    against the loop and against the whole row alike, and the largest
+    within a few bfloat16 steps of the outputs' scale."""
+    from tools.reference_check import MEDIAN_REL_LATENT
+
+    served = precision != "float32"
+    kernel, loop, whole = _latent(
+        *case, jnp.bfloat16 if served else jnp.float32, served)
+    assert np.isfinite(kernel).all()
+    for ref in (loop, whole):
+        if not served:
+            np.testing.assert_allclose(kernel, ref, rtol=2e-5, atol=2e-5)
+            continue
+        err = np.abs(kernel - ref)
+        assert np.median(err / np.maximum(np.abs(ref), 1e-3)) < (
+            MEDIAN_REL_LATENT)
+        assert err.max() < 0.05
+
+
+def test_a_latent_view_takes_the_kernel_where_decode_does(monkeypatch):
+    """LatentKV.attend_window chooses by kv._pallas_attention, as its
+    decode: the kernel when forced here (the interpreter), else the twin;
+    the two agree."""
+    import dataclasses
+
+    from localai_tpu.ops.pallas import mla as pallas_mla
+
+    width = kv.latent_row_width(LR, LP)
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
+    cache = jnp.pad(jax.random.normal(ks[0], (L, SLOTS, T, LR + LP)),
+                    ((0, 0),) * 3 + ((0, width - LR - LP),))
+    view = kv.LatentKV(
+        cache, None, layer=jnp.int32(LAYER), heads=LH, nope=LN, rope=LP,
+        rank=LR, vdim=LV,
+        w_kvb=jax.random.normal(ks[1], (LR, LH * (LN + LV))) * LR ** -0.5)
+    q = jax.random.normal(ks[2], (1, S, LH, LN + LP))
+    start = jnp.asarray([BLOCK + 9], jnp.int32)
+    args = (q, start[:, None] + jnp.arange(S)[None, :], start,
+            jnp.asarray([2]), True)
+    calls = []
+    real = pallas_mla.mla_chunk
+    monkeypatch.setattr(pallas_mla, "mla_chunk",
+                        lambda *a, **k: calls.append(1) or real(*a, **k))
+    twin = view.attend_window(*args)
+    assert not calls
+    monkeypatch.setenv("LOCALAI_FORCE_PALLAS", "1")
+    served = dataclasses.replace(view).attend_window(*args)
+    assert calls == [1]
+    np.testing.assert_allclose(served, twin, rtol=2e-5, atol=2e-5)
+    # and the kernel runs under a scope tools/trace_gaps.py reads as a part
+    from tools.trace_gaps import scope_of
+
+    text = jax.jit(lambda c: dataclasses.replace(view, k=c).attend_window(
+        *args)).lower(cache).as_text(debug_info=True)
+    assert "chunk_kernel" in text
+    assert scope_of("", {"tf_op": "jit(_extend_mid)/while/body/attention/"
+                         "latent/attention/chunk_kernel/jit(mla_chunk)/x"}) \
+        == "attention/latent/chunk_kernel"
+    assert scope_of("", {"tf_op": "jit(mla_chunk)/x"}) \
+        == "attention/latent/chunk_kernel"
+
+
+def _small_pangu_through_the_engine(workdir):
+    """(kernel_tiers' chunk_attention, the metrics, the tokens picked) of a
+    small openPangu through the engine's own loop: a 40-token prompt past a
+    16-token bucket, chunks from 0, 16 and 32 inside the first block of a
+    1024-row cache."""
+    import json
+    import os
+
+    from localai_tpu.engine import Engine, EngineConfig
+    from localai_tpu.engine.engine import GenRequest, SamplingParams
+    from localai_tpu.engine.loader import load_config
+    from localai_tpu.models.llama import init_params
+
+    with open(os.path.join(workdir, "config.json"), "w") as f:
+        json.dump(dict(
+            model_type="pangu_ultra_moe", vocab_size=96, hidden_size=48,
+            intermediate_size=64, moe_intermediate_size=24,
+            num_hidden_layers=3, num_attention_heads=4,
+            num_key_value_heads=4, kv_lora_rank=32, q_lora_rank=40,
+            qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+            max_position_embeddings=1024, rms_norm_eps=1e-5,
+            rope_theta=25600000, first_k_dense_replace=1,
+            n_routed_experts=8, num_experts_per_tok=2, n_shared_experts=1,
+            norm_topk_prob=True, routed_scaling_factor=2.5,
+            sandwich_norm=True, num_nextn_predict_layers=1,
+            attention_bias=False, hidden_act="silu",
+            tie_word_embeddings=False), f)
+    cfg = load_config(workdir, dtype="float32")
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(1)), None,
+                 EngineConfig(max_slots=2, max_context=1024,
+                              prefill_buckets=(16,), prefill_chunk=16))
+    assert eng.metrics["chunk_latent_rows__kernel"] == 0
+    eng.start()
+    try:
+        _, q = eng.submit(GenRequest(
+            prompt_ids=[(5 * i) % 90 + 1 for i in range(40)], max_tokens=4,
+            ignore_eos=True, params=SamplingParams(temperature=0.0, seed=1)))
+        ids = [q.get(timeout=300)]
+        while not ids[-1].finished:
+            ids.append(q.get(timeout=300))
+    finally:
+        eng.stop()
+    return (eng.kernel_tiers()["chunk_attention"], eng.metrics,
+            [o.token_id for o in ids])
+
+
+@pytest.mark.parametrize("tier", ["xla-blocks", "pallas-interpret"])
+def test_the_engine_counts_the_rows_the_kernel_expanded(tier, monkeypatch,
+                                                        tmp_path):
+    """`chunk_latent_rows__kernel` is the rows expanded where the chunk's
+    program holds the kernel and 0 on its twin; the tier's name does not
+    move the rows a chunk is counted to visit (one block of 512 a chunk,
+    not the row's 1024); and the kernel's engine picks the twin's tokens."""
+    twin = _small_pangu_through_the_engine(str(tmp_path))
+    if tier != "xla-blocks":
+        monkeypatch.setenv("LOCALAI_FORCE_PALLAS", "1")
+        served = _small_pangu_through_the_engine(str(tmp_path))
+        assert served[2] == twin[2] and len(twin[2]) == 4
+    name, m, _ = twin if tier == "xla-blocks" else served
+    assert name == tier
+    assert (m["chunk_ctx_tokens__attended"],
+            m["chunk_ctx_tokens__capacity"]) == (3 * BLOCK, 3 * 1024)
+    assert m["chunk_latent_rows__expanded"] == 3 * BLOCK
+    assert m["chunk_latent_rows__kernel"] == (
+        0 if tier == "xla-blocks" else 3 * BLOCK)
 
 
 def test_the_bench_rehearses(tmp_path):

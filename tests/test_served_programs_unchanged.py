@@ -16,6 +16,15 @@ kv.DenseKV.attend_window); the other eighteen are 8aabf74's still.
 
 The hashes are of this container's JAX (0.9.0); another version prints
 other jaxprs, and the table is then made again on both commits.
+
+PR 46: the three newer models beside them (NEWER: a small Trinity, a small
+Nemotron-3-Super, a small openPangu-Ultra-MoE, each with a share of its
+experts as its cell serves it; openPangu's cache bfloat16, the one a latent
+layer has), hashed the same way on PR 46's parent (f4aa4f7) and held to it,
+but ONE: openPangu's `extend` with the kernels on, which PR 46 moved on
+purpose (a chunk's attention over latents in ops/pallas/mla.py: mla_chunk;
+PARENT_OF_46 keeps the parent's hash beside it). On the XLA twin that
+program is the parent's too.
 """
 import hashlib
 import json
@@ -63,6 +72,56 @@ MODELS = {
         first_k_dense_replace=0, tie_word_embeddings=False,
         localai_expert_share=dict(router_experts=16, first_expert=4)),
 }
+NEWER = {
+    "trinity": dict(
+        model_type="afmoe", vocab_size=96, hidden_size=48,
+        intermediate_size=64, moe_intermediate_size=24,
+        num_hidden_layers=10, num_attention_heads=6, num_key_value_heads=1,
+        head_dim=16, max_position_embeddings=1024, rms_norm_eps=1e-5,
+        rope_theta=10000, rope_scaling=None, sliding_window=8,
+        layer_types=["sliding_attention", "full_attention"]
+        + (["sliding_attention"] * 3 + ["full_attention"]) * 2,
+        num_dense_layers=2, num_experts=8, num_experts_per_tok=2,
+        num_shared_experts=1, score_func="sigmoid", route_norm=True,
+        route_scale=2.448, n_group=1, topk_group=1, num_expert_groups=1,
+        num_limited_groups=1, mup_enabled=True, tie_word_embeddings=False,
+        localai_expert_share=dict(router_experts=16, first_expert=4)),
+    "nemotron3": dict(
+        model_type="nemotron_h", vocab_size=96, hidden_size=48,
+        intermediate_size=24, moe_intermediate_size=24,
+        num_hidden_layers=14, hybrid_override_pattern="*EMEMEM*EMEMEM",
+        num_attention_heads=4, num_key_value_heads=2, head_dim=16,
+        mamba_num_heads=8, mamba_head_dim=8, n_groups=2,
+        # (a state of N 128: the least the decode kernel tiles)
+        ssm_state_size=128,
+        conv_kernel=4, chunk_size=8, expand=2, max_position_embeddings=1024,
+        norm_eps=1e-5, layer_norm_epsilon=1e-5, n_routed_experts=8,
+        num_experts_per_tok=5, n_shared_experts=1,
+        moe_shared_expert_intermediate_size=40, moe_latent_size=32,
+        norm_topk_prob=True, n_group=1, topk_group=1,
+        routed_scaling_factor=5.0, mlp_hidden_act="relu2",
+        mamba_hidden_act="silu", use_conv_bias=True, mamba_proj_bias=False,
+        use_bias=False, mlp_bias=False, attention_bias=False,
+        num_nextn_predict_layers=0, rope_theta=10000,
+        partial_rotary_factor=1, time_step_min=0.001, time_step_max=0.1,
+        tie_word_embeddings=False,
+        localai_expert_share=dict(router_experts=16, first_expert=4)),
+    "openpangu": dict(
+        model_type="pangu_ultra_moe", vocab_size=96, hidden_size=48,
+        intermediate_size=64, moe_intermediate_size=24, num_hidden_layers=5,
+        num_attention_heads=4, num_key_value_heads=4, kv_lora_rank=32,
+        q_lora_rank=40, qk_nope_head_dim=16, qk_rope_head_dim=8,
+        v_head_dim=16, max_position_embeddings=1024, rms_norm_eps=1e-5,
+        rope_theta=25600000, first_k_dense_replace=2, n_routed_experts=8,
+        num_experts_per_tok=2, n_shared_experts=1, norm_topk_prob=True,
+        routed_scaling_factor=2.5, sandwich_norm=True,
+        num_nextn_predict_layers=1, attention_bias=False, hidden_act="silu",
+        tie_word_embeddings=False,
+        localai_expert_share=dict(router_experts=16, first_expert=4)),
+}
+MODELS.update(NEWER)
+# a latent layer's cache is bfloat16 (an int8 one is refused by name)
+CACHE_TYPE = {"openpangu": ""}
 PROGRAMS = ("_admit_many_fn", "_extend_mid_fn", "_decode_nomask_fn",
             "_decode_loop_fn")
 # model -> kernels -> program -> sha256 of its jaxpr, on the parent commit
@@ -111,6 +170,53 @@ PARENT = {
     },
 }
 
+# PR 46's parent (f4aa4f7) for the newer models; openPangu's `extend` with
+# the kernels on is PR 46's own (PARENT_OF_46 has the parent's)
+PARENT.update({
+    "trinity": {
+        "xla": {
+            "_admit_many_fn": "2dc541b1bb0ea338",
+            "_extend_mid_fn": "57453c63b3752287",
+            "_decode_nomask_fn": "4f969bb1c858d8e7",
+            "_decode_loop_fn": "27de698ee217f641",
+        },
+        "pallas": {
+            "_admit_many_fn": "442fd7f6812fdc9d",
+            "_extend_mid_fn": "07ed3cd063528d9c",
+            "_decode_nomask_fn": "401d33888884ce27",
+            "_decode_loop_fn": "1cbbffa38315a98a",
+        },
+    },
+    "nemotron3": {
+        "xla": {
+            "_admit_many_fn": "bdeed5dd4fd273f8",
+            "_extend_mid_fn": "e86f0a3c3098b18e",
+            "_decode_nomask_fn": "ee96a9ae161a34a4",
+            "_decode_loop_fn": "cd45fa63b5916f0f",
+        },
+        "pallas": {
+            "_admit_many_fn": "3a788d660edfbe8e",
+            "_extend_mid_fn": "0c8edc4ca79f22e0",
+            "_decode_nomask_fn": "833fa8079930f29d",
+            "_decode_loop_fn": "35a071f9118b2773",
+        },
+    },
+    "openpangu": {
+        "xla": {
+            "_admit_many_fn": "c6c5b33050f08a8a",
+            "_extend_mid_fn": "4a48dfa3eec8242f",
+            "_decode_nomask_fn": "118d73f5d6e67418",
+            "_decode_loop_fn": "25e35a53a3268d0a",
+        },
+        "pallas": {
+            "_admit_many_fn": "839e475d81c54176",
+            "_extend_mid_fn": "ee18f355c3657b65",
+            "_decode_nomask_fn": "985e4b109b1cee2d",
+            "_decode_loop_fn": "2606d3e6878ce30c",
+        },
+    },
+})
+PARENT_OF_46 = "115b3d97fce03bdc"
 
 def _text(jaxpr) -> str:
     """A jaxpr's text without what differs between two checkouts or two
@@ -133,7 +239,7 @@ def _drive(name: str, workdir: str) -> dict:
     params = load_params(workdir, cfg, dtype="int8")
     eng = Engine(cfg, params, None, EngineConfig(
         max_slots=4, max_context=256, prefill_buckets=(64,),
-        prefill_chunk=64, cache_type="int8"))
+        prefill_chunk=64, cache_type=CACHE_TYPE.get(name, "int8")))
     seen = {}
 
     def recorded(attr):
@@ -181,7 +287,11 @@ def hashes(name: str, kernels: str, workdir: str) -> dict:
 @pytest.mark.parametrize("kernels", ["xla", "pallas"])
 @pytest.mark.parametrize("name", list(MODELS))
 def test_the_older_models_programs_are_the_parents(name, kernels, tmp_path):
-    assert hashes(name, kernels, str(tmp_path)) == PARENT[name][kernels]
+    got = hashes(name, kernels, str(tmp_path))
+    assert got == PARENT[name][kernels]
+    # the one program PR 46 moved holds the kernel, and no other does
+    assert (got["_extend_mid_fn"] != PARENT_OF_46) or (
+        name, kernels) != ("openpangu", "pallas")
 
 
 if __name__ == "__main__":

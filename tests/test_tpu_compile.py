@@ -215,3 +215,39 @@ def test_a_chunks_attention_holds_no_score_array_of_the_whole_row(one_chip):
     assert attend(kv.NoKV.attend_window) > scores
     served = attend(kv.DenseKV.attend_window)
     assert served < scores // 16, served
+
+
+def test_a_latent_chunks_kernel_at_the_cells_shape(one_chip, mosaic,
+                                                   monkeypatch):
+    """openPangu's chunk (PR 46): 512 queries of 128 heads of 192 / 128 over
+    one gathered row of a bfloat16 latent stack of 32 x 12288 x 640 (rank
+    512 + 64), int8 W_kvb, as kv.LatentKV.attend_window serves it on one
+    chip: Mosaic takes mla_chunk's blocks under the VMEM it asks for, and
+    the program holds no expanded rows and no scores beside the kernel (the
+    XLA loop's block: 134 MB of float32 scores and probabilities)."""
+    from localai_tpu.models import kv
+    from localai_tpu.ops.pallas import mla
+
+    monkeypatch.setattr(mla, "_interpret", lambda: False)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    layers, b, t, s, h, r, p, n, v = 2, 32, 12288, 512, 128, 512, 64, 128, 128
+    assert mla.mla_chunk_vmem_bytes(512, s, mla._CHUNK_HEADS, 640, r, n, v,
+                                    True) < 64 << 20
+
+    def call(q, cache, wq, ws, start, rows, layer):
+        view = kv.LatentKV(cache, None, layer=layer, heads=h, nope=n, rope=p,
+                           rank=r, vdim=v, w_kvb={"q": wq, "s": ws})
+        positions = start[:, None] + jnp.arange(s)[None, :]
+        return view.attend_window(q, positions, start, rows, True)
+
+    out = _compile(
+        call, shape((1, s, h, n + p), jnp.bfloat16),
+        shape((layers, b, t, kv.latent_row_width(r, p)), jnp.bfloat16),
+        shape((r, h * (n + v)), jnp.int8), shape((1, h * (n + v)),
+                                                 jnp.float32),
+        shape((1,), jnp.int32), shape((1,), jnp.int32), shape((), jnp.int32))
+    assert "tpu_custom_call" in out.as_text()
+    assert out.memory_analysis().temp_size_in_bytes < 64 << 20

@@ -11,12 +11,15 @@ of the roofline (benchmark/harness/roofline_mla.py, benchmark/peaks/):
   size of `--blocks`; its XLA twin (ops/mla.mla_decode_xla); and the whole
   absorbed step (the query through W_UK, the kernel, the output through
   W_UV: kv.LatentKV.decode);
-- a 512-token chunk's attention at each context of `--contexts`, in both
-  forms over the same cache: EXPANDING (kv.LatentKV.attend_window, what is
-  served: every visited block of rows through W_kvb, heads of 192 / 128) and
-  ABSORBED (the chunk's queries through W_UK, the rows as they lie as one
-  KV head of 640 / 512 for all 128 heads, the output through W_UV), both in
-  ops/attention.mha_extend_blocks, and both checked against each other.
+- a 512-token chunk's attention at each context of `--contexts`, over the
+  same cache: EXPANDING (kv.LatentKV.attend_window: every visited block of
+  rows through W_kvb, heads of 192 / 128) in the kernel that is served
+  (ops/pallas/mla.py: mla_chunk, at every `--chunk-heads` heads a grid
+  step) and in its twin, the XLA block loop
+  (ops/attention.mha_extend_blocks), with `differs_by` between the two;
+  and ABSORBED (the chunk's queries through W_UK, the rows as they lie as
+  one KV head of 640 / 512 for all 128 heads, the output through W_UV) in
+  the XLA loop, checked against the expanding loop.
 
 Times are the host's clock round `--reps` calls that end in
 block_until_ready. The table goes to stdout and to
@@ -41,7 +44,9 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=40)
     ap.add_argument("--live", type=int, default=30)
     ap.add_argument("--context", type=int, default=6144)
-    ap.add_argument("--contexts", default="2048,6144,8192")
+    ap.add_argument("--contexts", default="2048,6144,8192,12288")
+    ap.add_argument("--chunk-heads", default="",
+                    help="heads a grid step of mla_chunk, beside its own")
     ap.add_argument("--blocks", default="512,1024,2048")
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--cpu-rehearsal", action="store_true")
@@ -59,7 +64,7 @@ def main() -> int:
     from localai_tpu.models import kv
     from localai_tpu.ops import mla
     from localai_tpu.ops.attention import mha_extend_blocks
-    from localai_tpu.ops.pallas.mla import mla_decode
+    from localai_tpu.ops.pallas.mla import mla_chunk, mla_decode
     from localai_tpu.ops.quant import quantize
 
     rehearsal = args.cpu_rehearsal
@@ -157,8 +162,13 @@ def main() -> int:
 
     def expanding(q, c, start):
         positions = start[:, None] + jnp.arange(S)[None, :]
-        return dataclasses.replace(view, k=c).attend_window(
+        return dataclasses.replace(view, k=c).attend_window_xla(
             q, positions, start, slot, True)
+
+    def kernel(heads):
+        return lambda q, c, start: mla_chunk(
+            q, c, w_kvb, start, slot, 1, rank=R, nope=N, scale=scale,
+            block=min(kv.CHUNK_BLOCK, T), heads_per_step=heads)
 
     def absorbed(q, c, start):
         positions = start[:, None] + jnp.arange(S)[None, :]
@@ -175,21 +185,32 @@ def main() -> int:
                               block=block, scale=scale, v_dim=R)
         return mla.unabsorb(o, w_kvb, N)
 
+    def differ(a, b):
+        err = float(jnp.abs(a.astype(jnp.float32)
+                            - b.astype(jnp.float32)).max())
+        assert err < (1e-4 if rehearsal else 0.05), err
+        return err
+
+    kernels = [("kernel (served)", None)] + [
+        (f"kernel, {g} heads a grid step", int(g))
+        for g in args.chunk_heads.split(",") if g]
     for ctx in contexts:
         start = jnp.asarray([ctx - S], jnp.int32)
-        outs = {}
-        for form, fn in (("expanding", expanding), ("absorbed", absorbed)):
-            sec, outs[form] = timed(jax.jit(fn), cq, rows, start)
-            row(f"chunk of {S} at context {ctx}, {form}"
-                + (" (served)" if form == "expanding" else ""), sec,
+
+        def chunk_row(name, fn, form="expanding", **against):
+            sec, out = timed(jax.jit(fn), cq, rows, start)
+            row(f"chunk of {S} at context {ctx}, {form}, {name}", sec,
                 rm.mla_chunk_cost(S, ctx - S, H, R, P, N, V, form,
                                   jnp.dtype(dtype).itemsize,
                                   1.0 if isinstance(w_kvb, dict) else
-                                  jnp.dtype(dtype).itemsize))
-        err = float(jnp.abs(outs["expanding"].astype(jnp.float32)
-                            - outs["absorbed"].astype(jnp.float32)).max())
-        assert err < (1e-4 if rehearsal else 0.05), err
-        report["rows"][-1]["forms_differ_by"] = err
+                                  jnp.dtype(dtype).itemsize),
+                **{k: differ(out, v) for k, v in against.items()})
+            return out
+
+        loop = chunk_row("XLA loop", expanding)
+        for name, heads in kernels:
+            chunk_row(name, kernel(heads), differs_by=loop)
+        chunk_row("XLA loop", absorbed, "absorbed", forms_differ_by=loop)
     report["crossing_tokens"] = rm.crossing_tokens(H, R, P, N, V)
     print(json.dumps({"crossing_tokens": report["crossing_tokens"]}))
 

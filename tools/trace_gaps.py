@@ -59,6 +59,7 @@ SCOPES = ("expert_einsums", "router", "dispatch", "shared", "latent_in",
 KERNELS = {"kda_decode": "attention/linear/kda_decode",
            "ssd_decode": "attention/ssm/ssd_decode",
            "mla_decode": "attention/latent/mla_decode",
+           "mla_chunk": "attention/latent/chunk_kernel",
            "grouped_matmul": "experts/expert_einsums",
            "ragged_decode": "attention", "ragged_paged_attention": "attention",
            "flash_prefill": "attention", "paged_scatter_append": "cache_update",
@@ -67,7 +68,9 @@ LAYER_KINDS = ("window", "full", "linear", "latent", "ssm")
 # what a linear layer's mixer is made of (models/kv.py StateKV), and a latent
 # layer's attention (models/llama.py _latent_qk, models/kv.py LatentKV)
 LINEAR_PARTS = ("conv", "kda_chunk", "kda_decode")
-LATENT_PARTS = ("q_lora", "kv_lora", "absorb", "expand")
+# (`chunk_kernel`: the scope round a chunk's kernel, ops/pallas/mla.py
+# mla_chunk, which expands the rows itself: PR 46)
+LATENT_PARTS = ("q_lora", "kv_lora", "absorb", "expand", "chunk_kernel")
 # a state-space layer's mixer (models/llama.py _ssm_mixer, models/kv.py SsmKV)
 SSM_PARTS = ("in_proj", "ssd_chunk", "ssd_decode", "gated_norm", "out_proj")
 TOP = 10
