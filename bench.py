@@ -111,7 +111,7 @@ def sched_base(eng):
 def sched_stats(eng, base=None, *, toks_per_s=0.0, device_kind="",
                 chips=1) -> dict:
     """Scheduler X-ray scoreboard fields (ISSUE 13) from a live engine:
-    tick-ledger aggregates (budget utilization, pad-row fraction, reason-
+    tick-ledger aggregates (pad-row fraction, reason-
     code counts, per-variant dispatch counts — deltas vs `base` when given)
     plus the per-variant cost-analysis rooflines. When a throughput is
     given, also computes the cost-backed `mfu`: measured tok/s times the
@@ -133,7 +133,6 @@ def sched_stats(eng, base=None, *, toks_per_s=0.0, device_kind="",
                 if n - v0.get(k, 0)}
     toks = int(eng.metrics.get("tokens_generated", 0)) - t0
     out = {
-        "budget_utilization": round(sched.budget_utilization(), 4),
         "pad_rows_frac": round(sched.pad_rows_frac(), 4),
         "reason_codes": reasons,
         "sched_variants": variants,
@@ -501,376 +500,6 @@ def bench_paged(args, size: str, on_cpu: bool):
          f"({paged_tps / max(dense_tps, 1e-9):.2f}x dense)")
     return (dense_tps, dense_ttft, paged_tps, paged_ttft, pages, context,
             dtype, stats)
-
-
-# -------------------------------------------------------------- ragged mode
-
-def _ragged_leg(args, cfg, params, context, kv_pages, budget, mixed,
-                loop_steps=0):
-    """One serving leg for --mode ragged: a `windows`-round burst workload
-    (slots requests each, decode_steps tokens each) through one engine.
-    Returns serving throughput (generated tok/s over the whole round,
-    prefill included — the number continuous batching moves), the
-    under-load TTFT distribution, the token-budget utilization, and the
-    fused-loop stats (steps/dispatch, exit-reason counts). `loop_steps`
-    gates the ISSUE 16 fused multi-step tick: 0 = single-step dispatch
-    (the pre-fused behavior the A/B legs baseline against)."""
-    import statistics as st
-
-    import numpy as np
-
-    from localai_tpu.engine import Engine, EngineConfig, GenRequest
-    from localai_tpu.ops.sampling import SamplingParams
-
-    eng = Engine(cfg, params, None, EngineConfig(
-        max_slots=args.slots, max_context=context,
-        prefill_buckets=(128, min(512, context)),
-        prefill_chunk=min(128, context),
-        kv_pages=kv_pages, prompt_cache=False,
-        ragged_token_budget=budget,
-        ragged_loop_steps=loop_steps,
-        **({} if args.decode_loop is None
-           else {"decode_loop": args.decode_loop}),
-    ))
-    rng = np.random.default_rng(0)
-
-    def prompt_lens(k):
-        if mixed:
-            # 3:1 length spread averaging prompt_len — the ragged pack's
-            # whole point is that this costs nothing vs equal lengths
-            lo = max(8, args.prompt_len // 2)
-            return rng.integers(lo, args.prompt_len * 3 // 2 + 1, k).tolist()
-        return [args.prompt_len] * k
-
-    def burst(n_tokens):
-        subs = []
-        for n in prompt_lens(args.slots):
-            _, q = eng.submit(GenRequest(
-                rng.integers(1, cfg.vocab_size, n).tolist(),
-                SamplingParams(temperature=0.8, top_k=40,
-                               seed=int(rng.integers(1 << 30))),
-                max_tokens=n_tokens, ignore_eos=True))
-            subs.append((time.perf_counter(), q))
-        ttfts, n0 = [], eng.metrics["tokens_generated"]
-        t0 = time.perf_counter()
-        while True:
-            busy = eng.step()
-            now = time.perf_counter()
-            waiting = []
-            for ts, q in subs:
-                if q.empty():
-                    waiting.append((ts, q))
-                else:
-                    ttfts.append((now - ts) * 1e3)
-            subs = waiting
-            if not busy:
-                break
-        dt = time.perf_counter() - t0
-        return (eng.metrics["tokens_generated"] - n0) / dt, ttfts
-
-    t0 = time.perf_counter()
-    eng.warmup()
-    burst(4)   # admission/prefill program compiles
-    note(f"  programs compiled in {time.perf_counter() - t0:.1f}s")
-    base = sched_base(eng)
-    d0 = eng.metrics["decode_dispatches"]
-    s0 = eng.metrics["decode_steps_dispatched"]
-    x0 = {k: v for k, v in eng.metrics.items()
-          if k.startswith("rloop_exit_")}
-    tput, ttfts = [], []
-    for _ in range(args.windows):
-        tps, tt = burst(args.decode_steps)
-        tput.append(tps)
-        ttfts.extend(tt)
-    m = dict(eng.metrics)
-    rows = getattr(eng, "_ragged_rows", 0)
-    util = (m.get("ragged_tokens_packed", 0)
-            / max(m.get("ragged_dispatches", 0) * rows, 1))
-    import jax
-
-    dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", dev.platform)
-    ttfts.sort()
-    return {
-        "tok_s": st.median(tput),
-        "ttft_p50_ms": ttfts[len(ttfts) // 2],
-        "ttft_p95_ms": ttfts[min(len(ttfts) - 1, int(len(ttfts) * 0.95))],
-        "budget_utilization": round(util, 4),
-        # dispatch-boundary amortization over the measured windows only
-        # (warmup/compile bursts excluded) — the fused leg's headline
-        "steps_per_dispatch": round(
-            (m["decode_steps_dispatched"] - s0)
-            / max(m["decode_dispatches"] - d0, 1), 2),
-        "loop_exit_reasons": {
-            k[len("rloop_exit_"):]: int(v - x0.get(k, 0))
-            for k, v in m.items() if k.startswith("rloop_exit_")
-            and v - x0.get(k, 0) > 0},
-        "sched": sched_stats(eng, base, toks_per_s=st.median(tput),
-                             device_kind=kind),
-        "metrics": m,
-    }
-
-
-def bench_ragged(args, size: str, on_cpu: bool):
-    """Ragged continuous batching A/B (one process, same token work):
-
-      dense mixed  : mixed-length stream, ragged off (bucketed prefill +
-                     separate decode dispatches) — the ragged_over_dense
-                     denominator,
-      ragged mixed : the same stream through the flat-stream mixed
-                     dispatch,
-      ragged equal : equal-length stream, ragged on — the packing
-                     reference; mixed-length serving must hold >= ~0.9x of
-                     it, since the ragged pack never pads lengths,
-      ragged-fused : the mixed stream again with the ISSUE 16 multi-step
-                     device loop (`--ragged-loop-steps`, 0 disables the
-                     leg) — reports steps/dispatch, the loop-exit reason
-                     mix, and fused_over_ragged vs the single-step leg."""
-    import jax
-
-    from localai_tpu.engine.loader import load_config, load_params
-    from localai_tpu.ops.paged import BLOCK
-
-    tmp = tempfile.mkdtemp(prefix="bench-ckpt-")
-    ckpt = write_synthetic_checkpoint(size, os.path.join(tmp, size))
-    os.environ["LOCALAI_ALLOW_SYNTHETIC"] = "1"
-    dtype = args.dtype or ("int8" if size == "8b" else "bfloat16")
-    if on_cpu:
-        dtype = args.dtype or "float32"
-    cfg = load_config(ckpt, dtype=dtype)
-    context = min(args.context, cfg.max_position)
-    params = load_params(ckpt, cfg, dtype=dtype)
-    jax.block_until_ready(params)
-    note("params initialized")
-
-    tokens = min(args.prompt_len * 3 // 2 + args.decode_steps + 33, context)
-    pages = args.kv_pages or args.slots * (-(-tokens // BLOCK)) + 1
-    budget = args.ragged_budget or args.slots * 8 + 128
-    note(f"pool {pages} blocks, token budget {budget} rows")
-
-    dense = _ragged_leg(args, cfg, params, context, pages, 0, mixed=True)
-    note(f"dense mixed: {dense['tok_s']:.1f} tok/s, "
-         f"ttft p50 {dense['ttft_p50_ms']:.0f}ms")
-    ragged = _ragged_leg(args, cfg, params, context, pages, budget,
-                         mixed=True)
-    note(f"ragged mixed: {ragged['tok_s']:.1f} tok/s "
-         f"({ragged['tok_s'] / max(dense['tok_s'], 1e-9):.2f}x dense), "
-         f"ttft p50 {ragged['ttft_p50_ms']:.0f}ms, "
-         f"budget util {ragged['budget_utilization']:.2f}")
-    equal = _ragged_leg(args, cfg, params, context, pages, budget,
-                        mixed=False)
-    note(f"ragged equal: {equal['tok_s']:.1f} tok/s (mixed holds "
-         f"{ragged['tok_s'] / max(equal['tok_s'], 1e-9):.2f}x of it)")
-    fused = None
-    if args.ragged_loop_steps > 1:
-        fused = _ragged_leg(args, cfg, params, context, pages, budget,
-                            mixed=True, loop_steps=args.ragged_loop_steps)
-        note(f"ragged fused: {fused['tok_s']:.1f} tok/s "
-             f"({fused['tok_s'] / max(ragged['tok_s'], 1e-9):.2f}x "
-             f"single-step), {fused['steps_per_dispatch']:.1f} "
-             f"steps/dispatch, ttft p50 {fused['ttft_p50_ms']:.0f}ms, "
-             f"exits {fused['loop_exit_reasons']}")
-    import shutil
-
-    shutil.rmtree(tmp, ignore_errors=True)
-    return dense, ragged, equal, fused, pages, budget, context, dtype
-
-
-# ---------------------------------------------------------------- soup mode
-
-SOUP_CORPUS = [
-    "the quick brown fox jumps over the lazy dog",
-    '{"a": 12, "b": "hello world"} {"a": 7, "b": "tokens"}',
-    "pack my box with five dozen liquor jugs",
-    '[1, 2, 3] {"key": "value", "n": 42} true false null',
-]
-
-SOUP_SCHEMA = {"type": "object",
-               "properties": {"a": {"type": "integer"},
-                              "b": {"type": "string"}},
-               "required": ["a", "b"]}
-
-
-def _soup_checkpoint(size: str, path: str) -> str:
-    """A synthetic checkpoint WITH a tokenizer: grammar compilation needs
-    real token texts, so train a small byte-level BPE in-process (the
-    `tokenizers` core dep — no torch) and clamp the config's vocab to it.
-    Soup numbers are self-relative (constrained vs plain on the SAME
-    geometry), so shrinking the vocab from the named size is fair."""
-    from tokenizers import Tokenizer, decoders, models, pre_tokenizers, \
-        trainers
-
-    write_synthetic_checkpoint(size, path)
-    tok = Tokenizer(models.BPE(unk_token=None))
-    tok.pre_tokenizer = pre_tokenizers.ByteLevel(add_prefix_space=False)
-    tok.decoder = decoders.ByteLevel()
-    trainer = trainers.BpeTrainer(
-        vocab_size=min(SIZES[size]["vocab_size"], 512) - 2,
-        special_tokens=["<s>", "</s>"],
-        initial_alphabet=pre_tokenizers.ByteLevel.alphabet(),
-        show_progress=False)
-    tok.train_from_iterator(SOUP_CORPUS * 4, trainer=trainer)
-    tok.save(os.path.join(path, "tokenizer.json"))
-    with open(os.path.join(path, "tokenizer_config.json"), "w") as fh:
-        json.dump({"bos_token": "<s>", "eos_token": "</s>",
-                   "add_bos_token": True}, fh)
-    with open(os.path.join(path, "config.json")) as fh:
-        body = json.load(fh)
-    body["vocab_size"] = tok.get_vocab_size()
-    with open(os.path.join(path, "config.json"), "w") as fh:
-        json.dump(body, fh)
-    return path
-
-
-def bench_soup(args, size: str, on_cpu: bool):
-    """--mode soup: ONE draft+ragged+paged engine serving a mixed tenant
-    trace — grammar-constrained (device automaton tables), multimodal
-    (packed embedding injects), and plain streams, all speculative (the
-    engine drafts against itself). Two legs on the same warmed engine:
-
-      plain : every tenant unconstrained — the denominator,
-      soup  : tenants cycle plain / grammar / mm — the number the one-
-              program claim moves: constrained_over_plain >= ~0.8 means
-              constrained traffic rides the fast paths instead of dense
-              per-token fallbacks.
-
-    The measured soup windows run under the dispatch-budget tripwire and a
-    compile-count snapshot; dense_fallback_dispatches and per-tenant path
-    counts come from the engine's own accounting."""
-    import statistics as st
-
-    import jax
-    import numpy as np
-
-    from localai_tpu.engine import (
-        Engine, EngineConfig, GenRequest, Tokenizer, load_config,
-        load_params,
-    )
-    from localai_tpu.functions.grammars import json_schema_grammar
-    from localai_tpu.ops.paged import BLOCK
-    from localai_tpu.ops.sampling import SamplingParams
-    from localai_tpu.testing.tripwires import (
-        decode_compile_count, dispatch_budget,
-    )
-
-    tmp = tempfile.mkdtemp(prefix="bench-ckpt-")
-    ckpt = _soup_checkpoint(size, os.path.join(tmp, size))
-    os.environ["LOCALAI_ALLOW_SYNTHETIC"] = "1"
-    dtype = args.dtype or ("float32" if on_cpu else "bfloat16")
-    cfg = load_config(ckpt, dtype=dtype)
-    context = min(args.context, cfg.max_position)
-    params = load_params(ckpt, cfg, dtype=dtype)
-    jax.block_until_ready(params)
-    tok = Tokenizer.from_dir(ckpt)
-    note("params + tokenizer ready")
-
-    gamma = 3
-    tokens = min(args.prompt_len * 3 // 2 + args.decode_steps + gamma + 34,
-                 context)
-    pages = args.kv_pages or \
-        args.slots * (-(-tokens // BLOCK)) + args.slots + 1
-    budget = args.ragged_budget or args.slots * (gamma + 1) + 128
-    note(f"pool {pages} blocks, token budget {budget} rows, gamma {gamma}")
-
-    eng = Engine(cfg, params, tok, EngineConfig(
-        max_slots=args.slots, max_context=context,
-        prefill_buckets=(128, min(512, context)),
-        prefill_chunk=min(128, context),
-        kv_pages=pages, prompt_cache=False, gamma=gamma,
-        ragged_token_budget=budget), draft=(cfg, params))
-    eng.record_paths = True
-    grammar = json_schema_grammar(SOUP_SCHEMA)
-    embed = np.asarray(params["embed"], np.float32)
-    rng = np.random.default_rng(0)
-
-    def make_req(kind):
-        n = int(rng.integers(max(8, args.prompt_len // 2),
-                             args.prompt_len * 3 // 2 + 1))
-        ids = rng.integers(2, cfg.vocab_size, n).tolist()
-        sp = SamplingParams(temperature=0.8, top_k=40,
-                            seed=int(rng.integers(1 << 30)))
-        r = GenRequest(ids, sp, max_tokens=args.decode_steps,
-                       ignore_eos=(kind != "grammar"))
-        if kind == "grammar":
-            r.grammar = grammar
-        elif kind == "mm":
-            r.mm_embeds = embed[ids[1:5]] + 0.25
-            r.mm_positions = np.arange(1, 5)
-        return r
-
-    def burst(kinds):
-        # 2x oversubscription so freed slots backfill within the window
-        reqs = [(k, eng.submit(make_req(k))) for k in kinds * 2]
-        n0 = eng.metrics["tokens_generated"]
-        t0 = time.perf_counter()
-        while eng.step():
-            pass
-        dt = time.perf_counter() - t0
-        for kind, (rid, _) in reqs:
-            tenant_of[rid] = kind
-        return (eng.metrics["tokens_generated"] - n0) / dt
-
-    tenant_of: dict = {}
-    plain_kinds = ["plain"] * args.slots
-    soup_kinds = [("plain", "grammar", "mm")[i % 3]
-                  for i in range(args.slots)]
-
-    t0 = time.perf_counter()
-    eng.warmup()
-    burst(soup_kinds[: max(3, args.slots // 2)])  # program compiles
-    note(f"  programs compiled in {time.perf_counter() - t0:.1f}s")
-    warm_compiles = decode_compile_count(eng)
-    tenant_of.clear()
-    eng.req_path_counts.clear()
-
-    plain_tps = [burst(plain_kinds) for _ in range(args.windows)]
-    note(f"plain: {st.median(plain_tps):.1f} tok/s")
-    d0 = eng.metrics["decode_dispatches"]
-    r0 = eng.metrics["ragged_dispatches"]
-    sbase = sched_base(eng)
-    with dispatch_budget(eng):
-        soup_tps = [burst(soup_kinds) for _ in range(args.windows)]
-    note(f"soup : {st.median(soup_tps):.1f} tok/s "
-         f"({st.median(soup_tps) / max(st.median(plain_tps), 1e-9):.2f}x "
-         f"plain)")
-
-    per_tenant: dict = {}
-    for rid, kind in tenant_of.items():
-        agg = per_tenant.setdefault(kind, {})
-        for path, cnt in eng.req_path_counts.get(rid, {}).items():
-            agg[path] = agg.get(path, 0) + cnt
-    dense_fallback = (eng.metrics["decode_dispatches"] - d0) \
-        - (eng.metrics["ragged_dispatches"] - r0)
-    dev = jax.devices()[0]
-    kind = getattr(dev, "device_kind", dev.platform)
-    sstats = sched_stats(eng, sbase, toks_per_s=st.median(soup_tps),
-                         device_kind=kind)
-    # every dense (non-ragged) dispatch in the soup windows emits exactly
-    # one dispatch-category reason code, so these sum to dense_fallback
-    from localai_tpu.telemetry import DISPATCH_CODES
-
-    fallback_reasons = {c: n for c, n in
-                        (sstats.get("reason_codes") or {}).items()
-                        if c in DISPATCH_CODES}
-    result = {
-        "tok_s": st.median(soup_tps),
-        "plain_tok_s": st.median(plain_tps),
-        "per_tenant_paths": per_tenant,
-        "dense_fallback_dispatches": int(dense_fallback),
-        "dense_fallback_reasons": fallback_reasons,
-        "sched": sstats,
-        "compile_count_delta": decode_compile_count(eng) - warm_compiles,
-        "grammar_table_states": int(
-            eng.metrics.get("grammar_table_states", 0)),
-        "draft_acceptance": round(
-            eng.metrics.get("draft_accepted", 0)
-            / max(eng.metrics.get("draft_proposed", 1), 1), 4),
-        "metrics": dict(eng.metrics),
-    }
-    import shutil
-
-    shutil.rmtree(tmp, ignore_errors=True)
-    return result, pages, budget, context, dtype, gamma
 
 
 def _longctx_leg(args, cfg, params, *, max_context, kv_policy="",
@@ -1449,25 +1078,17 @@ def build_parser() -> argparse.ArgumentParser:
                    help="tiny|1b|3b|8b (default: 8b on TPU, tiny on CPU)")
     p.add_argument("--mode", default="serve",
                    choices=["serve", "engine", "embed", "whisper", "paged",
-                            "tp", "ragged", "longctx", "soup", "session"],
+                            "tp", "longctx", "session"],
                    help="serve = gRPC backend subprocess (default); engine = "
                         "in-process; paged = dense AND paged in one process "
                         "with a paged_over_dense ratio; tp = single device "
                         "AND an N-device tensor-parallel mesh in one process "
                         "with a tp_over_single ratio (CPU: virtual 4-device "
-                        "mesh); ragged = mixed-length continuous batching "
-                        "through the flat-stream dispatch, three legs "
-                        "(dense mixed / ragged mixed / ragged equal) with "
-                        "ragged_over_dense + mixed_over_equal ratios; "
+                        "mesh); "
                         "longctx = KV lifecycle tier: ctx-32k decode under "
                         "sink_window vs ctx-1k full KV with a "
                         "longctx_over_short ratio, bounded-pool peak, and "
                         "token-parity probes (BASELINE #2f); "
-                        "soup = mixed tenant trace (grammar + multimodal + "
-                        "speculative + plain) on ONE draft+ragged engine "
-                        "with a constrained_over_plain ratio, per-tenant "
-                        "dispatch-path counts, and a dense-fallback count "
-                        "(ISSUE 12); "
                         "session = multi-turn conversations through the "
                         "host KV tier: turn-2 TTFT with host re-admission "
                         "vs re-prefill vs warm device hit, a worker-restart "
@@ -1490,14 +1111,6 @@ def build_parser() -> argparse.ArgumentParser:
                    help="max steps per single-dispatch while-loop decode "
                         "block (engine mode; default: engine's 64; 0 "
                         "disables the loop — scan-ladder comparison runs)")
-    p.add_argument("--ragged-budget", type=int, default=0,
-                   help="ragged token rows per mixed dispatch (--mode "
-                        "ragged; 0 = auto: slots*8 + 128 — every decode "
-                        "slot plus one 128-token prefill chunk)")
-    p.add_argument("--ragged-loop-steps", type=int, default=16,
-                   help="max decode iterations per fused ragged dispatch "
-                        "(--mode ragged's ragged-fused leg; 0/1 disables "
-                        "the leg — single-step dispatch only)")
     p.add_argument("--longctx-tokens", type=int, default=32768,
                    help="long-leg prompt length for --mode longctx")
     p.add_argument("--kv-window", type=int, default=1024,
@@ -1535,8 +1148,8 @@ def emit_result(result: dict, args) -> int:
     JSON line."""
     # engine-sourced latency percentiles: serve mode captured the backend's
     # hist_* GetMetrics keys; in-process modes read the live registry.
-    # setdefault — modes publishing their own under-load stopwatch numbers
-    # (ragged) keep them.
+    # setdefault — a mode publishing its own under-load stopwatch numbers
+    # keeps them.
     src = getattr(args, "slo_metrics", None)
     if src is None:
         try:
@@ -1694,64 +1307,6 @@ def main(argv=None):
             "device": device_kind,
         }
         return emit_result(result, args)
-    if args.mode == "ragged":
-        import jax
-
-        note("initializing device client...")
-        dev = jax.devices()[0]
-        device_kind = getattr(dev, "device_kind", dev.platform)
-        (dense, ragged, equal, fused, pages, budget, context,
-         dtype) = bench_ragged(args, size, on_cpu)
-        toks_per_s = ragged["tok_s"]
-        n_params = param_count(size)
-        result = {
-            "metric": f"serve tok/s (llama-{size} {dtype}, ragged "
-                      f"mixed-length vs dense, {args.slots} slots, "
-                      f"budget {budget} rows, ctx {context})",
-            "value": round(toks_per_s, 2),
-            "unit": "tok/s",
-            "vs_baseline": None if on_cpu else round(toks_per_s / 1000.0, 4),
-            "dense_tok_s": round(dense["tok_s"], 2),
-            "equal_len_tok_s": round(equal["tok_s"], 2),
-            "ragged_over_dense": round(
-                toks_per_s / max(dense["tok_s"], 1e-9), 4),
-            "mixed_over_equal": round(
-                toks_per_s / max(equal["tok_s"], 1e-9), 4),
-            "ttft_p50_ms": round(ragged["ttft_p50_ms"], 2),
-            "ttft_p95_ms": round(ragged["ttft_p95_ms"], 2),
-            "dense_ttft_p50_ms": round(dense["ttft_p50_ms"], 2),
-            "dense_ttft_p95_ms": round(dense["ttft_p95_ms"], 2),
-            "budget_utilization": ragged["budget_utilization"],
-            "ragged_dispatches": int(
-                ragged["metrics"].get("ragged_dispatches", 0)),
-            # single-step leg dispatch stats first: when the fused leg ran,
-            # its measured-window steps_per_dispatch below must win
-            **dispatch_stats(ragged["metrics"]),
-            # fused multi-step leg (ISSUE 16) — absent keys mean the leg
-            # was disabled (--ragged-loop-steps 0/1), so benchdiff's
-            # both-sides rule skips the ratio against pre-fused artifacts
-            **({} if fused is None else {
-                "ragged_fused_tok_s": round(fused["tok_s"], 2),
-                "fused_over_ragged": round(
-                    fused["tok_s"] / max(toks_per_s, 1e-9), 4),
-                "fused_ttft_p50_ms": round(fused["ttft_p50_ms"], 2),
-                "steps_per_dispatch": fused["steps_per_dispatch"],
-                "loop_exit_reasons": fused["loop_exit_reasons"],
-            }),
-            "mesh": None,
-            "chips": 1,
-            "tok_s_global": round(toks_per_s, 2),
-            "tok_s_per_chip": round(toks_per_s, 2),
-            "mfu": (ragged.get("sched") or {}).get("mfu"),
-            "pad_rows_frac": (ragged.get("sched") or {}).get(
-                "pad_rows_frac"),
-            "reason_codes": (ragged.get("sched") or {}).get(
-                "reason_codes") or {},
-            "rooflines": (ragged.get("sched") or {}).get("rooflines") or {},
-            "device": device_kind,
-            "params": n_params,
-        }
-        return emit_result(result, args)
     if args.mode == "session":
         import jax
 
@@ -1808,44 +1363,6 @@ def main(argv=None):
             "kv_host_spills": r["kv_host_spills"],
             "kv_host_evictions": r["kv_host_evictions"],
             "device": device_kind,
-        }
-        return emit_result(result, args)
-    if args.mode == "soup":
-        import jax
-
-        note("initializing device client...")
-        dev = jax.devices()[0]
-        device_kind = getattr(dev, "device_kind", dev.platform)
-        r, pages, budget, context, dtype, gamma = bench_soup(
-            args, size, on_cpu)
-        toks_per_s = r["tok_s"]
-        result = {
-            "metric": f"serve tok/s (llama-{size} {dtype}, mixed-tenant "
-                      f"soup on one draft+ragged engine, {args.slots} "
-                      f"slots, gamma {gamma}, budget {budget} rows, "
-                      f"ctx {context})",
-            "value": round(toks_per_s, 2),
-            "unit": "tok/s",
-            "vs_baseline": None,
-            "plain_tok_s": round(r["plain_tok_s"], 2),
-            "constrained_over_plain": round(
-                toks_per_s / max(r["plain_tok_s"], 1e-9), 4),
-            "per_tenant_paths": r["per_tenant_paths"],
-            "dense_fallback_dispatches": r["dense_fallback_dispatches"],
-            "dense_fallback_reasons": r.get("dense_fallback_reasons") or {},
-            "compile_count_delta": r["compile_count_delta"],
-            "grammar_table_states": r["grammar_table_states"],
-            "draft_acceptance": r["draft_acceptance"],
-            "ragged_dispatches": int(
-                r["metrics"].get("ragged_dispatches", 0)),
-            "mfu": (r.get("sched") or {}).get("mfu"),
-            "budget_utilization": (r.get("sched") or {}).get(
-                "budget_utilization"),
-            "pad_rows_frac": (r.get("sched") or {}).get("pad_rows_frac"),
-            "reason_codes": (r.get("sched") or {}).get("reason_codes") or {},
-            "rooflines": (r.get("sched") or {}).get("rooflines") or {},
-            "device": device_kind,
-            **dispatch_stats(r["metrics"]),
         }
         return emit_result(result, args)
     if args.mode == "paged":

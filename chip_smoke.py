@@ -17,7 +17,7 @@ wait for every process it started:
   A  defaults (dense KV), cold;
   B  the same again — LoadModel should now hit the compile cache A filled;
   C  the same model with `kv_pages` set (the paged pool the prefix cache,
-     ragged batching, kvtier, kvhost and resume all stand on).
+     kvtier, kvhost and resume all stand on).
 A lifetime starting at all is the check that the one before released the chip.
 
 Each lifetime answers /system before any request, then: one streamed

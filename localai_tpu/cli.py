@@ -400,10 +400,7 @@ def cli_util_sched(args) -> int:
         saw_any = True
         print(f"{model}: {snap.get('ticks_total', 0)} ticks, "
               f"{snap.get('dispatches_total', 0)} dispatches")
-        util = snap.get("budget_utilization")
-        if util is not None:
-            print(f"  budget utilization {util:.1%}  "
-                  f"pad rows {snap.get('pad_rows_frac', 0):.1%}")
+        print(f"  pad rows {snap.get('pad_rows_frac', 0):.1%}")
         reasons = snap.get("reason_counters") or {}
         if reasons:
             width = max(len(c) for c in reasons)

@@ -117,49 +117,6 @@ class EngineConfig:
                                   # reserve ceil((prompt+max_tokens)/128)
                                   # blocks at admission, so the pool
                                   # oversubscribes max_context, not requests.
-    ragged_token_budget: int = 0  # ragged continuous batching (paged KV
-                                  # only): token rows packed per mixed tick.
-                                  # When > 0, ticks with prefill work pack
-                                  # ALL live decode slots (one row each) plus
-                                  # chunked-prefill windows into ONE flat
-                                  # stream and run a single ragged-attention
-                                  # dispatch (ops/pallas/ragged_attention.py)
-                                  # — no per-bucket padding, no separate
-                                  # prefill+decode programs on mixed ticks.
-                                  # Admission becomes host-only bookkeeping
-                                  # (never stalls on a device prefill); pure-
-                                  # decode ticks keep the fused while-loop
-                                  # path. 0 disables (the default serving
-                                  # paths are untouched). Rounded up to a
-                                  # QBLK (8-row) multiple. Grammar slots ride
-                                  # the pack (fresh host masks each tick) and
-                                  # multimodal prompt chunks pack their
-                                  # feature rows via per-row embedding
-                                  # injection — neither forces a dense
-                                  # fallback dispatch.
-    ragged_loop_steps: int = 16   # fused multi-step ragged ticks (ragged
-                                  # engines only): up to this many decode
-                                  # iterations per ragged dispatch in ONE
-                                  # on-device lax.while_loop
-                                  # (models/llama.build_ragged_loop).
-                                  # Iteration 0 is the mixed ragged pack;
-                                  # follow-on iterations re-derive the
-                                  # decode metadata on device and run the
-                                  # dense decode body, early-exiting when
-                                  # any slot finishes (the host admits into
-                                  # the freed slot immediately), when the
-                                  # host-set prefill-pending flag is up
-                                  # (TTFT stays at ragged levels), or at
-                                  # this step cap. Pure-decode ticks on a
-                                  # ragged engine ride the same program
-                                  # (pack-free variant) instead of the
-                                  # decode_loop path, gaining the
-                                  # first-finish exit. 0/1 disables — the
-                                  # engine keeps the single-step ragged +
-                                  # decode_loop split (the escape hatch).
-                                  # Speculative (draft) engines ignore it:
-                                  # spec-as-ragged verify windows stay
-                                  # single-step per tick.
     grammar_table_states: int = 256  # device grammar tables: shared capacity
                                   # (automaton states across live grammars)
                                   # for the precompiled [S, ceil(V/32)] u32
@@ -333,7 +290,7 @@ class _Slot:
                                      # TPOT is the amortized gap over the
                                      # burst, weighted by its token count
     path: str = ""                   # decode path that served the latest
-                                     # token (loop/dense/ragged/spec)
+                                     # token (loop/dense/spec)
     dispatches: int = 0              # device dispatches this request rode
                                      # (Kernel Looping's per-request number)
     timeline: dict | None = None     # finished-request record handed to the
@@ -343,11 +300,6 @@ class _Slot:
                                      # None = host-masked (matcher walks the
                                      # mask) because the automaton overflowed
                                      # grammar_table_states or tables are off
-    path_counts: dict = dataclasses.field(default_factory=dict)
-                                     # per-path token counts for this request
-                                     # (exported via req_path_counts when
-                                     # engine.record_paths is set — bench
-                                     # soup's per-tenant dispatch attribution)
 
 
 # The engine thread's one tick may take this long (a cold compile of the
@@ -468,10 +420,9 @@ class Engine:
             raise ValueError(
                 f"{mixed_name} "
                 "(layer_types) cannot be served with paged KV (kv_pages > "
-                "0), nor with what rests on it: ragged batching "
-                "(ragged_token_budget), kv_policy windows, the host KV tier "
-                "(kv_host_bytes) and its resume: the block pool holds one "
-                "kind of cache. Serve it with kv_pages: 0")
+                "0), nor with what rests on it: kv_policy windows, the "
+                "host KV tier (kv_host_bytes) and its resume: the block "
+                "pool holds one kind of cache. Serve it with kv_pages: 0")
         if self._mixed and self._draft is not None:
             raise ValueError(
                 f"{mixed_name} "
@@ -482,25 +433,6 @@ class Engine:
         if self._paged:
             if self.ec.kv_pages < 2:
                 raise ValueError("kv_pages must be >= 2 (block 0 is trash)")
-        # ragged continuous batching: one flat-stream dispatch for mixed
-        # prefill+decode ticks (models/llama.ragged_forward). Paged-pool
-        # only — the flat KV writes resolve through block tables.
-        self._ragged = self.ec.ragged_token_budget > 0
-        if self._ragged:
-            if not self._paged:
-                raise ValueError(
-                    "ragged_token_budget requires paged KV (set kv_pages)")
-            from localai_tpu.ops.pallas import QBLK
-
-            rows = max(self.ec.ragged_token_budget, 2 * QBLK)
-            if self._draft is not None:
-                # spec-as-ragged: each verifying slot needs gamma+1 window
-                # rows (QBLK-aligned) in the flat stream — make sure a full
-                # slot population plus one prefill block always fits
-                winb = -(-(self.ec.gamma + 1) // QBLK)
-                rows = max(rows,
-                           (self.ec.max_slots * winb + 1) * QBLK)
-            self._ragged_rows = -(-rows // QBLK) * QBLK
         # KV lifecycle tier (engine/kvtier.py): a windowed engine policy
         # switches the paged table to COMPACT geometry — the per-slot table
         # row holds only sink_blocks identity columns plus a reused ring, so
@@ -524,11 +456,6 @@ class Engine:
                 raise ValueError(
                     "kv_policy sink_window does not support multi-host "
                     "replication (per-slot ring geometry is host state)")
-            if self._ragged and self._cold:
-                raise ValueError(
-                    "quantize_cold is incompatible with ragged continuous "
-                    "batching (the flat-stream program has no cold-tier "
-                    "lane); drop quantize_cold or ragged_token_budget")
             self._kv_margin = kvtier.engine_margin_tokens(self.ec)
             self._kv_ring = kvtier.ring_blocks(self._kv_policy.window,
                                                self._kv_margin)
@@ -703,11 +630,9 @@ class Engine:
             # the decode-loop + copy_to_host_async work is driving to zero
             "host_sync_wait_ms": 0.0,
             # per-path token attribution (ISSUE 13): always-on so live
-            # servers can compute constrained_over_plain-style ratios from
-            # GetMetrics, not just bench.py --mode soup
+            # servers can compute constrained_over_plain-style ratios
+            # from GetMetrics
             "tokens_by_path__loop": 0,
-            "tokens_by_path__rloop": 0,
-            "tokens_by_path__ragged": 0,
             "tokens_by_path__spec": 0,
             "tokens_by_path__dense": 0,
             # preemption-safe serving (ISSUE 19): spill-drains run, blocks
@@ -782,24 +707,6 @@ class Engine:
                     for kind in (FULL, self._state_kind)}
                 self.metrics["decode_cache_bytes__full"] = 0
                 self.metrics[f"decode_cache_bytes__{self._state_kind}"] = 0
-        if self._ragged:
-            # token-budget utilization = ragged_tokens_packed /
-            # (ragged_dispatches * ragged rows) — how full the flat stream
-            # runs; always-on (ISSUE 13), maintained incrementally at each
-            # ragged dispatch so GetMetrics needs no recompute
-            self.metrics["ragged_dispatches"] = 0
-            self.metrics["ragged_tokens_packed"] = 0
-            self.metrics["budget_utilization"] = 0.0
-            # dispatch-budget bookkeeping (ISSUE 16): prefill tokens that
-            # rode ragged packs (they earn budget credit alongside generated
-            # tokens) and spec-as-ragged dispatches (still exempt — see
-            # testing/tripwires.dispatch_budget)
-            self.metrics["ragged_prefill_tokens"] = 0
-            self.metrics["spec_ragged_dispatches"] = 0
-        # per-request path attribution (bench.py --mode soup): opt-in so the
-        # dict can't grow unbounded under a long-lived server
-        self.record_paths = False
-        self.req_path_counts: dict[int, dict] = {}
         if self._tiered:
             # KV lifecycle telemetry: cold demotions, evictions (window-
             # exited blocks dropped — ring overwrite, or a full cold pool),
@@ -918,9 +825,6 @@ class Engine:
             for h, _group, _fetch in self._host_pending:
                 self._kvhost.end_spill(h, None)
         self._host_pending = []
-        self._ragged_rr = 0   # ragged decode-row round-robin offset (fair
-                              # rotation when the token budget can't hold
-                              # every live slot in one tick)
 
         with activate_mesh(self.mesh):
             self._cos, self._sin = rope_tables(cfg, T)
@@ -1216,31 +1120,7 @@ class Engine:
                 build_spec_admit_tail(cfg), donate_argnums=(0,))
             self._draft_ingest_fn = jax.jit(
                 build_draft_ingest(dcfg), donate_argnums=(3, 4))
-            # spec-as-ragged: the verify pass as a ragged pack variant —
-            # draft windows are just extra qlen rows in the flat stream,
-            # packed alongside other tenants' prefill chunks (and their
-            # multimodal inject rows) in ONE program (engine/spec.py
-            # build_spec_ragged). Replaces the per-mode dense verify on
-            # ragged engines; the extend-based _spec_fn stays for dense ones.
-            self._spec_ragged_fn = None
-            if self._ragged:
-                from localai_tpu.engine.spec import build_spec_ragged
 
-                _specr_raw = build_spec_ragged(cfg, dcfg, self.ec.gamma)
-
-                def _specr(*a, **kw):
-                    (tokens_out, n_out, logprobs_out, next_tokens, kct, vct,
-                     kcd, vcd, sampler, last_logits, lengths,
-                     n_extra) = _specr_raw(*a, **kw)
-                    return (constrain(tokens_out, P(None, None)),
-                            constrain(n_out, P(None)),
-                            constrain(logprobs_out, P(None, None)),
-                            constrain(next_tokens, P(None)),
-                            kct, vct, kcd, vcd, sampler, last_logits,
-                            lengths, constrain(n_extra, P(None)))
-
-                self._spec_ragged_fn = jax.jit(
-                    _specr, donate_argnums=(6, 7, 8, 9, 10, 11, 12, 13))
         def named(fn, name: str):
             # jax.jit names a functools.partial's program `jit__unknown`. A
             # model with window and full layers spends whole seconds in
@@ -1327,87 +1207,6 @@ class Engine:
             self._decode_loop_fn = jax.jit(
                 _loop, donate_argnums=(3, 4, 5, 6, 7),
                 static_argnames=("fast_width",))
-
-        # standalone sampler-row install: the ragged path defers a final
-        # chunk's row to its own small dispatch (the ragged program's
-        # signature stays row-structure-free, so it compiles exactly once)
-        self._install_fn = jax.jit(_install_row, donate_argnums=(0,))
-
-        # ragged mixed-tick program: sample all slots from last_logits,
-        # splice the sampled tokens into the packed flat stream at the
-        # decode rows, then ONE ragged forward covers every decode slot and
-        # prefill chunk (models/llama.ragged_forward). Per-slot RNG/count
-        # semantics mirror _decode exactly — topk_width=None draws the same
-        # tokens as any fast-width tier (ops/sampling._draw is width-
-        # independent), so ragged and dense serving emit identical streams.
-        self._ragged_fn = None
-        self._ragged_loop_fn = None
-        if self._ragged:
-            from localai_tpu.models.llama import ragged_forward
-
-            def _ragged_step(params, cos, sin, kc, vc, sampler, last_logits,
-                             lengths, tokens_flat, decode_slot, is_decode,
-                             set_len, logit_set, logit_rows, block_seq,
-                             qstart, qlen, kvlen, table, kvt=None,
-                             mask_bits=None, inject=None):
-                # mask_bits [B, ceil(V/8)] u8 rides ticks with grammar slots
-                # (the pack is consumed synchronously, so host masks are
-                # always fresh — this covers table AND overflow grammars);
-                # inject (extra [T, H] f32, is_embed [T] bool) carries
-                # multimodal feature rows for packed prompt chunks. Both are
-                # None on the common path — jit specializes each variant.
-                sampled, keys, logprobs = sample(last_logits, sampler,
-                                                 mask_bits, topk_width=None)
-                toks = jnp.where(decode_slot >= 0,
-                                 sampled[jnp.maximum(decode_slot, 0)],
-                                 tokens_flat)
-                logits, kc, vc = ragged_forward(
-                    params, cfg, toks, cos, sin, kc, vc, block_seq, qstart,
-                    qlen, kvlen, table, logit_rows, kvt, inject)
-                act = is_decode.astype(jnp.int32)
-                counts = sampler.token_counts.at[
-                    jnp.arange(sampled.shape[0]), sampled].add(act)
-                sampler = dataclasses.replace(sampler, key=keys,
-                                              token_counts=counts)
-                # decode slots and final prefill chunks pick up their new
-                # last-token logits; mid-chunk and idle slots hold theirs
-                last_logits = jnp.where(logit_set[:, None], logits,
-                                        last_logits)
-                lengths = jnp.where(set_len >= 0, set_len, lengths + act)
-                return (constrain(sampled, P(None)),
-                        constrain(logprobs, P(None)),
-                        kc, vc, sampler, last_logits, lengths)
-
-            self._ragged_fn = jax.jit(_ragged_step,
-                                      donate_argnums=(3, 4, 5, 6, 7))
-
-            # fused multi-step ragged tick (ISSUE 16): iteration 0 is the
-            # mixed ragged body above, follow-on iterations re-derive the
-            # decode metadata on device and run the raw dense body — one
-            # dispatch covers up to ragged_loop_steps decode steps with
-            # first-finish / prefill-pending early exit
-            # (models/llama.build_ragged_loop). Draft engines keep the
-            # spec-as-ragged single-step tick: verify windows are whole
-            # rows of the pack and must return to the host every tick.
-            if self.ec.ragged_loop_steps > 1 and self._draft is None:
-                from localai_tpu.models.llama import build_ragged_loop
-
-                _rloop_raw = build_ragged_loop(
-                    _ragged_step, _decode_raw,
-                    max_steps=self.ec.ragged_loop_steps,
-                    limit=self.ec.max_context - 2 - self._ctx_reserve)
-
-                def _rloop(*a, **kw):
-                    (toks, lps, n_out, steps, code, kc, vc, sampler,
-                     last_logits, lengths) = _rloop_raw(*a, **kw)
-                    return (constrain(toks, P(None, None)),
-                            constrain(lps, P(None, None)),
-                            constrain(n_out, P(None)), steps, code,
-                            kc, vc, sampler, last_logits, lengths)
-
-                self._ragged_loop_fn = jax.jit(
-                    _rloop, donate_argnums=(3, 4, 5, 6, 7),
-                    static_argnames=("fast_width", "has_pack"))
 
         # cold demotion: copy ONE hot physical block into a cold-pool index
         # with sub-channel (per-token over head_dim) int8 quantization.
@@ -1801,234 +1600,6 @@ class Engine:
                 *fargs, **fkw)
         return _AsyncFetch((toks, lps, n_out, steps))
 
-    def _dev_ragged(self, pack):
-        """ONE flat-stream dispatch for a mixed tick: every live decode slot
-        (one sampled token each) plus packed chunked-prefill windows run a
-        single ragged-attention forward. `pack` is the host-built metadata
-        (see _ragged_tick); `packed` counts the live token rows for the
-        budget-utilization metric."""
-        self.metrics["decode_dispatches"] += 1
-        self.metrics["decode_steps_dispatched"] += 1
-        self.metrics["ragged_dispatches"] = (
-            self.metrics.get("ragged_dispatches", 0) + 1)
-        self.metrics["ragged_tokens_packed"] = (
-            self.metrics.get("ragged_tokens_packed", 0)
-            + int(pack["packed"]))
-        self._credit_experts(self._ragged_rows, pack["packed"])
-        # non-decode rows actually packed (prefill-chunk tokens): the
-        # dispatch-budget tripwire credits these against the per-token
-        # budget, so mixed consolidation stays exempt-by-math while
-        # decode-heavy single-step ragged streams count at full price
-        self.metrics["ragged_prefill_tokens"] = (
-            self.metrics.get("ragged_prefill_tokens", 0)
-            + int(pack["packed"]) - int(np.sum(pack["is_decode"])))
-        self.metrics["budget_utilization"] = (
-            self.metrics["ragged_tokens_packed"]
-            / max(self.metrics["ragged_dispatches"] * self._ragged_rows, 1))
-        self._bcast("ragged", **dict(
-            pack, inject=self._inj_msg(pack.get("inject"))))
-        with activate_mesh(self.mesh), self._decode_guard():
-            mask = pack.get("mask")
-            variant = ("ragged" + ("_mask" if mask is not None else "")
-                       + ("_inj" if pack.get("inject") is not None else ""))
-            fargs = (self.params, self._cos, self._sin, self._kc, self._vc,
-                     self._sampler, self._last_logits, self._lengths,
-                     jnp.asarray(pack["tokens"]),
-                     jnp.asarray(pack["decode_slot"]),
-                     jnp.asarray(pack["is_decode"]),
-                     jnp.asarray(pack["set_len"]),
-                     jnp.asarray(pack["logit_set"]),
-                     jnp.asarray(pack["logit_rows"]),
-                     jnp.asarray(pack["block_seq"]),
-                     jnp.asarray(pack["qstart"]), jnp.asarray(pack["qlen"]),
-                     jnp.asarray(pack["kvlen"]), self._tab(), self._kvt(),
-                     None if mask is None else jnp.asarray(mask),
-                     self._inj(pack.get("inject")))
-            n_dec = int(np.sum(pack["is_decode"]))
-            rows = int(pack.get("rows_used", 0))
-            inj = pack.get("inject")
-            self._sched_pack(
-                variant, self._ragged_fn, fargs, {},
-                decode_rows=n_dec,
-                prefill_tokens=int(pack["packed"]) - n_dec,
-                mm_rows=0 if inj is None else int(np.sum(inj[1])),
-                pad_rows=max(rows - int(pack["packed"]), 0),
-                rows_used=rows, budget_rows=self._ragged_rows,
-                packed=int(pack["packed"]))
-            (tokens, logprobs, self._kc, self._vc, self._sampler,
-             self._last_logits, self._lengths) = self._ragged_fn(*fargs)
-        return _AsyncFetch((tokens, logprobs))
-
-    def _dev_ragged_loop(self, pack, remaining, check_eos, prefill_pending,
-                         gstate=None):
-        """ONE fused multi-step ragged dispatch (ISSUE 16): the mixed pack
-        runs as iteration 0, then up to ragged_loop_steps-1 dense decode
-        iterations continue every live decode slot on device
-        (models/llama.build_ragged_loop). `remaining`/`check_eos` [B] are
-        the PR 6 per-slot stop inputs; `prefill_pending` (traced bool) makes
-        the loop collapse to a single iteration when the host has prefill or
-        admission work, so TTFT stays at single-step ragged levels. Steps
-        actually run and the exit code ride the async fetch — step and
-        exit-reason metrics are credited at consume time."""
-        self.metrics["decode_dispatches"] += 1
-        self.metrics["ragged_dispatches"] = (
-            self.metrics.get("ragged_dispatches", 0) + 1)
-        self.metrics["ragged_tokens_packed"] = (
-            self.metrics.get("ragged_tokens_packed", 0)
-            + int(pack["packed"]))
-        self._credit_experts(self._ragged_rows, pack["packed"])
-        n_dec = int(np.sum(pack["is_decode"]))
-        self.metrics["ragged_prefill_tokens"] = (
-            self.metrics.get("ragged_prefill_tokens", 0)
-            + int(pack["packed"]) - n_dec)
-        self.metrics["budget_utilization"] = (
-            self.metrics["ragged_tokens_packed"]
-            / max(self.metrics["ragged_dispatches"] * self._ragged_rows, 1))
-        self._bcast("ragged_loop", remaining=remaining, check_eos=check_eos,
-                    prefill_pending=bool(prefill_pending), gstate=gstate,
-                    **pack)
-        with activate_mesh(self.mesh), self._decode_guard():
-            gkw = {}
-            if gstate is not None:
-                gmasks, gtrans = self._gtab()
-                gkw = dict(gstate=jnp.asarray(np.asarray(gstate, np.int32)),
-                           gmasks=gmasks, gtrans=gtrans)
-            variant = ("rloop_pack"
-                       + ("_grammar" if gstate is not None else ""))
-            dev_pack = dict(
-                tokens=jnp.asarray(pack["tokens"]),
-                decode_slot=jnp.asarray(pack["decode_slot"]),
-                set_len=jnp.asarray(pack["set_len"]),
-                logit_set=jnp.asarray(pack["logit_set"]),
-                logit_rows=jnp.asarray(pack["logit_rows"]),
-                block_seq=jnp.asarray(pack["block_seq"]),
-                qstart=jnp.asarray(pack["qstart"]),
-                qlen=jnp.asarray(pack["qlen"]),
-                kvlen=jnp.asarray(pack["kvlen"]))
-            fargs = (self.params, self._cos, self._sin, self._kc, self._vc,
-                     self._sampler, self._last_logits, self._lengths,
-                     jnp.asarray(pack["is_decode"]),
-                     jnp.asarray(remaining), jnp.asarray(check_eos),
-                     self._eos_dev, jnp.asarray(bool(prefill_pending)))
-            fkw = dict(pack=dev_pack, table=self._tab(), kvt=self._kvt(),
-                       fast_width=None, has_pack=True, **gkw)
-            rows = int(pack.get("rows_used", 0))
-            self._sched_pack(
-                variant, self._ragged_loop_fn, fargs, fkw,
-                decode_rows=n_dec,
-                prefill_tokens=int(pack["packed"]) - n_dec,
-                pad_rows=max(rows - int(pack["packed"]), 0),
-                rows_used=rows, budget_rows=self._ragged_rows,
-                packed=int(pack["packed"]))
-            (toks, lps, n_out, steps, code, self._kc, self._vc,
-             self._sampler, self._last_logits,
-             self._lengths) = self._ragged_loop_fn(*fargs, **fkw)
-        return _AsyncFetch((toks, lps, n_out, steps, code))
-
-    def _dev_rloop_decode(self, active, remaining, check_eos,
-                          fast_width=None, gstate=None):
-        """The fused ragged loop's pack-free variant: a pure-decode tick on
-        a ragged engine. Same stop conditions and grammar-table handling as
-        _dev_decode_loop, plus the first-finish early exit — one finished
-        slot returns control to the host so the freed slot admits
-        immediately instead of waiting out the remaining steps."""
-        self.metrics["decode_dispatches"] += 1
-        self._bcast("rloop_decode", active=active, remaining=remaining,
-                    check_eos=check_eos, fast_width=fast_width,
-                    gstate=gstate)
-        with activate_mesh(self.mesh), self._decode_guard():
-            gkw = {}
-            if gstate is not None:
-                gmasks, gtrans = self._gtab()
-                gkw = dict(gstate=jnp.asarray(np.asarray(gstate, np.int32)),
-                           gmasks=gmasks, gtrans=gtrans)
-            variant = ("rloop" + (f"_fast{fast_width}" if fast_width else "")
-                       + ("_grammar" if gstate is not None else ""))
-            fargs = (self.params, self._cos, self._sin, self._kc, self._vc,
-                     self._sampler, self._last_logits, self._lengths,
-                     jnp.asarray(active), jnp.asarray(remaining),
-                     jnp.asarray(check_eos), self._eos_dev,
-                     jnp.asarray(False))
-            fkw = dict(pack=None, table=self._tab(), kvt=self._kvt(),
-                       fast_width=fast_width, has_pack=False, **gkw)
-            n_act = int(np.sum(active))
-            B = self.ec.max_slots
-            self._sched_pack(variant, self._ragged_loop_fn, fargs, fkw,
-                             decode_rows=n_act, rows_used=B,
-                             pad_rows=B - n_act, packed=n_act)
-            (toks, lps, n_out, steps, code, self._kc, self._vc,
-             self._sampler, self._last_logits,
-             self._lengths) = self._ragged_loop_fn(*fargs, **fkw)
-        return _AsyncFetch((toks, lps, n_out, steps, code))
-
-    def _dev_spec_ragged(self, pack):
-        """ONE spec-as-ragged dispatch: gamma draft steps + a ragged target
-        verify covering every verifying slot's (gamma+1)-row window PLUS any
-        packed prefill chunks (and their multimodal inject rows) — the
-        one-program-for-every-tenant tick of a draft+ragged engine. Counted
-        as a ragged dispatch (exempt from the per-token dispatch budget the
-        same way, and for the same reason: it replaces N programs with 1)."""
-        self.metrics["decode_dispatches"] += 1
-        self.metrics["decode_steps_dispatched"] += self.ec.gamma + 1
-        self.metrics["ragged_dispatches"] = (
-            self.metrics.get("ragged_dispatches", 0) + 1)
-        # spec dispatches keep the dispatch-budget exemption (gamma-fused by
-        # construction; acceptance is gated separately) — the tripwire
-        # subtracts this counter, not ragged_dispatches
-        self.metrics["spec_ragged_dispatches"] = (
-            self.metrics.get("spec_ragged_dispatches", 0) + 1)
-        self.metrics["ragged_tokens_packed"] = (
-            self.metrics.get("ragged_tokens_packed", 0)
-            + int(pack["packed"]))
-        self._credit_experts(self._ragged_rows, pack["packed"])
-        self.metrics["budget_utilization"] = (
-            self.metrics["ragged_tokens_packed"]
-            / max(self.metrics["ragged_dispatches"] * self._ragged_rows, 1))
-        self._bcast("spec_ragged", **dict(
-            pack, inject=self._inj_msg(pack.get("inject"))))
-        with activate_mesh(self.mesh), self._decode_guard():
-            gkw = {}
-            gstate = pack.get("gstate")
-            if gstate is not None:
-                gmasks, gtrans = self._gtab()
-                gkw = dict(gstate=jnp.asarray(np.asarray(gstate, np.int32)),
-                           gmasks=gmasks, gtrans=gtrans)
-            variant = ("spec_ragged"
-                       + ("_grammar" if gstate is not None else "")
-                       + ("_inj" if pack.get("inject") is not None else ""))
-            fargs = (self.params, self._draft[1], self._cos, self._sin,
-                     self._cos_d, self._sin_d, self._kc, self._vc,
-                     self._kcd, self._vcd, self._sampler, self._last_logits,
-                     self._lengths, self._next_tokens,
-                     jnp.asarray(pack["verify"]),
-                     jnp.asarray(pack["tokens"]),
-                     jnp.asarray(pack["spec_rows"]),
-                     jnp.asarray(pack["set_len"]),
-                     jnp.asarray(pack["logit_set"]),
-                     jnp.asarray(pack["logit_rows"]),
-                     jnp.asarray(pack["block_seq"]),
-                     jnp.asarray(pack["qstart"]), jnp.asarray(pack["qlen"]),
-                     jnp.asarray(pack["kvlen"]), self._tab())
-            fkw = dict(kvt=self._kvt(),
-                       inject=self._inj(pack.get("inject")), **gkw)
-            n_win = int(np.sum(pack["verify"]))
-            win_toks = n_win * (self.ec.gamma + 1)
-            rows = int(pack.get("rows_used", 0))
-            inj = pack.get("inject")
-            self._sched_pack(
-                variant, self._spec_ragged_fn, fargs, fkw,
-                spec_windows=n_win,
-                prefill_tokens=int(pack["packed"]) - win_toks,
-                mm_rows=0 if inj is None else int(np.sum(inj[1])),
-                pad_rows=max(rows - int(pack["packed"]), 0),
-                rows_used=rows, budget_rows=self._ragged_rows,
-                packed=int(pack["packed"]))
-            (tokens_out, n_out, logprobs_out, self._next_tokens,
-             self._kc, self._vc, self._kcd, self._vcd, self._sampler,
-             self._last_logits, self._lengths,
-             n_extra) = self._spec_ragged_fn(*fargs, **fkw)
-        return _AsyncFetch((tokens_out, n_out, logprobs_out, n_extra))
-
     def _dev_demote(self, pb: int, ci: int):
         """Copy hot physical block `pb` into cold-pool index `ci` (int8,
         sub-channel scales). Enqueued AFTER any in-flight decode dispatch on
@@ -2170,7 +1741,7 @@ class Engine:
         from localai_tpu.models.llama import kernel_tiers
 
         return kernel_tiers(self.cfg, self.mesh, paged=self._paged,
-                            ragged=self._ragged, tiered=self._tiered)
+                            tiered=self._tiered)
 
     def kvhost_snapshot(self) -> dict:
         """Host-tier stats for GetTrace/debug surfaces ({} when off)."""
@@ -2179,19 +1750,6 @@ class Engine:
         st = self._kvhost.stats()
         st["pending"] = len(self._host_pending)
         return st
-
-    def _dev_install(self, idx, row, counts_row):
-        """Sampler-row install for a ragged final prefill chunk (the dense
-        path installs inside _extend_final; the ragged program defers it
-        here so its own signature stays row-structure-free)."""
-        self._bcast("install", idx=idx,
-                    row={k: np.asarray(v) for k, v in row.items()},
-                    counts_row=counts_row)
-        with activate_mesh(self.mesh):
-            self._sampler = self._install_fn(
-                self._sampler, jnp.int32(idx),
-                {k: jnp.asarray(v) for k, v in row.items()},
-                None if counts_row is None else jnp.asarray(counts_row))
 
     def _dev_shift(self, idx):
         self._bcast("shift", idx=idx)
@@ -2257,7 +1815,7 @@ class Engine:
             n_act = int(np.sum(active))
             B = self.ec.max_slots
             if self._sched is not None:
-                # dense spec is a non-ragged decode dispatch: it needs its
+                # a spec dispatch leaves the fused loop: it needs its
                 # dispatch-category code for the fallback-sum invariant
                 self._sched.reason("spec_dense")
             self._sched_pack("spec", self._spec_fn, fargs, {},
@@ -2317,25 +1875,8 @@ class Engine:
             self._dev_decode_loop(kw["active"], kw["remaining"],
                                   kw["check_eos"], kw.get("fast_width"),
                                   kw.get("gstate"))
-        elif op == "ragged":
-            self._dev_ragged(dict(kw, inject=self._inj_of(kw.get("inject"))))
-        elif op == "ragged_loop":
-            kw = dict(kw)
-            self._dev_ragged_loop(kw, kw.pop("remaining"),
-                                  kw.pop("check_eos"),
-                                  kw.pop("prefill_pending"),
-                                  gstate=kw.pop("gstate"))
-        elif op == "rloop_decode":
-            self._dev_rloop_decode(kw["active"], kw["remaining"],
-                                   kw["check_eos"], kw.get("fast_width"),
-                                   kw.get("gstate"))
-        elif op == "spec_ragged":
-            self._dev_spec_ragged(
-                dict(kw, inject=self._inj_of(kw.get("inject"))))
         elif op == "gtable":
             self._dev_gtable(int(kw["base"]), kw["masks"], kw["trans"])
-        elif op == "install":
-            self._dev_install(kw["idx"], kw["row"], kw["counts_row"])
         elif op == "demote":
             self._dev_demote(kw["pb"], kw["ci"])
         elif op == "shift":
@@ -2366,29 +1907,16 @@ class Engine:
                 f"need a larger context window"
             )
         if req.grammar and self._draft is not None:
-            if not self._ragged:
-                raise ValueError(
-                    "grammar-constrained decoding with a draft model needs "
-                    "ragged continuous batching (the spec-as-ragged verify "
-                    "threads the device grammar tables; the dense spec "
-                    "program has no grammar lane)")
-            # the verify window masks come from the DEVICE tables (the host
-            # cannot resync inside the fused draft+verify program), so the
-            # grammar must compile to a bounded automaton that fits the cap
-            if not self._gtab_cap or self._compile_grammar(
-                    req.grammar).table(self._gtab_cap) is None:
-                raise ValueError(
-                    "grammar automaton exceeds grammar_table_states; "
-                    "speculative verify needs the precompiled device "
-                    "grammar table (raise grammar_table_states or drop "
-                    "the draft model for this grammar)")
+            raise ValueError(
+                "grammar-constrained decoding is not served with a draft "
+                "model: the speculative verify program has no grammar lane. "
+                "Serve the model without a draft to constrain its output")
         if req.mm_embeds is not None:
-            if self._draft is not None and not self._ragged:
+            if self._draft is not None:
                 raise ValueError(
-                    "multimodal prompts with a draft model need ragged "
-                    "continuous batching (feature rows pack into the flat "
-                    "stream; the bucketed dense prefill has no draft-side "
-                    "path). The draft itself ingests token ids only.")
+                    "multimodal prompts are not served with a draft model: "
+                    "the draft ingests token ids only, so it cannot follow "
+                    "a prompt's feature rows")
             emb = np.asarray(req.mm_embeds, np.float32)
             pos = np.asarray(req.mm_positions, np.int64)
             if emb.ndim != 2 or emb.shape[1] != self.cfg.hidden_size:
@@ -2525,11 +2053,6 @@ class Engine:
             # → the slot keeps per-token host masks (and bars the loop)
             gbase = (self._grammar_table_entry(req.grammar)
                      if req.grammar else None)
-            if req.grammar and gbase is None and self._draft is not None:
-                # shared-capacity overflow after submit's buildability check
-                # (other grammars filled the table): reject per-request —
-                # spec verify cannot host-resync
-                raise ValueError("grammar table capacity exhausted")
             n = len(req.prompt_ids)
             chunked = n > self._small_max
             bucket = None if chunked else self._bucket(n)
@@ -2543,15 +2066,6 @@ class Engine:
             ))
             return False
         mm = req.mm_embeds is not None
-        if self._ragged:
-            # ragged admissions are always chunked: admission itself becomes
-            # host-only slot bookkeeping, and the prompt is packed unpadded
-            # into mixed ragged ticks — no bucket padding, no admission-time
-            # device dispatch. Multimodal prompts pack too: their feature
-            # rows ride the flat stream as per-row embedding overrides
-            # (ragged_forward's inject), so mm prompts no longer force the
-            # bucketed dense prefill
-            chunked, bucket = True, None
         if self._tiered and not pol.windowed:
             # admission-time policy demotion: a full-policy request that
             # cannot fit the compact table (its identity mapping would write
@@ -2846,15 +2360,8 @@ class Engine:
 
     def _prefill_drain(self, budget: int, pending: list):
         for _ in range(budget):
-            pq = self._prefillq
-            if self._ragged_now():
-                # ragged mode packs ALL token-level prefill — multimodal
-                # included, via the flat-stream injection lane — into mixed
-                # ragged ticks (_ragged_tick / _spec_ragged_tick); nothing
-                # takes the dense chunked path here
-                pq = []
-            if pq:
-                idx = pq[0]
+            if self._prefillq:
+                idx = self._prefillq[0]
                 slot = self._slots[idx]
                 ids = slot.req.prompt_ids
                 pos = slot.prefill_pos
@@ -3089,17 +2596,13 @@ class Engine:
             return "stop_string"
         return None
 
-    def _loop_eligible(self, entries) -> bool:
-        return self._loop_block_reason(entries) is None
-
     def _dispatch_loop(self, active, entries, fast):
         """Dispatch the fused while-loop block. Per-slot `remaining` budgets
         are max_tokens net of the PENDING dispatch's reservation, so two
         loop blocks can pipeline without ever overshooting a budget; a slot
         whose whole budget is already in flight sits this dispatch out (the
         device would run it zero steps anyway)."""
-        G = (self.ec.ragged_loop_steps if self._ragged_loop_fn is not None
-             else self._loop_steps)
+        G = self._loop_steps
         B = self.ec.max_slots
         remaining = np.zeros((B,), np.int32)
         check_eos = np.zeros((B,), bool)
@@ -3123,20 +2626,11 @@ class Engine:
         self._inflight_steps = G
         if self._sched is not None:
             # the fast path is recorded too, so the dispatch-category codes
-            # stay exhaustive over dense dispatches (the fallback-sum
-            # invariant bench.py's dense_fallback_reasons relies on)
+            # stay exhaustive over decode dispatches (they sum to
+            # decode_dispatches)
             self._sched.reason("loop_native")
         gstate = self._gstate.copy() if self._grammar_slots > 0 else None
         rows = self._rows_at_dispatch(fast)
-        if self._ragged_loop_fn is not None:
-            # ragged engines with the fused loop: pure-decode dispatches
-            # ride the pack-free ragged-loop variant — same stop semantics
-            # as the decode_loop program plus the first-finish early exit
-            # (a freed slot admits immediately instead of waiting out the
-            # loop; G above already capped reservations at its step budget)
-            fetch = self._dev_rloop_decode(active, remaining, check_eos,
-                                           fast, gstate=gstate)
-            return ("rloop", fetch, live, res, rows)
         fetch = self._dev_decode_loop(active, remaining, check_eos, fast,
                                       gstate=gstate)
         return ("loop", fetch, live, res, rows)
@@ -3166,9 +2660,9 @@ class Engine:
         if loop_block is None:
             return self._dispatch_loop(active, entries, fast)
         if self._sched is not None:
-            # exactly ONE dispatch-category code per dense dispatch — this
-            # is what lets bench.py explain dense_fallback_dispatches as a
-            # sum of reason-code counts
+            # exactly ONE dispatch-category code per dispatch — this is
+            # what lets a reader explain the dispatches that left the loop
+            # as a sum of reason-code counts
             self._sched.reason(loop_block)
         steps = self._block_steps()
         if self._linear and self._grammar_slots > 0:
@@ -3351,27 +2845,6 @@ class Engine:
             if s is not None and s.request_id == rid:
                 s.inflight = max(0, s.inflight - res.get(i, 0))
 
-    # device exit codes of the fused ragged loop (models/llama.py
-    # RLOOP_EXIT_*) → telemetry.sched pack reason codes. host_arbitration is
-    # recorded host-side at decline time (_ragged_tick), never by the device.
-    _RLOOP_EXIT_REASON = {
-        0: "loop_early_exit_steps_cap",
-        1: "loop_early_exit_finish",
-        2: "loop_early_exit_prefill",
-    }
-
-    def _rloop_exit(self, code: int, reason: str | None = None) -> None:
-        """Record one fused-ragged-loop exit: the sched pack reason code
-        (per-tick attribution) plus a flat metrics counter
-        (`rloop_exit_<cause>`) the bench JSON reports as
-        loop_exit_reasons."""
-        reason = reason or self._RLOOP_EXIT_REASON.get(
-            code, "loop_early_exit_steps_cap")
-        if self._sched is not None:
-            self._sched.reason(reason)
-        key = "rloop_exit_" + reason[len("loop_early_exit_"):]
-        self.metrics[key] = self.metrics.get(key, 0) + 1
-
     def _consume_loop(self, pend):
         """Consume a fused while-loop dispatch: finish the async token fetch,
         credit the ACTUAL step count (early exit makes it <= decode_loop),
@@ -3379,16 +2852,8 @@ class Engine:
         re-derives every finish decision in _emit — cancel/deadline can
         terminate a slot mid-buffer, and the rest of its tokens are dropped
         by the request-id check exactly as on the block path."""
-        tag, fetch, entries, res, rows = pend
-        out = self._await(fetch)
-        if tag == "rloop":
-            # fused ragged loop (pack-free variant): the fetch carries the
-            # device's exit code — map it onto the pack reason taxonomy and
-            # the flat loop-exit counters the bench scoreboard reads
-            tokens, logprobs, n_out, steps, code = out
-            self._rloop_exit(int(code))
-        else:
-            tokens, logprobs, n_out, steps = out
+        _, fetch, entries, res, rows = pend
+        tokens, logprobs, n_out, steps = self._await(fetch)
         steps = int(steps)
         self.metrics["decode_steps_dispatched"] += steps
         self._credit_consumed(steps, entries, n_out, rows)
@@ -3407,8 +2872,7 @@ class Engine:
                 if slot is None or slot.request_id != rid:
                     continue  # finished earlier (cancel/deadline/shift race)
                 self._emit(i, slot, int(tokens[g, i]),
-                           float(logprobs[g, i]), now,
-                           path="rloop" if tag == "rloop" else "loop")
+                           float(logprobs[g, i]), now, path="loop")
         self._credit_live()
 
     def _consume(self, pend):
@@ -3418,7 +2882,7 @@ class Engine:
         their block-START mask: the first token a slot's (live) PDA rejects
         marks that slot for rollback — its accepted prefix stands, the rest of
         its block is discarded, and _repair restores the device state."""
-        if pend[0] in ("loop", "rloop"):
+        if pend[0] == "loop":
             self._consume_loop(pend)
             return
         _, fetch, entries, gmask, res, rows = pend
@@ -3524,399 +2988,6 @@ class Engine:
             self._admit_phase()
         return (any(s is not None for s in self._slots)
                 or not self._queue.empty() or self._deferred is not None)
-
-    def _step_spec_ragged(self) -> bool:
-        """Draft+ragged iteration: ONE spec-as-ragged dispatch per tick —
-        gamma draft steps plus a ragged target verify whose flat stream
-        holds every verifying slot's (gamma+1)-row window AND any packed
-        prefill chunks (multimodal inject rows included). This is the path
-        a mixed tenant soup rides: spec, grammar, mm and plain traffic all
-        share the one program (engine/spec.py build_spec_ragged)."""
-        self._admit_phase()    # ragged admissions are host-only bookkeeping,
-        # so new arrivals can pack into THIS tick's stream
-        active = self._active_mask()
-        if active.any() or self._ragged_chunkable():
-            self._spec_ragged_tick(active, self._ragged_chunkable())
-        return (any(s is not None for s in self._slots)
-                or not self._queue.empty() or self._deferred is not None)
-
-    def _spec_ragged_tick(self, active, chunkable: list[int]):
-        """Pack verify windows + prefill chunks into one flat [T] stream and
-        dispatch a single spec-as-ragged program. Layout contract matches
-        _ragged_tick (QBLK-aligned per-seq q blocks, seq index == slot
-        index), except a verifying slot spans ceil((gamma+1)/QBLK) blocks —
-        the draft window is spliced into its rows ON DEVICE (the window
-        tokens live in device state; the host ships zeros)."""
-        from localai_tpu.ops.pallas import QBLK
-        B = self.ec.max_slots
-        T = self._ragged_rows
-        G = self.ec.gamma
-        winb = -(-(G + 1) // QBLK)
-        block_seq = np.full((T // QBLK,), -1, np.int32)
-        tokens = np.zeros((T,), np.int32)
-        verify = np.zeros((B,), bool)
-        spec_rows = np.zeros((B,), np.int32)
-        qstart = np.zeros((B,), np.int32)
-        qlen = np.zeros((B,), np.int32)
-        kvlen = np.zeros((B,), np.int32)
-        set_len = np.full((B,), -1, np.int32)
-        logit_set = np.zeros((B,), bool)
-        logit_rows = np.zeros((B, G + 1), np.int32)
-        row = 0
-        cap = T - QBLK   # one q-block always reserved for prefill
-        entries = []
-        order = [(self._ragged_rr + j) % B for j in range(B)]
-        self._ragged_rr = (self._ragged_rr + 1) % max(B, 1)
-        for i in order:
-            if not active[i]:
-                continue
-            s = self._slots[i]
-            if row + winb * QBLK > cap:
-                if self._sched is not None:
-                    self._sched.reason("budget_cap", kind="verify_windows")
-                break
-            n = s.prompt_len + s.generated - s.shifted
-            qstart[i], qlen[i], kvlen[i] = row, G + 1, n + G + 1
-            block_seq[row // QBLK: row // QBLK + winb] = i
-            spec_rows[i] = row
-            verify[i] = True
-            logit_rows[i] = row + np.arange(G + 1)
-            entries.append((i, s.request_id))
-            row += winb * QBLK
-        packed = len(entries) * (G + 1)
-        chunks = []
-        inj_extra = inj_mask = None
-        for idx in chunkable:
-            if T - row < QBLK:
-                if self._sched is not None:
-                    self._sched.reason("budget_cap", kind="prefill_chunks")
-                break
-            s = self._slots[idx]
-            ids = s.req.prompt_ids
-            pos = s.prefill_pos
-            nvalid = min(len(ids) - pos, T - row, self._chunk)
-            tokens[row:row + nvalid] = ids[pos:pos + nvalid]
-            nb = -(-nvalid // QBLK)
-            block_seq[row // QBLK:row // QBLK + nb] = idx
-            final = pos + nvalid == len(ids)
-            qstart[idx], qlen[idx] = row, nvalid
-            kvlen[idx] = pos + nvalid
-            if final:
-                set_len[idx] = pos + nvalid
-                logit_set[idx] = True
-                # all G+1 logit rows point at the final prompt row, so the
-                # kernel's last_logits merge picks up the admission logits
-                logit_rows[idx, :] = row + nvalid - 1
-            if s.req.mm_embeds is not None:
-                mpos, emb = s.req.mm_positions, s.req.mm_embeds
-                lo = int(np.searchsorted(mpos, pos))
-                hi = int(np.searchsorted(mpos, pos + nvalid))
-                if hi > lo:
-                    if inj_extra is None:
-                        inj_extra = np.zeros(
-                            (T, self.cfg.hidden_size), np.float32)
-                        inj_mask = np.zeros((T,), bool)
-                    sel = (mpos[lo:hi] - pos).astype(np.int64) + row
-                    inj_extra[sel] = emb[lo:hi]
-                    inj_mask[sel] = True
-            chunks.append((idx, pos, nvalid, final))
-            packed += nvalid
-            row += nb * QBLK
-        pack = dict(verify=verify, tokens=tokens, spec_rows=spec_rows,
-                    set_len=set_len, logit_set=logit_set,
-                    logit_rows=logit_rows, block_seq=block_seq,
-                    qstart=qstart, qlen=qlen, kvlen=kvlen, packed=packed,
-                    rows_used=row,
-                    # grammar verify masks come from the DEVICE tables
-                    # (submit() rejects draft+grammar automata that
-                    # overflow them), keyed by each slot's automaton state
-                    gstate=(self._gstate.copy()
-                            if self._grammar_slots > 0 else None),
-                    inject=(None if inj_extra is None
-                            else (inj_extra, inj_mask)))
-        self._mark_join(entries)
-        rows = self._rows_at_dispatch()
-        fetch = self._dev_spec_ragged(pack)
-        # chunk bookkeeping overlaps the device step; the draft ingests each
-        # chunk's token ids through its own (tiny) prefill program
-        for idx, pos, nvalid, final in chunks:
-            s = self._slots[idx]
-            s.prefill_pos = pos + nvalid
-            buf = np.zeros((1, self._chunk), np.int32)
-            buf[0, :nvalid] = s.req.prompt_ids[pos:pos + nvalid]
-            self._dev_draft_ingest(buf, pos, idx)
-            if final:
-                self._dev_install(idx, s.row, s.counts_row)
-                s.prefilled = True
-                self._prefillq.remove(idx)
-                if self._slo is not None:
-                    s.dispatches += 1
-                    s.path = "ragged"
-                tok, lp = self._dev_spec_admit_tail(idx)
-                self._emit(idx, s, tok, lp, time.monotonic(), path="spec")
-            elif self._slo is not None:
-                s.dispatches += 1
-                s.path = "ragged"
-        tokens_out, n_out, logprobs_out, n_extra = self._await(fetch)
-        now = time.monotonic()
-        self._credit_consumed(G + 1, rows=rows)
-        for i, rid in entries:
-            slot = self._slots[i]
-            if slot is None or slot.request_id != rid:
-                continue
-            self.metrics["draft_proposed"] += G
-            self.metrics["draft_accepted"] += int(n_extra[i])
-            if self._slo is not None:
-                slot.dispatches += 1
-            for j in range(int(n_out[i])):
-                slot = self._slots[i]
-                if slot is None or slot.request_id != rid:
-                    break  # finished mid-window (EOS/length/stop)
-                self._emit(i, slot, int(tokens_out[i, j]),
-                           float(logprobs_out[i, j]), now, path="spec")
-        self._credit_live()
-
-    # ------------------------------------------------------ ragged scheduling
-
-    def _ragged_now(self) -> bool:
-        """True when this tick may run the ragged mixed-dispatch path.
-        Grammar slots ride it too: the tick is consumed synchronously, so
-        the per-slot mask rows shipped with the pack are never stale — the
-        PDA (or its table mirror) advances before the next dispatch."""
-        return self._ragged
-
-    def _ragged_chunkable(self) -> list[int]:
-        """Prefill-queue slots whose next chunk can ride the flat stream.
-        Multimodal prompts pack too — their embedding chunks ride the
-        per-row injection lane (see the `inject` pack field)."""
-        return [i for i in self._prefillq if self._slots[i] is not None]
-
-    def _step_ragged(self) -> bool:
-        """Run one mixed ragged tick if there is prefill work to pack with
-        the running decodes. Returns False to fall through to the dense
-        tick — pure decode keeps the single-dispatch while-loop, which a
-        mixed program cannot beat when there is nothing to mix."""
-        admissible = ((not self._queue.empty() and bool(self._free))
-                      or (self._deferred is not None and self._blocks_freed))
-        if not self._ragged_chunkable() and not admissible:
-            return False
-        # host lengths must be exact before packing (loop dispatches have
-        # data-dependent step counts): consume the in-flight dispatch first.
-        # The ragged dispatch below is consumed synchronously in-tick, so
-        # the pipeline resumes cleanly on the next pure-decode tick.
-        if self._pending is not None:
-            self._consume(self._pending)
-            self._pending = None
-        self._admit_phase()    # ragged admissions land chunked (host-only)
-        chunkable = self._ragged_chunkable()
-        if not chunkable:
-            return False       # only mm prompts queued: dense tick serves
-        self._phases.switch("dispatch")
-        self._ragged_tick(chunkable)
-        return True
-
-    def _ragged_tick(self, chunkable: list[int]):
-        """Pack every live decode slot plus as many prefill-chunk tokens as
-        fit into ONE flat [T] token stream and dispatch a single ragged
-        forward. Layout contract (ops/pallas/ragged_attention): each
-        QBLK-row q block belongs to exactly one sequence; a decode slot
-        occupies one live row + QBLK-1 dead pad rows; a prefill chunk spans
-        ceil(n/QBLK) blocks. Seq index == engine slot index, so the device
-        derives every per-row position and page target from the engine's
-        own block table — no remapping, no bucket padding."""
-        from localai_tpu.ops.pallas import QBLK
-        B = self.ec.max_slots
-        T = self._ragged_rows
-        block_seq = np.full((T // QBLK,), -1, np.int32)
-        tokens = np.zeros((T,), np.int32)
-        decode_slot = np.full((T,), -1, np.int32)
-        qstart = np.zeros((B,), np.int32)
-        qlen = np.zeros((B,), np.int32)
-        kvlen = np.zeros((B,), np.int32)
-        set_len = np.full((B,), -1, np.int32)
-        logit_set = np.zeros((B,), bool)
-        is_decode = np.zeros((B,), bool)
-        logit_rows = np.zeros((B,), np.int32)
-        row = 0
-        entries = []
-        # Decode packing: one QBLK-aligned row per prefilled slot. One QBLK
-        # is always reserved for prefill so admission can't be starved by a
-        # full decode population; when the budget can't hold every slot the
-        # rotating offset keeps the overflow fair across ticks.
-        cap = T - QBLK
-        order = [(self._ragged_rr + j) % B for j in range(B)]
-        self._ragged_rr = (self._ragged_rr + 1) % max(B, 1)
-        for i in order:
-            s = self._slots[i]
-            if s is None or not s.prefilled:
-                continue
-            if row + QBLK > cap:
-                if self._sched is not None:
-                    self._sched.reason("budget_cap", kind="decode_rows")
-                break
-            n = s.prompt_len + s.generated - s.shifted
-            qstart[i], qlen[i], kvlen[i] = row, 1, n + 1
-            block_seq[row // QBLK] = i
-            decode_slot[row] = i
-            is_decode[i] = True
-            logit_set[i] = True
-            logit_rows[i] = row
-            entries.append((i, s.request_id))
-            row += QBLK
-        packed = len(entries)
-        chunks = []
-        inj_extra = inj_mask = None
-        for idx in chunkable:
-            if T - row < QBLK:
-                if self._sched is not None:
-                    self._sched.reason("budget_cap", kind="prefill_chunks")
-                break
-            s = self._slots[idx]
-            ids = s.req.prompt_ids
-            pos = s.prefill_pos
-            nvalid = min(len(ids) - pos, T - row, self._chunk)
-            tokens[row:row + nvalid] = ids[pos:pos + nvalid]
-            nb = -(-nvalid // QBLK)
-            block_seq[row // QBLK:row // QBLK + nb] = idx
-            final = pos + nvalid == len(ids)
-            qstart[idx], qlen[idx] = row, nvalid
-            kvlen[idx] = pos + nvalid
-            if final:
-                # device length is set only at the final chunk (mid chunks
-                # mirror extend_mid: host tracks prefill_pos, device length
-                # stays 0 so the slot can't be decoded early)
-                set_len[idx] = pos + nvalid
-                logit_set[idx] = True
-                logit_rows[idx] = row + nvalid - 1
-            if s.req.mm_embeds is not None:
-                # multimodal packing: this chunk's image-feature rows land
-                # at their flat-stream rows via the per-row injection lane
-                # (lazily allocated — text-only ticks skip the [T, H] cost)
-                mpos, emb = s.req.mm_positions, s.req.mm_embeds
-                lo = int(np.searchsorted(mpos, pos))
-                hi = int(np.searchsorted(mpos, pos + nvalid))
-                if hi > lo:
-                    if inj_extra is None:
-                        inj_extra = np.zeros(
-                            (T, self.cfg.hidden_size), np.float32)
-                        inj_mask = np.zeros((T,), bool)
-                    sel = (mpos[lo:hi] - pos).astype(np.int64) + row
-                    inj_extra[sel] = emb[lo:hi]
-                    inj_mask[sel] = True
-            chunks.append((idx, pos, nvalid, final))
-            packed += nvalid
-            row += nb * QBLK
-        pack = dict(tokens=tokens, decode_slot=decode_slot,
-                    is_decode=is_decode, set_len=set_len,
-                    logit_set=logit_set, logit_rows=logit_rows,
-                    block_seq=block_seq, qstart=qstart, qlen=qlen,
-                    kvlen=kvlen, packed=packed, rows_used=row,
-                    # grammar decode slots sample under their CURRENT mask
-                    # rows — consumed synchronously below, so never stale
-                    mask=(self._mask_host.copy()
-                          if self._grammar_slots > 0 else None),
-                    inject=(None if inj_extra is None
-                            else (inj_extra, inj_mask)))
-        # fused multi-step tick (ISSUE 16): run the pack as iteration 0 of
-        # the ragged loop and let every decode slot keep advancing on device
-        # until a slot finishes, host work appears, or the step cap. Host
-        # arbitration declines the loop: host-only grammar overflows and
-        # stop-string slots need per-token host decisions, and mm inject
-        # rows only occur mid-prefill where the loop would cap at one step
-        # anyway — all three keep the single-step dispatch (exact current
-        # behavior, fresh host masks).
-        res: dict[int, int] = {}
-        arbitration = (self._grammar_hostonly > 0
-                       or any(self._slots[i] is not None
-                              and self._slots[i].req.stop
-                              for i, _ in entries))
-        use_loop = (self._ragged_loop_fn is not None and bool(entries)
-                    and inj_extra is None and not arbitration)
-        self._mark_join(entries)
-        rows = self._rows_at_dispatch()
-        if use_loop:
-            remaining = np.zeros((B,), np.int32)
-            check_eos = np.zeros((B,), bool)
-            for i, rid in entries:
-                s = self._slots[i]
-                remaining[i] = max(1, s.req.max_tokens - s.generated
-                                   - s.inflight)
-                check_eos[i] = self.tok is not None and not s.req.ignore_eos
-                # pipelined-style budget reservation (PR 6): released at
-                # consume below, before emission moves tokens to `generated`
-                res[i] = int(min(self.ec.ragged_loop_steps, remaining[i]))
-                s.inflight += res[i]
-            # prefill-pending flag, computed at dispatch time: chunk work
-            # left after this pack (mid chunks, budget-capped slots),
-            # queued/deferred admissions — any of these collapses the loop
-            # to a single iteration so TTFT stays at ragged levels
-            left = set(self._prefillq) - {
-                idx for idx, _pos, _nv, fin in chunks if fin}
-            prefill_pending = (bool(left) or self._deferred is not None
-                               or not self._queue.empty())
-            fetch = self._dev_ragged_loop(
-                pack, remaining, check_eos, prefill_pending,
-                gstate=(self._gstate.copy()
-                        if self._grammar_slots > 0 else None))
-        else:
-            if (self._ragged_loop_fn is not None and entries
-                    and arbitration):
-                self._rloop_exit(-1,
-                                 reason="loop_early_exit_host_arbitration")
-            fetch = self._dev_ragged(pack)
-        for idx, pos, nvalid, final in chunks:
-            s = self._slots[idx]
-            s.prefill_pos = pos + nvalid
-            if final:
-                # sampler row rides a separate tiny dispatch so the ragged
-                # program's signature stays row-structure-free
-                self._dev_install(idx, s.row, s.counts_row)
-                s.prefilled = True
-                self._prefillq.remove(idx)
-        steps = 1
-        if use_loop:
-            tokens_out, logprobs, n_out, steps, code = self._await(fetch)
-            steps = int(steps)
-            self.metrics["decode_steps_dispatched"] += steps
-            self._rloop_exit(int(code))
-            self._release_reservations(entries, res)
-        else:
-            tokens_out, logprobs = self._await(fetch)
-        self._credit_consumed(steps, rows=rows)
-        now = time.monotonic()
-        if self._slo is not None:
-            # dispatch attribution: every slot packed into this ragged tick
-            # (decode rows AND prefill chunks) rode one device dispatch
-            for i, rid in entries:
-                s = self._slots[i]
-                if s is not None and s.request_id == rid:
-                    s.dispatches += 1
-            for idx, _pos, _nv, _fin in chunks:
-                s = self._slots[idx]
-                if s is not None:
-                    s.dispatches += 1
-                    s.path = "ragged"
-        if use_loop:
-            # drain the [steps, B] device token ring in device order — the
-            # host re-derives every finish decision in _emit exactly as on
-            # the loop path (cancel/deadline can drop a slot mid-ring)
-            for g in range(steps):
-                for i, rid in entries:
-                    if g >= int(n_out[i]):
-                        continue
-                    s = self._slots[i]
-                    if s is None or s.request_id != rid:
-                        continue
-                    self._emit(i, s, int(tokens_out[g, i]),
-                               float(logprobs[g, i]), now, path="ragged")
-        else:
-            for i, rid in entries:
-                s = self._slots[i]
-                if s is None or s.request_id != rid:
-                    continue
-                self._emit(i, s, int(tokens_out[i]), float(logprobs[i]),
-                           now, path="ragged")
-        self._credit_live()
 
     def _kv_tick(self):
         """Advance the hot→cold→evicted lifecycle for windowed slots.
@@ -4057,10 +3128,7 @@ class Engine:
 
     def _step_inner(self) -> bool:
         if self._draft is not None:
-            # draft + ragged = spec-as-ragged: every tick is ONE dispatch
-            # covering verify windows + prefill chunks (mm rows included)
-            return (self._step_spec_ragged() if self._ragged
-                    else self._step_spec())
+            return self._step_spec()
         if self._tiered or self._host_pending:
             with self._phases.within("kv"):
                 if self._tiered:
@@ -4070,12 +3138,6 @@ class Engine:
                     # by now) so the pool's occupancy metrics stay current
                     # even on admission-free ticks
                     self._host_drain()
-        if self._ragged_now() and self._step_ragged():
-            # mixed tick: decode + prefill ran as one ragged dispatch,
-            # consumed synchronously (no pending survives a ragged tick)
-            return (any(s is not None for s in self._slots)
-                    or not self._queue.empty() or self._pending is not None
-                    or self._deferred is not None)
         sync = self._grammar_slots > 0 or not self.ec.pipeline
         if sync and self._pending is not None:
             self._consume(self._pending)
@@ -4182,7 +3244,6 @@ class Engine:
                 (now - (slot.req.queued_t or slot.start_time)) * 1e3
         slot.generated += 1
         slot.gen_ids.append(token_id)
-        slot.path_counts[path] = slot.path_counts.get(path, 0) + 1
         self.metrics["tokens_generated"] += 1
         self.metrics["tokens_by_path__" + path] += 1
         slo = self._slo
@@ -4685,8 +3746,6 @@ class Engine:
             self._gstate[idx] = 0  # row 0 = identity (all-ones, self-loop)
             if slot.gbase is None:
                 self._grammar_hostonly -= 1
-        if self.record_paths:
-            self.req_path_counts[slot.request_id] = dict(slot.path_counts)
         windowed = False
         if self._tiered:
             pol = self._slot_policy[idx]
@@ -4782,90 +3841,15 @@ class Engine:
         B, V = self.ec.max_slots, self.cfg.vocab_size
         snap = {k: self.metrics[k] for k in (
             "decode_dispatches", "decode_steps_dispatched",
-            "host_sync_wait_ms") + (
-            ("ragged_dispatches", "ragged_tokens_packed",
-             "budget_utilization", "ragged_prefill_tokens",
-             "spec_ragged_dispatches")
-            if self._ragged else ())}
+            "host_sync_wait_ms")}
         idle = np.zeros((B,), bool)
         ones_mask = np.full((B, self._mask_nbytes), 0xFF, np.uint8)
         idle_gstate = (np.zeros((B,), np.int32)
                        if self._gtab_cap > 0 else None)
         try:
             if self._draft is not None:
-                if self._spec_ragged_fn is not None:
-                    # spec-as-ragged: warm every variant a mixed tenant soup
-                    # can reach (grammar tables x multimodal inject) so the
-                    # one-program tick never compiles mid-stream
-                    T = self._ragged_rows
-                    from localai_tpu.ops.pallas import QBLK
-                    G = self.ec.gamma
-                    base = dict(
-                        verify=idle,
-                        tokens=np.zeros((T,), np.int32),
-                        spec_rows=np.zeros((B,), np.int32),
-                        set_len=np.full((B,), -1, np.int32),
-                        logit_set=np.zeros((B,), bool),
-                        logit_rows=np.zeros((B, G + 1), np.int32),
-                        block_seq=np.full((T // QBLK,), -1, np.int32),
-                        qstart=np.zeros((B,), np.int32),
-                        qlen=np.zeros((B,), np.int32),
-                        kvlen=np.zeros((B,), np.int32),
-                        packed=0, gstate=None, inject=None)
-                    inj = (np.zeros((T, self.cfg.hidden_size), np.float32),
-                           np.zeros((T,), bool))
-                    variants = [dict(base)]
-                    if idle_gstate is not None:
-                        variants.append(dict(base, gstate=idle_gstate))
-                    variants.append(dict(base, inject=inj))
-                    if idle_gstate is not None:
-                        variants.append(dict(base, gstate=idle_gstate,
-                                             inject=inj))
-                    for pk in variants:
-                        self._dev_spec_ragged(pk).wait()
-                else:
-                    self._dev_spec_decode(idle).wait()
+                self._dev_spec_decode(idle).wait()
                 return
-            if self._ragged:
-                # all-dead packs compile the ragged program's variant set
-                # (shapes are fixed — [T] stream + [B] metadata — so one
-                # trace per mask/inject presence combination covers every
-                # future mix of decode rows, grammar slots and mm chunks)
-                T = self._ragged_rows
-                from localai_tpu.ops.pallas import QBLK
-                base = dict(
-                    tokens=np.zeros((T,), np.int32),
-                    decode_slot=np.full((T,), -1, np.int32),
-                    is_decode=np.zeros((B,), bool),
-                    set_len=np.full((B,), -1, np.int32),
-                    logit_set=np.zeros((B,), bool),
-                    logit_rows=np.zeros((B,), np.int32),
-                    block_seq=np.full((T // QBLK,), -1, np.int32),
-                    qstart=np.zeros((B,), np.int32),
-                    qlen=np.zeros((B,), np.int32),
-                    kvlen=np.zeros((B,), np.int32),
-                    packed=0, mask=None, inject=None)
-                inj = (np.zeros((T, self.cfg.hidden_size), np.float32),
-                       np.zeros((T,), bool))
-                for pk in (dict(base), dict(base, mask=ones_mask),
-                           dict(base, inject=inj),
-                           dict(base, mask=ones_mask, inject=inj)):
-                    self._dev_ragged(pk).wait()
-                if self._ragged_loop_fn is not None:
-                    # fused multi-step pack variants (ISSUE 16): the loop
-                    # program is one trace per grammar-table presence —
-                    # prefill_pending/remaining are traced runtime values,
-                    # so one all-dead dispatch covers every future mix
-                    lp = {k: v for k, v in base.items()
-                          if k not in ("mask", "inject")}
-                    self._dev_ragged_loop(
-                        dict(lp), np.zeros((B,), np.int32),
-                        np.zeros((B,), bool), False).wait()
-                    if idle_gstate is not None:
-                        self._dev_ragged_loop(
-                            dict(lp), np.zeros((B,), np.int32),
-                            np.zeros((B,), bool), False,
-                            gstate=idle_gstate).wait()
             widths = [None]
             W = self.ec.sampling_topk_width
             if W:
@@ -4873,24 +3857,12 @@ class Engine:
                 if min(8 * W, V) != min(W, V):
                     widths.append(min(8 * W, V))   # the escalation tier
             for w in widths:
-                if self._ragged_loop_fn is not None:
-                    # fused-ragged engines dispatch the loop's pack-free
-                    # variant for pure-decode ticks; _dev_decode_loop never
-                    # runs there, so warming it would be a wasted compile
-                    self._dev_rloop_decode(
-                        idle, np.zeros((B,), np.int32),
-                        np.zeros((B,), bool), w).wait()
-                elif self._decode_loop_fn is not None:
+                if self._decode_loop_fn is not None:
                     self._dev_decode_loop(
                         idle, np.zeros((B,), np.int32),
                         np.zeros((B,), bool), w).wait()
                 self._dev_decode(idle, None, w).wait()
-            if self._ragged_loop_fn is not None and idle_gstate is not None:
-                self._dev_rloop_decode(idle, np.zeros((B,), np.int32),
-                                       np.zeros((B,), bool), None,
-                                       gstate=idle_gstate).wait()
-            elif (self._decode_loop_fn is not None
-                    and idle_gstate is not None):
+            if self._decode_loop_fn is not None and idle_gstate is not None:
                 # the grammar-table loop variant (full-sort sampling only —
                 # masked slots never ride a fast_width tier)
                 self._dev_decode_loop(idle, np.zeros((B,), np.int32),
@@ -4914,7 +3886,7 @@ class Engine:
     def rooflines(self, force: bool = False) -> dict:
         """Per-variant XLA cost analysis → roofline attribution (ISSUE 13).
 
-        AOT-lowers each captured decode/ragged/spec/loop variant with its
+        AOT-lowers each captured decode/spec/loop variant with its
         abstract arg shapes (jax.ShapeDtypeStruct — see _sched_pack) and
         reads `compile().cost_analysis()` for FLOPs + bytes accessed. The
         AOT compile does NOT populate the jit call cache, so the

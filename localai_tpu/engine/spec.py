@@ -26,30 +26,15 @@ penalties — with token counts frozen at window start (the same
 approximation llama.cpp's spec sampler makes). The draft proposes from a
 temperature-only distribution; any proposal is distribution-safe under the
 accept/residual rule.
-
-Fused multi-step ragged ticks (ISSUE 16) and spec: verify windows stay
-SINGLE-step. A spec tick already amortizes the dispatch boundary over
-gamma+1 tokens per slot, and the accept/rollback arbitration after each
-window is inherently host-side (acceptance counts feed gamma autotuning and
-per-request rollback bookkeeping), so draft engines never build
-`_ragged_loop_fn` — the engine gates the fused loop on `self._draft is
-None` in `_build_jit`. Were a future PR to fold verify windows into the
-device loop, acceptance would have to become a loop-carried reduction and
-any rejection would force the `loop_early_exit_host_arbitration` exit; the
-spec-as-ragged pack layout (gamma+1 rows per verifying slot) already fits
-the loop's ragged iteration, so only the arbitration move is open.
 """
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 
 import jax
 import jax.numpy as jnp
 
-from localai_tpu.models.llama import (
-    LlamaConfig, decode_step, extend, ragged_forward,
-)
+from localai_tpu.models.llama import LlamaConfig, decode_step, extend
 from localai_tpu.ops.sampling import (
     SamplerState, pipeline_logits, sample, sampling_probs,
 )
@@ -162,7 +147,7 @@ def build_spec_decode(cfg_t: LlamaConfig, cfg_d: LlamaConfig, gamma: int):
 
 def _verify_outputs(sampler, active, step_keys, carry_keys, d_tok,
                     p_d_stack, tlogits, G, mask_rows=None):
-    """Shared verify tail of both spec programs (extend-based and ragged):
+    """The spec program's verify tail:
     target distributions per window position, vectorized Leviathan accept,
     residual correction token, output assembly, sampler commit.
 
@@ -241,139 +226,6 @@ def _verify_outputs(sampler, active, step_keys, carry_keys, d_tok,
     sampler = dataclasses.replace(sampler, key=carry_keys,
                                   token_counts=counts)
     return tokens_out, n_out, logprobs_out, c, n_extra, sampler
-
-
-def build_spec_ragged(cfg_t: LlamaConfig, cfg_d: LlamaConfig, gamma: int):
-    """Speculative decode as a RAGGED PACK VARIANT (one program for every
-    tenant): the draft scan is unchanged, but the target verify runs through
-    ragged_forward — each verifying slot's [next_token, d_1..d_gamma] window
-    is just gamma+1 extra qlen rows in the flat token stream, packed
-    alongside chunked-prefill windows (and their multimodal inject rows) of
-    OTHER slots in the same dispatch. Draft tokens are spliced into the
-    stream on device (they are sampled inside this program), and
-    logit_rows [B, gamma+1] gathers the target distribution at every window
-    row. Grammar-constrained slots thread the device automaton tables: the
-    state chain along the draft path is unrolled (gamma is static), each
-    window position's target probs are masked by its state's row, and
-    grammar-invalid drafts die in the ordinary accept test.
-
-    (params_t, params_d, cos_t, sin_t, cos_d, sin_d, kct, vct, kcd, vcd,
-     sampler, last_logits, lengths, next_tokens, active, tokens [T],
-     spec_rows [B], set_len [B], logit_set [B], logit_rows [B, gamma+1],
-     block_seq, qstart, qlen, kvlen, table, kvt, inject, gstate, gmasks,
-     gtrans) →
-    (tokens_out [B, gamma+1], n_out [B], logprobs_out, next_tokens',
-     kct', vct', kcd', vcd', sampler', last_logits', lengths', n_extra)
-
-    `active` marks slots verifying a window this tick (prefilled, live);
-    `spec_rows[b]` is slot b's window start row in the stream (its rows are
-    host-zeroed and device-filled); set_len/logit_set carry the packed
-    prefill chunks' length commits and final-chunk last_logits updates,
-    exactly like the plain ragged program."""
-
-    def spec_ragged(params_t, params_d, cos_t, sin_t, cos_d, sin_d,
-                    kct, vct, kcd, vcd, sampler, last_logits, lengths,
-                    next_tokens, active, tokens, spec_rows, set_len,
-                    logit_set, logit_rows, block_seq, qstart, qlen, kvlen,
-                    table, kvt=None, inject=None, gstate=None, gmasks=None,
-                    gtrans=None):
-        B = next_tokens.shape[0]
-        G = gamma
-        T = tokens.shape[0]
-        act_i = active.astype(jnp.int32)
-
-        # one key split per step; all draws derive via fold_in (identical
-        # stream discipline to build_spec_decode so token parity holds)
-        new_keys = jax.vmap(
-            lambda kk: jax.random.split(jax.random.wrap_key_data(kk), 2)
-        )(sampler.key)
-        carry_keys = jax.vmap(jax.random.key_data)(new_keys[:, 0]).astype(
-            jnp.uint32)
-        step_keys = new_keys[:, 1]          # [B] typed keys
-
-        dstate = _draft_state(sampler)
-
-        # ---- draft phase: scan gamma draft decode steps (dense draft KV).
-        # Grammar slots thread their automaton state through the scan and
-        # mask each PROPOSAL by its state's row: a blind draft would be
-        # rejected by the masked verify almost every time (p_t = 0), which
-        # collapses speculative efficiency for constrained tenants. Any
-        # proposal distribution is safe under the accept/residual rule, so
-        # masking the draft changes throughput, never the output law.
-        gst0 = gstate if gmasks is not None else jnp.zeros(
-            (B,), jnp.int32)
-
-        def draft_iter(carry, i):
-            kcd, vcd, tok, gst = carry
-            logits_d, kcd, vcd = decode_step(
-                params_d, cfg_d, tok, lengths + i, cos_d, sin_d, kcd, vcd,
-                active)
-            dmask = gmasks[gst] if gmasks is not None else None
-            p_d = sampling_probs(logits_d, dstate, dmask)        # [B, V]
-            sub = jax.vmap(lambda k: jax.random.fold_in(k, 100 + i))(
-                step_keys)
-            d = jax.vmap(
-                lambda k, p: jax.random.categorical(k, jnp.log(p + TINY))
-            )(sub, p_d).astype(jnp.int32)
-            if gmasks is not None:
-                gst = gtrans[gst, d]
-            return (kcd, vcd, d, gst), (d, p_d)
-
-        (kcd, vcd, d_last, _), (drafts, p_ds) = jax.lax.scan(
-            draft_iter, (kcd, vcd, next_tokens, gst0), jnp.arange(G))
-        _, kcd, vcd = decode_step(params_d, cfg_d, d_last, lengths + G,
-                                  cos_d, sin_d, kcd, vcd, active)
-        d_tok = drafts.T                                         # [B, G]
-        p_d_stack = jnp.moveaxis(p_ds, 0, 1)                     # [B, G, V]
-
-        # ---- splice the verify windows into the flat stream on device:
-        # inactive slots' rows redirect past the end and the scatter drops
-        # them (their q blocks are dead padding in block_seq anyway)
-        window = jnp.concatenate([next_tokens[:, None], d_tok], axis=1)
-        rows = jnp.where(active[:, None],
-                         spec_rows[:, None] + jnp.arange(G + 1)[None, :],
-                         T)
-        toks = tokens.at[rows.reshape(-1)].set(window.reshape(-1),
-                                               mode="drop")
-
-        # ---- target verify: ONE ragged forward over spec windows AND any
-        # packed prefill chunks; [B, G+1] logit_rows → [B, G+1, V]
-        tlogits, kct, vct = ragged_forward(
-            params_t, cfg_t, toks, cos_t, sin_t, kct, vct, block_seq,
-            qstart, qlen, kvlen, table, logit_rows, kvt=kvt, inject=inject)
-
-        # packed final prefill chunks refresh last_logits (their G+1 gather
-        # rows all point at the chunk's last token, so any index works)
-        last_logits = jnp.where(logit_set[:, None], tlogits[:, -1],
-                                last_logits)
-
-        mask_rows = None
-        if gmasks is not None:
-            # automaton states along the draft path: window[0] is the
-            # already-emitted next_token (gstate is PAST it), so position j
-            # masks what may follow window[..j]. Unconstrained slots sit in
-            # identity row 0 (all-ones masks, self-loop) — bit-identical.
-            sts = [gstate]
-            for j in range(1, G + 1):
-                sts.append(gtrans[sts[-1], window[:, j]])
-            mask_rows = gmasks[jnp.stack(sts, axis=1)]       # [B,G+1,W32]
-
-        (tokens_out, n_out, logprobs_out, c, n_extra,
-         sampler) = _verify_outputs(sampler, active, step_keys, carry_keys,
-                                    d_tok, p_d_stack, tlogits, G,
-                                    mask_rows=mask_rows)
-        # prefill chunk slots commit their packed length; verify slots
-        # advance by the accepted run (disjoint sets — a slot mid-prefill
-        # is never active for verify)
-        lengths = jnp.where(set_len >= 0, set_len,
-                            lengths + act_i * (1 + n_extra))
-        next_tokens = jnp.where(active, c, next_tokens)
-        n_out = n_out * act_i
-        return (tokens_out, n_out, logprobs_out, next_tokens,
-                kct, vct, kcd, vcd, sampler, last_logits, lengths,
-                n_extra * act_i)
-
-    return spec_ragged
 
 
 def build_spec_admit_tail(cfg_t: LlamaConfig):

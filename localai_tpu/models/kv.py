@@ -6,10 +6,10 @@ calls `view` once; from then on its layer body talks to the view, and nothing
 outside this module knows how K and V are laid out. A view is a trace-time
 object: the arrays one layer touches (`k`, `v`) and what its format needs to
 address them. It knows how a window of K/V is WRITTEN (`append`: decode's one
-token a row; `write`: a prompt or a chunk; a pool's `write_stream`: a ragged
-tick), how a decode query READS it (`decode`: Pallas kernel or XLA twin, int8
-or not, per KV-head shard under a mesh), which rows a chunk's queries attend
-over (`attend_window`), and how it rides the layer scan (`carried`).
+token a row; `write`: a prompt or a chunk), how a decode query READS it
+(`decode`: Pallas kernel or XLA twin, int8 or not, per KV-head shard under a
+mesh), which rows a chunk's queries attend over (`attend_window`), and how it
+rides the layer scan (`carried`).
 
     DenseKV   the [L, B, KVH, T, D] stack, slot-contiguous
     RingKV    a WINDOW layer's stack: position p lives in row p mod R
@@ -95,12 +95,12 @@ def _pallas_attention(mesh) -> bool:
 
 
 def _pallas_paged_scatter(num_kv_heads: int) -> bool:
-    """Whether a block pool's decode write and ragged tick use the Pallas
-    kernels (ops/pallas/paged_scatter.py, ragged_attention.py) instead of
-    XLA. Under a mesh the pool shards its KV-head axis on 'model' and the
-    kernel runs per-shard via shard_map (the *_sharded twins) — usable iff
-    the KV-head count divides the TP axis; otherwise the XLA tier handles the
-    (unevenly shardable) pool."""
+    """Whether a block pool's decode write uses the Pallas kernel
+    (ops/pallas/paged_scatter.py) instead of XLA. Under a mesh the pool
+    shards its KV-head axis on 'model' and the kernel runs per-shard via
+    shard_map (the *_sharded twins) — usable iff the KV-head count divides
+    the TP axis; otherwise the XLA tier handles the (unevenly shardable)
+    pool."""
     mesh = current_mesh()
     if mesh is not None:
         tp = dict(zip(mesh.axis_names, mesh.devices.shape)).get("model", 1)
@@ -790,8 +790,6 @@ class PagedKV(NoKV):
     table: object = None
     redirect: object = None
     cold: tuple = ()        # (k, v) of the tier's cold pool (TieredKV)
-    target: tuple = ()      # a ragged stream's rows: (physical block, row)
-    meta: tuple = ()        # and its per-sequence metadata
     sb = rw = None          # the tier's ring map (TieredKV)
 
     def at(self, k, v, layer=None, cold=()):
@@ -861,38 +859,6 @@ class PagedKV(NoKV):
             return paged_view(self.k, self.table), paged_view(self.v, self.table)
         return (paged_view(self.k, self.table[rows]),
                 paged_view(self.v, self.table[rows]))
-
-    # ---- a ragged tick: one flat token stream over the pool
-
-    def stream(self, block_seq, qstart, qlen, kvlen, rows, pos, live, seq):
-        """The view of a ragged tick: per-sequence metadata (ragged_forward)
-        and, per stream row, its position, liveness and sequence. Each row's
-        scatter target (physical block, in-block row) is resolved here, once
-        a forward: dead rows target trash (block 0) at per-row offsets —
-        collisions there only overwrite other dead rows. (No cold pool rides
-        a ragged tick's scan: it does not read the cold tier.)"""
-        blk = self.k.shape[-2]
-        raw = self._resident(pos // blk, seq)
-        pb = jnp.where(live, self.table[seq, raw], 0)
-        off = jnp.where(live, pos % blk, rows % blk)
-        return dataclasses.replace(
-            self, target=(pb, off), cold=(),
-            meta=(block_seq, qstart, qlen, kvlen, self.table))
-
-    def write_stream(self, k, v):
-        """K/V [T, KVH, D] to their targets: row-DMA kernel or XLA twin."""
-        fn = (_kernel("ragged_scatter_append", self.quant) if self.kernels
-              else _kernel("ragged_scatter_xla", self.quant, sharded=False))
-        return self._repack(fn(*self._pools(), k, v, *self.target))
-
-    def attend_stream(self, q, **tier):
-        """Queries [T, H, D] over their sequences' blocks: the ragged kernel
-        streams through the table; the XLA twin gathers."""
-        fn = (_kernel("ragged_paged_attention", self.quant)
-              if self.kernels and not tier
-              else _kernel("ragged_attention_xla", self.quant, sharded=False))
-        return fn(q, *self._pools(), *self.meta, sliding_window=self.window,
-                  **tier)
 
 
 @dataclasses.dataclass
@@ -1015,12 +981,6 @@ class TieredKV(PagedKV):
         return mha_extend_tiered(
             q, kr, vr, positions, kv_pos, kv_ok, kvt["sinks"][rows],
             kvt["window"][rows], drop_window=not self.demotes)
-
-    def attend_stream(self, q):
-        # tiered reads ride the XLA twins (ring positions + retention
-        # masking); the ragged kernel's table streaming has no ring inverse
-        # yet. TODO(kvtier): _kv_map + _row_mask ring support.
-        return PagedKV.attend_stream(self, q, kvt=self.kvt)
 
 
 # ----------------------------------------------------- building a view

@@ -1168,7 +1168,7 @@ def _seq_ax():
 
 
 def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
-                 ragged: bool = False, tiered: bool = False) -> dict[str, str]:
+                 tiered: bool = False) -> dict[str, str]:
     """Which implementation serves each hot-path op for an engine of this
     shape — the same predicates the forwards consult at trace time, named
     for the backend's device report. 'pallas-interpret' means the Pallas
@@ -1202,11 +1202,6 @@ def kernel_tiers(cfg: LlamaConfig, mesh, *, paged: bool,
         "decode_kv_write": paged_kernel,
         "prefill_kv_write": "xla",
     }
-    if ragged:
-        # ragged ticks: one flat-stream attention + row-DMA write kernel
-        # pair, selected like the paged decode write
-        tiers["ragged_attention"] = "xla" if tiered else paged_kernel
-        tiers["ragged_kv_write"] = paged_kernel
     if cfg.num_experts:
         # a prompt's tokens through the experts (expert_form): the grouped
         # product kernel on one chip, its XLA loop under a mesh, the dense
@@ -1602,127 +1597,6 @@ def decode_step(params, cfg: LlamaConfig, tokens, lengths, cos, sin,
     return logits, k_cache, v_cache
 
 
-def ragged_forward(params, cfg: LlamaConfig, tokens, cos, sin,
-                   k_cache, v_cache, block_seq, qstart, qlen, kvlen,
-                   tables, logit_rows, kvt=None, inject=None):
-    """Mixed prefill+decode forward over ONE flat token stream (ragged
-    continuous batching, arXiv:2604.15464): decode tokens and chunked-prefill
-    windows from different requests pack into a single [T] stream and run as
-    one dispatch on the paged tier — no per-bucket padding, no separate
-    prefill and decode programs on mixed ticks.
-
-    tokens: [T] i32, T a multiple of ops.pallas.QBLK (8); every sequence's
-    rows start on a QBLK boundary (the engine packs this way) so each 8-row
-    kernel block belongs to exactly one sequence. Per-sequence metadata
-    ([NSEQ], padded with dead entries):
-      qstart[s]/qlen[s] — the sequence's row span in the stream (row units);
-      kvlen[s] — cache length INCLUDING this chunk (decode: old length + 1);
-      tables [NSEQ, MAXB] — block table into the paged pool;
-      block_seq [NQB=T/QBLK] — sequence id per q block, -1 for padding
-      blocks. logit_rows [NSEQ] — flat row of each sequence's last token
-      (decode rows and final prefill chunks; mid-prefill chunks may point
-      anywhere — their logits are ignored host-side). A 2-D logit_rows
-      [NSEQ, R] gathers R rows per sequence instead (logits [NSEQ, R, V]) —
-      the spec-as-ragged verify pass needs the distribution at every row of
-      its draft window, not just the last.
-
-    inject: optional (extra [T, H] float, is_embed [T] bool) — rows with
-    is_embed take `extra` directly instead of the token-id embedding lookup
-    (multimodal prefill chunks pack their projected image/audio embeddings
-    into the same flat stream; reference: LLaVA-style mm prompt splicing).
-
-    Everything per-ROW (rope positions, scatter targets) derives on device
-    from that per-sequence metadata, so the host ships O(NSEQ) scalars, not
-    O(T). Padding rows write to the trash block (physical 0) and produce
-    garbage attention output that never reaches a logit row.
-
-    k_cache/v_cache: paged pools [L, NB, KVH, BS, D] (QuantKV int8 twin
-    supported). Returns (logits [NSEQ, V] f32, k_cache, v_cache). Tier
-    selection matches the decode path: Pallas ragged kernels on TPU (or
-    LOCALAI_FORCE_PALLAS), sharded per KV-head shard under a TP mesh, XLA
-    gather/scatter twins otherwise."""
-    from localai_tpu.ops.pallas import QBLK
-
-    cache = kv.view(cfg, k_cache, v_cache, tables, kvt, pool=True)
-    t = tokens.shape[0]
-    block_seq, qstart, qlen, kvlen = (
-        a.astype(jnp.int32) for a in (block_seq, qstart, qlen, kvlen))
-
-    # per-row derivations (device-side, from per-seq metadata): sequence id,
-    # liveness and absolute position; the view resolves each row's scatter
-    # target from them (kv.PagedKV.stream)
-    rows = jnp.arange(t, dtype=jnp.int32)
-    sid = block_seq[rows // QBLK]
-    s = jnp.maximum(sid, 0)
-    live = (sid >= 0) & (rows >= qstart[s]) & (rows < qstart[s] + qlen[s])
-    pos = kvlen[s] - qlen[s] + (rows - qstart[s])
-    pos = jnp.where(live, jnp.clip(pos, 0, cos.shape[0] - 1), 0)
-    cache = cache.stream(block_seq, qstart, qlen, kvlen, rows, pos, live, s)
-    x = _embed(params, cfg, tokens, inject)[None]              # [1, T, H]
-
-    def layer(x, lp, view, kind):
-        def attend(q, k, v):
-            # current chunk lands in the pool FIRST (decode_step convention:
-            # attention then reads it back through the table — kvlen already
-            # counts it), so prefill chunks attend to themselves paged
-            wrote = view.write_stream(k[0], v[0])
-            return wrote.attend_stream(q[0]), wrote
-
-        return _block(cfg, x, lp, kind, cos, sin, pos[None], attend,
-                      (None, None))
-
-    x, (k_cache, v_cache) = _scan_layers(cfg, layer, x, params, cache)
-    x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-    # [NSEQ, H] for 1-D logit_rows, [NSEQ, R, H] for the 2-D spec windows
-    last = x[0][logit_rows.astype(jnp.int32)]
-    logits = _lm_head(last.astype(jnp.float32), params)
-    return logits, k_cache, v_cache
-
-
-def _loop_body(step_fn, limit: int, consts, stop, gmasks, gtrans):
-    """The iteration build_decode_loop and build_ragged_loop share: one
-    sample→decode `step_fn` over the slots still live, per-slot stops from
-    stop = (remaining, check_eos, eos_ids) and the static `limit`; consts =
-    (params, cos, sin, fast_width, table, kvt)."""
-    params, cos, sin, fast_width, table, kvt = consts
-    remaining, check_eos, eos_ids = stop
-    grammar = gmasks is not None
-
-    def body(carry):
-        (i, done, n_out, toks, lps, gstate, kc, vc, sampler,
-         last_logits, lengths) = carry
-        live = ~done
-        prev_key = sampler.key
-        mask = gmasks[gstate] if grammar else None
-        tokens, lp, kc, vc, sampler, logits, lengths = step_fn(
-            params, cos, sin, kc, vc, sampler, last_logits, lengths,
-            live, mask, fast_width, table, kvt)
-        # freeze finished slots: their key stream and last_logits hold
-        # at the finishing token (step_fn already gates lengths and
-        # token_counts on the active mask)
-        sampler = dataclasses.replace(
-            sampler, key=jnp.where(live[:, None], sampler.key, prev_key))
-        last_logits = jnp.where(live[:, None], logits, last_logits)
-        toks = toks.at[i].set(tokens)
-        lps = lps.at[i].set(lp)
-        n_out = n_out + live.astype(jnp.int32)
-        is_eos = check_eos & jnp.any(
-            tokens[:, None] == eos_ids[None, :], axis=1)
-        if grammar:
-            # advance the automaton on the emitted token; only a live
-            # slot's state moves. gtrans rows self-loop on EOS in
-            # accepting states and send masked-off tokens to the
-            # identity row 0 — neither is ever taken: sampling already
-            # excluded them.
-            gstate = jnp.where(live, gtrans[gstate, tokens], gstate)
-        done = done | (live & (is_eos | (n_out >= remaining)
-                               | (lengths >= limit)))
-        return (i + 1, done, n_out, toks, lps, gstate, kc, vc, sampler,
-                last_logits, lengths)
-
-    return body
-
-
 def build_decode_loop(step_fn, *, max_steps: int, limit: int):
     """While-loop variant of the fused decode block (Kernel Looping,
     arXiv:2410.23668): up to `max_steps` sample→decode iterations run as ONE
@@ -1774,6 +1648,7 @@ def build_decode_loop(step_fn, *, max_steps: int, limit: int):
                     fast_width=None, kvt=None, gstate=None, gmasks=None,
                     gtrans=None):
         B = lengths.shape[0]
+        grammar = gmasks is not None
         if gstate is None:
             gstate = jnp.zeros((B,), jnp.int32)
         init = (
@@ -1790,133 +1665,44 @@ def build_decode_loop(step_fn, *, max_steps: int, limit: int):
             i, done = carry[0], carry[1]
             return (i < max_steps) & jnp.any(~done)
 
-        body = _loop_body(step_fn, limit,
-                          (params, cos, sin, fast_width, table, kvt),
-                          (remaining, check_eos, eos_ids), gmasks, gtrans)
+        def body(carry):
+            (i, done, n_out, toks, lps, gstate, kc, vc, sampler,
+             last_logits, lengths) = carry
+            live = ~done
+            prev_key = sampler.key
+            mask = gmasks[gstate] if grammar else None
+            tokens, lp, kc, vc, sampler, logits, lengths = step_fn(
+                params, cos, sin, kc, vc, sampler, last_logits, lengths,
+                live, mask, fast_width, table, kvt)
+            # freeze finished slots: their key stream and last_logits hold
+            # at the finishing token (step_fn already gates lengths and
+            # token_counts on the active mask)
+            sampler = dataclasses.replace(
+                sampler, key=jnp.where(live[:, None], sampler.key, prev_key))
+            last_logits = jnp.where(live[:, None], logits, last_logits)
+            toks = toks.at[i].set(tokens)
+            lps = lps.at[i].set(lp)
+            n_out = n_out + live.astype(jnp.int32)
+            is_eos = check_eos & jnp.any(
+                tokens[:, None] == eos_ids[None, :], axis=1)
+            if grammar:
+                # advance the automaton on the emitted token; only a live
+                # slot's state moves. gtrans rows self-loop on EOS in
+                # accepting states and send masked-off tokens to the
+                # identity row 0 — neither is ever taken: sampling already
+                # excluded them.
+                gstate = jnp.where(live, gtrans[gstate, tokens], gstate)
+            done = done | (live & (is_eos | (n_out >= remaining)
+                                   | (lengths >= limit)))
+            return (i + 1, done, n_out, toks, lps, gstate, kc, vc, sampler,
+                    last_logits, lengths)
+
         (steps, _, n_out, toks, lps, _, kc, vc, sampler, last_logits,
          lengths) = jax.lax.while_loop(cond, body, init)
         return (toks, lps, n_out, steps, kc, vc, sampler, last_logits,
                 lengths)
 
     return decode_loop
-
-
-# fused ragged-loop exit codes (device → host; engine maps them onto the
-# telemetry.sched pack reason codes at consume time)
-RLOOP_EXIT_STEPS_CAP = 0   # ran the full max_steps budget
-RLOOP_EXIT_FINISH = 1      # a decode slot finished (EOS/max_tokens/context)
-RLOOP_EXIT_PREFILL = 2     # host-set prefill/admission-pending flag
-
-
-def build_ragged_loop(ragged_step, decode_step, *, max_steps: int,
-                      limit: int):
-    """Fused multi-step ragged tick (Kernel Looping over the ragged pack):
-    the mixed ragged dispatch plus up to `max_steps - 1` follow-on decode
-    iterations run as ONE device program, so every live decode slot keeps
-    advancing without a host round trip per token.
-
-    The re-pack between iterations degenerates to pure data movement on
-    device: iteration 0 runs `ragged_step` (the engine's single-step mixed
-    body — sample, splice into the flat stream, one ragged_forward over
-    decode rows + prefill chunks, set_len/logit_set commits), after which
-    every datum the next decode step needs (lengths, last_logits, sampler
-    state, block tables, grammar `gstate`) is already device-resident.
-    Iterations >= 1 therefore run `decode_step` (the SAME fused
-    sample→decode body the dense while loop uses) over the decode-live
-    slots — a [B]-row step, not a re-run of the [T]-row ragged forward, so
-    a multi-step dispatch costs ragged + (steps-1) x dense instead of
-    steps x ragged. Slots mid-prefill (or whose final chunk just packed,
-    sampler row pending host install) sit the continuation out frozen.
-
-    With `has_pack=False` the ragged iteration is skipped entirely and the
-    program is the pure-decode loop for ragged engines: `build_decode_loop`
-    semantics plus the early-exit conditions below. Per-slot RNG streams are
-    bit-identical to the single-step paths either way (`_draw` is width-
-    independent and finished slots freeze key/last_logits exactly as the
-    dense loop does).
-
-    The loop EARLY-EXITS (cond, evaluated per iteration) when:
-    - any decode slot finishes (EOS set / `remaining` budget / `limit`
-      context margin — the PR 6 stop conditions): the host can admit into
-      the freed slot immediately instead of waiting out the step cap;
-    - `prefill_pending` (a traced bool shipped per dispatch) says the host
-      has prefill chunks or admissible queue work: the dispatch collapses
-      to a single iteration so TTFT stays at ragged levels;
-    - the `max_steps` budget is spent.
-    Host-arbitration cases (host-only grammar masks, stop strings) never
-    reach this program — the engine falls back to the single-step ragged
-    dispatch and records `loop_early_exit_host_arbitration`.
-
-    Returns (toks [max_steps, B], lps [max_steps, B], n_out [B], steps_run,
-    exit_code, kc, vc, sampler, last_logits, lengths); slot b's valid
-    tokens are ring rows 0..n_out[b)-1 and exit_code is one of the
-    RLOOP_EXIT_* constants (finish wins over prefill wins over steps_cap).
-    """
-
-    def ragged_loop(params, cos, sin, kc, vc, sampler, last_logits, lengths,
-                    is_decode, remaining, check_eos, eos_ids,
-                    prefill_pending, pack=None, table=None, kvt=None,
-                    fast_width=None, gstate=None, gmasks=None, gtrans=None,
-                    *, has_pack: bool):
-        B = lengths.shape[0]
-        grammar = gmasks is not None
-        if gstate is None:
-            gstate = jnp.zeros((B,), jnp.int32)
-        done = ~is_decode
-        n_out = jnp.zeros((B,), jnp.int32)
-        toks = jnp.zeros((max_steps, B), jnp.int32)
-        lps = jnp.zeros((max_steps, B), jnp.float32)
-
-        i0 = jnp.int32(0)
-        if has_pack:
-            # iteration 0, unrolled: the exact single-step mixed ragged
-            # body. Every packed decode row samples and advances (the
-            # device cannot unpack a row), so the host only routes packs
-            # here when each decode entry has remaining budget >= 1.
-            mask0 = gmasks[gstate] if grammar else None
-            (tokens, lp, kc, vc, sampler, last_logits, lengths) = \
-                ragged_step(params, cos, sin, kc, vc, sampler, last_logits,
-                            lengths, pack["tokens"], pack["decode_slot"],
-                            is_decode, pack["set_len"], pack["logit_set"],
-                            pack["logit_rows"], pack["block_seq"],
-                            pack["qstart"], pack["qlen"], pack["kvlen"],
-                            table, kvt, mask0, pack.get("inject"))
-            toks = toks.at[0].set(tokens)
-            lps = lps.at[0].set(lp)
-            n_out = n_out + is_decode.astype(jnp.int32)
-            if grammar:
-                gstate = jnp.where(is_decode, gtrans[gstate, tokens], gstate)
-            is_eos = check_eos & jnp.any(
-                tokens[:, None] == eos_ids[None, :], axis=1)
-            done = done | (is_decode & (is_eos | (n_out >= remaining)
-                                        | (lengths >= limit)))
-            i0 = jnp.int32(1)
-
-        init = (i0, done, n_out, toks, lps, gstate, kc, vc, sampler,
-                last_logits, lengths)
-
-        def cond(carry):
-            i, done = carry[0], carry[1]
-            # first-finish exit: unlike build_decode_loop (which keeps
-            # looping until EVERY slot froze), one finished decode slot
-            # ends the dispatch — early-exit admission
-            return ((i < max_steps) & jnp.any(~done)
-                    & ~jnp.any(is_decode & done) & ~prefill_pending)
-
-        body = _loop_body(decode_step, limit,
-                          (params, cos, sin, fast_width, table, kvt),
-                          (remaining, check_eos, eos_ids), gmasks, gtrans)
-        (steps, done, n_out, toks, lps, _, kc, vc, sampler, last_logits,
-         lengths) = jax.lax.while_loop(cond, body, init)
-        exit_code = jnp.where(
-            jnp.any(is_decode & done), jnp.int32(RLOOP_EXIT_FINISH),
-            jnp.where(prefill_pending & jnp.any(~done),
-                      jnp.int32(RLOOP_EXIT_PREFILL),
-                      jnp.int32(RLOOP_EXIT_STEPS_CAP)))
-        return (toks, lps, n_out, steps, exit_code, kc, vc, sampler,
-                last_logits, lengths)
-
-    return ragged_loop
 
 
 def hidden_states(params, cfg: LlamaConfig, tokens, lengths=None):

@@ -108,13 +108,10 @@ try:
         ["model", "variant"])
     _SCHED_TICKS = Counter(
         "localai_sched_ticks_total", "Engine scheduler ticks", ["model"])
-    _SCHED_UTIL = Gauge(
-        "localai_sched_budget_utilization",
-        "Fraction of the ragged token budget carrying live tokens",
-        ["model"])
     _SCHED_PAD = Gauge(
         "localai_sched_pad_rows_frac",
-        "Fraction of allocated dispatch rows that were padding", ["model"])
+        "Fraction of dispatched rows that carried no live sequence",
+        ["model"])
     # host-RAM KV tier (ISSUE 17): pool occupancy is a level (Gauge);
     # spill/hit/eviction totals are cumulative (Counter via _counter_sync)
     _KV_HOST = Gauge(
@@ -1055,9 +1052,6 @@ class API:
                     continue
                 if key == "sched_ticks_total":
                     _counter_sync(_SCHED_TICKS, (name,), float(v))
-                    continue
-                if key == "sched_budget_utilization":
-                    _SCHED_UTIL.labels(name).set(v)
                     continue
                 if key == "sched_pad_rows_frac":
                     _SCHED_PAD.labels(name).set(v)

@@ -7,7 +7,7 @@ Two pieces, both process-wide singletons the way `trace.py`'s tracer is:
   (`BUCKETS_S`), keyed (metric, path). The engine feeds TTFT, its three
   stages (queue wait / admit→join / join→first token), inter-token latency
   (TPOT) and e2e per request, labeled by the decode path that served it
-  (loop / dense / ragged / spec); the HTTP process keeps the wait at its
+  (loop / dense / spec); the HTTP process keeps the wait at its
   admission gate (`gate_wait`), from the permit to the stream's pump
   thread running (`stream_start`), from the stream's end to the permit's
   release (`reply_to_release`) and the permit's whole life (`permit_hold`)

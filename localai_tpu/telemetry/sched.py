@@ -4,18 +4,17 @@ Three pieces, all riding the LOCALAI_METRICS default-ON gate:
 
 - `REASON_CODES`: the single registered taxonomy for every admission /
   fallback / demotion decision the engine makes. This is a STABLE CONTRACT
-  (README "Scheduler X-ray"): codes are only ever added, never renamed or
-  removed, and an unregistered code is a hard failure — a new fallback site
+  (README "Scheduler X-ray"): codes are added, never renamed, and leave
+  only with the path that emitted them (PR 47: the `pack` category); an
+  unregistered code is a hard failure — a new fallback site
   that forgets to register its reason fails the tripwire test, not a
   dashboard query six weeks later. The "dispatch" category has an exactness
-  invariant: every dense (non-ragged) decode dispatch emits EXACTLY ONE
-  dispatch-category code, so the per-code counters sum to
-  `decode_dispatches - ragged_dispatches` — the same quantity bench.py
-  reports as `dense_fallback_dispatches`.
+  invariant: every decode dispatch emits EXACTLY ONE dispatch-category
+  code, so the per-code counters sum to `decode_dispatches`.
 
 - `TickLedger`: per-engine ring of tick records. Each tick collects the
   pack composition of every dispatch (decode rows, prefill-chunk tokens,
-  spec verify windows, mm inject rows, pad/dead rows, token-budget rows)
+  spec verify windows, mm inject rows, pad/dead rows)
   plus the tick's reason codes, and commits one record — the record also
   feeds the flight recorder's tick ring, so a post-mortem shows the last N
   *scheduling decisions*, not just dispatch counts. Disabled
@@ -43,14 +42,12 @@ from localai_tpu.testing.lockdep import lockdep_lock
 
 # --------------------------------------------------------------- reason codes
 # code -> (category, description). Categories:
-#   dispatch  — why a dense decode dispatch ran instead of the fused
-#               while-loop (exactly ONE per dense dispatch; sums to
-#               dense_fallback_dispatches)
+#   dispatch  — why a decode dispatch ran instead of the fused while-loop
+#               (exactly ONE per dispatch; sums to decode_dispatches)
 #   demotion  — a fused block stepped DOWN the power-of-two ladder or was
 #               forced to a single step (may co-occur with a dispatch code)
 #   admission — a request was demoted/deferred/degraded at admission time
 #   kv        — KV lifecycle tier actions (per block)
-#   pack      — a ragged/spec pack hit its token-budget row cap
 REASON_CODES: dict[str, tuple[str, str]] = {
     "loop_native": (
         "dispatch", "fused while-loop dispatch (the fast path, not a "
@@ -72,8 +69,8 @@ REASON_CODES: dict[str, tuple[str, str]] = {
     "stop_string": (
         "dispatch", "an active slot has stop strings (per-token host scan)"),
     "spec_dense": (
-        "dispatch", "dense speculative dispatch (draft engine without "
-        "ragged packing)"),
+        "dispatch", "speculative dispatch (draft engine: gamma draft "
+        "steps and the verify window in one program)"),
     "context_margin": (
         "demotion", "a slot within 2*block of its context limit forced "
         "single-step dispatches"),
@@ -117,23 +114,6 @@ REASON_CODES: dict[str, tuple[str, str]] = {
         "admission", "a preempted request resumed without KV coverage "
         "(host pool disabled, evicted, or budget too small) and fell back "
         "to re-prefilling prompt+emitted"),
-    "budget_cap": (
-        "pack", "the ragged token budget filled; remaining decode rows or "
-        "prefill chunks wait for the next tick"),
-    "loop_early_exit_finish": (
-        "pack", "a fused ragged loop exited because a decode slot finished "
-        "(EOS/max_tokens/context) — the host admits into the freed slot "
-        "immediately instead of waiting out the step cap"),
-    "loop_early_exit_prefill": (
-        "pack", "a fused ragged loop ran a single iteration because the "
-        "host flagged pending prefill/admission work at dispatch time"),
-    "loop_early_exit_host_arbitration": (
-        "pack", "a fused-capable ragged tick fell back to a single-step "
-        "dispatch: a live slot needs per-token host decisions (host-only "
-        "grammar masks or stop-string scans)"),
-    "loop_early_exit_steps_cap": (
-        "pack", "a fused ragged loop ran its full ragged_loop_steps budget "
-        "with no early-exit condition"),
 }
 
 DISPATCH_CODES: tuple[str, ...] = tuple(
@@ -226,8 +206,7 @@ def roofline_entry(flops: float, bytes_: float,
 
 # ----------------------------------------------------------------- the ledger
 _PACK_FIELDS = ("decode_rows", "prefill_tokens", "spec_windows", "mm_rows",
-                "pad_rows", "rows_used", "budget_rows", "packed",
-                "budget_packed")
+                "pad_rows", "rows_used", "packed")
 
 
 class TickLedger:
@@ -235,7 +214,7 @@ class TickLedger:
 
         ledger.begin(tick_n)
         ledger.reason("pending_admission")        # any decision site
-        ledger.pack("ragged", decode_rows=..., ...)  # each dispatch
+        ledger.pack("loop", decode_rows=..., ...)  # each dispatch
         rec = ledger.commit(active_slots=..., queued=...)
 
     and hands the committed record to the flight recorder's tick ring.
@@ -307,7 +286,7 @@ class TickLedger:
     def pack(self, variant: str, *, decode_rows: int = 0,
              prefill_tokens: int = 0, spec_windows: int = 0,
              mm_rows: int = 0, pad_rows: int = 0, rows_used: int = 0,
-             budget_rows: int = 0, packed: int = 0) -> None:
+             packed: int = 0) -> None:
         """Record one dispatch's pack composition under its compiled program
         variant name (the same name engine.rooflines() costs). Every field
         counts ONCE A DISPATCH, whatever its steps: `pad_rows` of a dense
@@ -320,10 +299,7 @@ class TickLedger:
         comp = {"decode_rows": decode_rows, "prefill_tokens": prefill_tokens,
                 "spec_windows": spec_windows, "mm_rows": mm_rows,
                 "pad_rows": pad_rows, "rows_used": rows_used,
-                "budget_rows": budget_rows, "packed": packed,
-                # only budget-carrying dispatches feed the utilization ratio
-                # — a dense fallback's rows have no budget to utilize
-                "budget_packed": packed if budget_rows > 0 else 0}
+                "packed": packed}
         t = self.totals
         for k, v in comp.items():
             t[k] += v
@@ -347,18 +323,10 @@ class TickLedger:
 
     # -------------------------------------------------------------- export
 
-    def budget_utilization(self) -> float:
-        """Fraction of the ragged/spec token budget carrying live tokens
-        (dense dispatches have no budget rows and don't dilute this; 0.0
-        when no budget-carrying dispatch ran — dense-only engines)."""
-        if self.totals["budget_rows"] <= 0:
-            return 0.0
-        return self.totals["budget_packed"] / self.totals["budget_rows"]
-
     def pad_rows_frac(self) -> float:
-        """Fraction of ALLOCATED q rows that were QBLK-alignment padding —
-        the cost of the one-row-per-decode-slot layout contract. Counted
-        once a dispatch (see pack): no measure of rows idle by step."""
+        """Fraction of the rows dispatched that carried no live sequence.
+        Counted once a dispatch (see pack): no measure of rows idle by
+        step."""
         return self.totals["pad_rows"] / max(self.totals["rows_used"], 1)
 
     def flat(self, prefix: str = "sched_") -> dict[str, float]:
@@ -375,9 +343,6 @@ class TickLedger:
                 out[f"{prefix}variant__{name}"] = float(n)
             for k, v in self.totals.items():
                 out[f"{prefix}pack__{k}"] = float(v)
-            if self.totals["budget_rows"]:
-                out[f"{prefix}budget_utilization"] = \
-                    self.budget_utilization()
             out[f"{prefix}pad_rows_frac"] = self.pad_rows_frac()
             for name, e in self.rooflines.items():
                 out[f"{prefix}roofline__{name}__flops"] = e["cost_flops"]
@@ -395,9 +360,6 @@ class TickLedger:
                 "reason_counters": dict(self.counters),
                 "variants": dict(self.variants),
                 "pack_totals": dict(self.totals),
-                "budget_utilization": (self.budget_utilization()
-                                       if self.totals["budget_rows"]
-                                       else None),
                 "pad_rows_frac": self.pad_rows_frac(),
                 "rooflines": {k: dict(v)
                               for k, v in self.rooflines.items()},

@@ -22,9 +22,7 @@ see; these two guards catch what it can't:
   tokens. The single-dispatch while-loop makes a 128-token single-slot
   stream ~2 dispatches; a regression back to the scan ladder (8-16) or to
   per-step dispatches (128) trips the guard in a tier-1 test instead of a
-  chip profile. Ragged dispatches count too, credited with the tokens they
-  actually packed (generated + prefill-chunk) — only spec-as-ragged verify
-  windows are exempt.
+  chip profile.
 
 The concurrency sibling lives in `localai_tpu.testing.lockdep`: the same
 env-gate pattern (`LOCALAI_LOCKDEP=1` / `record`, raw locks when unset)
@@ -67,7 +65,7 @@ def transfer_guard(level: str = "disallow"):
 DECODE_FN_ATTRS = (
     "_decode_fn", "_decode_nomask_fn", "_decode_fast_fn",
     "_decode_block_fn", "_decode_block_mask_fn", "_decode_loop_fn",
-    "_spec_fn", "_ragged_fn", "_spec_ragged_fn", "_ragged_loop_fn",
+    "_spec_fn",
 )
 
 
@@ -105,27 +103,13 @@ def dispatch_budget(engine, max_per_128_tokens: float = 3.0):
     """Decode-dispatch counter guard: assert the enclosed stream spends no
     more than `max_per_128_tokens` decode dispatches per 128 generated
     tokens (pro-rated, floor 1). Reads the engine's own decode_dispatches /
-    tokens_generated counters, so it works across loop, block, ragged, and
-    spec paths without instrumentation.
-
-    Ragged mode counts for real (ISSUE 16): a ragged dispatch earns budget
-    from the tokens it actually packed — generated tokens through
-    `tokens_generated`, prefill chunk tokens through `ragged_prefill_tokens`
-    — so a decode-heavy single-step ragged stream (~1 dispatch per token,
-    ~4 prefill-credit tokens per dispatch) TRIPS a 3/128 budget unless the
-    fused multi-step loop engages. Only spec-as-ragged dispatches stay
-    exempt (`spec_ragged_dispatches` is subtracted): a verify window is
-    gamma-fused by construction and its efficiency is gated by acceptance
-    telemetry, not dispatch counting."""
+    tokens_generated counters, so it works across loop, block and spec
+    paths without instrumentation."""
     m = engine.metrics
     d0, t0 = m["decode_dispatches"], m["tokens_generated"]
-    s0 = m.get("spec_ragged_dispatches", 0)
-    p0 = m.get("ragged_prefill_tokens", 0)
     yield
-    dispatches = (m["decode_dispatches"] - d0) \
-        - (m.get("spec_ragged_dispatches", 0) - s0)
-    tokens = (m["tokens_generated"] - t0) \
-        + (m.get("ragged_prefill_tokens", 0) - p0)
+    dispatches = m["decode_dispatches"] - d0
+    tokens = m["tokens_generated"] - t0
     allowed = max(1, math.ceil(tokens / 128.0 * max_per_128_tokens))
     if dispatches > allowed:
         # flight-recorder post-mortem (ISSUE 11): the request timelines in
@@ -139,10 +123,9 @@ def dispatch_budget(engine, max_per_128_tokens: float = 3.0):
         rec.auto_dump("tripwire:dispatch_budget")
         raise AssertionError(
             f"decode dispatch budget exceeded: {dispatches} dispatches for "
-            f"{tokens} credited tokens (allowed {allowed} at "
-            f"{max_per_128_tokens}/128-token) — a fused loop (decode or "
-            f"ragged) is not engaging or has regressed to per-step "
-            f"dispatch")
+            f"{tokens} generated tokens (allowed {allowed} at "
+            f"{max_per_128_tokens}/128-token) — the fused decode loop is "
+            f"not engaging or has regressed to per-step dispatch")
 
 
 class CompileCounter:

@@ -245,12 +245,12 @@ def test_kernel_tiers_name_the_interpreter(monkeypatch):
     cfg = LlamaConfig(vocab_size=256, hidden_size=64, intermediate_size=128,
                       num_layers=2, num_heads=4, num_kv_heads=2, head_dim=16)
     monkeypatch.delenv("LOCALAI_FORCE_PALLAS", raising=False)
-    t = kernel_tiers(cfg, None, paged=True, ragged=True)
+    t = kernel_tiers(cfg, None, paged=True)
     assert set(t.values()) == {"xla"}          # CPU: XLA everywhere
     monkeypatch.setenv("LOCALAI_FORCE_PALLAS", "1")
-    t = kernel_tiers(cfg, None, paged=True, ragged=True)
+    t = kernel_tiers(cfg, None, paged=True)
     assert t["prefill_attention"] == t["decode_attention"] \
-        == t["decode_kv_write"] == t["ragged_attention"] == "pallas-interpret"
+        == t["decode_kv_write"] == "pallas-interpret"
     assert t["chunk_attention"] == "xla"
     # dense KV has no paged write, and a chunk attends over the blocks its
     # context fills; the tiered read has no kernel yet

@@ -1,6 +1,6 @@
 """Device-side grammar tables (ISSUE 12): dense automaton tables vs the
-host matcher, and the engine paths that consume them — the fused decode
-loop, the ragged pack, and the speculative verify window.
+host matcher, and the engine path that consumes them: the fused decode
+loop. A speculative engine refuses a grammar at submit.
 
 Table-unit cases run in tier-1; the engine parity sweeps are slow-marked
 and run standalone via `-m grammar`.
@@ -174,171 +174,29 @@ def test_loop_grammar_parity_vs_host_masking(loaded, temp):
         e_host.metrics["decode_dispatches"] / 4
 
 
-@pytest.mark.slow
-def test_ragged_grammar_parity(loaded):
-    """Grammar slots pack into the ragged stream alongside plain tenants
-    (greedy + sampled), matching the rollback-free dense reference; a
-    tables-off engine (hostonly masks) matches too."""
+@pytest.mark.parametrize("what, named", [
+    ("grammar", "grammar-constrained decoding is not served with a draft"),
+    ("multimodal", "multimodal prompts are not served with a draft"),
+], ids=["grammar", "multimodal"])
+def test_a_draft_engine_refuses_at_submit(loaded, what, named):
+    """A speculative engine has no grammar lane in its verify program and
+    its draft ingests token ids only: both are refused by name at submit,
+    nothing is enqueued, and the next plain request is served."""
     from localai_tpu.engine import Engine, EngineConfig
 
     cfg, params, tok = loaded
-
-    def ec(**kw):
-        return EngineConfig(max_slots=4, max_context=128,
-                            prefill_buckets=(16, 64), prefill_chunk=16,
-                            kv_pages=10, prompt_cache=False, **kw)
-
-    e_rag = Engine(cfg, params, tok, ec(ragged_token_budget=64))
-    e_ref = Engine(cfg, params, tok, ec(decode_block=1, decode_loop=0))
-    reqs = lambda: [_greq(tok, 0.0), _preq(tok), _greq(tok, 0.9, seed=9)]
-    ra = _drain(e_rag, reqs())
-    rb = _drain(e_ref, reqs())
-    assert ra == rb, (ra, rb)
-    assert e_rag.metrics["ragged_dispatches"] > 0
-
-    e_rag0 = Engine(cfg, params, tok,
-                    ec(ragged_token_budget=64, grammar_table_states=0))
-    rc = _drain(e_rag0, [_greq(tok, 0.0), _preq(tok)])
-    assert rc == ra[:2], (rc, ra[:2])
-
-
-@pytest.mark.slow
-def test_ragged_overflow_grammar_hostonly(loaded):
-    """The recursive JSON grammar overflows the table and keeps the host
-    mask path: greedy parity holds exactly (path-independent); sampled
-    streams stay grammar-conformant (the fused-block fallback re-keys on
-    rollback, so exact sampled parity is not a contract there)."""
-    from localai_tpu.engine import Engine, EngineConfig
-
-    cfg, params, tok = loaded
-
-    def ec(**kw):
-        return EngineConfig(max_slots=4, max_context=128,
-                            prefill_buckets=(16, 64), prefill_chunk=16,
-                            kv_pages=10, prompt_cache=False, **kw)
-
-    e_rag = Engine(cfg, params, tok, ec(ragged_token_budget=64))
-    e_ref = Engine(cfg, params, tok, ec(decode_block=1, decode_loop=0))
-    rj = _drain(e_rag, [_greq(tok, 0.0, g=JSON_GRAMMAR), _preq(tok)])
-    rk = _drain(e_ref, [_greq(tok, 0.0, g=JSON_GRAMMAR), _preq(tok)])
-    assert rj == rk, (rj, rk)
-    assert e_rag.metrics.get("grammar_table_overflows", 0) > 0
-    rs = _drain(e_rag, [_greq(tok, 0.9, seed=3, g=JSON_GRAMMAR)])
-    _assert_conformant(tok, JSON_GRAMMAR, rs[0][0])
-
-
-@pytest.mark.slow
-def test_mm_packed_prefill_parity(loaded):
-    """Multimodal embedding chunks pack into the flat ragged stream (the
-    per-row inject lane) and produce the same stream as the dense mm
-    prefill path."""
-    from localai_tpu.engine import Engine, EngineConfig, GenRequest
-    from localai_tpu.ops.sampling import SamplingParams
-
-    cfg, params, tok = loaded
-    embed = np.asarray(params["embed"], np.float32)
-    prompt = tok.encode("the quick brown fox jumps over")
-
-    def mmreq():
-        r = GenRequest(list(prompt), SamplingParams(temperature=0.0),
-                       max_tokens=10, ignore_eos=True)
-        r.mm_embeds = embed[prompt[1:4]] + 0.25
-        r.mm_positions = np.arange(1, 4)
-        return r
-
-    def ec(**kw):
-        return EngineConfig(max_slots=4, max_context=128,
-                            prefill_buckets=(16, 64), prefill_chunk=16,
-                            kv_pages=10, prompt_cache=False, **kw)
-
-    e_rag = Engine(cfg, params, tok, ec(ragged_token_budget=64))
-    e_ref = Engine(cfg, params, tok, ec(decode_block=1, decode_loop=0))
-    ma = _drain(e_rag, [mmreq(), _preq(tok)])
-    mb = _drain(e_ref, [mmreq(), _preq(tok)])
-    assert ma == mb, (ma, mb)
-    assert e_rag.metrics["ragged_dispatches"] > 0
-
-
-@pytest.mark.slow
-def test_spec_as_ragged_parity(loaded):
-    """Speculative decode as a ragged pack variant: the verify windows ride
-    ragged_forward and the token streams match the dense spec engine
-    exactly (same draft keys, same accept test)."""
-    from localai_tpu.engine import Engine, EngineConfig, load_config, \
-        load_params
-
-    cfg, params, tok = loaded
-
-    def ec(**kw):
-        return EngineConfig(max_slots=4, max_context=128,
-                            prefill_buckets=(16, 64), prefill_chunk=16,
-                            kv_pages=14, prompt_cache=False, gamma=3, **kw)
-
-    draft = (cfg, params)  # perfect draft: every proposal accepted
-    e_sr = Engine(cfg, params, tok, ec(ragged_token_budget=96), draft=draft)
-    e_sd = Engine(cfg, params, tok, ec(), draft=draft)
-    sa = _drain(e_sr, [_preq(tok, 16), _preq(tok, 16)])
-    sb = _drain(e_sd, [_preq(tok, 16), _preq(tok, 16)])
-    assert sa == sb, (sa, sb)
-    assert e_sr.metrics["ragged_dispatches"] > 0
-    assert e_sr.metrics["draft_accepted"] > 0
-
-
-@pytest.mark.slow
-@pytest.mark.tripwire
-def test_soup_tripwires_zero_fallback_zero_recompiles(loaded):
-    """The acceptance stream: grammar + multimodal + speculative + plain
-    tenants on ONE draft+ragged engine. After warmup and one warm stream,
-    a repeat soup adds ZERO compilations, stays inside the dispatch
-    budget, and never touches the dense fallback; every tenant's tokens
-    ride the spec-ragged path."""
-    from localai_tpu.engine import Engine, EngineConfig, GenRequest
-    from localai_tpu.ops.sampling import SamplingParams
-    from localai_tpu.testing.tripwires import (
-        CompileCounter, decode_cache_sizes, decode_compile_count,
-        dispatch_budget,
-    )
-
-    cfg, params, tok = loaded
-    embed = np.asarray(params["embed"], np.float32)
-    prompt = tok.encode("the quick brown fox jumps over")
-
-    def mmreq():
-        r = GenRequest(list(prompt), SamplingParams(temperature=0.0),
-                       max_tokens=10, ignore_eos=True)
-        r.mm_embeds = embed[prompt[1:3]] + 0.25
-        r.mm_positions = np.arange(1, 3)
-        return r
-
-    def soup():
-        return [_greq(tok, 0.0), mmreq(), _preq(tok, 12),
-                _greq(tok, 0.9, seed=11)]
-
     eng = Engine(cfg, params, tok, EngineConfig(
-        max_slots=4, max_context=128, prefill_buckets=(16, 64),
-        prefill_chunk=16, kv_pages=14, prompt_cache=False, gamma=3,
-        ragged_token_budget=96), draft=(cfg, params))
-    eng.warmup()
-    eng.record_paths = True
-
-    out1 = _drain(eng, soup())  # warm stream (admit-tail mask variant etc.)
-    assert all(r[1] is not None for r in out1), out1
-    warm = decode_compile_count(eng)
-
-    d0, r0 = eng.metrics["decode_dispatches"], \
-        eng.metrics["ragged_dispatches"]
-    with CompileCounter() as cc, dispatch_budget(eng):
-        out2 = _drain(eng, soup())
-    assert all(r[1] is not None for r in out2), out2
-    assert cc.total == 0, cc.counts
-    assert decode_compile_count(eng) == warm, decode_cache_sizes(eng)
-    # zero dense fallback: every decode tick was a spec-ragged dispatch
-    dense = (eng.metrics["decode_dispatches"] - d0) \
-        - (eng.metrics["ragged_dispatches"] - r0)
-    assert dense == 0, eng.metrics
-    _assert_conformant(tok, json_schema_grammar(SCHEMA), out2[0][0])
-    _assert_conformant(tok, json_schema_grammar(SCHEMA), out2[3][0])
-    # per-tenant path accounting: every emitted token rode the spec path
-    assert len(eng.req_path_counts) >= 8
-    for counts in eng.req_path_counts.values():
-        assert set(counts) == {"spec"}, eng.req_path_counts
+        max_slots=2, max_context=128, prefill_buckets=(16,),
+        prompt_cache=False, gamma=2), draft=(cfg, params))
+    if what == "grammar":
+        bad = _greq(tok)
+    else:
+        bad = _preq(tok)
+        bad.mm_embeds = np.zeros((2, cfg.hidden_size), np.float32)
+        bad.mm_positions = np.arange(1, 3)
+    with pytest.raises(ValueError, match=named):
+        eng.submit(bad)
+    assert eng._queue.empty()
+    (ids, reason), = _drain(eng, [_preq(tok, 6)])
+    assert len(ids) == 6 and reason == "length"
+    assert eng.metrics["tokens_by_path__spec"] == 6

@@ -377,7 +377,6 @@ def test_synthetic_decay_is_never_zero_or_one(tmp_path, monkeypatch):
 
 @pytest.mark.parametrize("over, match", [
     (dict(kv_pages=8), "linear-attention layers.*paged KV"),
-    (dict(kv_pages=8, ragged_token_budget=64), "paged KV"),
     (dict(kv_pages=8, kv_host_bytes=1 << 20), "paged KV"),
     (dict(kv_host_bytes=1 << 20), "requires paged KV"),
     (dict(kv_policy="sink_window(sinks=0, window=64)"), "requires paged KV"),

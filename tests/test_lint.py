@@ -547,6 +547,58 @@ def test_decode_compiles_exactly_once_across_mixed_stream(tiny_engine_parts):
 
 
 @pytest.mark.tripwire
+@pytest.mark.parametrize("kind, ec_kw", [
+    ("plain", dict()),
+    ("paged", dict(kv_pages=8)),
+    ("draft", dict(gamma=2)),
+])
+def test_decode_fn_attrs_are_the_programs_the_engine_builds(
+        tiny_engine_parts, kind, ec_kw):
+    """The compile-count guard sums the jit caches DECODE_FN_ATTRS names, so
+    it can only miss a program the list does not hold. A decode dispatch is
+    what the engine counts as one (a method that adds to
+    `decode_dispatches`); the jitted attributes those methods call are the
+    list, no more and no fewer, and each is an attribute this engine's
+    `_build_jit` set (`_spec_fn` with a draft model only)."""
+    import ast
+    import inspect
+
+    from localai_tpu.engine import engine as E
+    from localai_tpu.testing.tripwires import DECODE_FN_ATTRS, jit_cache_size
+
+    called = set()
+    for fn in ast.walk(ast.parse(inspect.getsource(E))):
+        if not isinstance(fn, ast.FunctionDef):
+            continue
+        nodes = list(ast.walk(fn))
+        if not any(isinstance(n, ast.AugAssign)
+                   and isinstance(n.target, ast.Subscript)
+                   and isinstance(n.target.slice, ast.Constant)
+                   and n.target.slice.value == "decode_dispatches"
+                   for n in nodes):
+            continue
+        called |= {n.attr for n in nodes if isinstance(n, ast.Attribute)
+                   and n.attr.endswith("_fn")}
+    assert called == set(DECODE_FN_ATTRS)
+
+    cfg, params = tiny_engine_parts
+    eng = E.Engine(cfg, params, None, E.EngineConfig(
+        max_slots=2, max_context=128, prefill_buckets=(16,),
+        prompt_cache=False, **ec_kw),
+        draft=(cfg, params) if kind == "draft" else None)
+    built = {a for a in DECODE_FN_ATTRS if hasattr(eng, a)}
+    assert built == set(DECODE_FN_ATTRS) - (
+        set() if kind == "draft" else {"_spec_fn"})
+    # each is a jitted program whose cache the guard can read
+    assert all(jit_cache_size(getattr(eng, a)) == 0 for a in built)
+    # the engine's other jitted attributes are admission, prefill and the
+    # cache's upkeep: none a decode dispatch calls (`called` above)
+    others = {a for a, v in vars(eng).items()
+              if a not in called and hasattr(v, "_cache_size")}
+    assert {"_admit_many_fn", "_extend_mid_fn", "_extend_final_fn"} <= others
+
+
+@pytest.mark.tripwire
 def test_decode_dispatch_budget_on_128_token_stream(tiny_engine_parts):
     """The dispatch-count guard (ISSUE 6 satellite): a 128-token single-slot
     stream rides the fused while loop in <= 3 decode dispatches (the ladder

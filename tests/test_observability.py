@@ -91,7 +91,7 @@ def test_hist_weighted_observe_equals_repeats():
 def test_registry_flat_parse_roundtrip():
     reg = SLORegistry()
     rng = np.random.default_rng(7)
-    for path in ("loop", "ragged"):
+    for path in ("loop", "spec"):
         for v in rng.uniform(1e-3, 0.5, 40):
             reg.observe("ttft", path, float(v))
             reg.observe("e2e", path, float(v) * 4)
@@ -101,8 +101,8 @@ def test_registry_flat_parse_roundtrip():
     assert flat["ttft_ms_p50"] == reg.merged("ttft").percentile(0.5) * 1e3
     assert flat["ttft_ms_p95"] == reg.merged("ttft").percentile(0.95) * 1e3
     back = parse_flat(flat)
-    assert set(back) == {("ttft", "loop"), ("ttft", "ragged"),
-                         ("e2e", "loop"), ("e2e", "ragged"),
+    assert set(back) == {("ttft", "loop"), ("ttft", "spec"),
+                         ("e2e", "loop"), ("e2e", "spec"),
                          ("tpot", "loop")}
     for key, h in reg._hists.items():
         assert back[key].counts == h.counts, key
@@ -115,12 +115,12 @@ def test_registry_flat_parse_roundtrip():
 def test_snapshot_shape_and_by_path():
     reg = SLORegistry()
     reg.observe("ttft", "loop", 5e-3)
-    reg.observe("ttft", "ragged", 50e-3)
+    reg.observe("ttft", "spec", 50e-3)
     snap = reg.snapshot()
     e = snap["ttft"]
     assert e["count"] == 2 and e["mean_ms"] > 0
-    assert set(e["by_path"]) == {"loop", "ragged"}
-    assert e["by_path"]["ragged"]["p50_ms"] >= e["by_path"]["loop"]["p50_ms"]
+    assert set(e["by_path"]) == {"loop", "spec"}
+    assert e["by_path"]["spec"]["p50_ms"] >= e["by_path"]["loop"]["p50_ms"]
     for k in ("p50_ms", "p95_ms", "p99_ms"):
         assert k in e
     assert "tpot" not in snap     # no samples → no entry
@@ -250,7 +250,7 @@ def ckpt(tmp_path_factory):
     return tiny_checkpoint(tmp_path_factory)
 
 
-def _engine(ckpt, **ec_kw):
+def _engine(ckpt, draft=False, **ec_kw):
     from localai_tpu.engine import (
         Engine, EngineConfig, Tokenizer, load_config, load_params,
     )
@@ -260,7 +260,8 @@ def _engine(ckpt, **ec_kw):
     tok = Tokenizer.from_dir(ckpt)
     return Engine(cfg, params, tok, EngineConfig(
         max_slots=4, max_context=128, prefill_buckets=(32, 64),
-        prefill_chunk=64, **ec_kw)), tok
+        prefill_chunk=64, **ec_kw),
+        draft=(cfg, params) if draft else None), tok
 
 
 def _run_collect(eng, tok, n_req=4, max_tokens=8):
@@ -282,6 +283,30 @@ def _run_collect(eng, tok, n_req=4, max_tokens=8):
     return finals
 
 
+@pytest.mark.parametrize("path, kw", [
+    ("loop", dict(decode_loop=8)),
+    ("dense", dict(decode_loop=0, decode_block=1)),
+    ("spec", dict(draft=True, gamma=2)),
+])
+def test_tokens_by_path_sums_to_tokens_generated(ckpt, path, kw):
+    """Every token `_emit` commits is put down to the path that served it:
+    the `tokens_by_path__*` counters are exactly the three labels the
+    engine has, they sum to `tokens_generated`, and an engine of one path
+    puts every token under its own label."""
+    eng, tok = _engine(ckpt, **kw)
+    finals = _run_collect(eng, tok, n_req=3, max_tokens=7)
+    assert len(finals) == 3
+    m = eng.metrics
+    by_path = {k[len("tokens_by_path__"):]: v for k, v in m.items()
+               if k.startswith("tokens_by_path__")}
+    assert set(by_path) == {"loop", "dense", "spec"}
+    assert sum(by_path.values()) == m["tokens_generated"] == 21
+    assert by_path[path] == 21, by_path
+    for o in finals:
+        if o.timings is not None:
+            assert o.timings["path"] == path
+
+
 @pytest.mark.slow
 def test_engine_timeline_integrity_concurrent(ckpt):
     """4 concurrent streams: every terminal StepOutput carries a complete
@@ -301,7 +326,7 @@ def test_engine_timeline_integrity_concurrent(ckpt):
             t = o.timings
             assert t is not None, o
             assert t["request_id"].startswith("rid-")
-            assert t["path"] in ("loop", "dense", "ragged", "spec")
+            assert t["path"] in ("loop", "dense", "spec")
             assert t["generated_tokens"] == max_tokens
             assert t["dispatches"] >= 1
             assert t["kv_policy"]
@@ -521,7 +546,7 @@ def test_sse_timings_and_slo_surfaces(obs_stack):
         if "timings" in chunk:
             timings = chunk["timings"]
     assert timings is not None, "no timings block in the SSE stream"
-    assert timings["path"] in ("loop", "dense", "ragged", "spec")
+    assert timings["path"] in ("loop", "dense", "spec")
     assert timings["ttft_ms"] > 0
     assert timings["e2e_ms"] >= timings["ttft_ms"]
     assert timings["generated_tokens"] >= 1

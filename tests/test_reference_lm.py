@@ -529,7 +529,6 @@ def test_engine_reuses_a_prefix_the_rings_still_hold():
 
 REFUSALS = {
     "paged": (dict(kv_pages=8), "paged KV"),
-    "ragged": (dict(kv_pages=8, ragged_token_budget=64), "ragged batching"),
     "kv_policy": (dict(kv_pages=8,
                        kv_policy="sink_window(sinks=0, window=64)"),
                   "kv_policy windows"),
@@ -562,15 +561,15 @@ def test_engine_refuses_a_draft_model_and_context_shift():
                               params=SamplingParams(temperature=0.0)))
 
 
-@pytest.mark.parametrize("what", ["ragged_forward", "cache_shift"])
+@pytest.mark.parametrize("what", ["cache_shift_paged", "cache_shift"])
 def test_model_functions_refuse_two_caches(what):
     from localai_tpu.models import llama
 
     cfg = _config("mellum")
     with pytest.raises(NotImplementedError, match="window and full layers"):
-        if what == "ragged_forward":
-            llama.ragged_forward(None, cfg, jnp.zeros((8,), jnp.int32),
-                                 *[None] * 10)
+        if what == "cache_shift_paged":
+            llama.cache_shift_paged(cfg, None, None, keep_blocks=1,
+                                    discard_blocks=1)
         else:
             llama.cache_shift(cfg, None, None, None, 0, keep=1, discard=1)
 

@@ -293,13 +293,19 @@ def test_dispatch_record_carries_the_rows(ckpt):
                    for k in eng._sched.flat())
 
 
-def test_identity_on_a_ragged_engine(ckpt):
-    """Mixed ragged ticks (decode rows beside prefill chunks) and the fused
-    ragged loop: a row whose chunk rides the tick is in prefill."""
+@pytest.mark.parametrize("path, ec_kw, want_variant", [
+    ("dense single step", dict(decode_loop=0, decode_block=1), "decode"),
+    ("fused block", dict(decode_loop=0, decode_block=4), "decode_block4"),
+    ("fused loop", dict(decode_loop=8), "loop"),
+])
+def test_identity_on_a_paged_engine(ckpt, path, ec_kw, want_variant):
+    """The same sum on the block pool (`kv_pages`): a long prompt's chunks
+    ride ticks of their own beside the decode dispatches, so its row is in
+    prefill while the others decode, and the states still add up to
+    max_slots x the steps consumed at every tick boundary."""
     eng, tok = _engine(ckpt, kv_pages=10, prompt_cache=False,
-                       ragged_token_budget=64, ragged_loop_steps=4,
-                       prefill_chunk=16, prefill_buckets=(16,))
-    assert eng._ragged
+                       prefill_chunk=16, prefill_buckets=(16,), **ec_kw)
+    assert eng._paged
     _submit(eng, tok, 0, max_tokens=14)
     eng.step()
     eng.step()
@@ -307,8 +313,10 @@ def test_identity_on_a_ragged_engine(ckpt):
     _submit(eng, tok, 2, max_tokens=9)
     _drain(eng)
     m, rows = eng.metrics, _rows(eng)
-    assert m["ragged_dispatches"] > 0
-    assert rows["live"] == 28 and rows["prefill"] > 0
+    assert m["tokens_generated"] == 28 == rows["live"]
+    assert rows["prefill"] > 0, rows
+    assert any(k.startswith(f"sched_variant__{want_variant}")
+               for k in eng._sched.flat()), eng._sched.flat()
 
 
 def test_identity_on_a_speculative_engine(ckpt):

@@ -3,11 +3,10 @@ and cost-analysis rooflines.
 
 Cheap taxonomy / ledger / roofline / benchdiff units run in tier-1; the
 engine-driving scenario streams (grammar overflow, pending admission, KV
-demotion, budget cap) are slow-marked. The load-bearing contract tested
+demotion) are slow-marked. The load-bearing contract tested
 here: every reason code an engine site emits is REGISTERED (unregistered is
-a hard ValueError), and the dispatch-category counters sum exactly to the
-dense (non-ragged) dispatch count — the same quantity bench.py reports as
-dense_fallback_dispatches.
+a hard ValueError), every registered code has a site that emits it, and the
+dispatch-category counters sum exactly to `decode_dispatches`.
 """
 import json
 import time
@@ -69,14 +68,35 @@ def test_unregistered_reason_code_hard_fails():
 
 
 def test_registry_shape_is_contractual():
-    cats = {"dispatch", "demotion", "admission", "kv", "pack"}
+    cats = {"dispatch", "demotion", "admission", "kv"}
     for code, (cat, desc) in S.REASON_CODES.items():
         assert cat in cats, code
         assert desc and code == code.lower()
     assert set(S.DISPATCH_CODES) == {
         c for c, (cat, _) in S.REASON_CODES.items() if cat == "dispatch"}
     assert "loop_native" in S.DISPATCH_CODES
-    assert S.reason_category("budget_cap") == "pack"
+    assert S.reason_category("kv_eviction") == "kv"
+
+
+def test_every_reason_code_has_an_emitter():
+    """A registered code that no site of the program names is a series a
+    dashboard waits on for ever: each key of REASON_CODES occurs as a
+    string literal in `localai_tpu/` outside the registry's own file (the
+    dispatch codes through `_loop_block_reason`'s returns)."""
+    import ast
+    import pathlib
+
+    import localai_tpu
+
+    root = pathlib.Path(localai_tpu.__file__).parent
+    literals = set()
+    for path in root.rglob("*.py"):
+        if path.name == "sched.py" and path.parent.name == "telemetry":
+            continue
+        for node in ast.walk(ast.parse(path.read_text())):
+            if isinstance(node, ast.Constant) and isinstance(node.value, str):
+                literals.add(node.value)
+    assert not set(S.REASON_CODES) - literals
 
 
 def test_sched_gate_and_per_engine_ledgers():
@@ -97,32 +117,32 @@ def test_ledger_flat_snapshot_roundtrip():
     led = S.TickLedger()
     led.begin(1)
     led.reason("pending_admission")
-    led.reason("budget_cap", kind="decode_rows")
-    led.pack("ragged", decode_rows=3, prefill_tokens=16, pad_rows=5,
-             rows_used=24, budget_rows=64, packed=19)
+    led.reason("kv_eviction", kind="ring_overwrite")
+    led.pack("loop", decode_rows=3, prefill_tokens=16, pad_rows=5,
+             rows_used=24, packed=19)
     rec = led.commit(active_slots=3)
     assert rec["tick"] == 1 and rec["active_slots"] == 3
-    assert rec["packs"][0]["variant"] == "ragged"
+    assert rec["packs"][0]["variant"] == "loop"
     assert json.loads(json.dumps(rec))  # tick records are JSON-clean
 
     flat = led.flat()
     assert flat["sched_ticks_total"] == 1.0
     assert flat["sched_reason__pending_admission"] == 1.0
-    assert flat["sched_variant__ragged"] == 1.0
+    assert flat["sched_variant__loop"] == 1.0
     assert flat["sched_pack__prefill_tokens"] == 16.0
-    assert flat["sched_budget_utilization"] == pytest.approx(19 / 64)
+    assert flat["sched_pack__packed"] == 19.0
     assert flat["sched_pad_rows_frac"] == pytest.approx(5 / 24)
 
     snap = led.snapshot()
-    assert snap["reason_counters"]["budget_cap"] == 1
+    assert snap["reason_counters"]["kv_eviction"] == 1
     assert snap["recent_ticks"][-1]["tick"] == 1
 
-    led.rooflines["ragged"] = S.roofline_entry(1e6, 1e6, 1e9, 1e9)
+    led.rooflines["loop"] = S.roofline_entry(1e6, 1e6, 1e9, 1e9)
     led.reset()
     # reset drops the stream but keeps the (expensive) cached rooflines
     assert led.n_ticks == 0 and not led.counters
-    assert "ragged" in led.rooflines
-    assert "sched_roofline__ragged__flops" in led.flat()
+    assert "loop" in led.rooflines
+    assert "sched_roofline__loop__flops" in led.flat()
 
 
 def test_tick_rings_wrap():
@@ -197,7 +217,7 @@ def test_unknown_device_kind_gives_no_peak():
 
 
 def _bench_json(tmp_path, name, **fields):
-    base = {"metric": "serve tok/s (llama-tiny f32, ragged ...)",
+    base = {"metric": "decode tok/s/chip (llama-tiny f32, paged ...)",
             "value": 100.0, "unit": "tok/s"}
     base.update(fields)
     p = tmp_path / name
@@ -208,23 +228,23 @@ def _bench_json(tmp_path, name, **fields):
 def test_benchdiff_gates_ratios_not_throughput(tmp_path):
     from tools import benchdiff
 
-    old = _bench_json(tmp_path, "old.json", ragged_over_dense=1.2,
+    old = _bench_json(tmp_path, "old.json", paged_over_dense=1.2,
                       compile_count_delta=0)
     # halved raw tok/s is box noise — NOT a regression on its own
     ok = _bench_json(tmp_path, "ok.json", value=55.0,
-                     ragged_over_dense=1.18, compile_count_delta=0)
+                     paged_over_dense=1.18, compile_count_delta=0)
     assert benchdiff.main([old, ok]) == 0
     # a collapsed ratio metric IS a regression
     bad = _bench_json(tmp_path, "bad.json", value=100.0,
-                      ragged_over_dense=0.6, compile_count_delta=0)
+                      paged_over_dense=0.6, compile_count_delta=0)
     assert benchdiff.main([old, bad]) == 1
     # counter invariants regress on ANY growth (new mid-stream compiles)
-    grew = _bench_json(tmp_path, "grew.json", ragged_over_dense=1.2,
+    grew = _bench_json(tmp_path, "grew.json", paged_over_dense=1.2,
                        compile_count_delta=2)
     assert benchdiff.main([old, grew]) == 1
     # raw-throughput collapse past the floor fails even with ratios intact
     dead = _bench_json(tmp_path, "dead.json", value=10.0,
-                       ragged_over_dense=1.2, compile_count_delta=0)
+                       paged_over_dense=1.2, compile_count_delta=0)
     assert benchdiff.main([old, dead]) == 1
     assert benchdiff.main([str(tmp_path / "missing.json"), ok]) == 2
 
@@ -247,11 +267,10 @@ def test_benchdiff_picks_latest_two_from_runs_dir(tmp_path):
 
 @pytest.mark.slow
 def test_dispatch_codes_sum_to_dense_dispatches(tiny_parts):
-    """The exactness invariant behind dense_fallback_dispatches: over a
-    stream with queued admissions, EVERY dense decode dispatch emits
-    exactly one dispatch-category code — the counters sum to
-    decode_dispatches - ragged_dispatches, and the pending_admission
-    scenario (more requests than slots) appears by name."""
+    """The exactness invariant: over a stream with queued admissions,
+    EVERY decode dispatch emits exactly one dispatch-category code — the
+    counters sum to decode_dispatches, and the pending_admission scenario
+    (more requests than slots) appears by name."""
     eng = _engine(tiny_parts, max_slots=2, max_context=128,
                   prefill_buckets=(16,), prompt_cache=False,
                   decode_loop=4)
@@ -265,8 +284,7 @@ def test_dispatch_codes_sum_to_dense_dispatches(tiny_parts):
         eng.submit(_req(seed=i, max_tokens=4 if i % 2 == 0 else 20))
     _drain(eng)
     sched = eng._sched
-    dense = eng.metrics["decode_dispatches"] - \
-        eng.metrics.get("ragged_dispatches", 0)
+    dense = eng.metrics["decode_dispatches"]
     code_sum = sum(sched.counters.get(c, 0) for c in S.DISPATCH_CODES)
     assert dense > 0 and code_sum == dense, dict(sched.counters)
     assert sched.counters.get("pending_admission", 0) > 0
@@ -282,28 +300,6 @@ def test_dispatch_codes_sum_to_dense_dispatches(tiny_parts):
     if eng._flightrec is not None:
         recs = [r for r in eng._flightrec.ticks if "packs" in r]
         assert recs and any(r["packs"] for r in recs)
-
-
-@pytest.mark.slow
-def test_budget_cap_reason_under_tiny_ragged_budget(tiny_parts):
-    """A 16-row token budget holds ONE decode q-block (cap = T - QBLK):
-    three concurrent decodes must trip the decode_rows budget cap, and the
-    ragged pack must report meaningful budget utilization."""
-    eng = _engine(tiny_parts, max_slots=3, max_context=128,
-                  prefill_buckets=(16,), prefill_chunk=16, kv_pages=16,
-                  prompt_cache=False, ragged_token_budget=16)
-    for i in range(3):
-        eng.submit(_req(seed=10 + i, max_tokens=6))
-    _drain(eng)
-    sched = eng._sched
-    assert sched.counters.get("budget_cap", 0) > 0, dict(sched.counters)
-    assert eng.metrics["ragged_dispatches"] > 0
-    assert 0.0 < sched.budget_utilization() <= 1.0
-    assert eng.metrics["budget_utilization"] > 0.0
-    # the committed tick records carry the machine-readable kind field
-    kinds = {r.get("kind") for rec in sched.ticks
-             for r in rec["reasons"] if isinstance(r, dict)}
-    assert "decode_rows" in kinds
 
 
 @pytest.mark.slow

@@ -4,7 +4,7 @@ die in Mosaic lowering on hardware, and only a chip run can tell.
 One lowering-and-parity test per `pallas_call` in `localai_tpu/ops/pallas/`,
 each against its XLA twin, at the Llama-8B head geometry (32/8/128) and one
 D=64 geometry; then the engine programs that compose them (dense, paged,
-int8-paged, ragged) for a few ticks.
+int8-paged) for a few ticks.
 
 Skipped on the CPU harness; run on a machine with a TPU attached:
 `LOCALAI_TPU_TESTS=1 python -m pytest tests/test_tpu_real.py`.
@@ -335,111 +335,6 @@ def test_paged_scatter_append_q8(H, KVH, D):
         np.testing.assert_array_equal(np.asarray(g), w)
 
 
-# ------------------------------------------------ ragged_attention.py
-
-def _ragged_case(H, KVH, D, maxb=4):
-    """Three sequences in one 48-row flat stream: a decode row (kv 300), a
-    decode row (kv 1) and a 20-token prefill chunk ending at kv 150."""
-    from localai_tpu.ops.pallas import QBLK
-
-    nseq, t = 4, 48
-    tables = _table(nseq, maxb)
-    block_seq = jnp.asarray([0, 1, 2, 2, 2, -1], jnp.int32)
-    assert block_seq.shape[0] == t // QBLK
-    qstart = jnp.asarray([0, 8, 16, 0], jnp.int32)
-    qlen = jnp.asarray([1, 1, 20, 0], jnp.int32)
-    kvlen = jnp.asarray([300, 1, 150, 0], jnp.int32)
-    q = _bf16(23, (t, H, D))
-    live = np.zeros((t,), bool)
-    live[0] = live[8] = True
-    live[16:36] = True
-    return q, tables, block_seq, qstart, qlen, kvlen, live
-
-
-@pytest.mark.parametrize("H,KVH,D", GEOMS + [(8, 8, 128)])
-def test_ragged_paged_attention(H, KVH, D):
-    from localai_tpu.ops.pallas import (
-        ragged_attention_xla, ragged_paged_attention,
-    )
-
-    q, tables, bseq, qs, ql, kl, live = _ragged_case(H, KVH, D)
-    nb = tables.size + 1
-    kp, vp = _bf16(24, (nb, KVH, BS, D)), _bf16(25, (nb, KVH, BS, D))
-    out = ragged_paged_attention(q, kp, vp, bseq, qs, ql, kl, tables)
-    ref = ragged_attention_xla(q, kp, vp, bseq, qs, ql, kl, tables)
-    _close(out[live], ref[live])
-
-
-@pytest.mark.parametrize("H,KVH,D", GEOMS + [(8, 8, 128)])
-def test_ragged_paged_attention_q8(H, KVH, D):
-    from localai_tpu.ops.pallas import (
-        ragged_attention_xla_q8, ragged_paged_attention_q8,
-    )
-
-    q, tables, bseq, qs, ql, kl, live = _ragged_case(H, KVH, D)
-    nb = tables.size + 1
-    kq, ks = _quant_pool(26, nb, KVH, D)
-    vq, vs = _quant_pool(27, nb, KVH, D)
-    out = ragged_paged_attention_q8(q, kq, ks, vq, vs, bseq, qs, ql, kl,
-                                    tables)
-    ref = ragged_attention_xla_q8(q, kq, ks, vq, vs, bseq, qs, ql, kl,
-                                  tables)
-    _close(out[live], ref[live])
-
-
-def _flat_targets(t, nb):
-    """(block, row) targets for t flat rows, shaped like a real pack: a
-    20-row run in one block that crosses native-tile boundaries (consecutive
-    grid steps revisiting one tile — what a prefill chunk does), then rows
-    scattered one per 32-row tile in pool order (a live tile or block is
-    never revisited once left — the kernel's contract, which position-ordered
-    sequences keep), then 4 padding rows aimed at the trash block."""
-    rng = np.random.default_rng(1)
-    tiles = np.sort(rng.permutation((nb - 2) * (BS // 32))[:t]) \
-        + 2 * (BS // 32)
-    pb, off = tiles // (BS // 32), tiles % (BS // 32) * 32 \
-        + rng.integers(0, 32, t)
-    pb[:20], off[:20] = 1, np.arange(27, 47)
-    pb[-4:] = 0
-    return jnp.asarray(pb, jnp.int32), jnp.asarray(off, jnp.int32)
-
-
-@pytest.mark.parametrize("H,KVH,D", GEOMS)
-def test_ragged_scatter_append(H, KVH, D):
-    from localai_tpu.ops.pallas import (
-        ragged_scatter_append, ragged_scatter_xla,
-    )
-
-    t, nb = 48, 17
-    pb, off = _flat_targets(t, nb)
-    kp, vp = _bf16(28, (nb, KVH, BS, D)), _bf16(29, (nb, KVH, BS, D))
-    kn, vn = _bf16(30, (t, KVH, D)), _bf16(31, (t, KVH, D))
-    wk, wv = ragged_scatter_xla(kp, vp, kn, vn, pb, off)
-    gk, gv = jax.jit(ragged_scatter_append)(kp, vp, kn, vn, pb, off)
-    # block 0 is trash: padding rows may land there in any order
-    np.testing.assert_array_equal(np.asarray(gk[1:], np.float32),
-                                  np.asarray(wk[1:], np.float32))
-    np.testing.assert_array_equal(np.asarray(gv[1:], np.float32),
-                                  np.asarray(wv[1:], np.float32))
-
-
-@pytest.mark.parametrize("H,KVH,D", GEOMS)
-def test_ragged_scatter_append_q8(H, KVH, D):
-    from localai_tpu.ops.pallas import (
-        ragged_scatter_append_q8, ragged_scatter_xla_q8,
-    )
-
-    t, nb = 48, 17
-    pb, off = _flat_targets(t, nb)
-    kq, ks = _quant_pool(32, nb, KVH, D)
-    vq, vs = _quant_pool(33, nb, KVH, D)
-    kn, vn = _bf16(34, (t, KVH, D)), _bf16(35, (t, KVH, D))
-    want = ragged_scatter_xla_q8(kq, ks, vq, vs, kn, vn, pb, off)
-    got = jax.jit(ragged_scatter_append_q8)(kq, ks, vq, vs, kn, vn, pb, off)
-    for g, w in zip(got, want):
-        np.testing.assert_array_equal(np.asarray(g[1:]), np.asarray(w[1:]))
-
-
 # ------------------------------------------------------------- kda.py
 
 def _kda_inputs(b, s, h, d, seed):
@@ -627,9 +522,6 @@ ENGINES = {
     "dense-int8kv": dict(cache_type="int8"),
     "paged": dict(kv_pages=24),
     "paged-int8kv": dict(kv_pages=24, cache_type="int8"),
-    "ragged": dict(kv_pages=24, ragged_token_budget=64),
-    "ragged-int8kv": dict(kv_pages=24, ragged_token_budget=64,
-                          cache_type="int8"),
 }
 
 
@@ -637,7 +529,7 @@ ENGINES = {
 @pytest.mark.parametrize("kind", sorted(ENGINES))
 def test_engine_runs_on_tpu(H, KVH, D, kind):
     """The serving programs (admission, chunked prefill, the fused decode
-    while-loop with donated caches, ragged ticks) compile and run on the
+    while-loop with donated caches) compile and run on the
     chip for a few ticks, on the Pallas tier, with exact token counts."""
     from localai_tpu.engine import Engine, EngineConfig
     from localai_tpu.engine.engine import GenRequest, SamplingParams
@@ -654,8 +546,6 @@ def test_engine_runs_on_tpu(H, KVH, D, kind):
         "xla" if "kv_pages" in ENGINES[kind] else "xla-blocks")
     if "kv_pages" in ENGINES[kind]:
         assert tiers["decode_kv_write"] == "pallas"
-    if kind.startswith("ragged"):
-        assert tiers["ragged_attention"] == "pallas"
     eng.warmup()
     eng.start()
     try:

@@ -7,15 +7,14 @@
 Compares two `bench.py` result lines (or archived bench_runs/ artifacts)
 per (mode, metric). Absolute tok/s on shared CI boxes swings ~2x run to
 run, so the gate leans on the RATIO metrics bench.py computes inside one
-process against its own denominator (ragged_over_dense,
-constrained_over_plain, paged_over_dense, tp_over_single, mixed_over_equal,
-longctx_over_short) plus the scale-free health fields (budget utilization,
-draft acceptance, MFU, pad-row fraction): those are self-relative and
+process against its own denominator (paged_over_dense, tp_over_single,
+longctx_over_short) plus the scale-free health fields (draft acceptance,
+MFU, pad-row fraction): those are self-relative and
 stable, so a modest threshold on them is signal, not noise. Raw
 throughput is reported but only FLAGGED, never gated, unless it collapses
 below the --collapse floor (default 0.33x — beyond any plausible box
-swing). Counter-like invariants (compile_count_delta,
-dense_fallback_dispatches) regress only when they GROW.
+swing). Counter-like invariants (compile_count_delta) regress only when
+they GROW.
 
 Exit codes: 0 ok / 1 regression / 2 usage or unreadable input. The CI
 step runs it advisory (continue-on-error) until the runner archives
@@ -31,9 +30,7 @@ import sys
 # ratio metrics: higher is better, gate at threshold * old (floored at a
 # small absolute slack so a 0.01 ratio wiggle on tiny numbers can't trip)
 RATIO_KEYS = (
-    "ragged_over_dense", "mixed_over_equal", "constrained_over_plain",
     "paged_over_dense", "tp_over_single", "longctx_over_short",
-    "fused_over_ragged",
     # --mode session (ISSUE 17): turn-2 re-prefill TTFT over host-tier
     # re-admission TTFT — the self-relative speedup the host KV tier buys;
     # a re-admission regression shrinks it
@@ -42,7 +39,7 @@ RATIO_KEYS = (
     # TTFT after a mid-decode preempt — the speedup the spill-drain
     # checkpoint buys; a resume-path regression shrinks it
     "resume_speedup",
-    "budget_utilization", "draft_acceptance", "mfu", "stage_coverage",
+    "draft_acceptance", "mfu", "stage_coverage",
 )
 # lower is better; gate when NEW exceeds threshold-scaled OLD.
 # turn2_over_turn1_ttft is the session-mode re-admission gate (ISSUE 17):
@@ -51,8 +48,8 @@ RATIO_KEYS = (
 # (its RATIO_KEYS twin is readmit_speedup above)
 INVERSE_KEYS = ("pad_rows_frac", "host_sync_wait_ms_per_token",
                 "turn2_over_turn1_ttft")
-# integer invariants: any growth is a regression (new compiles mid-stream,
-# new dense fallbacks) — these are exact, not noisy
+# integer invariants: any growth is a regression (new compiles
+# mid-stream) — these are exact, not noisy
 GROWTH_KEYS = ("compile_count_delta",)
 # informational throughput keys: flagged when they collapse, never gated
 # at the ratio threshold

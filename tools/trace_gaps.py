@@ -61,9 +61,8 @@ KERNELS = {"kda_decode": "attention/linear/kda_decode",
            "mla_decode": "attention/latent/mla_decode",
            "mla_chunk": "attention/latent/chunk_kernel",
            "grouped_matmul": "experts/expert_einsums",
-           "ragged_decode": "attention", "ragged_paged_attention": "attention",
-           "flash_prefill": "attention", "paged_scatter_append": "cache_update",
-           "ragged_scatter_append": "cache_update"}
+           "ragged_decode": "attention",
+           "flash_prefill": "attention", "paged_scatter_append": "cache_update"}
 LAYER_KINDS = ("window", "full", "linear", "latent", "ssm")
 # what a linear layer's mixer is made of (models/kv.py StateKV), and a latent
 # layer's attention (models/llama.py _latent_qk, models/kv.py LatentKV)
