@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from localai_tpu.models.kv import chunk_rows
+from localai_tpu.models.kv import _pallas_attention, chunk_rows
 from localai_tpu.models.llama import (
     FULL,
     LATENT,
@@ -707,6 +707,15 @@ class Engine:
                     for kind in (FULL, self._state_kind)}
                 self.metrics["decode_cache_bytes__full"] = 0
                 self.metrics[f"decode_cache_bytes__{self._state_kind}"] = 0
+            if self._state_kind == LINEAR:
+                # the real tokens of a prefill or a chunk call x the linear
+                # layers: what went through the chunkwise gated delta rule
+                # (_credit_chunk_state), and of it what the kernel served
+                # (ops/pallas/kda.py: kda_chunk, by the rule
+                # kv.StateKV._mix goes by) and not its twin, ops/kda.py's
+                self.metrics["chunk_state_tokens__seen"] = 0
+                self.metrics["chunk_state_tokens__kernel"] = 0
+                self._state_kernel = _pallas_attention(self.mesh)
         if self._tiered:
             # KV lifecycle telemetry: cold demotions, evictions (window-
             # exited blocks dropped — ring overwrite, or a full cold pool),
@@ -1430,6 +1439,7 @@ class Engine:
                         inject=None):
         self.metrics["admit_dispatches"] += 1
         self._credit_experts(np.size(ids), np.sum(lens))
+        self._credit_chunk_state(np.sum(lens))
         self._bcast("admit_many", ids=ids, lens=lens, slots=slots,
                     rows={k: np.asarray(v) for k, v in rows.items()},
                     counts_rows=counts_rows, inject=self._inj_msg(inject))
@@ -1470,6 +1480,7 @@ class Engine:
 
     def _dev_extend_mid(self, buf, pos, idx, inject=None):
         self._credit_experts(np.size(buf), np.size(buf))
+        self._credit_chunk_state(np.size(buf))
         self._credit_chunk_ctx(pos)
         self._bcast("extend_mid", buf=buf, pos=pos, idx=idx,
                     inject=self._inj_msg(inject))
@@ -1482,6 +1493,7 @@ class Engine:
     def _dev_extend_final(self, buf, pos, nvalid, idx, row, counts_row,
                           inject=None):
         self._credit_experts(np.size(buf), nvalid)
+        self._credit_chunk_state(nvalid)
         self._credit_chunk_ctx(pos)
         self._bcast("extend_final", buf=buf, pos=pos, nvalid=nvalid, idx=idx,
                     row={k: np.asarray(v) for k, v in row.items()},
@@ -2707,6 +2719,15 @@ class Engine:
             form = expert_form(self.cfg, call_tokens, self.mesh)
             self.metrics[f"expert_tokens__{form}"] += int(tokens) * (
                 self.cfg.expert_layers)
+
+    def _credit_chunk_state(self, tokens: int):
+        """`tokens` real tokens of a prefill or a chunk call went through
+        every linear layer's chunkwise form. Host arithmetic, once a call."""
+        if self._state_kind == LINEAR:
+            seen = int(tokens) * self.cfg.layer_types.count(LINEAR)
+            self.metrics["chunk_state_tokens__seen"] += seen
+            self.metrics["chunk_state_tokens__kernel"] += (
+                seen * self._state_kernel)
 
     def _credit_chunk_ctx(self, pos: int):
         """A chunk from position `pos` is dispatched: the rows of a full

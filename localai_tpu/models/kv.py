@@ -425,17 +425,22 @@ class StateKV(_SlotState):
 
     The mathematics is ops/kda.py's; on a TPU `step` is one Pallas kernel a
     layer (ops/pallas/kda.py: a live row's state read once, written once,
-    in place in the carried stack). No state is kept per position, so a
+    in place in the carried stack), and `prompt` and `chunk` run the
+    chunkwise form in one kernel a layer too (kda_chunk there: the state a
+    value, nothing aliased). No state is kept per position, so a
     prefix of a slot's tokens cannot be lent to the next tenant (the engine
     reuses none), and nothing can be rolled back."""
     heads: int = 0
 
-    def _qkv(self, y):
+    def _qkv(self, y, unit_qk=True):
         """The convolution's output [B, S, C] -> q, k, v [B, S, H, D]:
-        SiLU, then q and k L2-normalised a head (q also scaled D^-1/2)."""
+        SiLU, then q and k L2-normalised a head (q also scaled D^-1/2;
+        not `unit_qk`: the chunk's kernel does both itself)."""
         b, s, _ = y.shape
         q, k, v = jnp.split(jax.nn.silu(y.astype(jnp.float32)), 3, axis=-1)
         q, k, v = (a.reshape(b, s, self.heads, -1) for a in (q, k, v))
+        if not unit_qk:
+            return q, k, v
 
         def unit(a):
             return a * jax.lax.rsqrt(
@@ -446,11 +451,20 @@ class StateKV(_SlotState):
     def _mix(self, u, conv, g, beta, tail, state, n):
         from localai_tpu.ops.kda import kda_chunk, short_conv
 
+        # the chunkwise form: one kernel on a TPU without a mesh (the state
+        # a value in and out: _resume and _put stay XLA's), else its twin
+        kernel = _pallas_attention(current_mesh())
         with jax.named_scope("conv"):
             y, xx = short_conv(tail, u, conv)
-            q, k, v = self._qkv(y)
+            q, k, v = self._qkv(y, unit_qk=not kernel)
         with jax.named_scope("kda_chunk"):
-            o, state = kda_chunk(q, k, v, g, beta, state, n_valid=n)
+            if kernel:
+                from localai_tpu.ops.pallas import kda
+
+                o, state = kda.kda_chunk(q, k, v, g, beta, state, n_valid=n,
+                                         unit_qk=True)
+            else:
+                o, state = kda_chunk(q, k, v, g, beta, state, n_valid=n)
         if n is None:
             return o, state, xx[:, -tail.shape[1]:]
         # the K-1 inputs ending at the row's last real token: token t is

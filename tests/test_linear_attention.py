@@ -432,3 +432,127 @@ def test_a_slot_lends_no_prefix_and_starts_from_zero(model, engine):
     assert eng.metrics["prompt_tokens_reused"] == 0
     assert eng.metrics["prompt_tokens_processed"] == 43 + 42
     assert eng.metrics["decode_cache_bytes__linear"] > 0
+
+
+# ------------------------------------- the chunk's kernel against its twin
+
+def _chunk_inputs(b, s, h, seed, alpha=(0.15, 0.9995), state=True):
+    """q, k unit vectors a head (q scaled) as StateKV._qkv makes them, a
+    token's decay between `alpha`'s ends (what
+    test_synthetic_decay_is_never_zero_or_one allows), beta in (0, 2)."""
+    d = 128
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)  # noqa: E731
+    q = unit(jax.random.normal(ks[0], (b, s, h, d))) * d ** -0.5
+    k = unit(jax.random.normal(ks[1], (b, s, h, d)))
+    v = jax.random.normal(ks[2], (b, s, h, d))
+    g = jnp.log(jax.random.uniform(ks[3], (b, s, h, d), minval=alpha[0],
+                                   maxval=alpha[1]))
+    beta = 2 * jax.nn.sigmoid(jax.random.normal(ks[4], (b, s, h)))
+    s0 = jax.random.normal(ks[5], (b, h, d, d))
+    return q, k, v, g, beta, s0 if state else jnp.zeros_like(s0)
+
+
+CHUNK_CASES = {
+    # name -> (rows, tokens, heads, n_valid, kwargs of _chunk_inputs)
+    "[1, 512]": (1, 512, 2, None, {}),
+    "[4, 512]": (4, 512, 2, None, {}),
+    "[8, 256]": (8, 256, 2, None, {}),
+    "a length 64 does not divide": (2, 200, 2, None, {}),
+    "rows that end early": (3, 192, 2, [37, 192, 64], {}),
+    "two blocks of heads": (1, 128, 16, [100], {}),
+    "from a zero state": (2, 128, 2, None, dict(state=False)),
+    "decay at its slow end": (1, 256, 2, None, dict(alpha=(0.999, 0.9995))),
+    "decay at its fast end": (1, 256, 2, [250], dict(alpha=(0.15, 0.16))),
+    "q and k made unit vectors in the kernel": (2, 128, 2, [128, 90], {}),
+    "planted: the triangle not strict": (1, 128, 2, None, {}),
+}
+
+
+@pytest.mark.parametrize("case", list(CHUNK_CASES))
+def test_the_chunk_kernel_against_its_twin(monkeypatch, case):
+    """ops/pallas/kda.py:kda_chunk in the interpreter: float32 agreement
+    with ops/kda.py:kda_chunk and with the token-by-token scan, over the
+    real tokens' outputs and the state."""
+    monkeypatch.setenv("LOCALAI_FORCE_PALLAS", "1")
+    from localai_tpu.ops import kda
+    from localai_tpu.ops.pallas import kda as kernels
+
+    b, s, h, n, kw = CHUNK_CASES[case]
+    planted = case.startswith("planted")
+    if planted:
+        monkeypatch.setattr(kernels, "_below", lambda row, col: row >= col)
+    args = _chunk_inputs(b, s, h, seed=len(case), **kw)
+    n = None if n is None else jnp.asarray(n)
+    given = args
+    if case.startswith("q and k"):      # as the convolution's SiLU left them
+        given = (args[0] * 37.0, args[1] * 0.2) + args[2:]
+        unit = lambda a: a * jax.lax.rsqrt(  # noqa: E731 (StateKV._qkv's)
+            jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+        args = (unit(given[0]) * 128 ** -0.5, unit(given[1])) + args[2:]
+    # (the jitted wrapper would hand the planted case a cached program)
+    o, state = kernels.kda_chunk.__wrapped__(
+        *given, n_valid=n, unit_qk=given is not args)
+    twin_o, twin_state = kda.kda_chunk(*args, n_valid=n)
+    live = (jnp.ones((b, s), bool) if n is None
+            else jnp.arange(s)[None, :] < n[:, None])
+    q, k, v, g, beta, s0 = args
+    scan_o, scan_state = kda.kda_recurrent(
+        q, k, v, jnp.where(live[..., None, None], g, 0.0),
+        jnp.where(live[..., None], beta, 0.0), s0)
+    cut = lambda a: jnp.where(live[..., None, None], a, 0.0)  # noqa: E731
+    rels = [_rel(cut(o), cut(twin_o)), _rel(state, twin_state),
+            _rel(cut(o), cut(scan_o)), _rel(state, scan_state)]
+    if planted:
+        assert min(rels) > 100 * REL_F32, rels
+    else:
+        assert max(rels) < REL_F32, rels
+
+
+def test_the_chunk_kernel_refuses_a_shape_it_cannot_tile(monkeypatch):
+    monkeypatch.setenv("LOCALAI_FORCE_PALLAS", "1")
+    from localai_tpu.ops.pallas.kda import kda_chunk
+
+    z = jnp.zeros
+    with pytest.raises(ValueError, match="do not tile"):
+        kda_chunk(z((1, 64, 4, 16)), z((1, 64, 4, 16)), z((1, 64, 4, 128)),
+                  z((1, 64, 4, 16)), z((1, 64, 4)), z((1, 4, 16, 128)))
+
+
+def _state_tokens_through_the_engine(d):
+    """Two prompts (80 tokens: three chunks, the last padded; 20: one
+    admission bucket) through an engine whose linear heads are 128 wide
+    (what the kernels tile): the counters and the greedy tokens."""
+    from localai_tpu.engine import GenRequest
+
+    hf = dict(HF, linear_attn_config=dict(HF["linear_attn_config"],
+                                          head_dim=128))
+    cfg = load_config(_dir(d, **hf), dtype="float32")
+    params = init_params(cfg, jax.random.PRNGKey(0), dtype=jnp.float32)
+    eng = Engine(cfg, params, None, EngineConfig(
+        max_slots=2, max_context=256, prefill_buckets=(CHUNK,),
+        prefill_chunk=CHUNK))
+    greedy = SamplingParams(temperature=0.0)
+    toks = [[o.token_id for o in eng.generate(GenRequest(
+        [int(t) for t in _ids(n, seed=n)], greedy, max_tokens=3,
+        ignore_eos=True)) if o.token_id >= 0] for n in (80, 20)]
+    return eng.metrics, toks
+
+
+@pytest.mark.parametrize("tier", ["xla", "pallas-interpret"])
+def test_the_engine_counts_the_tokens_the_chunk_kernel_served(
+        tier, monkeypatch, tmp_path):
+    """`chunk_state_tokens__seen` is a prompt's real tokens x the linear
+    layers, whatever serves them; `__kernel` the same where
+    kv.StateKV._mix takes the kernel and 0 on its twin; and the kernel's
+    engine picks the twin's tokens."""
+    monkeypatch.setenv("LOCALAI_NO_PREWARM", "1")
+    m, toks = _state_tokens_through_the_engine(tmp_path)
+    if tier != "xla":
+        monkeypatch.setenv("LOCALAI_FORCE_PALLAS", "1")
+        twin = toks
+        m, toks = _state_tokens_through_the_engine(tmp_path)
+        assert toks == twin and [len(t) for t in toks] == [3, 3]
+    assert m["chunk_state_tokens__seen"] == (80 + 20) * 6
+    assert m["chunk_state_tokens__kernel"] == (
+        0 if tier == "xla" else (80 + 20) * 6)

@@ -25,6 +25,13 @@ but ONE: openPangu's `extend` with the kernels on, which PR 46 moved on
 purpose (a chunk's attention over latents in ops/pallas/mla.py: mla_chunk;
 PARENT_OF_46 keeps the parent's hash beside it). On the XLA twin that
 program is the parent's too.
+
+PR 51: Solar-Open2's `_admit_many` and `extend` with the kernels on moved on
+purpose (a prompt's and a chunk's gated delta rule in ops/pallas/kda.py:
+kda_chunk, which kv.StateKV._mix takes where `step` takes kda_decode;
+PARENT_OF_51 keeps the parent's two hashes). Its two decode programs, its
+four programs on the XLA twin, and all forty of the other five models are
+the parent's still.
 """
 import hashlib
 import json
@@ -161,9 +168,11 @@ PARENT = {
             "_decode_nomask_fn": "ee68658b5c841909",
             "_decode_loop_fn": "8f3773a8464a0440",
         },
+        # PR 51's own: a prompt's and a chunk's gated delta rule in the
+        # kernel (PARENT_OF_51 has the parent's)
         "pallas": {
-            "_admit_many_fn": "7983ec38f42576db",
-            "_extend_mid_fn": "f82f638d423e1b4a",
+            "_admit_many_fn": "8ca43fc684c3eea1",
+            "_extend_mid_fn": "a3ffa74a15683244",
             "_decode_nomask_fn": "3e502a281b398fa4",
             "_decode_loop_fn": "aff8bda03d3e95e8",
         },
@@ -217,6 +226,8 @@ PARENT.update({
     },
 })
 PARENT_OF_46 = "115b3d97fce03bdc"
+PARENT_OF_51 = {"_admit_many_fn": "7983ec38f42576db",
+                "_extend_mid_fn": "f82f638d423e1b4a"}
 
 def _text(jaxpr) -> str:
     """A jaxpr's text without what differs between two checkouts or two
@@ -292,6 +303,9 @@ def test_the_older_models_programs_are_the_parents(name, kernels, tmp_path):
     # the one program PR 46 moved holds the kernel, and no other does
     assert (got["_extend_mid_fn"] != PARENT_OF_46) or (
         name, kernels) != ("openpangu", "pallas")
+    # and the two PR 51 moved are not the parent's
+    if (name, kernels) == ("solar-open2", "pallas"):
+        assert all(got[p] != h for p, h in PARENT_OF_51.items())
 
 
 if __name__ == "__main__":

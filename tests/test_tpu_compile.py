@@ -34,7 +34,10 @@ def one_chip():
 @pytest.fixture
 def mosaic(monkeypatch):
     """The kernels as a TPU backend would trace them: not the interpreter."""
-    from localai_tpu.ops.pallas import flash_attention, grouped_matmul
+    # every kernel module, imported BEFORE the patch: one first imported
+    # under it would bind the patched `_interpret` for the process's life
+    from localai_tpu.ops.pallas import (  # noqa: F401
+        flash_attention, grouped_matmul, kda, mla, ssd)
 
     monkeypatch.setenv("LOCALAI_FORCE_PALLAS", "1")
     for mod in (flash_attention, grouped_matmul):
@@ -251,3 +254,40 @@ def test_a_latent_chunks_kernel_at_the_cells_shape(one_chip, mosaic,
         shape((1,), jnp.int32), shape((1,), jnp.int32), shape((), jnp.int32))
     assert "tpu_custom_call" in out.as_text()
     assert out.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("rows,tokens", [(1, 512), (4, 512)],
+                         ids=["a chunk", "an admission group"])
+def test_the_state_chunks_kernel_at_the_cells_shape(one_chip, mosaic,
+                                                    monkeypatch, rows,
+                                                    tokens):
+    """Solar-Open2's chunk (PR 51): 512 tokens of 64 heads x 128 x 128
+    through kda_chunk as kv.StateKV._mix serves it on one chip (the state a
+    value in and a value out): Mosaic takes the blocks (a pair of heads of the
+    whole chunk) under the VMEM it asks for, the arrays are read as they lie (the
+    program holds no copy of one beside the kernel), and nothing is
+    aliased."""
+    from localai_tpu.ops.pallas import kda
+
+    monkeypatch.setattr(kda, "_interpret", lambda: False)
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    h, d = 64, 128
+    assert kda.kda_chunk_vmem_bytes(tokens, kda.CHUNK_HEADS, d, d) < 64 << 20
+    # [B, S, H D]: as the projections' products and the convolution leave
+    # them, seen as [B, S, H, D]
+    wide = shape((rows, tokens, h * d))
+    heads = lambda a: a.reshape(rows, tokens, h, d)  # noqa: E731
+    out = _compile(
+        lambda q, k, v, g, beta, state, n: kda.kda_chunk(
+            heads(q), heads(k), heads(v), heads(g), beta, state, n_valid=n,
+            unit_qk=True)[0].reshape(rows, tokens, h * d),
+        wide, wide, wide, wide, shape((rows, tokens, h)),
+        shape((rows, h, d, d)), shape((rows,), jnp.int32))
+    text = out.as_text()
+    assert "tpu_custom_call" in text
+    assert "output_to_operand_aliasing" not in text
+    # the new state, which this program drops: no 17 MB a row of a copy
+    assert out.memory_analysis().temp_size_in_bytes < rows * (8 << 20)

@@ -8,8 +8,12 @@ cell's shapes (solar-open2-250b-ep8-d8: 64 heads x 128 x 128 float32 state,
 
 - `kda_decode` (ops/pallas/kda.py) over a stack of two layers: every row
   live, the cell's mix (`--live` rows of 32 live), and its XLA twin;
-- `kda_chunk` (ops/kda.py, the XLA form) over one 512-token chunk of one
-  row, products at HIGHEST precision (as served) and at the default.
+- `kda_chunk` over one 512-token chunk of one row: the kernel a chip
+  serves (ops/pallas/kda.py), and its XLA twin (ops/kda.py) with products at
+  HIGHEST precision (as the CPU and a mesh serve it) and at the default;
+  the kernel and the twin again at the admission groups' shapes, [4, 512]
+  and [8, 256]. `differs_by`: the kernel's output against the twin's;
+  `max_err`: against the token-by-token scan.
 
 The routed expert layer at this cell's shapes: tools/moe_layer_bench.py.
 
@@ -20,6 +24,7 @@ nothing.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import sys
@@ -47,7 +52,7 @@ def main() -> int:
 
     from benchmark.harness import roofline_kda as rk
     from localai_tpu.ops import kda
-    from localai_tpu.ops.pallas.kda import kda_decode
+    from localai_tpu.ops.pallas.kda import kda_chunk, kda_decode
 
     rehearsal = args.cpu_rehearsal
     if not rehearsal and jax.default_backend() != "tpu":
@@ -120,28 +125,53 @@ def main() -> int:
     row("kda_step (XLA twin), all rows", sec, rk.kda_decode_cost(B, H, dk, D))
 
     # ---- kda_chunk
-    cq = unit(jax.random.normal(ks[6], (1, T, H, dk))) * dk ** -0.5
-    ck = unit(jax.random.normal(ks[7], (1, T, H, dk)))
-    cv = jax.random.normal(ks[8], (1, T, H, D))
-    cg = -jnp.exp(jax.random.uniform(ks[9], (1, T, H, dk), minval=-7.,
-                                     maxval=0.))
-    cb = 2 * jax.nn.sigmoid(jax.random.normal(ks[10], (1, T, H)))
-    s0 = stack[0, :1]
-    chunk_cost = rk.kda_chunk_cost(T, H, dk, D)
-    served = jax.jit(lambda s: kda.kda_chunk(cq, ck, cv, cg, cb, s))
-    sec, (o_hi, s_hi) = timed(served, s0)
-    ro, rs = kda.kda_recurrent(cq, ck, cv, cg, cb, s0)
-    row("kda_chunk (XLA, HIGHEST: as served)", sec, chunk_cost,
-        max_err=float(jnp.abs(o_hi - ro).max()))
-    hi = kda._HI
-    kda._HI = None
-    try:
-        loose = jax.jit(lambda s: kda.kda_chunk(cq, ck, cv, cg, cb, s))
-        sec, (o_lo, _) = timed(loose, s0)
-    finally:
-        kda._HI = hi
-    row("kda_chunk (XLA, default precision)", sec, chunk_cost,
-        max_err=float(jnp.abs(o_lo - ro).max()))
+    if rehearsal:       # the kernel tiles a key of whole lane tiles
+        H, dk = 4, D
+    for rows, tokens in ((1, T), (4, T), (8, T // 2)):
+        shape = (rows, tokens, H)
+        cq = unit(jax.random.normal(ks[6], (*shape, dk))) * dk ** -0.5
+        ck = unit(jax.random.normal(ks[7], (*shape, dk)))
+        cv = jax.random.normal(ks[8], (*shape, D))
+        cg = -jnp.exp(jax.random.uniform(ks[9], (*shape, dk), minval=-7.,
+                                         maxval=0.))
+        cb = 2 * jax.nn.sigmoid(jax.random.normal(ks[10], shape))
+        s0 = jax.random.normal(ks[11], (rows, H, dk, D))
+        at = f"[{rows}, {tokens}]"
+        cost = rk.kda_chunk_cost(tokens, H, dk, D, rows=rows)
+        ro, rs = kda.kda_recurrent(cq, ck, cv, cg, cb, s0)
+        twin = jax.jit(lambda s: kda.kda_chunk(cq, ck, cv, cg, cb, s))
+        sec_twin, (o_hi, s_hi) = timed(twin, s0)
+        # the kernel's inputs as the served program has them: [B, S, H D]
+        # arrays (what the projections and the convolution leave) seen as
+        # [B, S, H, D], q and k made unit vectors inside (`unit_qk`; these
+        # are unit vectors already: the work is timed, `differs_by` is read
+        # with it off)
+        wide = [a.reshape(rows, tokens, -1) for a in (cq, ck, cv, cg)]
+
+        def kernel(s, unit_qk=True):
+            q, k, v, g = (a.reshape(*shape, -1) for a in wide)
+            return kda_chunk(q, k, v, g, cb, s, unit_qk=unit_qk)
+
+        sec, _ = timed(jax.jit(kernel), s0)
+        o_k, s_k = jax.jit(functools.partial(kernel, unit_qk=False))(s0)
+        differs = max(float(jnp.abs(o_k - o_hi).max()),
+                      float(jnp.abs(s_k - s_hi).max()))
+        assert differs < 1e-3, differs
+        row(f"kda_chunk {at} (Pallas kernel: as served)", sec, cost,
+            max_err=float(jnp.abs(o_k - ro).max()), differs_by=differs)
+        row(f"kda_chunk {at} (XLA twin, HIGHEST)", sec_twin, cost,
+            max_err=float(jnp.abs(o_hi - ro).max()))
+        if rows > 1:
+            continue
+        hi = kda._HI
+        kda._HI = None
+        try:
+            loose = jax.jit(lambda s: kda.kda_chunk(cq, ck, cv, cg, cb, s))
+            sec, (o_lo, _) = timed(loose, s0)
+        finally:
+            kda._HI = hi
+        row(f"kda_chunk {at} (XLA twin, default precision)", sec, cost,
+            max_err=float(jnp.abs(o_lo - ro).max()))
 
     os.makedirs(os.path.dirname(args.out), exist_ok=True)
     with open(args.out, "w") as f:

@@ -57,6 +57,7 @@ SCOPES = ("expert_einsums", "router", "dispatch", "shared", "latent_in",
 # Pallas kernels are traced under no scope (a scope would change their
 # compile-cache key, models/llama.py): they are told by their own jit name
 KERNELS = {"kda_decode": "attention/linear/kda_decode",
+           "kda_chunk": "attention/linear/kda_chunk",
            "ssd_decode": "attention/ssm/ssd_decode",
            "mla_decode": "attention/latent/mla_decode",
            "mla_chunk": "attention/latent/chunk_kernel",
