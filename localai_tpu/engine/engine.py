@@ -38,11 +38,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from localai_tpu.models.kv import _pallas_attention, chunk_rows
+from localai_tpu.models.kv import _pallas, _pallas_attention, chunk_rows
 from localai_tpu.models.llama import (
     FULL,
     LATENT,
     LINEAR,
+    ROUTED,
     SSM,
     WINDOW,
     LlamaConfig,
@@ -616,6 +617,13 @@ class Engine:
             # dispatch's at consume. 0 for a model without experts
             "expert_tokens__routed": 0,
             "expert_tokens__dense": 0,
+            # the expert layers of every call that takes the routed form,
+            # and of them those whose grouped products' grid ends at the
+            # tiles in use (ops/pallas/grouped_matmul.py, by the rule
+            # _grouped_experts goes by: the kernel, so a TPU and no mesh)
+            # and not at the static worst case the XLA loop's buffer has
+            "expert_tile_calls__seen": 0,
+            "expert_tile_calls__bounded": 0,
             # once a prompt chunk (_extend_mid / _extend_final), for ONE
             # full-attention layer: the rows of the slot's cache row the
             # chunk's attention visits (a dense cache: the blocks up to the
@@ -2719,6 +2727,11 @@ class Engine:
             form = expert_form(self.cfg, call_tokens, self.mesh)
             self.metrics[f"expert_tokens__{form}"] += int(tokens) * (
                 self.cfg.expert_layers)
+            if form == ROUTED:
+                self.metrics["expert_tile_calls__seen"] += (
+                    self.cfg.expert_layers)
+                self.metrics["expert_tile_calls__bounded"] += (
+                    self.cfg.expert_layers * _pallas(self.mesh is None))
 
     def _credit_chunk_state(self, tokens: int):
         """`tokens` real tokens of a prefill or a chunk call went through

@@ -972,6 +972,15 @@ def _grouped_experts(xt, eid, weight, up, gate, down, tm: int):
     every group on this chip's compiler: 40 times the operations, PERF.md
     section 6.)
 
+    Both stop at `used`: the loop's trip count and the kernel's grid (its
+    first bound is that traced count, PR 53: a chip that holds an eighth of
+    the router's experts uses a sixth of the static tiles). The rows of the
+    tiles past `used` are zeros out of the loop and NOT WRITTEN by the
+    kernel: there they hold what the buffer held, NaN and inf included.
+    Nothing of them comes back: such a row is of no pair, so its weight
+    `row_w` and its column of `sel` are 0, `lost` takes a row that is not
+    finite out of the way back, and a finite leftover times 0 adds 0.
+
     No sort and no gather: a pair's row is its group's first row plus its
     place in the group (a running count), and the rows are filled, and a
     token's k results summed, by products with one 0/1 matrix on the

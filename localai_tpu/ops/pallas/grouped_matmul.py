@@ -4,14 +4,25 @@ where it lies.
 
     out[t] = (a[t] @ W[layer, tile_e[t]]) * scale[layer, tile_e[t]]
 
-for the tiles t < used; a tile past `used` computes nothing, fetches nothing
-and is written as zeros. The grid walks (tile, output block, inner block);
-the index maps read the tile's expert from scalar prefetch, so the pipeline
-fetches the next tile's weights (int8 as stored, or bf16/f32) while this
-tile multiplies, and consecutive tiles of one expert fetch it once. int8
-weights are converted in the kernel and the per-output-channel scale is
-applied to the product. The pattern is jax.experimental.pallas.ops.tpu.
-megablox's; the XLA twin is models/llama._grouped_experts' loop.
+for the tiles t < used, the prefix of the layout that holds a pair. The
+grid walks (tile, output block, inner block) and ENDS at `used`: its first
+bound is that traced count (at least 1: a call none of whose pairs landed
+here multiplies one tile of zeros), on the chip and under the interpreter
+alike, so a chip that holds a sixteenth of the router's experts does not
+step over the fifteen sixteenths of the static worst case that stand empty
+(0.17 us a step on a v5e, a seventh to a quarter of a chunk's product in
+the cells that hold a share: PERF.md section 6, PR 53). THE ROWS OF THE
+TILES PAST `used` ARE NOT WRITTEN: they hold what the buffer held (NaN
+under the interpreter), and the caller keeps them out (_grouped_experts:
+their weight and their column of the 0/1 matrix are 0, and a row that is
+not finite is masked before the way back).
+The index maps read the tile's expert from scalar prefetch, so the
+pipeline fetches the next tile's weights (int8 as stored, or bf16/f32)
+while this tile multiplies, and consecutive tiles of one expert fetch it
+once. int8 weights are converted in the kernel and the per-output-channel
+scale is applied to the product. The pattern is
+jax.experimental.pallas.ops.tpu.megablox's; the XLA twin is
+models/llama._grouped_experts' loop.
 """
 from __future__ import annotations
 
@@ -48,54 +59,44 @@ def _blocks(k: int, n: int, itemsize: int) -> tuple[int, int]:
     return bk, _split(n, max(LANES, BLOCK_BYTES // (bk * itemsize)))
 
 
-def _kernel(sc_ref, te_ref, a_ref, w_ref, *rest, scaled, nk, keep):
+def _kernel(la_ref, te_ref, a_ref, w_ref, *rest, scaled, nk, keep):
     s_ref = rest[0] if scaled else None
     o_ref, acc_ref = rest[scaled:scaled + 2]
     t, kk = pl.program_id(0), pl.program_id(2)
-    live = t < sc_ref[1]
+    if keep:
+        # the expert's whole matrix is one block: converted once for the
+        # consecutive tiles that share it
+        wc_ref = rest[-1]
 
-    @pl.when(live)
-    def _product():
-        if keep:
-            # the expert's whole matrix is one block: converted once for
-            # the consecutive tiles that share it
-            wc_ref = rest[-1]
+        @pl.when((t == 0) | (te_ref[t] != te_ref[jnp.maximum(t - 1, 0)]))
+        def _convert():
+            wc_ref[...] = w_ref[...].astype(jnp.float32).astype(wc_ref.dtype)
 
-            @pl.when((t == 0) | (te_ref[t] != te_ref[jnp.maximum(t - 1, 0)]))
-            def _convert():
-                wc_ref[...] = w_ref[...].astype(jnp.float32).astype(
-                    wc_ref.dtype)
+        w = wc_ref[...]
+    else:
+        w = w_ref[...]
+        if w.dtype != a_ref.dtype:
+            w = w.astype(jnp.float32).astype(a_ref.dtype)
+    # bfloat16 operands have one precision; a default set from outside (the
+    # tests' float32) is for float32 operands, and Mosaic refuses it on these
+    part = jnp.dot(a_ref[...], w, preferred_element_type=jnp.float32,
+                   precision=None if a_ref.dtype == jnp.float32
+                   else jax.lax.Precision.DEFAULT)
 
-            w = wc_ref[...]
-        else:
-            w = w_ref[...]
-            if w.dtype != a_ref.dtype:
-                w = w.astype(jnp.float32).astype(a_ref.dtype)
-        # bfloat16 operands have one precision; a default set from outside
-        # (the tests' float32) is for float32 operands, and Mosaic refuses
-        # it on these
-        part = jnp.dot(a_ref[...], w, preferred_element_type=jnp.float32,
-                       precision=None if a_ref.dtype == jnp.float32
-                       else jax.lax.Precision.DEFAULT)
+    @pl.when(kk == 0)
+    def _first():
+        acc_ref[...] = part
 
-        @pl.when(kk == 0)
-        def _first():
-            acc_ref[...] = part
+    @pl.when(kk > 0)
+    def _more():
+        acc_ref[...] += part
 
-        @pl.when(kk > 0)
-        def _more():
-            acc_ref[...] += part
-
-        @pl.when(kk == nk - 1)
-        def _last():
-            y = acc_ref[...]
-            if scaled:
-                y = y * s_ref[...]
-            o_ref[...] = y.astype(o_ref.dtype)
-
-    @pl.when(jnp.logical_not(live))
-    def _idle():
-        o_ref[...] = jnp.zeros_like(o_ref)
+    @pl.when(kk == nk - 1)
+    def _last():
+        y = acc_ref[...]
+        if scaled:
+            y = y * s_ref[...]
+        o_ref[...] = y.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("blocks",))
@@ -103,7 +104,9 @@ def grouped_matmul(a, body, scale, tile_e, used, layer, blocks=None):
     """a [tiles, tm, K]; body [L, E, K, N] (int8 with scale [L, E, 1, N]
     float32, or a float dtype with scale None); tile_e [tiles] int32, the
     expert of each tile; used, layer: int32 scalars. Returns [tiles, tm, N]
-    in a's dtype. `blocks`: (inner, output) block sizes, for the bench."""
+    in a's dtype, the tiles [0, max(used, 1)) written and the rest NOT (the
+    module's docstring). `blocks`: (inner, output) block sizes, for the
+    bench."""
     tiles, tm, k = a.shape
     n = body.shape[-1]
     bk, bn = blocks or _blocks(k, n, body.dtype.itemsize)
@@ -118,29 +121,19 @@ def grouped_matmul(a, body, scale, tile_e, used, layer, blocks=None):
     vmem = (2 * (tm * bk * item + bk * bn * body.dtype.itemsize
                  + tm * bn * item + bn * 4) + tm * bn * 4
             + bk * bn * (4 + item + (item if keep else 0)) + (8 << 20))
-    scalars = jnp.stack([jnp.asarray(layer, jnp.int32).reshape(()),
-                         jnp.asarray(used, jnp.int32).reshape(())])
+    # the grid's first bound: the tiles in use, a traced scalar
+    used = jnp.clip(jnp.asarray(used, jnp.int32).reshape(()), 1, tiles)
+    layer = jnp.asarray(layer, jnp.int32).reshape((1,))
     tile_e = tile_e.astype(jnp.int32)
 
-    # a tile past `used` stays on the last live tile's last blocks: nothing
-    # is fetched for it
-    def at(t, j, kk, sc):
-        live = t < sc[1]
-        last = jnp.maximum(sc[1] - 1, 0)
-        return (jnp.where(live, t, last), jnp.where(live, j, nn - 1),
-                jnp.where(live, kk, nk - 1))
-
-    def a_map(t, j, kk, sc, te):
-        t, _, kk = at(t, j, kk, sc)
+    def a_map(t, j, kk, la, te):
         return (t, 0, kk)
 
-    def w_map(t, j, kk, sc, te):
-        t, j, kk = at(t, j, kk, sc)
-        return (sc[0], te[t], kk, j)
+    def w_map(t, j, kk, la, te):
+        return (la[0], te[t], kk, j)
 
-    def s_map(t, j, kk, sc, te):
-        t, j, _ = at(t, j, kk, sc)
-        return (sc[0], te[t], 0, j)
+    def s_map(t, j, kk, la, te):
+        return (la[0], te[t], 0, j)
 
     in_specs = [pl.BlockSpec((None, tm, bk), a_map),
                 pl.BlockSpec((None, None, bk, bn), w_map)]
@@ -153,10 +146,10 @@ def grouped_matmul(a, body, scale, tile_e, used, layer, blocks=None):
                           keep=keep),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(tiles, nn, nk),
+            grid=(used, nn, nk),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((None, tm, bn),
-                                   lambda t, j, kk, sc, te: (t, 0, j)),
+                                   lambda t, j, kk, la, te: (t, 0, j)),
             scratch_shapes=[pltpu.VMEM((tm, bn), jnp.float32)] + (
                 [pltpu.VMEM((bk, bn), a.dtype)] if keep else []),
         ),
@@ -166,4 +159,4 @@ def grouped_matmul(a, body, scale, tile_e, used, layer, blocks=None):
             vmem_limit_bytes=vmem),
         interpret=_interpret(),
         name="grouped_matmul",
-    )(scalars, tile_e, *operands)
+    )(layer, tile_e, *operands)

@@ -311,6 +311,10 @@ def test_the_engine_counts_expert_tokens_by_the_same_rule():
     finally:
         eng.stop()
     assert m["expert_tokens__routed"] == 70 * cfg.num_layers
+    # two routed calls' expert layers; on a CPU the XLA loop serves them,
+    # whose buffer is the static worst case: none bounded
+    assert m["expert_tile_calls__seen"] == 2 * cfg.num_layers
+    assert m["expert_tile_calls__bounded"] == 0
     # the first token comes from the prompt's logits, the other five from
     # decode steps (one more may have been dispatched before the stop)
     assert m["expert_tokens__dense"] in (5 * cfg.num_layers,
@@ -324,7 +328,37 @@ def test_the_engine_counts_expert_tokens_by_the_same_rule():
     assert eng.metrics["expert_tokens__routed"] == 0
 
 
-def test_the_layer_bench_rehearses(tmp_path):
+@pytest.mark.parametrize("env,bounded", [
+    (None, 0), ("LOCALAI_NO_PALLAS", 0), ("LOCALAI_FORCE_PALLAS", 1)],
+    ids=["a CPU", "XLA asked for", "the kernel (the interpreter)"])
+def test_the_engine_counts_the_calls_whose_grid_ends_at_the_tiles_in_use(
+        env, bounded, monkeypatch):
+    """`expert_tile_calls__seen`: the expert layers of each call that takes
+    the routed form; `__bounded`: the same where the grouped product kernel
+    serves them (_grouped_experts' own rule). A decode step's rows (the
+    dense form) credit neither."""
+    from localai_tpu.engine import Engine, EngineConfig
+
+    if env:
+        monkeypatch.setenv(env, "1")
+    cfg = _cfg(8, 2, vocab_size=128)
+    eng = Engine(cfg, init_params(cfg, jax.random.PRNGKey(0),
+                                  dtype=jnp.float32), None,
+                 EngineConfig(max_slots=2, max_context=128,
+                              prefill_buckets=(64,), prefill_chunk=64))
+    m = eng.metrics
+    assert (m["expert_tile_calls__seen"],
+            m["expert_tile_calls__bounded"]) == (0, 0)
+    eng._credit_experts(64, 50)
+    eng._credit_experts(2, 2)
+    eng._credit_experts(128, 128)
+    assert m["expert_tile_calls__seen"] == 2 * cfg.num_layers
+    assert m["expert_tile_calls__bounded"] == 2 * cfg.num_layers * bounded
+
+
+@pytest.mark.parametrize("model", ["mellum2", "solar", "trinity",
+                                   "openpangu", "nemotron"])
+def test_the_layer_bench_rehearses(tmp_path, model):
     import importlib.util
     import json
     import os
@@ -335,8 +369,16 @@ def test_the_layer_bench_rehearses(tmp_path):
     bench = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(bench)
     out = tmp_path / "bench.json"
-    assert bench.main(["--cpu-rehearsal", "--models", "mellum2",
+    assert bench.main(["--cpu-rehearsal", "--models", model,
                        "--out", str(out)]) == 0
     rows = json.loads(out.read_text())["rows"]
     assert {r["form"] for r in rows} >= {ROUTED, DENSE}
     assert all(r["ms"] is None for r in rows)       # a CPU run times nothing
+    alone = [r for r in rows if r["form"].startswith("the products alone")]
+    assert len(alone) == 2                          # one a call shape
+    for r in alone:
+        # the grid ends at the tiles in use, and the kernel is its twin
+        assert r["grid"][0] == "used"
+        assert r["static_tiles"] > r["tiles_in_use"]
+        assert set(r["grid_steps"].values()) == {max(r["tiles_in_use"], 1)}
+        assert max(r["differs_by"].values()) < 0.02

@@ -32,6 +32,15 @@ kda_chunk, which kv.StateKV._mix takes where `step` takes kda_decode;
 PARENT_OF_51 keeps the parent's two hashes). Its two decode programs, its
 four programs on the XLA twin, and all forty of the other five models are
 the parent's still.
+
+PR 53: every program with the kernels on that holds the routed expert layer
+moved on purpose (ops/pallas/grouped_matmul.py: the grid's first bound is
+the traced count of tiles in use, and the kernel lost its `live` predicates):
+`_admit_many` and `extend` of all six models, and the two decode programs of
+the four that hold a share of their experts (Mixtral's and Mellum2's decode
+steps take the dense form). PARENT_OF_53 keeps the parent's twenty hashes
+(d05db0e); the four decode programs that hold no such product and all
+twenty-four programs on the XLA twin are the parent's still.
 """
 import hashlib
 import json
@@ -141,8 +150,8 @@ PARENT = {
             "_decode_loop_fn": "8a860900cd45289a",
         },
         "pallas": {
-            "_admit_many_fn": "8f4729f85dc0ea26",
-            "_extend_mid_fn": "0d164d65a1d721f4",
+            "_admit_many_fn": "a0b28fe163d0393e",
+            "_extend_mid_fn": "d034059b37c808e1",
             "_decode_nomask_fn": "2f0cbca457f87e88",
             "_decode_loop_fn": "638f5517a3e82122",
         },
@@ -155,8 +164,8 @@ PARENT = {
             "_decode_loop_fn": "ba6b6e51f0014472",
         },
         "pallas": {
-            "_admit_many_fn": "f28fb2fdb76012f2",
-            "_extend_mid_fn": "087969ebba11f2fc",
+            "_admit_many_fn": "ae1779dd90b9e0c1",
+            "_extend_mid_fn": "73b5097510e740cb",
             "_decode_nomask_fn": "b65452d32874300b",
             "_decode_loop_fn": "20075d0627bfddc4",
         },
@@ -171,10 +180,10 @@ PARENT = {
         # PR 51's own: a prompt's and a chunk's gated delta rule in the
         # kernel (PARENT_OF_51 has the parent's)
         "pallas": {
-            "_admit_many_fn": "8ca43fc684c3eea1",
-            "_extend_mid_fn": "a3ffa74a15683244",
-            "_decode_nomask_fn": "3e502a281b398fa4",
-            "_decode_loop_fn": "aff8bda03d3e95e8",
+            "_admit_many_fn": "74861ad335c2533f",
+            "_extend_mid_fn": "9c4f9f223a43790e",
+            "_decode_nomask_fn": "a8466d8370543df4",
+            "_decode_loop_fn": "538ce749c2b66c46",
         },
     },
 }
@@ -190,10 +199,10 @@ PARENT.update({
             "_decode_loop_fn": "27de698ee217f641",
         },
         "pallas": {
-            "_admit_many_fn": "442fd7f6812fdc9d",
-            "_extend_mid_fn": "07ed3cd063528d9c",
-            "_decode_nomask_fn": "401d33888884ce27",
-            "_decode_loop_fn": "1cbbffa38315a98a",
+            "_admit_many_fn": "3fca136a096a7d8a",
+            "_extend_mid_fn": "95c7f9a826a4d773",
+            "_decode_nomask_fn": "08edf4c967fe5c5d",
+            "_decode_loop_fn": "49342b1402988222",
         },
     },
     "nemotron3": {
@@ -204,10 +213,10 @@ PARENT.update({
             "_decode_loop_fn": "cd45fa63b5916f0f",
         },
         "pallas": {
-            "_admit_many_fn": "3a788d660edfbe8e",
-            "_extend_mid_fn": "0c8edc4ca79f22e0",
-            "_decode_nomask_fn": "833fa8079930f29d",
-            "_decode_loop_fn": "35a071f9118b2773",
+            "_admit_many_fn": "5a7db382ee38bae2",
+            "_extend_mid_fn": "7b0fb8e0651ed4e2",
+            "_decode_nomask_fn": "424a99fd375cf9b9",
+            "_decode_loop_fn": "de456dc2d5ec5a8f",
         },
     },
     "openpangu": {
@@ -218,16 +227,41 @@ PARENT.update({
             "_decode_loop_fn": "25e35a53a3268d0a",
         },
         "pallas": {
-            "_admit_many_fn": "839e475d81c54176",
-            "_extend_mid_fn": "ee18f355c3657b65",
-            "_decode_nomask_fn": "985e4b109b1cee2d",
-            "_decode_loop_fn": "2606d3e6878ce30c",
+            "_admit_many_fn": "8fcc076dac3d2017",
+            "_extend_mid_fn": "824029f7259ed3d5",
+            "_decode_nomask_fn": "b01e410c68908da1",
+            "_decode_loop_fn": "918e069cb795e214",
         },
     },
 })
 PARENT_OF_46 = "115b3d97fce03bdc"
 PARENT_OF_51 = {"_admit_many_fn": "7983ec38f42576db",
                 "_extend_mid_fn": "f82f638d423e1b4a"}
+
+# the parent's (d05db0e) hashes of the programs PR 53 moved, kernels on
+PARENT_OF_53 = {
+    "mixtral": {"_admit_many_fn": "8f4729f85dc0ea26",
+                "_extend_mid_fn": "0d164d65a1d721f4"},
+    "mellum2": {"_admit_many_fn": "f28fb2fdb76012f2",
+                "_extend_mid_fn": "087969ebba11f2fc"},
+    "solar-open2": {"_admit_many_fn": "8ca43fc684c3eea1",
+                    "_extend_mid_fn": "a3ffa74a15683244",
+                    "_decode_nomask_fn": "3e502a281b398fa4",
+                    "_decode_loop_fn": "aff8bda03d3e95e8"},
+    "trinity": {"_admit_many_fn": "442fd7f6812fdc9d",
+                "_extend_mid_fn": "07ed3cd063528d9c",
+                "_decode_nomask_fn": "401d33888884ce27",
+                "_decode_loop_fn": "1cbbffa38315a98a"},
+    "nemotron3": {"_admit_many_fn": "3a788d660edfbe8e",
+                  "_extend_mid_fn": "0c8edc4ca79f22e0",
+                  "_decode_nomask_fn": "833fa8079930f29d",
+                  "_decode_loop_fn": "35a071f9118b2773"},
+    "openpangu": {"_admit_many_fn": "839e475d81c54176",
+                  "_extend_mid_fn": "ee18f355c3657b65",
+                  "_decode_nomask_fn": "985e4b109b1cee2d",
+                  "_decode_loop_fn": "2606d3e6878ce30c"},
+}
+
 
 def _text(jaxpr) -> str:
     """A jaxpr's text without what differs between two checkouts or two
@@ -306,6 +340,10 @@ def test_the_older_models_programs_are_the_parents(name, kernels, tmp_path):
     # and the two PR 51 moved are not the parent's
     if (name, kernels) == ("solar-open2", "pallas"):
         assert all(got[p] != h for p, h in PARENT_OF_51.items())
+    # and the programs PR 53 moved (those that hold a grouped product) are
+    # not the parent's
+    if kernels == "pallas":
+        assert all(got[p] != h for p, h in PARENT_OF_53[name].items())
 
 
 if __name__ == "__main__":

@@ -187,6 +187,31 @@ def test_routed_share_at_the_cells_widths(one_chip, mosaic, rows):
     assert out.output_shardings is not None
 
 
+@pytest.mark.parametrize("tiles,tm,k,n,held", [
+    (192, 128, 2304, 896, 64), (127, 64, 896, 2304, 64),
+    (479, 128, 1024, 2688, 128), (271, 16, 7680, 2048, 16),
+    (294, 16, 1280, 4096, 40), (16, 128, 4096, 14336, 8)],
+    ids=["Mellum2 up at [4, 512] (one block, kept)", "Mellum2 down",
+         "Nemotron up at [8, 256] (one block, kept)", "openPangu up",
+         "Solar-Open2 down", "Mixtral up"])
+def test_grouped_product_with_a_traced_grid_bound(one_chip, mosaic, tiles,
+                                                  tm, k, n, held):
+    """PR 53: the grid's first bound is the traced count of tiles in use;
+    Mosaic takes it with the scalar prefetch, the accumulator and, where
+    the int8 matrix is one block, the scratch it is converted into once."""
+    from localai_tpu.ops.pallas.grouped_matmul import grouped_matmul
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    out = _compile(
+        grouped_matmul.__wrapped__, shape((tiles, tm, k), jnp.bfloat16),
+        shape((2, held, k, n), jnp.int8),
+        shape((2, held, 1, n), jnp.float32), shape((tiles,), jnp.int32),
+        shape((), jnp.int32), shape((), jnp.int32))
+    assert "tpu_custom_call" in out.as_text()
+
+
 def test_a_chunks_attention_holds_no_score_array_of_the_whole_row(one_chip):
     """The long-document cell's chunk (512 queries of 64 heads over 8 KV
     heads, one gathered row of an int8 stack of 32 x 16384) compiled as it
